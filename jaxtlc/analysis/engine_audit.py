@@ -37,9 +37,9 @@ from . import SEV_ERROR, SEV_WARNING, Finding
 U32_MAX = 1 << 32
 
 # host-callback primitives that have no place in a fused engine body
+# (jax.debug.print traces to its own `debug_print` primitive)
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "host_callback_call", "outside_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 })
 
 
@@ -49,7 +49,7 @@ CALLBACK_PRIMS = frozenset({
 
 
 def _sub_jaxprs(params: dict):
-    import jax.core as jc
+    import jax.extend.core as jc
 
     for v in params.values():
         if isinstance(v, jc.ClosedJaxpr):

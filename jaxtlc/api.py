@@ -202,6 +202,21 @@ def _err(args) -> TextIO:
 
 
 def _run_check(args) -> int:
+    from .runtime import (
+        PlatformError,
+        enable_compile_cache,
+        fp_mesh,
+        require_platform,
+    )
+
+    try:
+        require_platform(args.workers)
+        if args.sharded and args.fpset != "DiskFPSet":
+            fp_mesh(args.sharded)  # fewer devices than asked: an error
+    except PlatformError as e:
+        print(f"Error: {e}", file=_err(args))
+        return 1
+    enable_compile_cache()
     try:
         spec: RunSpec = resolve(
             args.config,
@@ -261,7 +276,8 @@ def _run_check(args) -> int:
 
     device = str(jax.devices()[0])
     log.version(__version__)
-    log.banner(spec.fp_index, DEFAULT_SEED, spec.workers, device)
+    log.banner(spec.fp_index, DEFAULT_SEED, jax.devices()[0].platform,
+               device)
     log.sany(*_sany_inputs(args.config, spec.spec_name))
     log.starting()
     log.computing_init()
@@ -342,12 +358,9 @@ def _run_check(args) -> int:
         if device_path:
             mesh = None
             if args.sharded:
-                from jax.sharding import Mesh
+                from .runtime import fp_mesh
 
-                import numpy as np
-
-                mesh = Mesh(np.array(jax.devices()[: args.sharded]),
-                            ("fp",))
+                mesh = fp_mesh(args.sharded)
             results = check_properties_device(
                 spec.model, props, chunk=args.chunk,
                 state_capacity=args.fpcap, fp_capacity=args.fpcap,
@@ -451,15 +464,11 @@ def _dispatch_check(args, spec, log):
     The resil supervisor wraps the device engines whenever -auto-grow
     (default) or -checkpoint is in play; -no-auto-grow without
     -checkpoint keeps the raw fused single-dispatch path."""
-    import jax
-
     if args.sharded and args.fpset != "DiskFPSet":
-        import numpy as np
-        from jax.sharding import Mesh
-
         from .engine.sharded import check_sharded
+        from .runtime import fp_mesh
 
-        mesh = Mesh(np.array(jax.devices()[: args.sharded]), ("fp",))
+        mesh = fp_mesh(args.sharded)
         if args.checkpoint or args.autogrow:
             from .resil import check_sharded_supervised
 
@@ -564,12 +573,25 @@ def _preflight_gate(args, log, build_report):
     from .analysis.report import emit_to_journal
     from .obs.views import render_tlc_event
 
+    journal = getattr(args, "_journal", None)
     try:
         report = build_report(args.analyze)
-    except Exception as e:  # a broken lint must never block a run
-        log.msg(1000, f"Preflight analysis skipped: {e}", severity=1)
-        return None
-    journal = getattr(args, "_journal", None)
+    except Exception as e:
+        if not args.analyze:
+            # a broken default lint must never block a run
+            log.msg(1000, f"Preflight analysis skipped: {e}", severity=1)
+            return None
+        # the deep audit was asked for by name: a crash inside it is a
+        # failed run, not a skipped check
+        if journal is not None:
+            journal.event("final", verdict="error", generated=0,
+                          distinct=0, depth=0, queue=0, wall_s=0.0,
+                          interrupted=False)
+        log.msg(1000, f"Preflight analysis (-analyze) crashed: "
+                      f"{type(e).__name__}: {e}; run aborted "
+                      "(-no-preflight to override).", severity=1)
+        _finish_journal(args, log)
+        return 1
 
     def on_event(kind, info):
         import time as _time
@@ -739,10 +761,13 @@ def _finish_journal(args, log, r=None, sup=None, verdict: str = None,
                     name="Temporal properties were violated")
         if sup is None and r is not None:
             v = verdict or ("violation" if r.violation != 0 else "ok")
+            shards = getattr(r, "shard_distinct", None)
             j.event("final", verdict=v, generated=r.generated,
                     distinct=r.distinct, depth=r.depth,
                     queue=r.queue_left, wall_s=round(wall_s, 6),
-                    interrupted=False)
+                    interrupted=False,
+                    **({"shard_distinct": list(shards)}
+                       if shards is not None else {}))
         if args.traceout:
             from .obs.journal import read as read_journal
             from .obs.trace import export_chrome_trace
@@ -875,18 +900,14 @@ def _run_check_gen(args, spec) -> int:
                 fp_index=spec.fp_index,
                 check_deadlock=spec.check_deadlock,
             )
-        import jax
-        import numpy as np
-        from jax.sharding import Mesh
-
         from .engine.sharded import (
             check_sharded,
             check_sharded_with_checkpoints,
             gen_backend,
         )
+        from .runtime import fp_mesh
 
-        n_dev = args.sharded or 1
-        mesh = Mesh(np.array(jax.devices()[:n_dev]), ("fp",))
+        mesh = fp_mesh(args.sharded or 1)
         backend = gen_backend(g)
         kw = dict(
             chunk=args.chunk,
@@ -920,12 +941,9 @@ def _run_check_gen(args, spec) -> int:
         if use_device_path(distinct, args.fairness, args.liveness_host):
             mesh = None
             if args.sharded:
-                import jax
-                import numpy as np
-                from jax.sharding import Mesh
+                from .runtime import fp_mesh
 
-                mesh = Mesh(np.array(jax.devices()[: args.sharded]),
-                            ("fp",))
+                mesh = fp_mesh(args.sharded)
             return check_leads_to_device(
                 g, p, q, name, chunk=args.chunk,
                 state_capacity=args.fpcap, fp_capacity=args.fpcap,
@@ -1037,11 +1055,9 @@ def _run_check_struct(args, spec) -> int:
         kw = dict(chunk=args.chunk, queue_capacity=args.qcap,
                   fp_capacity=args.fpcap)
         if args.sharded:
-            import numpy as np
-            import jax
-            from jax.sharding import Mesh
+            from .runtime import fp_mesh
 
-            mesh = Mesh(np.array(jax.devices()[: args.sharded]), ("fp",))
+            mesh = fp_mesh(args.sharded)
             if args.checkpoint or args.autogrow:
                 from .resil import check_sharded_supervised
 
@@ -1229,7 +1245,8 @@ def _run_sim_struct(args, spec) -> int:
 
     device = str(jax.devices()[0])
     log.version(__version__)
-    log.banner(spec.fp_index, DEFAULT_SEED, spec.workers, device)
+    log.banner(spec.fp_index, DEFAULT_SEED, jax.devices()[0].platform,
+               device)
     log.sany(*_sany_inputs(args.config, spec.spec_name))
     log.starting()
     log.computing_init()
@@ -1470,7 +1487,8 @@ def _run_infer_struct(args, spec) -> int:
 
     device = str(jax.devices()[0])
     log.version(__version__)
-    log.banner(spec.fp_index, DEFAULT_SEED, spec.workers, device)
+    log.banner(spec.fp_index, DEFAULT_SEED, jax.devices()[0].platform,
+               device)
     log.sany(*_sany_inputs(args.config, spec.spec_name))
     log.starting()
     log.computing_init()
@@ -1761,7 +1779,8 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
 
     device = str(jax.devices()[0])
     log.version(__version__)
-    log.banner(spec.fp_index, DEFAULT_SEED, spec.workers, device)
+    log.banner(spec.fp_index, DEFAULT_SEED, jax.devices()[0].platform,
+               device)
     log.sany(*_sany_inputs(args.config, spec.spec_name))
     log.starting()
     log.computing_init()

@@ -13,8 +13,12 @@ Two modes:
 * **launcher** (``--spawn N``): fork N localhost worker subprocesses
   around a fresh coordinator port - the test/bench topology, each
   worker a real jax.distributed process with its own device set (gloo
-  collectives over loopback).  SIGTERM to the launcher forwards to
-  every worker, so pod preemption drills work through it.
+  collectives over loopback).  This is a CPU drill by construction:
+  the workers are forced onto ``JAX_PLATFORMS=cpu`` whatever the
+  launcher inherited (N processes cannot share one chip; a real pod is
+  one worker per host, started by the cluster, not by this flag) and
+  the launcher says "cpu pod" in its output.  SIGTERM to the launcher
+  forwards to every worker, so pod preemption drills work through it.
 
 The module sets XLA's host-platform device count from
 ``--devices-per-host`` BEFORE any jax backend initializes (jaxtlc.dist
@@ -99,7 +103,9 @@ def _worker(args) -> int:
             ).strip()
     from . import DEFAULT_COORDINATOR, init_pod, run_pod
     from ..config import ModelConfig
+    from ..runtime import enable_compile_cache
 
+    enable_compile_cache()
     init_pod(args.coordinator or DEFAULT_COORDINATOR,
              args.num_hosts, args.host)
     cfg = ModelConfig(False, False) if args.ff else ModelConfig()
@@ -152,10 +158,11 @@ def _spawn(args, argv) -> int:
             skip = True
         elif not a.startswith("--spawn="):
             child_argv.append(a)
+    print(f"cpu pod: {args.spawn} localhost workers, gloo loopback, "
+          "JAX_PLATFORMS=cpu forced", flush=True)
     procs = []
     for i in range(args.spawn):
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "jaxtlc.dist", *child_argv,
              "--coordinator", coord, "--num-hosts", str(args.spawn),

@@ -116,16 +116,15 @@ def pod_mesh(devices: int = None):
     hi & (D-1) assigns each host a contiguous row block.  `devices`
     truncates to the first N devices (single-process width-change
     tests; a real pod always meshes every device)."""
-    import jax
-    from jax.sharding import Mesh
+    from ..runtime import fp_mesh
 
-    devs = np.array(jax.devices()[:devices] if devices else jax.devices())
-    assert devs.size & (devs.size - 1) == 0, (
+    mesh = fp_mesh(devices or 0)
+    assert mesh.size & (mesh.size - 1) == 0, (
         "pod device count must be a power of two "
-        f"(got {devs.size}: set --xla_force_host_platform_device_count "
+        f"(got {mesh.size}: set --xla_force_host_platform_device_count "
         "or adjust the host count)"
     )
-    return Mesh(devs, ("fp",))
+    return mesh
 
 
 def host_checkpoint_path(base: str, host: int) -> str:
@@ -200,10 +199,8 @@ def make_stop_vote(mesh):
     """Pod-wide preemption consensus: pmax over per-host stop flags, so
     one SIGTERM stops every host at the SAME segment fence."""
     import jax
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
-
-    from ..engine.sharded import shard_map
 
     (axis,) = mesh.axis_names
     fn = jax.jit(shard_map(
@@ -228,10 +225,10 @@ def make_stats_gather(mesh, carry):
     result_from_shard_carry unchanged, so pod statistics reduce with
     bit-identical semantics to the single-process path."""
     import jax
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..engine.sharded import shard_host_rows, shard_map
+    from ..engine.sharded import shard_host_rows
 
     (axis,) = mesh.axis_names
     fields = [f for f in _STAT_FIELDS

@@ -192,6 +192,10 @@ class CheckResult(NamedTuple):
     # Reported on the 2193 stats line so users can size fp_capacity (and
     # see how close a run came to the fp_highwater regrow trigger)
     fp_occupancy: float = None
+    # mesh engine only: distinct states held by each device's shard of
+    # the fingerprint table (sums to `distinct`; a zero means a device
+    # that owned no part of the space); None on single-device engines
+    shard_distinct: tuple = None
     # device per-site coverage totals ({site key: visits}, obs.coverage);
     # None when the engine carried no coverage plane
     site_coverage: dict = None

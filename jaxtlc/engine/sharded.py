@@ -54,12 +54,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - older jax keeps it experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 # the supervisor's degradation ladder consults this before offering the
@@ -73,25 +68,6 @@ SPILL_CAPABLE = True
 # lanes safely degrade to a host probe, so a small cap bounds the device
 # filter at the price of a few extra host lookups
 SPILL_MEMBER_ROUNDS = 4
-
-
-def shard_map(f, mesh, in_specs, out_specs, **kw):
-    """Version-portable shard_map: the replication-check kwarg was renamed
-    check_rep -> check_vma across jax releases; accept either here so the
-    engine runs on both the TPU driver's jax and the pinned CPU test jax."""
-    try:
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    except TypeError:
-        kw2 = dict(kw)
-        if "check_vma" in kw2:
-            kw2["check_rep"] = kw2.pop("check_vma")
-        elif "check_rep" in kw2:
-            kw2["check_vma"] = kw2.pop("check_rep")
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw2
-        )
 
 from ..config import ModelConfig
 from ..spec.labels import LABELS
@@ -1368,6 +1344,9 @@ def result_from_shard_carry(
         fp_occupancy=(
             int(np.asarray(out.distinct).sum()) / fp_capacity_total
             if fp_capacity_total else None
+        ),
+        shard_distinct=tuple(
+            int(v) for v in np.asarray(out.distinct).reshape(-1)
         ),
         site_coverage=site_coverage,
     )

@@ -85,7 +85,8 @@ EVENTS = {
     # -- verdicts ----------------------------------------------------------
     "violation": {"code": _NUM, "name": _STR},
     # the structured final event: EVERY run (clean, violated, interrupted,
-    # progress-lost) ends its journal with exactly one of these
+    # progress-lost) ends its journal with exactly one of these.  Mesh
+    # runs add shard_distinct (extra field): per-device table occupancy
     "final": {"verdict": _STR, "generated": _NUM, "distinct": _NUM,
               "depth": _NUM, "queue": _NUM, "wall_s": _NUM,
               "interrupted": _BOOL},

@@ -68,6 +68,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import numpy as np
 from jax import lax
+from jax.errors import JaxRuntimeError
 
 from ..engine import checkpoint as ckpt
 from ..engine.bfs import (
@@ -90,23 +91,12 @@ from .regrow import (
     migrate_shard_carry,
 )
 
-# exception types the segment-retry loop CATCHES; the injected stand-in
-# plus whatever XLA runtime error type this jax exposes.  Caught is not
-# retried: every caught error is classified first (is_resource_exhausted)
-# - a deterministic RESOURCE_EXHAUSTED routes to the degradation ladder,
-# only genuinely transient errors get the backoff budget.
-_TRANSIENT: tuple = (TransientFault,)
-try:  # pragma: no cover - depends on the installed jaxlib
-    from jax.errors import JaxRuntimeError
-
-    _TRANSIENT = (TransientFault, JaxRuntimeError)
-except ImportError:  # pragma: no cover
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-
-        _TRANSIENT = (TransientFault, XlaRuntimeError)
-    except ImportError:
-        pass
+# exception types the segment-retry loop CATCHES: the injected stand-in
+# plus jax's runtime error.  Caught is not retried: every caught error
+# is classified first (is_resource_exhausted) - a deterministic
+# RESOURCE_EXHAUSTED routes to the degradation ladder, only genuinely
+# transient errors get the backoff budget.
+_TRANSIENT: tuple = (TransientFault, JaxRuntimeError)
 
 # python-level allocation failures (and the injected AllocDeniedFault,
 # a MemoryError) are caught alongside the runtime errors - they are
@@ -1282,7 +1272,9 @@ def supervise(adapter, params: dict,
     _emit(opts, "final", verdict=verdict, generated=result.generated,
           distinct=result.distinct, depth=result.depth,
           queue=result.queue_left, wall_s=round(wall, 6),
-          interrupted=interrupted)
+          interrupted=interrupted,
+          **({"shard_distinct": list(result.shard_distinct)}
+             if result.shard_distinct is not None else {}))
     spill_hits = 0
     if spill_rt is not None and getattr(carry, "spill_hits",
                                         None) is not None:

@@ -1,0 +1,99 @@
+"""Process-entry rules shared by every front door: which platform a run
+may use, how many devices a mesh may claim, and where compiled programs
+persist.
+
+Each entry point (`api.run_check`, `jaxtlc.serve` start-up, the
+`jaxtlc.dist` worker, `bench.py`, `chip_smoke.py`) calls
+`enable_compile_cache()` once and resolves its platform through
+`require_platform()`; engines never do either themselves.  The point of
+both is that a run cannot look like a chip run when it is not one: CPU
+is used only when asked for, a mesh never shrinks to the devices that
+happen to exist, and the compile cache sits where the caller (or the
+checkout) put it - not under $HOME.
+"""
+
+from __future__ import annotations
+
+import os
+
+# the in-checkout default, derived from the package location: the cache
+# key includes the directory, so a path that moves never hits
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache",
+)
+
+
+class PlatformError(RuntimeError):
+    """The resolved JAX platform or device count is not what the run
+    asked for (entry points turn this into exit code 1)."""
+
+
+def enable_compile_cache() -> str:
+    """Persist every XLA compile of this process; returns the directory.
+
+    JAX_COMPILATION_CACHE_DIR set: JAX already has the directory and it
+    is left alone.  Unset: the fixed `<checkout>/.jax_cache`.  The
+    persistence thresholds are zeroed either way - the fused engine
+    loops are exactly the long compiles the cache exists for, and the
+    small ones cost nothing to keep.  JAX_ENABLE_COMPILATION_CACHE=false
+    is JAX's own off switch."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return jax.config.jax_compilation_cache_dir
+    if jax.config.jax_compilation_cache_dir != DEFAULT_CACHE_DIR:
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
+
+
+def cpu_requested(workers: str = "") -> bool:
+    """Whether CPU was asked for: `-workers cpu`, or a JAX platform
+    list (JAX_PLATFORMS / jax.config) that leads with cpu."""
+    import jax
+
+    platforms = jax.config.jax_platforms or ""
+    return workers == "cpu" or platforms.split(",")[0].strip() == "cpu"
+
+
+def require_platform(workers: str = "") -> str:
+    """Resolve the platform this process runs on and return its name.
+
+    CPU is a platform only when asked for (`cpu_requested`); any other
+    request must resolve to an accelerator - JAX drops to CPU without a
+    word when it finds no chip, and a run that then reports rates is
+    the failure this guards against."""
+    import jax
+
+    if workers == "cpu" and not cpu_requested():
+        # effective only before the backend initializes, which is when
+        # the CLI calls this; afterwards the platform is already fixed
+        jax.config.update("jax_platforms", "cpu")
+    platform = jax.devices()[0].platform
+    if platform == "cpu" and not cpu_requested(workers):
+        raise PlatformError(
+            "JAX found no accelerator and resolved to cpu; to run on "
+            "CPU ask for it: pass -workers cpu or set JAX_PLATFORMS=cpu"
+        )
+    return platform
+
+
+def fp_mesh(n_devices: int = 0):
+    """The single-axis "fp" mesh over the first `n_devices` devices
+    (0 = all of them).  Asking for more devices than exist is an error,
+    never a smaller mesh."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    devices = jax.devices()
+    if n_devices > len(devices):
+        raise PlatformError(
+            f"{n_devices} devices requested but JAX reports "
+            f"{len(devices)} ({devices[0].platform})"
+        )
+    return Mesh(np.array(devices[:n_devices] if n_devices else devices),
+                ("fp",))

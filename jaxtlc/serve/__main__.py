@@ -46,17 +46,23 @@ def main(argv=None) -> int:
                    help="smoke: serve + submit + assert warm reuse, "
                         "then exit")
     args = p.parse_args(argv)
+    from ..runtime import PlatformError
     from .server import start_server
 
     if args.tiny:
         return _tiny()
-    srv = start_server(
-        args.root, port=args.port, host=args.host,
-        pool_capacity=args.pool_cap, sweep_width=args.sweep_width,
-        large_fpcap=args.large_fpcap,
-        prewarm=[s for s in args.prewarm.split(",") if s],
-        queue_bound=args.queue_bound, tenant_quota=args.tenant_quota,
-    )
+    try:
+        srv = start_server(
+            args.root, port=args.port, host=args.host,
+            pool_capacity=args.pool_cap, sweep_width=args.sweep_width,
+            large_fpcap=args.large_fpcap,
+            prewarm=[s for s in args.prewarm.split(",") if s],
+            queue_bound=args.queue_bound,
+            tenant_quota=args.tenant_quota,
+        )
+    except PlatformError as e:
+        print(f"Error: {e}", file=sys.stderr)
+        return 1
     print(f"jaxtlc checking service at {srv.url} "
           f"(POST /jobs, DELETE /jobs/<id>; GET /jobs /pool /health "
           f"/runs /metrics /events; runs dir {srv.root}; ctrl-c exits)")

@@ -39,37 +39,37 @@ class CompileMeter:
     cache hits included: deserialization still passes through the
     event), never by a warm executable call.  Monotonic; assert on
     deltas.
-
-    Registration prefers the public ``jax.monitoring`` module (the
-    private ``jax._src`` spelling is a fallback for old jaxes) and a
-    jax that exposes neither degrades the meter to ``available=False``
-    - the count stays zero and pool construction/server start proceed;
-    only the zero-compile ASSERTION loses its ground truth, and
-    callers can see that in `/pool`'s ``xla_meter`` field."""
+    `cache_hits` counts the persistent-cache hits among them
+    (`/jax/compilation_cache/cache_hits`): `count - cache_hits` is the
+    number of programs the backend actually compiled, which is what a
+    second process over a warm cache must see at zero."""
 
     _instance: Optional["CompileMeter"] = None
 
     def __init__(self):
+        from jax import monitoring
+
         self.count = 0
         self.wall_s = 0.0
-        self.available = False
+        self.cache_hits = 0
         self._lock = threading.Lock()
 
-        def on_event(name, duration, **kw):
+        def on_duration(name, duration, **kw):
             if name.endswith("backend_compile_duration"):
                 with self._lock:
                     self.count += 1
                     self.wall_s += float(duration)
 
-        try:
-            try:
-                from jax import monitoring
-            except ImportError:  # pragma: no cover - pre-public-API jax
-                from jax._src import monitoring
-            monitoring.register_event_duration_secs_listener(on_event)
-            self.available = True
-        except Exception:  # pragma: no cover - a metric, not a fault line
-            pass
+        def on_event(name, **kw):
+            if name == "/jax/compilation_cache/cache_hits":
+                with self._lock:
+                    self.cache_hits += 1
+
+        # registration failing raises: with no listener "warm submit =
+        # 0 compiles" would be vacuous, so there is no degraded meter
+        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_listener(on_event)
+        self.available = True
 
     @classmethod
     def instance(cls) -> "CompileMeter":

@@ -1320,7 +1320,6 @@ class Scheduler:
         out = io.StringIO()
         kw = {k: job.options[k] for k in _REQUEST_OPTIONS
               if k in job.options}
-        kw.setdefault("workers", "cpu" if _on_cpu() else "tpu")
         if frontend is not None:
             kw.setdefault("frontend", frontend)
         req = CheckRequest(
@@ -1486,9 +1485,3 @@ def _version() -> str:
     from .. import __version__
 
     return __version__
-
-
-def _on_cpu() -> bool:
-    import jax
-
-    return jax.devices()[0].platform == "cpu"

@@ -162,8 +162,13 @@ class CheckServer:
                  breaker_cooldown_s: float = None, faults=None):
         from http.server import ThreadingHTTPServer
 
+        from ..runtime import enable_compile_cache, require_platform
         from .scheduler import DEFAULT_LARGE_FPCAP
 
+        # process entry: refuse to serve from a CPU nobody asked for
+        # (PlatformError), persist every engine compile
+        require_platform()
+        enable_compile_cache()
         self.root = root or tempfile.mkdtemp(prefix="jaxtlc-serve-")
         os.makedirs(self.root, exist_ok=True)
         self.pool = pool or EnginePool(capacity=pool_capacity,

@@ -231,9 +231,6 @@ class SweepEngine:
         sort_free: bool = None,
         deferred: bool = None,
     ):
-        from ..struct.cache import enable_persistent_cache
-
-        enable_persistent_cache()  # class compiles persist like struct's
         self.model = model
         self.params = {c: (int(lo), int(hi))
                        for c, (lo, hi) in params.items()}

@@ -444,9 +444,6 @@ class SimEngine:
         check_deadlock: bool = True,
         width: int = DEFAULT_SIM_WIDTH,
     ):
-        from ..struct.cache import enable_persistent_cache
-
-        enable_persistent_cache()
         self.model = model
         self.params = (
             {c: (int(lo), int(hi)) for c, (lo, hi) in params.items()}

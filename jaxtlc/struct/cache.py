@@ -1,4 +1,4 @@
-"""Persistent + in-process step-compile cache for struct specs.
+"""In-process step-compile cache for struct specs.
 
 Compiling a struct spec is the expensive part of running one: the
 parse -> shape-infer -> lane-compile pipeline is seconds of Python and
@@ -14,15 +14,10 @@ cost (minutes for Model_1-class modules).  Both are pure functions of
   and jax's jit cache keeps the compiled executable alive because the
   memo returns the SAME engine closures.
 
-* **Persistent XLA compilation cache**: enabled (default
-  ``~/.cache/jaxtlc/xla``, override with ``JAXTLC_COMPILE_CACHE=DIR``,
-  disable with ``JAXTLC_COMPILE_CACHE=off``) whenever a struct engine
-  is built, so a SECOND PROCESS checking the same model skips the XLA
-  compile entirely: the cache key is the optimized HLO, which embeds
-  the compiled lane tables - i.e. it already encodes (module-text hash,
-  constant overrides, chunk, fp geometry).  Clear it by deleting the
-  directory.  `bench.py --struct` measures the effect as
-  ``struct_warm_start_s``.
+Compiled executables persist ACROSS processes through jax's own
+compilation cache, which every process entry point switches on for all
+engines (`jaxtlc.runtime.enable_compile_cache`); nothing here touches
+it.
 """
 
 from __future__ import annotations
@@ -30,12 +25,6 @@ from __future__ import annotations
 import os
 from collections import OrderedDict
 from typing import Tuple
-
-_DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "jaxtlc", "xla"
-)
-
-_persistent_enabled: str = ""
 
 
 class _LRUMemo:
@@ -113,30 +102,6 @@ def set_caps(backend: int = None, engine: int = None) -> None:
             memo.evictions += 1
 
 
-def enable_persistent_cache(path: str = None) -> str:
-    """Point jax's persistent compilation cache at `path` (idempotent).
-
-    Returns the directory in effect, or "" when disabled
-    (JAXTLC_COMPILE_CACHE=off).  Thresholds are zeroed so every engine
-    compile persists - struct steps are exactly the long-compile
-    artifacts the cache exists for."""
-    global _persistent_enabled
-    env = os.environ.get("JAXTLC_COMPILE_CACHE", "")
-    if env.lower() in ("off", "0", "none"):
-        return ""
-    path = path or env or _DEFAULT_CACHE_DIR
-    if _persistent_enabled == path:
-        return path
-    import jax
-
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _persistent_enabled = path
-    return path
-
-
 def model_key(model) -> tuple:
     """The spec-meaning component of every cache key."""
     from .backend import canonical_constants
@@ -196,7 +161,6 @@ def get_backend(model, check_deadlock: bool = True, bounds=None,
     (COL_SYM ring column, prune counters) and different step XLA."""
     from .backend import struct_backend
 
-    enable_persistent_cache()
     key = (model_key(model), bool(check_deadlock), _bounds_key(bounds),
            bool(elide), bool(coverage), bool(symmetry), bool(por))
     hit = _BACKEND_MEMO.get(key)
@@ -276,8 +240,7 @@ def get_engine(
     por: bool = None,
 ) -> Tuple:
     """Memoized single-device engine triple (init_fn, run_fn, step_fn)
-    for a struct model; enables the persistent XLA cache as a side
-    effect so the jit compiles it triggers land on disk.  obs_slots is
+    for a struct model.  obs_slots is
     part of the key: the ring changes the carry pytree, so an obs-on
     engine is a different compile than an obs-off one.  `bounds`
     selects the narrowed engine (certificate check on, keyed on the
@@ -292,7 +255,6 @@ def get_engine(
         resolve_symmetry,
     )
 
-    enable_persistent_cache()
     key = engine_key(
         model, chunk, queue_capacity, fp_capacity, fp_index, seed,
         fp_highwater, check_deadlock=check_deadlock, pipeline=pipeline,
@@ -317,7 +279,7 @@ def get_engine(
 
 
 def clear() -> None:
-    """Drop the in-process memos (tests; the persistent cache is files)."""
+    """Drop the in-process memos (tests)."""
     _BACKEND_MEMO.clear()
     _ENGINE_MEMO.clear()
     _BOUNDS_MEMO.clear()

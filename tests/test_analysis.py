@@ -274,7 +274,7 @@ def test_seeded_purity_violation():
     f = audit_purity("dirty-engine", jax.jit(dirty), jnp.int32(0))
     assert len(f) == 1
     assert (f[0].check, f[0].severity) == ("hot-body-purity", "error")
-    assert "debug_callback" in f[0].detail
+    assert "debug_print" in f[0].detail
 
 
 def test_seeded_donation_reuse_is_error():

@@ -42,11 +42,53 @@ SMALL = ["-chunk", "128", "-qcap", "4096", "-fpcap", "16384"]
 
 
 def test_cli_clean_run_exit0_and_counts(model_dir, capsys):
+    import jax
+
+    from jaxtlc.runtime import DEFAULT_CACHE_DIR
+
     rc = main(["check", str(model_dir / "MC.cfg"), "-noTool"] + SMALL)
     out = capsys.readouterr().out
     assert rc == 0
     assert "17020" in out and "8203" in out  # FF corner final counts
     assert "Model checking completed. No error has been found" in out
+    # the banner names the platform jax resolved, not the -workers text
+    assert "with cpu workers on" in out
+    # the HAND path persists its compiles too (run_check switches the
+    # compile cache on for every engine): where the caller put it, else
+    # the fixed in-checkout directory
+    want = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == want
+    assert any(os.scandir(want)), "no persisted XLA cache entries"
+
+
+def test_cli_sharded_beyond_device_count_exit1(model_dir, capsys):
+    """-sharded N with fewer than N devices is an error, never a
+    smaller mesh (conftest provides 8 virtual devices)."""
+    rc = main(["check", str(model_dir / "MC.cfg"), "-noTool",
+               "-sharded", "16"] + SMALL)
+    cap = capsys.readouterr()
+    assert rc == 1
+    assert "16 devices requested but JAX reports 8" in cap.err
+    assert "states generated" not in cap.out  # no engine ran
+
+
+def test_cli_crashing_analyze_audit_exit1(model_dir, capsys, monkeypatch):
+    """A crash inside the deep audit that -analyze asked for by name
+    fails the run (exit 1, -no-preflight named as the override); it is
+    not reported as "skipped" with exit 0."""
+    import jaxtlc.analysis.preflight as pf
+
+    def boom(*a, **kw):
+        raise RuntimeError("audit blew up")
+
+    monkeypatch.setattr(pf, "preflight_kubeapi", boom)
+    rc = main(["check", str(model_dir / "MC.cfg"), "-noTool",
+               "-analyze"] + SMALL)
+    out = capsys.readouterr().out
+    assert rc == 1
+    assert "crashed: RuntimeError: audit blew up" in out
+    assert "-no-preflight" in out
+    assert "skipped" not in out and "states generated" not in out
 
 
 def test_cli_tool_mode_framing(model_dir, capsys):

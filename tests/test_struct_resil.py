@@ -5,10 +5,9 @@ exactly the recovery code the hand kernel uses - no struct-specific
 paths - and every recovered run is pinned bit-for-bit against the clean
 run (mirroring tests/test_resil.py's hand-kernel cases).  Plus the
 step-compile cache: in-process memoization of the parse -> shape-infer
--> lane-compile pipeline and the persistent XLA compilation cache.
+-> lane-compile pipeline (the persistent XLA cache is a process-entry
+concern: tests/test_runtime.py, tests/test_cli.py).
 """
-
-import os
 
 import pytest
 
@@ -165,15 +164,3 @@ def test_engine_memo_returns_same_engine(model):
     # and a reloaded model with the same digest hits the same memo
     e4 = cache.get_engine(load(CFG), **geometry)
     assert e4 is e1
-
-
-def test_persistent_cache_dir_enabled():
-    path = cache.enable_persistent_cache()
-    if os.environ.get("JAXTLC_COMPILE_CACHE", "").lower() in (
-        "off", "0", "none"
-    ):
-        assert path == ""
-        return
-    # every struct engine build in this session routed compiles here
-    assert os.path.isdir(path)
-    assert any(os.scandir(path)), "no persisted XLA cache entries"
