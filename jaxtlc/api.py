@@ -32,6 +32,8 @@ from .config import ModelConfig
 from .engine.fingerprint import DEFAULT_SEED
 from .frontend.model import RunSpec, resolve
 from .io.tlc_log import TLCLog
+from .obs import spans
+from .obs.spans import span
 
 
 @dataclasses.dataclass
@@ -184,6 +186,7 @@ class CheckOutcome:
     journal_path: str = ""
 
 
+@spans.in_check
 def run_check(req: CheckRequest) -> CheckOutcome:
     """Run one check end to end.  Everything the CLI `check` subcommand
     did - resolution, preflight, dispatch, liveness, traces, journal -
@@ -209,26 +212,27 @@ def _run_check(args) -> int:
         require_platform,
     )
 
-    try:
-        require_platform(args.workers)
-        if args.sharded and args.fpset != "DiskFPSet":
-            fp_mesh(args.sharded)  # fewer devices than asked: an error
-    except PlatformError as e:
-        print(f"Error: {e}", file=_err(args))
-        return 1
-    enable_compile_cache()
-    try:
-        spec: RunSpec = resolve(
-            args.config,
-            workers=args.workers,
-            fp_index=args.fp,
-            check_deadlock=not args.nodeadlock,
-            frontend=args.frontend,
-            const_overrides=getattr(args, "constants", None) or None,
-        )
-    except (ValueError, OSError) as e:
-        print(f"Error: {e}", file=_err(args))
-        return 1
+    with span("check.resolve"):
+        try:
+            require_platform(args.workers)
+            if args.sharded and args.fpset != "DiskFPSet":
+                fp_mesh(args.sharded)  # fewer devices than asked: an error
+        except PlatformError as e:
+            print(f"Error: {e}", file=_err(args))
+            return 1
+        enable_compile_cache()
+        try:
+            spec: RunSpec = resolve(
+                args.config,
+                workers=args.workers,
+                fp_index=args.fp,
+                check_deadlock=not args.nodeadlock,
+                frontend=args.frontend,
+                const_overrides=getattr(args, "constants", None) or None,
+            )
+        except (ValueError, OSError) as e:
+            print(f"Error: {e}", file=_err(args))
+            return 1
     from .frontend.model import GenRunSpec, StructRunSpec
 
     if getattr(args, "simulate", False) and not isinstance(
@@ -334,6 +338,23 @@ def _run_check(args) -> int:
         _finish_journal(args, log, r=None, sup=sup)
         return EXIT_INTERRUPTED
 
+    with span("check.verdict"):
+        violated, liveness_violated = _render_verdict(args, spec, log, r,
+                                                      t0)
+    _finish_journal(
+        args, log, r=r, sup=sup,
+        verdict="liveness_violation" if liveness_violated else None,
+        wall_s=time.time() - t0,
+    )
+    if violated:
+        return 12
+    return 13 if liveness_violated else 0  # TLC liveness exit convention
+
+
+def _render_verdict(args, spec, log, r, t0):
+    """The KubeAPI path after the engine: temporal properties, the
+    violation banner and trace or the success report, final counts.
+    Returns (violated, liveness_violated)."""
     from .engine.bfs import (
         VIOL_ASSERT,
         VIOL_DEADLOCK,
@@ -432,14 +453,7 @@ def _run_check(args) -> int:
     if r.outdegree is not None:
         log.outdegree(*r.outdegree)
     log.finished(int((time.time() - t0) * 1000))
-    _finish_journal(
-        args, log, r=r, sup=sup,
-        verdict="liveness_violation" if liveness_violated else None,
-        wall_s=time.time() - t0,
-    )
-    if violated:
-        return 12
-    return 13 if liveness_violated else 0  # TLC liveness exit convention
+    return violated, liveness_violated
 
 
 def _xprof(args):
@@ -570,6 +584,11 @@ def _preflight_gate(args, log, build_report):
     nonzero exit code on error-severity findings, None to proceed."""
     if not args.preflight:
         return None
+    with span("check.preflight"):
+        return _preflight(args, log, build_report)
+
+
+def _preflight(args, log, build_report):
     from .analysis.report import emit_to_journal
     from .obs.views import render_tlc_event
 
@@ -752,6 +771,14 @@ def _finish_journal(args, log, r=None, sup=None, verdict: str = None,
     j = getattr(args, "_journal", None)
     if j is None:
         return
+    with span("check.journal_close") as s:
+        try:
+            _close_journal(args, log, j, r, sup, verdict, wall_s)
+        finally:
+            s.attrs.update(j.cost())
+
+
+def _close_journal(args, log, j, r, sup, verdict, wall_s) -> None:
     try:
         if r is not None and r.violation != 0:
             j.event("violation", code=int(r.violation),
@@ -762,6 +789,7 @@ def _finish_journal(args, log, r=None, sup=None, verdict: str = None,
         if sup is None and r is not None:
             v = verdict or ("violation" if r.violation != 0 else "ok")
             shards = getattr(r, "shard_distinct", None)
+            j.event("spans", rows=spans.journal_rows())
             j.event("final", verdict=v, generated=r.generated,
                     distinct=r.distinct, depth=r.depth,
                     queue=r.queue_left, wall_s=round(wall_s, 6),
@@ -1915,6 +1943,40 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
         log.msg(1000, f"ERROR: {detail}", severity=1)
         _finish_journal(args, log)
         return 1
+    with span("check.verdict"):
+        violated, liveness_violated = _render_verdict_interp(
+            args, spec, kit, log, r, n_init, t0)
+    if (plan is not None and not violated and not liveness_violated
+            and (sup is None or not (sup.interrupted
+                                     or getattr(sup, "exhausted",
+                                                False)))):
+        # the clean-final-verdict write point: error/violation/
+        # interrupted/exhausted runs never reach this branch, and
+        # record() re-checks violation + certificate itself
+        try:
+            plan.record(
+                r, n_init=n_init,
+                journal=getattr(args, "_journal", None),
+                action_order=(kit.action_order()
+                              if kit.action_order is not None else None),
+            )
+        except OSError as e:  # a full disk must not fail the verdict
+            log.msg(1000, f"Warning: artifact cache write failed: {e}",
+                    severity=1)
+    _finish_journal(
+        args, log, r=r, sup=sup,
+        verdict="liveness_violation" if liveness_violated else None,
+        wall_s=time.time() - t0,
+    )
+    if violated:
+        return 12
+    return 13 if liveness_violated else 0
+
+
+def _render_verdict_interp(args, spec, kit, log, r, n_init, t0):
+    """The interpreted frontends after the engine: temporal properties,
+    the violation trace or the success and coverage report, final
+    counts.  Returns (violated, liveness_violated)."""
     violated = r.violation != 0
     liveness_violated = False
     if not violated and spec.properties:
@@ -2049,31 +2111,7 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
     log.final_counts(r.generated, r.distinct, r.queue_left)
     log.depth(r.depth)
     log.finished(int((time.time() - t0) * 1000))
-    if (plan is not None and not violated and not liveness_violated
-            and (sup is None or not (sup.interrupted
-                                     or getattr(sup, "exhausted",
-                                                False)))):
-        # the clean-final-verdict write point: error/violation/
-        # interrupted/exhausted runs never reach this branch, and
-        # record() re-checks violation + certificate itself
-        try:
-            plan.record(
-                r, n_init=n_init,
-                journal=getattr(args, "_journal", None),
-                action_order=(kit.action_order()
-                              if kit.action_order is not None else None),
-            )
-        except OSError as e:  # a full disk must not fail the verdict
-            log.msg(1000, f"Warning: artifact cache write failed: {e}",
-                    severity=1)
-    _finish_journal(
-        args, log, r=r, sup=sup,
-        verdict="liveness_violation" if liveness_violated else None,
-        wall_s=time.time() - t0,
-    )
-    if violated:
-        return 12
-    return 13 if liveness_violated else 0
+    return violated, liveness_violated
 
 
 def _print_trace(log: TLCLog, model: ModelConfig, chunk: int,

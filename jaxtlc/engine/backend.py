@@ -236,8 +236,9 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
                 for k in range(len(inv_codes))
             ]
 
-        packed = cdc.pack(flat)
-        lo, hi = fp64_words_mxu(packed, nbits, fp_index, seed)
+        with jax.named_scope("jaxtlc.pack_fp"):
+            packed = cdc.pack(flat)
+            lo, hi = fp64_words_mxu(packed, nbits, fp_index, seed)
 
         # runtime certificate: verify the claimed bounds on the RAW
         # (pre-pack) fields of every valid successor - escapes that

@@ -424,16 +424,22 @@ def make_stage_pair(
         the pre-commit carry (queue buffer `parity`, which the commit
         stage never writes), so XLA may schedule it alongside the
         commit of the previous block."""
-        avail = c.level_n - c.qhead
-        n = jnp.clip(avail, 0, ck)
-        rows = jnp.arange(ck, dtype=jnp.int32)
-        mask = rows < n
-        # contiguous pop (the buffer is chunk-padded: no OOB clamping)
-        block = lax.dynamic_slice(
-            c.queue, (c.parity, c.qhead, jnp.int32(0)), (1, ck, W)
-        )[0]
-        batch = cdc.unpack(block)
-        return expand_fn(batch, mask), n
+        # device scopes (jax.named_scope, trace-time metadata only):
+        # the same layer names a profiler trace shows for the host
+        # spans of obs.spans - expand, pack_fp (inside the backend's
+        # expand), dedup and fpset (fpset_insert_dedup), enqueue, level
+        with jax.named_scope("jaxtlc.expand"):
+            avail = c.level_n - c.qhead
+            n = jnp.clip(avail, 0, ck)
+            rows = jnp.arange(ck, dtype=jnp.int32)
+            mask = rows < n
+            # contiguous pop (the buffer is chunk-padded: no OOB
+            # clamping)
+            block = lax.dynamic_slice(
+                c.queue, (c.parity, c.qhead, jnp.int32(0)), (1, ck, W)
+            )[0]
+            batch = cdc.unpack(block)
+            return expand_fn(batch, mask), n
 
     def commit(c: EngineCarry, ex, n, qhead_pop, qhead_out, veto=None):
         """Commit stage for one block's ExpandOut `ex` (`n` popped
@@ -461,205 +467,207 @@ def make_stage_pair(
         n_new = is_new_c.sum().astype(jnp.int32)
         q_full = c.next_n + n_new > qcap
 
-        # enqueue + per-new-state stats: bring new entries to the
-        # front ordered by original lane index (2-key sort) - the
-        # same append order as the v3 scatter engine, so pop order
-        # and therefore in-batch attribution statistics (outdegree
-        # min/max, MC.out:1104) are preserved bit-for-bit.  All new
-        # entries sit in the first nreps compacted positions, so
-        # when nreps fits the probe width the sort runs at R width
-        # instead of ncand (~6x less comparator traffic); the
-        # full-width branch covers all-distinct bursts.
-        new_key = (~is_new_c).astype(jnp.uint32)
-        cidx_u = c_idx.astype(jnp.uint32)
+        with jax.named_scope("jaxtlc.enqueue"):
+            # enqueue + per-new-state stats: bring new entries to the
+            # front ordered by original lane index (2-key sort) - the
+            # same append order as the v3 scatter engine, so pop order
+            # and therefore in-batch attribution statistics (outdegree
+            # min/max, MC.out:1104) are preserved bit-for-bit.  All new
+            # entries sit in the first nreps compacted positions, so
+            # when nreps fits the probe width the sort runs at R width
+            # instead of ncand (~6x less comparator traffic); the
+            # full-width branch covers all-distinct bursts.
+            new_key = (~is_new_c).astype(jnp.uint32)
+            cidx_u = c_idx.astype(jnp.uint32)
 
-        def e_sorted_sliced(_):
-            _, e = lax.sort(
-                (new_key[:R], cidx_u[:R]), num_keys=2, is_stable=True
+            def e_sorted_sliced(_):
+                _, e = lax.sort(
+                    (new_key[:R], cidx_u[:R]), num_keys=2, is_stable=True
+                )
+                return jnp.concatenate(
+                    [e, jnp.zeros(ncand - R, jnp.uint32)]
+                )
+
+            def e_sorted_full(_):
+                _, e = lax.sort(
+                    (new_key, cidx_u), num_keys=2, is_stable=True
+                )
+                return e
+
+            if R == ncand:
+                _, e_idx = lax.sort(
+                    (new_key, cidx_u), num_keys=2, is_stable=True
+                )
+            else:
+                e_idx = lax.cond(
+                    nreps <= R, e_sorted_sliced, e_sorted_full, 0
+                )
+            e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
+
+            def enq_cond(st):
+                _, _, s = st
+                return s * A < n_new
+
+            def enq_body(st):
+                queue, act_dist, s = st
+                offs = s * A
+                idx_a = lax.dynamic_slice(e_idx_p, (offs,), (A,)).astype(
+                    jnp.int32
+                )
+                active = (jnp.arange(A) + offs) < n_new
+                rows_a = ex.packed[idx_a]  # [A, W] row gather (the only one)
+                woff = jnp.minimum(c.next_n + offs, qcap)
+                queue = lax.dynamic_update_slice(
+                    queue, rows_a[None], (1 - c.parity, woff, jnp.int32(0))
+                )
+                # per-action distinct counts by [A, n_labels] compare-
+                # reduce (scatter-adds cost ~140ns/element on-chip)
+                acts_a = ex.action[idx_a]
+                act_dist = act_dist.at[:n_labels].add(
+                    (
+                        (acts_a[:, None] == label_ids[None, :])
+                        & active[:, None]
+                    ).sum(axis=0).astype(jnp.uint32)
+                )
+                return queue, act_dist, s + 1
+
+            queue, act_dist, _ = lax.while_loop(
+                enq_cond, enq_body, (c.queue, c.act_dist, jnp.int32(0))
             )
-            return jnp.concatenate(
-                [e, jnp.zeros(ncand - R, jnp.uint32)]
+
+        with jax.named_scope("jaxtlc.level"):
+            # outdegree histogram of the popped states (TLC's outdegree =
+            # distinct new successors per expansion, MC.out:1104) via run
+            # lengths: e_idx's active prefix is ascending in source row,
+            # so each row's new-child count is a run length - no
+            # [chunk+1]-bin scatter-add
+            pos = jnp.arange(ncand)
+            active_new = pos < n_new
+            src_e = jnp.where(active_new, e_idx.astype(jnp.int32) // L, -1)
+            startf = jnp.concatenate(
+                [jnp.ones(1, bool), src_e[1:] != src_e[:-1]]
+            ) & active_new
+            endf = jnp.concatenate(
+                [src_e[1:] != src_e[:-1], jnp.ones(1, bool)]
+            ) & active_new
+            run0 = lax.cummax(jnp.where(startf, pos, 0))
+            run_len = jnp.where(endf, pos - run0 + 1, 0)
+            nruns = startf.sum()
+            deg_hist = (
+                (run_len[:, None] == jnp.arange(1, L + 1)[None, :])
+                .sum(axis=0)
+                .astype(jnp.uint32)
+            )
+            outdeg_hist = c.outdeg_hist.at[1 : L + 1].add(deg_hist)
+            outdeg_hist = outdeg_hist.at[0].add(
+                (n - nruns).astype(jnp.uint32)
             )
 
-        def e_sorted_full(_):
-            _, e = lax.sort(
-                (new_key, cidx_u), num_keys=2, is_stable=True
-            )
-            return e
+            act_gen = c.act_gen.at[:n_labels].add(ex.gen)
+            generated = c.generated + ex.valid.sum().astype(jnp.uint32)
+            distinct = c.distinct + n_new.astype(jnp.uint32)
 
-        if R == ncand:
-            _, e_idx = lax.sort(
-                (new_key, cidx_u), num_keys=2, is_stable=True
-            )
-        else:
-            e_idx = lax.cond(
-                nreps <= R, e_sorted_sliced, e_sorted_full, 0
-            )
-        e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
+            # violations, first wins: carried > deferred invariant (when
+            # evaluation is deferred, checked on the fresh claimants just
+            # inserted - outranking the kernel-derived codes exactly as
+            # the immediate reduce orders invariant > assert) >
+            # expand-stage (invariant > assert > deadlock > slot,
+            # pre-reduced in ex) > capacity
+            viol = c.viol
+            viol_state = c.viol_state
+            viol_action = c.viol_action
+            d_cert = None
+            if checker is not None:
+                d_viol, d_state, d_action, d_cert = checker(
+                    ex.flat, ex.action, is_new_c, c_idx, nreps
+                )
+                hit = (d_viol != OK) & (viol == OK)
+                viol = jnp.where(hit, d_viol, viol)
+                viol_state = jnp.where(hit, d_state, viol_state)
+                viol_action = jnp.where(hit, d_action, viol_action)
+            hit = (ex.viol != OK) & (viol == OK)
+            viol = jnp.where(hit, ex.viol, viol)
+            viol_state = jnp.where(hit, ex.viol_state, viol_state)
+            viol_action = jnp.where(hit, ex.viol_action, viol_action)
+            if not spill:
+                hit = fp_full & ex.valid.any() & (viol == OK)
+                viol = jnp.where(hit, VIOL_FPSET_FULL, viol)
+            hit = q_full & (viol == OK)
+            viol = jnp.where(hit, VIOL_QUEUE_FULL, viol)
 
-        def enq_cond(st):
-            _, _, s = st
-            return s * A < n_new
+            # level bookkeeping: ping-pong at the level boundary
+            next_n = jnp.minimum(c.next_n + n_new, qcap)
+            level_done = qhead_pop >= c.level_n
+            advance = level_done & (next_n > 0)
+            parity = jnp.where(level_done, 1 - c.parity, c.parity)
+            level_n = jnp.where(level_done, next_n, c.level_n)
+            next_n = jnp.where(level_done, 0, next_n)
+            qhead = jnp.where(level_done, 0, qhead_out)
+            level = jnp.where(advance, c.level + 1, c.level)
+            depth = jnp.maximum(c.depth, level)
 
-        def enq_body(st):
-            queue, act_dist, s = st
-            offs = s * A
-            idx_a = lax.dynamic_slice(e_idx_p, (offs,), (A,)).astype(
-                jnp.int32
-            )
-            active = (jnp.arange(A) + offs) < n_new
-            rows_a = ex.packed[idx_a]  # [A, W] row gather (the only one)
-            woff = jnp.minimum(c.next_n + offs, qcap)
-            queue = lax.dynamic_update_slice(
-                queue, rows_a[None], (1 - c.parity, woff, jnp.int32(0))
-            )
-            # per-action distinct counts by [A, n_labels] compare-
-            # reduce (scatter-adds cost ~140ns/element on-chip)
-            acts_a = ex.action[idx_a]
-            act_dist = act_dist.at[:n_labels].add(
-                (
-                    (acts_a[:, None] == label_ids[None, :])
-                    & active[:, None]
-                ).sum(axis=0).astype(jnp.uint32)
-            )
-            return queue, act_dist, s + 1
-
-        queue, act_dist, _ = lax.while_loop(
-            enq_cond, enq_body, (c.queue, c.act_dist, jnp.int32(0))
-        )
-
-        # outdegree histogram of the popped states (TLC's outdegree =
-        # distinct new successors per expansion, MC.out:1104) via run
-        # lengths: e_idx's active prefix is ascending in source row,
-        # so each row's new-child count is a run length - no
-        # [chunk+1]-bin scatter-add
-        pos = jnp.arange(ncand)
-        active_new = pos < n_new
-        src_e = jnp.where(active_new, e_idx.astype(jnp.int32) // L, -1)
-        startf = jnp.concatenate(
-            [jnp.ones(1, bool), src_e[1:] != src_e[:-1]]
-        ) & active_new
-        endf = jnp.concatenate(
-            [src_e[1:] != src_e[:-1], jnp.ones(1, bool)]
-        ) & active_new
-        run0 = lax.cummax(jnp.where(startf, pos, 0))
-        run_len = jnp.where(endf, pos - run0 + 1, 0)
-        nruns = startf.sum()
-        deg_hist = (
-            (run_len[:, None] == jnp.arange(1, L + 1)[None, :])
-            .sum(axis=0)
-            .astype(jnp.uint32)
-        )
-        outdeg_hist = c.outdeg_hist.at[1 : L + 1].add(deg_hist)
-        outdeg_hist = outdeg_hist.at[0].add(
-            (n - nruns).astype(jnp.uint32)
-        )
-
-        act_gen = c.act_gen.at[:n_labels].add(ex.gen)
-        generated = c.generated + ex.valid.sum().astype(jnp.uint32)
-        distinct = c.distinct + n_new.astype(jnp.uint32)
-
-        # violations, first wins: carried > deferred invariant (when
-        # evaluation is deferred, checked on the fresh claimants just
-        # inserted - outranking the kernel-derived codes exactly as
-        # the immediate reduce orders invariant > assert) >
-        # expand-stage (invariant > assert > deadlock > slot,
-        # pre-reduced in ex) > capacity
-        viol = c.viol
-        viol_state = c.viol_state
-        viol_action = c.viol_action
-        d_cert = None
-        if checker is not None:
-            d_viol, d_state, d_action, d_cert = checker(
-                ex.flat, ex.action, is_new_c, c_idx, nreps
-            )
-            hit = (d_viol != OK) & (viol == OK)
-            viol = jnp.where(hit, d_viol, viol)
-            viol_state = jnp.where(hit, d_state, viol_state)
-            viol_action = jnp.where(hit, d_action, viol_action)
-        hit = (ex.viol != OK) & (viol == OK)
-        viol = jnp.where(hit, ex.viol, viol)
-        viol_state = jnp.where(hit, ex.viol_state, viol_state)
-        viol_action = jnp.where(hit, ex.viol_action, viol_action)
-        if not spill:
-            hit = fp_full & ex.valid.any() & (viol == OK)
-            viol = jnp.where(hit, VIOL_FPSET_FULL, viol)
-        hit = q_full & (viol == OK)
-        viol = jnp.where(hit, VIOL_QUEUE_FULL, viol)
-
-        # level bookkeeping: ping-pong at the level boundary
-        next_n = jnp.minimum(c.next_n + n_new, qcap)
-        level_done = qhead_pop >= c.level_n
-        advance = level_done & (next_n > 0)
-        parity = jnp.where(level_done, 1 - c.parity, c.parity)
-        level_n = jnp.where(level_done, next_n, c.level_n)
-        next_n = jnp.where(level_done, 0, next_n)
-        qhead = jnp.where(level_done, 0, qhead_out)
-        level = jnp.where(advance, c.level + 1, c.level)
-        depth = jnp.maximum(c.depth, level)
-
-        extra = {}
-        if spill:
-            extra["spill_hits"] = c.spill_hits + (
-                veto & ex.valid
-            ).sum().astype(jnp.uint32)
-        cert_now = None
-        cert_src = d_cert if deferred else ex.cert
-        if cert_src is not None and c.cert_viol is not None:
-            # sticky: once any block's certificate check fired, every
-            # later carry (and ring row) carries the flag (deferred
-            # mode latches it from the commit-site checker instead of
-            # the staged expand bit - same column, same stickiness)
-            cert_now = c.cert_viol | cert_src
-            extra["cert_viol"] = cert_now
-        sym_now = None
-        if ex.sym is not None and c.sym_viol is not None:
-            # orbit certification (ISSUE 18): same sticky latch as the
-            # certificate bit - computed at expand on the canonical
-            # fields, so the deferred mode needs no commit-site variant
-            sym_now = c.sym_viol | ex.sym
-            extra["sym_viol"] = sym_now
-        if ex.pruned is not None and c.por_pruned is not None:
-            extra["por_pruned"] = c.por_pruned + ex.pruned
-        if ex.cov is not None and c.cov_counts is not None:
-            # device coverage plane: fold this block's per-site visit
-            # increments into the cumulative counters (telemetry only)
-            extra["cov_counts"] = c.cov_counts + ex.cov
-        obs = {}
-        if obs_slots:
-            # one telemetry row per completed level (post-commit
-            # cumulative counters; the dump row absorbs non-flip
-            # bodies so the store is unconditional).  The sticky
-            # COL_OVERFLOW flag marks any uint32 wrap so saturated
-            # counters are detected, never silently wrong
-            obs_bodies = c.obs_bodies + jnp.uint32(1)
-            obs_expanded = c.obs_expanded + n.astype(jnp.uint32)
-            wrap_pairs = [
-                (generated, c.generated), (distinct, c.distinct),
-                (act_gen, c.act_gen), (act_dist, c.act_dist),
-                (obs_bodies, c.obs_bodies),
-                (obs_expanded, c.obs_expanded),
-            ]
+            extra = {}
             if spill:
-                wrap_pairs.append((extra["spill_hits"], c.spill_hits))
-            if "cov_counts" in extra:
-                wrap_pairs.append((extra["cov_counts"], c.cov_counts))
-            wrapped = wrapped_any(wrap_pairs)
-            row = pack_row(
-                c.level, generated, distinct, level_n, obs_bodies,
-                obs_expanded, act_gen[:n_labels],
-                act_dist[:n_labels],
-                overflow=sticky_overflow(c.obs_ring, wrapped),
-                spill=extra.get("spill_hits"),
-                cert=cert_now,
-                sym=sym_now,
-            )
-            ring, head = ring_update(
-                c.obs_ring, c.obs_head, row, level_done
-            )
-            obs = dict(obs_ring=ring, obs_head=head,
-                       obs_bodies=obs_bodies,
-                       obs_expanded=obs_expanded)
+                extra["spill_hits"] = c.spill_hits + (
+                    veto & ex.valid
+                ).sum().astype(jnp.uint32)
+            cert_now = None
+            cert_src = d_cert if deferred else ex.cert
+            if cert_src is not None and c.cert_viol is not None:
+                # sticky: once any block's certificate check fired, every
+                # later carry (and ring row) carries the flag (deferred
+                # mode latches it from the commit-site checker instead of
+                # the staged expand bit - same column, same stickiness)
+                cert_now = c.cert_viol | cert_src
+                extra["cert_viol"] = cert_now
+            sym_now = None
+            if ex.sym is not None and c.sym_viol is not None:
+                # orbit certification (ISSUE 18): same sticky latch as the
+                # certificate bit - computed at expand on the canonical
+                # fields, so the deferred mode needs no commit-site variant
+                sym_now = c.sym_viol | ex.sym
+                extra["sym_viol"] = sym_now
+            if ex.pruned is not None and c.por_pruned is not None:
+                extra["por_pruned"] = c.por_pruned + ex.pruned
+            if ex.cov is not None and c.cov_counts is not None:
+                # device coverage plane: fold this block's per-site visit
+                # increments into the cumulative counters (telemetry only)
+                extra["cov_counts"] = c.cov_counts + ex.cov
+            obs = {}
+            if obs_slots:
+                # one telemetry row per completed level (post-commit
+                # cumulative counters; the dump row absorbs non-flip
+                # bodies so the store is unconditional).  The sticky
+                # COL_OVERFLOW flag marks any uint32 wrap so saturated
+                # counters are detected, never silently wrong
+                obs_bodies = c.obs_bodies + jnp.uint32(1)
+                obs_expanded = c.obs_expanded + n.astype(jnp.uint32)
+                wrap_pairs = [
+                    (generated, c.generated), (distinct, c.distinct),
+                    (act_gen, c.act_gen), (act_dist, c.act_dist),
+                    (obs_bodies, c.obs_bodies),
+                    (obs_expanded, c.obs_expanded),
+                ]
+                if spill:
+                    wrap_pairs.append((extra["spill_hits"], c.spill_hits))
+                if "cov_counts" in extra:
+                    wrap_pairs.append((extra["cov_counts"], c.cov_counts))
+                wrapped = wrapped_any(wrap_pairs)
+                row = pack_row(
+                    c.level, generated, distinct, level_n, obs_bodies,
+                    obs_expanded, act_gen[:n_labels],
+                    act_dist[:n_labels],
+                    overflow=sticky_overflow(c.obs_ring, wrapped),
+                    spill=extra.get("spill_hits"),
+                    cert=cert_now,
+                    sym=sym_now,
+                )
+                ring, head = ring_update(
+                    c.obs_ring, c.obs_head, row, level_done
+                )
+                obs = dict(obs_ring=ring, obs_head=head,
+                           obs_bodies=obs_bodies,
+                           obs_expanded=obs_expanded)
 
         return c._replace(
             fps=fps,

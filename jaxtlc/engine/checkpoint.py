@@ -32,6 +32,7 @@ import numpy as np
 from jax import lax
 
 from ..config import ModelConfig
+from ..obs.spans import in_check, span
 from .bfs import (
     DEFAULT_FP_HIGHWATER,
     CheckResult,
@@ -239,6 +240,7 @@ def load_latest_generation(base: str, template):
     raise FileNotFoundError(f"no checkpoint generations under {base!r}")
 
 
+@in_check
 def check_with_checkpoints(
     cfg: ModelConfig,
     chunk: int = 1024,
@@ -274,17 +276,11 @@ def check_with_checkpoints(
     jax.block_until_ready only at the next boundary - checkpoint/coverage
     readback stays off the device critical path (PERF.md round 7).
     """
+    from ..runtime import aot_build
     from .bfs import resolve_deferred, resolve_sort_free
 
     sort_free = resolve_sort_free(sort_free, chunk)
     deferred = resolve_deferred(deferred, chunk)
-    # donate=False: segment k's output is serialized to disk while
-    # segment k+1 (fed the same arrays) is in flight
-    init_fn, _, step_fn = make_engine(
-        cfg, chunk, queue_capacity, fp_capacity, fp_index, seed,
-        fp_highwater=fp_highwater, pipeline=pipeline, donate=False,
-        obs_slots=obs_slots, sort_free=sort_free, deferred=deferred,
-    )
     meta = _meta(
         cfg,
         chunk=chunk,
@@ -299,12 +295,23 @@ def check_with_checkpoints(
         deferred=deferred,
     )
 
-    @jax.jit
-    def segment(c: EngineCarry) -> EngineCarry:
-        return lax.fori_loop(0, ckpt_every, lambda _, cc: step_fn(cc), c)
+    def make():
+        # donate=False: segment k's output is serialized to disk while
+        # segment k+1 (fed the same arrays) is in flight
+        init_fn, _, step_fn = make_engine(
+            cfg, chunk, queue_capacity, fp_capacity, fp_index, seed,
+            fp_highwater=fp_highwater, pipeline=pipeline, donate=False,
+            obs_slots=obs_slots, sort_free=sort_free, deferred=deferred,
+        )
 
-    template = init_fn()
-    compiled_segment = segment.lower(template).compile()
+        @jax.jit
+        def segment(c: EngineCarry) -> EngineCarry:
+            return lax.fori_loop(0, ckpt_every,
+                                 lambda _, cc: step_fn(cc), c)
+
+        return init_fn, segment
+
+    template, compiled_segment = aot_build(make)
     t0 = time.time()
     if resume:
         if ckpt_path is None or not os.path.exists(ckpt_path):
@@ -331,36 +338,48 @@ def check_with_checkpoints(
     else:
         carry = template
 
+    def owed(pending):
+        """Snapshot and progress of the boundary `pending` came from."""
+        if ckpt_path is not None:
+            save_checkpoint(ckpt_path, pending, meta)
+        if on_progress is not None and not carry_done(pending):
+            st = pending.st_n if pending.st_n is not None else 0
+            d, g, di, ln, qh, nn, sn = jax.device_get(
+                (pending.depth, pending.generated, pending.distinct,
+                 pending.level_n, pending.qhead, pending.next_n, st)
+            )
+            on_progress(int(d), int(g), int(di),
+                        int(ln) - int(qh) + int(nn) + int(sn))
+
     segments = 0
     pending = None  # carry whose snapshot/progress is owed
-    while not carry_done(carry):
-        if max_segments is not None and segments >= max_segments:
-            break
-        in_flight = compiled_segment(carry)  # async dispatch
-        # host work for the PREVIOUS boundary overlaps the running
-        # segment (reading `carry` concurrently is safe: donate=False)
-        if pending is not None:
-            if ckpt_path is not None:
-                save_checkpoint(ckpt_path, pending, meta)
-            if on_progress is not None and not carry_done(pending):
-                st = pending.st_n if pending.st_n is not None else 0
-                d, g, di, ln, qh, nn, sn = jax.device_get(
-                    (pending.depth, pending.generated, pending.distinct,
-                     pending.level_n, pending.qhead, pending.next_n, st)
-                )
-                on_progress(int(d), int(g), int(di),
-                            int(ln) - int(qh) + int(nn) + int(sn))
-        carry = jax.block_until_ready(in_flight)
-        segments += 1
-        pending = carry
-    # the last boundary has no next segment to hide behind
-    if pending is not None and ckpt_path is not None:
-        save_checkpoint(ckpt_path, pending, meta)
+    with span("loop"):
+        done = carry_done(carry)
+        while not done:
+            if max_segments is not None and segments >= max_segments:
+                break
+            with span("loop.dispatch"):
+                in_flight = compiled_segment(carry)  # async dispatch
+            # host work for the PREVIOUS boundary overlaps the running
+            # segment (reading `carry` concurrently is safe: donate=False)
+            with span("loop.overlap"):
+                if pending is not None:
+                    owed(pending)
+            with span("loop.wait"):
+                carry = jax.block_until_ready(in_flight)
+            segments += 1
+            pending = carry
+            with span("loop.readback"):
+                done = carry_done(carry)
+        # the last boundary has no next segment to hide behind
+        if pending is not None and ckpt_path is not None:
+            save_checkpoint(ckpt_path, pending, meta)
 
     wall = time.time() - t0
     from .fpset import fpset_actual_collision
 
-    afc = float(fpset_actual_collision(carry.fps))
-    return result_from_carry(carry, wall, iterations=segments)._replace(
-        actual_fp_collision=afc
-    )
+    with span("check.result"):
+        afc = float(fpset_actual_collision(carry.fps))
+        return result_from_carry(carry, wall, iterations=segments)._replace(
+            actual_fp_collision=afc
+        )

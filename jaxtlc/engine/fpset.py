@@ -368,6 +368,15 @@ def _dense_walk_default() -> bool:
 
 def _probe_block(table, lo, hi, active, claim_width: int,
                  dense_walk: bool = None):
+    """`_probe_claim` under the device scope `jaxtlc.fpset` (the probe
+    / claim of the engine's commit; bfs.make_stage_pair has the list)."""
+    with jax.named_scope("jaxtlc.fpset"):
+        return _probe_claim(table, lo, hi, active, claim_width,
+                            dense_walk)
+
+
+def _probe_claim(table, lo, hi, active, claim_width: int,
+                 dense_walk: bool = None):
     """Insert-or-find `active` entries of a fingerprint block that is
     sorted ascending by (hi, lo) and duplicate-free.  Returns
     (table, is_new).  table: [nb, 2B]; lo/hi/active: [R].
@@ -855,15 +864,19 @@ def fpset_insert_dedup(
     -sort-free mode here, so every stage composition - fused,
     pipelined, spill, phased - and the sharded owner-side insert share
     one dispatch point).  Contract identical either way."""
-    if not sort_free:
-        return fpset_insert_sorted(
+    # device scope of the in-batch dedup (sorts or slab); the probe /
+    # claim inside it is `jaxtlc.fpset` (_probe_block), so a trace
+    # attributes an op to the innermost of the two
+    with jax.named_scope("jaxtlc.dedup"):
+        if not sort_free:
+            return fpset_insert_sorted(
+                s, lo, hi, mask, probe_width=probe_width,
+                claim_width=claim_width,
+            )
+        return fpset_insert_slab(
             s, lo, hi, mask, probe_width=probe_width,
             claim_width=claim_width,
         )
-    return fpset_insert_slab(
-        s, lo, hi, mask, probe_width=probe_width,
-        claim_width=claim_width,
-    )
 
 
 def fpset_insert(s: FPSet, lo, hi, mask, sort_free: bool = False,

@@ -54,6 +54,10 @@ class RunJournal:
         self.events: List[dict] = []
         self.fsync_every = max(1, int(fsync_every))
         self._unsynced = 0
+        # what the journal costs itself (cost()); whoever opens the
+        # span around close() puts them on it
+        self.fsyncs = 0
+        self.seconds = 0.0  # inside event() and sync()
         self._f = None
         if path:
             mode = "a" if resume and os.path.exists(path) else "w"
@@ -61,8 +65,8 @@ class RunJournal:
 
     def event(self, kind: str, **fields) -> dict:
         """Validate + append one event; returns the stamped event dict."""
-        ev = {"v": SCHEMA_VERSION, "t": time.time(), "event": kind,
-              **fields}
+        t = time.time()
+        ev = {"v": SCHEMA_VERSION, "t": t, "event": kind, **fields}
         validate_event(ev)
         self.events.append(ev)
         if self._f is not None:
@@ -70,23 +74,35 @@ class RunJournal:
             self._f.flush()
             self._unsynced += 1
             if self._unsynced >= self.fsync_every:
-                os.fsync(self._f.fileno())
-                self._unsynced = 0
+                self._fsync()
+        self.seconds += time.time() - t
         return ev
+
+    def _fsync(self) -> None:
+        os.fsync(self._f.fileno())
+        self._unsynced = 0
+        self.fsyncs += 1
 
     def sync(self) -> None:
         """Force the durability barrier now (batched mode's checkpoint
         hook; a no-op when nothing is pending or the journal is
         in-memory)."""
         if self._f is not None and self._unsynced:
-            os.fsync(self._f.fileno())
-            self._unsynced = 0
+            t = time.time()
+            self._fsync()
+            self.seconds += time.time() - t
 
     def close(self) -> None:
         if self._f is not None:
             self.sync()
             self._f.close()
             self._f = None
+
+    def cost(self) -> dict:
+        """What this journal has cost so far: events written, fsyncs,
+        and seconds inside event() and sync()."""
+        return {"events": len(self.events), "fsyncs": self.fsyncs,
+                "seconds": round(self.seconds, 6)}
 
     def __enter__(self) -> "RunJournal":
         return self

@@ -109,6 +109,14 @@ EVENTS = {
     # "chunk" from the spill runtime's host-driven loop
     "phase": {"scope": _STR, "index": _NUM, "phase": _STR,
               "wall_s": _NUM},
+    # -- host spans (obs.spans) --------------------------------------------
+    # ONE per check, right before its `final` event: the recorder's
+    # spans of this check that have closed by then, as rows
+    # [name, t0 (epoch s), dur_s, parent_index] (parent_index -1 = the
+    # parent is still open - `check`, `sched.run` - or is not a row).
+    # views.phase_totals folds them into the per-phase totals by name;
+    # the trace exporter renders them as host slices
+    "spans": {"rows": (list,)},
     # -- preflight analysis (jaxtlc.analysis) ------------------------------
     # one event per finding, severity in ("error", "warning", "info")
     "analysis": {"layer": _STR, "check": _STR, "severity": _STR,
@@ -232,6 +240,17 @@ def validate_event(ev: dict) -> dict:
                 f"{kind!r} field {field!r} has type {type(v).__name__}, "
                 f"want one of {[t.__name__ for t in types]}: {ev!r}"
             )
+    if kind == "spans":
+        for row in ev["rows"]:
+            if not (isinstance(row, (list, tuple)) and len(row) == 4
+                    and isinstance(row[0], str)
+                    and all(isinstance(x, _NUM)
+                            and not isinstance(x, bool)
+                            for x in row[1:])):
+                raise JournalSchemaError(
+                    f"'spans' row is not [name, t0, dur_s, "
+                    f"parent_index]: {row!r}"
+                )
     if kind == "final" and ev["verdict"] not in VERDICTS:
         raise JournalSchemaError(
             f"final verdict {ev['verdict']!r} not in {VERDICTS}"

@@ -3,21 +3,22 @@
 Renders the journal's host-observed intervals as a `chrome://tracing` /
 https://ui.perfetto.dev JSON file (`-trace-out run.trace.json`):
 
-* pid "device": one slice per supervised segment (dispatch -> fence),
-  subdivided into per-level expand/commit sub-slices on two threads.
+* pid "device": one slice per supervised segment (dispatch -> fence).
   When the journal carries MEASURED per-level `phase` events (a
-  `-phase-timing` run, obs.phases), the sub-slices use those walls -
-  the lanes are measurement, not illustration.  Without them the
-  per-level spans fall back to the SCHEMATIC body-count-proportional
-  placement inside the segment's host-observed wall; the overlap
-  structure is still real either way: in pipeline mode the commit lane
-  of level k overlaps the expand lane of level k+1 (the staged-block
-  schedule), in fused mode they abut.  Ground-truth device timelines
-  come from `-xprof DIR` (jax.profiler).
-* pid "host": checkpoint-write and regrow-migration slices, plus
-  instant markers for retries, faults, interruption, recovery and the
-  final verdict - so "why was this segment slow" is one glance (the
-  TensorFlow timeline discipline, arXiv:1605.08695 §5).
+  `-phase-timing` run, obs.phases), each level's expand and commit
+  walls are drawn as sub-slices on two threads, in sequence, from the
+  measured walls.  Without them the segment slice is all the journal
+  knows about the device: nothing is drawn inside it (the per-level
+  counters still feed the counter tracks).  Ground-truth device
+  timelines come from `-xprof DIR` (jax.profiler).
+* pid "host": the check's host spans (the `spans` event, obs.spans:
+  `build` with its trace / lower / compile children, `loop` with its
+  per-segment dispatch / overlap / wait / readback) as nested slices on
+  one thread, at the times the recorder measured; checkpoint-write and
+  regrow-migration slices, plus instant markers for retries, faults,
+  interruption, recovery and the final verdict - so "why was this
+  segment slow" is one glance (the TensorFlow timeline discipline,
+  arXiv:1605.08695 §5).
 * counter tracks: distinct states, queue depth and fingerprint-table
   load per level, which Perfetto renders as rate/occupancy graphs.
 
@@ -53,6 +54,7 @@ TID_EXPAND = 2
 TID_COMMIT = 3
 TID_CKPT = 1
 TID_REGROW = 2
+TID_SPANS = 3
 
 
 def _meta(pid: int, name: str) -> dict:
@@ -70,14 +72,12 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
     to the first journal event)."""
     if not events:
         return []
-    t0 = events[0]["t"]
+    # origin: the first journal event, or the earliest host span where
+    # one began before it (check.resolve precedes run_start)
+    t0 = min([events[0]["t"]] + [row[1] for ev in events
+                                 if ev["event"] == "spans"
+                                 for row in ev["rows"]])
     us = lambda t: (t - t0) * 1e6  # noqa: E731
-
-    pipeline = False
-    for ev in events:
-        if ev["event"] == "run_start":
-            pipeline = bool(ev.get("params", {}).get("pipeline"))
-            break
 
     out = []
     known: set = set()
@@ -102,11 +102,12 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
             _meta(pid_host(h), f"host (checkpoint/regrow){tag}"),
             _thread(pid_device(h), TID_SEGMENT, "segments"),
             _thread(pid_device(h), TID_EXPAND,
-                    "expand (per level, schematic)"),
+                    "expand (per level, -phase-timing)"),
             _thread(pid_device(h), TID_COMMIT,
-                    "commit (per level, schematic)"),
+                    "commit (per level, -phase-timing)"),
             _thread(pid_host(h), TID_CKPT, "checkpoint writes"),
             _thread(pid_host(h), TID_REGROW, "regrow migrations"),
+            _thread(pid_host(h), TID_SPANS, "host spans (obs.spans)"),
         ])
 
     ensure(None)
@@ -127,29 +128,26 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
     prev_level: dict = {}  # host key -> last level event
 
     def flush_levels(h):
-        """Subdivide host `h`'s last segment wall among its buffered
-        levels, emitting expand/commit sub-slices whose overlap mirrors
-        the engine's step schedule.  MEASURED placement when the
-        segment's `phase` events cover every buffered level (a
-        -phase-timing run: sequential expand->commit slices of the
-        measured walls); body-count-proportional schematic otherwise."""
+        """Emit host `h`'s buffered levels against its last segment:
+        the counter tracks always, and - when the segment's `phase`
+        events cover every buffered level (a -phase-timing run) - the
+        measured expand -> commit sub-slices.  Unmeasured levels get no
+        slice: the journal does not know where in the segment they
+        ran."""
         seg = last_segment.get(h)
         levels = pending_levels.pop(h, [])
         phases = pending_phases.pop(h, {})
         if seg is None or not levels:
             return
-        # shadow the module pids with this host's row pair: the slice
-        # emission below then lands on the right process row unchanged
-        PID_DEVICE = pid_device(h)
-        seg_ts = us(seg["t_dispatch"])
-        seg_dur = max(seg["wall_s"] * 1e6, 1.0)
+        pid = pid_device(h)
         measured = all(
             {"expand", "commit"} <= set(phases.get(lv["level"], {}))
             for lv in levels
         )
-        if measured:
-            cursor = seg_ts
-            for lv in levels:
+        cursor = us(seg["t_dispatch"])
+        end = cursor + max(seg["wall_s"] * 1e6, 1.0)
+        for lv in levels:
+            if measured:
                 ph = phases[lv["level"]]
                 args = {k: lv[k] for k in
                         ("level", "generated", "distinct", "queue",
@@ -159,61 +157,23 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                     dur = max(ph[phase] * 1e6, 1.0)
                     out.append({
                         "name": f"{phase} L{lv['level']}", "ph": "X",
-                        "ts": cursor, "dur": dur, "pid": PID_DEVICE,
+                        "ts": cursor, "dur": dur, "pid": pid,
                         "tid": TID_EXPAND if phase == "expand"
                         else TID_COMMIT,
                         "args": {**args, "wall_s": ph[phase]},
                     })
                     cursor += dur
-                out.append({"name": "states", "ph": "C",
-                            "ts": cursor, "pid": PID_DEVICE, "tid": 0,
-                            "args": {"distinct": lv["distinct"],
-                                     "queue": lv["queue"]}})
-                if "fp_load" in lv:
-                    out.append({"name": "fp_load", "ph": "C",
-                                "ts": cursor, "pid": PID_DEVICE,
-                                "tid": 0,
-                                "args": {"load": lv["fp_load"]}})
-            return
-        bodies = [max(lv.get("bodies_level", 1), 1) for lv in levels]
-        total = float(sum(bodies))
-        cursor = seg_ts
-        for lv, b in zip(levels, bodies):
-            dur = seg_dur * (b / total)
-            half = dur / 2.0
-            args = {k: lv[k] for k in
-                    ("level", "generated", "distinct", "queue",
-                     "bodies", "expanded") if k in lv}
-            if pipeline:
-                # staged schedule: commit of level k rides alongside the
-                # NEXT level's expansion - draw commit shifted half a
-                # span so the overlap is visible in the two lanes
-                out.append({"name": f"expand L{lv['level']}", "ph": "X",
-                            "ts": cursor, "dur": dur, "pid": PID_DEVICE,
-                            "tid": TID_EXPAND, "args": args})
-                out.append({"name": f"commit L{lv['level']}", "ph": "X",
-                            "ts": cursor + half, "dur": dur,
-                            "pid": PID_DEVICE, "tid": TID_COMMIT,
-                            "args": args})
-            else:
-                out.append({"name": f"expand L{lv['level']}", "ph": "X",
-                            "ts": cursor, "dur": half,
-                            "pid": PID_DEVICE, "tid": TID_EXPAND,
-                            "args": args})
-                out.append({"name": f"commit L{lv['level']}", "ph": "X",
-                            "ts": cursor + half, "dur": half,
-                            "pid": PID_DEVICE, "tid": TID_COMMIT,
-                            "args": args})
-            out.append({"name": "states", "ph": "C",
-                        "ts": cursor + dur, "pid": PID_DEVICE, "tid": 0,
+            # counters: at the measured end of the level, else at the
+            # fence the row was read back at
+            at = cursor if measured else end
+            out.append({"name": "states", "ph": "C", "ts": at,
+                        "pid": pid, "tid": 0,
                         "args": {"distinct": lv["distinct"],
                                  "queue": lv["queue"]}})
             if "fp_load" in lv:
-                out.append({"name": "fp_load", "ph": "C",
-                            "ts": cursor + dur, "pid": PID_DEVICE,
-                            "tid": 0,
+                out.append({"name": "fp_load", "ph": "C", "ts": at,
+                            "pid": pid, "tid": 0,
                             "args": {"load": lv["fp_load"]}})
-            cursor += dur
 
     for ev in events:
         kind = ev["event"]
@@ -237,14 +197,8 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                 # empty-queue trailing flips re-record the final
                 # level's (identical, cumulative) row each no-op step
                 continue
-            lv = dict(ev)
-            # per-level body count from the cumulative counter
-            lv["bodies_level"] = (
-                ev["bodies"] - prev["bodies"]
-                if prev is not None else ev["bodies"]
-            )
             prev_level[h] = ev
-            pending_levels.setdefault(h, []).append(lv)
+            pending_levels.setdefault(h, []).append(ev)
         elif kind == "phase":
             if ev["scope"] == "level":
                 pending_phases.setdefault(h, {}).setdefault(
@@ -258,6 +212,16 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                     "dur": max(ev["wall_s"] * 1e6, 1.0),
                     "pid": pid_host(h), "tid": TID_CKPT,
                     "args": {"segment": ev["index"]},
+                })
+        elif kind == "spans":
+            # the recorder's host spans, at the times it measured
+            # (nested complete events on one thread: Perfetto stacks a
+            # child under its parent by containment)
+            for name, t_start, dur_s, _parent in ev["rows"]:
+                out.append({
+                    "name": name, "ph": "X", "ts": us(t_start),
+                    "dur": max(dur_s * 1e6, 1.0), "pid": PID_HOST,
+                    "tid": TID_SPANS, "args": {"wall_s": dur_s},
                 })
         elif kind == "checkpoint":
             ensure(h)
@@ -367,7 +331,7 @@ def _tiny_journal(path: str) -> None:
                 lvl = 2 * s + i + 1
                 # second segment: measured per-level walls (the
                 # -phase-timing tier) so the exporter's measured-lane
-                # path is exercised alongside the schematic one
+                # path is exercised alongside the bare-segment one
                 if s == 1:
                     j.event("phase", scope="level", index=lvl,
                             phase="expand", wall_s=0.03, bodies=2)
@@ -380,6 +344,12 @@ def _tiny_journal(path: str) -> None:
                     distinct=120 * (s + 1), queue=30)
         j.event("checkpoint", path="ck.g000001.npz", seconds=0.004,
                 label="periodic")
+        j.event("spans", rows=[
+            ["build.compile", base - 0.4, 0.3, 1],
+            ["build", base - 0.5, 0.45, -1],
+            ["loop.wait", base + 0.1, 0.09, 3],
+            ["loop", base, 0.2, -1],
+        ])
         j.event("regrow", resource="fp_capacity", old=1 << 11,
                 new=1 << 12, violation="fpset full", seconds=0.01)
         j.event("degrade", rung="regrow", resource="fp_capacity",
@@ -428,6 +398,9 @@ def main(argv=None) -> int:
             names = {e.get("name", "") for e in doc["traceEvents"]}
             assert any(s.startswith("expand L") for s in names)
             assert any(s.startswith("commit L") for s in names)
+            assert {"build", "build.compile", "loop.wait"} <= names
+            assert min(e["ts"] for e in doc["traceEvents"]
+                       if "ts" in e) >= 0
         print(f"trace-export tiny OK: {n} trace events "
               f"({len(events)} journal events)")
         return 0

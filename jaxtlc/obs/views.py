@@ -165,12 +165,18 @@ def phase_totals(events) -> dict:
     """Cumulative measured wall seconds per phase name from the `phase`
     events (obs.phases) of a journal: {phase: seconds}.  Level- and
     segment-scope rows both accumulate (they attribute different walls:
-    expand/commit device halves vs device/readback fence intervals)."""
+    expand/commit device halves vs device/readback fence intervals).
+    The check's host spans (the `spans` event, obs.spans) fold into the
+    same totals under their own names (`build`, `loop.wait`, ...), so
+    /metrics and tlcstat show them with no exporter of their own."""
     out = {}
     for ev in events:
         if ev.get("event") == "phase":
             key = ev["phase"]
             out[key] = out.get(key, 0.0) + float(ev["wall_s"])
+        elif ev.get("event") == "spans":
+            for name, _t0, dur_s, _parent in ev["rows"]:
+                out[name] = out.get(name, 0.0) + float(dur_s)
     return out
 
 

@@ -83,6 +83,9 @@ from ..engine.bfs import (
 )
 from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
 from ..engine.spill import SpillWriteError
+from ..obs import spans
+from ..obs.spans import span
+from ..runtime import aot_build
 from .faults import FaultInjector, FaultPlan, TransientFault
 from .regrow import (
     GROWABLE,
@@ -324,39 +327,41 @@ class SingleDeviceAdapter:
         # donate=False: the supervisor feeds the SAME last-good carry
         # back into the segment on retry/regrow and checkpoints it while
         # the next segment is in flight - donation would invalidate it
-        if self.backend is not None:
-            from ..engine.bfs import make_backend_engine
+        def make():
+            if self.backend is not None:
+                from ..engine.bfs import make_backend_engine
 
-            init_fn, _, step_fn = make_backend_engine(
-                self.backend, self.chunk, params["queue_capacity"],
-                params["fp_capacity"], self.fp_index, self.seed,
-                fp_highwater=self.fp_highwater,
-                check_deadlock=self.check_deadlock,
-                pipeline=self.pipeline, donate=False,
-                obs_slots=self.obs_slots, sort_free=self.sort_free,
-                deferred=self.deferred,
-            )
-        else:
-            init_fn, _, step_fn = make_engine(
-                self.cfg, self.chunk, params["queue_capacity"],
-                params["fp_capacity"], self.fp_index, self.seed,
-                fp_highwater=self.fp_highwater,
-                pipeline=self.pipeline, donate=False,
-                obs_slots=self.obs_slots, sort_free=self.sort_free,
-                deferred=self.deferred,
-            )
+                init_fn, _, step_fn = make_backend_engine(
+                    self.backend, self.chunk, params["queue_capacity"],
+                    params["fp_capacity"], self.fp_index, self.seed,
+                    fp_highwater=self.fp_highwater,
+                    check_deadlock=self.check_deadlock,
+                    pipeline=self.pipeline, donate=False,
+                    obs_slots=self.obs_slots, sort_free=self.sort_free,
+                    deferred=self.deferred,
+                )
+            else:
+                init_fn, _, step_fn = make_engine(
+                    self.cfg, self.chunk, params["queue_capacity"],
+                    params["fp_capacity"], self.fp_index, self.seed,
+                    fp_highwater=self.fp_highwater,
+                    pipeline=self.pipeline, donate=False,
+                    obs_slots=self.obs_slots, sort_free=self.sort_free,
+                    deferred=self.deferred,
+                )
 
-        @jax.jit
-        def segment(c):
-            return lax.fori_loop(0, ckpt_every, lambda _, cc: step_fn(cc), c)
+            @jax.jit
+            def segment(c):
+                return lax.fori_loop(0, ckpt_every,
+                                     lambda _, cc: step_fn(cc), c)
 
-        template = init_fn()
-        compiled = segment.lower(template).compile()
+            return init_fn, segment
+
         # async contract: seg_fn DISPATCHES and returns in-flight arrays;
         # the supervision loop overlaps host work (checkpoint write,
         # stats readback of the previous carry) with the running segment
         # and fences with jax.block_until_ready
-        return template, compiled
+        return aot_build(make)
 
     def meta(self, params: dict) -> dict:
         return ckpt._meta(
@@ -540,18 +545,15 @@ class ShardedAdapter:
     def build(self, params: dict, ckpt_every: int):
         from ..engine.sharded import make_sharded_engine
 
-        init_fn, seg_fn = make_sharded_engine(
+        # async contract: dispatch only; the supervision loop fences
+        return aot_build(lambda: make_sharded_engine(
             self.cfg, self.mesh, self.chunk,
             params["queue_capacity"], params["fp_capacity"],
             route_factor=params["route_factor"], segment=ckpt_every,
             backend=self.backend, fp_highwater=self.fp_highwater,
             pipeline=self.pipeline, obs_slots=self.obs_slots,
             sort_free=self.sort_free, deferred=self.deferred,
-        )
-        template = init_fn()
-        compiled = seg_fn.lower(template).compile()
-        # async contract: dispatch only; the supervision loop fences
-        return template, compiled
+        ))
 
     def meta(self, params: dict) -> dict:
         return ckpt._meta(
@@ -772,6 +774,7 @@ def _can_shrink(adapter, floor: int) -> bool:
     return bool(f(floor)) if callable(f) else False
 
 
+@spans.in_check
 def supervise(adapter, params: dict,
               opts: SupervisorOptions = None) -> SupervisedResult:
     """Run an exhaustive check under supervision.  `params` holds the
@@ -821,7 +824,9 @@ def supervise(adapter, params: dict,
 
     def build_engine(p):
         if phase_rec is not None:
-            return adapter.build_phased(p, opts.ckpt_every, phase_rec)
+            with span("build", phased=True):
+                return adapter.build_phased(p, opts.ckpt_every,
+                                            phase_rec)
         return adapter.build(p, opts.ckpt_every)
 
     def rebuild(p):
@@ -956,7 +961,7 @@ def supervise(adapter, params: dict,
             spill_rt.store.restore(good_store)
 
     drained = (lambda: opts.drain is not None and opts.drain.is_set())
-    with _SignalCatcher() as sig:
+    with span("loop"), _SignalCatcher() as sig:
         while not adapter.done(carry):
             if sig.hit is not None or drained():
                 interrupted = True
@@ -974,11 +979,14 @@ def supervise(adapter, params: dict,
                         # the failed attempt must not double-count
                         phase_rec.reset()
                     t_dispatch = time.time()
-                    in_flight = seg_fn(good)
+                    with span("loop.dispatch"):
+                        in_flight = seg_fn(good)
                     # host work overlapping the running segment: the
                     # previous segment's checkpoint write + progress line
-                    flush_save()
-                    carry2 = jax.block_until_ready(in_flight)
+                    with span("loop.overlap"):
+                        flush_save()
+                    with span("loop.wait"):
+                        carry2 = jax.block_until_ready(in_flight)
                     t_fence = time.time()
                     break
                 except SpillWriteError as e:
@@ -1172,42 +1180,42 @@ def supervise(adapter, params: dict,
                   wall_s=round(t_fence - t_dispatch, 6))
             if opts.ckpt_path:
                 pending_save = (good, good_store)
-            t_readback = time.time()
-            if adapter.viol(carry) == OK and not adapter.done(carry):
-                d, g, di, q = adapter.progress(carry)
-                _emit(opts, "progress", depth=d, generated=g,
-                      distinct=di, queue=q)
-            if obs_read is not None:
-                # decode the counter ring's new per-level rows (the
-                # same fence the progress readback already paid for)
-                rows, obs_seen = obs_read(carry, obs_seen, params)
-                for row in rows:
-                    _emit(opts, "level", **row)
-                if rows:
-                    cov_level = max(cov_level, rows[-1]["level"])
-            if cov_sites is not None:
-                # device coverage readback at the fence already paid:
-                # per-site DELTAS journal as one `coverage` event, and
-                # a run that stops visiting NEW sites for N levels
-                # journals the saturation signal once
-                from ..obs.coverage import coverage_delta_event
+            with span("loop.readback") as readback:
+                if adapter.viol(carry) == OK and not adapter.done(carry):
+                    d, g, di, q = adapter.progress(carry)
+                    _emit(opts, "progress", depth=d, generated=g,
+                          distinct=di, queue=q)
+                if obs_read is not None:
+                    # decode the counter ring's new per-level rows (the
+                    # same fence the progress readback already paid for)
+                    rows, obs_seen = obs_read(carry, obs_seen, params)
+                    for row in rows:
+                        _emit(opts, "level", **row)
+                    if rows:
+                        cov_level = max(cov_level, rows[-1]["level"])
+                if cov_sites is not None:
+                    # device coverage readback at the fence already paid:
+                    # per-site DELTAS journal as one `coverage` event, and
+                    # a run that stops visiting NEW sites for N levels
+                    # journals the saturation signal once
+                    from ..obs.coverage import coverage_delta_event
 
-                totals = adapter.cov_totals(carry)
-                payload = coverage_delta_event(cov_sites, totals,
-                                               cov_seen)
-                if payload is not None:
-                    _emit(opts, "coverage", **payload)
-                    cov_seen = totals
-                    if payload["visited"] > cov_visited:
-                        cov_visited = payload["visited"]
-                        cov_last_new_level = cov_level
-                if (not cov_saturated and cov_visited
-                        and cov_level - cov_last_new_level
-                        >= opts.coverage_sat_levels):
-                    cov_saturated = True
-                    _emit(opts, "coverage", visited=cov_visited,
-                          sites=len(cov_sites), delta={},
-                          saturated=True, level=cov_level)
+                    totals = adapter.cov_totals(carry)
+                    payload = coverage_delta_event(cov_sites, totals,
+                                                   cov_seen)
+                    if payload is not None:
+                        _emit(opts, "coverage", **payload)
+                        cov_seen = totals
+                        if payload["visited"] > cov_visited:
+                            cov_visited = payload["visited"]
+                            cov_last_new_level = cov_level
+                    if (not cov_saturated and cov_visited
+                            and cov_level - cov_last_new_level
+                            >= opts.coverage_sat_levels):
+                        cov_saturated = True
+                        _emit(opts, "coverage", visited=cov_visited,
+                              sites=len(cov_sites), delta={},
+                              saturated=True, level=cov_level)
             # phase attribution (obs.phases): the free fence-scope rows
             # (device wall + the host readback wall just measured) plus
             # the measured per-level expand/commit walls in -phase-
@@ -1216,7 +1224,7 @@ def supervise(adapter, params: dict,
 
             for row in segment_phases(
                 segments - 1, t_fence - t_dispatch,
-                readback_s=time.time() - t_readback,
+                readback_s=readback.seconds,
             ):
                 _emit(opts, "phase", **row)
             if phase_rec is not None:
@@ -1254,21 +1262,25 @@ def supervise(adapter, params: dict,
             flush_save()
 
     wall = time.time() - t0
-    result = adapter.result(carry, wall, segments, params)
-    # every supervised run ends with exactly one structured final event:
-    # verdict + counters + wall, whatever the exit path
-    verdict = ("exhausted" if exhausted
-               else "interrupted" if interrupted
-               else "violation" if result.violation != OK else "ok")
-    if (opts.capture_fps and verdict == "ok" and spill_rt is None
-            and getattr(adapter, "CAPTURES_FPS", False)
-            and getattr(carry, "fps", None) is not None):
-        # the artifact cache's reachable-set source: one host copy of
-        # the final table, only on a clean non-spilled single-device
-        # verdict (a spilled run's device table is partial)
-        result = result._replace(
-            fp_table=np.asarray(jax.device_get(carry.fps.table))
-        )
+    with span("check.result"):
+        result = adapter.result(carry, wall, segments, params)
+        # every supervised run ends with exactly one structured final
+        # event: verdict + counters + wall, whatever the exit path
+        verdict = ("exhausted" if exhausted
+                   else "interrupted" if interrupted
+                   else "violation" if result.violation != OK else "ok")
+        if (opts.capture_fps and verdict == "ok" and spill_rt is None
+                and getattr(adapter, "CAPTURES_FPS", False)
+                and getattr(carry, "fps", None) is not None):
+            # the artifact cache's reachable-set source: one host copy
+            # of the final table, only on a clean non-spilled
+            # single-device verdict (a spilled run's table is partial)
+            result = result._replace(
+                fp_table=np.asarray(jax.device_get(carry.fps.table))
+            )
+    # the host spans of this check that have closed by now (build, loop
+    # and their children; `check` itself is still open), once
+    _emit(opts, "spans", rows=spans.journal_rows())
     _emit(opts, "final", verdict=verdict, generated=result.generated,
           distinct=result.distinct, depth=result.depth,
           queue=result.queue_left, wall_s=round(wall, 6),

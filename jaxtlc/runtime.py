@@ -2,6 +2,11 @@
 may use, how many devices a mesh may claim, and where compiled programs
 persist.
 
+`aot_build` is the one place an engine's program is built ahead of time
+(the supervisor's adapters, `check_with_checkpoints`, the serve pool),
+under the `build` spans of obs.spans; `CompileMeter` counts what XLA did
+meanwhile.
+
 Each entry point (`api.run_check`, `jaxtlc.serve` start-up, the
 `jaxtlc.dist` worker, `bench.py`, `chip_smoke.py`) calls
 `enable_compile_cache()` once and resolves its platform through
@@ -15,6 +20,10 @@ checkout) put it - not under $HOME.
 from __future__ import annotations
 
 import os
+import threading
+from typing import Optional
+
+from .obs.spans import span
 
 # the in-checkout default, derived from the package location: the cache
 # key includes the directory, so a path that moves never hits
@@ -97,3 +106,91 @@ def fp_mesh(n_devices: int = 0):
         )
     return Mesh(np.array(devices[:n_devices] if n_devices else devices),
                 ("fp",))
+
+
+class CompileMeter:
+    """Process-wide XLA backend-compile counter (jax.monitoring).
+
+    Counts `/jax/core/compile/backend_compile_duration` events - fired
+    once per real XLA compile (AOT .compile() included, persistent-
+    cache hits included: deserialization still passes through the
+    event), never by a warm executable call.  Monotonic; assert on
+    deltas.
+    `cache_hits` counts the persistent-cache hits among them
+    (`/jax/compilation_cache/cache_hits`): `count - cache_hits` is the
+    number of programs the backend actually compiled, which is what a
+    second process over a warm cache must see at zero.  `retrieval_s`
+    sums `/jax/compilation_cache/cache_retrieval_time_sec`: what those
+    hits spent fetching and deserializing their executables."""
+
+    _instance: Optional["CompileMeter"] = None
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.count = 0
+        self.wall_s = 0.0
+        self.cache_hits = 0
+        self.retrieval_s = 0.0
+        self._lock = threading.Lock()
+
+        def on_duration(name, duration, **kw):
+            if name.endswith("backend_compile_duration"):
+                with self._lock:
+                    self.count += 1
+                    self.wall_s += float(duration)
+            elif name.endswith("cache_retrieval_time_sec"):
+                with self._lock:
+                    self.retrieval_s += float(duration)
+
+        def on_event(name, **kw):
+            if name == "/jax/compilation_cache/cache_hits":
+                with self._lock:
+                    self.cache_hits += 1
+
+        # registration failing raises: with no listener "warm submit =
+        # 0 compiles" would be vacuous, so there is no degraded meter
+        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_listener(on_event)
+        self.available = True
+
+    @classmethod
+    def instance(cls) -> "CompileMeter":
+        if cls._instance is None:
+            cls._instance = CompileMeter()
+        return cls._instance
+
+    def read(self) -> tuple:
+        with self._lock:
+            return (self.count, self.cache_hits, self.wall_s,
+                    self.retrieval_s)
+
+
+def aot_build(make):
+    """Build one engine program ahead of time: `make()` gives (init_fn,
+    jitted program of one carry); returns (template carry, compiled
+    executable).  The `build` span and its five children say where a
+    build's time goes - the engine factory's Python, `init_fn()`, the
+    trace to a jaxpr, the lowering to MLIR, and `.compile()`, which on a
+    warm process is the persistent cache's fetch plus the load of the
+    executable onto the device (`requests`, `cache_hits`, `backend_s`,
+    `retrieval_s` on `build.compile` are CompileMeter's deltas)."""
+    meter = CompileMeter.instance()
+    with span("build"):
+        with span("build.engine"):
+            init_fn, program = make()
+        with span("build.init"):
+            template = init_fn()
+        with span("build.trace"):
+            traced = program.trace(template)
+        with span("build.lower"):
+            lowered = traced.lower()
+        before = meter.read()
+        with span("build.compile") as s:
+            compiled = lowered.compile()
+            n, hits, backend_s, retrieval_s = (
+                b - a for a, b in zip(before, meter.read()))
+            s.attrs.update(requests=n, cache_hits=hits,
+                           backend_s=round(backend_s, 6),
+                           retrieval_s=round(retrieval_s, 6))
+    return template, compiled

@@ -29,53 +29,8 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
-
-
-class CompileMeter:
-    """Process-wide XLA backend-compile counter (jax.monitoring).
-
-    Counts `/jax/core/compile/backend_compile_duration` events - fired
-    once per real XLA compile (AOT .compile() included, persistent-
-    cache hits included: deserialization still passes through the
-    event), never by a warm executable call.  Monotonic; assert on
-    deltas.
-    `cache_hits` counts the persistent-cache hits among them
-    (`/jax/compilation_cache/cache_hits`): `count - cache_hits` is the
-    number of programs the backend actually compiled, which is what a
-    second process over a warm cache must see at zero."""
-
-    _instance: Optional["CompileMeter"] = None
-
-    def __init__(self):
-        from jax import monitoring
-
-        self.count = 0
-        self.wall_s = 0.0
-        self.cache_hits = 0
-        self._lock = threading.Lock()
-
-        def on_duration(name, duration, **kw):
-            if name.endswith("backend_compile_duration"):
-                with self._lock:
-                    self.count += 1
-                    self.wall_s += float(duration)
-
-        def on_event(name, **kw):
-            if name == "/jax/compilation_cache/cache_hits":
-                with self._lock:
-                    self.cache_hits += 1
-
-        # registration failing raises: with no listener "warm submit =
-        # 0 compiles" would be vacuous, so there is no degraded meter
-        monitoring.register_event_duration_secs_listener(on_duration)
-        monitoring.register_event_listener(on_event)
-        self.available = True
-
-    @classmethod
-    def instance(cls) -> "CompileMeter":
-        if cls._instance is None:
-            cls._instance = CompileMeter()
-        return cls._instance
+from ..obs.spans import span
+from ..runtime import CompileMeter, aot_build  # noqa: F401 - re-export
 
 
 def xla_compiles() -> int:
@@ -104,19 +59,24 @@ class _SingleRunner:
     def __init__(self, model, chunk, queue_capacity, fp_capacity,
                  fp_index, seed, check_deadlock, pipeline, obs_slots,
                  sort_free=None, deferred=None):
+        import jax
+
         from ..engine.bfs import DEFAULT_FP_HIGHWATER
         from ..struct.cache import get_backend, get_engine
 
         self.model = model
         self.fp_capacity = fp_capacity
         self.backend = get_backend(model, check_deadlock)
-        init_fn, run_fn, _ = get_engine(
-            model, chunk, queue_capacity, fp_capacity, fp_index, seed,
-            DEFAULT_FP_HIGHWATER, check_deadlock=check_deadlock,
-            pipeline=pipeline, obs_slots=obs_slots, sort_free=sort_free,
-            deferred=deferred,
-        )
-        import jax
+
+        def make():
+            init_fn, run_fn, _ = get_engine(
+                model, chunk, queue_capacity, fp_capacity, fp_index,
+                seed, DEFAULT_FP_HIGHWATER, check_deadlock=check_deadlock,
+                pipeline=pipeline, obs_slots=obs_slots,
+                sort_free=sort_free, deferred=deferred,
+            )
+            self._mk_carry = jax.jit(lambda: init_fn())
+            return self._mk_carry, run_fn
 
         # the engine memo shares jit closures; the POOL owns the AOT
         # executables so a warm submit never re-lowers or re-traces
@@ -124,9 +84,7 @@ class _SingleRunner:
         # init_fn re-compiles its fpset while_loop per call - both
         # would make every submit of a memo-hit engine pay fresh XLA
         # compiles; the zero-compile warm contract pins this)
-        self._mk_carry = jax.jit(lambda: init_fn())
-        carry0 = self._mk_carry()
-        self._aot = run_fn.lower(carry0).compile()
+        _, self._aot = aot_build(make)
 
     def run(self, capture_fps: bool = False):
         import jax
@@ -134,23 +92,24 @@ class _SingleRunner:
         from ..engine.bfs import result_from_carry
         from ..struct.backend import struct_viol_names
 
-        carry = self._mk_carry()
-        t0 = time.time()
-        out = jax.block_until_ready(self._aot(carry))
-        wall = time.time() - t0
-        result = result_from_carry(
-            out, wall, fp_capacity=self.fp_capacity,
-            labels=self.backend.labels,
-            viol_names=struct_viol_names(self.model),
-        )
-        if capture_fps and result.violation == 0:
-            # the artifact cache's reachable-set source (ISSUE 13):
-            # one host copy of the final table, clean verdicts only
-            import numpy as np
-
-            result = result._replace(
-                fp_table=np.asarray(jax.device_get(out.fps.table))
+        with span("pool.carry"):
+            carry = self._mk_carry()
+        with span("pool.run") as ran:
+            out = jax.block_until_ready(self._aot(carry))
+        with span("pool.readback"):
+            result = result_from_carry(
+                out, ran.seconds, fp_capacity=self.fp_capacity,
+                labels=self.backend.labels,
+                viol_names=struct_viol_names(self.model),
             )
+            if capture_fps and result.violation == 0:
+                # the artifact cache's reachable-set source (ISSUE 13):
+                # one host copy of the final table, clean verdicts only
+                import numpy as np
+
+                result = result._replace(
+                    fp_table=np.asarray(jax.device_get(out.fps.table))
+                )
         return result
 
 

@@ -76,6 +76,8 @@ import uuid
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional
 
+from ..obs import spans
+from ..obs.spans import span
 from ..resil.faults import FaultInjector, FaultPlan, TransientFault
 from .pool import EnginePool
 
@@ -712,7 +714,9 @@ class Scheduler:
             try:
                 if self._injector is not None:
                     self._injector.dispatch(n)
-                self._run_batch(batch)
+                # one span identifier per dispatch: the head job's id
+                with spans.job(batch[0].id), span("sched.run"):
+                    self._run_batch(batch)
             except Exception as e:  # a broken job must not kill the loop
                 self._dispatch_failed(batch, e)
 
@@ -1190,72 +1194,85 @@ class Scheduler:
         from ..struct.loader import StructLoadError, load
         from ..struct.parser import StructParseError
 
-        cfg_path = self._jobdir(job)
+        with span("sched.jobdir"):
+            cfg_path = self._jobdir(job)
         try:
-            model = load(
-                cfg_path,
-                const_overrides=_loader_constants(job.constants) or None,
-            )
+            with span("sched.load"):
+                model = load(
+                    cfg_path,
+                    const_overrides=(_loader_constants(job.constants)
+                                     or None),
+                )
         except (StructLoadError, StructParseError, JobError):
             self._run_supervised(job)
             return
-        geo = self._geometry(job)
-        store = arts.get_store()
-        use_cache = (store is not None
-                     and not job.options.get("recheck")
-                     and not job.options.get("noartifactcache"))
-        vkey = ""
-        if use_cache:
-            # the pooled path checks safety only, so its verdict key
-            # carries an empty property selection (api keys runs WITH
-            # properties differently - the two can never cross-answer)
-            vkey = arts.verdict_key(model, geo["check_deadlock"])
-            payload = store.lookup_verdict(vkey)
-            if payload is not None:
-                self._finish_cached(job, geo, vkey, payload)
-                return
-            if store.has_reach(
-                    arts.reach_key(model, geo["check_deadlock"])):
-                # invariant-only edit: api.run_check's reach tier
-                # skips BFS entirely - cheaper than a pool dispatch.
-                # Forced onto the struct frontend: the stored artifact
-                # was keyed by this very struct load, and "auto" could
-                # route a gen-subset spec away from the cache
-                self._run_supervised(job, frontend="struct")
-                return
+        with span("sched.cache_lookup"):
+            geo = self._geometry(job)
+            store = arts.get_store()
+            use_cache = (store is not None
+                         and not job.options.get("recheck")
+                         and not job.options.get("noartifactcache"))
+            vkey = ""
+            payload = reach = None
+            if use_cache:
+                # the pooled path checks safety only, so its verdict key
+                # carries an empty property selection (api keys runs
+                # WITH properties differently - the two can never
+                # cross-answer)
+                vkey = arts.verdict_key(model, geo["check_deadlock"])
+                payload = store.lookup_verdict(vkey)
+                reach = payload is None and store.has_reach(
+                    arts.reach_key(model, geo["check_deadlock"]))
+        if payload is not None:
+            self._finish_cached(job, geo, vkey, payload)
+            return
+        if reach:
+            # invariant-only edit: api.run_check's reach tier skips BFS
+            # entirely - cheaper than a pool dispatch.  Forced onto the
+            # struct frontend: the stored artifact was keyed by this
+            # very struct load, and "auto" could route a gen-subset
+            # spec away from the cache
+            self._run_supervised(job, frontend="struct")
+            return
         pre = self.pool.hits
-        entry = self.pool.get_single(model, **geo)
+        with span("pool.get"):
+            entry = self.pool.get_single(model, **geo)
         hit = self.pool.hits > pre
-        jr = self._journal(job)
-        jr.event("run_start", version=_version(), workload=job.name,
-                 engine="pool", device=str(jax.devices()[0]),
-                 params=dict(**geo, constants=job.constants,
-                             pool_hit=hit))
-        try:
-            r = entry.runner.run(capture_fps=use_cache)
-        except BaseException:
-            self._abort_journals([jr])
-            raise
-        if r.violation != 0:
-            jr.event("violation", code=int(r.violation),
-                     name=r.violation_name)
-        if use_cache and r.violation == 0:
+        with span("sched.journal") as journal_span:
+            jr = self._journal(job)
+            jr.event("run_start", version=_version(), workload=job.name,
+                     engine="pool", device=str(jax.devices()[0]),
+                     params=dict(**geo, constants=job.constants,
+                                 pool_hit=hit))
             try:
-                arts.ArtifactPlan(
-                    store, model,
-                    check_deadlock=geo["check_deadlock"],
-                    fp_capacity=geo["fp_capacity"],
-                ).record(r, n_init=len(model.system.initial_states()),
-                         journal=jr)
-            except OSError:
-                pass  # a full disk must not fail the job
-        jr.event("final",
-                 verdict="ok" if r.violation == 0 else "violation",
-                 generated=r.generated, distinct=r.distinct,
-                 depth=r.depth, queue=r.queue_left,
-                 wall_s=round(r.wall_s, 6), interrupted=False)
-        jr.close()
-        self._finish_ok(job, _result_dict(r, "pool", pool_hit=hit))
+                r = entry.runner.run(capture_fps=use_cache)
+            except BaseException:
+                self._abort_journals([jr])
+                raise
+            if r.violation != 0:
+                jr.event("violation", code=int(r.violation),
+                         name=r.violation_name)
+            if use_cache and r.violation == 0:
+                try:
+                    arts.ArtifactPlan(
+                        store, model,
+                        check_deadlock=geo["check_deadlock"],
+                        fp_capacity=geo["fp_capacity"],
+                    ).record(r,
+                             n_init=len(model.system.initial_states()),
+                             journal=jr)
+                except OSError:
+                    pass  # a full disk must not fail the job
+            jr.event("spans", rows=spans.journal_rows())
+            jr.event("final",
+                     verdict="ok" if r.violation == 0 else "violation",
+                     generated=r.generated, distinct=r.distinct,
+                     depth=r.depth, queue=r.queue_left,
+                     wall_s=round(r.wall_s, 6), interrupted=False)
+            jr.close()
+            journal_span.attrs.update(jr.cost())
+        with span("sched.finish"):
+            self._finish_ok(job, _result_dict(r, "pool", pool_hit=hit))
 
     def _finish_cached(self, job: Job, geo: dict, key: str,
                        payload: dict) -> None:

@@ -7,7 +7,7 @@
   full signature (counts, per-action, outdegree, fpset table words)
   equals the obs-off engine's exactly;
 - SIGTERM'd -checkpoint run + -recover -> ONE continuous journal (the
-  resumed run APPENDS), trace export renders expand/commit lanes;
+  resumed run APPENDS), trace export renders both attempts' host spans;
 - "progress lost" (SIGTERM with no checkpoint path) still ends the
   journal with a structured final event (verdict, counters, wall);
 - the 2200 Progress line's interval rates are pinned byte-for-byte.
@@ -34,6 +34,9 @@ from jaxtlc.resil import FaultPlan, SupervisorOptions, check_supervised
 FF = ModelConfig(False, False)
 EXPECT_FF = (17020, 8203, 109)
 KW = dict(chunk=128, queue_capacity=1 << 12, fp_capacity=1 << 14)
+
+
+RECORDED = {}  # filled by obs_run
 
 
 def signature(r):
@@ -66,11 +69,14 @@ def obs_run(tmp_path_factory):
     extra engine compiles."""
     from jaxtlc.obs.serve import start_server
 
+    from jaxtlc.obs import spans
+
     d = tmp_path_factory.mktemp("obs")
     path = str(d / "run.journal.jsonl")
     server = start_server(str(d))
     live = {}
     seen = [0]
+    t_start = _time.time()
 
     def hook(j, kind, info):
         j.event(kind, **info)
@@ -95,6 +101,10 @@ def obs_run(tmp_path_factory):
             )
     finally:
         server.shutdown()
+    # the recorder's rows of this run and what the journal counted of
+    # itself, for test_supervised_check_leaves_every_span
+    RECORDED.update(rows=spans.snapshot(since=t_start), fsyncs=j.fsyncs,
+                    events=len(j.events), seconds=j.seconds)
     return sr, path, live
 
 
@@ -122,6 +132,57 @@ def test_journal_schema_golden(obs_run):
     assert fin["verdict"] == "ok" and not fin["interrupted"]
     assert (fin["generated"], fin["distinct"], fin["depth"]) == EXPECT_FF
     assert fin["wall_s"] > 0
+
+
+def test_supervised_check_leaves_every_span(obs_run):
+    """ISSUE 24: the supervised check's host spans - every name of the
+    vocabulary once (four a segment in the loop), one identifier,
+    children inside parents, top-level children covering the check -
+    and their ONE durable copy in the journal: a `spans` event right
+    before `final`, equal to the recorder's rows closed by then, for
+    exactly one fsync."""
+    from test_spans import BUILD, SEGMENT, assert_tree
+
+    sr, path, _ = obs_run
+    rows = [r for r in RECORDED["rows"] if r.job is not None]
+    root = assert_tree(rows, "check")
+    names = [r.name for r in rows]
+    for n in ("build", "loop", "check.result", *BUILD):
+        assert names.count(n) == 1, n
+    for n in SEGMENT:
+        assert names.count(n) == sr.segments, n
+    assert len(rows) <= 13 + 4 * sr.segments  # the budget
+    events = jr.read(path)  # validates the new kind too
+    kinds = [e["event"] for e in events]
+    assert kinds.count("spans") == 1
+    assert kinds[-2:] == ["spans", "final"]
+    ev = events[-2]
+    closed = [r for r in rows if r.t1 <= ev["t"]]
+    assert [r.name for r in closed] == [row[0] for row in ev["rows"]]
+    assert {r.name for r in rows} - {r.name for r in closed} == {"check"}
+    index = {r.id: i for i, r in enumerate(closed)}
+    for r, (name, t0, dur_s, parent) in zip(closed, ev["rows"]):
+        assert t0 == r.t0 and dur_s == pytest.approx(r.t1 - r.t0,
+                                                     abs=1e-6)
+        assert parent == index.get(r.parent, -1)
+        assert (parent == -1) == (r.parent == root.id)
+    # per-event fsync: as many barriers as events, so the spans event
+    # cost exactly one - and the journal says what it cost itself
+    assert RECORDED["fsyncs"] == RECORDED["events"] == len(events)
+    assert 0 < RECORDED["seconds"] < root.t1 - root.t0
+    # the same names reach /metrics and tlcstat through phase_totals
+    from jaxtlc.obs.views import metrics_from_events, phase_totals
+
+    totals = phase_totals(events)
+    by_name = {r.name: r for r in rows}
+    assert totals["build"] == pytest.approx(
+        by_name["build"].t1 - by_name["build"].t0, abs=1e-5)
+    assert totals["loop.wait"] == pytest.approx(
+        sum(r.t1 - r.t0 for r in rows if r.name == "loop.wait"),
+        abs=1e-4)
+    assert {"device", "readback"} <= set(totals)  # phase rows still fold
+    assert "build.compile" in metrics_from_events(events)[
+        "phase_wall_seconds"]
 
 
 def test_serve_endpoints_answer_during_live_run(obs_run):
@@ -220,7 +281,8 @@ def test_obs_ring_survives_regrow(clean_ff):
 
 def test_trace_export_from_golden_journal(obs_run, tmp_path):
     """The journal renders to a Perfetto-loadable Chrome trace with the
-    expand/commit lanes and counter tracks present."""
+    segment slices, the check's host spans as real slices and the
+    counter tracks - and no schematic per-level lane."""
     _, path, _ = obs_run
     out = str(tmp_path / "run.trace.json")
     n = export_chrome_trace(jr.read(path), out)
@@ -228,8 +290,22 @@ def test_trace_export_from_golden_journal(obs_run, tmp_path):
     assert len(doc["traceEvents"]) == n > 0
     names = [e.get("name", "") for e in doc["traceEvents"]]
     assert any(s.startswith("segment") for s in names)
-    assert any(s.startswith("expand L") for s in names)
-    assert any(s.startswith("commit L") for s in names)
+    # no schematic lane: an unmeasured run draws nothing inside its
+    # segments; the host slices are the recorder's spans, as measured
+    assert not any(s.startswith(("expand L", "commit L")) for s in names)
+    assert not any("schematic" in json.dumps(e)
+                   for e in doc["traceEvents"])
+    spans_ev = [e for e in jr.read(path) if e["event"] == "spans"]
+    assert len(spans_ev) == 1
+    slices = {(e["name"], round(e["dur"])): e
+              for e in doc["traceEvents"]
+              if e.get("ph") == "X" and e.get("tid") == 3
+              and e.get("pid") == 2}
+    for name, _t0, dur_s, _parent in spans_ev[0]["rows"]:
+        assert (name, round(max(dur_s * 1e6, 1.0))) in slices, name
+    assert {"build", "build.compile", "loop", "loop.wait"} <= {
+        n for n, _ in slices}
+    assert min(e["ts"] for e in doc["traceEvents"] if "ts" in e) >= 0
     assert "states" in names  # counter track (ph: C)
     phases = {e.get("ph") for e in doc["traceEvents"]}
     assert {"X", "C", "M"} <= phases
@@ -267,7 +343,7 @@ def test_cli_sigterm_recover_one_continuous_journal(tmp_path, capsys):
     """Acceptance: a SIGTERM'd -checkpoint CLI run followed by -recover
     produces ONE continuous journal (run_start ... interrupted ...
     run_resume ... final ok) that validates, and whose trace export
-    carries the expand/commit overlap lanes."""
+    carries the host spans of both attempts."""
     from jaxtlc.cli import main
 
     d = tmp_path / "m"
@@ -361,8 +437,12 @@ def test_cli_sigterm_recover_one_continuous_journal(tmp_path, capsys):
     doc = json.load(open(trace))
     names = [e.get("name", "") for e in doc["traceEvents"]]
     assert any(s.startswith("interrupted") for s in names)
-    assert any(s.startswith("expand L") for s in names)
-    assert any(s.startswith("commit L") for s in names)
+    # both attempts' host spans are in the one timeline (a `spans`
+    # event before each final), and no schematic per-level lane
+    assert [e["event"] for e in events].count("spans") == 2
+    assert names.count("build") == 2 and names.count("loop") == 2
+    assert {"check.resolve", "check.preflight", "loop.wait"} <= set(names)
+    assert not any(s.startswith(("expand L", "commit L")) for s in names)
 
 
 def test_schema_rejects_drift():

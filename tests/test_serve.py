@@ -20,6 +20,8 @@ import time
 import urllib.error
 import urllib.request
 
+import pytest
+
 from jaxtlc.obs import journal as jr
 from jaxtlc.obs import serve as obs_serve
 
@@ -162,3 +164,51 @@ def test_tlcstat_connect_renders_remote_run(tmp_path, capsys):
             assert needle in out, (needle, out)
     finally:
         srv.shutdown()
+
+
+def test_spans_event_reaches_metrics_and_tlcstat(tmp_path):
+    """ISSUE 24: a check's host spans (the journal's one `spans` event)
+    fold into the per-phase wall totals, so /metrics and tlcstat show
+    them by name with no exporter of their own."""
+    from jaxtlc.obs.views import phase_totals
+
+    path = str(tmp_path / "run.journal.jsonl")
+    with jr.RunJournal(path) as j:
+        j.event("run_start", version="t", workload="FF", engine="single",
+                device="cpu", params={})
+        j.event("phase", scope="segment", index=0, phase="device",
+                wall_s=0.25)
+        t = time.time()
+        j.event("spans", rows=[["build.trace", t - 3.0, 1.5, 2],
+                               ["build.lower", t - 1.5, 0.5, 2],
+                               ["build", t - 3.0, 2.25, -1],
+                               ["loop.wait", t - 0.5, 0.125, 5],
+                               ["loop.wait", t - 0.25, 0.125, 5],
+                               ["loop", t - 0.75, 0.75, -1]])
+        j.event("final", verdict="ok", generated=1, distinct=1, depth=1,
+                queue=0, wall_s=0.75, interrupted=False)
+    totals = phase_totals(jr.read(path))
+    assert totals == {"device": 0.25, "build.trace": 1.5,
+                      "build.lower": 0.5, "build": 2.25,
+                      "loop.wait": 0.25, "loop": 0.75}
+    srv = obs_serve.start_server(str(tmp_path))
+    try:
+        metrics = _get(srv.url + "/metrics")
+    finally:
+        srv.shutdown()
+    for needle in ('jaxtlc_phase_wall_seconds{phase="build.trace"} 1.5',
+                   'jaxtlc_phase_wall_seconds{phase="loop.wait"} 0.25',
+                   'jaxtlc_phase_wall_seconds{phase="device"} 0.25'):
+        assert needle in metrics, (needle, metrics)
+    spec = importlib.util.spec_from_file_location(
+        "tlcstat", os.path.join(os.path.dirname(__file__), "..",
+                                "tools", "tlcstat.py"))
+    tlcstat = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tlcstat)
+    board = tlcstat.render(jr.read(path))
+    assert "build.trace 1.500s" in board and "loop.wait 0.250s" in board
+    # a malformed row is a schema error at write time
+    from jaxtlc.obs.schema import JournalSchemaError
+
+    with pytest.raises(JournalSchemaError):
+        jr.RunJournal().event("spans", rows=[["build", t, "long"]])

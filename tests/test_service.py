@@ -26,6 +26,7 @@ import io
 import json
 import os
 import time
+import urllib.error
 
 import pytest
 
@@ -235,6 +236,54 @@ def test_warm_resubmit_zero_fresh_xla_compiles(server, sweep_jobs):
     assert st["result"]["pool_hit"] is True
     assert xla_compiles() - pre == 0, "warm sweep submit recompiled"
     assert st["result"]["distinct"] == _EXPECT[1][1]
+
+
+def test_pooled_job_leaves_every_span(server, sweep_jobs):
+    """ISSUE 24: a pooled job's host spans - the scheduler thread's ten
+    under one `sched.run`, all carrying the served job's id, children
+    inside parents, top-level children covering the dispatch - the
+    HTTP handlers' threads adding nothing to the recorder, and the job
+    journal's one `spans` event."""
+    from test_spans import assert_tree
+
+    from jaxtlc.obs import journal as jr
+    from jaxtlc.obs import spans
+
+    t = time.time()
+    st = client.check(server.url, _TPB, _cfg(2), name="plain-spans",
+                      options=_OPTS)
+    assert st["result"]["engine"] == "pool"
+    # a client's polls (any id, any rate) cost the recorder no row:
+    # every row since the submit is the scheduler thread's, of this job
+    with pytest.raises(urllib.error.HTTPError):
+        client.status(server.url, "no-such-job")
+    sched = spans.snapshot(since=t)
+    assert {r.job for r in sched} == {st["id"]}
+    root = assert_tree(sched, "sched.run")
+    assert len({r.thread for r in sched}) == 1
+    assert sorted(r.name for r in sched) == sorted([
+        "sched.run", "sched.jobdir", "sched.load", "sched.cache_lookup",
+        "pool.get", "pool.carry", "pool.run", "pool.readback",
+        "sched.journal", "sched.finish"])
+    assert len(sched) <= 16  # the budget
+    by_name = {r.name: r for r in sched}
+    for n in ("pool.carry", "pool.run", "pool.readback"):
+        assert by_name[n].parent == by_name["sched.journal"].id
+    # the engine's wall IS the pool.run span: one clock, one pair
+    assert st["result"]["wall_s"] == pytest.approx(
+        by_name["pool.run"].t1 - by_name["pool.run"].t0, abs=1e-5)
+    events = jr.read(os.path.join(server.root,
+                                  f"{st['id']}.journal.jsonl"))
+    kinds = [e["event"] for e in events]
+    assert kinds.count("spans") == 1 and kinds[-2:] == ["spans", "final"]
+    assert [row[0] for row in events[-2]["rows"]] == [
+        r.name for r in sched if r.t1 <= events[-2]["t"]] == [
+        "sched.jobdir", "sched.load", "sched.cache_lookup", "pool.get",
+        "pool.carry", "pool.run", "pool.readback"]
+    # the journal reports what it cost itself on its closing span
+    closing = by_name["sched.journal"].attrs
+    assert closing["events"] == len(events) and closing["fsyncs"] >= 1
+    assert 0 < closing["seconds"] < root.t1 - root.t0
 
 
 # ---------------------------------------------------------------------------
