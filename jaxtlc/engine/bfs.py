@@ -221,14 +221,19 @@ class CheckResult(NamedTuple):
 
 
 def carry_done(carry: EngineCarry) -> bool:
-    """Host-side termination check (used by the checkpointed driver)."""
-    if int(carry.viol) != OK:
+    """Host-side termination check (used by the checkpointed driver):
+    one batched read of the five scalars, not five blocking pulls - it
+    sits between two segments, where the device waits for the host."""
+    st = carry.st_n if carry.st_n is not None else 0
+    viol, level_n, qhead, next_n, st_n = jax.device_get(
+        (carry.viol, carry.level_n, carry.qhead, carry.next_n, st)
+    )
+    if int(viol) != OK:
         return True
-    pending = carry.st_n is not None and int(carry.st_n) > 0
     return (
-        int(carry.level_n) - int(carry.qhead) <= 0
-        and int(carry.next_n) == 0
-        and not pending
+        int(level_n) - int(qhead) <= 0
+        and int(next_n) == 0
+        and int(st_n) <= 0
     )
 
 
@@ -521,7 +526,7 @@ def make_stage_pair(
                     queue, rows_a[None], (1 - c.parity, woff, jnp.int32(0))
                 )
                 # per-action distinct counts by [A, n_labels] compare-
-                # reduce (scatter-adds cost ~140ns/element on-chip)
+                # reduce: dense work in place of an A-element scatter-add
                 acts_a = ex.action[idx_a]
                 act_dist = act_dist.at[:n_labels].add(
                     (
@@ -691,6 +696,37 @@ def make_stage_pair(
         )
 
     return pop_expand, commit
+
+
+def run_steps(cond, body, c, steps=None, small_body=None, big=None):
+    """Up to `steps` steps of `body` on the carry `c` (None: until
+    `cond(c)` is false), stopping when `cond` does.  With a `small_body`
+    each step is `body` where `big(c)` holds and `small_body` where it
+    does not.  While loops only: a while loop writes its carry in
+    place, where a conditional that takes the carry (an identity branch
+    for a finished check, a branch per tier) cost a copy of the queue a
+    step on the chip (PERF.md PR 26, Step 0).  So the two tiers are two
+    inner loops, each running its body for as long as that tier is the
+    one to take: the same bodies in the same order as a choice made
+    step by step, and one step counter for both."""
+
+    def live(st):
+        i, cc = st
+        return cond(cc) if steps is None else cond(cc) & (i < steps)
+
+    def stepping(tier_body, wanted=None):
+        go = live if wanted is None else (
+            lambda st: live(st) & wanted(st[1]))
+        return lambda st: lax.while_loop(
+            go, lambda s: (s[0] + 1, tier_body(s[1])), st)
+
+    start = (jnp.int32(0), c)
+    if small_body is None:
+        return stepping(body)(start)[1]
+    big_steps = stepping(body, big)
+    small_steps = stepping(small_body, lambda cc: ~big(cc))
+    return lax.while_loop(
+        live, lambda st: small_steps(big_steps(st)), start)[1]
 
 
 def make_backend_engine(
@@ -947,6 +983,7 @@ def make_backend_engine(
 
         return body
 
+    small_body = None
     if pipeline:
         pop_expand, commit = make_stages(chunk)
 
@@ -1012,20 +1049,17 @@ def make_backend_engine(
             ) & (c.viol == OK)
 
     else:
-        big_body = make_body(chunk)
+        body = make_body(chunk)
         if small:
             small_body = make_body(small)
-            # break-even: a big step costs ~what chunk/small small steps
-            # cost, so take the big body only when the level remainder
-            # mostly fills it
-            def body(c: EngineCarry) -> EngineCarry:
-                avail = c.level_n - c.qhead
-                return lax.cond(avail >= chunk // 2, big_body, small_body, c)
-        else:
-            body = big_body
 
         def cond(c: EngineCarry):
             return ((c.qhead < c.level_n) | (c.next_n > 0)) & (c.viol == OK)
+
+    # break-even: a big step costs ~what chunk/small small steps cost,
+    # so take the big body only when the level remainder mostly fills it
+    def big(c: EngineCarry):
+        return c.level_n - c.qhead >= chunk // 2
 
     # donate the carry so XLA aliases the ping-pong queue / staged
     # candidate buffers in place of copies (CPU has no donation support;
@@ -1033,12 +1067,15 @@ def make_backend_engine(
     donate_ok = bool(donate) and jax.devices()[0].platform != "cpu"
     jit_kw = {"donate_argnums": (0,)} if donate_ok else {}
 
-    run_fn = jax.jit(
-        lambda c: lax.while_loop(cond, body, c), **jit_kw
-    )
-    step_fn = jax.jit(
-        lambda c: lax.cond(cond(c), body, lambda x: x, c), **jit_kw
-    )
+    def steps_fn(n):
+        return jax.jit(
+            lambda c: run_steps(cond, body, c, n, small_body, big),
+            **jit_kw)
+
+    run_fn = steps_fn(None)
+    step_fn = steps_fn(1)
+    # a checkpointed driver's program: `n` steps a call
+    step_fn.segment = steps_fn
     # donation metadata for the preflight audit (analysis.engine_audit):
     # donate_requested is the factory intent, donates_carry what XLA
     # will actually do on this platform - the gap is the class of bug

@@ -11,8 +11,9 @@ the resumed run reproduces the uninterrupted run's final counts bit-for-bit
 (tested in tests/test_checkpoint.py).
 
 The checkpointed driver trades the single fused `lax.while_loop` for a
-host loop over an n-chunk fused segment (`lax.fori_loop` of engine steps),
-syncing to host once per segment - the standard checkpoint-granularity
+host loop over an n-chunk fused segment (the engine's own `step_fn.segment`:
+a while loop that also ends when the check does), syncing to host once per
+segment - the standard checkpoint-granularity
 trade-off.
 """
 
@@ -29,7 +30,6 @@ from typing import NamedTuple, Optional
 
 import jax
 import numpy as np
-from jax import lax
 
 from ..config import ModelConfig
 from ..obs.spans import in_check, span
@@ -304,12 +304,7 @@ def check_with_checkpoints(
             obs_slots=obs_slots, sort_free=sort_free, deferred=deferred,
         )
 
-        @jax.jit
-        def segment(c: EngineCarry) -> EngineCarry:
-            return lax.fori_loop(0, ckpt_every,
-                                 lambda _, cc: step_fn(cc), c)
-
-        return init_fn, segment
+        return init_fn, step_fn.segment(ckpt_every)
 
     template, compiled_segment = aot_build(make)
     t0 = time.time()

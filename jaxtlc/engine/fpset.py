@@ -3,17 +3,19 @@
 TLC stores every seen state's 64-bit fingerprint in an open-addressing
 off-heap table (`OffHeapDiskFPSet`, /root/reference/KubeAPI.toolbox/Model_1/
 MC.out:5); 72% of generated states are rejected here (MC.out:1098), making
-dedup the hot path.  v4 design, driven by on-chip microbenchmarks
-(tools/microbench.py: random row gathers ~70ns, row scatters ~140ns, 245k
-4-lane sorts ~2.5ms): the cost model is *row operations*, so the structure
-minimizes them.
+dedup the hot path.  The cost model is *row operations* on the table, so
+the structure minimizes them.  On a TPU v5e with the table at
+[2^21, 16] (PERF.md PR 26, Step 0): a gather of 32,768 bucket rows takes
+0.73 ms and a scatter-add of as many rows 3.3 ms, both in place on the
+table's own layout; an element scatter into the rank-2 table is
+flattened by XLA first, two whole-table relayouts (6.1 ms) a call.
 
 * **Bucketized table**: ``[cap/8, 16] uint32`` - one 64-byte row per
   8-slot bucket, slots interleaved ``lo0,hi0,...,lo7,hi7``; (0, 0) = empty
   slot.  The rank-2 interleaved layout is the measured fast point: a probe
-  is ONE row gather (7.5 ms for 262k probes vs 45 ms for a
-  reshaped-3D-view gather, which makes XLA rematerialize the relayout
-  every call).  A bucket's occupied slots are always a prefix (inserts
+  is ONE row gather (0.73 ms for 32,768 probes on the chip, against
+  9.9 ms as element gathers from a flat [2 * cap] table and 37.8 ms as
+  a windowed gather from it: PERF.md PR 26).  A bucket's occupied slots are always a prefix (inserts
   fill in order, nothing is ever deleted), and the home bucket of a
   (mixed) fingerprint is the top bits of ``hi`` - monotonic in
   fingerprint sort order.
@@ -35,13 +37,13 @@ minimizes them.
   the straggler slice by current bucket (the CPU form), or the dense
   [S, S] bucket-coincidence reduction per the BLEST tensor-core BFS
   papers (the accelerator form - no comparator network in the walk;
-  `JAXTLC_DENSE_WALK` overrides the platform auto).  No claim-verify exists anywhere: slot writes
-  are a pair of element scatters (lo column, hi column), and with every
-  claim targeting a distinct slot, scatter duplicate-resolution order can
-  never tear a row (a verify-based loop would live-lock on a backend that
-  resolved the two scatters in different orders).  tests/test_fpset.py's
-  high-load test drives the straggler walk hard (0.68 load, 5.5 expected
-  per 8-slot bucket).
+  `JAXTLC_DENSE_WALK` overrides the platform auto).  No claim-verify
+  exists anywhere: a slot write is one scatter-add of whole bucket rows
+  (`_slot_write`), and with every claim targeting a distinct EMPTY slot
+  the sum is the write whatever order the rows land in (a verify-based
+  loop would live-lock on a backend that resolved two element scatters
+  in different orders).  tests/test_fpset.py's high-load test drives
+  the straggler walk hard (0.68 load, 5.5 expected per 8-slot bucket).
 
 Lookup/insert invariant: a fingerprint lives in bucket ``b + j`` only if
 buckets ``b .. b+j-1`` are full; so a probe that sees its home bucket
@@ -105,17 +107,58 @@ def fpset_count(s: FPSet) -> jnp.ndarray:
     return ((lo != 0) | (hi != 0)).sum().astype(jnp.uint32)
 
 
-def _slot_write(table, slot, lo, hi, active):
-    """Write (lo, hi) into global slot ids where active (drop otherwise).
+WRITE_BLOCKS = 8  # a wide write goes block by block, as far as lanes are live
 
-    Two element scatters into the interleaved bucket row; see the module
-    docstring for why this is tear-safe in practice."""
-    nb = table.shape[0]
-    b = jnp.where(active, slot // BUCKET, nb)
-    col = 2 * (slot % BUCKET)
-    table = table.at[b, col].set(lo, mode="drop")
-    table = table.at[b, col + 1].set(hi, mode="drop")
-    return table
+
+def _blocked(n: int) -> bool:
+    """Whether an n-lane write is worth cutting into WRITE_BLOCKS blocks
+    behind a trip count: a scattered row costs ~100 ns on the chip
+    whether its lane is live or not (PERF.md PR 26), so a wide write
+    pays for its width unless it stops where the live lanes do."""
+    return n >= 512 and n % WRITE_BLOCKS == 0
+
+
+def _slot_write(table, slot, lo, hi, active, n_live=None):
+    """Write (lo, hi) into global slot ids where active.
+
+    A scatter-add of whole [2B] bucket rows, each zero but for its
+    lane's word pair at its slot's columns.  Every claim targets an
+    EMPTY (0, 0) slot and no two claims share a slot (the rank claims),
+    so adding is writing, rows aimed at one bucket combine, and an
+    inactive lane adds a zero row: no lane needs an out-of-range index.
+    The row window is the form the TPU scatters in place on the table's
+    own layout ([2B, nb] tiled); an element scatter on the rank-2 table
+    is flattened first, at two whole-table relayouts a call (PERF.md
+    PR 26, Step 0).
+
+    `n_live` (traced) promises that only the first n_live lanes can be
+    active: a `_blocked` write then scatters block after block only as
+    far as that."""
+
+    def add_rows(table, slot, lo, hi, active):
+        b = jnp.where(active, slot // BUCKET, 0)
+        col = (2 * (slot % BUCKET))[:, None]
+        j = jnp.arange(2 * BUCKET, dtype=col.dtype)[None, :]
+        zero = jnp.uint32(0)
+        row = jnp.where(j == col, lo[:, None],
+                        jnp.where(j == col + 1, hi[:, None], zero))
+        row = jnp.where(active[:, None], row, zero)
+        return table.at[b].add(row, mode="promise_in_bounds")
+
+    n = slot.shape[0]
+    if n_live is None or not _blocked(n):
+        return add_rows(table, slot, lo, hi, active)
+    block = n // WRITE_BLOCKS
+
+    def write(st):
+        table, k = st
+        part = [lax.dynamic_slice(x, (k * block,), (block,))
+                for x in (slot, lo, hi, active)]
+        return add_rows(table, *part), k + 1
+
+    return lax.while_loop(
+        lambda st: st[1] * block < n_live, write, (table, jnp.int32(0))
+    )[0]
 
 
 def _remap(lo, hi):
@@ -409,23 +452,19 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
     slot = noccup + rank
     fits = want & (slot < BUCKET)
 
-    # compact claimers to a C-row scatter (row scatters cost ~140ns/row:
-    # scattering only the claimers is the win).  Claimers beyond C (or
-    # whose bucket is full) settle in the straggler loop.
+    # the first C fitting claimers write now; the rest (and claimers
+    # whose bucket is full) settle in the straggler loop.  The claimers
+    # are compacted to the front first (stable, so they stay slot-
+    # ascending), and a wide write stops where they do.
     claim_pos = jnp.cumsum(fits.astype(jnp.int32)) - 1
     claimed = fits & (claim_pos < C)
-    tgt32 = (bid * BUCKET + slot).astype(jnp.uint32)
-    nf = (~claimed).astype(jnp.uint32)
-    _, t_tgt, t_lo, t_hi = lax.sort((nf, tgt32, lo, hi), num_keys=1,
-                                    is_stable=True)
     nclaim = claimed.sum()
-    table = _slot_write(
-        table,
-        t_tgt[:C].astype(jnp.int32),
-        t_lo[:C],
-        t_hi[:C],
-        jnp.arange(C) < nclaim,
+    _, t_tgt, t_lo, t_hi = lax.sort(
+        ((~claimed).astype(jnp.uint32), bid * BUCKET + slot, lo, hi),
+        num_keys=1, is_stable=True,
     )
+    table = _slot_write(table, t_tgt, t_lo, t_hi,
+                        jnp.arange(R) < nclaim, nclaim)
 
     is_new = claimed
     pending = active & ~found & ~claimed
@@ -453,7 +492,10 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
         s_bid = p_bid[:S].astype(jnp.int32)
         s_lo, s_hi = p_lo[:S], p_hi[:S]
         s_pos = p_pos[:S].astype(jnp.int32)
-        s_act = jnp.arange(S) < jnp.minimum(pending.sum(), S)
+        # the slice's live lanes are its first n_act (and stay a
+        # prefix in the sorted walk: it sorts pending lanes first)
+        n_act = jnp.minimum(pending.sum(), S)
+        s_act = jnp.arange(S) < n_act
 
         def walk_cond(wst):
             _, _, pend, _, _ = wst
@@ -491,7 +533,7 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
             sl = occ + rnk
             ok = wnt & (sl < BUCKET)
             table = _slot_write(table, cur_b * BUCKET + sl, s_lo, s_hi,
-                                ok)
+                                ok, n_act)
             new = new | ok
             pend2 = pend & ~(f | ok)
             # unsettled claimants advance to the next bucket
@@ -523,7 +565,7 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
             sl = occ + rnk
             ok = wnt & (sl < BUCKET)
             table = _slot_write(
-                table, w_b * BUCKET + sl, w_lo, w_hi, ok
+                table, w_b * BUCKET + sl, w_lo, w_hi, ok, n_act
             )
             # map verdicts back to slice order (w_o is a permutation)
             oi = w_o.astype(jnp.int32)
@@ -552,14 +594,55 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
     return table, is_new
 
 
-def _sorted_dedup_probe(
-    table, lo, hi, n: int, R: int, C: int
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """The sorted dedup + probe core over already MIXED, remapped,
-    mask-zeroed fingerprint words (the body of fpset_insert_sorted
-    below its mixing prologue, lifted so the sort-free slab path can
-    fall back to the exact same computation).  Returns (table,
-    is_new_c [n], c_idx [n] int32, nreps)."""
+def _probe_segments(table, c_lo, c_hi, active, n_rows, R: int, C: int):
+    """Probe / claim the ordered candidates c_lo/c_hi [n] (fp-ascending,
+    `active` marking the dup-free representatives, all of them within
+    the first `n_rows` rows) in R-wide blocks.  Returns (table,
+    is_new [n]).  The one place the commit's table is written: both
+    orderings (sorted, slab) hand their candidates here, so the table
+    is never the operand of a conditional."""
+    n = c_lo.shape[0]
+    if R == n:
+        return _probe_block(table, c_lo, c_hi, active, C)
+
+    # block loop: one trip unless a chunk is nearly all-distinct (the
+    # slab ordering always fits one block); each block stays fp-sorted.
+    # Pad to a whole number of blocks: dynamic_slice CLAMPS out-of-
+    # bounds start offsets, so an unpadded final partial block would
+    # re-probe earlier entries and never probe the tail.
+    nseg = (n + R - 1) // R
+    pad = nseg * R - n
+    p_lo = jnp.pad(c_lo, (0, pad))
+    p_hi = jnp.pad(c_hi, (0, pad))
+    p_act = jnp.pad(active, (0, pad))
+
+    def seg_cond(st):
+        table, is_new_p, seg = st
+        return (seg * R < n_rows) & (seg < nseg)
+
+    def seg_body(st):
+        table, is_new_p, seg = st
+        off = seg * R
+        b_lo = lax.dynamic_slice(p_lo, (off,), (R,))
+        b_hi = lax.dynamic_slice(p_hi, (off,), (R,))
+        b_act = lax.dynamic_slice(p_act, (off,), (R,))
+        table, b_new = _probe_block(table, b_lo, b_hi, b_act, C)
+        is_new_p = lax.dynamic_update_slice(is_new_p, b_new, (off,))
+        return table, is_new_p, seg + 1
+
+    table, is_new_p, _ = lax.while_loop(
+        seg_cond, seg_body, (table, jnp.zeros(nseg * R, bool), jnp.int32(0))
+    )
+    return table, is_new_p[:n]
+
+
+def _sorted_order(lo, hi):
+    """The sorted dedup over already MIXED, remapped, mask-zeroed
+    fingerprint words: (c_lo, c_hi, c_idx int32, nreps), the distinct
+    representatives compacted fp-ascending into the first nreps rows
+    (the ordering half of fpset_insert_sorted, lifted so the sort-free
+    slab path can fall back to the exact same computation)."""
+    n = lo.shape[0]
     # sort 1: group duplicates.  Invalid lanes are encoded as the RESERVED
     # (0,0) word pair - _remap guarantees no real fingerprint is (0,0) -
     # so validity needs no separate sort key: 3 arrays / 2 keys instead of
@@ -583,41 +666,7 @@ def _sorted_dedup_probe(
         (nonrep, s_lo, s_hi, s_idx), num_keys=1, is_stable=True
     )
     nreps = rep.sum().astype(jnp.int32)
-
-    if R == n:
-        table, is_new_c = _probe_block(
-            table, c_lo, c_hi, jnp.arange(n) < nreps, C
-        )
-        return table, is_new_c, c_idx.astype(jnp.int32), nreps
-
-    # segment loop for batches wider than probe_width (rare: only when a
-    # chunk is nearly all-distinct); each segment stays fp-sorted.  Pad to
-    # a whole number of segments: dynamic_slice CLAMPS out-of-bounds start
-    # offsets, so an unpadded final partial segment would re-probe earlier
-    # entries and never probe the tail.
-    nseg = (n + R - 1) // R
-    pad = nseg * R - n
-    p_lo = jnp.pad(c_lo, (0, pad))
-    p_hi = jnp.pad(c_hi, (0, pad))
-
-    def seg_cond(st):
-        table, is_new_p, seg = st
-        return (seg * R < nreps) & (seg < nseg)
-
-    def seg_body(st):
-        table, is_new_p, seg = st
-        off = seg * R
-        b_lo = lax.dynamic_slice(p_lo, (off,), (R,))
-        b_hi = lax.dynamic_slice(p_hi, (off,), (R,))
-        active = (jnp.arange(R) + off) < nreps
-        table, b_new = _probe_block(table, b_lo, b_hi, active, C)
-        is_new_p = lax.dynamic_update_slice(is_new_p, b_new, (off,))
-        return table, is_new_p, seg + 1
-
-    table, is_new_p, _ = lax.while_loop(
-        seg_cond, seg_body, (table, jnp.zeros(nseg * R, bool), jnp.int32(0))
-    )
-    return table, is_new_p[:n], c_idx.astype(jnp.int32), nreps
+    return c_lo, c_hi, c_idx.astype(jnp.int32), nreps
 
 
 def fpset_insert_sorted(
@@ -643,8 +692,9 @@ def fpset_insert_sorted(
     lo, hi = _remap(lo, hi)
     lo = jnp.where(mask, lo, 0)
     hi = jnp.where(mask, hi, 0)
-    table, is_new_c, c_idx, nreps = _sorted_dedup_probe(
-        s.table, lo, hi, n, R, C
+    c_lo, c_hi, c_idx, nreps = _sorted_order(lo, hi)
+    table, is_new_c = _probe_segments(
+        s.table, c_lo, c_hi, jnp.arange(n) < nreps, nreps, R, C
     )
     return FPSet(table), is_new_c, c_idx, nreps
 
@@ -815,12 +865,15 @@ def fpset_insert_slab(
     Every engine consumer is layout-blind - commit re-orders by
     (is_new, lane) and masks on n_new - so results are bit-for-bit.
 
-    Falls back to the sorted computation wholesale (one lax.cond; only
-    the taken branch executes) when the claimants exceed the probe
-    width - the all-distinct-burst regime where the sorted path would
-    run its segment loop anyway.  The ordering sort runs INSIDE the
-    taken branch with explicit operands: raw sort outputs crossing the
-    cond boundary mis-wire under shard_map (see _slab_dedup_core)."""
+    Falls back to the sorted ORDERING wholesale (one lax.cond; only the
+    taken branch executes) when the claimants exceed the probe width -
+    the all-distinct-burst regime where the sorted path would run its
+    block loop anyway.  The conditional chooses the ordered candidates
+    and nothing else; the probe that writes the table runs after it, on
+    either ordering (_probe_segments), so the table crosses no
+    conditional.  Each ordering sort runs INSIDE its branch with
+    explicit operands: raw sort outputs crossing INTO a cond mis-wire
+    under shard_map (see _slab_dedup_core)."""
     n = lo.shape[0]
     R = min(probe_width or n, n)
     C = min(claim_width or R, R)
@@ -828,31 +881,34 @@ def fpset_insert_slab(
         lo, hi, mask, R, slab_factor, slab_passes, slab_bits
     )
 
-    def slab_finish(op):
-        table, mlo, mhi, lanes, nc = op
+    # both orderings give n-wide (c_lo, c_hi, c_idx, active, rows in
+    # use); the conditional carries candidates only, never the table
+    def slab_order(op):
+        mlo, mhi, lanes, nc = op
         c_lo, c_hi, c_idx, active = _order_and_dedup(
             mlo, mhi, lanes, nc, R, n
         )
-        table, is_new_r = _probe_block(table, c_lo, c_hi, active, C)
-        nreps = active.sum().astype(jnp.int32)
+        wide = n - R
         return (
-            table,
-            jnp.concatenate([is_new_r, jnp.zeros(n - R, bool)]),
-            jnp.concatenate(
-                [c_idx, jnp.full(n - R, n, jnp.int32)]
-            ),
-            nreps,
+            jnp.concatenate([c_lo, jnp.zeros(wide, jnp.uint32)]),
+            jnp.concatenate([c_hi, jnp.zeros(wide, jnp.uint32)]),
+            jnp.concatenate([c_idx, jnp.full(wide, n, jnp.int32)]),
+            jnp.concatenate([active, jnp.zeros(wide, bool)]),
+            nc,
         )
 
-    def sorted_fb(op):
-        table, mlo, mhi, _lanes, _nc = op
-        return _sorted_dedup_probe(table, mlo, mhi, n, R, C)
+    def sorted_order(op):
+        mlo, mhi, _lanes, _nc = op
+        c_lo, c_hi, c_idx, nreps = _sorted_order(mlo, mhi)
+        return c_lo, c_hi, c_idx, jnp.arange(n) < nreps, nreps
 
-    table, is_new_c, c_idx_out, nreps_out = lax.cond(
-        fallback, sorted_fb, slab_finish,
-        (s.table, m_lo, m_hi, c_lane, n_cand),
+    c_lo, c_hi, c_idx, active, n_rows = lax.cond(
+        fallback, sorted_order, slab_order, (m_lo, m_hi, c_lane, n_cand)
     )
-    return FPSet(table), is_new_c, c_idx_out, nreps_out
+    table, is_new_c = _probe_segments(
+        s.table, c_lo, c_hi, active, n_rows, R, C
+    )
+    return FPSet(table), is_new_c, c_idx, active.sum().astype(jnp.int32)
 
 
 def fpset_insert_dedup(

@@ -67,7 +67,6 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import numpy as np
-from jax import lax
 from jax.errors import JaxRuntimeError
 
 from ..engine import checkpoint as ckpt
@@ -350,12 +349,7 @@ class SingleDeviceAdapter:
                     deferred=self.deferred,
                 )
 
-            @jax.jit
-            def segment(c):
-                return lax.fori_loop(0, ckpt_every,
-                                     lambda _, cc: step_fn(cc), c)
-
-            return init_fn, segment
+            return init_fn, step_fn.segment(ckpt_every)
 
         # async contract: seg_fn DISPATCHES and returns in-flight arrays;
         # the supervision loop overlaps host work (checkpoint write,

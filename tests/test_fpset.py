@@ -4,6 +4,7 @@ in-batch duplicates, masking, and load."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from jaxtlc.engine.fpset import fpset_count, fpset_insert, fpset_new
 
@@ -115,3 +116,45 @@ def test_high_load():
         s, jnp.asarray(vals), jnp.asarray(vals ^ 0xFFFF), jnp.ones(700, bool)
     )
     assert int(np.asarray(new).sum()) == 700
+
+
+@pytest.mark.parametrize("dense", ["0", "1"], ids=["sort-walk", "dense-walk"])
+def test_blocked_write_bit_for_bit(monkeypatch, dense):
+    """A wide write goes block by block behind a trip count (ISSUE 26:
+    `_blocked`, round 0 over its compacted claimers, the straggler walk
+    over its live prefix): the table words and verdicts are those of
+    the one whole-width scatter, with and without straggler pressure,
+    and the distinct count is the set's."""
+    from jaxtlc.engine import fpset
+    from jaxtlc.engine.fpset import fpset_insert_sorted
+
+    monkeypatch.setenv("JAXTLC_DENSE_WALK", dense)
+    n = 1024
+    assert fpset._blocked(n)
+    got = {}
+    for blocked in (True, False):
+        if not blocked:
+            monkeypatch.setattr(fpset, "_blocked", lambda n: False)
+        s, seen, verdicts = fpset_new(1 << 13), set(), []
+        for step in range(3):
+            rng = np.random.default_rng(7 + step)
+            lo = rng.integers(0, 2 ** 32, size=n, dtype=np.uint32)
+            hi = rng.integers(0, 2 ** 6, size=n, dtype=np.uint32) << 26
+            lo[::5] = lo[1::5][: len(lo[::5])]  # in-batch duplicates
+            hi[::5] = hi[1::5][: len(hi[::5])]
+            mask = rng.random(n) < 0.9
+            # step 0 claims in round 0 (several blocks); a narrow claim
+            # width then sends most claimers to the straggler walk
+            s, is_new_c, c_idx, nreps = fpset_insert_sorted(
+                s, jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(mask),
+                claim_width=128 if step else 0,
+            )
+            fresh = {(int(a), int(b))
+                     for a, b, m in zip(lo, hi, mask) if m} - seen
+            assert int(np.asarray(is_new_c).sum()) == len(fresh)
+            seen |= fresh
+            verdicts.append((np.asarray(is_new_c), np.asarray(c_idx)))
+        got[blocked] = (np.asarray(s.table), verdicts)
+    assert (got[True][0] == got[False][0]).all()
+    for (n0, c0), (n1, c1) in zip(got[True][1], got[False][1]):
+        assert (n0 == n1).all() and (c0 == c1).all()

@@ -184,6 +184,149 @@ def test_auto_resolution_and_memo_key():
 
 
 # ---------------------------------------------------------------------------
+# the segment program (ISSUE 26): loops only, writes in place.  The
+# exactness cases pay one FF segment compile a mode (a segment is a
+# program of its own); the structural pin only lowers.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("sort_free", [False, True],
+                         ids=["sorted", "sort-free"])
+def test_segment_that_ends_with_the_check_exact(ab_runs, sort_free):
+    """`step_fn.segment(n)` leaves its loop when the check is done: a
+    run cut into 64-step segments, the last of which exhausts the
+    queue part-way, ends on run_fn's very carry - counters, queue and
+    fingerprint-table words - and a finished carry passes through."""
+    import jax
+
+    from jaxtlc.engine.bfs import carry_done
+
+    init_fn, _, step_fn = make_engine(
+        FF, **KW, donate=False, sort_free=sort_free,
+    )
+    segment = step_fn.segment(64)
+    carry, calls = init_fn(), 0
+    while not carry_done(carry):
+        carry = segment(carry)
+        calls += 1
+    assert calls >= 2  # whole segments, then one that ends early
+    ref = ab_runs[sort_free][0]
+
+    def same(a, b):
+        la = jax.tree_util.tree_leaves(a)
+        lb = jax.tree_util.tree_leaves(b)
+        return len(la) == len(lb) and all(
+            (np.asarray(x) == np.asarray(y)).all() for x, y in zip(la, lb))
+
+    assert same(carry, ref)
+    assert same(segment(carry), ref)  # nothing left to do: unchanged
+
+
+def test_segment_program_crosses_no_conditional_with_its_buffers():
+    """What the chip's HLO showed (PERF.md PR 26, Step 0), pinned on the
+    lowered text: no conditional returns a table- or queue-shaped
+    tensor (a whole-buffer copy a step where one did), and every write
+    into the table is ONE scatter of whole bucket rows (an element
+    scatter is flattened by XLA at two table relayouts a call)."""
+    import re
+
+    import jax
+
+    init_fn, _, step_fn = make_engine(
+        FF, **KW, donate=False, sort_free=True,
+    )
+    shapes = jax.eval_shape(init_fn)
+    text = step_fn.segment(8).lower(shapes).as_text()
+    dims = lambda x: "x".join(map(str, x.shape)) + "xui32"  # noqa: E731
+    table, queue = dims(shapes.fps.table), dims(shapes.queue)
+    cases = re.findall(r"^\s*\}\) : \(tensor<i32>\) -> (.*)$", text, re.M)
+    assert cases  # the slab fallback and the enqueue order are conditionals
+    for results in cases:
+        assert table not in results and queue not in results, results
+    writes = re.findall(
+        rf"^\s*\}}\) : \(tensor<{table}>, (tensor<\S+>), (tensor<\S+>)\)"
+        rf" -> tensor<{table}>$", text, re.M)
+    # round 0 and the straggler walk: one scatter each, [n, 2B] rows
+    assert len(writes) == 2, writes
+    for idx, upd in writes:
+        assert re.fullmatch(r"tensor<\d+x1xi32>", idx), idx
+        assert re.fullmatch(r"tensor<\d+x16xui32>", upd), upd
+    assert f"tensor<{table}>" in text and f"tensor<{queue}>" in text
+
+
+# the two-tier nest on stub bodies (no engine: the tier threshold is
+# chunk / 2 = 8,192 states of one level at the only width that has a
+# small tier, which no tier-1 model reaches)
+_TIER_WIDTHS = (3, 40, 100, 17, 9, 64, 8, 7)  # level widths
+_TIER_CHUNK, _TIER_SMALL = 16, 4
+
+
+def _tier_reference():
+    """The tier taken and the states popped, step by step, by a choice
+    made before every step: 1000 * tier + pop."""
+    lvl, qh, log = 0, 0, []
+    while lvl < len(_TIER_WIDTHS):
+        avail = _TIER_WIDTHS[lvl] - qh
+        tier, width = ((1, _TIER_CHUNK) if avail >= _TIER_CHUNK // 2
+                       else (2, _TIER_SMALL))
+        pop = min(width, avail)
+        qh += pop
+        log.append(1000 * tier + pop)
+        if qh >= _TIER_WIDTHS[lvl]:
+            lvl, qh = lvl + 1, 0
+    return log
+
+
+@pytest.mark.parametrize("steps", [None, 0, 1, 6, 10, 11, 13, 20, 21, 25])
+def test_two_tier_nest_is_the_step_by_step_choice(steps):
+    """`bfs.run_steps` with a small body: two inner loops under one
+    step counter take the bodies a per-step choice would, in its order,
+    and `steps=n` stops after exactly n of them - between the tiers,
+    inside a run of either, at the end and past it."""
+    import jax
+    import jax.numpy as jnp
+
+    from jaxtlc.engine.bfs import run_steps
+
+    ref = _tier_reference()
+    assert ref[:2] == [2003, 1016] and ref[-2:] == [2004, 2003]
+    widths = jnp.asarray(_TIER_WIDTHS + (0,), jnp.int32)
+
+    def cond(c):
+        return c[0] < len(_TIER_WIDTHS)
+
+    def big(c):
+        return widths[c[0]] - c[1] >= _TIER_CHUNK // 2
+
+    def tier_body(tier, width):
+        def body(c):
+            lvl, qh, log, n = c
+            pop = jnp.minimum(width, widths[lvl] - qh)
+            done = qh + pop >= widths[lvl]
+            return (jnp.where(done, lvl + 1, lvl),
+                    jnp.where(done, 0, qh + pop),
+                    log.at[n].set(1000 * tier + pop), n + 1)
+        return body
+
+    start = (jnp.int32(0), jnp.int32(0),
+             jnp.zeros(len(ref) + 4, jnp.int32), jnp.int32(0))
+    lvl, qh, log, n = jax.jit(lambda c: run_steps(
+        cond, tier_body(1, _TIER_CHUNK), c, steps,
+        tier_body(2, _TIER_SMALL), big))(start)
+    want = ref if steps is None else ref[:steps]
+    assert int(n) == len(want)
+    assert np.asarray(log)[:int(n)].tolist() == want
+    assert not np.asarray(log)[int(n):].any()
+    # where the carry stands is where the reference stands after n steps
+    popped = sum(w % 1000 for w in want)
+    at = 0
+    while at < len(_TIER_WIDTHS) and popped >= _TIER_WIDTHS[at]:
+        popped -= _TIER_WIDTHS[at]
+        at += 1
+    assert (int(lvl), int(qh)) == (at, popped)
+
+
+# ---------------------------------------------------------------------------
 # checkpoint mode continuity (supervised FF, ONE segment compile +
 # the resume rebuild; wrong-mode rejection happens BEFORE any build)
 # ---------------------------------------------------------------------------

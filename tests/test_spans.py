@@ -15,7 +15,6 @@ from collections import deque
 
 import jax
 import pytest
-from jax import lax
 
 from jaxtlc.config import ModelConfig
 from jaxtlc.obs import spans
@@ -298,9 +297,7 @@ def lowered_segment():
     from jaxtlc.engine.bfs import make_engine
 
     init_fn, _, step_fn = make_engine(FF, donate=False, **KW)
-    seg = jax.jit(lambda c: lax.fori_loop(0, 4, lambda _, x: step_fn(x),
-                                          c))
-    return seg.trace(jax.eval_shape(init_fn)).lower()
+    return step_fn.segment(4).trace(jax.eval_shape(init_fn)).lower()
 
 
 def test_lowered_segment_holds_the_six_scopes_and_the_same_program(
