@@ -297,6 +297,10 @@ def _run_check(args) -> int:
                     sort_free=_sort_free(args),
                     deferred=_deferred(args),
                     obs_slots=_obs_slots(args)),
+        # handed over whole, after the dict above: this frame lies
+        # under every build, whose trace time on the chip's host moves
+        # with the frame's size (PERF.md section 6, PR 24 and PR 27)
+        model=spec.model,
     )
 
     def _kubeapi_preflight(deep):
@@ -716,8 +720,10 @@ def _por(args) -> bool:
 
 
 def _open_journal(args, workload: str, engine: str, device: str,
-                  params: dict):
-    """Create the run journal and stamp the manifest.
+                  params: dict, model: ModelConfig = None):
+    """Create the run journal and stamp the manifest (`model`: the hand
+    frontend's process set and fault constants as resolved from MC.cfg,
+    MC.tla and CheckRequest.constants, recorded under params).
 
     Path resolution: -journal PATH wins; else a -checkpoint run
     journals beside its snapshots (PATH.journal.jsonl) so preemption
@@ -739,6 +745,13 @@ def _open_journal(args, workload: str, engine: str, device: str,
             tempfile.gettempdir(),
             f"jaxtlc-{os.getpid()}.journal.jsonl",
         )
+    if model is not None:
+        params = dict(params, model=dict(
+            n_reconcilers=model.n_reconcilers,
+            n_binders=model.n_clients - model.n_reconcilers,
+            requests_can_fail=model.requests_can_fail,
+            requests_can_timeout=model.requests_can_timeout,
+            clients=list(model.clients)))
     resume = bool(args.recover and path and os.path.exists(path))
     j = RunJournal(path or None, resume=resume)
     if resume:
@@ -788,14 +801,13 @@ def _close_journal(args, log, j, r, sup, verdict, wall_s) -> None:
                     name="Temporal properties were violated")
         if sup is None and r is not None:
             v = verdict or ("violation" if r.violation != 0 else "ok")
-            shards = getattr(r, "shard_distinct", None)
+            from .engine.bfs import mesh_counters
+
             j.event("spans", rows=spans.journal_rows())
             j.event("final", verdict=v, generated=r.generated,
                     distinct=r.distinct, depth=r.depth,
                     queue=r.queue_left, wall_s=round(wall_s, 6),
-                    interrupted=False,
-                    **({"shard_distinct": list(shards)}
-                       if shards is not None else {}))
+                    interrupted=False, **mesh_counters(r))
         if args.traceout:
             from .obs.journal import read as read_journal
             from .obs.trace import export_chrome_trace

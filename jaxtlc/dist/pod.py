@@ -534,6 +534,11 @@ def reshard_carry(carry, backend, d_new: int,
         extra["obs_head"] = np.full(d_new, heads.min(), heads.dtype)
         extra["obs_bodies"] = row0(carry.obs_bodies)
         extra["obs_expanded"] = row0(carry.obs_expanded)
+    if getattr(carry, "route_stat", None) is not None:
+        # owner-routing telemetry (fullest bucket, bodies run): the same
+        # maxima on every new row
+        stat = np.asarray(carry.route_stat)
+        extra["route_stat"] = np.tile(stat.max(axis=0), (d_new, 1))
     return ShardCarry(
         table=table2,
         queue=queue2,

@@ -218,6 +218,29 @@ class CheckResult(NamedTuple):
     # candidate transitions pruned by POR ample sets (None when POR is
     # off) - the journalled counter delta of the `reduce` event
     por_pruned: int = None
+    # mesh engine only (telemetry; None elsewhere): successors each
+    # device generated (sums to `generated`; device 0's holds the
+    # initial states); the fullest per-destination bucket any device
+    # packed in any step beside the bucket's width - at the width the
+    # run halts with VIOL_ROUTE_OVERFLOW; and the bytes each device
+    # handed to the two all_to_alls over the check, from the static
+    # shapes and the step count (engine.sharded.route_geometry)
+    shard_generated: tuple = None
+    route_max_fill: int = None
+    route_bucket: int = None
+    route_bytes: int = None
+
+
+MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
+                 "route_bucket", "route_bytes")
+
+
+def mesh_counters(result: CheckResult) -> dict:
+    """The mesh engine's counters of a result, as the extra fields of
+    the journal's `final` event; {} for a one-chip result."""
+    return {k: list(v) if isinstance(v, tuple) else v
+            for k in MESH_COUNTERS
+            for v in (getattr(result, k),) if v is not None}
 
 
 def carry_done(carry: EngineCarry) -> bool:

@@ -74,6 +74,7 @@ from ..spec.labels import LABELS
 from .bfs import (
     CheckResult,
     OK,
+    run_steps,
     VIOL_ASSERT,
     VIOL_DEADLOCK,
     VIOL_FPSET_FULL,
@@ -83,7 +84,8 @@ from .bfs import (
     VIOLATION_NAMES,
     outdegree_from_hist,
 )
-from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED, fp64_words
+from . import backend as _backend
+from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
 from .fpset import FPSet, fpset_insert, fpset_member, host_insert
 
 
@@ -153,6 +155,13 @@ class ShardCarry(NamedTuple):
     # exactly like a device-table hit); partials, psum'd at read-out
     # like generated/distinct.
     spill_hits: jnp.ndarray = None  # [D] uint32
+    # --- owner-routing telemetry (no control flow reads it) ------------
+    # column 0: the fullest per-destination bucket this device packed in
+    # any body (its width is route_bucket_width: at that width a
+    # candidate would not fit and the run halts with
+    # VIOL_ROUTE_OVERFLOW); column 1: bodies run, each of which hands
+    # the two all_to_alls their static shapes (route_geometry)
+    route_stat: jnp.ndarray = None  # [D, 2] int32
 
 
 class ShardEx(NamedTuple):
@@ -186,6 +195,7 @@ class ShardEx(NamedTuple):
     s_pos: jnp.ndarray  # [ncand] position within owner bucket
     s_valid: jnp.ndarray  # [ncand] sorted-candidate validity
     route_ovf: jnp.ndarray  # [] bucket overflow anywhere this device
+    route_fill: jnp.ndarray  # [] int32 fullest destination bucket
     r_flat: jnp.ndarray  # [D*B, F] received (owner-side) candidates
     r_lo: jnp.ndarray  # [D*B] uint32 received fp low words
     r_hi: jnp.ndarray  # [D*B] uint32 received fp high words
@@ -203,6 +213,19 @@ def route_bucket_width(chunk: int, n_lanes: int, D: int,
     return ncand if D == 1 else min(
         ncand, int(route_factor * ncand / D) + 8
     )
+
+
+def route_geometry(backend: SpecBackend, chunk: int, D: int,
+                   route_factor: float) -> dict:
+    """What one body hands the two all_to_alls, from the static shapes:
+    `bucket` slots per destination, and `step_bytes` a device - the
+    candidate exchange's [D, B, F + 3] int32 (state words, fingerprint
+    lo and hi, valid) plus the verdict return's [D, B] uint8.  The
+    device's own bucket is in the count: it is packed and handed over
+    like the others and never crosses a link."""
+    B = route_bucket_width(chunk, backend.n_lanes, D, route_factor)
+    return dict(bucket=B,
+                step_bytes=D * B * (backend.cdc.n_fields + 3) * 4 + D * B)
 
 
 def make_sharded_engine(
@@ -236,9 +259,9 @@ def make_sharded_engine(
     a bucket overflow halts with VIOL_ROUTE_OVERFLOW rather than dropping
     a candidate.
 
-    segment > 0 makes run_fn execute exactly `segment` chunk steps (a
-    fused fori_loop; finished engines no-op) instead of running to
-    exhaustion - the checkpointing driver's unit of work.
+    segment > 0 makes run_fn execute up to `segment` chunk steps (one
+    `while`, bfs.run_steps; it ends with the check) instead of running
+    to exhaustion - the checkpointing driver's unit of work.
 
     pipeline=True defers chunk k-1's verdict-return all_to_all into
     chunk k's body: the candidate-routing collective of chunk k is
@@ -335,6 +358,11 @@ def make_sharded_engine(
     # candidates ~2 per popped state at steady load balance, so 4x
     # chunk covers bursts; wider batches take the exact sorted fallback
     SRW = min(4 * chunk, D * B)
+    # the rows of the insert's compacted claimants that one enqueue
+    # segment writes (the insert's own probe width), and how many
+    # segments cover the received batch
+    ENQ_ROWS = SRW if sort_free else D * B
+    ENQ_SEGS = -(-(D * B) // ENQ_ROWS)
     # owner-side deferred invariant checker (ISSUE 15); the segment
     # width mirrors the insert's compaction (SRW under -sort-free, the
     # full received batch on the sorted path whose compacted reps are
@@ -361,7 +389,7 @@ def make_sharded_engine(
             # canonicalizing loses no initial orbit
             inits = sym_plan.canon_host(inits)
         packed = cdc.pack(jnp.asarray(inits))
-        lo, hi = fp64_words(packed, nbits, fp_index, seed)
+        lo, hi = _backend.fp64_words_mxu(packed, nbits, fp_index, seed)
         own = np.asarray(owner_of(hi))
         queue = np.zeros((D, qcap + 1, F), np.int32)
         qtail = np.zeros(D, np.int32)
@@ -430,6 +458,7 @@ def make_sharded_engine(
             viol_state=jnp.zeros((D, F), jnp.int32),
             viol_local=jnp.zeros(D, bool),
             cont=jnp.ones(D, bool),
+            route_stat=jnp.zeros((D, 2), jnp.int32),
             **pv,
             **obs,
         )
@@ -458,114 +487,134 @@ def make_sharded_engine(
         # nothing pending (pv_svalid all false) every update lands in
         # the dump rows, so fill/drain iterations are exact no-ops.
         if pipeline:
-            verd_prev = lax.all_to_all(
-                c.pv_send[0], axis, split_axis=0, concat_axis=0,
-                tiled=False,
-            )
-            p_got = (
-                verd_prev[
-                    jnp.clip(c.pv_sown[0], 0, D - 1),
-                    jnp.clip(c.pv_pos[0], 0, B - 1),
-                ] == 1
-            ) & c.pv_svalid[0] & (c.pv_pos[0] < B)
-            is_new_prev = (
-                jnp.zeros(ncand, bool).at[c.pv_order[0]].set(p_got)
-            )
-            newdeg_prev = is_new_prev.reshape(chunk, L).sum(axis=1)
-            p_mask = jnp.arange(chunk, dtype=jnp.int32) < c.pv_n[0]
-            outdeg_hist0 = c.outdeg_hist[0].at[
-                jnp.where(p_mask, newdeg_prev, L + 1)
-            ].add(1)
-            act_dist0 = c.act_dist[0].at[
-                jnp.where(is_new_prev, c.pv_faction[0], n_labels)
-            ].add(1)
+            with jax.named_scope("jaxtlc.verdict_return"):
+                verd_prev = lax.all_to_all(
+                    c.pv_send[0], axis, split_axis=0, concat_axis=0,
+                    tiled=False,
+                )
+                p_got = (
+                    verd_prev[
+                        jnp.clip(c.pv_sown[0], 0, D - 1),
+                        jnp.clip(c.pv_pos[0], 0, B - 1),
+                    ] == 1
+                ) & c.pv_svalid[0] & (c.pv_pos[0] < B)
+                is_new_prev = (
+                    jnp.zeros(ncand, bool).at[c.pv_order[0]].set(p_got)
+                )
+                newdeg_prev = is_new_prev.reshape(chunk, L).sum(axis=1)
+                p_mask = jnp.arange(chunk, dtype=jnp.int32) < c.pv_n[0]
+                outdeg_hist0 = c.outdeg_hist[0].at[
+                    jnp.where(p_mask, newdeg_prev, L + 1)
+                ].add(1)
+                act_dist0 = c.act_dist[0].at[
+                    jnp.where(is_new_prev, c.pv_faction[0], n_labels)
+                ].add(1)
         else:
             outdeg_hist0 = c.outdeg_hist[0]
             act_dist0 = c.act_dist[0]
 
-        avail = jnp.minimum(level_end, qtail) - qhead
-        # gate on viol so segment-mode no-op iterations leave a halted or
-        # finished engine untouched
-        n = jnp.where(viol == OK, jnp.minimum(chunk, avail), 0)
-        rows = jnp.arange(chunk, dtype=jnp.int32)
-        mask = rows < n
-        idx = (qhead + rows) % qcap
-        batch = queue[idx]
+        # device scopes as bfs.make_stage_pair names them (expand >
+        # pack_fp), plus the mesh's own: route, verdict_return, fence
+        with jax.named_scope("jaxtlc.expand"):
+            avail = jnp.minimum(level_end, qtail) - qhead
+            # gate on viol so a halted or finished engine's body pops
+            # nothing
+            n = jnp.where(viol == OK, jnp.minimum(chunk, avail), 0)
+            rows = jnp.arange(chunk, dtype=jnp.int32)
+            mask = rows < n
+            idx = (qhead + rows) % qcap
+            batch = queue[idx]
 
-        succs, valid, action, afail, ovf = jax.vmap(step)(batch)
-        valid = valid & mask[:, None]
-        afail = afail & valid
-        ovf = ovf & valid
-        dead = (
-            mask & ~valid.any(axis=1) if backend.check_deadlock
-            else jnp.zeros(chunk, bool)
-        )
-        if por_on:
-            # singleton-ample pruning AFTER afail/ovf/dead are taken
-            # from the full valid set: a pruned trapping transition
-            # still halts, and POR never fabricates a deadlock
-            valid = por_keep(valid, backend.lane_action, safe_vec,
-                             n_labels)
+            succs, valid, action, afail, ovf = jax.vmap(step)(batch)
+            valid = valid & mask[:, None]
+            afail = afail & valid
+            ovf = ovf & valid
+            dead = (
+                mask & ~valid.any(axis=1) if backend.check_deadlock
+                else jnp.zeros(chunk, bool)
+            )
+            if por_on:
+                # singleton-ample pruning AFTER afail/ovf/dead are taken
+                # from the full valid set: a pruned trapping transition
+                # still halts, and POR never fabricates a deadlock
+                valid = por_keep(valid, backend.lane_action, safe_vec,
+                                 n_labels)
 
-        flat = succs.reshape(ncand, F)
-        fvalid = valid.reshape(-1)
-        faction = action.reshape(-1)
-        if sym_plan is not None:
-            # canonicalize before invariants/pack/fingerprint: the
-            # invariant sweep sees the orbit representative (sound -
-            # symfind verified the invariants cannot distinguish orbit
-            # members) and owners dedup representatives
-            flat = sym_plan.canon(flat)
+            flat = succs.reshape(ncand, F)
+            fvalid = valid.reshape(-1)
+            faction = action.reshape(-1)
+            if sym_plan is not None:
+                # canonicalize before invariants/pack/fingerprint: the
+                # invariant sweep sees the orbit representative (sound -
+                # symfind verified the invariants cannot distinguish
+                # orbit members) and owners dedup representatives
+                flat = sym_plan.canon(flat)
 
-        # deferred mode skips the pre-routing chunk*L invariant sweep:
-        # the owner checks its fresh-insert claimants below instead
-        inv_bad = []
-        if not deferred:
-            inv = jax.vmap(inv_check)(flat)
-            inv_bad = [
-                fvalid & ((inv & (1 << k)) == 0)
-                for k in range(len(backend.inv_codes))
-            ]
+            # deferred mode skips the pre-routing chunk*L invariant
+            # sweep: the owner checks its fresh-insert claimants below
+            inv_bad = []
+            if not deferred:
+                inv = jax.vmap(inv_check)(flat)
+                inv_bad = [
+                    fvalid & ((inv & (1 << k)) == 0)
+                    for k in range(len(backend.inv_codes))
+                ]
 
-        packed = cdc.pack(flat)
-        lo, hi = fp64_words(packed, nbits, fp_index, seed)
-        own = owner_of(hi)
+            with jax.named_scope("jaxtlc.pack_fp"):
+                packed = cdc.pack(flat)
+                # the backend module's MXU fingerprint, as the one-chip
+                # engines take it (same words as fingerprint.fp64_words)
+                lo, hi = _backend.fp64_words_mxu(
+                    packed, nbits, fp_index, seed)
 
         # ---- route candidates to owners over ICI ----
         # sort by owner, then slice into D contiguous buckets of B slots
         # (B = route_factor * ncand / D: send bytes stay O(ncand) as the
         # mesh grows; overflow halts rather than dropping a candidate)
-        order = jnp.argsort(jnp.where(fvalid, own, D), stable=True)
-        s_flat = flat[order]
-        s_lo, s_hi = lo[order], hi[order]
-        s_own = jnp.where(fvalid, own, D)[order]
-        s_valid = fvalid[order]
-        # position within bucket
-        pos_in_bucket = jnp.arange(ncand) - jnp.searchsorted(
-            s_own, jnp.arange(D + 1), side="left"
-        )[jnp.clip(s_own, 0, D)]
-        route_ovf = (s_valid & (pos_in_bucket >= B)).any()
-        send = jnp.zeros((D, B, F + 3), jnp.int32)
-        payload = jnp.concatenate(
-            [
-                s_flat,
-                s_lo.astype(jnp.int32)[:, None],
-                s_hi.astype(jnp.int32)[:, None],
-                s_valid.astype(jnp.int32)[:, None],
-            ],
-            axis=1,
-        )
-        # invalid/overflow rows scatter out of range (mode="drop"); valid
-        # rows land at (owner bucket, position within bucket)
-        tgt_bucket = jnp.where(s_valid, s_own, D)
-        tgt_pos = jnp.where(s_valid, pos_in_bucket, B)
-        send = send.at[tgt_bucket, tgt_pos].set(payload, mode="drop")
-        recv = lax.all_to_all(send, axis, split_axis=0, concat_axis=0, tiled=False)
-        r = recv.reshape(D * B, F + 3)
-        r_flat = r[:, :F]
-        r_lo = r[:, F].astype(jnp.uint32)
-        r_hi = r[:, F + 1].astype(jnp.uint32)
-        r_valid = r[:, F + 2] == 1
+        with jax.named_scope("jaxtlc.route"):
+            own = owner_of(hi)
+            order = jnp.argsort(jnp.where(fvalid, own, D), stable=True)
+            s_flat = flat[order]
+            s_lo, s_hi = lo[order], hi[order]
+            s_own = jnp.where(fvalid, own, D)[order]
+            s_valid = fvalid[order]
+            # position within bucket
+            starts = jnp.searchsorted(
+                s_own, jnp.arange(D + 1), side="left"
+            )
+            pos_in_bucket = jnp.arange(ncand) - starts[
+                jnp.clip(s_own, 0, D)]
+            route_ovf = (s_valid & (pos_in_bucket >= B)).any()
+            counts = (starts[1:] - starts[:-1]).astype(jnp.int32)
+            # telemetry: the fullest destination bucket of this body
+            route_fill = counts.max()
+            payload = jnp.concatenate(
+                [
+                    s_flat,
+                    s_lo.astype(jnp.int32)[:, None],
+                    s_hi.astype(jnp.int32)[:, None],
+                    s_valid.astype(jnp.int32)[:, None],
+                ],
+                axis=1,
+            )
+            # the owner sort left bucket d's candidates contiguous from
+            # starts[d]: slot (d, p) takes sorted row starts[d] + p
+            # while p is under the bucket's count, and is zero past it.
+            # A row gather; as a scatter of the ncand rows to (owner,
+            # position) this pack was a tenth of a 2x1FF step on the
+            # chip (PERF.md section 5, Step 0 of PR 27)
+            slot = jnp.arange(B, dtype=jnp.int32)
+            src = jnp.clip(starts[:D, None].astype(jnp.int32)
+                           + slot[None, :], 0, ncand - 1)
+            live = slot[None, :] < counts[:, None]
+            send = jnp.where(live[:, :, None], payload[src], 0)
+            recv = lax.all_to_all(send, axis, split_axis=0,
+                                  concat_axis=0, tiled=False)
+            r = recv.reshape(D * B, F + 3)
+            r_flat = r[:, :F]
+            r_lo = r[:, F].astype(jnp.uint32)
+            r_hi = r[:, F + 1].astype(jnp.uint32)
+            r_valid = r[:, F + 2] == 1
 
         if with_member:
             # spill-mode owner filter: bounded membership walk over the
@@ -597,6 +646,7 @@ def make_sharded_engine(
             s_pos=pos_in_bucket,
             s_valid=s_valid,
             route_ovf=route_ovf,
+            route_fill=route_fill,
             r_flat=r_flat,
             r_lo=r_lo,
             r_hi=r_hi,
@@ -658,11 +708,44 @@ def make_sharded_engine(
                                         ins_mask, sort_free=sort_free,
                                         probe_width=SRW)
 
-        n_new = is_new.sum().astype(jnp.int32)
-        q_full = (qtail - qhead) + n_new > qcap
-        pos = qtail + jnp.cumsum(is_new.astype(jnp.int32)) - 1
-        tgt = jnp.where(is_new & ~q_full, pos % qcap, qcap)
-        queue = queue.at[tgt].set(r_flat)
+        with jax.named_scope("jaxtlc.enqueue"):
+            n_new = is_new.sum().astype(jnp.int32)
+            q_full = (qtail - qhead) + n_new > qcap
+            pos = qtail + jnp.cumsum(is_new.astype(jnp.int32)) - 1
+            if deferred:
+                # only the insert's claimants can be new, and they lie
+                # in the first `nreps` rows of (is_new_c, c_idx) in
+                # use: write those rows alone, each at its lane's
+                # position, a probe-width segment at a time (one in
+                # steady state, as the deferred checker walks them).
+                # A scattered row costs the chip ~140 ns live or not,
+                # and all D * B lanes aimed mostly at the dump row were
+                # half of a 2x1FF step (PERF.md section 5, Step 0 of
+                # PR 27)
+                pad = ENQ_SEGS * ENQ_ROWS - D * B
+                seg_idx = jnp.concatenate(
+                    [c_idx, jnp.full(pad, D * B, c_idx.dtype)])
+                seg_new = jnp.concatenate(
+                    [is_new_c, jnp.zeros(pad, bool)])
+
+                def enqueue_segment(st):
+                    k, q = st
+                    lane = jnp.clip(lax.dynamic_slice(
+                        seg_idx, (k * ENQ_ROWS,), (ENQ_ROWS,)),
+                        0, D * B - 1)
+                    new = lax.dynamic_slice(
+                        seg_new, (k * ENQ_ROWS,), (ENQ_ROWS,))
+                    tgt = jnp.where(new & ~q_full,
+                                    pos[lane] % qcap, qcap)
+                    return k + 1, q.at[tgt].set(r_flat[lane])
+
+                _, queue = lax.while_loop(
+                    lambda st: (st[0] * ENQ_ROWS < nreps)
+                    & (st[0] < ENQ_SEGS),
+                    enqueue_segment, (jnp.int32(0), queue))
+            else:
+                tgt = jnp.where(is_new & ~q_full, pos % qcap, qcap)
+                queue = queue.at[tgt].set(r_flat)
 
         # ---- route verdicts back to the source (second all_to_all) ----
         # back[d, p] = is_new of the candidate this device placed in bucket
@@ -674,29 +757,35 @@ def make_sharded_engine(
             outdeg_hist = outdeg_hist0
             act_dist = act_dist0
         else:
-            verd = lax.all_to_all(
-                is_new.reshape(D, B).astype(jnp.uint8),
-                axis, split_axis=0, concat_axis=0, tiled=False,
-            )
-            got_new = (
-                verd[jnp.clip(s_own, 0, D - 1),
-                     jnp.clip(pos_in_bucket, 0, B - 1)]
-                == 1
-            ) & s_valid & (pos_in_bucket < B)
-            is_new_local = jnp.zeros(ncand, bool).at[order].set(got_new)
-            newdeg = is_new_local.reshape(chunk, L).sum(axis=1)
-            outdeg_hist = (
-                outdeg_hist0.at[jnp.where(mask, newdeg, L + 1)].add(1)
-            )
-            act_dist = (
-                act_dist0.at[
-                    jnp.where(is_new_local, faction, n_labels)
-                ].add(1)
-            )
+            with jax.named_scope("jaxtlc.verdict_return"):
+                verd = lax.all_to_all(
+                    is_new.reshape(D, B).astype(jnp.uint8),
+                    axis, split_axis=0, concat_axis=0, tiled=False,
+                )
+                got_new = (
+                    verd[jnp.clip(s_own, 0, D - 1),
+                         jnp.clip(pos_in_bucket, 0, B - 1)]
+                    == 1
+                ) & s_valid & (pos_in_bucket < B)
+                is_new_local = jnp.zeros(ncand, bool).at[order].set(
+                    got_new)
+            with jax.named_scope("jaxtlc.level"):
+                newdeg = is_new_local.reshape(chunk, L).sum(axis=1)
+                outdeg_hist = (
+                    outdeg_hist0.at[
+                        jnp.where(mask, newdeg, L + 1)].add(1)
+                )
+                act_dist = (
+                    act_dist0.at[
+                        jnp.where(is_new_local, faction, n_labels)
+                    ].add(1)
+                )
 
-        generated = c.generated[0] + valid.sum().astype(jnp.uint32)
-        distinct = my_distinct + n_new.astype(jnp.uint32)
-        act_gen = c.act_gen[0].at[jnp.where(fvalid, faction, n_labels)].add(1)
+        with jax.named_scope("jaxtlc.level"):
+            generated = c.generated[0] + valid.sum().astype(jnp.uint32)
+            distinct = my_distinct + n_new.astype(jnp.uint32)
+            act_gen = c.act_gen[0].at[
+                jnp.where(fvalid, faction, n_labels)].add(1)
 
         cov_acc = {}
         if backend.coverage is not None:
@@ -737,20 +826,24 @@ def make_sharded_engine(
         new_viol = jnp.where(
             (new_viol == OK) & route_ovf, VIOL_ROUTE_OVERFLOW, new_viol
         )
-        global_viol = lax.pmax(jnp.where(viol == OK, new_viol, viol), axis)
+        with jax.named_scope("jaxtlc.fence"):
+            global_viol = lax.pmax(
+                jnp.where(viol == OK, new_viol, viol), axis)
         became = (viol == OK) & (new_viol != OK)
         viol_local2 = viol_local | became
         viol_state2 = jnp.where(became, new_vstate, viol_state)
 
         # ---- advance + level fencing (global) ----
         # `adv` gates the level bookkeeping so a halted engine's no-op
-        # iterations (segment mode) cannot inflate level/depth
+        # bodies (the spill runtime's, the drain body) cannot inflate
+        # level/depth
         adv = viol == OK
         qhead = qhead + n
         qtail = jnp.where(q_full, qtail, qtail + n_new)
         rem_in_level = jnp.minimum(level_end, qtail) - qhead
-        total_rem = lax.psum(rem_in_level, axis)
-        total_left = lax.psum(qtail - qhead, axis)
+        with jax.named_scope("jaxtlc.fence"):
+            total_rem = lax.psum(rem_in_level, axis)
+            total_left = lax.psum(qtail - qhead, axis)
         level_done = total_rem == 0
         more = total_left > 0
         level2 = jnp.where(adv & level_done & more, level + 1, level)
@@ -845,6 +938,10 @@ def make_sharded_engine(
                 # count is pure telemetry (SupervisedResult.spill_hits)
                 hits = hits + (veto & r_valid).sum().astype(jnp.uint32)
             sp = dict(spill_hits=hits[None])
+        route_stat = jnp.stack([
+            jnp.maximum(c.route_stat[0, 0], ex.route_fill),
+            c.route_stat[0, 1] + 1,
+        ])
 
         return ShardCarry(
             table=fset.table[None],
@@ -863,6 +960,7 @@ def make_sharded_engine(
             viol_state=viol_state2[None],
             viol_local=viol_local2[None],
             cont=cont[None],
+            route_stat=route_stat[None],
             **pv2,
             **obs2,
             **cov_acc,
@@ -879,9 +977,10 @@ def make_sharded_engine(
         return lax.while_loop(lambda cc: cc.cont[0], body, c)
 
     def device_segment(c: ShardCarry) -> ShardCarry:
-        # fixed iteration count: a finished/halted engine no-ops (n is
-        # gated on viol; an empty queue pops nothing)
-        return lax.fori_loop(0, segment, lambda _, cc: body(cc), c)
+        # up to `segment` bodies, as the one-chip engines' segments
+        # (bfs.run_steps): a finished or halted check leaves the loop
+        # instead of running its last segment out on empty pops
+        return run_steps(lambda cc: cc.cont[0], body, c, segment)
 
     pv_specs = {}
     if pipeline:
@@ -919,6 +1018,7 @@ def make_sharded_engine(
         viol_state=P(axis),
         viol_local=P(axis),
         cont=P(axis),
+        route_stat=P(axis),
         **pv_specs,
     )
     run_fn = jax.jit(
@@ -1301,11 +1401,22 @@ def result_from_shard_carry(
     out: ShardCarry, wall: float, iterations: int = -1,
     labels: tuple = LABELS, viol_names: dict = None,
     fp_capacity_total: int = 0, sites: tuple = None,
+    route: dict = None,
 ) -> CheckResult:
     """Globally-reduced statistics from a (finished or paused) carry.
 
     fp_capacity_total (= per-device fp_capacity * device count) enables
-    the fp_occupancy fraction on the result."""
+    the fp_occupancy fraction on the result; `route` (route_geometry of
+    the engine that ran the carry) the owner-routing counters."""
+    routing = {}
+    if route is not None and getattr(out, "route_stat", None) is not None:
+        stat = np.asarray(out.route_stat)
+        routing = dict(
+            route_max_fill=int(stat[:, 0].max()),
+            route_bucket=int(route["bucket"]),
+            # every device runs every body: column 1 is the same on all
+            route_bytes=int(stat[:, 1].max()) * int(route["step_bytes"]),
+        )
     act_gen = np.asarray(out.act_gen).sum(axis=0)[: len(labels)]
     act_dist = np.asarray(out.act_dist).sum(axis=0)[: len(labels)]
     hist = np.asarray(out.outdeg_hist).sum(axis=0)[:-1].astype(np.int64)
@@ -1348,7 +1459,11 @@ def result_from_shard_carry(
         shard_distinct=tuple(
             int(v) for v in np.asarray(out.distinct).reshape(-1)
         ),
+        shard_generated=tuple(
+            int(v) for v in np.asarray(out.generated).reshape(-1)
+        ),
         site_coverage=site_coverage,
+        **routing,
     )
 
 
@@ -1569,6 +1684,8 @@ def check_sharded(
         out, wall, labels=backend.labels, viol_names=backend.viol_names,
         fp_capacity_total=fp_capacity * mesh.devices.size,
         sites=backend.coverage.sites if backend.coverage else None,
+        route=route_geometry(backend, chunk, int(mesh.devices.size),
+                             route_factor),
     )
 
 
@@ -1603,12 +1720,15 @@ def check_sharded_with_checkpoints(
         backend = kubeapi_backend(cfg)
     sort_free = resolve_sort_free(sort_free, chunk)
     deferred = resolve_deferred(deferred, chunk)
-    init_fn, seg_fn = make_sharded_engine(
+    from ..runtime import aot_build
+
+    # the one AOT build (its `build*` spans), as the supervisor's
+    template, compiled = aot_build(lambda: make_sharded_engine(
         cfg, mesh, chunk, queue_capacity, fp_capacity,
         route_factor=route_factor, segment=ckpt_every, backend=backend,
         pipeline=pipeline, obs_slots=obs_slots, sort_free=sort_free,
         deferred=deferred,
-    )
+    ))
     # the reduction flags ride on the backend; a reduced run explores a
     # DIFFERENT (smaller) frontier, so resuming a reduced checkpoint
     # without the flags (or vice versa) must mismatch loudly
@@ -1626,8 +1746,6 @@ def check_sharded_with_checkpoints(
         symmetry=bool(red is not None and red.plan is not None),
         por=bool(red is not None and red.por and red.safe_ids),
     )
-    template = init_fn()
-    compiled = seg_fn.lower(template).compile()
     t0 = time.time()
     if resume:
         if ckpt_path is None or not os.path.exists(ckpt_path):
@@ -1664,4 +1782,6 @@ def check_sharded_with_checkpoints(
         carry, time.time() - t0, iterations=segments,
         labels=backend.labels, viol_names=backend.viol_names,
         fp_capacity_total=fp_capacity * mesh.devices.size,
+        route=route_geometry(backend, chunk, int(mesh.devices.size),
+                             route_factor),
     )

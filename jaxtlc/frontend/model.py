@@ -24,7 +24,7 @@ import dataclasses
 import os
 from typing import List, Optional
 
-from ..config import ModelConfig
+from ..config import ModelConfig, make_scaled
 from ..engine.fingerprint import DEFAULT_FP_INDEX
 from .launch import LaunchConfig, parse_launch_file
 from .mc_cfg import TLCConfig, parse_cfg_file
@@ -32,6 +32,10 @@ from .mc_tla import eval_constant, parse_mc_tla_file
 
 KNOWN_INVARIANTS = ("TypeOK", "OnlyOneVersion")
 KNOWN_PROPERTIES = ("ReconcileCompletes", "CleansUpProperly")
+# the process set of the hand frontend as two integer constants of an
+# MC.cfg CONSTANT line or of CheckRequest.constants; neither = the
+# source's own Model_1 (one Client, one PVCController)
+SCALING_CONSTANTS = ("N_RECONCILERS", "N_BINDERS")
 
 
 @dataclasses.dataclass
@@ -213,18 +217,34 @@ def resolve(
     if cfg.specification not in (None, "Spec"):
         raise ValueError(f"unsupported SPECIFICATION {cfg.specification!r}")
 
-    def boolify(name: str, default: bool) -> bool:
+    def constant(name: str, default):
         v = consts.get(name, default)
-        if isinstance(v, str):
-            v = eval_constant(v)
+        return eval_constant(v) if isinstance(v, str) else v
+
+    def boolify(name: str, default: bool) -> bool:
+        v = constant(name, default)
         if not isinstance(v, bool):
             raise ValueError(f"constant {name} must be BOOLEAN, got {v!r}")
         return v
 
-    model = ModelConfig(
-        requests_can_fail=boolify("REQUESTS_CAN_FAIL", True),
-        requests_can_timeout=boolify("REQUESTS_CAN_TIMEOUT", True),
-    )
+    def count(name: str) -> int:
+        # a count left out is 1
+        v = constant(name, 1)
+        if isinstance(v, bool) or not isinstance(v, int) or v < 1:
+            raise ValueError(
+                f"constant {name} must be an integer >= 1, got {v!r}")
+        return v
+
+    fail = boolify("REQUESTS_CAN_FAIL", True)
+    timeout = boolify("REQUESTS_CAN_TIMEOUT", True)
+    if any(name in consts for name in SCALING_CONSTANTS):
+        # this repo's scaling rule (config.make_scaled), not the
+        # source's: N copies of `process Client`, M of `PVCController`
+        n, m = (count(name) for name in SCALING_CONSTANTS)
+        model = make_scaled(n, m, fail, timeout)
+    else:
+        model = ModelConfig(requests_can_fail=fail,
+                            requests_can_timeout=timeout)
 
     invariants = [i for i in cfg.invariants if i]
     for inv in invariants:

@@ -313,6 +313,12 @@ def migrate_shard_carry(
         pv["spill_hits"] = jnp.asarray(
             np.asarray(carry.spill_hits), jnp.uint32
         )
+    if getattr(carry, "route_stat", None) is not None:
+        # owner-routing telemetry: the fullest bucket is counted in
+        # candidates, not slots, so it survives a route_factor change
+        pv["route_stat"] = jnp.asarray(
+            np.asarray(carry.route_stat), jnp.int32
+        )
     return ShardCarry(
         table=jnp.asarray(table2),
         queue=jnp.asarray(queue2),

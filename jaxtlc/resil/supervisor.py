@@ -78,6 +78,7 @@ from ..engine.bfs import (
     CheckResult,
     carry_done,
     make_engine,
+    mesh_counters,
     result_from_carry,
 )
 from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
@@ -628,16 +629,20 @@ class ShardedAdapter:
 
     def result(self, carry, wall: float, segments: int,
                params: dict) -> CheckResult:
-        from ..engine.sharded import result_from_shard_carry
+        from ..engine.sharded import (
+            result_from_shard_carry,
+            route_geometry,
+        )
 
+        D = int(self.mesh.devices.size)
         return result_from_shard_carry(
             carry, wall, iterations=segments,
             labels=self.backend.labels,
             viol_names=self.backend.viol_names,
-            fp_capacity_total=(
-                params["fp_capacity"] * int(self.mesh.devices.size)
-            ),
+            fp_capacity_total=params["fp_capacity"] * D,
             sites=self.cov_sites(),
+            route=route_geometry(self.backend, self.chunk, D,
+                                 params["route_factor"]),
         )
 
 
@@ -1278,9 +1283,7 @@ def supervise(adapter, params: dict,
     _emit(opts, "final", verdict=verdict, generated=result.generated,
           distinct=result.distinct, depth=result.depth,
           queue=result.queue_left, wall_s=round(wall, 6),
-          interrupted=interrupted,
-          **({"shard_distinct": list(result.shard_distinct)}
-             if result.shard_distinct is not None else {}))
+          interrupted=interrupted, **mesh_counters(result))
     spill_hits = 0
     if spill_rt is not None and getattr(carry, "spill_hits",
                                         None) is not None:
