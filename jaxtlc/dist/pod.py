@@ -535,8 +535,8 @@ def reshard_carry(carry, backend, d_new: int,
         extra["obs_bodies"] = row0(carry.obs_bodies)
         extra["obs_expanded"] = row0(carry.obs_expanded)
     if getattr(carry, "route_stat", None) is not None:
-        # owner-routing telemetry (fullest bucket, bodies run): the same
-        # maxima on every new row
+        # owner-routing telemetry (fullest bucket, bodies run, insert
+        # segments run): the same maxima on every new row
         stat = np.asarray(carry.route_stat)
         extra["route_stat"] = np.tile(stat.max(axis=0), (d_new, 1))
     return ShardCarry(

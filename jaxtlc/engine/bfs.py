@@ -224,15 +224,21 @@ class CheckResult(NamedTuple):
     # packed in any step beside the bucket's width - at the width the
     # run halts with VIOL_ROUTE_OVERFLOW; and the bytes each device
     # handed to the two all_to_alls over the check, from the static
-    # shapes and the step count (engine.sharded.route_geometry)
+    # shapes and the step count (engine.sharded.route_geometry); and
+    # the segments of `commit_rows` compacted candidates each device's
+    # owner-side insert ran over the check (a body runs as many as
+    # what it received needs: none for nothing, one in the common case)
     shard_generated: tuple = None
     route_max_fill: int = None
     route_bucket: int = None
     route_bytes: int = None
+    commit_segments: tuple = None
+    commit_rows: int = None
 
 
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
-                 "route_bucket", "route_bytes")
+                 "route_bucket", "route_bytes", "commit_segments",
+                 "commit_rows")
 
 
 def mesh_counters(result: CheckResult) -> dict:

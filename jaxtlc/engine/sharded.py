@@ -86,7 +86,12 @@ from .bfs import (
 )
 from . import backend as _backend
 from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
-from .fpset import FPSet, fpset_insert, fpset_member, host_insert
+from .fpset import (
+    FPSet,
+    fpset_insert_dedup,
+    fpset_member,
+    host_insert,
+)
 
 
 # the frontend -> engine seam now lives in engine.backend (shared with
@@ -160,8 +165,10 @@ class ShardCarry(NamedTuple):
     # any body (its width is route_bucket_width: at that width a
     # candidate would not fit and the run halts with
     # VIOL_ROUTE_OVERFLOW); column 1: bodies run, each of which hands
-    # the two all_to_alls their static shapes (route_geometry)
-    route_stat: jnp.ndarray = None  # [D, 2] int32
+    # the two all_to_alls their static shapes (route_geometry);
+    # column 2: segments this device's owner-side insert has run
+    # (commit_width rows each: over column 1, the trips a body)
+    route_stat: jnp.ndarray = None  # [D, 3] int32
 
 
 class ShardEx(NamedTuple):
@@ -215,6 +222,132 @@ def route_bucket_width(chunk: int, n_lanes: int, D: int,
     )
 
 
+def commit_width(chunk: int, D: int, bucket: int) -> int:
+    """Rows of one segment of the owner-side insert: the received
+    candidates are inserted as a compacted stream, this many at a time
+    (insert_compacted).  One per-device chunk: on the chip a gathered,
+    scattered or sorted row costs the same live or dead, so what a
+    body's insert and enqueue cost follows their rows, trips x width,
+    and a narrow segment wastes the least of its last trip; the trips
+    themselves cost nothing that shows down to half a chunk (PERF.md
+    section 6, PR 28: a 2x1FF body that received 18k candidates takes
+    19.8 ms at 4 x chunk, 9.2 at one chunk, 8.7 at half)."""
+    return min(chunk, D * bucket)
+
+
+def compact_lanes(cnt, j, bucket: int):
+    """Received lanes of the compacted positions `j` (int32, any
+    shape): the send pack turned round.  After the candidate
+    all_to_all, bucket d of the received [D, bucket] batch holds its
+    cnt[d] live rows as a prefix (the send pack fills slot (d, p) while
+    p < counts[d]), so the compacted stream is those prefixes end to
+    end, in lane order.  Position j lies past every bucket whose
+    prefix ends at or before it, and each of those adds its unused
+    tail, bucket - cnt[d], to the lane; a position at or past the total
+    gives the out-of-range lane D * bucket.  Index arithmetic only."""
+    ends = jnp.cumsum(cnt)
+    tails = bucket - cnt
+    skip = jnp.where(j[..., None] >= ends[:-1], tails[:-1], 0).sum(-1)
+    return jnp.where(j < ends[-1], j + skip, cnt.shape[0] * bucket)
+
+
+def compact_rows(padded, cnt, start, width: int):
+    """Elements [start, start + width) of the compacted stream of a
+    received [D * bucket] array (compact_lanes' order; zero past the
+    total), without a gather: bucket d's live prefix is contiguous, so
+    the part of the segment that lies in it is one dynamic slice, and
+    a select per bucket puts the D of them together.  The slice for
+    bucket d starts at lane d * bucket - first[d] + start: never
+    negative (no earlier bucket holds more than `bucket` rows), and
+    below D * bucket wherever the segment reads it, so with `width`
+    zeros behind the array (`padded`) no slice that is read is
+    clamped."""
+    (D,) = cnt.shape
+    bucket = (padded.shape[0] - width) // D
+    ends = jnp.cumsum(cnt)
+    first = ends - cnt
+    j = start + jnp.arange(width, dtype=jnp.int32)
+    out = jnp.zeros(width, padded.dtype)
+    for d in range(D):
+        piece = lax.dynamic_slice(
+            padded, (d * bucket - first[d] + start,), (width,))
+        out = jnp.where((j >= first[d]) & (j < ends[d]), piece, out)
+    return out
+
+
+def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int,
+                     sort_free: bool):
+    """The owner-side insert of one body: the received candidates as a
+    compacted stream (compact_lanes over the per-bucket live counts
+    `cnt` [D]), `width` rows a segment behind a trip count - no
+    conditional holds the table - so every gather, scatter and sort of
+    the insert is `width` lanes wide, not D * B, and a body that
+    received nothing inserts nothing.  r_lo / r_hi / ins_mask are
+    [D * B]; the state rows are not touched.
+
+    The HIGHEST segment goes first: a fingerprint that lies in two
+    segments is new in the higher one and found in the table by the
+    lower.  is_new therefore marks each new fingerprint's highest
+    received lane, as one insert over all D * B lanes does (the dedup's
+    pinned representative rule), and everything read from it - queue
+    rows and their order, act_dist, outdeg_hist, the deferred checker's
+    violating lane - is that insert's bit for bit.  The table holds the
+    same fingerprints; where two segments claim slots of one bucket,
+    their order inside it is the segments' and not the fingerprints'.
+    A body of one segment is the one insert exactly, table included:
+    compaction keeps lane order.
+
+    Returns (table, is_new [D * B], c_lane, c_new, c_rows, trips): the
+    claimants of every segment end to end, each segment's up to its
+    last new row - received lane (D * B on the rows between) and
+    verdict, `c_rows` rows in use of a whole number of segments - for
+    the enqueue and the deferred checker, and the segments run."""
+    (D,) = cnt.shape
+    DB = r_lo.shape[0]
+    bucket = DB // D
+    trips = (cnt.sum() + (width - 1)) // width
+    row = jnp.arange(width, dtype=jnp.int32)
+    p_lo, p_hi, p_mask = (
+        jnp.concatenate([a, jnp.zeros(width, a.dtype)])
+        for a in (r_lo, r_hi, ins_mask))
+
+    def insert_segment(st):
+        k, table, is_new, c_lane, c_new, used = st
+        # device scope of the compaction: the segment's words, and the
+        # claimants mapped back to received lanes
+        with jax.named_scope("jaxtlc.compact"):
+            lo_k, hi_k, mask_k = (
+                compact_rows(a, cnt, k * width, width)
+                for a in (p_lo, p_hi, p_mask))
+        fset, new_k, idx_k, _ = fpset_insert_dedup(
+            FPSet(table), lo_k, hi_k, mask_k,
+            probe_width=width if sort_free else 0, sort_free=sort_free,
+        )
+        with jax.named_scope("jaxtlc.compact"):
+            # the slab pads its claimants with the out-of-range row
+            # `width`
+            lane_k = jnp.where(
+                idx_k < width,
+                compact_lanes(cnt, k * width + idx_k, bucket), DB)
+            return (
+                k - 1, fset.table,
+                is_new.at[lane_k].set(new_k, mode="drop"),
+                lax.dynamic_update_slice(c_lane, lane_k, (used,)),
+                lax.dynamic_update_slice(c_new, new_k, (used,)),
+                used + jnp.max(jnp.where(new_k, row + 1, 0)),
+            )
+
+    # the claimant buffers hold every received lane and one segment's
+    # overrun, in whole segments
+    cap = (-(-DB // width) + 1) * width
+    _, table, is_new, c_lane, c_new, c_rows = lax.while_loop(
+        lambda st: st[0] >= 0, insert_segment,
+        (trips - 1, table, jnp.zeros(DB, bool),
+         jnp.full(cap, DB, jnp.int32), jnp.zeros(cap, bool),
+         jnp.int32(0)))
+    return table, is_new, c_lane, c_new, c_rows, trips
+
+
 def route_geometry(backend: SpecBackend, chunk: int, D: int,
                    route_factor: float) -> dict:
     """What one body hands the two all_to_alls, from the static shapes:
@@ -222,10 +355,12 @@ def route_geometry(backend: SpecBackend, chunk: int, D: int,
     candidate exchange's [D, B, F + 3] int32 (state words, fingerprint
     lo and hi, valid) plus the verdict return's [D, B] uint8.  The
     device's own bucket is in the count: it is packed and handed over
-    like the others and never crosses a link."""
+    like the others and never crosses a link.  `commit_rows` is the
+    width of one segment of the owner-side insert (commit_width)."""
     B = route_bucket_width(chunk, backend.n_lanes, D, route_factor)
     return dict(bucket=B,
-                step_bytes=D * B * (backend.cdc.n_fields + 3) * 4 + D * B)
+                step_bytes=D * B * (backend.cdc.n_fields + 3) * 4 + D * B,
+                commit_rows=commit_width(chunk, D, B))
 
 
 def make_sharded_engine(
@@ -281,27 +416,42 @@ def make_sharded_engine(
     documented lag, since fixed; the deferred-row leaves on ShardCarry
     carry the staged flip across the body boundary).
 
+    The owner-side insert (insert_compacted) takes what a body
+    received, not the D*B bucket slots it arrived in: bucket d of the
+    received batch holds its live rows as a prefix, so the candidates
+    are inserted as a compacted stream, commit_width (= one per-device
+    chunk) rows a segment behind a trip count; every gather, scatter
+    and sort of the insert, and the enqueue and deferred checker that
+    walk its claimants, are that wide.  The highest segment goes first,
+    which keeps the dedup's highest-lane representative across
+    segments: counts, queue rows, per-action and outdegree statistics
+    are bit-for-bit those of one insert over all D*B lanes, and the
+    table holds the same fingerprints (slot order inside a bucket may
+    differ where two segments claim in it).  `commit_segments` (per
+    device, carry leaf route_stat[:, 2]) and `commit_rows` on the
+    result and in the journal's `final` event say how often it ran.
+
     sort_free (tri-state, resolved against the PER-DEVICE chunk by
     bfs.resolve_sort_free) takes the hash-slab dedup on the owner-side
     insert - the all_to_all routing argsort is untouched (it orders by
-    OWNER, not fingerprint; a different problem than dedup).  The
-    owner-side received batch is D*B wide but carries ~2 valid
-    candidates per popped state, so the slab compaction runs at ~4x
-    chunk rows; results are bit-for-bit the sorted engine's.
+    OWNER, not fingerprint; a different problem than dedup).  Each
+    segment's slab, claimant compaction and probe run at the segment's
+    width (which is also its probe width, so no segment can take the
+    sorted fallback); results are bit-for-bit the sorted engine's.
 
     deferred (tri-state, resolved against the PER-DEVICE chunk by
     bfs.resolve_deferred) moves invariant evaluation OWNER-SIDE and
     POST-ROUTING (ISSUE 15): instead of every source device sweeping
     all chunk*L generated candidates pre-routing, the owner checks
     only the fresh-insert claimants of its received batch, compacted
-    by the same insert it already pays (backend.make_deferred_checker
-    - ~4x chunk rows under -sort-free).  Counts, depth and table
-    words are bit-for-bit; the violating STATE is then captured on
-    the owner device under the pinned highest-lane rule instead of on
-    the generating source (the viol_local machinery is device-
-    agnostic either way).  The mesh engine has no certificate column,
-    so the checker runs invariants only - exactly like the immediate
-    mesh body, which never called cert_check either.
+    by the same insert it already pays (backend.make_deferred_checker,
+    a commit_width segment of them at a time).  Counts, depth and the
+    table's fingerprints are bit-for-bit; the violating STATE is then
+    captured on the owner device under the pinned highest-lane rule
+    instead of on the generating source (the viol_local machinery is
+    device-agnostic either way).  The mesh engine has no certificate
+    column, so the checker runs invariants only - exactly like the
+    immediate mesh body, which never called cert_check either.
     """
     from ..obs.counters import (
         pack_row,
@@ -354,26 +504,18 @@ def make_sharded_engine(
         safe_vec = jnp.asarray(np.array(
             [a in red.safe_ids for a in range(n_labels)], bool
         ))
-    # slab compaction width of the owner-side insert: received valid
-    # candidates ~2 per popped state at steady load balance, so 4x
-    # chunk covers bursts; wider batches take the exact sorted fallback
-    SRW = min(4 * chunk, D * B)
-    # the rows of the insert's compacted claimants that one enqueue
-    # segment writes (the insert's own probe width), and how many
-    # segments cover the received batch
-    ENQ_ROWS = SRW if sort_free else D * B
-    ENQ_SEGS = -(-(D * B) // ENQ_ROWS)
-    # owner-side deferred invariant checker (ISSUE 15); the segment
-    # width mirrors the insert's compaction (SRW under -sort-free, the
-    # full received batch on the sorted path whose compacted reps are
-    # not probe-width bounded)
+    # the owner inserts what it received as a compacted stream, W rows
+    # at a time (commit_half); W is also the width the enqueue and the
+    # deferred checker walk the insert's claimants at
+    DB = D * B
+    W = commit_width(chunk, D, B)
+    # owner-side deferred invariant checker (ISSUE 15)
     checker = None
     if deferred and backend.inv_codes:
         from .backend import make_deferred_checker
 
         checker = make_deferred_checker(
-            backend, D * B, probe_width=SRW if sort_free else 0,
-            with_cert=False,
+            backend, DB, probe_width=W, with_cert=False,
         )
 
     def owner_of(hi):
@@ -458,7 +600,7 @@ def make_sharded_engine(
             viol_state=jnp.zeros((D, F), jnp.int32),
             viol_local=jnp.zeros(D, bool),
             cont=jnp.ones(D, bool),
-            route_stat=jnp.zeros((D, 2), jnp.int32),
+            route_stat=jnp.zeros((D, 3), jnp.int32),
             **pv,
             **obs,
         )
@@ -610,7 +752,7 @@ def make_sharded_engine(
             send = jnp.where(live[:, :, None], payload[src], 0)
             recv = lax.all_to_all(send, axis, split_axis=0,
                                   concat_axis=0, tiled=False)
-            r = recv.reshape(D * B, F + 3)
+            r = recv.reshape(DB, F + 3)
             r_flat = r[:, :F]
             r_lo = r[:, F].astype(jnp.uint32)
             r_hi = r[:, F + 1].astype(jnp.uint32)
@@ -624,7 +766,7 @@ def make_sharded_engine(
             member = fpset_member(FPSet(table), r_lo, r_hi, r_valid,
                                   max_rounds=SPILL_MEMBER_ROUNDS)
         else:
-            member = jnp.zeros(D * B, bool)
+            member = jnp.zeros(DB, bool)
 
         return ShardEx(
             outdeg0=outdeg_hist0,
@@ -685,67 +827,35 @@ def make_sharded_engine(
             fp_full = jnp.bool_(False)
             ins_mask = r_valid & ~veto
         else:
-            fp_full = (my_distinct.astype(jnp.int32) + D * B) > int(
+            fp_full = (my_distinct.astype(jnp.int32) + DB) > int(
                 fp_capacity * fp_highwater
             )
             ins_mask = r_valid & ~fp_full
-        if deferred:
-            # same computation fpset_insert performs, with the
-            # compacted (is_new_c, c_idx, nreps) kept for the
-            # owner-side deferred checker (bit-identical is_new)
-            from .fpset import fpset_insert_dedup
-
-            fset, is_new_c, c_idx, nreps = fpset_insert_dedup(
-                FPSet(table), r_lo, r_hi, ins_mask,
-                probe_width=SRW if sort_free else 0,
-                sort_free=sort_free,
-            )
-            is_new = jnp.zeros(D * B, bool).at[c_idx].set(
-                is_new_c, mode="drop"
-            )
-        else:
-            fset, is_new = fpset_insert(FPSet(table), r_lo, r_hi,
-                                        ins_mask, sort_free=sort_free,
-                                        probe_width=SRW)
+        cnt = r_valid.reshape(D, B).sum(axis=1).astype(jnp.int32)
+        table, is_new, c_lane, c_new, c_rows, trips = insert_compacted(
+            table, r_lo, r_hi, ins_mask, cnt, W, sort_free)
 
         with jax.named_scope("jaxtlc.enqueue"):
             n_new = is_new.sum().astype(jnp.int32)
             q_full = (qtail - qhead) + n_new > qcap
             pos = qtail + jnp.cumsum(is_new.astype(jnp.int32)) - 1
-            if deferred:
-                # only the insert's claimants can be new, and they lie
-                # in the first `nreps` rows of (is_new_c, c_idx) in
-                # use: write those rows alone, each at its lane's
-                # position, a probe-width segment at a time (one in
-                # steady state, as the deferred checker walks them).
-                # A scattered row costs the chip ~140 ns live or not,
-                # and all D * B lanes aimed mostly at the dump row were
-                # half of a 2x1FF step (PERF.md section 5, Step 0 of
-                # PR 27)
-                pad = ENQ_SEGS * ENQ_ROWS - D * B
-                seg_idx = jnp.concatenate(
-                    [c_idx, jnp.full(pad, D * B, c_idx.dtype)])
-                seg_new = jnp.concatenate(
-                    [is_new_c, jnp.zeros(pad, bool)])
 
-                def enqueue_segment(st):
-                    k, q = st
-                    lane = jnp.clip(lax.dynamic_slice(
-                        seg_idx, (k * ENQ_ROWS,), (ENQ_ROWS,)),
-                        0, D * B - 1)
-                    new = lax.dynamic_slice(
-                        seg_new, (k * ENQ_ROWS,), (ENQ_ROWS,))
-                    tgt = jnp.where(new & ~q_full,
-                                    pos[lane] % qcap, qcap)
-                    return k + 1, q.at[tgt].set(r_flat[lane])
+            # only the insert's claimants can be new: write those rows
+            # alone, each at its lane's position, W at a time.  A
+            # scattered row costs the chip ~140 ns live or not, and all
+            # D * B lanes aimed mostly at the dump row were half of a
+            # 2x1FF step (PERF.md section 5, Step 0 of PR 27)
+            def enqueue_segment(st):
+                k, q = st
+                lane = jnp.minimum(
+                    lax.dynamic_slice(c_lane, (k * W,), (W,)), DB - 1)
+                new = lax.dynamic_slice(c_new, (k * W,), (W,))
+                tgt = jnp.where(new & ~q_full, pos[lane] % qcap, qcap)
+                return k + 1, q.at[tgt].set(r_flat[lane])
 
-                _, queue = lax.while_loop(
-                    lambda st: (st[0] * ENQ_ROWS < nreps)
-                    & (st[0] < ENQ_SEGS),
-                    enqueue_segment, (jnp.int32(0), queue))
-            else:
-                tgt = jnp.where(is_new & ~q_full, pos % qcap, qcap)
-                queue = queue.at[tgt].set(r_flat)
+            _, queue = lax.while_loop(
+                lambda st: st[0] * W < c_rows, enqueue_segment,
+                (jnp.int32(0), queue))
 
         # ---- route verdicts back to the source (second all_to_all) ----
         # back[d, p] = is_new of the candidate this device placed in bucket
@@ -805,7 +915,7 @@ def make_sharded_engine(
             # no action ids - violation_action stays -1, as the
             # sharded result always reports)
             d_viol, d_state, _d_act, _d_cert = checker(
-                r_flat, None, is_new_c, c_idx, nreps
+                r_flat, None, c_new[:DB], c_lane[:DB], c_rows
             )
             hit = d_viol != OK
             new_viol = jnp.where(hit, d_viol, new_viol)
@@ -941,10 +1051,11 @@ def make_sharded_engine(
         route_stat = jnp.stack([
             jnp.maximum(c.route_stat[0, 0], ex.route_fill),
             c.route_stat[0, 1] + 1,
+            c.route_stat[0, 2] + trips,
         ])
 
         return ShardCarry(
-            table=fset.table[None],
+            table=table[None],
             queue=queue[None],
             qhead=qhead[None],
             qtail=qtail[None],
@@ -1416,6 +1527,8 @@ def result_from_shard_carry(
             route_bucket=int(route["bucket"]),
             # every device runs every body: column 1 is the same on all
             route_bytes=int(stat[:, 1].max()) * int(route["step_bytes"]),
+            commit_segments=tuple(int(v) for v in stat[:, 2]),
+            commit_rows=int(route["commit_rows"]),
         )
     act_gen = np.asarray(out.act_gen).sum(axis=0)[: len(labels)]
     act_dist = np.asarray(out.act_dist).sum(axis=0)[: len(labels)]

@@ -3,6 +3,7 @@ the hand frontend's process set as two integer constants, api.run_check
 -sharded 4 on a scaled rung against the benchmark's plain reference, the
 shards of the fingerprint space, and the owner-routing counters."""
 
+import contextlib
 import hashlib
 import io
 import json
@@ -15,7 +16,12 @@ import pytest
 
 from jaxtlc.api import CheckRequest, run_check
 from jaxtlc.config import ModelConfig, make_scaled
+from jaxtlc.engine.backend import kubeapi_backend
+from jaxtlc.engine import sharded
 from jaxtlc.engine.sharded import (
+    commit_width,
+    compact_lanes,
+    compact_rows,
     make_sharded_engine,
     result_from_shard_carry,
     route_bucket_width,
@@ -140,6 +146,14 @@ def test_journal_records_the_process_set_and_the_counts(
     assert (final["route_max_fill"], final["route_bucket"],
             final["route_bytes"]) == (
         r.route_max_fill, r.route_bucket, r.route_bytes)
+    # the owner-side insert's segments: a chunk of rows each, as many a
+    # body as what a device received needs (none, one, at most two at
+    # this rung's ~2 candidates a popped state)
+    assert final["commit_rows"] == r.commit_rows == GEOM["chunk"]
+    assert final["commit_segments"] == list(r.commit_segments)
+    bodies = r.route_bytes // route_geometry(
+        kubeapi_backend(FF), GEOM["chunk"], 4, 2.0)["step_bytes"]
+    assert all(0 < s <= 2 * bodies for s in r.commit_segments)
 
 
 # -- (c) the shares add up ------------------------------------------------
@@ -169,9 +183,20 @@ def mesh_run():
     return carry, segments
 
 
-def test_shards_partition_the_one_chip_table(mesh_run):
+@pytest.fixture(scope="module")
+def one_chip_fps():
+    """The fingerprints the one-chip engine's table holds after the
+    same check."""
     from jaxtlc.engine.bfs import make_engine
 
+    init_fn, run_fn, _ = make_engine(FF, chunk=128,
+                                     queue_capacity=1 << 13,
+                                     fp_capacity=1 << 15)
+    single = jax.block_until_ready(run_fn(init_fn()))
+    return _raw_fps(single.fps.table)
+
+
+def test_shards_partition_the_one_chip_table(mesh_run, one_chip_fps):
     carry, _ = mesh_run
     shards = [_raw_fps(carry.table[d]) for d in range(4)]
     assert [len(s) for s in shards] == [
@@ -181,23 +206,21 @@ def test_shards_partition_the_one_chip_table(mesh_run):
     assert len(union) == 8203  # disjoint
     for d, s in enumerate(shards):  # each fingerprint at its owner
         assert all(hi & 3 == d for _, hi in s)
-    init_fn, run_fn, _ = make_engine(FF, chunk=128,
-                                     queue_capacity=1 << 13,
-                                     fp_capacity=1 << 15)
-    single = jax.block_until_ready(run_fn(init_fn()))
-    assert union == _raw_fps(single.fps.table)
+    assert union == one_chip_fps
 
 
 # -- (d) the routing counters ---------------------------------------------
 
 
-def carry_digest(c, qcap: int) -> str:
+def carry_digest(c, qcap: int, table: bool = True) -> str:
     """What a check leaves behind, less the dump rows and bins that a
     body writes whether or not it pops (tests/test_mesh_cell.py pins the
-    parent engine's digest with it)."""
+    parent engine's digest with it); `table=False` leaves the table's
+    words out."""
     h = hashlib.sha256()
-    for name in ("table", "generated", "distinct", "depth", "level",
-                 "qhead", "qtail", "viol"):
+    for name in (("table",) if table else ()) + (
+            "generated", "distinct", "depth", "level", "qhead", "qtail",
+            "viol"):
         h.update(np.ascontiguousarray(
             np.asarray(getattr(c, name))).tobytes())
     for name in ("act_gen", "act_dist", "outdeg_hist"):
@@ -212,31 +235,66 @@ def carry_digest(c, qcap: int) -> str:
 # carry_digest above: fused loop and 16-step segments alike
 PARENT_DIGEST = (
     "4142a18eacd18e5acd01b68649707a98b517141b0dbb981f5ce3758e37573e7a")
+# the same less the table's words (9bca0db, PR 27's engine: one insert
+# over all D x B received lanes); sorted and wide paths alike
+PARENT_DIGEST_LESS_TABLE = (
+    "6e6ca3fbac083d5103dc07096f006006d08356f272c91a0a7364b3bbc6256fd9")
+# the paths a 16384-wide chunk takes on the chip (sort-free slab,
+# owner-side deferred invariants), forced at chunk 128
+WIDE = dict(sort_free=True, deferred=True)
+
+
+@contextlib.contextmanager
+def segments_of(width: int):
+    """Engines built inside take `width` rows an insert segment: the
+    width is a function of the geometry and not an option, so the test
+    swaps the function while the engine is built."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sharded, "commit_width", lambda chunk, D, B: width)
+        yield
+
+
+def engine_at_width(width: int, **kw):
+    with segments_of(width):
+        return make_sharded_engine(FF, fp_mesh(4), **GEOM, **kw)
 
 
 def test_results_with_counters_are_the_parents_bit_for_bit(mesh_run):
+    """Counts, queue rows and their order, per-action and outdegree
+    statistics: the parent's, bit for bit, though some bodies of this
+    run take two insert segments (the table's fingerprints, slot order
+    aside: test_shards_partition_the_one_chip_table)."""
     carry, segments = mesh_run
-    assert carry_digest(carry, GEOM["queue_capacity"]) == PARENT_DIGEST
+    assert carry_digest(carry, GEOM["queue_capacity"], table=False
+                        ) == PARENT_DIGEST_LESS_TABLE
     # a finished check leaves its last segment: 109 levels of at most
     # one body per level here, so fewer than 16 x segments bodies
-    bodies = int(np.asarray(carry.route_stat)[:, 1].max())
-    assert 16 * (segments - 1) < bodies < 16 * segments
+    stat = np.asarray(carry.route_stat)
+    assert 16 * (segments - 1) < stat[0, 1] < 16 * segments
+    assert (stat[:, 2] > stat[:, 1]).any()
 
 
 def test_wide_chunk_paths_are_the_parents_bit_for_bit():
-    """The paths a 16384-wide chunk takes on the chip (sort-free slab,
-    owner-side deferred invariants, the enqueue of the compacted
-    claimants alone), forced here at chunk 128."""
-    init_fn, run_fn = make_sharded_engine(
-        FF, fp_mesh(4), sort_free=True, deferred=True, **GEOM)
+    init_fn, run_fn = make_sharded_engine(FF, fp_mesh(4), **WIDE, **GEOM)
+    carry = jax.block_until_ready(run_fn(init_fn()))
+    assert carry_digest(carry, GEOM["queue_capacity"], table=False
+                        ) == PARENT_DIGEST_LESS_TABLE
+
+
+@pytest.mark.parametrize("kw", [{}, WIDE], ids=["sorted", "wide"])
+def test_one_segment_a_body_leaves_the_parents_table_too(kw):
+    """Where a body's candidates fit one segment (512 rows hold any of
+    this run's), compaction keeps lane order and the insert is the
+    parent's one insert exactly: the table's words as well."""
+    init_fn, run_fn = engine_at_width(512, **kw)
     carry = jax.block_until_ready(run_fn(init_fn()))
     assert carry_digest(carry, GEOM["queue_capacity"]) == PARENT_DIGEST
+    stat = np.asarray(carry.route_stat)
+    assert (stat[:, 2] <= stat[:, 1]).all()
 
 
 def test_route_counters_against_the_hand_count(mesh_run):
     carry, _ = mesh_run
-    from jaxtlc.engine.backend import kubeapi_backend
-
     be = kubeapi_backend(FF)
     L, F = be.n_lanes, be.cdc.n_fields
     # by hand: 128 x L candidates a body over 4 owners at factor 2.0
@@ -244,7 +302,8 @@ def test_route_counters_against_the_hand_count(mesh_run):
     assert route_bucket_width(128, L, 4, 2.0) == B
     step_bytes = 4 * B * (F + 3) * 4 + 4 * B
     geo = route_geometry(be, 128, 4, 2.0)
-    assert geo == dict(bucket=B, step_bytes=step_bytes)
+    # one insert segment: a chunk of compacted candidates
+    assert geo == dict(bucket=B, step_bytes=step_bytes, commit_rows=128)
     r = result_from_shard_carry(carry, 1.0, route=geo)
     stat = np.asarray(carry.route_stat)
     assert (stat[:, 1] == stat[0, 1]).all()  # every device, every body
@@ -255,3 +314,185 @@ def test_route_counters_against_the_hand_count(mesh_run):
     # without the geometry the result carries no routing counters
     bare = result_from_shard_carry(carry, 1.0)
     assert bare.route_bytes is None and bare.route_max_fill is None
+
+
+# -- (e) the owner-side insert, a segment of compacted candidates at a time
+
+
+def hand_lanes(cnt, bucket):
+    """The received lanes that hold a candidate, in lane order: bucket
+    d's first cnt[d] slots."""
+    return [d * bucket + p for d, n in enumerate(cnt) for p in range(n)]
+
+
+# (live rows a bucket, bucket width, segment width)
+COMPACT_CASES = [
+    ([3, 5, 2, 4], 8, 4),   # prefixes of every length, 14 = 3.5 widths
+    ([0, 6, 0, 1], 8, 4),   # empty buckets, the first among them
+    ([2, 8, 8, 0], 8, 6),   # buckets filled to the brim; 18 = 3 widths
+    ([0, 0, 0, 0], 8, 4),   # nothing received
+    ([4, 4, 4, 4], 4, 16),  # everything received, one segment, exactly
+    ([7], 9, 4),            # one device: one bucket
+]
+
+
+@pytest.mark.parametrize("cnt,bucket,width", COMPACT_CASES)
+def test_compact_lanes_against_the_hand_count(cnt, bucket, width):
+    want = hand_lanes(cnt, bucket)
+    dead = len(cnt) * bucket
+    trips = -(-len(want) // width)
+    got = []
+    for k in range(trips + 1):  # one segment past the last: all dead
+        j = k * width + np.arange(width, dtype=np.int32)
+        got += np.asarray(compact_lanes(
+            np.asarray(cnt, np.int32), j, bucket)).tolist()
+    assert got == want + [dead] * (len(got) - len(want))
+    # any shape of positions, as the insert maps its claimants back
+    j2 = np.arange(2 * width, dtype=np.int32).reshape(2, width)
+    assert np.asarray(compact_lanes(
+        np.asarray(cnt, np.int32), j2, bucket)).reshape(-1).tolist() == (
+        (want + [dead] * 2 * width)[:2 * width])
+
+
+def test_commit_width_is_a_chunk_or_all_that_arrives():
+    assert commit_width(16384, 4, 98312) == 16384  # the four-chip cell
+    assert commit_width(1024, 4, 6152) == 1024     # chip_smoke's leg
+    assert commit_width(128, 1, 100) == 100        # never past D x B
+
+
+@pytest.mark.parametrize("cnt,bucket,width", COMPACT_CASES)
+def test_compact_rows_are_the_gather_at_compact_lanes(cnt, bucket, width):
+    """The segment's words by slices and selects against the plain
+    gather, zeros past the total; every segment and one beyond."""
+    D = len(cnt)
+    arr = np.arange(1, D * bucket + 1, dtype=np.uint32)
+    padded = np.concatenate([arr, np.zeros(width, np.uint32)])
+    c = np.asarray(cnt, np.int32)
+    for k in range(-(-sum(cnt) // width) + 1):
+        j = k * width + np.arange(width, dtype=np.int32)
+        lane = np.asarray(compact_lanes(c, j, bucket))
+        want = np.where(lane < D * bucket,
+                        arr[np.minimum(lane, D * bucket - 1)], 0)
+        got = np.asarray(compact_rows(
+            padded, c, np.int32(k * width), width))
+        assert got.tolist() == want.tolist()
+
+
+NARROW = 16  # rows a segment, where a body of this rung receives ~40
+
+
+@pytest.fixture(scope="module", params=[{}, WIDE], ids=["sorted", "wide"])
+def narrow_run(request):
+    init_fn, run_fn = engine_at_width(NARROW, **request.param)
+    return jax.block_until_ready(run_fn(init_fn()))
+
+
+def test_many_segments_a_body_give_the_same_check(
+        narrow_run, mesh_run, one_chip_fps, reference_1x1):
+    carry, ref = narrow_run, reference_1x1
+    r = result_from_shard_carry(
+        carry, 1.0, labels=kubeapi_backend(FF).labels)
+    assert (r.generated, r.distinct, r.depth, r.queue_left, r.violation
+            ) == (ref.generated, ref.distinct, ref.depth, 0, 0)
+    assert {k: v for k, v in r.action_generated.items() if v} == dict(
+        ref.action_generated)
+    # the table holds the one-chip engine's fingerprints, each at its
+    # owner and each counted new once
+    shards = [_raw_fps(carry.table[d]) for d in range(4)]
+    assert [len(s) for s in shards] == list(r.shard_distinct)
+    assert set().union(*shards) == one_chip_fps
+    assert sum(len(s) for s in shards) == len(one_chip_fps) == 8203
+    # the highest segment goes first, so each new fingerprint is still
+    # claimed by its highest received lane: queue rows and order, the
+    # per-action distinct counts and the outdegree histogram are the
+    # one-segment run's bit for bit; only slots inside a table bucket
+    # may be taken in another order
+    assert carry_digest(carry, GEOM["queue_capacity"], table=False
+                        ) == PARENT_DIGEST_LESS_TABLE
+    stat = np.asarray(carry.route_stat)
+    bodies = int(stat[0, 1])
+    assert bodies == int(np.asarray(mesh_run[0].route_stat)[0, 1])
+    assert (stat[:, 2] > bodies).all()  # several segments a body
+    # and never more than the received candidates need
+    assert (stat[:, 2] <= (ref.generated + 3 * bodies) // NARROW + bodies
+            ).all()
+
+
+def test_spill_veto_goes_through_the_segments():
+    """The halves as the spill runtime runs them, the host's veto
+    between them: a veto of everything commits nothing, whatever the
+    segment; a veto of nothing is the fused body's step."""
+    from jaxtlc.engine.sharded import ShardedSpillRuntime
+
+    with segments_of(NARROW):
+        rt = ShardedSpillRuntime(FF, fp_mesh(4), **GEOM)
+    init_fn, run_fn = engine_at_width(NARROW, segment=1)
+    carry = rt.init_fn()
+    for _ in range(40):  # to a level that fills several segments
+        carry = rt.audit_step_fn(carry)
+    fused = init_fn()
+    for _ in range(40):
+        fused = run_fn(fused)
+    qcap = GEOM["queue_capacity"]
+    assert carry_digest(carry, qcap) == carry_digest(fused, qcap)
+    ex = rt._expand_fn(carry)
+    received = np.asarray(ex.r_valid).sum(axis=1)
+    assert (received > NARROW).any()
+    before = np.asarray(carry.route_stat)[:, 2]
+    all_veto = rt._commit_fn(carry, ex, np.ones((4, rt._DB), bool))
+    assert (np.asarray(all_veto.distinct)
+            == np.asarray(carry.distinct)).all()
+    assert (np.asarray(all_veto.qtail) == np.asarray(carry.qtail)).all()
+    assert (np.asarray(all_veto.table) == np.asarray(carry.table)).all()
+    assert int(np.asarray(all_veto.spill_hits).sum()) == received.sum()
+    # the segments still ran: they cover what arrived, vetoed or not
+    assert (np.asarray(all_veto.route_stat)[:, 2] - before
+            == -(-received // NARROW)).all()
+    # half the lanes vetoed: exactly the rest can be new
+    ex_lo = np.asarray(ex.r_lo)
+    veto = (ex_lo & 1).astype(bool)
+    some = rt._commit_fn(carry, ex, veto)
+    none = rt._commit_fn(carry, ex, np.zeros((4, rt._DB), bool))
+    kept = [_raw_fps(some.table[d]) - _raw_fps(carry.table[d])
+            for d in range(4)]
+    full = [_raw_fps(none.table[d]) - _raw_fps(carry.table[d])
+            for d in range(4)]
+    for d in range(4):
+        ok = {(lo, hi) for lo, hi in zip(
+            ex_lo[d][np.asarray(ex.r_valid)[d] & ~veto[d]].tolist(),
+            np.asarray(ex.r_hi)[d][
+                np.asarray(ex.r_valid)[d] & ~veto[d]].tolist())}
+        assert kept[d] == full[d] & ok and len(full[d]) > len(kept[d]) > 0
+
+
+def test_pipeline_goes_through_the_segments():
+    init_fn, run_fn = engine_at_width(NARROW, pipeline=True)
+    carry = jax.block_until_ready(run_fn(init_fn()))
+    # the deferred verdict fold lands the same adds one body later
+    assert carry_digest(carry, GEOM["queue_capacity"], table=False
+                        ) == PARENT_DIGEST_LESS_TABLE
+    stat = np.asarray(carry.route_stat)
+    assert (stat[:, 2] > stat[:, 1]).all()
+
+
+def test_commit_counters_cross_a_regrow_and_a_reshard(mesh_run):
+    from jaxtlc.dist.pod import reshard_carry
+    from jaxtlc.resil.regrow import migrate_shard_carry
+
+    carry, _ = mesh_run
+    stat = np.asarray(carry.route_stat)
+    assert stat.shape == (4, 3) and (stat[:, 2] > 0).all()
+    old = dict(queue_capacity=GEOM["queue_capacity"],
+               fp_capacity=GEOM["fp_capacity"], route_factor=2.0)
+    grown = migrate_shard_carry(carry, old, dict(
+        old, fp_capacity=2 * GEOM["fp_capacity"], route_factor=4.0))
+    assert (np.asarray(grown.route_stat) == stat).all()
+    r = result_from_shard_carry(
+        grown, 1.0, route=route_geometry(kubeapi_backend(FF), 128, 4, 4.0))
+    assert r.commit_segments == tuple(int(v) for v in stat[:, 2])
+    assert r.commit_rows == 128
+    halved = reshard_carry(
+        jax.tree.map(np.asarray, carry), kubeapi_backend(FF), 2)
+    # a pod's new rows all start from the old pod's maxima
+    assert (np.asarray(halved.route_stat) == stat.max(axis=0)).all()
+    assert np.asarray(halved.route_stat).shape == (2, 3)
