@@ -16,7 +16,7 @@ user calls, and gates every leg on exact state counts:
       "not run: 1 device" in so many words      L1's counts, all tables
                                                 occupied
   L2  engine.checkpoint.check_with_checkpoints on scaled 2x1FF at its
-      full geometry (what `python bench.py` runs; fp table 2^26 slots,
+      full geometry (config.scaled_config; fp table 2^26 slots,
       ~104k-state levels)               62,014,325 / 19,359,985 / 186
 
 L2 runs last so that the peak-memory reading after each smaller leg is
@@ -131,7 +131,6 @@ def leg_l2():
     from jaxtlc.config import scaled_config
     from jaxtlc.engine.bfs import resolve_deferred, resolve_sort_free
     from jaxtlc.engine.checkpoint import check_with_checkpoints
-    from jaxtlc.engine.fpset import _dense_walk_default
 
     cfg, kw = scaled_config()
     r = check_with_checkpoints(cfg, ckpt_every=64, **kw)
@@ -144,7 +143,6 @@ def leg_l2():
         segments=r.iterations, geometry=kw,
         sort_free=resolve_sort_free(None, kw["chunk"]),
         deferred_inv=resolve_deferred(None, kw["chunk"]),
-        claim_walk="dense" if _dense_walk_default() else "sort",
     )
 
 

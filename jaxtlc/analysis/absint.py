@@ -3,10 +3,9 @@
 The shape-inference pass (struct.shapes) answers "what layout can hold
 every reachable value" by ASCENDING iteration with threshold widening
 and TypeOK-hint clamping - over-approximate by design, because the
-codec only needs an upper bound.  COSTMODEL.json says commit is
-sort-dominated and sort cost scales with the packed word count the
-codec emits, so those over-approximations are paid for on every chunk
-of every run.  This module is the DESCENDING half of the classic
+codec only needs an upper bound.  The commit's row gathers, scatters
+and sorts all move the packed words the codec emits, so those
+over-approximations are paid for on every chunk of every run.  This module is the DESCENDING half of the classic
 abstract-interpretation recipe (widen up, narrow down, verify):
 
 * **Interval domain** for integer leaves, **length domain** for

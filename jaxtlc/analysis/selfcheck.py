@@ -479,23 +479,6 @@ def _build_sweep():
                 fp_capacity=_TINY["fp_capacity"])
 
 
-def _build_phased():
-    # the -phase-timing engine wrapper (obs.phases.PhasedRuntime): the
-    # DEVICE composition (separately-jitted expand + commit halves) is
-    # traced as one step; the fences sit between the two jits on the
-    # host, outside any device body - what the purity audit verifies
-    from ..obs.phases import PhasedRuntime
-
-    rt = PhasedRuntime(
-        _ff_backend(), chunk=_TINY["chunk"],
-        queue_capacity=_TINY["queue_capacity"],
-        fp_capacity=_TINY["fp_capacity"],
-    )
-    return dict(init_fn=rt.init_fn, step_fn=rt.audit_step_fn,
-                n_lanes=_ff_backend().n_lanes,
-                fp_capacity=_TINY["fp_capacity"])
-
-
 # every shipped engine factory; audited by the self-check and pinned
 # by tier-1 so a new engine path cannot ship unaudited
 FACTORIES: Dict[str, Callable[[], dict]] = {
@@ -505,7 +488,6 @@ FACTORIES: Dict[str, Callable[[], dict]] = {
     "fused": _build_fused,
     "infer": _build_infer,
     "narrowed": _build_narrowed,
-    "phased": _build_phased,
     "pipelined": _build_pipelined,
     "por": _build_por,
     "sharded": _build_sharded,

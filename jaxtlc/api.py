@@ -87,7 +87,6 @@ class CheckRequest:
     obsslots: int = 256
     journal: str = ""
     serve: int = 0
-    phasetiming: bool = False
     traceout: str = ""
     xprof: str = ""
     analyze: bool = False
@@ -671,7 +670,6 @@ def _sup_opts(args, log, capture_fps: bool = False):
         ckpt_every=args.checkpointevery,
         resume=args.recover,
         spill=args.spill,
-        phase_timing=args.phasetiming,
         faults=FaultPlan.parse(args.faults) if args.faults else None,
         capture_fps=capture_fps,
         on_event=on_event,
@@ -1266,7 +1264,6 @@ def _run_sim_struct(args, spec) -> int:
             ("-liveness", args.liveness),
             ("-coverage", args.coverage),
             ("-narrow", args.narrow),
-            ("-phase-timing", args.phasetiming),
             ("-mutation", args.mutation),
             ("-symmetry", getattr(args, "symmetry", None)),
             ("-por", getattr(args, "por", None)),
@@ -1504,7 +1501,6 @@ def _run_infer_struct(args, spec) -> int:
             ("-liveness", args.liveness),
             ("-coverage", args.coverage),
             ("-narrow", args.narrow),
-            ("-phase-timing", args.phasetiming),
             ("-mutation", args.mutation),
             ("-checkpoint", args.checkpoint),
             ("-recover", args.recover),
@@ -1645,11 +1641,11 @@ def _run_infer_struct(args, spec) -> int:
 def _artifact_plan(args, spec, sm, bounds):
     """The incremental-re-checking plan for a struct run (ISSUE 13), or
     None when the run is ineligible: resume/fault/mutation runs exist
-    to exercise the engines, coverage/phase-timing/xprof runs produce
+    to exercise the engines, coverage/xprof runs produce
     run-shaped artifacts a cached verdict cannot, and -no-artifact-cache
     (or JAXTLC_ARTIFACT_CACHE=off) disables the store outright."""
     if (args.recover or args.faults or args.mutation or args.coverage
-            or args.phasetiming or args.xprof
+            or args.xprof
             or getattr(args, "simulate", False)
             or getattr(args, "infer", False)):
         # simulate/infer are unreachable here (both paths branch off

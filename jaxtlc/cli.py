@@ -99,15 +99,16 @@ def main(argv=None) -> int:
                         "scatter-max in-batch dedup + a probe-width "
                         "claimant compaction, inherited by every engine "
                         "at the expand/commit seam (fused, -pipeline, "
-                        "-sharded, spill, -phase-timing, -narrow, "
-                        "-coverage).  Results are bit-for-bit the "
-                        "sorted path's - full signature AND fpset "
-                        "table words (bench.py --commit-ab gates it).  "
-                        "Default auto: on at -chunk >= 2048, where the "
-                        "fitted cost model shows the sorts at 89%% of "
-                        "commit (COSTMODEL.json); off below, where "
-                        "they are cheap.  A checkpoint records the "
-                        "resolved mode: -recover must match")
+                        "-sharded, spill, -narrow, -coverage).  "
+                        "Results are bit-for-bit the sorted path's - "
+                        "full signature AND fpset table words "
+                        "(tests/test_sortfree.py::test_ff_bit_for_bit "
+                        "pins it).  Default auto: on at -chunk >= "
+                        "2048, off below.  Each side runs in one "
+                        "benchmark cell (sorted at chunk 1024, sort-"
+                        "free at 16384); the two were never compared "
+                        "on a chip (ROADMAP A3).  A checkpoint records "
+                        "the resolved mode: -recover must match")
     c.add_argument("-no-sort-free", dest="sortfree", action="store_const",
                    const=False,
                    help="force the sorted dedup commit at any chunk")
@@ -122,20 +123,21 @@ def main(argv=None) -> int:
                         "the distinct fpset insert.  Inherited by "
                         "every engine at the expand/commit seam "
                         "(fused, -pipeline, -sharded owner-side, "
-                        "spill, -phase-timing, -narrow, -coverage); "
+                        "spill, -narrow, -coverage); "
                         "-simulate ignores it (every walker state is "
                         "fresh - the sim tier keeps its immediate "
                         "per-walker invariant path).  Verdict, "
                         "counters, fpset table words and rendered "
                         "traces are bit-for-bit the immediate "
-                        "path's (bench.py --expand-ab gates it); the "
+                        "path's (tests/test_deferred.py::"
+                        "test_ff_bit_for_bit pins it); the "
                         "reported violating LANE follows the pinned "
                         "highest-lane rule (the PR 12 dedup rep "
                         "convention) instead of first-lane.  Default "
-                        "auto: on at -chunk >= 2048, where the "
-                        "fitted cost model shows the invariant "
-                        "sweep dominating the step (COSTMODEL.json); "
-                        "off below.  A checkpoint records the "
+                        "auto: on at -chunk >= 2048, off below "
+                        "(each side runs in one benchmark cell; never "
+                        "compared on a chip: ROADMAP A3).  A "
+                        "checkpoint records the "
                         "resolved mode: -recover must match")
     c.add_argument("-no-deferred-inv", dest="deferredinv",
                    action="store_const", const=False,
@@ -267,7 +269,8 @@ def main(argv=None) -> int:
                         "counts), read back at segment fences and "
                         "journaled as `level` events.  Pure telemetry: "
                         "results are bit-for-bit identical to -no-obs "
-                        "(bench.py --obs-ab gates overhead at <= 2%)")
+                        "(tests/test_obs.py::"
+                        "test_obs_bit_identical_and_ring pins it)")
     c.add_argument("-no-obs", dest="obs", action="store_false",
                    help="disable the device counter ring (also the "
                         "carry shape pre-obs checkpoints expect)")
@@ -293,17 +296,6 @@ def main(argv=None) -> int:
                         "--connect renders it).  python -m "
                         "jaxtlc.obs.serve serves existing journals "
                         "standalone")
-    c.add_argument("-phase-timing", dest="phasetiming",
-                   action="store_true",
-                   help="measured per-level expand/commit walls: the "
-                        "supervisor swaps the fused segment dispatch "
-                        "for a host-fenced step loop built from the "
-                        "same stage closures (bit-for-bit results), "
-                        "journaling `phase` events the trace exporter "
-                        "renders as measured lanes.  Costs a fence per "
-                        "step (PERF.md round 11); unpipelined single-"
-                        "device engines only - other paths keep the "
-                        "free segment-scope attribution")
     c.add_argument("-trace-out", dest="traceout", default="",
                    metavar="FILE",
                    help="export the run timeline as a Chrome-trace JSON "

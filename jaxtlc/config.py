@@ -178,7 +178,7 @@ def make_scaled(
 
 
 def scaled_config():
-    """The `bench.py --scaled` workload: config + engine sizing.
+    """The scaled 2x1 FF flagship (chip_smoke.py L2): config + engine sizing.
 
     This is the workload the 50x throughput target is defined on
     (BASELINE.json): a frontier wide enough to keep the MXU/VPU busy, unlike

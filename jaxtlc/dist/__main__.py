@@ -6,7 +6,7 @@ Two modes:
   workload to completion.  The three jax.distributed knobs are
   ``--coordinator --num-hosts --host``; everything else mirrors the
   engine parameters (per-device, like the sharded engine).  Prints one
-  ``POD_RESULT {json}`` line (bench.py --multihost-ab parses it) and
+  ``POD_RESULT {json}`` line (tests/test_multihost.py parses it) and
   exits with the run's verdict code (0 ok / 12 violation / 75
   preempted-and-checkpointed).
 

@@ -45,7 +45,8 @@ protocol around that body:
   journals into pod-global counters and obs.trace can render one
   timeline with a process row per host, lanes aligned on the fence
   timestamps.  Pure telemetry: obs-on pod runs are bit-for-bit obs-off
-  runs (bench.py --pod-obs-ab gates signature + fpset TABLE words).
+  runs (tests/test_multihost.py::test_pod_obs_coverage_parity pins the
+  folded counters against the engine's own).
 """
 
 from __future__ import annotations
@@ -614,15 +615,15 @@ def run_pod(
 
     chunk/queue_capacity/fp_capacity are PER DEVICE, exactly the
     sharded-engine contract - a pod of H hosts multiplies total table
-    capacity by H at constant per-host memory, which is the scaling
-    claim bench.py --multihost-ab commits.
+    capacity by H at constant per-host memory (a CPU drill so far:
+    never run across hosts on chips).
 
     obs_slots > 0 turns the device counter ring on (per-host PARTIAL
     `level` events with a `host` field, decoded from this process's
     ring rows at each fence); coverage=True attaches the workload's
     CoveragePlane (per-host `coverage` delta events).  Both are pure
     telemetry - obs-on results are bit-for-bit obs-off results
-    (bench.py --pod-obs-ab)."""
+    (tests/test_multihost.py::test_pod_obs_coverage_parity)."""
     import jax
 
     from ..engine.bfs import resolve_deferred, resolve_sort_free

@@ -8,20 +8,22 @@ fingerprints + dedups against the device hash table, and appends the new
 states - no host round-trips until the state space is exhausted or a
 violation is found.
 
-v4 data layout, driven by on-chip microbenchmarks (tools/microbench.py:
-random row scatters ~140ns/row dominate; contiguous dynamic-slice writes
-are 3-9x cheaper; sorts are cheap):
+Data layout and step as the chip runs them (PERF.md PR 26, Step 0: a
+row gather or a row scatter-add costs its rows, in place; a conditional
+that takes the carry copies it):
 
 * The frontier is a ping-pong pair of level buffers of *packed* state
-  words ([2, qcap + 2*chunk, W] uint32): pops are contiguous dynamic
-  slices, appends are contiguous dynamic-update-slices of fingerprint-
-  sorted new states - no row scatters on the queue at all.  States are
-  unpacked to field vectors only at the kernel boundary (codec.unpack).
-* Dedup probes only the sort-compacted unique candidates
-  (fpset.fpset_insert_sorted), and per-new-state bookkeeping (enqueue,
-  per-action distinct counts, outdegree credit) runs over compacted
-  A-wide segments instead of the full chunk*L candidate array.
-* Fingerprints ride the MXU (fingerprint.fp64_words_mxu).
+  words ([2, qcap + 2*chunk, W] uint32): a pop is a contiguous dynamic
+  slice, an append one row gather of the new states and a contiguous
+  dynamic-update-slice.  States are unpacked to field vectors only at
+  the kernel boundary (codec.unpack); fingerprints ride the MXU.
+* The commit dedups the chunk*L candidates (fpset.fpset_insert_dedup:
+  two stable sorts, or the hash slab), probes only the unique ones and
+  writes the table by one scatter-add of whole bucket rows; enqueue and
+  per-new-state statistics run over compacted probe-width segments.
+* Every loop is a `while` (`run_steps`; the two pop widths at chunk >=
+  2^14 are two inner loops): no conditional holds the carry.  The stages
+  are device scopes (`jaxtlc.expand`, `.dedup`, `.fpset`, `.enqueue`, `.level`).
 * Per-action generated counters are factorized through the dispatch
   structure (all lanes of a client share that client's pc label; server
   lanes are always APIStart) instead of scatter-adds over all candidates.
@@ -268,11 +270,10 @@ def carry_done(carry: EngineCarry) -> bool:
 
 DEFAULT_FP_HIGHWATER = 0.85
 
-# -sort-free auto threshold: the fitted cost model (COSTMODEL.json,
-# PERF.md round 11) shows the two full-width dedup sorts dominating
-# commit at large chunks (8.3 of 9.3 ms at chunk 2048 = 89%); at small
-# chunks the sorts are cheap and the slab setup is pure overhead, so
-# auto keeps the sorted path there.
+# -sort-free auto threshold.  Each side of it runs in one benchmark
+# cell - sorted at chunk 1024 in `kubeapi-model1.recheck`, sort-free at
+# 16,384 in the wide and four-chip cells; the two were never compared
+# on a chip (ROADMAP A3).
 SORT_FREE_AUTO_CHUNK = 2048
 
 
@@ -287,15 +288,13 @@ def resolve_sort_free(sort_free, chunk: int) -> bool:
     return chunk >= SORT_FREE_AUTO_CHUNK
 
 
-# -deferred-inv auto threshold (ISSUE 15): the fitted cost model
-# (COSTMODEL.json v2) puts the invariant+fingerprint subphase at 69%
-# of the sort-free step at chunk 2048 (14.2 of 20.6 ms) - the
-# per-candidate chunk*L invariant evaluation is the dominant lever
-# there, and deferring it to the ~2*chunk fresh-insert claimants is
-# the distinct-first collapse.  At small chunks the claimant gather +
-# segment loop is overhead against a cheap candidate sweep, so auto
-# keeps the immediate evaluation - same shape, and deliberately the
-# same threshold, as the sort-free auto rule.
+# -deferred-inv auto threshold (ISSUE 15): deferring the invariant
+# sweep from the chunk*L candidate lanes to the ~2*chunk fresh-insert
+# claimants is the distinct-first collapse.  Deliberately the same
+# threshold as the sort-free auto rule, and as little compared: each
+# side runs in one benchmark cell - immediate at chunk 1024 in
+# `kubeapi-model1.recheck`, deferred at 16,384 in the wide and four-chip
+# cells; the two were never compared on a chip (ROADMAP A3).
 DEFERRED_AUTO_CHUNK = 2048
 
 
@@ -392,7 +391,7 @@ def make_stage_pair(
     tri-state flag via resolve_sort_free) commits through the hash-slab
     dedup (fpset.fpset_insert_slab) instead of the two full-width
     stable sorts - bit-identical results by contract, so every engine
-    composed from this seam (fused, pipelined, spill, phased, narrowed,
+    composed from this seam (fused, pipelined, spill, narrowed,
     covered) inherits the mode with no per-engine code.  The slab is an
     ephemeral per-commit tensor derived from this pair's geometry, so
     regrow/chunk-shrink rebuilds migrate it by construction.
@@ -407,7 +406,7 @@ def make_stage_pair(
     rendered traces are bit-for-bit the immediate path's; only the
     violation-LANE attribution changes, to the pinned highest-lane
     rule (the checker docstring).  Because both modes meet at this one
-    seam, every composed engine - fused, pipelined, spill, phased,
+    seam, every composed engine - fused, pipelined, spill,
     narrowed, covered - inherits the mode with no per-engine code.
 
     spill=True builds the commit for spill mode: it takes an extra
@@ -819,13 +818,14 @@ def make_backend_engine(
     single contiguous row store per body (the dump-row trick makes the
     write unconditional).  The ring is pure telemetry - it feeds no
     control flow and no arbitration - so check results with obs on are
-    bit-for-bit those of an obs-off run (bench.py --obs-ab gates the
-    wall-clock overhead at <= 2%).
+    bit-for-bit those of an obs-off run (tests/test_obs.py::
+    test_obs_bit_identical_and_ring pins it).
 
     sort_free (tri-state: None = auto, resolve_sort_free) selects the
     hash-slab commit dedup in place of the two full-width stable sorts
     (ISSUE 12).  Results are BIT-FOR-BIT the sorted path's - full
-    signature plus fpset TABLE words (bench.py --commit-ab gates it) -
+    signature plus fpset TABLE words (tests/test_sortfree.py::
+    test_ff_bit_for_bit pins it) -
     the flag is purely a performance mode, but it is still recorded in
     engine memos and checkpoint meta so a resume can never silently
     cross modes.
@@ -834,8 +834,8 @@ def make_backend_engine(
     invariant + certificate evaluation to the commit stage, over the
     fresh-insert claimants only (ISSUE 15; make_stage_pair docstring).
     Verdict, full counter signature, fpset TABLE words and rendered
-    traces are bit-for-bit the immediate path's (bench.py --expand-ab
-    gates it); violation-LANE attribution follows the pinned
+    traces are bit-for-bit the immediate path's (tests/test_deferred.py::
+    test_ff_bit_for_bit pins it); violation-LANE attribution follows the pinned
     highest-lane rule.  Like sort_free, the resolved mode is engine-
     memo and checkpoint-meta material - a wrong-mode -recover is a
     loud pre-build rejection - because the pipelined staged-block

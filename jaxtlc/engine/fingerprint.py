@@ -246,8 +246,10 @@ def fp64_words_mxu(words, nbits: int, fp_index: int = DEFAULT_FP_INDEX,
     reduction of an affine GF(2) map is a parity per output bit, and
     parity = popcount & 1, so ``bits [n, nbits] @ basis_bits [nbits, 64]``
     accumulated in f32 (exact: counts <= nbits << 2^24) followed by ``& 1``
-    gives the fingerprint bits.  ~3x faster than the XOR tree on TPU
-    (tools/microbench.py) because the reduction rides the systolic array.
+    gives the fingerprint bits.  The reduction rides the systolic array
+    (tests/test_fingerprint.py::test_mxu_path_matches_xor_tree_and_host
+    pins the values; the two were not timed against each other on the
+    present chip).
     """
     bb = jnp.asarray(_basis_bits(nbits, fp_index, seed), dtype=jnp.bfloat16)
     const, _ = affine_basis(nbits, fp_index, seed)

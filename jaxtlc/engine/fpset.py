@@ -33,11 +33,10 @@ flattened by XLA first, two whole-table relayouts (6.1 ms) a call.
 * **Straggler path**: candidates whose home bucket is (or becomes) full
   walk buckets linearly; each walk round rank-claims against the
   CURRENT bucket so straggler writes are conflict-free too.  The round's
-  rank arbitration has two bit-identical forms (ISSUE 15): a re-sort of
-  the straggler slice by current bucket (the CPU form), or the dense
-  [S, S] bucket-coincidence reduction per the BLEST tensor-core BFS
-  papers (the accelerator form - no comparator network in the walk;
-  `JAXTLC_DENSE_WALK` overrides the platform auto).  No claim-verify
+  rank arbitration is the dense [S, S] bucket-coincidence reduction of
+  the BLEST tensor-core BFS papers (ISSUE 15: no comparator network in
+  the walk), on every platform; tests/test_deferred.py::
+  test_dense_walk_matches_host_replay pins it.  No claim-verify
   exists anywhere: a slot write is one scatter-add of whole bucket rows
   (`_slot_write`), and with every claim targeting a distinct EMPTY slot
   the sum is the write whatever order the rows land in (a verify-based
@@ -383,52 +382,17 @@ def fpset_member(s: FPSet, lo, hi, mask,
     return found
 
 
-def _dense_walk_default() -> bool:
-    """Whether the straggler claim walk runs its dense rank-claim form
-    (ISSUE 15, per the BLEST tensor-core BFS formulation): the per-
-    round 4-key comparator sort over the straggler slice is replaced
-    by an [S, S] bucket-coincidence x fingerprint-order mask reduced
-    row-wise to in-bucket ranks - a dense segmented reduction with no
-    comparator network anywhere in the walk.  BIT-FOR-BIT either way
-    (the rank a lane claims with is identical - tests/test_fpset and
-    tests/test_deferred pin both forms against each other and the host
-    oracle), so the choice is pure schedule, NOT memo/meta material:
-    auto takes the dense form on accelerators, where comparator sorts
-    are the measured cost (PAPERS.md: BLEST; Graph Traversal on Tensor
-    Cores), and keeps the sort on CPU, where the [S, S] mask is.
-    JAXTLC_DENSE_WALK=1/0 forces it (read at trace time)."""
-    import os
-
-    v = os.environ.get("JAXTLC_DENSE_WALK", "auto").lower()
-    if v in ("1", "true", "on"):
-        return True
-    if v in ("0", "false", "off"):
-        return False
-    import jax
-
-    return jax.default_backend() != "cpu"
-
-
-def _probe_block(table, lo, hi, active, claim_width: int,
-                 dense_walk: bool = None):
+def _probe_block(table, lo, hi, active, claim_width: int):
     """`_probe_claim` under the device scope `jaxtlc.fpset` (the probe
     / claim of the engine's commit; bfs.make_stage_pair has the list)."""
     with jax.named_scope("jaxtlc.fpset"):
-        return _probe_claim(table, lo, hi, active, claim_width,
-                            dense_walk)
+        return _probe_claim(table, lo, hi, active, claim_width)
 
 
-def _probe_claim(table, lo, hi, active, claim_width: int,
-                 dense_walk: bool = None):
+def _probe_claim(table, lo, hi, active, claim_width: int):
     """Insert-or-find `active` entries of a fingerprint block that is
     sorted ascending by (hi, lo) and duplicate-free.  Returns
-    (table, is_new).  table: [nb, 2B]; lo/hi/active: [R].
-
-    dense_walk selects the straggler-walk arbitration form (None =
-    platform auto, _dense_walk_default); both forms produce identical
-    verdicts AND identical table words."""
-    if dense_walk is None:
-        dense_walk = _dense_walk_default()
+    (table, is_new).  table: [nb, 2B]; lo/hi/active: [R]."""
     nb = table.shape[0]
     cap = nb * BUCKET
     R = lo.shape[0]
@@ -471,8 +435,8 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
 
     # straggler loop: candidates whose home bucket is full (or whose claim
     # fell beyond C) walk buckets linearly.  Each outer round compacts the
-    # pending set to an S-slice; each walk round sorts that slice by its
-    # CURRENT bucket and rank-claims - conflict-free again, so no
+    # pending set to an S-slice; each walk round rank-claims against
+    # the slice's CURRENT buckets - conflict-free again, so no
     # claim-verify (whose torn-write hazard under the interleaved layout
     # could live-lock) and every write is to a distinct slot.
     S = min(R, 2048)
@@ -492,8 +456,7 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
         s_bid = p_bid[:S].astype(jnp.int32)
         s_lo, s_hi = p_lo[:S], p_hi[:S]
         s_pos = p_pos[:S].astype(jnp.int32)
-        # the slice's live lanes are its first n_act (and stay a
-        # prefix in the sorted walk: it sorts pending lanes first)
+        # the slice's live lanes are its first n_act
         n_act = jnp.minimum(pending.sum(), S)
         s_act = jnp.arange(S) < n_act
 
@@ -501,18 +464,17 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
             _, _, pend, _, _ = wst
             return pend.any()
 
-        def walk_body_dense(wst):
+        def walk_body(wst):
             # dense rank-claim round (ISSUE 15, BLEST formulation): the
             # slice stays in ITS OWN order - no per-round sort.  Each
             # pending lane gathers its current bucket row (the
             # membership test needs the stored words), and the in-
             # bucket claim rank comes from one [S, S] bucket-
             # coincidence x fingerprint-order mask reduced row-wise: a
-            # dense segmented reduction (VPU/MXU-shaped) in place of
-            # the 5-array 4-key comparator sort.  Ranks are identical
-            # to the sorted round's (the slice is duplicate-free, so
-            # ascending (lo, hi) is a strict order), hence identical
-            # slot targets and identical table words.
+            # dense segmented reduction (VPU/MXU-shaped), no comparator
+            # network.  The slice is duplicate-free, so ascending
+            # (lo, hi) is a strict order: same-bucket claimants get
+            # distinct ranks, hence distinct slots.
             table, cur_b, pend, new, k = wst
             row = table[jnp.where(pend, cur_b, 0)]  # [S, 2B]
             rlo, rhi = row[:, 0::2], row[:, 1::2]
@@ -541,46 +503,8 @@ def _probe_claim(table, lo, hi, active, claim_width: int,
                               cur_b)
             return table, cur_b, pend2, new, k + 1
 
-        def walk_body(wst):
-            table, cur_b, pend, new, k = wst
-            # sort the slice by current bucket so same-bucket claimants
-            # are adjacent; carry everything through the sort
-            o = jnp.arange(S, dtype=jnp.uint32)
-            _, w_b, w_lo, w_hi, w_o = lax.sort(
-                ((~pend).astype(jnp.uint32), cur_b.astype(jnp.uint32),
-                 s_lo, s_hi, o),
-                num_keys=4, is_stable=True,
-            )
-            w_b = w_b.astype(jnp.int32)
-            w_pend = pend[w_o.astype(jnp.int32)]
-            row = table[jnp.where(w_pend, w_b, 0)]  # [S, 2B]
-            rlo, rhi = row[:, 0::2], row[:, 1::2]
-            f = w_pend & ((rlo == w_lo[:, None]) & (rhi == w_hi[:, None])).any(1)
-            occ = ((rlo != 0) | (rhi != 0)).sum(axis=1).astype(jnp.int32)
-            wnt = w_pend & ~f
-            st_ = jnp.concatenate([jnp.ones(1, bool), w_b[1:] != w_b[:-1]])
-            wc2 = jnp.cumsum(wnt.astype(jnp.int32))
-            base2 = lax.cummax(jnp.where(st_, wc2 - wnt.astype(jnp.int32), 0))
-            rnk = wc2 - wnt.astype(jnp.int32) - base2
-            sl = occ + rnk
-            ok = wnt & (sl < BUCKET)
-            table = _slot_write(
-                table, w_b * BUCKET + sl, w_lo, w_hi, ok, n_act
-            )
-            # map verdicts back to slice order (w_o is a permutation)
-            oi = w_o.astype(jnp.int32)
-            ok_s = jnp.zeros(S, bool).at[oi].set(ok)
-            settled_s = jnp.zeros(S, bool).at[oi].set(f | ok)
-            adv_s = jnp.zeros(S, bool).at[oi].set(wnt & ~ok)
-            new = new | ok_s
-            pend2 = pend & ~settled_s
-            # unsettled claimants advance to the next bucket
-            cur_b = jnp.where(adv_s & pend2, (cur_b + 1) % nb, cur_b)
-            return table, cur_b, pend2, new, k + 1
-
         table, _, _, s_new, _ = lax.while_loop(
-            walk_cond,
-            walk_body_dense if dense_walk else walk_body,
+            walk_cond, walk_body,
             (table, s_bid, s_act, jnp.zeros(S, bool), jnp.int32(0)),
         )
         upd_pos = jnp.where(s_act, s_pos, R)
@@ -702,9 +626,9 @@ def fpset_insert_sorted(
 # ---------------------------------------------------------------------------
 # sort-free commit path (ISSUE 12): hash-slab in-batch dedup + the
 # bucketized rank-claim probe over a compacted claimant slice, replacing
-# the two full-width stable dedup sorts above (89% of commit at chunk
-# 2048, COSTMODEL.json round 11) with scatter/gather primitives per the
-# BLEST frontier-membership formulation.  Exactness is the contract:
+# the two full-width stable dedup sorts above with scatter/gather
+# primitives per the BLEST frontier-membership formulation (on the chip
+# since PR 26: PERF.md section 5, `jaxtlc.dedup`).  Exactness is the contract:
 # identical is_new verdicts, identical compacted-prefix order, identical
 # TABLE words - where the slab cannot guarantee that cheaply (residue /
 # width overflow) it falls back to the sorted path wholesale.
@@ -918,7 +842,7 @@ def fpset_insert_dedup(
     """The engine seam's insert: the sorted dedup path or the sort-free
     hash-slab path, one flag (bfs.make_stage_pair threads the resolved
     -sort-free mode here, so every stage composition - fused,
-    pipelined, spill, phased - and the sharded owner-side insert share
+    pipelined, spill - and the sharded owner-side insert share
     one dispatch point).  Contract identical either way."""
     # device scope of the in-batch dedup (sorts or slab); the probe /
     # claim inside it is `jaxtlc.fpset` (_probe_block), so a trace

@@ -1253,7 +1253,8 @@ class ShardedSpillRuntime:
 
     Exactness: a host-vetoed candidate dedups exactly like an owner-
     table hit, so counters/verdict are bit-for-bit a correctly-sized
-    clean sharded run's (tests/test_shardspill.py pins parity)."""
+    clean sharded run's (tests/test_multihost.py::
+    test_pod_over_capacity_needs_spill pins the counts)."""
 
     def __init__(self, cfg, mesh: Mesh, chunk: int, queue_capacity: int,
                  fp_capacity: int, fp_index: int = DEFAULT_FP_INDEX,

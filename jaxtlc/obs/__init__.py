@@ -10,19 +10,17 @@ Three tiers, one source of truth:
 * **Host tier** (obs.journal + obs.schema): a crash-safe append-only
   JSONL run journal - manifest, segments, levels, checkpoints, regrows,
   retries, faults, violations, final verdict - validated against a
-  versioned schema at write AND read time.  TLC progress lines, bench
-  payloads and the tlcstat dashboard are derived views (obs.views).
+  versioned schema at write AND read time.  TLC progress lines and
+  the tlcstat dashboard are derived views (obs.views).
 * **Timeline tier** (obs.trace): Chrome-trace/Perfetto export of the
   journal (`-trace-out`), plus the `-xprof DIR` jax.profiler hook in
   the CLI for ground-truth device timelines.
 
 The live ops plane rides on top (ISSUE 8): **phase attribution**
-(obs.phases - free segment-scope walls at every fence, measured
-per-level expand/commit walls behind `-phase-timing`) and the
-**run-monitoring server** (obs.serve - /metrics Prometheus text,
-/events SSE journal tail, /runs registry; `-serve PORT` or
-`python -m jaxtlc.obs.serve`), with tools/costmodel.py fitting the
-per-phase cost model from the phase events.
+(obs.phases - segment-scope walls at every fence), the check's **host
+spans** (obs.spans) and the **run-monitoring server** (obs.serve -
+/metrics Prometheus text, /events SSE journal tail, /runs registry;
+`-serve PORT` or `python -m jaxtlc.obs.serve`).
 """
 
 from .counters import (  # noqa: F401
@@ -33,11 +31,11 @@ from .counters import (  # noqa: F401
     shard_rows_from_ring,
 )
 from .journal import RunJournal, read as read_journal  # noqa: F401
-from .phases import PhaseRecorder, segment_phases  # noqa: F401
+from .phases import segment_phases  # noqa: F401
 from .schema import (  # noqa: F401
     SCHEMA_VERSION,
     JournalSchemaError,
     validate_event,
 )
 from .trace import export_chrome_trace  # noqa: F401
-from .views import bench_payload, render_tlc_event  # noqa: F401
+from .views import render_tlc_event  # noqa: F401

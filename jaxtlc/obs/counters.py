@@ -10,8 +10,9 @@ and XLA-friendly.  The host reads the ring back only at the segment
 fences it already pays for (the supervisor's batched async device_get),
 decodes the new rows here, and journals them as `level` events: that is
 where TLC-style per-level rate attribution (BLEST, arXiv:2512.21967)
-comes from at near-zero steady-state cost (bench.py --obs-ab gates the
-overhead at <= 2%).
+comes from (every benchmark cell runs with the ring on; results are
+bit-for-bit an obs-off run's: tests/test_obs.py::
+test_obs_bit_identical_and_ring).
 
 Row layout (all cumulative uint32 counters; cumulative so a lost row -
 ring wrap between fences - degrades per-level resolution, never total

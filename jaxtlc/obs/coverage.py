@@ -66,7 +66,8 @@ class CoveragePlane(NamedTuple):
     HOST function charging the Init-site visits for the seed states
     (None = all-zero seed).  Pure telemetry: neither feeds control
     flow, so coverage-on results are bit-for-bit coverage-off results
-    (bench.py --cov-ab gates the wall overhead)."""
+    (tests/test_coverage_device.py::
+    test_struct_coverage_deterministic_and_pure pins it)."""
 
     sites: tuple  # tuple[Site]
     count: object  # device fn(batch, mask, valid) -> [n_sites] uint32

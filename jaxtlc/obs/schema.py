@@ -102,11 +102,8 @@ EVENTS = {
     "coverage": {"visited": _NUM, "sites": _NUM, "delta": (dict,)},
     # -- phase attribution (obs.phases) ------------------------------------
     # one measured wall per (scope, index, phase): scope "segment" rows
-    # come free at the fences the supervisor already pays (phase
-    # "device"/"readback"), scope "level" rows from the -phase-timing
-    # fenced step loop (phase "expand"/"commit", measured walls the
-    # trace exporter renders instead of its schematic lanes), scope
-    # "chunk" from the spill runtime's host-driven loop
+    # come free at the fences the supervisor and the pod driver already
+    # pay (phase "device"/"readback")
     "phase": {"scope": _STR, "index": _NUM, "phase": _STR,
               "wall_s": _NUM},
     # -- host spans (obs.spans) --------------------------------------------
@@ -180,9 +177,6 @@ EVENTS = {
     "sched": {"action": _STR, "job": _STR},
     # -- derived artifacts -------------------------------------------------
     "trace_export": {"path": _STR, "events": _NUM},
-    # one bench.py metric payload (the BENCH_*.json line contract)
-    "bench_metric": {"metric": _STR, "value": _NUM, "unit": _STR,
-                     "vs_baseline": _NUM},
 }
 
 # the verdict vocabulary of the "final" event.  The last three are

@@ -4,13 +4,11 @@ Renders the journal's host-observed intervals as a `chrome://tracing` /
 https://ui.perfetto.dev JSON file (`-trace-out run.trace.json`):
 
 * pid "device": one slice per supervised segment (dispatch -> fence).
-  When the journal carries MEASURED per-level `phase` events (a
-  `-phase-timing` run, obs.phases), each level's expand and commit
-  walls are drawn as sub-slices on two threads, in sequence, from the
-  measured walls.  Without them the segment slice is all the journal
-  knows about the device: nothing is drawn inside it (the per-level
-  counters still feed the counter tracks).  Ground-truth device
-  timelines come from `-xprof DIR` (jax.profiler).
+  The segment slice is all the journal knows about the device: nothing
+  is drawn inside it (the per-level counters feed the counter tracks,
+  at the fence they were read back at).  Ground-truth device timelines
+  come from `-xprof DIR` (jax.profiler), where the engine's stages are
+  the `jaxtlc.*` named scopes.
 * pid "host": the check's host spans (the `spans` event, obs.spans:
   `build` with its trace / lower / compile children, `loop` with its
   per-segment dispatch / overlap / wait / readback) as nested slices on
@@ -50,8 +48,6 @@ PID_DEVICE = 1
 PID_HOST = 2
 POD_PID_BASE = 10  # host h -> pids (BASE + 2h, BASE + 2h + 1)
 TID_SEGMENT = 1
-TID_EXPAND = 2
-TID_COMMIT = 3
 TID_CKPT = 1
 TID_REGROW = 2
 TID_SPANS = 3
@@ -101,10 +97,6 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
             _meta(pid_device(h), f"device engine{tag}"),
             _meta(pid_host(h), f"host (checkpoint/regrow){tag}"),
             _thread(pid_device(h), TID_SEGMENT, "segments"),
-            _thread(pid_device(h), TID_EXPAND,
-                    "expand (per level, -phase-timing)"),
-            _thread(pid_device(h), TID_COMMIT,
-                    "commit (per level, -phase-timing)"),
             _thread(pid_host(h), TID_CKPT, "checkpoint writes"),
             _thread(pid_host(h), TID_REGROW, "regrow migrations"),
             _thread(pid_host(h), TID_SPANS, "host spans (obs.spans)"),
@@ -119,59 +111,30 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                     "tid": TID_CKPT, "args": args or {}})
 
     # level events journal at the fence AFTER the segment they ran in:
-    # walk in order, buffering levels (and any measured per-level phase
-    # walls) against the most recent segment - PER HOST KEY, so a
-    # merged pod stream's interleaved hosts never cross-attribute
+    # walk in order, buffering levels against the most recent segment -
+    # PER HOST KEY, so a merged pod stream's interleaved hosts never
+    # cross-attribute
     pending_levels: dict = {}  # host key -> [level rows]
-    pending_phases: dict = {}  # host key -> {level: {expand, commit}}
     last_segment: dict = {}  # host key -> segment event
     prev_level: dict = {}  # host key -> last level event
 
     def flush_levels(h):
-        """Emit host `h`'s buffered levels against its last segment:
-        the counter tracks always, and - when the segment's `phase`
-        events cover every buffered level (a -phase-timing run) - the
-        measured expand -> commit sub-slices.  Unmeasured levels get no
-        slice: the journal does not know where in the segment they
-        ran."""
+        """Emit host `h`'s buffered levels' counter tracks at the fence
+        of its last segment: the journal does not know where in the
+        segment a level ran, so no slice is drawn for it."""
         seg = last_segment.get(h)
         levels = pending_levels.pop(h, [])
-        phases = pending_phases.pop(h, {})
         if seg is None or not levels:
             return
         pid = pid_device(h)
-        measured = all(
-            {"expand", "commit"} <= set(phases.get(lv["level"], {}))
-            for lv in levels
-        )
-        cursor = us(seg["t_dispatch"])
-        end = cursor + max(seg["wall_s"] * 1e6, 1.0)
+        end = us(seg["t_dispatch"]) + max(seg["wall_s"] * 1e6, 1.0)
         for lv in levels:
-            if measured:
-                ph = phases[lv["level"]]
-                args = {k: lv[k] for k in
-                        ("level", "generated", "distinct", "queue",
-                         "bodies", "expanded") if k in lv}
-                args["measured"] = True
-                for phase in ("expand", "commit"):
-                    dur = max(ph[phase] * 1e6, 1.0)
-                    out.append({
-                        "name": f"{phase} L{lv['level']}", "ph": "X",
-                        "ts": cursor, "dur": dur, "pid": pid,
-                        "tid": TID_EXPAND if phase == "expand"
-                        else TID_COMMIT,
-                        "args": {**args, "wall_s": ph[phase]},
-                    })
-                    cursor += dur
-            # counters: at the measured end of the level, else at the
-            # fence the row was read back at
-            at = cursor if measured else end
-            out.append({"name": "states", "ph": "C", "ts": at,
+            out.append({"name": "states", "ph": "C", "ts": end,
                         "pid": pid, "tid": 0,
                         "args": {"distinct": lv["distinct"],
                                  "queue": lv["queue"]}})
             if "fp_load" in lv:
-                out.append({"name": "fp_load", "ph": "C", "ts": at,
+                out.append({"name": "fp_load", "ph": "C", "ts": end,
                             "pid": pid, "tid": 0,
                             "args": {"load": lv["fp_load"]}})
 
@@ -200,11 +163,7 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
             prev_level[h] = ev
             pending_levels.setdefault(h, []).append(ev)
         elif kind == "phase":
-            if ev["scope"] == "level":
-                pending_phases.setdefault(h, {}).setdefault(
-                    ev["index"], {}
-                )[ev["phase"]] = ev["wall_s"]
-            elif ev["scope"] == "segment" and ev["phase"] == "readback":
+            if ev["scope"] == "segment" and ev["phase"] == "readback":
                 ensure(h)
                 out.append({
                     "name": "readback", "ph": "X",
@@ -329,14 +288,6 @@ def _tiny_journal(path: str) -> None:
                     wall_s=0.002)
             for i in range(2):
                 lvl = 2 * s + i + 1
-                # second segment: measured per-level walls (the
-                # -phase-timing tier) so the exporter's measured-lane
-                # path is exercised alongside the bare-segment one
-                if s == 1:
-                    j.event("phase", scope="level", index=lvl,
-                            phase="expand", wall_s=0.03, bodies=2)
-                    j.event("phase", scope="level", index=lvl,
-                            phase="commit", wall_s=0.012, bodies=2)
                 j.event("level", level=lvl, generated=100 * lvl,
                         distinct=60 * lvl, queue=30, bodies=4 * lvl,
                         expanded=50 * lvl, fp_load=0.01 * lvl)
@@ -370,7 +321,7 @@ def _tiny_journal(path: str) -> None:
 def main(argv=None) -> int:
     """CLI: `python -m jaxtlc.obs.trace JOURNAL [-o OUT]` exports a
     journal file; `--tiny` self-tests the whole pipeline on a synthetic
-    journal (wired into tier-1, the profile_v4 --tiny pattern)."""
+    journal (wired into tier-1: tests/test_tools.py)."""
     import argparse
     import sys
     import tempfile
@@ -396,8 +347,16 @@ def main(argv=None) -> int:
                 doc = json.load(f)
             assert doc["traceEvents"] and n == len(doc["traceEvents"])
             names = {e.get("name", "") for e in doc["traceEvents"]}
-            assert any(s.startswith("expand L") for s in names)
-            assert any(s.startswith("commit L") for s in names)
+            # bare segments: two slices, nothing drawn inside them,
+            # each level's counters at its segment's fence
+            assert {"segment 0", "segment 1", "readback"} <= names
+            assert not any(s.startswith(("expand L", "commit L"))
+                           for s in names)
+            fences = {e["ts"] + e["dur"] for e in doc["traceEvents"]
+                      if e.get("name", "").startswith("segment ")}
+            states = [e["ts"] for e in doc["traceEvents"]
+                      if e.get("name") == "states"]
+            assert len(states) == 4 and set(states) == fences
             assert {"build", "build.compile", "loop.wait"} <= names
             assert min(e["ts"] for e in doc["traceEvents"]
                        if "ts" in e) >= 0

@@ -11,18 +11,12 @@ telemetry; everything user-facing renders FROM it:
 * `interval_rates` - the shared rate arithmetic (states/min between two
   observations), used by TLCLog and tools/tlcstat.py alike so the
   progress line and the dashboard can never disagree.
-* `bench_payload` - the BENCH_*.json line contract: every bench.py
-  payload is stamped through a journal as a `bench_metric` event, so
-  the required metric/unit/vs_baseline fields are schema-enforced at
-  emit time instead of by reviewer eyeball.
 * `eta_s` - queue-drain ETA from the two most recent observations.
 """
 
 from __future__ import annotations
 
 from typing import Optional, Tuple
-
-from .journal import RunJournal
 
 
 def merge_journals(*streams):
@@ -163,9 +157,8 @@ def eta_s(prev: Optional[dict], cur: dict) -> Optional[float]:
 
 def phase_totals(events) -> dict:
     """Cumulative measured wall seconds per phase name from the `phase`
-    events (obs.phases) of a journal: {phase: seconds}.  Level- and
-    segment-scope rows both accumulate (they attribute different walls:
-    expand/commit device halves vs device/readback fence intervals).
+    events (obs.phases) of a journal: {phase: seconds} (the
+    device/readback fence intervals of every segment).
     The check's host spans (the `spans` event, obs.spans) fold into the
     same totals under their own names (`build`, `loop.wait`, ...), so
     /metrics and tlcstat show them with no exporter of their own."""
@@ -552,57 +545,3 @@ def render_tlc_event(log, ev: dict, resume_cmd: str = "") -> None:
             + (f"; resume with: {resume_cmd}" if resume_cmd else ""),
             severity=1,
         )
-
-
-_BENCH_BASE = {
-    "metric": "distinct_states_per_s",
-    "value": 0,
-    "unit": "states/s",
-    "vs_baseline": 0,
-    "pipeline": False,
-    # which commit dedup produced the number (ISSUE 12): the sorted
-    # path (False) or the hash-slab sort-free path (True); modes that
-    # run both put their setting in explicitly, like "pipeline"
-    "sort_free": False,
-    # which search produced the number (ISSUE 14): exhaustive BFS
-    # (False) or the random-walk simulation tier (True - walks/s
-    # payloads, bench.py --sim)
-    "sim": False,
-    # which expand mode produced the number (ISSUE 15): immediate
-    # per-candidate invariant/cert evaluation (False) or the
-    # distinct-first deferred evaluation on the fresh-insert
-    # claimants (True - bench.py --expand-ab); modes that run both
-    # put their setting in explicitly, like "pipeline"/"sort_free"
-    "deferred": False,
-    # which job class produced the number (ISSUE 16): checking (False)
-    # or the invariant-inference predicates x states filter (True -
-    # predicate-evals/s payloads, bench.py --infer)
-    "infer": False,
-    # which state space produced the number (ISSUE 18): the full one
-    # (False/False) or one shrunk by symmetry canonicalization /
-    # partial-order ample-set pruning (bench.py --reduce-ab puts the
-    # reduced engine's settings in explicitly)
-    "symmetry": False,
-    "por": False,
-}
-
-
-def bench_payload(payload: dict,
-                  journal: Optional[RunJournal] = None) -> dict:
-    """Assemble one bench metric line: base contract fields + `payload`,
-    schema-validated by stamping it through a journal as a
-    `bench_metric` event (an in-memory journal when none is given).
-    Returns the payload WITHOUT the journal envelope - the emitted JSON
-    line is byte-compatible with every committed BENCH_*.json."""
-    out = dict(_BENCH_BASE)
-    out.update(payload)
-    j = journal if journal is not None else RunJournal()
-    if "error" in out:
-        # failure payloads carry the contract fields too (zeroed metric)
-        j.event("bench_metric", **{
-            k: out.get(k, _BENCH_BASE.get(k)) for k in
-            ("metric", "value", "unit", "vs_baseline")
-        }, error=str(out["error"]))
-    else:
-        j.event("bench_metric", **out)
-    return out

@@ -177,13 +177,6 @@ class SupervisorOptions:
     # max_regrow is exhausted); "on" prefers it over regrowing at the
     # FIRST fpset saturation; "off" removes the rung from the ladder
     spill: str = "auto"
-    # CLI -phase-timing: swap the fused segment dispatch for the
-    # host-fenced expand/commit step loop (obs.phases.PhasedRuntime) so
-    # every level gets MEASURED phase walls as `phase` journal events.
-    # Bit-for-bit results; costs a fence per step (PERF.md round 11).
-    # Adapters without a phased build (pipelined, sharded) fall back to
-    # the free segment-scope attribution every run gets anyway.
-    phase_timing: bool = False
     # initial host-store capacity (auto-grows in host RAM)
     spill_capacity: int = 1 << 15
     # rung-3 floor: chunk never shrinks below this
@@ -442,35 +435,6 @@ class SingleDeviceAdapter:
             store=store, on_event=on_event,
             spill_write_hook=spill_write_hook,
         )
-
-    def supports_phase_timing(self) -> bool:
-        # fencing the pipelined body would serialize the overlap it
-        # exists to create; the ladder's segment-scope attribution
-        # still applies there
-        return not self.pipeline
-
-    def build_phased(self, params: dict, ckpt_every: int, recorder):
-        """(template, seg_fn) through obs.phases.PhasedRuntime: the
-        host-fenced expand/commit step loop with measured per-level
-        walls, bit-for-bit the fused segment's carry."""
-        from ..obs.phases import PhasedRuntime
-
-        backend = self.backend
-        check_deadlock = self.check_deadlock
-        if backend is None:
-            from ..engine.backend import kubeapi_backend
-
-            backend = kubeapi_backend(self.cfg)
-            check_deadlock = None  # the kubeapi backend's own default
-        rt = PhasedRuntime(
-            backend, self.chunk, params["queue_capacity"],
-            params["fp_capacity"], fp_index=self.fp_index,
-            seed=self.seed, fp_highwater=self.fp_highwater,
-            check_deadlock=check_deadlock, obs_slots=self.obs_slots,
-            sort_free=self.sort_free, deferred=self.deferred,
-            recorder=recorder,
-        )
-        return rt.init_fn(), rt.segment_fn(ckpt_every)
 
     def can_shrink(self, floor: int = MIN_CHUNK) -> bool:
         return not self.pipeline and self.chunk // 2 >= floor
@@ -808,24 +772,7 @@ def supervise(adapter, params: dict,
             spill_write_hook=faults.spill_write,
         )
 
-    # -phase-timing: measured per-level expand/commit walls through the
-    # host-fenced step loop, where the adapter supports it (unpipelined
-    # single-device); every run gets the free segment-scope attribution
-    # below regardless
-    phase_rec = None
-    if (opts.phase_timing
-            and callable(getattr(adapter, "build_phased", None))
-            and getattr(adapter, "supports_phase_timing",
-                        lambda: False)()):
-        from ..obs.phases import PhaseRecorder
-
-        phase_rec = PhaseRecorder()
-
     def build_engine(p):
-        if phase_rec is not None:
-            with span("build", phased=True):
-                return adapter.build_phased(p, opts.ckpt_every,
-                                            phase_rec)
         return adapter.build(p, opts.ckpt_every)
 
     def rebuild(p):
@@ -973,10 +920,6 @@ def supervise(adapter, params: dict,
             while True:
                 try:
                     faults.segment_start(segments)
-                    if phase_rec is not None:
-                        # a replayed segment re-measures; timings of
-                        # the failed attempt must not double-count
-                        phase_rec.reset()
                     t_dispatch = time.time()
                     with span("loop.dispatch"):
                         in_flight = seg_fn(good)
@@ -1215,10 +1158,9 @@ def supervise(adapter, params: dict,
                         _emit(opts, "coverage", visited=cov_visited,
                               sites=len(cov_sites), delta={},
                               saturated=True, level=cov_level)
-            # phase attribution (obs.phases): the free fence-scope rows
-            # (device wall + the host readback wall just measured) plus
-            # the measured per-level expand/commit walls in -phase-
-            # timing mode - pure host arithmetic over syncs already paid
+            # phase attribution (obs.phases): the fence-scope rows
+            # (device wall + the host readback wall just measured) -
+            # pure host arithmetic over syncs already paid
             from ..obs.phases import segment_phases
 
             for row in segment_phases(
@@ -1226,9 +1168,6 @@ def supervise(adapter, params: dict,
                 readback_s=readback.seconds,
             ):
                 _emit(opts, "phase", **row)
-            if phase_rec is not None:
-                for row in phase_rec.drain():
-                    _emit(opts, "phase", **row)
 
         # the final segment's snapshot has no next segment to hide
         # behind: write it at the fence

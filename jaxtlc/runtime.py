@@ -8,7 +8,7 @@ under the `build` spans of obs.spans; `CompileMeter` counts what XLA did
 meanwhile.
 
 Each entry point (`api.run_check`, `jaxtlc.serve` start-up, the
-`jaxtlc.dist` worker, `bench.py`, `chip_smoke.py`) calls
+`jaxtlc.dist` worker, `chip_smoke.py`) calls
 `enable_compile_cache()` once and resolves its platform through
 `require_platform()`; engines never do either themselves.  The point of
 both is that a run cannot look like a chip run when it is not one: CPU

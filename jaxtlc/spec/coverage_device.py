@@ -785,9 +785,8 @@ def kubeapi_coverage_plane(cfg: ModelConfig) -> CoveragePlane:
         ck = batch.shape[0]
         # one [S, ck] stack + ONE masked matvec instead of S separate
         # multiply-reduces: the per-site arithmetic fuses into a
-        # handful of elementwise ops and a single dot, which is what
-        # keeps the measured -coverage overhead in the sub-percent
-        # range (bench.py --cov-ab)
+        # handful of elementwise ops and a single dot (-coverage has
+        # no benchmark cell: its cost on the chip is not measured)
         cols = []
         for _k, _a, fn in entries:
             v = fn(ctx) if callable(fn) else jnp.int32(fn)

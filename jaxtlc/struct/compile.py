@@ -25,8 +25,8 @@ TPU-first design decisions (vs TLC's heap interpreter):
   state's set exceeds the slot budget (the hand kernel's convention).
 
 Reference semantics: /root/reference/KubeAPI.tla:455-768; every path is
-differentially pinned against the structural oracle (tests/test_struct
-_engine.py).
+differentially pinned against the structural oracle
+(tests/test_struct_engine.py).
 """
 
 from __future__ import annotations

@@ -542,7 +542,7 @@ def test_selfcheck_registry_pinned():
 
     assert sorted(FACTORIES) == [
         "covered", "covsharded", "deferred", "enumerator", "fused",
-        "infer", "narrowed", "phased", "pipelined", "por", "sharded",
+        "infer", "narrowed", "pipelined", "por", "sharded",
         "shardspill", "sim", "sortfree", "spill", "struct", "sweep",
         "symmetry",
     ]

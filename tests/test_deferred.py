@@ -382,53 +382,75 @@ def test_sharded_2dev_parity(ab_runs):
 
 
 def _hot_bucket_batch(seed: int, n: int):
-    """Random fingerprints squeezed into 32 hot buckets: round-0 claims
-    overflow into the straggler walk, which is what the dense form
-    replaces."""
-    rng = np.random.default_rng(seed)
-    lo = rng.integers(0, 2 ** 32, size=n, dtype=np.uint32)
-    hi = (rng.integers(0, 2 ** 5, size=n, dtype=np.uint32)) << 27
-    mask = rng.random(n) < 0.9
-    return lo, hi, mask
-
-
-def test_dense_walk_bit_for_bit(monkeypatch):
-    """The dense rank-claim walk (JAXTLC_DENSE_WALK=1) produces the
-    EXACT table words of the comparator-sort walk, on both the sorted
-    and the slab commit paths, under hot-bucket straggler pressure -
-    the claim the platform-auto selection rests on."""
+    """Random fingerprints whose STORED (mixed) form falls into 32 hot
+    buckets of a 256-bucket table (the table hashes by the top bits of
+    the mixed `hi`, so the batch is drawn in mixed space and unmixed):
+    round-0 claims overflow into the straggler walk, and a hot bucket's
+    ~30 words fill the buckets behind it."""
     import jax.numpy as jnp
 
-    from jaxtlc.engine.fpset import (
-        fpset_insert_slab,
-        fpset_insert_sorted,
-        fpset_new,
-    )
+    from jaxtlc.engine.fpset import _unmix
 
-    n, R = 384, 384
-    tabs = {}
-    for dense in ("0", "1"):
-        monkeypatch.setenv("JAXTLC_DENSE_WALK", dense)
-        s_a, s_b = fpset_new(1 << 11), fpset_new(1 << 11)
-        verdicts = []
-        for step in range(3):
-            lo, hi, mask = _hot_bucket_batch(100 + step, n)
-            lo, hi = jnp.asarray(lo), jnp.asarray(hi)
-            mask = jnp.asarray(mask)
-            s_a, na, ca, ra = fpset_insert_sorted(
-                s_a, lo, hi, mask, probe_width=R, claim_width=64,
-            )
-            s_b, nb, cb, rb = fpset_insert_slab(
-                s_b, lo, hi, mask, probe_width=R, claim_width=64,
-            )
-            verdicts.append((np.asarray(na), np.asarray(ca)))
-        tabs[dense] = (np.asarray(s_a.table), np.asarray(s_b.table),
-                       verdicts)
-    assert (tabs["0"][0] == tabs["1"][0]).all()  # sorted path
-    assert (tabs["0"][1] == tabs["1"][1]).all()  # slab path
-    assert (tabs["0"][0] == tabs["0"][1]).all()  # sorted == slab
-    for (n0, c0), (n1, c1) in zip(tabs["0"][2], tabs["1"][2]):
-        assert (n0 == n1).all() and (c0 == c1).all()
+    rng = np.random.default_rng(seed)
+    mlo = rng.integers(1, 2 ** 32, size=n, dtype=np.uint32)
+    mhi = (rng.integers(0, 2 ** 5, size=n, dtype=np.uint32) << 27) | (
+        rng.integers(0, 2 ** 24, size=n, dtype=np.uint32))
+    lo, hi = _unmix(jnp.asarray(mlo), jnp.asarray(mhi))
+    mask = rng.random(n) < 0.9
+    return np.array(lo), np.array(hi), mask
+
+
+@pytest.mark.parametrize("path", ["sorted", "slab"])
+def test_dense_walk_matches_host_replay(path):
+    """The straggler walk's own pin, against a reference that shares no
+    code with it (a Python set and fpset.host_insert's one-at-a-time
+    linear walk): under hot-bucket pressure with a narrow round-0 claim
+    width, on either insert path, the verdicts name the highest lane of
+    every fresh fingerprint, the table holds each of them exactly once
+    (the host replay's words, wherever in a bucket they sit), and every
+    stored word is where a lookup's walk finds it."""
+    import jax.numpy as jnp
+
+    from jaxtlc.engine import fpset
+
+    insert = getattr(fpset, f"fpset_insert_{path}")
+    n, cap = 384, 1 << 11
+    s = fpset.fpset_new(cap)
+    ref = np.zeros_like(np.asarray(s.table))
+    seen = set()
+    for step in range(3):
+        lo, hi, mask = _hot_bucket_batch(100 + step, n)
+        lo[::7], hi[::7] = lo[1::7][:len(lo[::7])], hi[1::7][:len(hi[::7])]
+        s, is_new_c, c_idx, _ = insert(
+            s, jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(mask),
+            probe_width=n, claim_width=64,
+        )
+        fresh = {}
+        for lane in np.flatnonzero(mask):
+            key = (int(lo[lane]), int(hi[lane]))
+            if key not in seen:
+                fresh[key] = int(lane)  # the highest lane wins
+        new_lanes = np.asarray(c_idx)[np.asarray(is_new_c)]
+        assert sorted(new_lanes.tolist()) == sorted(fresh.values())
+        for key in fresh:
+            assert fpset.host_insert(ref, *key)
+        seen |= set(fresh)
+
+    def words(t):
+        pairs = t.reshape(-1, 2)
+        return sorted(map(tuple, pairs[pairs.any(axis=1)].tolist()))
+
+    table = np.asarray(s.table).copy()
+    assert len(words(table)) == len(seen)  # the distinct count
+    assert words(table) == words(ref)
+    # the lookup invariant: a host walk finds every stored word
+    assert not any(fpset.host_insert(table, *key) for key in seen)
+    # and the pressure was real: some words walked past a full bucket
+    pairs = table.reshape(-1, 2)
+    home = np.array([fpset.bucket_of_host(int(h), cap // fpset.BUCKET)
+                     for h in pairs[:, 1]])
+    at = np.arange(len(pairs)) // fpset.BUCKET
+    assert (pairs.any(axis=1) & (home != at)).sum() > 10
 
 
 # ---------------------------------------------------------------------------

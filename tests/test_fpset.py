@@ -118,17 +118,16 @@ def test_high_load():
     assert int(np.asarray(new).sum()) == 700
 
 
-@pytest.mark.parametrize("dense", ["0", "1"], ids=["sort-walk", "dense-walk"])
-def test_blocked_write_bit_for_bit(monkeypatch, dense):
+@pytest.mark.parametrize("path", ["sorted", "slab"])
+def test_blocked_write_bit_for_bit(monkeypatch, path):
     """A wide write goes block by block behind a trip count (ISSUE 26:
     `_blocked`, round 0 over its compacted claimers, the straggler walk
     over its live prefix): the table words and verdicts are those of
     the one whole-width scatter, with and without straggler pressure,
-    and the distinct count is the set's."""
+    on either insert path, and the distinct count is the set's."""
     from jaxtlc.engine import fpset
-    from jaxtlc.engine.fpset import fpset_insert_sorted
 
-    monkeypatch.setenv("JAXTLC_DENSE_WALK", dense)
+    insert = getattr(fpset, f"fpset_insert_{path}")
     n = 1024
     assert fpset._blocked(n)
     got = {}
@@ -145,7 +144,7 @@ def test_blocked_write_bit_for_bit(monkeypatch, dense):
             mask = rng.random(n) < 0.9
             # step 0 claims in round 0 (several blocks); a narrow claim
             # width then sends most claimers to the straggler walk
-            s, is_new_c, c_idx, nreps = fpset_insert_sorted(
+            s, is_new_c, c_idx, nreps = insert(
                 s, jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(mask),
                 claim_width=128 if step else 0,
             )

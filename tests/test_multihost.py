@@ -11,8 +11,7 @@ real jax.distributed pod.  Every run_pod call AOT-compiles a sharded
 engine, so the tests are folded to the minimum compile count (three
 tests, six engine builds); width parity itself rides along as the
 resume-completion assertions.  The real 2-process gloo pod
-(subprocess, ~30s) is slow-marked; bench.py --multihost-ab commits
-its scaling + over-capacity evidence as MULTICHIP_r06.json."""
+(subprocess, ~30s) is slow-marked."""
 
 import os
 import signal

@@ -254,9 +254,9 @@ def test_segment_program_crosses_no_conditional_with_its_buffers():
     assert f"tensor<{table}>" in text and f"tensor<{queue}>" in text
 
 
-# the two-tier nest on stub bodies (no engine: the tier threshold is
-# chunk / 2 = 8,192 states of one level at the only width that has a
-# small tier, which no tier-1 model reaches)
+# the two-tier nest on stub bodies (the tier threshold is chunk / 2 =
+# 8,192 states of one level at the only width that has a small tier; a
+# real engine reaches it in test_real_engine_takes_both_tiers below)
 _TIER_WIDTHS = (3, 40, 100, 17, 9, 64, 8, 7)  # level widths
 _TIER_CHUNK, _TIER_SMALL = 16, 4
 
@@ -324,6 +324,51 @@ def test_two_tier_nest_is_the_step_by_step_choice(steps):
         popped -= _TIER_WIDTHS[at]
         at += 1
     assert (int(lvl), int(qh)) == (at, popped)
+
+
+def test_real_engine_takes_both_tiers():
+    """A real engine at chunk 2^14, the one width with a small tier
+    (PERF.md 7-8e): the 1x2 FF rung passes 8,192 states a level at
+    level 38, so by level 44 the engine has stepped in both tiers.
+    Level by level it has taken the bodies the step-by-step rule takes
+    (the big body while at least chunk / 2 of the level is left, the
+    chunk / 16 body otherwise), and what it generated, found distinct,
+    popped and queued is the chunk-1024 engine's, which has one tier.
+    (The full signature is not compared: within a batch the highest
+    lane claims a duplicate, so per-action attribution follows the
+    batch boundaries.)"""
+    from jaxtlc.config import make_scaled
+    from jaxtlc.engine.bfs import obs_rows
+
+    cfg, upto, chunk = make_scaled(1, 2, False, False), 44, 1 << 14
+
+    def levels(ck_):
+        init_fn, _run, step_fn = make_engine(
+            cfg, chunk=ck_, queue_capacity=1 << 16, fp_capacity=1 << 20,
+            obs_slots=64)
+        carry, seg = init_fn(), step_fn.segment(8)
+        while int(carry.level) <= upto:
+            carry = seg(carry)
+        assert int(carry.viol) == 0
+        return [r for r in obs_rows(carry)[0] if r["level"] <= upto]
+
+    two_tier, one_tier = levels(chunk), levels(1024)
+    assert len(two_tier) == len(one_tier) == upto
+    counted = ("level", "generated", "distinct", "queue", "expanded")
+    for a, b in zip(two_tier, one_tier):
+        assert [a[k] for k in counted] == [b[k] for k in counted]
+    # level 1 is the Init states: what its row says was popped
+    width, bodies, tiers = two_tier[0]["expanded"], 0, set()
+    for row in two_tier:
+        left = width
+        while left > 0:
+            big = left >= chunk // 2
+            left -= min(chunk if big else chunk // 16, left)
+            bodies += 1
+            tiers.add(big)
+        assert row["bodies"] == bodies, row
+        width = row["queue"]
+    assert tiers == {True, False}
 
 
 # ---------------------------------------------------------------------------

@@ -1,22 +1,23 @@
 """Tooling smoke: the instruments must not silently rot (ISSUEs 4, 5).
 
-tools/profile_v4.py is the instrument every PERF.md round leans on;
-tools/tlcstat.py and the Chrome-trace exporter are the observability
-plane's operator surface; bench.py's metric payloads are the BENCH_*
-history contract.  A broken import, drifted engine signature, or a
-payload missing its required fields must show up in tier-1, not on the
-next TPU session.  Each tool's --tiny runs its WHOLE pipeline
-in-process.
+tools/tlcstat.py, tools/covdiff.py and the Chrome-trace exporter are the
+observability plane's operator surface; tools/loadgen.py and
+tools/chaos.py drive the service.  A broken import or a drifted engine
+signature must show up in tier-1, not on the next TPU session.  Each
+tool's --tiny runs its WHOLE pipeline in-process.
 """
 
-import glob
+import ast
+import functools
 import importlib.util
-import io
 import json
 import os
-from contextlib import redirect_stdout
+import re
+import tokenize
 
 import pytest
+
+ROOT = os.path.normpath(os.path.join(os.path.dirname(__file__), os.pardir))
 
 
 def _load_tool(name):
@@ -28,31 +29,6 @@ def _load_tool(name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
-
-
-def test_profile_v4_tiny_smoke(capsys):
-    spec = importlib.util.spec_from_file_location(
-        "profile_v4",
-        os.path.join(os.path.dirname(__file__), os.pardir, "tools",
-                     "profile_v4.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    buf = io.StringIO()
-    with redirect_stdout(buf):
-        mod.main(["--tiny"])
-    out = buf.getvalue()
-    # every stage line the PERF rounds read must be present
-    for needle in (
-        "vmap(step) expansion",
-        "fpset_insert_sorted",
-        "REAL step_fn",
-        "expand stage (seam)",
-        "commit stage (real step - expand)",
-        "PIPELINED step_fn",
-        "overlap efficiency:",
-    ):
-        assert needle in out, f"profiler output lost {needle!r}:\n{out}"
 
 
 def test_covdiff_tiny_smoke(capsys):
@@ -78,75 +54,6 @@ def test_tlcstat_tiny_smoke(capsys):
     for needle in ("ds/min", "fp space", "(spilling)", "spill tier:",
                    "ETA", "VERDICT:", "tlcstat tiny OK"):
         assert needle in out, f"tlcstat output lost {needle!r}:\n{out}"
-
-
-def test_costmodel_tiny_smoke(capsys):
-    """costmodel --tiny: the sweep -> fit -> COSTMODEL.json -> PERF
-    table pipeline on the synthetic measurer, whose walls are exactly
-    linear - so the smoke asserts the fitter RECOVERS the planted
-    coefficients (no engine compiles: tier-1 budget; the committed
-    COSTMODEL.json exercises the real measurement path)."""
-    mod = _load_tool("costmodel")
-    assert mod.main(["--tiny"]) == 0
-    out = capsys.readouterr().out
-    for needle in ("| chunk |", "costmodel tiny OK"):
-        assert needle in out, f"costmodel output lost {needle!r}:\n{out}"
-
-
-def test_committed_costmodel_document():
-    """The committed COSTMODEL.json (the measured baseline ROADMAP #1's
-    MXU commit rewrite is judged against) satisfies the document
-    contract: every phase measured at every chunk, fits present, and
-    the commit-phase breakdown (sort vs probe vs enqueue) non-trivial."""
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "COSTMODEL.json")
-    assert os.path.exists(path), "COSTMODEL.json must be committed"
-    with open(path) as f:
-        doc = json.load(f)
-    mod = _load_tool("costmodel")
-    assert doc["version"] == mod.COSTMODEL_VERSION
-    assert doc["workload"] == "Model_1"
-    chunks = {str(c) for c in doc["chunks"]}
-    for p in mod.PHASES:
-        assert set(doc["ms_per_step"][p]) == chunks, p
-        assert "a_ms" in doc["fit"][p]
-    # the fitted commit breakdown: sort + probe + enqueue account for
-    # the commit half at the largest chunk (within measurement slop)
-    big = str(max(doc["chunks"]))
-    parts = sum(doc["ms_per_step"][p][big]
-                for p in ("sort", "probe", "enqueue"))
-    assert parts > 0
-    assert doc["ms_per_step"]["commit"][big] > 0
-    assert doc["phase_event_ms_per_step"]["commit"][big] > 0
-    # v2 (ISSUE 12): the sort-free columns ride the same document, and
-    # the committed numbers must carry the acceptance relation - the
-    # hash-slab dedup at the largest chunk is >= 2x cheaper than the
-    # two full-width sorts it replaces (deterministic: this checks the
-    # COMMITTED measurement, not the machine running the test)
-    assert doc["ms_per_step_sort_free"]["sort"][big] <= (
-        doc["ms_per_step"]["sort"][big] / 2.0
-    )
-    # v3 (ISSUE 15): the deferred-evaluation columns ride the same
-    # document, and the committed `inv` subphase at the largest chunk
-    # is >= 2x cheaper under deferred evaluation than immediate (the
-    # distinct-first acceptance relation)
-    assert doc["ms_per_step_deferred"]["inv"][big] <= (
-        doc["ms_per_step_sort_free"]["inv"][big] / 2.0
-    )
-    for p in mod.PHASES:
-        assert "a_ms" in doc["fit_sort_free"][p], p
-        assert "a_ms" in doc["fit_deferred"][p], p
-        # v2 clamps: no fitted slope may be negative (the r11 enqueue
-        # column's -1.32 is the regression this guards); v3 extends
-        # the same physicality rule to intercepts (the v2 sort
-        # a_ms = -0.4441 is the regression THAT guards)
-        for table in ("fit", "fit_sort_free", "fit_deferred"):
-            assert doc[table][p]["b_ms_per_1k"] >= 0, (table, p)
-            assert doc[table][p]["a_ms"] >= 0, (table, p)
-    # and the table renderer accepts the committed document
-    assert "| chunk |" in mod.perf_table(doc)
-    assert "sort-free commit" in mod.perf_table(doc)
-    assert "deferred evaluation" in mod.perf_table(doc)
 
 
 def test_loadgen_tiny_smoke(capsys):
@@ -230,75 +137,12 @@ def test_loadgen_cache_tiny_smoke(capsys):
 
 def test_trace_exporter_tiny_smoke(capsys):
     """The Chrome-trace exporter's --tiny: synthesize a journal, export
-    it, and assert the expand/commit lanes landed in the JSON."""
+    it, and assert the bare-segment rendering landed in the JSON."""
     from jaxtlc.obs import trace as obs_trace
 
     assert obs_trace.main(["--tiny"]) == 0
     out = capsys.readouterr().out
     assert "trace-export tiny OK" in out
-
-
-# ---- bench payload contract (ISSUE 5 satellite) --------------------------
-
-
-REQUIRED_PAYLOAD_FIELDS = ("metric", "value", "unit", "vs_baseline")
-
-
-def test_bench_emit_enforces_payload_contract(capsys):
-    """Every line bench.py emits goes through the journal-validated
-    payload view: required fields are always present (base-filled), and
-    the line doubles as a schema-checked bench_metric event."""
-    spec = importlib.util.spec_from_file_location(
-        "bench",
-        os.path.join(os.path.dirname(__file__), os.pardir, "bench.py"),
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench._emit({"metric": "x_per_s", "value": 1.5, "unit": "x/s",
-                 "workload": "FF"})
-    bench._emit({"error": "deliberate"})  # failure payloads too
-    lines = capsys.readouterr().out.strip().splitlines()
-    for line in lines:
-        payload = json.loads(line)
-        for field in REQUIRED_PAYLOAD_FIELDS:
-            assert field in payload, f"payload lost {field!r}: {payload}"
-        assert "pipeline" in payload
-        # ISSUE 12: which commit dedup produced the number rides every
-        # payload, exactly like the pipeline flag
-        assert "sort_free" in payload
-        # ISSUE 14: which SEARCH produced the number (exhaustive BFS
-        # vs the random-walk simulation tier) rides every payload too
-        assert "sim" in payload
-        # ISSUE 15: which EXPAND mode produced the number (immediate
-        # per-candidate vs distinct-first deferred inv/cert) too
-        assert "deferred" in payload
-        # ISSUE 18: which STATE SPACE produced the number (full vs
-        # symmetry-canonicalized / POR-pruned) rides every payload
-        assert "symmetry" in payload
-        assert "por" in payload
-    # both emissions were journaled as validated bench_metric events
-    kinds = [e["event"] for e in bench._JOURNAL.events]
-    assert kinds.count("bench_metric") == 2
-
-
-def test_committed_bench_payloads_have_required_fields():
-    """The committed BENCH_*.json history (driver wrappers whose
-    `parsed` member is the bench payload line) must satisfy the same
-    contract the emitter now enforces."""
-    root = os.path.join(os.path.dirname(__file__), os.pardir)
-    paths = sorted(glob.glob(os.path.join(root, "BENCH_*.json")))
-    assert paths, "no committed BENCH_*.json payloads found"
-    for path in paths:
-        with open(path) as f:
-            doc = json.load(f)
-        payload = doc.get("parsed")
-        if payload is None:  # a failed round records no payload
-            continue
-        for field in REQUIRED_PAYLOAD_FIELDS:
-            assert field in payload, (
-                f"{os.path.basename(path)} payload lost {field!r}: "
-                f"{payload}"
-            )
 
 
 def test_loadgen_overload_tiny_smoke(capsys):
@@ -337,3 +181,98 @@ def test_chaos_serve_tiny_smoke(capsys):
     assert mod.main(["--serve", "--tiny"]) == 0
     out = capsys.readouterr().out
     assert "chaos serve OK" in out, out
+
+
+# ---- reference guard: what the documents name exists ---------------------
+
+
+def _py_files(*dirs):
+    for d in dirs:
+        for base, _dirs, names in os.walk(os.path.join(ROOT, d)):
+            for n in sorted(names):
+                if n.endswith(".py"):
+                    yield os.path.join(base, n)
+
+
+@functools.lru_cache(maxsize=None)
+def _prose(source):
+    """The text a reader of `source` sees: a markdown file whole, or the
+    comments and string literals (docstrings, help strings) of every
+    module under jaxtlc/."""
+    if source.endswith(".md"):
+        with open(os.path.join(ROOT, source), encoding="utf-8") as f:
+            return f.read()
+    out = []
+    for path in _py_files("jaxtlc"):
+        with open(path, "rb") as f:
+            out += [t.string for t in tokenize.tokenize(f.readline)
+                    if t.type in (tokenize.COMMENT, tokenize.STRING)]
+    return "\n".join(out)
+
+
+DOCUMENTS = ["README.md", ".claude/skills/verify/SKILL.md", "jaxtlc"]
+
+
+@pytest.mark.parametrize("source", DOCUMENTS)
+def test_named_files_exist(source):
+    """Every tools/*.py, every path under the repo's own directories,
+    every bare *.py and every top-level record (*.json in capitals)
+    that a document names is on disk: a sentence about a deleted
+    instrument fails here, not in a reader's shell."""
+    text = _prose(source)
+    basenames = {os.path.basename(p) for p in _py_files(
+        "jaxtlc", "tools", "tests", "benchmark")}
+    basenames |= {n for n in os.listdir(ROOT) if n.endswith(".py")}
+    missing = set()
+    for m in re.finditer(
+            r"(?<![\w/.-])((?:jaxtlc|tools|tests|benchmark|specs)/"
+            r"[\w./-]*\w\.(?:py|json|md|cfg|tla))\b", text):
+        if not os.path.exists(os.path.join(ROOT, m.group(1))):
+            missing.add(m.group(1))
+    for m in re.finditer(r"(?<![\w/.*<{-])([A-Za-z_]\w*\.py)\b", text):
+        if m.group(1) not in basenames:
+            missing.add(m.group(1))
+    for m in re.finditer(r"(?<![\w/.*<{-])([A-Z][A-Z0-9_]*(?:_r\d+)?"
+                         r"\.jsonl?)\b", text):
+        if not os.path.exists(os.path.join(ROOT, m.group(1))):
+            missing.add(m.group(1))
+    assert not missing, f"{source} names files that do not exist: " \
+                        f"{sorted(missing)}"
+
+
+def _parser_flags():
+    """Every single-dash option some argparse parser of the repo
+    defines (AST scan of add_argument calls: no import, no compile)."""
+    flags = set()
+    for path in _py_files("jaxtlc", "tools"):
+        with open(path, encoding="utf-8") as f:
+            tree = ast.parse(f.read(), path)
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "add_argument"):
+                flags |= {a.value for a in node.args
+                          if isinstance(a, ast.Constant)
+                          and isinstance(a.value, str)
+                          and re.fullmatch(r"-[A-Za-z][\w-]+", a.value)}
+    return flags
+
+
+# single-dash options of other programs that the documents quote:
+# TLC's own command line, and the C compiler's
+FOREIGN_FLAGS = {"-config", "-deadlock", "-fPIC", "-shared", "-std"}
+
+
+@pytest.mark.parametrize("source", DOCUMENTS)
+def test_named_flags_exist(source):
+    """Every `-flag` a document names is an option of one of the
+    repo's parsers (cli.py's above all): a removed flag cannot stay
+    in a synopsis, a help string or a comment."""
+    known = _parser_flags()
+    assert "-sharded" in known and "-checkpoint" in known
+    named = set(re.findall(
+        r"(?:(?<=[\s/|,(\[])|^)[`\"']?(-[A-Za-z][A-Za-z-]*[A-Za-z])\b",
+        _prose(source)))
+    unknown = named - known - FOREIGN_FLAGS
+    assert not unknown, f"{source} names flags no parser defines: " \
+                        f"{sorted(unknown)}"

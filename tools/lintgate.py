@@ -27,9 +27,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 # the registry is jax-free, so this stays an engine-free gate
 REQUIRED_FACTORIES = (
     "covered", "covsharded", "deferred", "enumerator", "fused",
-    "infer", "narrowed", "phased", "pipelined", "por", "sharded",
-    "shardspill", "sim", "sortfree", "spill", "struct", "sweep",
-    "symmetry",
+    "infer", "narrowed", "pipelined", "por", "sharded", "shardspill",
+    "sim", "sortfree", "spill", "struct", "sweep", "symmetry",
 )
 
 

@@ -238,8 +238,7 @@ def render(events) -> str:
             ) + (f"  |  queue {depth}" if depth is not None else "")
         )
     # phase attribution (obs.phases): cumulative measured walls per
-    # phase - expand/commit from -phase-timing, device/readback free
-    # at every fence
+    # phase - device/readback at every fence, the host spans by name
     phases = phase_totals(events)
     if phases:
         lines.append("phase walls: " + "  ".join(
@@ -328,7 +327,7 @@ def main(argv=None) -> int:
             _tiny_journal(path)
             frame = render(jr.read(path))
         assert "VERDICT: interrupted" in frame and "ds/min" in frame
-        assert "phase walls:" in frame and "expand" in frame
+        assert "phase walls:" in frame and "readback" in frame
         print(frame)
         print("tlcstat tiny OK")
         return 0

@@ -2,8 +2,8 @@
 
 Re-derives the scaled-config expected counts by running INDEPENDENT
 engine configurations and recording their agreement in
-SCALED_VALIDATION.json - the artifact bench.py's EXPECT pins and
-tests/test_scaled.py cite.  Independence axes:
+SCALED_VALIDATION.json - the artifact tests/test_scaled.py and the
+benchmark's kubeapi-2x1ff pins cite.  Independence axes:
 
 * engine geometry: different chunk sizes and fingerprint-table
   capacities execute different instruction schedules, candidate
@@ -37,7 +37,7 @@ ARTIFACT = os.path.join(
 )
 
 PINS = {
-    "2x1FF": (62014325, 19359985, 186),  # the bench.py --scaled flagship
+    "2x1FF": (62014325, 19359985, 186),  # the flagship (benchmark cell kubeapi-2x1ff.sharded4)
     "1x2FF": (30582846, 9942722, 160),  # tests/test_scaled.py slow pin
 }
 
