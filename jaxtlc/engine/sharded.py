@@ -198,10 +198,12 @@ class ShardEx(NamedTuple):
     ovf: jnp.ndarray  # [chunk, L] slot overflows
     dead: jnp.ndarray  # [chunk] deadlocked popped states
     order: jnp.ndarray  # [ncand] owner-sort permutation
-    s_own: jnp.ndarray  # [ncand] owner per sorted candidate
+    # the next three from the D per-owner counts, no gather
+    # (sorted_route)
+    s_own: jnp.ndarray  # [ncand] owner per sorted candidate (D: none)
     s_pos: jnp.ndarray  # [ncand] position within owner bucket
     s_valid: jnp.ndarray  # [ncand] sorted-candidate validity
-    route_ovf: jnp.ndarray  # [] bucket overflow anywhere this device
+    route_ovf: jnp.ndarray  # [] a count above the bucket's B slots
     route_fill: jnp.ndarray  # [] int32 fullest destination bucket
     r_flat: jnp.ndarray  # [D*B, F] received (owner-side) candidates
     r_lo: jnp.ndarray  # [D*B] uint32 received fp low words
@@ -273,6 +275,71 @@ def compact_rows(padded, cnt, start, width: int):
             padded, (d * bucket - first[d] + start,), (width,))
         out = jnp.where((j >= first[d]) & (j < ends[d]), piece, out)
     return out
+
+
+def owner_counts(key, D: int):
+    """Candidates per owner, [D] int32, from an owner key of any order
+    (values 0 .. D; D marks a lane that routes nowhere and is not
+    counted): one [D, n] compare-reduce."""
+    owners = jnp.arange(D, dtype=jnp.int32)
+    return (key[None, :] == owners[:, None]).sum(axis=1, dtype=jnp.int32)
+
+
+def sorted_route(counts, ncand: int):
+    """What a stable sort by owner key implies for sorted lane i, from
+    the D per-owner counts alone - no gather through the permutation
+    and no search of the sorted key: bucket d is lanes
+    [starts[d], ends[d]), so the lane's owner is the number of buckets
+    that end at or before it (D on the invalid tail), it is valid
+    below ends[D - 1], and its position in its bucket is i less the
+    counts of those same buckets (the invalid tail counts on from
+    ends[D - 1]).  Returns (s_own, s_pos, s_valid), [ncand] each."""
+    (D,) = counts.shape
+    ends = jnp.cumsum(counts)
+    i = jnp.arange(ncand, dtype=jnp.int32)
+    s_own = jnp.zeros(ncand, jnp.int32)
+    first = jnp.zeros(ncand, jnp.int32)
+    for d in range(D):
+        past = i >= ends[d]
+        s_own = s_own + past
+        first = first + jnp.where(past, counts[d], 0)
+    return s_own, i - first, i < ends[D - 1]
+
+
+def sorted_verdicts(verd, s_own):
+    """verd[s_own[i], i - starts[s_own[i]]] for every sorted lane i
+    ([ncand] of verd's dtype; zero on the invalid tail), without a
+    gather: the send pack turned round.  Bucket d's lanes are
+    contiguous from starts[d] (the sorted owners give the counts back
+    by one compare-reduce) and its verdicts are row d of the returned
+    [D, B] batch, so lane i of bucket d reads flat element
+    d * B + i - starts[d]: one dynamic slice of ncand elements an
+    owner and a select.  On a skewed body starts[d] passes d * B (and
+    a lane past its bucket's B slots reads on into the next row: the
+    caller gates those), so the flat verdicts carry ncand zeros in
+    front and behind and no slice is ever clamped."""
+    D, B = verd.shape
+    (ncand,) = s_own.shape
+    counts = owner_counts(s_own, D)
+    starts = jnp.cumsum(counts) - counts
+    pad = jnp.zeros(ncand, verd.dtype)
+    padded = jnp.concatenate([pad, verd.reshape(-1), pad])
+    out = jnp.zeros(ncand, verd.dtype)
+    for d in range(D):
+        piece = lax.dynamic_slice(
+            padded, (ncand + d * B - starts[d],), (ncand,))
+        out = jnp.where(s_own == d, piece, out)
+    return out
+
+
+def masked_hist(ids, mask, n_bins: int):
+    """Lanes per id in 0 .. n_bins - 1 among those `mask` keeps, [n_bins]
+    uint32: an [n_bins, n] compare-reduce - dense work in place of an
+    n-element scatter-add (engine.bfs's enqueue has counted its
+    per-action distinct states this way since round 4)."""
+    bins = jnp.arange(n_bins, dtype=jnp.int32)
+    return ((ids[None, :] == bins[:, None]) & mask[None, :]).sum(
+        axis=1, dtype=jnp.uint32)
 
 
 def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int,
@@ -626,8 +693,9 @@ def make_sharded_engine(
         # issued FIRST so this collective can be in flight while chunk
         # k's expansion + candidate-routing all_to_all below run; it
         # feeds only source-side statistics, never control flow.  With
-        # nothing pending (pv_svalid all false) every update lands in
-        # the dump rows, so fill/drain iterations are exact no-ops.
+        # nothing pending (pv_svalid all false, pv_n 0) nothing is new
+        # and no popped row is counted, so fill/drain iterations are
+        # exact no-ops.
         if pipeline:
             with jax.named_scope("jaxtlc.verdict_return"):
                 verd_prev = lax.all_to_all(
@@ -635,22 +703,17 @@ def make_sharded_engine(
                     tiled=False,
                 )
                 p_got = (
-                    verd_prev[
-                        jnp.clip(c.pv_sown[0], 0, D - 1),
-                        jnp.clip(c.pv_pos[0], 0, B - 1),
-                    ] == 1
+                    sorted_verdicts(verd_prev, c.pv_sown[0]) == 1
                 ) & c.pv_svalid[0] & (c.pv_pos[0] < B)
                 is_new_prev = (
                     jnp.zeros(ncand, bool).at[c.pv_order[0]].set(p_got)
                 )
                 newdeg_prev = is_new_prev.reshape(chunk, L).sum(axis=1)
                 p_mask = jnp.arange(chunk, dtype=jnp.int32) < c.pv_n[0]
-                outdeg_hist0 = c.outdeg_hist[0].at[
-                    jnp.where(p_mask, newdeg_prev, L + 1)
-                ].add(1)
-                act_dist0 = c.act_dist[0].at[
-                    jnp.where(is_new_prev, c.pv_faction[0], n_labels)
-                ].add(1)
+                outdeg_hist0 = c.outdeg_hist[0].at[:L + 1].add(
+                    masked_hist(newdeg_prev, p_mask, L + 1))
+                act_dist0 = c.act_dist[0].at[:n_labels].add(
+                    masked_hist(c.pv_faction[0], is_new_prev, n_labels))
         else:
             outdeg_hist0 = c.outdeg_hist[0]
             act_dist0 = c.act_dist[0]
@@ -710,46 +773,52 @@ def make_sharded_engine(
                     packed, nbits, fp_index, seed)
 
         # ---- route candidates to owners over ICI ----
-        # sort by owner, then slice into D contiguous buckets of B slots
-        # (B = route_factor * ncand / D: send bytes stay O(ncand) as the
-        # mesh grows; overflow halts rather than dropping a candidate)
+        # sort by owner -> bucket boundaries -> one row gather -> pack:
+        # the stable sort leaves D contiguous buckets (and the invalid
+        # tail), which the pack cuts to B slots each (B = route_factor *
+        # ncand / D: send bytes stay O(ncand) as the mesh grows;
+        # overflow halts rather than dropping a candidate).  Nothing
+        # here indexes per element at candidate width: the boundaries
+        # are D compare-and-sums over the unsorted key, a sorted lane's
+        # owner, validity and position follow from them by arithmetic
+        # (sorted_route), and the fingerprint words and the valid flag
+        # ride the one row gather the sort needs anyway.  On the chip an
+        # element gather costs 7-9 ns a lane live or dead, a row of 37
+        # words under 3 (PERF.md section 6, PR 30)
         with jax.named_scope("jaxtlc.route"):
-            own = owner_of(hi)
-            order = jnp.argsort(jnp.where(fvalid, own, D), stable=True)
-            s_flat = flat[order]
-            s_lo, s_hi = lo[order], hi[order]
-            s_own = jnp.where(fvalid, own, D)[order]
-            s_valid = fvalid[order]
-            # position within bucket
-            starts = jnp.searchsorted(
-                s_own, jnp.arange(D + 1), side="left"
-            )
-            pos_in_bucket = jnp.arange(ncand) - starts[
-                jnp.clip(s_own, 0, D)]
-            route_ovf = (s_valid & (pos_in_bucket >= B)).any()
-            counts = (starts[1:] - starts[:-1]).astype(jnp.int32)
+            key = jnp.where(fvalid, owner_of(hi), D)
+            order = jnp.argsort(key, stable=True)
+            counts = owner_counts(key, D)
+            starts = jnp.cumsum(counts) - counts
+            s_own, pos_in_bucket, s_valid = sorted_route(counts, ncand)
+            route_ovf = (counts > B).any()
             # telemetry: the fullest destination bucket of this body
             route_fill = counts.max()
             payload = jnp.concatenate(
                 [
-                    s_flat,
-                    s_lo.astype(jnp.int32)[:, None],
-                    s_hi.astype(jnp.int32)[:, None],
-                    s_valid.astype(jnp.int32)[:, None],
+                    flat,
+                    lo.astype(jnp.int32)[:, None],
+                    hi.astype(jnp.int32)[:, None],
+                    fvalid.astype(jnp.int32)[:, None],
                 ],
                 axis=1,
-            )
+            )[order]
             # the owner sort left bucket d's candidates contiguous from
             # starts[d]: slot (d, p) takes sorted row starts[d] + p
             # while p is under the bucket's count, and is zero past it.
-            # A row gather; as a scatter of the ncand rows to (owner,
-            # position) this pack was a tenth of a 2x1FF step on the
-            # chip (PERF.md section 5, Step 0 of PR 27)
-            slot = jnp.arange(B, dtype=jnp.int32)
-            src = jnp.clip(starts[:D, None].astype(jnp.int32)
-                           + slot[None, :], 0, ncand - 1)
-            live = slot[None, :] < counts[:, None]
-            send = jnp.where(live[:, :, None], payload[src], 0)
+            # One dynamic slice of B rows an owner (B zero rows behind
+            # the payload, so none is clamped); as a row gather this
+            # pack took 0.81 ms of a 2x1FF body on the chip, as slices
+            # 0.11 (PERF.md, Step 0 of PR 30), and as a scatter of the
+            # ncand rows to (owner, position) a tenth of the step
+            # (section 5, Step 0 of PR 27)
+            padded = jnp.concatenate(
+                [payload, jnp.zeros((B, F + 3), jnp.int32)])
+            rows = jnp.stack([
+                lax.dynamic_slice(padded, (starts[d], 0), (B, F + 3))
+                for d in range(D)])
+            live = jnp.arange(B, dtype=jnp.int32)[None, :] < counts[:, None]
+            send = jnp.where(live[:, :, None], rows, 0)
             recv = lax.all_to_all(send, axis, split_axis=0,
                                   concat_axis=0, tiled=False)
             r = recv.reshape(DB, F + 3)
@@ -873,29 +942,27 @@ def make_sharded_engine(
                     axis, split_axis=0, concat_axis=0, tiled=False,
                 )
                 got_new = (
-                    verd[jnp.clip(s_own, 0, D - 1),
-                         jnp.clip(pos_in_bucket, 0, B - 1)]
-                    == 1
+                    sorted_verdicts(verd, s_own) == 1
                 ) & s_valid & (pos_in_bucket < B)
+                # undoing the permutation takes a gather or a scatter:
+                # the one per-element index left on the source side
                 is_new_local = jnp.zeros(ncand, bool).at[order].set(
                     got_new)
             with jax.named_scope("jaxtlc.level"):
                 newdeg = is_new_local.reshape(chunk, L).sum(axis=1)
-                outdeg_hist = (
-                    outdeg_hist0.at[
-                        jnp.where(mask, newdeg, L + 1)].add(1)
-                )
-                act_dist = (
-                    act_dist0.at[
-                        jnp.where(is_new_local, faction, n_labels)
-                    ].add(1)
-                )
+                outdeg_hist = outdeg_hist0.at[:L + 1].add(
+                    masked_hist(newdeg, mask, L + 1))
+                act_dist = act_dist0.at[:n_labels].add(
+                    masked_hist(faction, is_new_local, n_labels))
 
         with jax.named_scope("jaxtlc.level"):
             generated = c.generated[0] + valid.sum().astype(jnp.uint32)
             distinct = my_distinct + n_new.astype(jnp.uint32)
-            act_gen = c.act_gen[0].at[
-                jnp.where(fvalid, faction, n_labels)].add(1)
+            # the histograms by compare-reduce: their last bins (the
+            # scatter-adds' dump bins, which nothing reads) stay in the
+            # carry's shapes and are no longer written
+            act_gen = c.act_gen[0].at[:n_labels].add(
+                masked_hist(faction, fvalid, n_labels))
 
         cov_acc = {}
         if backend.coverage is not None:
@@ -1669,7 +1736,6 @@ def drain_pending_host(carry: ShardCarry) -> ShardCarry:
     act_dist = np.asarray(carry.act_dist).astype(np.int64)
     L = outdeg.shape[1] - 2
     chunk = ncand // L
-    n_labels = act_dist.shape[1] - 1
     for s in range(D):
         verd = send[:, s, :]
         got = (
@@ -1681,11 +1747,10 @@ def drain_pending_host(carry: ShardCarry) -> ShardCarry:
         is_new_local[order[s]] = got
         newdeg = is_new_local.reshape(chunk, L).sum(axis=1)
         mask = np.arange(chunk) < pv_n[s]
-        # dump-row adds included: bit-for-bit what the deferred device
-        # application would have added
-        np.add.at(outdeg[s], np.where(mask, newdeg, L + 1), 1)
-        np.add.at(act_dist[s],
-                  np.where(is_new_local, faction[s], n_labels), 1)
+        # bit-for-bit what the deferred device application would have
+        # added (the device counts by compare-reduce: no dump bin)
+        np.add.at(outdeg[s], newdeg[mask], 1)
+        np.add.at(act_dist[s], faction[s][is_new_local], 1)
     return carry._replace(
         outdeg_hist=jnp.asarray(outdeg.astype(np.uint32)),
         act_dist=jnp.asarray(act_dist.astype(np.uint32)),

@@ -23,9 +23,13 @@ from jaxtlc.engine.sharded import (
     compact_lanes,
     compact_rows,
     make_sharded_engine,
+    masked_hist,
+    owner_counts,
     result_from_shard_carry,
     route_bucket_width,
     route_geometry,
+    sorted_route,
+    sorted_verdicts,
 )
 from jaxtlc.frontend.model import resolve
 from jaxtlc.runtime import fp_mesh
@@ -496,3 +500,132 @@ def test_commit_counters_cross_a_regrow_and_a_reshard(mesh_run):
     # a pod's new rows all start from the old pod's maxima
     assert (np.asarray(halved.route_stat) == stat.max(axis=0)).all()
     assert np.asarray(halved.route_stat).shape == (2, 3)
+
+
+# -- (f) the source side without per-element indexing at candidate width --
+
+
+def sort_and_gather(own, valid, D):
+    """The forms sorted_route replaces, in numpy: the stable sort by
+    owner key, the key and the validity gathered through it, the
+    bucket starts searched in the sorted key."""
+    own, valid = np.asarray(own, np.int32), np.asarray(valid, bool)
+    key = np.where(valid, own, D).astype(np.int32)
+    order = np.argsort(key, kind="stable")
+    s_own, s_valid = key[order], valid[order]
+    starts = np.searchsorted(s_own, np.arange(D + 1), side="left")
+    pos = np.arange(len(key)) - starts[np.clip(s_own, 0, D)]
+    return (key, s_own, pos.astype(np.int32), s_valid,
+            starts[1:] - starts[:-1])
+
+
+# (owner a lane, valid a lane, D, bucket width B, a bucket overflows)
+ROUTE_CASES = {
+    "mixed": ([2, 0, 3, 0, 1, 2, 0, 3, 1, 0, 2, 2],
+              [1, 1, 0, 1, 1, 1, 0, 1, 1, 1, 0, 1], 4, 5, False),
+    "empty-owners": ([3, 1, 1, 3, 3, 1, 1, 3], [1] * 8, 4, 4, False),
+    "nothing-valid": ([0, 1, 2, 3, 0, 1], [0] * 6, 4, 3, False),
+    "everything-valid": ([1, 0, 3, 2, 2, 3, 0, 1], [1] * 8, 4, 4, False),
+    # owner 0 holds 5 > B = 3: route_ovf, and positions at and past B
+    "overflow": ([0, 0, 1, 0, 0, 2, 0, 3], [1, 1, 1, 1, 1, 0, 1, 1], 4, 3,
+                 True),
+    "one-device": ([0] * 7, [1, 0, 1, 1, 0, 1, 1], 1, 7, False),
+}
+
+
+@pytest.mark.parametrize("own,valid,D,B,ovf", ROUTE_CASES.values(),
+                         ids=ROUTE_CASES.keys())
+def test_sorted_route_is_the_sort_and_gather_form(own, valid, D, B, ovf):
+    key, s_own, pos, s_valid, cnt = sort_and_gather(own, valid, D)
+    counts = np.asarray(owner_counts(key, D))
+    assert counts.tolist() == cnt.tolist() == [
+        sum(1 for o, v in zip(own, valid) if v and o == d)
+        for d in range(D)]
+    got = sorted_route(counts, len(key))
+    for g, want in zip(got, (s_own, pos, s_valid)):
+        assert np.asarray(g).dtype == want.dtype
+        assert np.asarray(g).tolist() == want.tolist()
+    # the overflow flag as the counts give it
+    assert bool((counts > B).any()) == bool(
+        (s_valid & (pos >= B)).any()) == ovf
+    # the sorted owners give the counts back (the pipeline's stash)
+    assert np.asarray(owner_counts(got[0], D)).tolist() == cnt.tolist()
+
+
+# counts a bucket and its width: in the second and third the earlier
+# buckets hold more than d * B, so bucket d's slice starts in front of
+# the verdicts; in the last nothing was sent
+VERDICT_CASES = [([3, 2, 4, 1], 5), ([5, 2, 0, 1], 3), ([0, 7, 1, 2], 3),
+                 ([6], 8), ([0, 0, 0, 0], 4)]
+
+
+@pytest.mark.parametrize("cnt,B", VERDICT_CASES)
+def test_sorted_verdicts_are_the_gather_at_owner_and_position(cnt, B):
+    D, ncand = len(cnt), sum(cnt) + 3  # an invalid tail of 3
+    s_own, pos, s_valid = (np.asarray(a) for a in sorted_route(
+        np.asarray(cnt, np.int32), ncand))
+    rng = np.random.default_rng(sum(cnt) + B)
+    verd = rng.integers(0, 2, (D, B)).astype(np.uint8)
+    verd[:, 0] = 1  # a wrong row shows
+    gate = s_valid & (pos < B)
+    want = (verd[np.clip(s_own, 0, D - 1), np.clip(pos, 0, B - 1)] == 1
+            ) & gate
+    got = np.asarray(sorted_verdicts(verd, s_own))
+    assert got.dtype == verd.dtype and got.shape == (ncand,)
+    assert ((got == 1) & gate).tolist() == want.tolist()
+    assert (got[s_own == D] == 0).all()
+
+
+@pytest.mark.parametrize("n_bins,n,live", [(23, 64, 40), (5, 16, 0),
+                                           (3, 9, 9), (14, 1, 1)])
+def test_masked_hist_is_the_scatter_add_less_its_dump_bin(n_bins, n, live):
+    rng = np.random.default_rng(n_bins * n)
+    ids = rng.integers(0, n_bins, n).astype(np.int32)
+    mask = np.arange(n) < live  # a masked-out tail
+    want = np.zeros(n_bins + 1, np.uint32)
+    np.add.at(want, np.where(mask, ids, n_bins), 1)
+    got = np.asarray(masked_hist(ids, mask, n_bins))
+    assert got.dtype == np.uint32
+    assert got.tolist() == want[:n_bins].tolist()
+    assert int(got.sum()) == live
+
+
+def scoped_eqns(jaxpr, stack=""):
+    """(name stack, equation) of every equation of a traced program,
+    the bodies of its loops, maps and calls included."""
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        yield here, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from scoped_eqns(sub, here)
+
+
+def test_source_side_indexes_no_element_at_candidate_width():
+    """Inside jaxtlc.route, jaxtlc.verdict_return and jaxtlc.level
+    nothing gathers or scatters single elements at the ncand lanes of
+    a body: the one row gather by the owner sort's order and
+    is_new_local's scatter are all that index there (immediate and
+    pipelined bodies alike)."""
+    ncand = GEOM["chunk"] * kubeapi_backend(FF).n_lanes
+    for kw in ({}, dict(pipeline=True)):
+        init_fn, seg_fn = make_sharded_engine(FF, fp_mesh(4), segment=16,
+                                              **GEOM, **kw)
+        traced = jax.make_jaxpr(seg_fn)(init_fn())
+        found = []
+        for stack, eqn in scoped_eqns(traced.jaxpr):
+            if not any(sc in stack for sc in (
+                    "jaxtlc.route", "jaxtlc.verdict_return",
+                    "jaxtlc.level")):
+                continue
+            if not eqn.primitive.name.startswith(("gather", "scatter")):
+                continue
+            shapes_ = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+            if any(sh and sh[0] == ncand for sh in shapes_):
+                found.append((eqn.primitive.name,
+                              eqn.invars[0].aval.shape))
+        rows = kubeapi_backend(FF).cdc.n_fields + 3
+        # the payload's row gather by `order`, and undoing the
+        # permutation (in the pipeline's prologue when the verdicts are
+        # deferred)
+        assert sorted(found) == [("gather", (ncand, rows)),
+                                 ("scatter", (ncand,))], found
