@@ -30,8 +30,8 @@ def render_spec_section(spec) -> List[str]:
         a = spec.actions[name]
         extra = ""
         if a.slot_binders:
-            extra += "  slots=" + ",".join(
-                f"{nm}:{u}/cap4" for nm, u in a.slot_binders
+            extra += "  lanes=" + ",".join(
+                f"{nm}:{u}" for nm, u in a.slot_binders
             )
         if a.seq_reads:
             extra += (f"  seq_reads={a.seq_reads}"

@@ -8,7 +8,7 @@ the whole pass is milliseconds of host Python:
 
 * **Action decomposition** mirrors the lane walker's label attribution
   (struct/compile.py `_walk_seq` / struct/actions.py `_enum`): the
-  innermost expanded non-disjunction definition names the action, `\\/`
+  first expanded non-disjunction definition names the action, `\\/`
   and action-position `\\E` fork branches, `var' = e` / `var' \\in S`
   are writes, everything else is a guard.
 * **Read/write sets** per action: a variable is READ when its
@@ -24,12 +24,13 @@ the whole pass is milliseconds of host Python:
   every branch means the action can never fire.
 * **Invariant vacuity**: an INVARIANT that reads no state variable is
   checking nothing about the run.
-* **Slot/trap budget**: an action-position `\\E x \\in S` over a
-  STATE-DEPENDENT set compiles to SLOT_CAP k-th-set-bit lanes when the
-  element universe exceeds UNROLL_LIMIT; a reachable state whose set
-  grows past SLOT_CAP then halts the device run with
-  VIOL_SLOT_OVERFLOW.  The audit bounds the universe statically and
-  names the action up front.  Dynamic sequence reads (`s[expr]`) are
+* **Lane fan / trap budget**: an action-position `\\E x \\in S` over a
+  STATE-DEPENDENT set compiles to one lane per element of S's universe
+  (less what the compiler prunes at trace time), exact for any set
+  size; the audit bounds the universe statically and names the action
+  whose fan passes UNROLL_LIMIT up front (a wide step is compacted to
+  `compile.compact_width` slots a state at first; a state that fires
+  more halts the run and it starts again with twice the slots).  Dynamic sequence reads (`s[expr]`) are
   reported as trap sites, with their IF/CASE branch gating noted - the
   RaftReplication false-trap class (PERF.md round 7) as a line in a
   report instead of a dead device run.
@@ -51,11 +52,11 @@ from ..struct.shapes import (
     typeok_hints,
     universe,
 )
-from . import SEV_WARNING, Finding
+from . import SEV_INFO, SEV_WARNING, Finding
 
 # the LaneCompiler's fan-out constants (struct/compile.py); imported
 # rather than duplicated so the audit can never drift from the compiler
-from ..struct.compile import SLOT_CAP, UNROLL_LIMIT
+from ..struct.compile import UNROLL_LIMIT
 
 
 @dataclasses.dataclass
@@ -70,7 +71,7 @@ class ActionInfo:
     n_disabled: int = 0  # branches with a statically-FALSE guard
     slot_binders: List[Tuple[str, int]] = dataclasses.field(
         default_factory=list
-    )  # (binder name, element-universe size) on the mask/slot path
+    )  # (binder name, element-universe size) fanned as universe lanes
     seq_reads: int = 0  # dynamic sequence index sites
     gated_seq_reads: int = 0  # of those, inside an IF/CASE branch
 
@@ -425,7 +426,7 @@ class _SpecWalker:
             if state_dep:
                 u = self._dom_universe(dom_ast, br)
                 if u is not None and u > UNROLL_LIMIT:
-                    # the mask path: SLOT_CAP k-th-set-bit slot lanes
+                    # universe lanes: one per element, less the prune
                     for nm in names:
                         b2.slot_binders.append((nm, u))
             self._seq([body] + rest, 0, b2, label)
@@ -459,7 +460,8 @@ class _SpecWalker:
                 env = self._shape_env(br)
                 for p, a in zip(d.params, args):
                     b2.senv[p] = self._abs(a, env)
-                inner = label if d.body[0] == "or" else dname
+                inner = dname if label is None and d.body[0] != "or" \
+                    else label
                 self._seq([d.body] + rest, 0, b2, inner)
                 return
         if op == "unchanged":
@@ -543,14 +545,14 @@ def analyze_spec(model, var_shapes: Optional[dict] = None,
             ))
         for nm, u in info.slot_binders:
             findings.append(Finding(
-                layer="spec", check="slot-budget",
-                severity=SEV_WARNING, subject=label,
-                detail=(f"\\E {nm} picks from a state-dependent set of "
-                        f"up to {u} elements through {SLOT_CAP} slot "
-                        f"lanes (universe {u} > unroll limit "
-                        f"{UNROLL_LIMIT}); a reachable state whose set "
-                        f"exceeds {SLOT_CAP} halts with "
-                        "VIOL_SLOT_OVERFLOW"),
+                layer="spec", check="lane-fan",
+                severity=SEV_INFO, subject=label,
+                detail=(f"\\E {nm} picks from a state-dependent set "
+                        f"over a universe of {u} elements (> unroll "
+                        f"limit {UNROLL_LIMIT}): up to {u} universe "
+                        "lanes, less the elements a conjunct over the "
+                        "bound element's own fields rules out at "
+                        "trace time"),
             ))
 
     inv_reads: Dict[str, Set[str]] = {}

@@ -1086,6 +1086,38 @@ def _run_check_struct(args, spec) -> int:
     capture = art_plan is not None and not args.sharded
 
     def check():
+        # a compacted step (struct.compile.compact_width) halts loudly on
+        # a state with more live lanes than slots; the rung: every
+        # backend of the model with twice the slots, and the check again
+        # from its initial states, until the step is as wide as its
+        # static fan - there the overflow is a trap of the codec
+        from .engine.bfs import VIOL_SLOT_OVERFLOW
+        from .resil import SlotOverflowError
+        from .struct.cache import widen_slots
+
+        while True:
+            halt = None
+            try:
+                r, sup = check_once()
+            except SlotOverflowError as e:
+                halt = e
+            if halt is None and r.violation != VIOL_SLOT_OVERFLOW:
+                return r, sup
+            wider = widen_slots(sm, get_backend(
+                sm, spec.check_deadlock, bounds=bounds,
+                elide=not args.sharded, coverage=args.coverage,
+                symmetry=_symmetry(args), por=_por(args)))
+            if wider is None:
+                if halt is not None:
+                    raise halt
+                return r, sup
+            _sup_opts(args, log_holder[0]).on_event("degrade", dict(
+                rung="widen", resource="step_slots",
+                action="%d->%d" % wider,
+                reason="a state fired more lanes than the compacted "
+                       "step keeps; the check starts again"))
+
+    def check_once():
         log = log_holder[0]
         ckd = spec.check_deadlock
         cov = args.coverage

@@ -236,19 +236,55 @@ class CheckResult(NamedTuple):
     route_bytes: int = None
     commit_segments: tuple = None
     commit_rows: int = None
+    # struct-compiled step only (telemetry; None elsewhere): the static
+    # lane fan of the compiled step (`step_lanes`) and the slots a
+    # state keeps of it when it leaves the step (`step_slots`, the
+    # engine's candidate lanes a state; equal where the step is not
+    # compacted); packed 32-bit words a state; states popped and
+    # expanded (distinct less what was left on the queue: every state
+    # of an exhaustive run, once); lanes that fired (the per-action
+    # generated totals summed: generated less the initial states); and
+    # whether a trap of the compiled step (range, declared-universe or
+    # compaction overflow: VIOL_SLOT_OVERFLOW) halted the run
+    step_lanes: int = None
+    step_slots: int = None
+    state_words: int = None
+    states_expanded: int = None
+    lane_fires: int = None
+    struct_traps: int = None
 
 
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
                  "route_bucket", "route_bytes", "commit_segments",
                  "commit_rows")
+STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
+                 "states_expanded", "lane_fires", "struct_traps")
 
 
 def mesh_counters(result: CheckResult) -> dict:
-    """The mesh engine's counters of a result, as the extra fields of
-    the journal's `final` event; {} for a one-chip result."""
+    """The mesh engine's and the struct-compiled step's counters of a
+    result, as the extra fields of the journal's `final` event; {} for
+    a one-chip result of the hand kernel."""
     return {k: list(v) if isinstance(v, tuple) else v
-            for k in MESH_COUNTERS
+            for k in MESH_COUNTERS + STEP_COUNTERS
             for v in (getattr(result, k),) if v is not None}
+
+
+def with_step_counters(result: CheckResult, backend) -> CheckResult:
+    """`result` with the struct-compiled step's counters, where
+    `backend` is one (struct.backend marks its codec with the static
+    lane fan); any other backend's result comes back as it is."""
+    static = getattr(getattr(backend, "cdc", None), "static_lanes", None)
+    if static is None:
+        return result
+    return result._replace(
+        step_lanes=static,
+        step_slots=backend.n_lanes,
+        state_words=backend.cdc.n_words,
+        states_expanded=result.distinct - result.queue_left,
+        lane_fires=sum(result.action_generated.values()),
+        struct_traps=int(result.violation == VIOL_SLOT_OVERFLOW),
+    )
 
 
 def carry_done(carry: EngineCarry) -> bool:

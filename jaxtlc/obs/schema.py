@@ -69,7 +69,9 @@ EVENTS = {
                     "generated": _NUM, "distinct": _NUM, "queue": _NUM,
                     "wall_s": _NUM},
     # one per degradation-ladder transition (resil.supervisor): rung in
-    # ("regrow", "spill", "shrink", "oom", "halt")
+    # ("regrow", "spill", "shrink", "oom", "halt"), or "widen" from
+    # api._run_check_struct (a compacted struct step rebuilt with twice
+    # the slots, struct.cache.widen_slots)
     "degrade": {"rung": _STR, "resource": _STR, "action": _STR,
                 "reason": _STR},
     # host spill tier lifecycle (engine.spill): phase in

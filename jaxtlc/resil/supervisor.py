@@ -34,7 +34,8 @@ supervision loop that converts all three from run-killers into events:
 
   VIOL_SLOT_OVERFLOW (codec bit-widths too narrow) is NOT on the ladder
   - it needs a recompile - and degrades to checkpoint + actionable
-  error as before.
+  error as before (a compacted struct step's overflow is answered one
+  level up, by api._run_check_struct's widen rung).
 * **Preemption safety**: SIGTERM/SIGINT finish the current segment,
   write a final checkpoint generation, and return `interrupted=True`
   (the CLI exits with EXIT_INTERRUPTED and prints the resume command).
@@ -79,6 +80,7 @@ from ..engine.bfs import (
     carry_done,
     make_engine,
     mesh_counters,
+    with_step_counters,
     result_from_carry,
 )
 from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
@@ -460,10 +462,10 @@ class SingleDeviceAdapter:
             kw = dict(labels=self.backend.labels,
                       viol_names=self.backend.viol_names,
                       sites=self.cov_sites())
-        return result_from_carry(
+        return with_step_counters(result_from_carry(
             carry, wall, iterations=segments,
             fp_capacity=params["fp_capacity"], **kw,
-        )._replace(actual_fp_collision=afc)
+        )._replace(actual_fp_collision=afc), self.backend)
 
 
 class ShardedAdapter:
@@ -599,7 +601,7 @@ class ShardedAdapter:
         )
 
         D = int(self.mesh.devices.size)
-        return result_from_shard_carry(
+        return with_step_counters(result_from_shard_carry(
             carry, wall, iterations=segments,
             labels=self.backend.labels,
             viol_names=self.backend.viol_names,
@@ -607,7 +609,7 @@ class ShardedAdapter:
             sites=self.cov_sites(),
             route=route_geometry(self.backend, self.chunk, D,
                                  params["route_factor"]),
-        )
+        ), self.backend)
 
 
 def _params_from_meta(adapter, meta: dict, params: dict) -> dict:

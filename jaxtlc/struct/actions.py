@@ -13,9 +13,12 @@ ordered processing is complete for them.
 Operator applications expand into their definition body when the body
 mentions primes or UNCHANGED (action operators: API(self), Client(self),
 ...); otherwise they are state predicates and evaluate as guards.  The
-innermost expanded non-disjunction definition names the fired action -
-exactly the PlusCal label attribution TLC's coverage output uses
-(MC.out:44-1092 lists DoRequest/DoReply/... as the action names).
+first expanded non-disjunction definition on the way down from Next
+names the fired action - the PlusCal label attribution TLC's coverage
+output uses (MC.out:44-1092 lists DoRequest/DoReply/... as the action
+names); an action operator applied inside a named action (Paxos's
+Send(m) inside Phase1a(b)) does not rename it, as TLC splits Next into
+actions by its disjuncts only.
 """
 
 from __future__ import annotations
@@ -237,7 +240,7 @@ class ActionSystem:
                 for p, a in zip(d.params, args):
                     env2[p] = self.ev.eval(a, env, primed)
                 inner_label = label
-                if d.body[0] != "or":
+                if label is None and d.body[0] != "or":
                     inner_label = dname
                 self._enum(d.body, env2, primed, inner_label, outs)
                 return

@@ -27,7 +27,7 @@ import numpy as np
 from ..engine.backend import SpecBackend
 from ..engine.bfs import VIOL_ASSERT
 from .codec import StructCodec
-from .compile import LaneCompiler, TrapPolicy
+from .compile import LaneCompiler, TrapPolicy, compact_lanes, compact_width
 from .loader import StructModel
 from .shapes import infer_shapes, typeok_hints
 
@@ -92,7 +92,8 @@ def struct_backend(model: StructModel,
                    elide: bool = True,
                    coverage: bool = False,
                    symmetry: bool = False,
-                   por: bool = False) -> SpecBackend:
+                   por: bool = False,
+                   slots: int = 0) -> SpecBackend:
     """Compile `model` into a SpecBackend: parse -> shape-infer ->
     lane-compile, the pipeline struct.cache memoizes in-process.
 
@@ -101,8 +102,7 @@ def struct_backend(model: StructModel,
     codec's enum universes, mask bit counts and sequence caps shrink
     to the certified ranges (fewer packed uint32 words through the
     fingerprint/sort/probe path) and, with `elide` (default), the
-    compiler drops the range traps and slot lanes the bounds prove
-    safe while the backend carries the on-device certificate check
+    compiler drops the range traps the bounds prove safe while the backend carries the on-device certificate check
     that re-verifies every claimed bound on every generated state -
     so an unsound bound turns the verdict loud instead of silently
     narrowing real states away.  `elide=False` narrows the codec but
@@ -128,47 +128,73 @@ def struct_backend(model: StructModel,
     (analysis.symfind) before fingerprinting, POR prunes commutative
     interleavings through singleton ample sets.  Verdicts, invariant
     outcomes and rendered traces are preserved; DISTINCT/GENERATED
-    counts legitimately shrink, which is why both default off."""
+    counts legitimately shrink, which is why both default off.
+
+    `slots` is the least a compacted step keeps a state (0: what
+    compile.compact_width gives): struct.cache.widen_slots raises it
+    after a run met a state with more live lanes."""
+    from ..obs.spans import span
+
     system = model.system
     trap_policy = None
     cert = False
-    if bounds is not None and getattr(bounds, "certified", False):
-        var_shapes = {v: bounds.bounds[v] for v in system.variables}
-        if elide:
-            trap_policy = TrapPolicy(
-                elide_range=True,
-                card_bounds=dict(bounds.card_bounds),
-            )
-            cert = True
-    else:
-        bounds = None
-        hints = typeok_hints(system.ev, model.invariants,
-                             system.variables)
-        var_shapes = infer_shapes(system.ev, system.variables,
-                                  system.init_ast, system.next_ast,
-                                  hints=hints)
-    cdc = StructCodec(system.variables, var_shapes)
+    with span("build.struct.shapes"):
+        if bounds is not None and getattr(bounds, "certified", False):
+            var_shapes = {v: bounds.bounds[v] for v in system.variables}
+            if elide:
+                trap_policy = TrapPolicy(elide_range=True)
+                cert = True
+        else:
+            bounds = None
+            hints = typeok_hints(system.ev, model.invariants,
+                                 system.variables)
+            var_shapes = infer_shapes(system.ev, system.variables,
+                                      system.init_ast, system.next_ast,
+                                      hints=hints)
+        cdc = StructCodec(system.variables, var_shapes)
     compiler = LaneCompiler(system.ev, system.variables, var_shapes,
                             cdc, trap_policy=trap_policy)
-    batch_step = compiler.build_step(system.next_ast)
+    # jitted at the [1, F] shape the per-row seam calls them with: the
+    # lane walk (seconds of Python for a wide fan: Paxos's 256 lanes are
+    # 52k equations) then runs once per backend, and every engine trace
+    # after it - each tier of each check's per-call build - replays the
+    # cached jaxpr instead of walking the spec again
+    batch_step = jax.jit(compiler.build_step(system.next_ast))
     inv_fns = [
-        compiler.build_invariant(ast) for ast in model.invariants.values()
+        jax.jit(compiler.build_invariant(ast))
+        for ast in model.invariants.values()
     ]
     F = cdc.n_fields
 
-    # discover the lane structure (labels) with a shape-only trace
-    jax.eval_shape(batch_step, jax.ShapeDtypeStruct((1, F), jnp.int32))
+    # discover the lane structure (labels) with a shape-only trace:
+    # the lane walk itself (host span `build.struct.lanes`)
+    with span("build.struct.lanes"):
+        jax.eval_shape(batch_step,
+                       jax.ShapeDtypeStruct((1, F), jnp.int32))
     labels: List[str] = list(compiler.labels)
     action_names: Tuple[str, ...] = tuple(sorted(set(labels)))
     lane_action = jnp.asarray(
         [action_names.index(x) for x in labels], jnp.int32
     )
-    trap_stats = (compiler.trap_sites, compiler.elided_traps,
-                  compiler.reduced_slot_lanes)
+    # a wide static fan (universe lanes: Paxos's 256, ~8 live a state)
+    # leaves the step compacted to `width` slots a state, so the
+    # engine's candidate width follows the live lanes.  The coverage
+    # plane and POR read static lanes, so they keep the full fan
+    width = len(labels) if coverage or por else min(
+        len(labels), max(compact_width(len(labels)), slots))
+    compacted = width < len(labels)
+    trap_stats = (compiler.trap_sites + int(compacted),
+                  compiler.elided_traps)
 
     def step(vec):
-        succs, valid, ovf, afail = batch_step(vec[None])
-        return succs[0], valid[0], lane_action, afail[0], ovf[0]
+        # device scope of the compiled successor function (inside the
+        # engine's `jaxtlc.expand`): the lanes, and their compaction
+        with jax.named_scope("jaxtlc.step.struct"):
+            succs, valid, ovf, afail = batch_step(vec[None])
+            if compacted:
+                return compact_lanes(succs[0], valid[0], lane_action,
+                                     afail[0], ovf[0], width)
+            return succs[0], valid[0], lane_action, afail[0], ovf[0]
 
     def inv_check(vec):
         bits = jnp.int32(0)
@@ -267,7 +293,7 @@ def struct_backend(model: StructModel,
     backend = SpecBackend(
         cdc=cdc,
         step=step,
-        n_lanes=len(labels),
+        n_lanes=width,
         inv_check=inv_check,
         inv_codes=tuple(
             VIOL_INVARIANT_BASE + k for k in range(len(model.invariants))
@@ -275,7 +301,7 @@ def struct_backend(model: StructModel,
         initial_vectors=initial_vectors,
         labels=action_names,
         viol_names=viol_names,
-        lane_action=lane_action,
+        lane_action=None if compacted else lane_action,
         check_deadlock=check_deadlock,
         cert_check=cert_check,
         coverage=plane,
@@ -283,6 +309,8 @@ def struct_backend(model: StructModel,
     )
     # trap-audit surface (preflight renders which traps remain and why)
     backend.cdc.trap_stats = trap_stats
+    # the static fan before compaction (CheckResult.step_lanes)
+    backend.cdc.static_lanes = len(labels)
     return backend
 
 

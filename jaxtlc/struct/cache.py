@@ -145,6 +145,26 @@ def _bounds_key(bounds) -> str:
     return bounds.digest()
 
 
+# model key -> slots a state of the model's compacted step keeps, where
+# a run met a state with more live lanes than `compile.compact_width`
+# gave it (widen_slots); part of every backend and engine key
+_SLOT_FLOOR: dict = {}
+
+
+def widen_slots(model, backend):
+    """The rung a compaction overflow takes: twice the slots `backend`
+    kept a state, at most its static fan, for every backend of `model`
+    built from now on (this process).  Returns (old, new), or None
+    where `backend` is not compacted: its overflow is a trap of the
+    codec, which no width cures."""
+    static = getattr(backend.cdc, "static_lanes", backend.n_lanes)
+    if backend.n_lanes >= static:
+        return None
+    new = min(static, 2 * backend.n_lanes)
+    _SLOT_FLOOR[model_key(model)] = new
+    return backend.n_lanes, new
+
+
 def get_backend(model, check_deadlock: bool = True, bounds=None,
                 elide: bool = True, coverage: bool = False,
                 symmetry: bool = False, por: bool = False):
@@ -159,17 +179,25 @@ def get_backend(model, check_deadlock: bool = True, bounds=None,
     bools) attach the state-space reduction ops - distinct memo
     entries because the reduced engine has a different carry layout
     (COL_SYM ring column, prune counters) and different step XLA."""
+    from ..obs.spans import span
     from .backend import struct_backend
 
-    key = (model_key(model), bool(check_deadlock), _bounds_key(bounds),
-           bool(elide), bool(coverage), bool(symmetry), bool(por))
-    hit = _BACKEND_MEMO.get(key)
-    if hit is None:
-        hit = struct_backend(model, check_deadlock=check_deadlock,
-                             bounds=bounds, elide=elide,
-                             coverage=coverage, symmetry=symmetry,
-                             por=por)
-        _BACKEND_MEMO.put(key, hit)
+    spec = model_key(model)
+    slots = _SLOT_FLOOR.get(spec, 0)
+    key = (spec, bool(check_deadlock), _bounds_key(bounds),
+           bool(elide), bool(coverage), bool(symmetry), bool(por), slots)
+    # host span `build.struct`: the memo's look-up and, on a miss, the
+    # shape inference and the lane walk inside it (`build.struct.shapes`,
+    # `build.struct.lanes`) - the struct path's own part of a build
+    with span("build.struct") as sp:
+        hit = _BACKEND_MEMO.get(key)
+        sp.attrs["memo"] = "hit" if hit is not None else "miss"
+        if hit is None:
+            hit = struct_backend(model, check_deadlock=check_deadlock,
+                                 bounds=bounds, elide=elide,
+                                 coverage=coverage, symmetry=symmetry,
+                                 por=por, slots=slots)
+            _BACKEND_MEMO.put(key, hit)
     return hit
 
 
@@ -211,13 +239,15 @@ def engine_key(
         resolve_symmetry,
     )
 
+    spec = model_key(model)
     return (
-        model_key(model), "single", chunk, queue_capacity, fp_capacity,
+        spec, "single", chunk, queue_capacity, fp_capacity,
         fp_index, seed, fp_highwater, bool(check_deadlock),
         bool(pipeline), int(obs_slots), _bounds_key(bounds),
         bool(coverage), resolve_sort_free(sort_free, chunk),
         resolve_deferred(deferred, chunk),
         resolve_symmetry(symmetry, chunk), resolve_por(por, chunk),
+        _SLOT_FLOOR.get(spec, 0),
     )
 
 
@@ -283,3 +313,4 @@ def clear() -> None:
     _BACKEND_MEMO.clear()
     _ENGINE_MEMO.clear()
     _BOUNDS_MEMO.clear()
+    _SLOT_FLOOR.clear()

@@ -20,9 +20,19 @@ TPU-first design decisions (vs TLC's heap interpreter):
   a matmul - the MXU does the pair enumeration.
 * Nondeterminism fans into static lanes: disjuncts, bound parameters
   over constant sets, per-key unrolls for quantifiers over partial-
-  function domains (PendingClients), and k-th-set-bit slot lanes for
-  `with x \\in <set-valued expr>` picks, with an overflow flag when a
-  state's set exceeds the slot budget (the hand kernel's convention).
+  function domains (PendingClients), and UNIVERSE lanes for
+  `\\E m \\in <set of records>` picks: one lane per universe element,
+  the binder a host constant, the lane gated by the element's
+  membership bit; an element that fails a conjunct of its own fields
+  alone (`m.type = "1a"`) drops at trace time, so the fan is what the
+  static prune leaves, exact for any set size, with no overflow trap.
+* A step whose static fan is wide (Paxos: 256 lanes, ~8 live a state)
+  is compacted per state to `compact_width(L)` slots before it leaves
+  the step (the k-th live lane by a one-hot select), so the engine's
+  candidate width follows the live lanes; a state with more live lanes
+  than slots halts the run loudly, and the run starts again with twice
+  the slots (struct.cache.widen_slots, api._run_check_struct), up to
+  the static fan, where the step is not compacted and cannot overflow.
 
 Reference semantics: /root/reference/KubeAPI.tla:455-768; every path is
 differentially pinned against the structural oracle
@@ -31,6 +41,7 @@ differentially pinned against the structural oracle
 
 from __future__ import annotations
 
+from itertools import product as _product
 from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
@@ -38,11 +49,12 @@ import numpy as np
 
 from ..spec.labels import DEFAULT_INIT
 from .codec import EnumLeaf, MaskLeaf, RecNode, SeqNode, StructCodec, layout_of
-from .eval import _SORT_KEY, BUILTIN_SETS, Evaluator, is_fn
+from .eval import _SORT_KEY, BUILTIN_SETS, Evaluator, StructEvalError, is_fn
 from .parser import Definition
 from .shapes import (
     SAtoms,
     SBool,
+    SEnum,
     SInt,
     SRec,
     SSeq,
@@ -51,25 +63,41 @@ from .shapes import (
     Shape,
     ShapeError,
     _mentions_prime_static,
+    enum_field,
+    enum_fields,
+    join as _join_shapes,
+    shape_of_value,
 )
 
 UNROLL_LIMIT = 12  # quantifier domains up to this size unroll in Python
-SLOT_CAP = 4  # lanes per set-valued nondeterministic pick
+# a step with more static lanes than this leaves the step compacted
+COMPACT_MIN_LANES = 64
+
+
+def compact_width(n_lanes: int) -> int:
+    """Slots a state of a step with `n_lanes` static lanes keeps after
+    compaction at first (n_lanes itself where the step is not
+    compacted): an eighth of the static fan, at least 32.  A first
+    guess, not a limit: universe lanes are mutually exclusive by the
+    dozen (one element of a set, one ballot, one quorum at a time), so
+    most states fire a small share of them; one that fires more halts
+    the run (VIOL_SLOT_OVERFLOW), is never cut, and the model's step is
+    rebuilt with twice the slots (struct.cache.widen_slots)."""
+    if n_lanes <= COMPACT_MIN_LANES:
+        return n_lanes
+    return max(32, n_lanes // 8)
 
 
 class TrapPolicy:
     """What the certified bound report (analysis.absint) lets the
     compiler drop: range traps whose value interval is PROVEN inside
-    the destination universe, and slot lanes / slot-overflow traps on
-    set binders whose certified cardinality bound fits.  Built only
-    from a CERTIFIED BoundReport; the runtime certificate column
-    re-verifies every claim on device, so an unsound bound turns the
-    verdict loud instead of silently narrowing states away."""
+    the destination universe.  Built only from a CERTIFIED
+    BoundReport; the runtime certificate column re-verifies every
+    claim on device, so an unsound bound turns the verdict loud
+    instead of silently narrowing states away."""
 
-    def __init__(self, elide_range: bool = False,
-                 card_bounds: Optional[Dict[str, int]] = None):
+    def __init__(self, elide_range: bool = False):
         self.elide_range = bool(elide_range)
-        self.card_bounds = dict(card_bounds or {})
 
 
 class CompileError(ValueError):
@@ -196,10 +224,13 @@ class LE(LV):
     """Enum-coded value: arr holds indices into leaf.values; -1 = absent
     / invalid (guard-unreachable paths)."""
 
-    def __init__(self, arr, leaf: EnumLeaf, depth=0):
+    def __init__(self, arr, leaf: EnumLeaf, depth=0, universe=False):
         self.arr = arr
         self.leaf = leaf
         self.depth = depth
+        # arr is the arange of leaf's universe on its own lift axis (a
+        # lifted binder): a table look-up by it IS the table
+        self.universe = universe
 
 
 class LM(LV):
@@ -207,12 +238,33 @@ class LM(LV):
 
     `depth` counts the PREFIX lift axes the mask varies over; bits has
     shape [B, l1..l_depth, U] - the universe axis is always last and is
-    NOT a lift axis (until a quantifier lifts over this very mask)."""
+    NOT a lift axis (until a quantifier lifts over this very mask).
 
-    def __init__(self, bits, elem_leaf: EnumLeaf, depth=0):
-        self.bits = bits
+    `support` ([U] numpy bool, None = every element) is what is known
+    at trace time: an element outside it is never a member, whatever
+    the state (a filter's conjuncts over the bound element's own fields
+    decide it).  Universe lanes fan over the support alone.
+
+    `origin` ((state field arrays, MaskLeaf, [U] numpy bool `add`), or
+    None) marks a mask that is a state variable's own fields with host-
+    constant elements added (`msgs \\cup {m}` for a constant m): it
+    encodes as those fields OR-ed with constants, not bit by bit."""
+
+    def __init__(self, bits, elem_leaf: EnumLeaf, depth=0, support=None,
+                 origin=None):
+        # bits: the plane, or a thunk that makes it when first read (a
+        # mask with an origin is mostly encoded, never read again)
+        self._bits = bits
         self.elem_leaf = elem_leaf
         self.depth = depth
+        self.support = support
+        self.origin = origin
+
+    @property
+    def bits(self):
+        if callable(self._bits):
+            self._bits = self._bits()
+        return self._bits
 
 
 class LRec(LV):
@@ -267,7 +319,8 @@ class LaneCompiler:
         self.trap_policy = trap_policy
         self.trap_sites = 0
         self.elided_traps = 0
-        self.reduced_slot_lanes = 0
+        # static lanes of the last step trace, before compaction
+        self.static_lanes = 0
         # swept constants (jaxtlc.serve.sweep): CONSTANT names promoted
         # to read-only codec fields so their value is RUNTIME data - one
         # compiled step serves every configuration of the constants
@@ -276,6 +329,7 @@ class LaneCompiler:
         # never primes them, so build_step passes them through verbatim
         self.sweep_vars = frozenset(sweep_vars)
         self._field_tables: Dict = {}
+        self._look_ups: Dict = {}  # per trace: (table, value) -> gather
         self._trans_tables: Dict = {}
         self._pred_tables: Dict = {}
         self.trap = None  # LB set when a guard-unreachable encode happens
@@ -336,6 +390,44 @@ class LaneCompiler:
             self._field_tables[key] = t
         return t
 
+    def _rec_fields(self, sh):
+        """[(field, shape, optional)] of the record values of an enum
+        leaf's shape (a record, a union with one, an explicit universe
+        of records); None where it has none."""
+        if isinstance(sh, SRec):
+            return list(sh.fields)
+        if isinstance(sh, SUnion):
+            rec = None
+            for alt in sh.alts:
+                if isinstance(alt, SRec):
+                    rec = alt
+            return list(rec.fields) if rec is not None else None
+        if isinstance(sh, SEnum):
+            key = ("#recfields", sh)
+            hit = self._field_tables.get(key)
+            if hit is None:
+                hit = [(f,) + enum_field(sh, f) for f in enum_fields(sh)]
+                self._field_tables[key] = hit
+            return hit or None
+        return None
+
+    def look_up(self, table: np.ndarray, le: "LE"):
+        """table[le] for a host table over le's universe (absent codes
+        read entry 0; callers mask them).  A lifted binder is the
+        universe's arange, so its look-up is the table laid on its
+        axis: a constant, no gather."""
+        if le.universe:
+            return jnp.asarray(table.reshape(le.arr.shape))
+        # one gather per (table, value) a trace: a state variable's
+        # field is read by many lanes
+        key = (id(table), id(le.arr))
+        hit = self._look_ups.get(key)
+        if hit is None:
+            hit = (table, le.arr,
+                   jnp.asarray(table)[jnp.maximum(le.arr, 0)])
+            self._look_ups[key] = hit
+        return hit[2]
+
     def trans_table(self, src: EnumLeaf, dst: EnumLeaf) -> np.ndarray:
         key = (id(src), id(dst))
         t = self._trans_tables.get(key)
@@ -377,9 +469,9 @@ class LaneCompiler:
             if lv.leaf is leaf:
                 return lv
             t = self.trans_table(lv.leaf, leaf)
-            idx = jnp.where(
-                lv.arr >= 0, jnp.asarray(t)[jnp.maximum(lv.arr, 0)], -1
-            )
+            if lv.universe:
+                return LE(self.look_up(t, lv), leaf, lv.depth)
+            idx = jnp.where(lv.arr >= 0, self.look_up(t, lv), -1)
             return LE(idx, leaf, lv.depth)
         if isinstance(lv, LC):
             return LE(jnp.full((1,), leaf.index.get(lv.value, -1),
@@ -433,7 +525,62 @@ class LaneCompiler:
                 off += len(alt_leaf.values)
         raise CompileError(f"no {klass.__name__} alternative in {sh}")
 
+    def _rec_to_enum(self, lv: LRec, leaf: EnumLeaf) -> LE:
+        """A structural record -> its index in an explicit universe
+        (SEnum leaf): the records of the universe with lv's field names
+        form one kind; a dense table over the product of the kind's
+        per-field universes gives the index (-1: not in the universe,
+        e.g. a message outside the declared `Message`)."""
+        const = _const_record(lv)
+        if const is not _NOCONST:
+            return LE(jnp.full((1,), leaf.index.get(const, -1), jnp.int32),
+                      leaf, 0)
+        names = tuple(sorted(f for f, _, _ in lv.entries))
+        for f, p, _ in lv.entries:
+            if not (isinstance(p, LC) and p.value is True):
+                raise CompileError(
+                    f"field {f} of a record headed for a declared "
+                    "universe has dynamic presence"
+                )
+        key = (id(leaf), "#kind", names)
+        hit = self._pred_tables.get(key)
+        if hit is None:
+            kind = [(i, dict(v)) for i, v in enumerate(leaf.values)
+                    if isinstance(v, tuple) and v and is_fn(v)
+                    and tuple(sorted(k for k, _ in v)) == names]
+            fleaves = []
+            for f in names:
+                fsh = None
+                for _, d in kind:
+                    fsh = _join_shapes(fsh, shape_of_value(d[f]))
+                fleaves.append(self._leaf_of_shape(fsh))
+            radices = [len(fl.values) for fl in fleaves]
+            size = int(np.prod(radices)) if kind else 1
+            if size > ENUM_LEAF_LIMIT_TABLE:
+                raise CompileError("record kind table too large")
+            table = np.full(size, -1, np.int32)
+            for i, d in kind:
+                code = 0
+                for f, fl, r in zip(names, fleaves, radices):
+                    code = code * r + fl.index[d[f]]
+                table[code] = i
+            hit = (fleaves, radices, table)
+            self._pred_tables[key] = hit
+        fleaves, radices, table = hit
+        idx, bad, depth = jnp.zeros((1,), jnp.int32), \
+            jnp.zeros((1,), bool), 0
+        for f, fl, r in zip(names, fleaves, radices):
+            fe = self.to_leaf(lv.get(f)[1], fl)
+            ia, ca, d2 = _binop_arrs(idx, depth, fe.arr, fe.depth)
+            idx = ia * r + jnp.maximum(ca, 0)
+            bad = _align(bad, depth, d2) | (ca < 0)
+            depth = d2
+        return LE(jnp.where(bad, -1, jnp.asarray(table)[idx]), leaf,
+                  depth)
+
     def _rec_to_leaf(self, lv: LRec, leaf: EnumLeaf) -> LE:
+        if isinstance(leaf.shape, SEnum):
+            return self._rec_to_enum(lv, leaf)
         off, rec_leaf = self._resolve_alt(leaf, SRec)
         sh: SRec = rec_leaf.shape
         # mixed-radix index, first field most significant (codec
@@ -546,22 +693,15 @@ class LaneCompiler:
     def explode(self, lv: LE) -> LRec:
         """Enum record -> structural record (field gathers)."""
         sh = lv.leaf.shape
-        rec_sh = None
-        if isinstance(sh, SRec):
-            rec_sh = sh
-        elif isinstance(sh, SUnion):
-            for alt in sh.alts:
-                if isinstance(alt, SRec):
-                    rec_sh = alt
-        if rec_sh is None:
+        fields = self._rec_fields(sh)
+        if fields is None:
             raise CompileError(f"cannot explode non-record leaf {sh}")
         entries = []
-        safe = jnp.maximum(lv.arr, 0)
-        for f, s, opt in rec_sh.fields:
+        for f, s, opt in fields:
             fleaf = self._leaf_of_shape(s)
-            tab = jnp.asarray(self.field_table(lv.leaf, f, fleaf))
-            val = LE(tab[safe], fleaf, lv.depth)
-            pres = jnp.asarray(self.presence_table(lv.leaf, f))[safe]
+            val = LE(self.look_up(self.field_table(lv.leaf, f, fleaf), lv),
+                     fleaf, lv.depth)
+            pres = self.look_up(self.presence_table(lv.leaf, f), lv)
             entries.append((f, LB(pres, lv.depth), self._from_leaf(val, s)))
         return LRec(entries)
 
@@ -766,6 +906,16 @@ class LaneCompiler:
             return LRec([
                 (f, LC(True), self.comp(x, env, ctx)) for f, x in ast[1]
             ])
+        if op == "recset":
+            doms = [self.comp(x, env, ctx) for _, x in ast[1]]
+            if not all(isinstance(d, LC) and isinstance(d.value, frozenset)
+                       for d in doms):
+                raise CompileError("record set over a dynamic field set")
+            names = [f for f, _ in ast[1]]
+            return LC(frozenset(
+                tuple(sorted(zip(names, combo))) for combo in _product(
+                    *(sorted(d.value, key=_SORT_KEY) for d in doms))
+            ))
         if op == "apply":
             return self._comp_apply(ast, env, ctx)
         if op == "domain":
@@ -980,19 +1130,14 @@ class LaneCompiler:
             return v
         if isinstance(base, LE):
             sh = base.leaf.shape
-            fs = None
-            if isinstance(sh, SRec):
-                fs = sh.field(key)
-            elif isinstance(sh, SUnion):
-                for alt in sh.alts:
-                    if isinstance(alt, SRec) and alt.field(key):
-                        fs = alt.field(key)
+            fs = next(((s_, o_) for f_, s_, o_ in
+                       self._rec_fields(sh) or () if f_ == key), None)
             if fs is None:
                 raise CompileError(f"no field {key!r} on {sh}")
             fleaf = self._leaf_of_shape(fs[0])
-            tab = jnp.asarray(self.field_table(base.leaf, key, fleaf))
-            safe = jnp.maximum(base.arr, 0)
-            return self._from_leaf(LE(tab[safe], fleaf, base.depth), fs[0])
+            tab = self.field_table(base.leaf, key, fleaf)
+            return self._from_leaf(
+                LE(self.look_up(tab, base), fleaf, base.depth), fs[0])
         if isinstance(base, LSeq) and isinstance(key, int):
             if 1 <= key <= base.cap:
                 return self._from_leaf(base.slots[key - 1],
@@ -1030,14 +1175,10 @@ class LaneCompiler:
             return LM(bits, leaf, depth)
         if isinstance(base, LE):
             sh = base.leaf.shape
-            rec_sh = sh if isinstance(sh, SRec) else None
-            if rec_sh is None and isinstance(sh, SUnion):
-                for alt in sh.alts:
-                    if isinstance(alt, SRec):
-                        rec_sh = alt
-            if rec_sh is None:
+            fields = self._rec_fields(sh)
+            if fields is None:
                 raise CompileError(f"DOMAIN of {sh}")
-            names = [f for f, _, _ in rec_sh.fields]
+            names = [f for f, _, _ in fields]
             leaf = self._leaf_of_shape(SAtoms(frozenset(names)))
             safe = jnp.maximum(base.arr, 0)
             cols = []
@@ -1085,7 +1226,7 @@ class LaneCompiler:
         if isinstance(lv, LI):
             return lv.arr, lv.depth
         if isinstance(lv, LC):
-            return jnp.asarray(int(lv.value))[None], 0
+            return np.asarray([int(lv.value)], np.int32), 0
         if isinstance(lv, LE) and isinstance(lv.leaf.shape, SInt):
             return lv.arr + lv.leaf.shape.lo, lv.depth
         raise CompileError(
@@ -1151,8 +1292,8 @@ class LaneCompiler:
                         a.leaf, _named(lambda v: v in bv,
                                        ("inset", tuple(sorted(map(repr,
                                                                   bv))))))
-                    safe = jnp.maximum(a.arr, 0)
-                    return LB(jnp.asarray(tab)[safe] & (a.arr >= 0),
+                    hit = self.look_up(tab, a)
+                    return LB(hit if a.universe else hit & (a.arr >= 0),
                               a.depth)
                 if isinstance(a, LB):
                     ok_t = True in bv
@@ -1180,14 +1321,16 @@ class LaneCompiler:
                         a.leaf, _named(
                             lambda v: isinstance(v, str)
                             and v != DEFAULT_INIT, ("isstr",)))
-                    safe = jnp.maximum(a.arr, 0)
-                    return LB(jnp.asarray(tab)[safe] & (a.arr >= 0),
+                    hit = self.look_up(tab, a)
+                    return LB(hit if a.universe else hit & (a.arr >= 0),
                               a.depth)
             raise CompileError(f"\\in over constant {bv!r}")
         if isinstance(b, LM):
-            if isinstance(a, LC):
-                i = b.elem_leaf.index.get(a.value)
-                if i is None:
+            const = _const_record(a)
+            if const is not _NOCONST:
+                i = b.elem_leaf.index.get(const)
+                if i is None or (b.support is not None
+                                 and not b.support[i]):
                     return LC(False)
                 return LB(b.bits[..., i], b.depth)
             ae = self.to_leaf(a, b.elem_leaf)
@@ -1242,9 +1385,45 @@ class LaneCompiler:
                     r"\cap": a.value & b.value,
                     "\\": a.value - b.value,
                 }[sym])
-            x, y, d = _mask_align(am.bits, am.depth, bm.bits, bm.depth)
-            bits = {r"\cup": x | y, r"\cap": x & y, "\\": x & ~y}[sym]
-            return LM(bits, am.elem_leaf, d)
+            if sym == r"\cup" and isinstance(am.elem_leaf.shape, SEnum):
+                # a declared universe (`msgs \subseteq Message`) has no
+                # bit for a value outside it: the union must not lose
+                # one silently - it is the TypeOK violation the mask
+                # cannot hold, so the lane traps
+                for side in (a, b):
+                    for item in getattr(side, "items", ()):
+                        const = _const_record(item)
+                        if const is not _NOCONST \
+                                and const in am.elem_leaf.index:
+                            continue
+                        ie = self.to_leaf(item, am.elem_leaf)
+                        self.trap_sites += 1
+                        ctx.trap = self._lor(ctx.trap, _flatten(
+                            LB(ie.arr < 0, ie.depth)))
+            d = max(am.depth, bm.depth)
+
+            def bits():
+                x, y, _ = _mask_align(am.bits, am.depth, bm.bits, bm.depth)
+                return {r"\cup": x | y, r"\cap": x & y,
+                        "\\": x & ~y}[sym]
+
+            sa, sb = am.support, bm.support
+            if sym == r"\cup":
+                sup = None if sa is None or sb is None else sa | sb
+            elif sym == r"\cap":
+                sup = sb if sa is None else (
+                    sa if sb is None else sa & sb)
+            else:
+                sup = sa
+            origin = None
+            if sym == r"\cup":
+                for lm, other in ((a, b), (b, a)):
+                    add = self._const_bits(other, am.elem_leaf) \
+                        if isinstance(lm, LM) and lm.origin else None
+                    if add is not None:
+                        origin = lm.origin[:2] + (lm.origin[2] | add,)
+            return LM(bits if origin is not None else bits(),
+                      am.elem_leaf, d, support=sup, origin=origin)
         if sym in ("+", "-", "*"):
             if isinstance(a, LC) and isinstance(b, LC):
                 return LC({"+": a.value + b.value,
@@ -1304,7 +1483,30 @@ class LaneCompiler:
                 return a, self._setlit_mask(b, a.elem_leaf)
         raise CompileError("set operation without a mask operand")
 
+    def _const_bits(self, lv, elem_leaf: EnumLeaf):
+        """[U] numpy bool of a host-constant set (a constant, or a
+        literal of constant records) over `elem_leaf`; None where it is
+        not one, or holds an element outside the universe."""
+        if isinstance(lv, LC) and isinstance(lv.value, frozenset):
+            items = list(lv.value)
+        elif isinstance(lv, LSetLit):
+            items = [_const_record(x) for x in lv.items]
+        else:
+            return None
+        out = np.zeros(len(elem_leaf.values), bool)
+        for x in items:
+            i = None if x is _NOCONST else elem_leaf.index.get(x)
+            if i is None:
+                return None
+            out[i] = True
+        return out
+
     def _setlit_mask(self, lit: "LSetLit", elem_leaf: EnumLeaf) -> LM:
+        const = self._const_bits(lit, elem_leaf)
+        if const is not None:
+            # a literal of host constants: its plane is a constant
+            return LM(lambda: jnp.asarray(const)[None, :], elem_leaf, 0,
+                      support=const)
         bits = None
         depth = 0
         n = len(elem_leaf.values)
@@ -1533,7 +1735,7 @@ class LaneCompiler:
         arange = jnp.arange(n, dtype=jnp.int32).reshape(
             (1,) + (1,) * (level - 1) + (n,)
         )
-        return LE(arange, m.elem_leaf, level), level
+        return LE(arange, m.elem_leaf, level, universe=True), level
 
     def _quant_reduce(self, m: LM, body, level, kind) -> LB:
         if isinstance(body, LC):
@@ -1603,16 +1805,48 @@ class LaneCompiler:
             arrs = [_align(c, d, depth) for c, d in cols]
             bits = jnp.stack(jnp.broadcast_arrays(*arrs), axis=-1)
             return LM(bits, m.elem_leaf, depth)
+        # conjuncts over the bound element's own fields and host
+        # constants (`m.type = "1b" /\ m.acc \in Q /\ m.bal = b`) are
+        # decided per universe element at trace time: they become the
+        # filter's static support, which universe lanes fan over
+        alive, dyn = self._static_filter(var, pred, m, env)
+        sbits = m.bits & jnp.asarray(alive)
+        if not dyn:
+            return LM(sbits, m.elem_leaf, m.depth, support=alive)
         lifted, level = self._lift_binder(m)
         env2 = dict(env)
         env2[var] = lifted
-        r = self.comp(pred, env2, ctx)
+        r = self.comp(dyn[0] if len(dyn) == 1 else ("and", dyn), env2, ctx)
         if isinstance(r, LC):
-            return m if r.value else LM(m.bits & False, m.elem_leaf,
-                                        m.depth)
+            return LM(sbits if r.value else sbits & False, m.elem_leaf,
+                      m.depth, support=alive)
         barr = _align(r.arr, r.depth, level)
-        mbits = _mask_align(m.bits, m.depth, barr, level - 1)[0]
-        return LM(mbits & barr, m.elem_leaf, level - 1)
+        mbits = _mask_align(sbits, m.depth, barr, level - 1)[0]
+        return LM(mbits & barr, m.elem_leaf, level - 1, support=alive)
+
+    def _static_filter(self, var, pred, m: LM, env):
+        """(alive [U] numpy bool, dynamic conjuncts): the conjuncts of
+        `pred`, in order, that evaluate to a host constant for every
+        element still alive (TLC's short-circuit: a later conjunct is
+        only asked of an element the earlier ones kept) narrow `alive`;
+        the others stay for the lifted, vectorised path."""
+        n = len(m.elem_leaf.values)
+        alive = np.ones(n, bool) if m.support is None else m.support.copy()
+        conj = list(pred[1]) if pred[0] == "and" else [pred]
+        dyn = []
+        for c in conj:
+            out = alive.copy()
+            for i in np.flatnonzero(alive):
+                env2 = dict(env)
+                env2[var] = LC(m.elem_leaf.values[i])
+                verdict = self._host_bool(c, var, env2)
+                if verdict is None:
+                    dyn.append(c)
+                    break
+                out[i] = verdict
+            else:
+                alive = out
+        return alive, dyn
 
     def _comp_setmap(self, ast, env, ctx) -> LV:
         _, expr, var, dom_ast = ast
@@ -1635,6 +1869,11 @@ class LaneCompiler:
 
     def _comp_choose(self, ast, env, ctx) -> LV:
         _, var, dom_ast, pred = ast
+        if dom_ast is None:
+            raise CompileError(
+                f"unbounded CHOOSE {var}: override the definition in "
+                "the model's cfg"
+            )
         desc = self._dom_descriptor(dom_ast, env, ctx)
         if desc[0] != "mask":
             raise CompileError("CHOOSE over non-mask domain")
@@ -1780,6 +2019,10 @@ class LaneCompiler:
         """fields [B, F] int32 -> {var: LV} (batch-resident values)."""
         out: Dict[str, LV] = {}
         pos = 0
+        # one read of each source column a trace: a successor field
+        # that IS its source column (by identity) is not written back
+        self._src_cols = [fields[:, j] for j in range(fields.shape[1])]
+        self._look_ups = {}
         for v, lay in zip(self.variables, self.codec.layouts):
             lv, pos = self._decode_layout(lay, fields, pos,
                                           self.var_shapes[v])
@@ -1800,7 +2043,9 @@ class LaneCompiler:
                 for b in range(w):
                     cols.append((word >> b) & 1)
             bits = jnp.stack(cols, axis=-1) == 1
-            return LM(bits, lay.elem, 0), pos + lay.n_fields
+            origin = (self._src_cols[pos:pos + lay.n_fields], lay,
+                      np.zeros(lay.n_bits, bool))
+            return LM(bits, lay.elem, 0, origin=origin), pos + lay.n_fields
         if isinstance(lay, RecNode):
             entries = []
             for (f, opt, child), (fs, fsh, fopt) in zip(
@@ -1833,6 +2078,16 @@ class LaneCompiler:
             arr = jnp.broadcast_to(_to_b(le.arr, B), (B,))
             ctx.trap = self._lor(ctx.trap, LB(arr < 0, 0))
             return [jnp.maximum(arr, 0)]
+        if isinstance(lay, MaskLeaf) and isinstance(lv, LM) \
+                and lv.origin is not None and lv.origin[1] is lay:
+            # the variable's own fields with constants OR-ed in
+            words, _, add = lv.origin
+            out, off = [], 0
+            for word, w in zip(words, lay.widths):
+                const = int(sum(1 << i for i in range(w) if add[off + i]))
+                out.append(word | const if const else word)
+                off += w
+            return out
         if isinstance(lay, MaskLeaf):
             m = self.as_mask(lv, like=LM(jnp.zeros(
                 (1, len(lay.elem.values)), bool), lay.elem, 0))
@@ -1992,7 +2247,8 @@ class LaneCompiler:
                 env2 = dict(env)
                 for p, a in zip(d.params, args):
                     env2[p] = self.comp(a, env, ctx)
-                inner = label if d.body[0] == "or" else dname
+                inner = dname if label is None and d.body[0] != "or" \
+                    else label
                 self._walk_seq([d.body] + rest, 0, env2, ctx, inner, out)
                 return
         if op == "unchanged":
@@ -2105,52 +2361,101 @@ class LaneCompiler:
         m: LM = desc[1]
         if m.depth != 0:
             raise CompileError("lifted set in action-position \\E")
-        if desc[0] == "atoms":
-            for i, v in enumerate(m.elem_leaf.values):
-                env2 = dict(env)
-                env2[name] = LC(v)
-                c2 = ctx.fork()
-                c2.guard = self._land(c2.guard, LB(m.bits[..., i], 0))
+        # one lane per element of the set's universe, the binder a host
+        # constant, gated by the element's membership bit (small atom
+        # universes and record universes alike).  A body conjunct over
+        # the bound element's own fields (`m.type = "1a"`) is then a
+        # host constant, and a FALSE one ends the walk of that element
+        # before it becomes a lane; a derived set fans over its static
+        # support only.  Exact for any set size: no slot, no overflow
+        unevaluable = LC(False)
+        for i, v in enumerate(m.elem_leaf.values):
+            if m.support is not None and not m.support[i]:
+                continue
+            env2 = dict(env)
+            env2[name] = LC(v)
+            if self._dead_on(body, name, env2):
+                continue
+            c2 = ctx.fork()
+            c2.guard = self._land(c2.guard, LB(m.bits[..., i], 0))
+            mine: List[Lane] = []
+            n_cov = len(self.cov._contribs) if cov_idx is not None else 0
+            try:
                 if cov_idx is not None:
                     self.cov.hit(cov_idx, c2.guard)
-                self._walk_seq([body] + rest, 0, env2, c2, label, out)
-            return
-        # record-universe set: k-th set-bit slot lanes.  A certified
-        # cardinality bound on a bare-variable domain (analysis.absint
-        # TrapPolicy) shrinks the lane fan to the bound and - when the
-        # bound fits the slot budget - elides the overflow trap: lanes
-        # k >= |set| are never valid, so dropping them is count-exact,
-        # and the runtime certificate column re-verifies the bound
-        # (popcount of the committed mask) on device
-        slot_cap = SLOT_CAP
-        card = None
-        if self.trap_policy is not None and dom_ast[0] == "name":
-            card = self.trap_policy.card_bounds.get(dom_ast[1])
-        if card is not None and card < SLOT_CAP:
-            self.reduced_slot_lanes += SLOT_CAP - card
-            slot_cap = max(card, 1)
-        counts = m.bits.astype(jnp.int32).cumsum(axis=-1)
-        total = counts[..., -1]
-        self.trap_sites += 1
-        proven = card is not None and card <= slot_cap
-        if proven:
-            self.elided_traps += 1
-        for k in range(slot_cap):
-            sel = m.bits & (counts == k + 1)
-            idx = jnp.argmax(sel, axis=-1).astype(jnp.int32)
-            has = sel.any(axis=-1)
-            env2 = dict(env)
-            env2[name] = self._from_leaf(
-                LE(jnp.where(has, idx, -1), m.elem_leaf, 0),
-                m.elem_leaf.shape,
-            )
+                self._walk_seq([body] + rest, 0, env2, c2, label, mine)
+            except StructEvalError:
+                # the body cannot be evaluated on this element (an
+                # over-approximated universe holds records a run never
+                # builds: `r.n` of a record without n).  The host
+                # evaluator would fail on it too, were it ever a member,
+                # so its membership traps instead of fanning lanes
+                if cov_idx is not None:
+                    del self.cov._contribs[n_cov:]
+                unevaluable = self._lor(unevaluable,
+                                        LB(m.bits[..., i], 0))
+                continue
+            out.extend(mine)
+        if not (isinstance(unevaluable, LC) and not unevaluable.value):
+            self.trap_sites += 1
             c2 = ctx.fork()
-            c2.guard = self._land(c2.guard, LB(has, 0))
-            if cov_idx is not None:
-                self.cov.hit(cov_idx, c2.guard)
-            if not proven:
-                c2.ovf = self._lor(c2.ovf, LB(total > slot_cap, 0))
-            self._walk_seq([body] + rest, 0, env2, c2, label, out)
+            c2.guard = self._land(c2.guard, unevaluable)
+            c2.trap = LC(True)
+            env2 = dict(env)
+            for v in self.variables:
+                env2[("'", v)] = "passthrough"
+            out.append(Lane(label or "?", env2, c2))
+
+    def _dead_on(self, body, name, env) -> bool:
+        """Whether `body`'s leading conjuncts over the bound element's
+        own fields and host constants alone already rule the element
+        out: it then costs the trace nothing, not even its membership
+        bit.  A conjunct that reads anything else ends the look."""
+        for c in (body[1] if body[0] == "and" else [body]):
+            verdict = self._host_bool(c, name, env)
+            if verdict is not True:
+                return verdict is False
+        return False
+
+    def _host_bool(self, c, name, env):
+        """The host value of predicate `c` under `env`, where it reads
+        nothing but `name` (bound to a host constant there), host
+        constants and builtins and folds to a BOOLEAN; else None (and
+        nothing was traced for it)."""
+        if c[0] not in ("cmp", "not", "and", "or", "implies") \
+                or not self._only_reads(c, name, env):
+            return None
+        try:
+            r = self.comp(c, env, LaneCtx())
+        except (ValueError, KeyError, TypeError):
+            return None
+        if isinstance(r, LC) and isinstance(r.value, bool):
+            return r.value
+        return None
+
+    def _only_reads(self, ast, name, env) -> bool:
+        """No name in `ast` but `name`, host constants and builtins;
+        no prime, no operator application, no binder of its own."""
+        stack = [ast]
+        while stack:
+            node = stack.pop()
+            if isinstance(node, tuple) and node:
+                if node[0] == "name":
+                    if node[1] != name and not (
+                            node[1] not in env
+                            and (node[1] in self.ev.constants
+                                 or node[1] in BUILTIN_SETS)
+                            or isinstance(env.get(node[1]), LC)):
+                        return False
+                    continue
+                if node[0] in ("prime", "call", "unchanged", "exists",
+                               "forall", "choose", "setfilter", "setmap",
+                               "let", "fnlit", "atref"):
+                    return False
+                stack.extend(node[1:])
+            elif isinstance(node, list):
+                stack.extend(node)
+        return True
 
     # ======================================================================
     # Step function
@@ -2168,11 +2473,25 @@ class LaneCompiler:
             # then jit) report one compile's numbers, not a running sum
             self.trap_sites = 0
             self.elided_traps = 0
-            self.reduced_slot_lanes = 0
             env0 = dict(self.decode_state(fields))
             lanes = self.walk_lanes(next_ast, env0)
+            self.static_lanes = len(lanes)
             labels = []
             succ_cols, valids, ovfs, afails = [], [], [], []
+            never = jnp.zeros((B,), bool)
+
+            def flag(g):
+                if isinstance(g, LC) and not g.value:
+                    return never
+                return self._guard_arr(g, B)
+
+            runs = {}
+
+            def src_run(a, b):
+                if (a, b) not in runs:
+                    runs[a, b] = fields[:, a:b]
+                return runs[a, b]
+
             for lane in lanes:
                 labels.append(lane.label)
                 cols = []
@@ -2187,23 +2506,39 @@ class LaneCompiler:
                         )
                     if lv == "passthrough":
                         off = self.codec.offsets[v]
-                        for j in range(lay.n_fields):
-                            cols.append(fields[:, off + j])
+                        cols.extend(
+                            self._src_cols[off:off + lay.n_fields])
                     else:
                         cols.extend(self.encode_var(
                             lv, lay, self.var_shapes[v], B, lane.ctx))
-                succ_cols.append(jnp.stack(cols, axis=-1))
+                # pieces joined on the minor axis, then lanes: runs of
+                # untouched source columns as one slice of the source
+                # row, the written columns between them (a lane as the
+                # source row with fields written over it by
+                # `.at[:, j].set` made the lanes' concatenate 45 % of
+                # the chip's step: PR 31)
+                pieces, run = [], None
+                for j, col in enumerate(cols + [None]):
+                    if col is not None and col is self._src_cols[j]:
+                        run = j if run is None else run
+                        continue
+                    if run is not None:
+                        pieces.append(src_run(run, j))
+                        run = None
+                    if col is not None:
+                        pieces.append(col[:, None])
+                succ_cols.append(pieces[0] if len(pieces) == 1
+                                 else jnp.concatenate(pieces, axis=-1))
                 valids.append(self._guard_arr(lane.ctx.guard, B))
                 # overflow/trap only matter when the lane actually
                 # fires (a guard-disabled Append past cap is harmless);
                 # trap = semantic escape (a value fell outside the
                 # inferred universe) - both halt the run loudly
                 ovfs.append(
-                    (self._guard_arr(lane.ctx.ovf, B)
-                     | self._guard_arr(lane.ctx.trap, B)) & valids[-1]
+                    (flag(lane.ctx.ovf) | flag(lane.ctx.trap))
+                    & valids[-1]
                 )
-                afails.append(self._guard_arr(lane.ctx.afail, B)
-                              & valids[-1])
+                afails.append(flag(lane.ctx.afail) & valids[-1])
             if self.labels is None:
                 self.labels = labels
             succs = jnp.stack(succ_cols, axis=1)
@@ -2237,16 +2572,14 @@ class LaneCompiler:
 
         def cov_fn(fields, mask, valid):
             B = fields.shape[0]
-            saved = (self.trap_sites, self.elided_traps,
-                     self.reduced_slot_lanes)
+            saved = (self.trap_sites, self.elided_traps)
             self.cov.begin()
             try:
                 env0 = dict(self.decode_state(fields))
                 self.walk_lanes(next_ast, env0)
             finally:
                 contribs = self.cov.end()
-                (self.trap_sites, self.elided_traps,
-                 self.reduced_slot_lanes) = saved
+                self.trap_sites, self.elided_traps = saved
             n = len(self.cov.sites)
             if n == 0:
                 return jnp.zeros(0, jnp.uint32)
@@ -2318,6 +2651,37 @@ class Lane:
         self.ctx = ctx
 
 
+def compact_lanes(succs, valid, action, afail, ovf, width: int):
+    """One state's static lanes ([L, F] successors, [L] flags) packed
+    into `width` slots: slot k holds the k-th live lane, by a one-hot
+    select (no gather, no sort).  A state with more live lanes than
+    slots raises `ovf` on its first slot, which halts the run
+    (VIOL_SLOT_OVERFLOW): a successor is never dropped in silence."""
+    rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
+    sel = valid[None, :] & (
+        rank[None, :] == jnp.arange(width, dtype=jnp.int32)[:, None]
+    )  # [width, L], at most one lane a slot
+    pick = sel.astype(jnp.int32)
+    over = rank[-1] >= width
+    return (
+        pick @ succs,
+        sel.any(axis=1),
+        pick @ action,
+        (sel & afail[None, :]).any(axis=1),
+        (sel & ovf[None, :]).any(axis=1).at[0].max(over),
+    )
+
+
+def _flatten(lb):
+    """A lane condition reduced over its lift axes (any)."""
+    if isinstance(lb, LC):
+        return lb
+    arr = lb.arr
+    for _ in range(lb.depth):
+        arr = arr.any(axis=-1)
+    return LB(arr, 0)
+
+
 def _to_b(arr, B):
     """[1]- or [B]-shaped array -> broadcastable to [B]."""
     if arr.ndim == 0:
@@ -2356,3 +2720,21 @@ def _mask_align(a_bits, a_pre, b_bits, b_pre):
         return bits
 
     return fix(a_bits, a_pre), fix(b_bits, b_pre), pre
+
+
+ENUM_LEAF_LIMIT_TABLE = 1 << 20
+_NOCONST = object()
+
+
+def _const_record(lv):
+    """The host value of a structural record whose every field is a
+    host constant (a message literal over bound constants), else
+    _NOCONST."""
+    if isinstance(lv, LC):
+        return lv.value
+    if isinstance(lv, LRec) and all(
+        isinstance(p, LC) and p.value is True and isinstance(v, LC)
+        for _, p, v in lv.entries
+    ):
+        return tuple(sorted((f, v.value) for f, _, v in lv.entries))
+    return _NOCONST

@@ -26,6 +26,7 @@ from ..engine.bfs import (
     CheckResult,
     VIOLATION_NAMES,
     result_from_carry,
+    with_step_counters,
 )
 from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
 from .backend import (  # noqa: F401 - VIOL_INVARIANT_BASE is API here
@@ -92,11 +93,11 @@ def check_struct(
     t0 = time.time()
     out = jax.block_until_ready(compiled(carry))
     wall = time.time() - t0
-    result = result_from_carry(
+    result = with_step_counters(result_from_carry(
         out, wall, fp_capacity=fp_capacity, labels=backend.labels,
         viol_names=backend.viol_names,
         sites=backend.coverage.sites if backend.coverage else None,
-    )
+    ), backend)
     if capture_fps and result.violation == 0:
         import numpy as np
 

@@ -228,8 +228,22 @@ class Evaluator:
                     # (their value cannot depend on later bindings)
                     env2[name] = self.eval(body, env2, primed)
             return self.eval(ast[2], env2, primed)
+        if op == "recset":
+            names = [f for f, _ in ast[1]]
+            doms = [sorted(self._set(x, env, primed), key=_SORT_KEY)
+                    for _, x in ast[1]]
+            return frozenset(
+                tuple(sorted(zip(names, combo)))
+                for combo in _product(*doms)
+            )
         if op == "choose":
             _, var, dom_ast, pred = ast
+            if dom_ast is None:
+                raise StructEvalError(
+                    f"unbounded CHOOSE {var} cannot be evaluated (TLC "
+                    "cannot either): override the definition in the "
+                    "model's cfg"
+                )
             dom = self._set(dom_ast, env, primed)
             for x in sorted(dom, key=_SORT_KEY):
                 env2 = dict(env)

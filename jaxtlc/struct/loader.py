@@ -131,6 +131,17 @@ def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
 
 def load(cfg_path: str,
          const_overrides: Optional[Dict[str, object]] = None) -> StructModel:
+    """Parse the model's cfg and module closure and resolve its
+    constants (host span `build.struct.load`: a check pays it on every
+    call, before its backend is looked up)."""
+    from ..obs.spans import span
+
+    with span("build.struct.load"):
+        return _load(cfg_path, const_overrides)
+
+
+def _load(cfg_path: str,
+          const_overrides: Optional[Dict[str, object]]) -> StructModel:
     cfg = parse_cfg_file(cfg_path)
     model_dir = os.path.dirname(os.path.abspath(cfg_path))
     toolbox_parent = os.path.dirname(os.path.dirname(model_dir))
@@ -169,21 +180,26 @@ def load(cfg_path: str,
     constants: Dict[str, object] = {}
     for name, val in cfg.constants.items():
         constants[name] = _parse_const_literal(val)
-    ev0 = Evaluator(module.defs, {})
-    for name, defname in cfg.substitutions.items():
-        d = module.defs.get(defname)
-        if d is None:
-            raise StructLoadError(
-                f"CONSTANT {name} <- {defname}: no such definition"
-            )
-        constants[name] = ev0.eval(d.body, {})
     if const_overrides:
         constants.update(const_overrides)
     # every declared constant needs a value (defaultInitValue is a model
     # value equal only to itself when left unassigned)
     for c in module.constants:
-        if c not in constants:
+        if c not in constants and c not in cfg.substitutions:
             constants[c] = DEFAULT_INIT if c == "defaultInitValue" else c
+    # `Quorum <- MCQuorum`: the replacing definition may name the
+    # model's assigned constants and model values (MC.tla's
+    # `CONSTANTS a1, a2, a3`) and an earlier replacement, so it is
+    # evaluated over them, in the cfg's order
+    for name, defname in cfg.substitutions.items():
+        if const_overrides and name in const_overrides:
+            continue
+        d = module.defs.get(defname)
+        if d is None:
+            raise StructLoadError(
+                f"CONSTANT {name} <- {defname}: no such definition"
+            )
+        constants[name] = Evaluator(module.defs, constants).eval(d.body, {})
 
     ev = Evaluator(module.defs, constants)
 

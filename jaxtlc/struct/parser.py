@@ -28,12 +28,12 @@ AST nodes are plain tuples (texpr-compatible where the form overlaps):
   ("apply", f, arg)            f[arg] and r.field (field as ("str", f))
   ("call", name, [args])       operator application Foo(a, b)
   ("setlit", [..]) ("setfilter", var, dom, pred) ("setmap", e, var, dom)
-  ("tuple", [..]) ("record", [(f, e), ..])
+  ("tuple", [..]) ("record", [(f, e), ..]) ("recset", [(f, S), ..])
   ("fnlit", var, dom, body) ("funcset", dom, rng)
   ("except", f, [([path..], val), ..])   path elements are value ASTs
   ("if", c, t, e) ("case", [(g, e), ..], other|None)
   ("let", [(name, params, body), ..], e)
-  ("choose", var, dom, pred)
+  ("choose", var, dom|None, pred)
   ("forall", [vars], dom, body) ("exists", [vars], dom, body)
   ("unchanged", [names]) ("domain", e) ("atref",)
 """
@@ -129,6 +129,8 @@ _TOKEN_RE = re.compile(
   | (?P<lor>\\/)
   | (?P<forall>\\A\b)
   | (?P<exists>\\E\b)
+  | (?P<ge>\\geq\b)
+  | (?P<le>\\leq\b|=<)
   | (?P<op>\\(?:in|notin|subseteq|cup|cap|o)\b)
   | (?P<setminus>\\)
   | (?P<leadsto>~>)
@@ -137,8 +139,8 @@ _TOKEN_RE = re.compile(
   | (?P<arrow>->)
   | (?P<defeq>==)
   | (?P<range>\.\.)
-  | (?P<le><=)
-  | (?P<ge>>=)
+  | (?P<le_><=)
+  | (?P<ge_>>=)
   | (?P<ltup><<)
   | (?P<rtup>>>)
   | (?P<box>\[\])
@@ -483,23 +485,33 @@ class _ExprParser:
 
     def parse_quantifier(self, t: Tok) -> tuple:
         self.next()
-        names = [self.expect("name").val]
-        while self.peek().kind == "sym" and self.peek().val == ",":
-            self.next()
-            names.append(self.expect("name").val)
-        op = self.next()
-        if (op.kind, op.val) != ("op", r"\in"):
-            raise StructParseError(
-                f"expected \\in in quantifier (line {t.line})"
-            )
-        dom = self.parse_cmp_operand()
+        # \A b1, b2 \in S, v \in T : P - one (names, domain) group per
+        # \in, nested left to right
+        groups = []
+        while True:
+            names = [self.expect("name").val]
+            while self.peek().kind == "sym" and self.peek().val == ",":
+                self.next()
+                names.append(self.expect("name").val)
+            op = self.next()
+            if (op.kind, op.val) != ("op", r"\in"):
+                raise StructParseError(
+                    f"expected \\in in quantifier (line {t.line})"
+                )
+            groups.append((names, self.parse_cmp_operand()))
+            if self.peek().kind == "sym" and self.peek().val == ",":
+                self.next()
+                continue
+            break
         self.expect(":", "':' in quantifier")
         body = self.parse_expr()
         node = "forall" if t.kind == "forall" else "exists"
-        return (node, names, dom, body)
+        for names, dom in reversed(groups):
+            body = (node, names, dom, body)
+        return body
 
     _CMP_KINDS = {"eq": "=", "ne": "#", "lt": "<", "gt": ">", "le": "<=",
-                  "ge": ">="}
+                  "ge": ">=", "le_": "<=", "ge_": ">="}
 
     def parse_cmp(self) -> tuple:
         left = self.parse_cmp_operand()
@@ -705,10 +717,13 @@ class _ExprParser:
             return ("let", binds, self.parse_expr())
         if v == "CHOOSE":
             var = self.expect("name").val
-            op = self.next()
-            if (op.kind, op.val) != ("op", r"\in"):
-                raise StructParseError("expected \\in in CHOOSE")
-            dom = self.parse_cmp_operand()
+            dom = None
+            if self.peek().kind == "op" and self.peek().val == r"\in":
+                self.next()
+                dom = self.parse_cmp_operand()
+            # `CHOOSE v : P` (unbounded) parses; like TLC the evaluator
+            # refuses to evaluate it, so a model overrides the
+            # definition (Paxos's None)
             self.expect(":", "':' in CHOOSE")
             pred = self.parse_expr()
             return ("choose", var, dom, pred)
@@ -804,6 +819,9 @@ class _ExprParser:
             if t2.kind == "mapsto":
                 self.i = save
                 return self.parse_record_literal()
+            if t2.kind == "sym" and t2.val == ":":
+                self.i = save
+                return self.parse_record_set()
             if t2.kind == "op" and t2.val == r"\in":
                 self.next()
                 dom = self.parse_expr()
@@ -852,6 +870,20 @@ class _ExprParser:
         raise StructParseError(
             f"unsupported bracket expression (line {t.line})"
         )
+
+    def parse_record_set(self) -> tuple:
+        """[f : S, g : T] - the set of records with f in S and g in T."""
+        fields = []
+        while True:
+            f = self.expect("name").val
+            self.expect(":", "':' in record set")
+            fields.append((f, self.parse_expr()))
+            t = self.next()
+            if t.kind == "sym" and t.val == "]":
+                break
+            if not (t.kind == "sym" and t.val == ","):
+                raise StructParseError("expected , or ] in record set")
+        return ("recset", fields)
 
     def parse_record_literal(self) -> tuple:
         fields = []

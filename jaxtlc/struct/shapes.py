@@ -8,7 +8,8 @@ dynamic value representations:
 
   SBool | SInt(lo,hi) | SAtoms(strings/model values) |
   SRec(field -> (shape, optional)) | SSet(elem) |
-  SFun(keys, val, partial) | SSeq(elem, cap) | SUnion(alts)
+  SFun(keys, val, partial) | SSeq(elem, cap) | SUnion(alts) |
+  SEnum(values)
 
 Records with optional fields become presence-tagged products; sets of
 records become bitmasks over the record universe (KubeAPI's apiState,
@@ -98,6 +99,39 @@ class SUnion(Shape):
     alts: Tuple[Shape, ...]  # at most one alt per shape class
 
 
+@dataclasses.dataclass(frozen=True)
+class SEnum(Shape):
+    """An explicit finite universe: exactly these canonical values, in
+    this order.  Where a spec DECLARES a set variable's type as a union
+    of record sets (`msgs \\subseteq Message`, typeok_hints), the
+    declared set is the universe - the product of every field's values
+    that joining the record shapes would give is many times larger
+    (Paxos at Ballot == 0..1: 1,536 against Message's 72)."""
+    values: tuple
+
+
+def enum_field(sh: SEnum, fname: str) -> Optional[Tuple[Shape, bool]]:
+    """(shape, optional) of field `fname` over the record values of an
+    explicit universe; None where no value has it."""
+    out, have, n = None, 0, 0
+    for v in sh.values:
+        if isinstance(v, tuple) and v and is_fn(v):
+            n += 1
+            d = dict(v)
+            if fname in d:
+                have += 1
+                out = join(out, shape_of_value(d[fname]))
+    return None if not have else (out, have < n)
+
+
+def enum_fields(sh: SEnum) -> List[str]:
+    names = set()
+    for v in sh.values:
+        if isinstance(v, tuple) and v and is_fn(v):
+            names |= {k for k, _ in v}
+    return sorted(names)
+
+
 SEQ_CAP_LIMIT = 2  # widening clamp; kernel checks overflow at runtime
 
 
@@ -131,6 +165,10 @@ def join(a: Optional[Shape], b: Optional[Shape]) -> Optional[Shape]:
         return SInt(min(a.lo, b.lo), max(a.hi, b.hi))
     if isinstance(a, SAtoms):
         return SAtoms(a.atoms | b.atoms)
+    if isinstance(a, SEnum):
+        have = set(a.values)
+        extra = tuple(v for v in b.values if v not in have)
+        return SEnum(a.values + extra) if extra else a
     if isinstance(a, SRec):
         names = sorted({f for f, _, _ in a.fields}
                        | {f for f, _, _ in b.fields})
@@ -238,6 +276,10 @@ def universe(shape: Optional[Shape], limit: int = ENUM_LIMIT) -> List:
         return list(range(shape.lo, shape.hi + 1))
     if isinstance(shape, SAtoms):
         return sorted(shape.atoms)
+    if isinstance(shape, SEnum):
+        if len(shape.values) > limit:
+            raise ShapeError("explicit universe too large")
+        return list(shape.values)
     if isinstance(shape, SRec):
         per_field = []
         total = 1
@@ -488,6 +530,11 @@ class ShapeInference:
             return SRec(tuple(sorted(
                 (f, self._abstract(x, env), False) for f, x in ast[1]
             )))
+        if op == "recset":
+            return SSet(SRec(tuple(sorted(
+                (f, self._elem_shape(self._abstract(x, env)), False)
+                for f, x in ast[1]
+            ))))
         if op == "apply":
             base = self._abstract(ast[1], env)
             arg_ast = ast[2]
@@ -594,6 +641,8 @@ class ShapeInference:
             return frozenset(sh.keys)
         if isinstance(sh, SRec):
             return frozenset(f for f, _, _ in sh.fields)
+        if isinstance(sh, SEnum):
+            return frozenset(enum_fields(sh))
         if sh is None or sh == SSeq(None, 0):
             return frozenset()  # DOMAIN of the empty function is {}
         if isinstance(sh, SUnion):
@@ -625,6 +674,13 @@ class ShapeInference:
                 out = join(out, sh.val)
             elif isinstance(sh, SSeq):
                 out = join(out, sh.elem)
+            elif isinstance(sh, SEnum):
+                names = [arg_ast[1]] if arg_ast[0] == "str" \
+                    else enum_fields(sh)
+                for f in names:
+                    got = enum_field(sh, f)
+                    if got is not None:
+                        out = join(out, got[0])
         return out
 
     def _binop_shape(self, ast, env) -> Optional[Shape]:
@@ -936,6 +992,17 @@ def typeok_hints(ev: Evaluator, invariants: Dict[str, tuple],
                 sh = dom_shape(rhs)
                 if sh is not None:
                     hints[var] = sh
+        if ast[0] == "cmp" and ast[1] == r"\subseteq" \
+                and ast[2][0] == "name" and ast[2][1] in variables:
+            # `msgs \subseteq Message`: the declared set IS the element
+            # universe, value for value (no slack: a value outside it
+            # has no bit, and the compiled union traps on it)
+            try:
+                v = ev.eval(ast[3], {})
+            except Exception:
+                return
+            if isinstance(v, frozenset) and 0 < len(v) <= ENUM_LIMIT:
+                hints[ast[2][1]] = SSet(SEnum(tuple(sorted(v, key=repr))))
 
     for ast in invariants.values():
         visit(ast)
@@ -964,6 +1031,17 @@ def _clamp(sh: Optional[Shape], hint: Optional[Shape]) -> Optional[Shape]:
             for f, s, o in sh.fields
         ))
     if isinstance(sh, SSet) and isinstance(hint, SSet):
+        if isinstance(hint.elem, SEnum):
+            # a declared universe replaces the inferred one where it is
+            # the smaller of the two
+            if isinstance(sh.elem, SEnum):
+                return sh
+            try:
+                n = len(universe(sh.elem, len(hint.elem.values)))
+            except ShapeError:
+                n = None
+            return sh if n is not None and n <= len(hint.elem.values) \
+                else hint
         return SSet(_clamp(sh.elem, hint.elem))
     if isinstance(sh, SSeq):
         elem_hint = hint.elem if isinstance(hint, SSeq) else (
@@ -1004,6 +1082,8 @@ def shape_leq(a: Optional[Shape], b: Optional[Shape]) -> bool:
         return b.lo <= a.lo and a.hi <= b.hi
     if isinstance(a, SAtoms):
         return a.atoms <= b.atoms
+    if isinstance(a, SEnum):
+        return set(a.values) <= set(b.values)
     if isinstance(a, SRec):
         bf = {f: (s, o) for f, s, o in b.fields}
         for f, s, o in a.fields:

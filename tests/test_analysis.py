@@ -229,19 +229,20 @@ TypeOK == \\A m \\in msgs : m.from \\in RM
 """
 
 
-def test_seeded_slot_over_budget(tmp_path):
+def test_seeded_universe_lane_fan(tmp_path):
     """An action-position \\E over a state-dependent set whose element
-    universe exceeds the unroll limit runs through SLOT_CAP slot lanes:
-    the RaftReplication overflow class, named at preflight."""
+    universe exceeds the unroll limit fans into universe lanes, one an
+    element: named at preflight with the universe's size (the slot-lane
+    form and its overflow class went in PR 31)."""
     m = load(_write_model(
         tmp_path, "Slot", _SLOT,
         "CONSTANT RM = {r1, r2, r3, r4, r5, r6, r7}\n"
         "INVARIANT\nTypeOK\n",
     ))
     sa = analyze_spec(m)
-    slot = [f for f in sa.findings if f.check == "slot-budget"]
+    slot = [f for f in sa.findings if f.check == "lane-fan"]
     assert [f.subject for f in slot] == ["Drop"]
-    assert slot[0].severity == "warning"
+    assert slot[0].severity == "info"
     assert sa.actions["Drop"].slot_binders == [("m", 14)]
     # constant-set binders (SendA/SendB over RM) never use slots
     assert sa.actions["SendA"].slot_binders == []

@@ -239,7 +239,8 @@ def test_warm_resubmit_zero_fresh_xla_compiles(server, sweep_jobs):
 
 
 def test_pooled_job_leaves_every_span(server, sweep_jobs):
-    """ISSUE 24: a pooled job's host spans - the scheduler thread's ten
+    """ISSUE 24: a pooled job's host spans - the scheduler thread's
+    eleven (since PR 31: `build.struct.load` inside `sched.load`)
     under one `sched.run`, all carrying the served job's id, children
     inside parents, top-level children covering the dispatch - the
     HTTP handlers' threads adding nothing to the recorder, and the job
@@ -262,9 +263,9 @@ def test_pooled_job_leaves_every_span(server, sweep_jobs):
     root = assert_tree(sched, "sched.run")
     assert len({r.thread for r in sched}) == 1
     assert sorted(r.name for r in sched) == sorted([
-        "sched.run", "sched.jobdir", "sched.load", "sched.cache_lookup",
-        "pool.get", "pool.carry", "pool.run", "pool.readback",
-        "sched.journal", "sched.finish"])
+        "sched.run", "sched.jobdir", "sched.load", "build.struct.load",
+        "sched.cache_lookup", "pool.get", "pool.carry", "pool.run",
+        "pool.readback", "sched.journal", "sched.finish"])
     assert len(sched) <= 16  # the budget
     by_name = {r.name: r for r in sched}
     for n in ("pool.carry", "pool.run", "pool.readback"):
@@ -278,8 +279,9 @@ def test_pooled_job_leaves_every_span(server, sweep_jobs):
     assert kinds.count("spans") == 1 and kinds[-2:] == ["spans", "final"]
     assert [row[0] for row in events[-2]["rows"]] == [
         r.name for r in sched if r.t1 <= events[-2]["t"]] == [
-        "sched.jobdir", "sched.load", "sched.cache_lookup", "pool.get",
-        "pool.carry", "pool.run", "pool.readback"]
+        "sched.jobdir", "build.struct.load", "sched.load",
+        "sched.cache_lookup", "pool.get", "pool.carry", "pool.run",
+        "pool.readback"]
     # the journal reports what it cost itself on its closing span
     closing = by_name["sched.journal"].attrs
     assert closing["events"] == len(events) and closing["fsyncs"] >= 1
