@@ -1,10 +1,12 @@
 """Thin client for the checking service (stdlib urllib only).
 
-`submit` posts a job, `wait` polls it to completion, `stream` follows
-the job-scoped SSE event feed, `check` is submit+wait in one call,
-`cancel` is DELETE /jobs/<id>.  A 429 from admission control (ISSUE
-17) is retried automatically, honoring the server's drain-rate
-``Retry-After`` with capped deterministic-jitter backoff.
+`submit` posts a job, `wait` blocks in ``GET /jobs/<id>?wait=`` until
+the server has the verdict (one request a job; it polls only a server
+that does not block), `stream` follows the job-scoped SSE event feed,
+`check` is submit+wait in one call, `cancel` is DELETE /jobs/<id>.  A
+429 from admission control (ISSUE 17) is retried automatically,
+honoring the server's drain-rate ``Retry-After`` with capped
+deterministic-jitter backoff.
 The CLI form drives a live server from a model directory::
 
     python -m jaxtlc.serve.client http://HOST:PORT path/to/MC.cfg \
@@ -28,6 +30,14 @@ from typing import Dict, Iterator, Optional
 # replays back off on the same clock
 _RNG = random.Random(0x5EED429)
 
+_SOCKET_TIMEOUT_S = 30.0  # every request's, unless the call says more
+# a held GET /jobs/<id>?wait= gets this much socket timeout above what
+# it asked for
+_WAIT_MARGIN_S = 5.0
+# the most `wait` asks one such GET to hold (a server whose own cap is
+# lower answers sooner, and is asked again)
+_WAIT_ASK_S = _SOCKET_TIMEOUT_S - _WAIT_MARGIN_S
+
 
 class ClientError(RuntimeError):
     """An HTTP-level failure.  `code` is the status (0 when the error
@@ -41,7 +51,8 @@ class ClientError(RuntimeError):
         self.retry_after = retry_after
 
 
-def _post(url: str, payload: dict, timeout: float = 30.0) -> dict:
+def _post(url: str, payload: dict,
+          timeout: float = _SOCKET_TIMEOUT_S) -> dict:
     req = urllib.request.Request(
         url, data=json.dumps(payload).encode(),
         headers={"Content-Type": "application/json"}, method="POST",
@@ -56,7 +67,7 @@ def _post(url: str, payload: dict, timeout: float = 30.0) -> dict:
                           retry_after=(int(ra) if ra else None))
 
 
-def _get(url: str, timeout: float = 30.0) -> dict:
+def _get(url: str, timeout: float = _SOCKET_TIMEOUT_S) -> dict:
     with urllib.request.urlopen(url, timeout=timeout) as r:
         return json.loads(r.read().decode())
 
@@ -96,21 +107,33 @@ def status(url: str, job_id: str) -> dict:
 
 def wait(url: str, job_id: str, timeout: float = 300.0,
          poll_s: float = 0.05) -> dict:
-    """Poll until the job leaves queued/running; returns its record.
-    Returns immediately on EVERY terminal state - done, error, and the
-    scheduler-terminal expired / canceled / quarantined (ISSUE 17)."""
+    """Block until the job leaves queued/running; returns its record.
+    Returns on EVERY terminal state - done, error, and the
+    scheduler-terminal expired / canceled / quarantined (ISSUE 17) -
+    and raises ClientError after `timeout`.
+
+    Each request is ``GET /jobs/<id>?wait=<s>``, which the server
+    answers when the job completes (at most _WAIT_ASK_S a request, then
+    asked again at once).  `poll_s` is the pause after a server that
+    answered "unfinished" SOONER than asked - an older build or a proxy
+    that drops `wait`, a server shutting down - and nothing else."""
     deadline = time.time() + timeout
+    job_url = f"{url.rstrip('/')}/jobs/{job_id}"
     while True:
-        st = status(url, job_id)
+        ask = round(max(0.0, min(deadline - time.time(), _WAIT_ASK_S)), 3)
+        t0 = time.monotonic()
+        st = _get(f"{job_url}?wait={ask}", timeout=ask + _WAIT_MARGIN_S)
         if st["state"] not in ("queued", "running"):
             return st
         if time.time() > deadline:
             raise ClientError(f"job {job_id} still {st['state']} "
                               f"after {timeout}s")
-        time.sleep(poll_s)
+        if time.monotonic() - t0 < ask:
+            time.sleep(poll_s)
 
 
-def cancel(url: str, job_id: str, timeout: float = 30.0) -> dict:
+def cancel(url: str, job_id: str,
+           timeout: float = _SOCKET_TIMEOUT_S) -> dict:
     """DELETE /jobs/<id>; returns the job record (state `canceled`
     for a queued job; a running checkpointed heavy job drains through
     the preempt path and reaches `canceled` shortly after)."""

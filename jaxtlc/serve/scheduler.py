@@ -216,6 +216,10 @@ class Job:
         self.cancel_requested = False
         self._drain: Optional[threading.Event] = None
         self._preemptible = False
+        # set once state, result and finished_t are written (by
+        # Scheduler._release, or _finish_terminal itself): what
+        # Scheduler.wait blocks on
+        self._done = threading.Event()
 
     # -- routing -----------------------------------------------------------
 
@@ -364,7 +368,12 @@ class Scheduler:
         self._breaker: Dict[str, dict] = {}
         self._counters = dict(admitted=0, rejected=0, expired=0,
                               canceled=0, quarantined=0, preempted=0,
-                              requeued=0, retried=0)
+                              requeued=0, retried=0,
+                              # Scheduler.wait: answered by the job's
+                              # completion / already terminal on
+                              # arrival / still unfinished at the end
+                              wait_blocked=0, wait_ready=0,
+                              wait_timeout=0)
         self._rng = random.Random(0xC0FFEE)  # deterministic jitter
         # the scheduler's own journal: every control-plane decision is
         # a schema-v1 `sched` event, rendered by the same /runs /
@@ -506,6 +515,41 @@ class Scheduler:
         with self._cond:
             return self.jobs.get(job_id)
 
+    def wait(self, job_id: str, timeout: float) -> Optional[Job]:
+        """Block until the job is terminal, at most `timeout` seconds;
+        returns the job whatever its state then is (None: no such id).
+        The wait is on the job's own event, not on `_cond`: that is the
+        scheduler thread's lock, notified on every submit and finish,
+        and a waiter woken by each of those would take it, and the
+        GIL, from the one thread that does the work.  The event is set
+        with `_cond` released, so the waiter's count does not start by
+        blocking on its waker.  `shutdown` releases every waiter with
+        the job as it stands."""
+        with self._cond:
+            job = self.jobs.get(job_id)
+            if job is None:
+                return None
+            if job.state in TERMINAL_STATES:
+                self._counters["wait_ready"] += 1
+                return job
+        done = job._done.wait(timeout) and job.state in TERMINAL_STATES
+        with self._cond:
+            self._counters["wait_blocked" if done
+                           else "wait_timeout"] += 1
+        return job
+
+    def _release(self, jobs) -> None:
+        """Wake the waiters of each of `jobs` that is terminal (a
+        requeued or preempted one is not, and waits on).  The
+        dispatch's side of a completion: `_finish_ok` and
+        `_finish_error` write the record, and the loop calls this once
+        `sched.run` has closed, so the woken handler's send is no part
+        of the job's service time (a route that runs a batch's jobs
+        one after another calls it after each)."""
+        for j in jobs:
+            if j.state in TERMINAL_STATES:
+                j._done.set()
+
     def list(self) -> List[dict]:
         with self._cond:
             return [j.summary() for j in self.jobs.values()]
@@ -614,6 +658,9 @@ class Scheduler:
                 return
             self._stop = True
             self._cond.notify_all()
+            jobs = list(self.jobs.values())
+        for j in jobs:
+            j._done.set()  # no completion is coming: free waiters
         self._thread.join(timeout=10)
         self._reaper.join(timeout=10)
         with self._jlock:
@@ -719,6 +766,8 @@ class Scheduler:
                     self._run_batch(batch)
             except Exception as e:  # a broken job must not kill the loop
                 self._dispatch_failed(batch, e)
+            finally:
+                self._release(batch)
 
     def _retryable(self, e: BaseException) -> bool:
         """The resil taxonomy applied to a dead dispatch: transient
@@ -989,6 +1038,7 @@ class Scheduler:
             # real error
             for j in batch:
                 self._run_supervised(j, frontend="struct")
+                self._release((j,))  # not behind the next job's run
             return
         o = head.options
         walkers = int(o.get("walkers", DEFAULT_SIM_WALKERS))
@@ -1090,6 +1140,7 @@ class Scheduler:
             # error
             for j in batch:
                 self._run_supervised(j, frontend="struct")
+                self._release((j,))  # not behind the next job's run
             return
         o = head.options
         budget = int(o.get("inferbudget", 64))
@@ -1175,6 +1226,9 @@ class Scheduler:
                 ),
             )
             self._finish_ok(j, res)
+            # this route runs its jobs one after another: a verdict
+            # does not wait behind the rest of the batch
+            self._release((j,))
         with self._cond:
             self.batches_run += 1
             self.batched_jobs += len(batch)
@@ -1470,6 +1524,9 @@ class Scheduler:
             self._counters[verdict] += 1
             self._breaker_note_locked(job, verdict)
             self._cond.notify_all()
+        # cancel, the reaper and a quarantining submit finish a job
+        # outside any dispatch: nobody else would wake its waiters
+        job._done.set()
         self._sched_event(action, job, tenant=job.tenant,
                           reason=(reason or verdict))
 

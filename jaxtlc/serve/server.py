@@ -19,13 +19,19 @@ Endpoints (on top of the inherited monitor):
        "options": {"chunk": 64, "qcap": 1024, "fpcap": 4096,
                    "priority": 5, "deadline_s": 30}}
 
-  -> 202 with the job id + the URLs to poll/stream.  Compatible sweep
+  -> 202 with the job id + the URLs to wait on/stream.  Compatible sweep
   jobs batch into one vmapped dispatch; large jobs route through the
   resil supervisor (see serve.scheduler for the discipline).  An
   over-limit submit (queue bound / tenant quota) is **429** with a
   ``Retry-After`` header computed from the measured drain rate.
 * ``GET /jobs`` - the job registry (state, engine, result per job).
 * ``GET /jobs/<id>`` - one job's record (the verdict lives here).
+  With ``?wait=<seconds>`` the answer is held until the job is
+  terminal, at most min(seconds, WAIT_CAP_S): the record
+  arrives when the verdict exists (what ``client.wait`` asks for).
+  Whatever the state is when the wait ends is what is sent.  A held
+  GET is a handler thread outside admission control, and a client
+  that went away is noticed only when the answer is written.
 * ``DELETE /jobs/<id>`` - cancel: a queued job flips to the terminal
   ``canceled`` state; a running checkpointed heavy job drains through
   the programmatic preempt path (ISSUE 17).
@@ -45,11 +51,16 @@ import json
 import os
 import tempfile
 import threading
+import urllib.parse
 from typing import Optional
 
 from ..obs import serve as obs_serve
 from .pool import EnginePool
 from .scheduler import AdmissionError, JobError, Scheduler
+
+# the longest one GET /jobs/<id>?wait= is held, whatever it asks for:
+# under the 30 s socket timeout of serve.client (and of most proxies)
+WAIT_CAP_S = 25.0
 
 
 class _JobHandler(obs_serve._Handler):
@@ -105,14 +116,21 @@ class _JobHandler(obs_serve._Handler):
         }).encode(), "application/json")
 
     def do_GET(self):  # noqa: N802
-        route = self.path.split("?", 1)[0].rstrip("/") or "/"
+        route, _, query = self.path.partition("?")
+        route = route.rstrip("/") or "/"
         try:
             if route == "/jobs":
                 self._send(200, json.dumps(
                     {"jobs": self.scheduler.list()}
                 ).encode(), "application/json")
             elif route.startswith("/jobs/"):
-                job = self.scheduler.get(route[len("/jobs/"):])
+                job_id = route[len("/jobs/"):]
+                wait_s = _wait_seconds(query)
+                # ?wait= holds the answer for the job's completion
+                # (Scheduler.shutdown frees the waiter); without it, or
+                # at 0, the record as it stands
+                job = (self.scheduler.wait(job_id, wait_s) if wait_s
+                       else self.scheduler.get(job_id))
                 if job is None:
                     self._send(404, b"no such job\n", "text/plain")
                     return
@@ -146,6 +164,16 @@ class _JobHandler(obs_serve._Handler):
                        "application/json")
         except (BrokenPipeError, ConnectionResetError):
             pass
+
+
+def _wait_seconds(query: str) -> float:
+    """`wait` of a GET /jobs/<id> query, in [0, WAIT_CAP_S]; absent,
+    negative or not a number is 0: answer at once."""
+    try:
+        asked = float(urllib.parse.parse_qs(query).get("wait", ["0"])[0])
+    except ValueError:
+        return 0.0
+    return min(asked, WAIT_CAP_S) if asked > 0 else 0.0
 
 
 class CheckServer:

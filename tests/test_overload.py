@@ -17,7 +17,9 @@ live in tests/test_service.py against its shared warm server.
 
 import json
 import os
+import threading
 import time
+import types
 import urllib.error
 import urllib.request
 
@@ -287,6 +289,261 @@ def test_breaker_trip_cooldown_half_open(server):
 
 
 # ---------------------------------------------------------------------------
+# GET /jobs/<id>?wait=: the verdict is delivered when it exists
+
+
+class _GetCounter:
+    """client._get with each job request's URL recorded."""
+
+    def __init__(self, monkeypatch):
+        self.urls = []
+        real = client._get
+
+        def counting(url, timeout=30.0):
+            if "/jobs/" in url:
+                self.urls.append(url)
+            return real(url, timeout=timeout)
+
+        monkeypatch.setattr(client, "_get", counting)
+
+
+def _client_sleep(monkeypatch, sleep):
+    """Replace the CLIENT's `time.sleep` only: the server's threads in
+    this process sleep as they must."""
+    monkeypatch.setattr(client, "time", types.SimpleNamespace(
+        time=time.time, monotonic=time.monotonic, sleep=sleep))
+
+
+def _no_sleep(monkeypatch):
+    def fail(secs):
+        raise AssertionError(f"client slept {secs}s: it polled")
+
+    _client_sleep(monkeypatch, fail)
+
+
+def _wait_counters(server):
+    sched = client.pool_stats(server.url)["scheduler"]["sched"]
+    return {k: sched[k] for k in ("wait_blocked", "wait_ready",
+                                  "wait_timeout")}
+
+
+def test_wait_costs_one_get_and_no_sleep(server, monkeypatch):
+    """client.wait on a job still running: ONE request, answered by
+    the job's completion, and no pause in the client."""
+    before = _wait_counters(server)
+    jid = client.submit(server.url, OK_SPEC, CFG, name="slow:0.2-wait")
+    gets = _GetCounter(monkeypatch)
+    _no_sleep(monkeypatch)
+    st = client.wait(server.url, jid, timeout=30)
+    assert st["state"] == "done" and st["finished_t"], st
+    assert len(gets.urls) == 1 and "?wait=" in gets.urls[0], gets.urls
+    after = _wait_counters(server)
+    assert after == dict(before, wait_blocked=before["wait_blocked"] + 1)
+    # already terminal on arrival: answered at once, counted apart
+    assert client.wait(server.url, jid, timeout=30) == st
+    assert len(gets.urls) == 2
+    assert _wait_counters(server) == dict(
+        after, wait_ready=after["wait_ready"] + 1)
+
+
+def test_wait_on_a_held_job_answers_unfinished_at_its_end(server):
+    stall = _stall(server, 0.6, "hold")
+    jid = client.submit(server.url, OK_SPEC, CFG, name="held")
+    before = _wait_counters(server)
+    t0 = time.monotonic()
+    st = client._get(f"{server.url}/jobs/{jid}?wait=0.2")
+    took = time.monotonic() - t0
+    assert st["state"] == "queued" and st["finished_t"] is None, st
+    assert 0.2 <= took < 0.55, took
+    assert _wait_counters(server) == dict(
+        before, wait_timeout=before["wait_timeout"] + 1)
+    # no `wait`, wait=0 and a wait that is no number: the record as it
+    # stands, at once, none of them counted
+    t0 = time.monotonic()
+    for q in ("", "?wait=0", "?wait=soon", "?wait=-1", "?wait=nan"):
+        assert client._get(
+            f"{server.url}/jobs/{jid}{q}")["state"] == "queued", q
+    assert time.monotonic() - t0 < 0.2
+    assert _wait_counters(server)["wait_timeout"] == \
+        before["wait_timeout"] + 1
+    for j in (stall, jid):
+        assert client.wait(server.url, j, timeout=30)["state"] == "done"
+
+
+def _arrange_done(server):
+    return client.submit(server.url, OK_SPEC, CFG, name="w-done")
+
+
+def _arrange_error(server):
+    # a digest of its own: one failure stays under the breaker's two
+    return client.submit(server.url, OK_SPEC + "\\* w-error\n", CFG,
+                         name="boom-w")
+
+
+def _arrange_canceled(server):
+    jid = client.submit(server.url, OK_SPEC, CFG, name="w-canceled")
+    # the waiter must be blocked first: cancel from a timer
+    t = threading.Timer(0.1, client.cancel, (server.url, jid))
+    t.daemon = True
+    t.start()
+    return jid
+
+
+def _arrange_expired(server):
+    return client.submit(server.url, OK_SPEC, CFG, name="w-expired",
+                         options={"deadline_s": 0.1})
+
+
+def _arrange_quarantined(server):
+    spec = OK_SPEC + "\\* w-quarantined\n"
+    for i in (1, 2):  # trip this digest's breaker first
+        assert client.check(server.url, spec, CFG,
+                            name=f"boom-q{i}")["state"] == "error"
+    return client.submit(server.url, spec, CFG, name="w-quarantined")
+
+
+_ARRANGE = dict(done=_arrange_done, error=_arrange_error,
+                canceled=_arrange_canceled, expired=_arrange_expired,
+                quarantined=_arrange_quarantined)
+
+
+@pytest.mark.parametrize("state", TERMINAL_STATES)
+def test_every_terminal_state_releases_a_waiter(server, state,
+                                                monkeypatch):
+    """One blocked GET per terminal state, each answered by the
+    completion itself: the three _finish_* all set the job's event."""
+    # the job under test is held in the queue until the stall ends
+    # (a quarantined job never queues: its waiter finds it terminal)
+    stall = _stall(server, 0.3, f"w-{state}")
+    jid = _ARRANGE[state](server)
+    gets = _GetCounter(monkeypatch)
+    _no_sleep(monkeypatch)
+    st = client.wait(server.url, jid, timeout=30)
+    assert st["state"] == state and st["finished_t"], st
+    assert len(gets.urls) == 1, gets.urls
+    monkeypatch.undo()
+    assert client.wait(server.url, stall, timeout=30)["state"] == "done"
+
+
+def test_many_waiters_on_one_job_all_released_and_counted(server):
+    """More waiters than cores on ONE job, a short switch interval:
+    every Scheduler.wait returns the finished job and every one is
+    counted (the counters are read-modify-write under _cond)."""
+    import sys
+
+    n = 4 * (os.cpu_count() or 8)
+    stall = _stall(server, 0.3, "crowd")
+    jid = client.submit(server.url, OK_SPEC, CFG, name="crowded")
+    before = _wait_counters(server)
+    got = []
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        waiters = [threading.Thread(
+            target=lambda: got.append(server.scheduler.wait(jid, 20)),
+            daemon=True) for _ in range(n)]
+        for t in waiters:
+            t.start()
+        for t in waiters:
+            t.join(20)
+        assert not any(t.is_alive() for t in waiters)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(got) == n and {j.state for j in got} == {"done"}
+    after = _wait_counters(server)
+    assert (after["wait_blocked"] + after["wait_ready"]
+            == before["wait_blocked"] + before["wait_ready"] + n)
+    assert after["wait_blocked"] > before["wait_blocked"]
+    assert after["wait_timeout"] == before["wait_timeout"]
+    assert client.wait(server.url, stall, timeout=30)["state"] == "done"
+
+
+def test_a_retried_job_holds_its_waiter_until_it_is_done(
+        server, monkeypatch):
+    """A dispatch that dies requeues its job: the waiter is not woken
+    for that (the job is not terminal), and the ONE held GET is
+    answered by the retry's completion."""
+    before = _wait_counters(server)
+    jid = client.submit(server.url, OK_SPEC, CFG, name="die-once-w")
+    gets = _GetCounter(monkeypatch)
+    _no_sleep(monkeypatch)
+    st = client.wait(server.url, jid, timeout=30)
+    assert st["state"] == "done" and st["retries"] == 1, st
+    assert len(gets.urls) == 1, gets.urls
+    assert _wait_counters(server) == dict(
+        before, wait_blocked=before["wait_blocked"] + 1)
+
+
+def test_a_waiter_is_woken_once_sched_run_has_closed(server):
+    """The wake is no part of the job's service time: at the moment
+    the job's event is set, the dispatch's `sched.run` row is already
+    in the recorder (test_service holds its children to 95 % of it)."""
+    from jaxtlc.obs import spans
+
+    t = time.time()
+    jid = client.submit(server.url, OK_SPEC, CFG, name="slow:0.2-span")
+
+    class Probe(threading.Event):
+        rows = None
+
+        def set(self):
+            if self.rows is None:
+                self.rows = [r.name for r in spans.snapshot(since=t)
+                             if r.job == jid]
+            super().set()
+
+    probe = server.scheduler.get(jid)._done = Probe()
+    assert client.wait(server.url, jid, timeout=30)["state"] == "done"
+    assert probe.rows == ["sched.run"]
+
+
+def test_the_cap_is_the_servers_and_covers_what_the_client_asks():
+    from jaxtlc.serve import server as srv
+
+    assert srv._wait_seconds("wait=1e9") == srv.WAIT_CAP_S
+    assert srv._wait_seconds("wait=0.2&x=1") == 0.2
+    # a full wait of the client's is a full wait at the server too:
+    # a capped answer would read as "sooner than asked" and be paused on
+    assert client._WAIT_ASK_S <= srv.WAIT_CAP_S
+    assert client._WAIT_ASK_S + client._WAIT_MARGIN_S \
+        <= client._SOCKET_TIMEOUT_S
+
+
+def test_unknown_job_is_404_with_and_without_wait(server):
+    for q in ("", "?wait=0.2"):
+        with pytest.raises(urllib.error.HTTPError) as ei:
+            client._get(f"{server.url}/jobs/job-nonesuch{q}")
+        assert ei.value.code == 404
+
+
+def test_shutdown_releases_a_blocked_waiter():
+    """A server of its own (shutdown is final): a job that will never
+    finish, a waiter asking for far longer than the test may take."""
+    srv = CheckServer(pool=_StubPool())
+    release = threading.Event()
+    srv.scheduler._run_batch = lambda batch: release.wait(30)
+    got = {}
+    try:
+        jid = client.submit(srv.url, OK_SPEC, CFG, name="never")
+        waiter = threading.Thread(
+            target=lambda: got.update(
+                client._get(f"{srv.url}/jobs/{jid}?wait=20")),
+            daemon=True)
+        waiter.start()
+        time.sleep(0.2)
+        assert waiter.is_alive(), "the waiter did not block"
+        t0 = time.monotonic()
+        threading.Timer(0.3, release.set).start()
+        srv.shutdown()
+        waiter.join(5)
+        assert not waiter.is_alive(), "shutdown left the waiter blocked"
+        assert time.monotonic() - t0 < 5
+        assert got["state"] in ("queued", "running"), got
+    finally:
+        release.set()
+
+
+# ---------------------------------------------------------------------------
 # drain, surfaces
 
 
@@ -313,6 +570,8 @@ def test_health_stats_and_metrics_surfaces(server):
     assert stats["tenant_quota"] == TENANT_QUOTA
     assert stats["dispatches"] >= 1
     assert stats["sched"] == h["counters"]
+    for k in ("wait_blocked", "wait_ready", "wait_timeout"):
+        assert stats["sched"][k] >= 1, k
     # every control-plane decision renders as a Prometheus gauge off
     # the sched journal (obs.views.metrics_from_events)
     with urllib.request.urlopen(

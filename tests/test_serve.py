@@ -212,3 +212,57 @@ def test_spans_event_reaches_metrics_and_tlcstat(tmp_path):
 
     with pytest.raises(JournalSchemaError):
         jr.RunJournal().event("spans", rows=[["build", t, "long"]])
+
+
+def test_client_wait_polls_a_server_that_does_not_block(monkeypatch):
+    """ISSUE 32: against a handler that ignores `?wait=` (an older
+    build, a proxy) client.wait still pauses `poll_s` between
+    requests, returns the terminal record, and raises after
+    `timeout`."""
+    from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+
+    from test_overload import _client_sleep
+
+    from jaxtlc.serve import client
+
+    seen = []
+
+    class Stub(BaseHTTPRequestHandler):
+        finish_at = 3  # the request that finds the job done
+
+        def do_GET(self):  # noqa: N802
+            seen.append(self.path)
+            state = "done" if len(seen) >= self.finish_at else "running"
+            body = json.dumps({"id": "job-x", "state": state}).encode()
+            self.send_response(200)
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
+
+        def log_message(self, fmt, *args):
+            pass
+
+    httpd = ThreadingHTTPServer(("127.0.0.1", 0), Stub)
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    url = f"http://127.0.0.1:{httpd.server_address[1]}"
+    pauses = []
+    _client_sleep(monkeypatch,
+                  lambda s: (pauses.append(s), time.sleep(s)))
+    try:
+        st = client.wait(url, "job-x", timeout=30, poll_s=0.01)
+        assert st["state"] == "done"
+        assert len(seen) == 3 and pauses == [0.01, 0.01], (seen, pauses)
+        assert all(p.startswith("/jobs/job-x?wait=") for p in seen)
+        # never terminal: a pause after every request, then the raise
+        Stub.finish_at = 10 ** 9
+        del seen[:], pauses[:]
+        with pytest.raises(client.ClientError, match="still running"):
+            client.wait(url, "job-x", timeout=0.15, poll_s=0.02)
+        # (the last request has no pause; nor has one whose few ms
+        # used up an `ask` rounded down to the millisecond)
+        assert len(seen) >= 3 and len(pauses) >= len(seen) - 2, (
+            seen, pauses)
+        assert set(pauses) == {0.02}
+    finally:
+        httpd.shutdown()
+        httpd.server_close()

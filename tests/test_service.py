@@ -238,6 +238,29 @@ def test_warm_resubmit_zero_fresh_xla_compiles(server, sweep_jobs):
     assert st["result"]["distinct"] == _EXPECT[1][1]
 
 
+def test_warm_job_verdict_in_one_get_without_a_pause(
+        server, sweep_jobs, monkeypatch):
+    """ISSUE 32: a pooled job (warm from the test above: no compile)
+    answered through client.wait costs exactly ONE GET, which the
+    server holds until the verdict exists; the client never pauses.
+    No assertion on milliseconds: six workers share this CPU."""
+    from test_overload import _GetCounter, _no_sleep
+
+    jid = client.submit(server.url, _TPB, _cfg(2), name="plain-wait",
+                        options=_OPTS)
+    gets = _GetCounter(monkeypatch)
+    _no_sleep(monkeypatch)
+    st = client.wait(server.url, jid, timeout=600)
+    assert gets.urls == [
+        f"{server.url}/jobs/{jid}?wait={client._WAIT_ASK_S}"]
+    assert st["state"] == "done" and st["finished_t"], st
+    assert st["result"]["engine"] == "pool"
+    assert st["result"]["distinct"] == _EXPECT[2][1]
+    # the record a waiter receives is the one a plain GET returns
+    monkeypatch.undo()
+    assert client.status(server.url, jid) == st
+
+
 def test_pooled_job_leaves_every_span(server, sweep_jobs):
     """ISSUE 24: a pooled job's host spans - the scheduler thread's
     eleven (since PR 31: `build.struct.load` inside `sched.load`)
@@ -254,10 +277,11 @@ def test_pooled_job_leaves_every_span(server, sweep_jobs):
     st = client.check(server.url, _TPB, _cfg(2), name="plain-spans",
                       options=_OPTS)
     assert st["result"]["engine"] == "pool"
-    # a client's polls (any id, any rate) cost the recorder no row:
+    # a client's requests (any id, any rate) cost the recorder no row:
     # every row since the submit is the scheduler thread's, of this job
     with pytest.raises(urllib.error.HTTPError):
         client.status(server.url, "no-such-job")
+    # (the waiter is woken once sched.run has closed: ISSUE 32)
     sched = spans.snapshot(since=t)
     assert {r.job for r in sched} == {st["id"]}
     root = assert_tree(sched, "sched.run")

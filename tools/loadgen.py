@@ -195,13 +195,10 @@ def run_cache(url: str, jobs: int, in_process: bool,
     hit_lat = []
     for i in range(max(0, jobs - 1)):
         t0 = time.time()
-        # fine-grained poll (5 ms vs the default 50): the hit path is
-        # O(HTTP), so the default poll interval would BE the number
         st = client.wait(
             url,
             client.submit(url, _SPEC, _CFG, name=f"cache-hit-{i}",
                           options=opts),
-            poll_s=0.005,
         )
         hit_lat.append(time.time() - t0)
         assert st["state"] == "done", st
