@@ -8,9 +8,16 @@ Static, engine-free verification of the two state-space reductions
   spec only ever compares for equality.  In this IR that is checkable
   syntactically: atoms are plain strings, and the only way a spec can
   distinguish two atoms of a set S is (a) naming one as a string
-  literal, (b) pinning one through ANOTHER constant whose value embeds
-  it, or (c) ``CHOOSE`` (whose deterministic pick is not
-  permutation-equivariant).  A candidate passing all three checks is
+  literal, (b) reading ANOTHER constant whose value embeds atoms of S
+  and is not itself invariant under S's permutations (`Leader = r1`
+  pins; `Quorum = {{a1,a2},{a1,a3},{a2,a3}}`, which every permutation
+  of the acceptors maps to itself, does not; neither does a constant
+  the spec never reads, such as the model values `a1 = a1` of an
+  MC.cfg), or (c) a ``CHOOSE`` it can reach (whose deterministic pick
+  is not permutation-equivariant).  The surface is what the evaluator
+  would evaluate: a definition the cfg overrides with a constant
+  (`None = None` over the module's `None == CHOOSE ...`) is not on it.
+  A candidate passing all three checks is
   permutation-symmetric: for every permutation pi of S and reachable
   state s, pi(s) is reachable, and every invariant/property satisfies
   Inv(pi(s)) = Inv(s) - the soundness basis for fingerprinting only
@@ -40,9 +47,10 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, NamedTuple, Optional, Set, Tuple
 
-from . import SEV_WARNING, Finding
+from ..struct.eval import permute_value
+from . import SEV_INFO, SEV_WARNING, Finding
 from .speclint import SpecAnalysis, analyze_spec
 
 # orbit-group budget: the canonicalization kernel unrolls one field
@@ -57,13 +65,27 @@ PERM_LIMIT = 24
 # ---------------------------------------------------------------------------
 
 
-def _spec_atom_surface(model) -> Tuple[Set[str], bool]:
-    """(string literals, CHOOSE reachable?) over the reachable-def
-    closure of init/next/invariants/properties - the full surface a
-    permutation of constant atoms must commute with."""
+class Surface(NamedTuple):
+    """What the reachable-definition closure of init / next / the cfg's
+    invariants and properties mentions: string literals, names (those
+    of constants among them), and whether a CHOOSE is on it."""
+
+    strs: Set[str]
+    names: Set[str]
+    has_choose: bool
+
+
+def _spec_atom_surface(model) -> Surface:
+    """The full surface a permutation of constant atoms must commute
+    with.  A name the cfg resolves to a constant is read as that
+    constant, as the evaluator reads it: a module definition of the
+    same name (the source's `None == CHOOSE v : v \\notin Value` under
+    `None = None`) is never evaluated and is not descended into."""
     system = model.system
     defs = system.ev.defs
+    constants = model.constants
     strs: Set[str] = set()
+    names: Set[str] = set()
     has_choose = False
     stack: List[object] = [system.init_ast, system.next_ast]
     stack.extend(model.invariants.values())
@@ -86,8 +108,10 @@ def _spec_atom_surface(model) -> Tuple[Set[str], bool]:
             has_choose = True
         if op in ("name", "call") and len(node) >= 2 \
                 and isinstance(node[1], str):
+            names.add(node[1])
             d = defs.get(node[1])
-            if d is not None and node[1] not in seen:
+            overridden = op == "name" and node[1] in constants
+            if d is not None and not overridden and node[1] not in seen:
                 seen.add(node[1])
                 stack.append(d.body)
             if op == "call" and len(node) == 3:
@@ -97,7 +121,7 @@ def _spec_atom_surface(model) -> Tuple[Set[str], bool]:
         start = 1 if isinstance(op, str) else 0
         stack.extend(x for x in node[start:]
                      if isinstance(x, (tuple, list)))
-    return strs, has_choose
+    return Surface(strs, names, has_choose)
 
 
 def _atoms_in(value, out: Set[str]) -> None:
@@ -111,44 +135,64 @@ def _atoms_in(value, out: Set[str]) -> None:
             _atoms_in(x, out)
 
 
+def _invariant_under(value, atoms: Tuple[str, ...]) -> bool:
+    """`value` is mapped to itself by every permutation of `atoms`: by
+    the adjacent transpositions, which generate them all."""
+    return all(
+        permute_value(value, {a: b, b: a}) == value
+        for a, b in zip(atoms, atoms[1:])
+    )
+
+
+class SymmetryError(ValueError):
+    """A set the cfg's SYMMETRY declares failed verification: the run
+    may not go on unreduced (its counts would not be what the cfg asks
+    for), so this is an error with the reason, never a warning."""
+
+
 def find_symmetric_sets(model) -> Tuple[
         Dict[str, Tuple[str, ...]], Dict[str, str]]:
-    """(kept, rejected): candidate symmetric sets are CONSTANTs resolved
-    to frozensets of >= 2 atoms; `kept` maps constant name -> sorted
-    atom tuple for the sets that pass static verification, `rejected`
-    maps the rest to a human-readable reason."""
+    """(kept, rejected): candidate symmetric sets are the sets the
+    model's cfg declares (`model.symmetry`), else every CONSTANT
+    resolved to a frozenset of >= 2 atoms; `kept` maps constant name ->
+    sorted atom tuple for the sets that pass static verification,
+    `rejected` maps the rest to a human-readable reason."""
+    declared = dict(getattr(model, "symmetry", ()) or ())
     candidates = {
         name: v for name, v in sorted(model.constants.items())
         if isinstance(v, frozenset) and len(v) >= 2
         and all(isinstance(x, str) for x in v)
+        and (not declared or name in declared)
     }
     kept: Dict[str, Tuple[str, ...]] = {}
     rejected: Dict[str, str] = {}
     if not candidates:
         return kept, rejected
-    strs, has_choose = _spec_atom_surface(model)
+    surface = _spec_atom_surface(model)
     budget = 1
     for name, val in candidates.items():
         atoms = tuple(sorted(val))
         why: Optional[str] = None
-        if has_choose:
+        if surface.has_choose:
             why = ("spec reaches a CHOOSE; its deterministic pick is "
                    "not permutation-equivariant")
         if why is None:
-            hit = sorted(set(atoms) & strs)
+            hit = sorted(set(atoms) & surface.strs)
             if hit:
                 why = (f"element(s) {', '.join(hit)} appear as string "
                        "literals in the spec")
         if why is None:
             for other, oval in sorted(model.constants.items()):
-                if other == name or oval == val:
+                if other == name or oval == val \
+                        or other not in surface.names:
                     continue
                 used: Set[str] = set()
                 _atoms_in(oval, used)
                 pin = sorted(set(atoms) & used)
-                if pin:
+                if pin and not _invariant_under(oval, atoms):
                     why = (f"element(s) {', '.join(pin)} are pinned "
-                           f"through constant {other}")
+                           f"through constant {other}, whose value a "
+                           f"permutation of {name} changes")
                     break
         if why is None:
             fact = math.factorial(len(atoms))
@@ -163,13 +207,31 @@ def find_symmetric_sets(model) -> Tuple[
     return kept, rejected
 
 
+def require_declared(model, failed: Dict[str, str]) -> None:
+    """Hold a reduction to the model's cfg: a set its SYMMETRY declares
+    that is among `failed` (name -> reason: the verification's
+    rejections, the plan's drops) is a SymmetryError naming the
+    reason."""
+    for name, _ in getattr(model, "symmetry", ()) or ():
+        if name in failed:
+            raise SymmetryError(
+                f"SYMMETRY over {name} cannot be reduced: {failed[name]}")
+
+
 def unreduced_symmetry_findings(model) -> List[Finding]:
     """One SEV_WARNING per SYMMETRY-eligible set: the spec qualifies
     for orbit dedup but the run is not taking it (preflight journals
     these; a `-symmetry` run drops the reduced sets from the list the
-    struct backend leaves over)."""
-    kept, _rejected = find_symmetric_sets(model)
-    out: List[Finding] = []
+    struct backend leaves over) - and one SEV_INFO per candidate set
+    that is NOT eligible, with what it was rejected for, so that
+    `-symmetry` on such a spec is no surprise."""
+    kept, rejected = find_symmetric_sets(model)
+    out: List[Finding] = [
+        Finding(layer="spec", check="unreduced-symmetry",
+                severity=SEV_INFO, subject=name,
+                detail=f"constant {name} is not SYMMETRY-eligible: {why}")
+        for name, why in rejected.items()
+    ]
     for name, atoms in kept.items():
         out.append(Finding(
             layer="spec", check="unreduced-symmetry",

@@ -63,12 +63,20 @@ class CheckRequest:
     # the sim engines keep their immediate per-walker invariant path.
     deferredinv: Optional[bool] = None
     # tri-state -symmetry/-no-symmetry and -por/-no-por (ISSUE 18):
-    # None = auto (resolve_symmetry/resolve_por - currently OFF: both
-    # reductions legitimately shrink the state counts, so they are
-    # opt-in, not auto-on perf modes).  -symmetry canonicalizes every
-    # successor to its orbit representative over statically-verified
-    # symmetric constant sets (runtime orbit certificate on single
-    # device); -por prunes commutative interleavings of provably safe
+    # None = auto (resolve_symmetry/resolve_por - OFF: both reductions
+    # legitimately shrink the state counts, so they are opt-in, not
+    # auto-on perf modes).  -symmetry canonicalizes every successor to
+    # its orbit representative over every constant set of model values
+    # that analysis.symfind can verify symmetric (runtime orbit
+    # certificate on single device); a set it rejects stays unreduced
+    # and the transcript and the journal's `reduce` event say what it
+    # was rejected for (a string literal naming an element, a constant
+    # the spec reads whose value a permutation changes, a reachable
+    # CHOOSE, the group past PERM_LIMIT).  A cfg's own `SYMMETRY <def>`
+    # is the second way in and needs no flag: it resolves to the same
+    # bool (struct.cache.wants_symmetry), reduces exactly the sets it
+    # declares, and a declared set that fails verification is an
+    # error.  -por prunes commutative interleavings of provably safe
     # actions.  Struct frontend only.
     symmetry: Optional[bool] = None
     por: Optional[bool] = None
@@ -704,10 +712,44 @@ def _deferred(args) -> bool:
 
 def _symmetry(args) -> bool:
     """The RESOLVED -symmetry mode this run's engines will use (journal
-    manifests record the fact, not the tri-state)."""
+    manifests record the fact, not the tri-state): the flag's, or on
+    where the struct model's cfg declares SYMMETRY
+    (`_resolve_struct_symmetry` has then left its mark on `args`)."""
     from .engine.bfs import resolve_symmetry
 
+    if getattr(args, "_cfg_symmetry", False):
+        return True
     return resolve_symmetry(getattr(args, "symmetry", None), args.chunk)
+
+
+def _resolve_struct_symmetry(args, spec, sm, bounds):
+    """Settle a struct run's symmetry before anything is built on it:
+    the cfg's declaration or the flag (struct.cache.wants_symmetry),
+    the sets' verification and the plan (the reduced backend's build:
+    the memo's first look-up of the run).  Leaves on `args` the
+    resolved mode and the reduction itself (`_reduce_ops`: what
+    `run_start` says of it, and the sets `-symmetry` could not take,
+    each with its reason).  An error text where the run may not start:
+    a declared set that fails, or -no-symmetry against a cfg that
+    declares one."""
+    from .analysis.symfind import SymmetryError
+    from .struct.cache import get_backend, wants_symmetry
+
+    try:
+        sym = wants_symmetry(sm, getattr(args, "symmetry", None),
+                             args.chunk)
+        args._cfg_symmetry = bool(sm.symmetry)
+        if not sym:
+            return None
+        red = get_backend(
+            sm, spec.check_deadlock, bounds=bounds,
+            elide=not args.sharded, coverage=args.coverage,
+            symmetry=True, por=_por(args),
+        ).reduce
+    except SymmetryError as e:
+        return str(e)
+    args._reduce_ops = red
+    return None
 
 
 def _por(args) -> bool:
@@ -1082,6 +1124,10 @@ def _run_check_struct(args, spec) -> int:
     # reachable-set tier (invariant-only edit -> BFS-free vmapped
     # invariant pass).  Resume/fault/mutation/coverage/profiling runs
     # opt out - they exist to exercise the engines themselves.
+    sym_error = _resolve_struct_symmetry(args, spec, sm, bounds)
+    if sym_error:
+        print(f"Error: {sym_error}", file=_err(args))
+        return 1
     art_plan = _artifact_plan(args, spec, sm, bounds)
     capture = art_plan is not None and not args.sharded
 
@@ -1852,6 +1898,7 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
     log.sany(*_sany_inputs(args.config, spec.spec_name))
     log.starting()
     log.computing_init()
+    red = getattr(args, "_reduce_ops", None)
     _open_journal(
         args, workload=spec.spec_name,
         engine="sharded" if args.sharded else "single",
@@ -1862,8 +1909,15 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
                     sort_free=_sort_free(args),
                     deferred=_deferred(args),
                     symmetry=_symmetry(args), por=_por(args),
-                    obs_slots=_obs_slots(args)),
+                    obs_slots=_obs_slots(args),
+                    # a reduced run names its sets and the group's order
+                    **({} if red is None else dict(
+                        symmetric_sets={k: list(v)
+                                        for k, v in red.sym_sets},
+                        sym_perms=red.orbit_factor))),
     )
+    for name, why in (() if red is None else red.dropped_sets):
+        log.msg(1000, f"-symmetry: constant {name} is not reduced: {why}")
     # incremental re-checking (ISSUE 13): try the artifact tiers BEFORE
     # preflight or any engine build.  A verdict hit swaps the engine
     # dispatch for the cached result (and stands in for the temporal

@@ -127,6 +127,13 @@ class ExpandOut(NamedTuple):
     # to re-canonicalize (engine.reduce.ReducePlan.orbit_check; None
     # when symmetry reduction is off, keeping pytree layouts exact)
     sym: jnp.ndarray = None
+    # [4] uint32 beside it: this block's valid candidate rows that were
+    # canonicalized, those whose representative differs from the
+    # candidate, whether the orbit check had a row to sample (0 / 1),
+    # and whether it tripped (0 / 1) - summed into the carry's
+    # `sym_stat` (CheckResult.canon_rows, canon_moved, sym_cert_checks,
+    # sym_cert_trips)
+    sym_stat: jnp.ndarray = None
     # uint32 scalar: candidate transitions pruned by the POR ample-set
     # mask in this block (None when POR is off)
     pruned: jnp.ndarray = None
@@ -221,8 +228,11 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         # deferred commit-side checker reading ExpandOut.flat) sees
         # canonical states - sound because symfind verified the spec
         # cannot distinguish orbit members
+        moved = None
         if sym_plan is not None:
-            flat = sym_plan.canon(flat)
+            raw = flat
+            flat = sym_plan.canon(raw)
+            moved = (fvalid & (flat != raw).any(axis=1)).sum()
 
         # deferred mode: invariants + certificate run at the commit
         # stage on the fresh-insert claimants only (the distinct-first
@@ -264,9 +274,12 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         # permutation - a mismatch means the symmetry plan is not
         # acting as a permutation group and the run's dedup cannot be
         # trusted; the engine latches it into an error verdict
-        sym = None
+        sym = sym_stat = None
         if sym_plan is not None:
             sym = sym_plan.orbit_check(flat, fvalid)
+            sym_stat = jnp.stack(
+                [fvalid.sum(), moved, fvalid.any(), sym]
+            ).astype(jnp.uint32)
 
         # per-action generated counters, scatter-free: the backend's
         # factorized hook (KubeAPI dispatch structure, PERF.md item 5)
@@ -317,7 +330,7 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
             gen=gen, viol=viol, viol_state=viol_state,
             viol_action=viol_action, cert=cert, cov=cov,
             flat=flat if deferred else None,
-            sym=sym, pruned=pruned,
+            sym=sym, sym_stat=sym_stat, pruned=pruned,
         )
 
     return expand

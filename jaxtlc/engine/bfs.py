@@ -163,6 +163,10 @@ class EngineCarry(NamedTuple):
     # drivers - the COL_CERT pattern exactly.
     sym_viol: jnp.ndarray = None  # bool
     st_sym: jnp.ndarray = None  # staged block's orbit-check bit
+    # Cumulative [4] uint32 beside it (ExpandOut.sym_stat summed): rows
+    # canonicalized, rows moved, orbit checks made, orbit checks tripped
+    sym_stat: jnp.ndarray = None
+    st_sym_stat: jnp.ndarray = None  # staged block's four
     # Cumulative uint32: candidate transitions the POR ample-set mask
     # pruned at expand time (journalled as the `reduce` event's counter
     # delta; state counts legitimately shrink under POR)
@@ -252,13 +256,29 @@ class CheckResult(NamedTuple):
     states_expanded: int = None
     lane_fires: int = None
     struct_traps: int = None
+    # symmetry-reduced single-device runs only (telemetry; None
+    # elsewhere): the order of the group the tournament minimises over
+    # (identity included) and the constant sets it permutes; valid
+    # candidate rows canonicalized and those whose representative
+    # differs from the candidate (`canon_moved / canon_rows`: the share
+    # the tournament rewrote, a constant of the model, and 0 the day the
+    # reduction stops engaging); bodies whose orbit certificate had a
+    # row to sample, and those where it tripped (`sym_violated`)
+    sym_perms: int = None
+    sym_sets: int = None
+    canon_rows: int = None
+    canon_moved: int = None
+    sym_cert_checks: int = None
+    sym_cert_trips: int = None
 
 
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
                  "route_bucket", "route_bytes", "commit_segments",
                  "commit_rows")
 STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
-                 "states_expanded", "lane_fires", "struct_traps")
+                 "states_expanded", "lane_fires", "struct_traps",
+                 "sym_perms", "sym_sets", "canon_rows", "canon_moved",
+                 "sym_cert_checks", "sym_cert_trips")
 
 
 def mesh_counters(result: CheckResult) -> dict:
@@ -277,6 +297,10 @@ def with_step_counters(result: CheckResult, backend) -> CheckResult:
     static = getattr(getattr(backend, "cdc", None), "static_lanes", None)
     if static is None:
         return result
+    plan = getattr(backend.reduce, "plan", None)
+    if plan is not None:
+        result = result._replace(sym_perms=plan.n_perms,
+                                 sym_sets=len(plan.sym_sets))
     return result._replace(
         step_lanes=static,
         step_slots=backend.n_lanes,
@@ -696,6 +720,7 @@ def make_stage_pair(
                 # fields, so the deferred mode needs no commit-site variant
                 sym_now = c.sym_viol | ex.sym
                 extra["sym_viol"] = sym_now
+                extra["sym_stat"] = c.sym_stat + ex.sym_stat
             if ex.pruned is not None and c.por_pruned is not None:
                 extra["por_pruned"] = c.por_pruned + ex.pruned
             if ex.cov is not None and c.cov_counts is not None:
@@ -982,12 +1007,14 @@ def make_backend_engine(
                 staged["st_flat"] = jnp.zeros((ncand_full, F), jnp.int32)
             if has_sym:
                 staged["st_sym"] = jnp.bool_(False)
+                staged["st_sym_stat"] = jnp.zeros(4, jnp.uint32)
             if has_por:
                 staged["st_pruned"] = jnp.uint32(0)
         if has_cert:
             staged["cert_viol"] = jnp.bool_(False)
         if has_sym:
             staged["sym_viol"] = jnp.bool_(False)
+            staged["sym_stat"] = jnp.zeros(4, jnp.uint32)
         if has_por:
             staged["por_pruned"] = jnp.uint32(0)
         if cov_plane is not None:
@@ -1060,6 +1087,7 @@ def make_backend_engine(
                 extra["st_flat"] = ex.flat
             if has_sym:
                 extra["st_sym"] = ex.sym
+                extra["st_sym_stat"] = ex.sym_stat
             if has_por:
                 extra["st_pruned"] = ex.pruned
             return c._replace(
@@ -1079,6 +1107,7 @@ def make_backend_engine(
                 cov=c.st_cov if cov_plane is not None else None,
                 flat=c.st_flat if deferred else None,
                 sym=c.st_sym if has_sym else None,
+                sym_stat=c.st_sym_stat if has_sym else None,
                 pruned=c.st_pruned if has_por else None,
             )
 
@@ -1455,6 +1484,10 @@ def result_from_carry(
     cert_violated = bool(cert) if cert is not None else None
     sym = getattr(carry, "sym_viol", None)
     sym_violated = bool(sym) if sym is not None else None
+    stat = getattr(carry, "sym_stat", None)
+    sym_counts = {} if stat is None else dict(zip(
+        ("canon_rows", "canon_moved", "sym_cert_checks",
+         "sym_cert_trips"), map(int, np.asarray(stat))))
     pruned = getattr(carry, "por_pruned", None)
     if pruned is not None:
         pruned = int(np.asarray(pruned).sum())  # shards carry partials
@@ -1491,4 +1524,5 @@ def result_from_carry(
         site_coverage=site_coverage,
         sym_violated=sym_violated,
         por_pruned=por_pruned,
+        **sym_counts,
     )

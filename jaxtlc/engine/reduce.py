@@ -13,10 +13,12 @@ per-engine code:
   model values.  The canonicalization is a dense tournament over the
   codec's flat [N, F] int32 fields: each non-identity permutation of
   the symmetry group compiles to a static *field program* (gather +
-  per-field remap tables + bitmask bit-permutations) and the kernel
-  takes a running lexicographic minimum - no sort, no host pass, no
-  new engine loops (the BLEST framing: bitmaps and dense compares over
-  the packed representation).
+  per-field remap tables + bitmask bit-permutations), the programs
+  are stacked into arrays (`_ArrayForm`: one remap matrix a field, one
+  bits-to-fields matrix a mask, for all programs at once), and the
+  kernel takes the lexicographic minimum over the stacked images field
+  by field - no sort, no host pass, no new engine loops, and a few
+  equations a field whatever the group's order or a mask's width.
 
 * **POR (singleton ample sets)** - when a state enables an action the
   static analysis proved independent-of-everything, invisible and
@@ -55,6 +57,7 @@ import itertools
 import os
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -65,7 +68,7 @@ from ..struct.codec import (
     RecNode,
     SeqNode,
 )
-from ..struct.eval import is_fn
+from ..struct.eval import permute_value
 
 
 class RejectSet(Exception):
@@ -73,25 +76,6 @@ class RejectSet(Exception):
     codec field program (permuted value outside an enumerated universe,
     unequal sibling layouts under permuted field names); the caller
     drops the set and reports why."""
-
-
-def permute_value(v, pmap: Dict[str, str]):
-    """Apply an atom permutation to an evaluator value, mirroring the
-    evaluator's own conventions (struct.eval): atoms are strings,
-    records/functions are key-sorted tuples of (str, value) pairs
-    (is_fn), sets are frozensets, sequences plain tuples."""
-    if isinstance(v, str):
-        return pmap.get(v, v)
-    if isinstance(v, frozenset):
-        return frozenset(permute_value(x, pmap) for x in v)
-    if isinstance(v, tuple):
-        if v and is_fn(v):
-            return tuple(sorted(
-                (permute_value(k, pmap), permute_value(x, pmap))
-                for k, x in v
-            ))
-        return tuple(permute_value(x, pmap) for x in v)
-    return v
 
 
 class _PermProgram(NamedTuple):
@@ -202,34 +186,161 @@ def _emit(lay, off: int, pmap, prog: dict, guards: tuple) -> int:
     raise RejectSet(f"no field program for layout {type(lay).__name__}")
 
 
-def _apply_program(prog: _PermProgram, flat, xp) -> list:
-    """Apply one permutation program to flat [N, F]; returns the F
-    per-field columns (xp is jnp on device, np for the host twin)."""
-    F = flat.shape[-1]
-    cols = [flat[..., j] for j in range(F)]
-    if prog.src is not None:
-        cols = [cols[int(prog.src[j])] for j in range(F)]
-    for field, tbl, guards in prog.tables:
-        t = xp.asarray(tbl)
-        nv = t[xp.clip(cols[field], 0, len(tbl) - 1)]
-        if guards:
-            cond = None
-            for g in guards:
-                c = (cols[g[1]] > g[2]) if g[0] == "len" \
-                    else (cols[g[1]] != 0)
-                cond = c if cond is None else (cond & c)
-            nv = xp.where(cond, nv, cols[field])
-        cols[field] = nv
-    for off, widths, sigma in prog.masks:
-        newf = [xp.zeros_like(cols[off]) for _ in widths]
-        for i, d in enumerate(sigma):
-            bit = (cols[off + i // MASK_BITS_PER_FIELD]
-                   >> (i % MASK_BITS_PER_FIELD)) & 1
-            fi, bo = d // MASK_BITS_PER_FIELD, d % MASK_BITS_PER_FIELD
-            newf[fi] = newf[fi] | (bit << bo)
-        for fi in range(len(widths)):
-            cols[off + fi] = newf[fi]
-    return cols
+# a remap table at most this long is applied as a one-hot product (the
+# form the chip's matrix unit runs; an element gather costs it ~8 ns a
+# row, PERF.md section 5), a longer one as a gather: a one-hot of an
+# enumerated universe of 10^5 values would not fit beside the rows
+ONEHOT_MAX = 1024
+_BIG = np.int32(np.iinfo(np.int32).max)
+
+
+class _ArrayForm(NamedTuple):
+    """Every non-identity program of a plan, stacked: what `_images`
+    applies to flat [N, F] in a handful of array operations."""
+
+    n_programs: int
+    src: Optional[np.ndarray]  # [Pm, F] dest<-src gather, None = none moves
+    tables: tuple  # ((field, T [Pm, len] int32, guards), ...)
+    masks: tuple  # ((offset, n_fields, W [Pm, n_fields*16, n_fields]), ...)
+    touched: tuple  # fields some program changes, ascending
+
+
+def _array_form(programs: List[_PermProgram], n_fields: int) -> _ArrayForm:
+    Pm = len(programs)
+    ident = np.arange(n_fields, dtype=np.int32)
+    src = None
+    if any(p.src is not None for p in programs):
+        src = np.stack([ident if p.src is None else p.src
+                        for p in programs])
+    by_field: Dict[int, list] = {}
+    for k, p in enumerate(programs):
+        for field, tbl, guards in p.tables:
+            ent = by_field.setdefault(field, [len(tbl), guards, {}])
+            assert ent[0] == len(tbl) and ent[1] == guards
+            ent[2][k] = tbl
+    tables = tuple(
+        (field, np.stack([rows.get(k, np.arange(n, dtype=np.int32))
+                          for k in range(Pm)]), guards)
+        for field, (n, guards, rows) in sorted(by_field.items())
+    )
+    by_mask: Dict[int, list] = {}
+    for k, p in enumerate(programs):
+        for off, widths, sigma in p.masks:
+            by_mask.setdefault(off, [len(widths), {}])[1][k] = sigma
+    masks = []
+    B = MASK_BITS_PER_FIELD
+    for off, (nf, sigmas) in sorted(by_mask.items()):
+        # image field = sum over source bits of bit x 2^(its place in
+        # the image field): one 0 / power-of-two matrix for all programs
+        W = np.zeros((Pm, nf * B, nf), np.int32)
+        for k in range(Pm):
+            sigma = sigmas.get(k)
+            for i in range(nf * B):
+                d = sigma[i] if sigma is not None and i < len(sigma) else i
+                W[k, i, d // B] = 1 << (d % B)
+        masks.append((off, nf, W))
+    touched = {f for f, _, _ in tables}
+    for off, nf, _ in masks:
+        touched |= set(range(off, off + nf))
+    if src is not None:
+        touched |= {j for j in range(n_fields) if (src[:, j] != j).any()}
+    return _ArrayForm(Pm, src, tables, tuple(masks),
+                      tuple(sorted(touched)))
+
+
+def _product(spec: str, a, w, xp):
+    """einsum `spec` of rows `a` with the static matrix `w`, exact: small
+    non-negative integers.  On the device as bf16 into f32 (every entry
+    an integer below 2^8 or a power of two, every sum below 2^24: exact
+    on the matrix unit)."""
+    if xp is np:
+        return np.einsum(spec, a.astype(np.int64),
+                         w.astype(np.int64)).astype(np.int32)
+    return jnp.einsum(
+        spec, a.astype(jnp.bfloat16), jnp.asarray(w, jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+
+
+def _images(form: _ArrayForm, flat, xp) -> Dict[int, object]:
+    """The touched fields of every program's image of flat [N, F]:
+    {field: [Pm, N]}."""
+    Pm, N = form.n_programs, flat.shape[0]
+    if form.src is not None:
+        moved = flat[:, xp.asarray(form.src)]  # [N, Pm, F]
+
+        def col(j):
+            return moved[:, :, j].T
+    else:
+        def col(j):
+            return xp.broadcast_to(flat[:, j][None, :], (Pm, N))
+    out: Dict[int, object] = {}
+    for field, T, guards in form.tables:
+        n = T.shape[1]
+        cur = col(field)
+        if n <= ONEHOT_MAX and form.src is None:
+            onehot = flat[:, field][:, None] == xp.arange(n)[None, :]
+            both = _product("nk,km->mn", onehot, np.concatenate(
+                [T.T & 0xFF, T.T >> 8], axis=1), xp)  # [2 Pm, N]
+            nv = both[:Pm] + (both[Pm:] << 8)
+            # a code outside the table (a trapped lane) stays as it is
+            nv = xp.where((cur >= 0) & (cur < n), nv, cur)
+        else:
+            nv = xp.take_along_axis(xp.asarray(T), xp.clip(cur, 0, n - 1),
+                                    axis=1)
+        for g in guards:
+            # canonical zeros stay zero (module docstring); the guard
+            # columns are lengths and presence bits, which no table maps
+            cond = (col(g[1]) > g[2]) if g[0] == "len" \
+                else (col(g[1]) != 0)
+            nv = xp.where(cond, nv, cur)
+        out[field] = nv
+    B = MASK_BITS_PER_FIELD
+    for off, nf, W in form.masks:
+        # image field = sum over source bits of bit x 2^(its place)
+        if form.src is None:
+            bits = (flat[:, off:off + nf, None] >> xp.arange(B)) & 1
+            img = _product("nb,pbf->pfn", bits.reshape(N, nf * B), W, xp)
+        else:  # each program permutes the bits of its own moved fields
+            bits = (moved[:, :, off:off + nf, None] >> xp.arange(B)) & 1
+            img = _product("npb,pbf->pfn",
+                           bits.reshape(N, Pm, nf * B), W, xp)
+        for fi in range(nf):
+            out[off + fi] = img[:, fi, :]
+    for j in form.touched:
+        if j not in out:
+            out[j] = col(j)
+    return out
+
+
+def _image_rows(form: _ArrayForm, flat, xp):
+    """[Pm, N, F]: every program's image of flat's rows, whole."""
+    img = _images(form, flat, xp)
+    shape = (form.n_programs, flat.shape[0])
+    return xp.stack([
+        img[j] if j in img else xp.broadcast_to(flat[:, j][None, :], shape)
+        for j in range(flat.shape[1])
+    ], axis=-1)
+
+
+def _lexmin(flat, images, xp):
+    """The lexicographic minimum of flat's rows and their images, field
+    by field: the images still level with the minimum so far compete
+    for the next field, whose least value IS the minimum's field."""
+    alive = None
+    cols = []
+    for j in range(flat.shape[1]):
+        own = flat[:, j]
+        if j not in images:
+            cols.append(own)  # the same in every image
+            continue
+        col = xp.concatenate([own[None, :], images[j]], axis=0)  # [P, N]
+        m = (col if alive is None else xp.where(alive, col, _BIG)).min(
+            axis=0)
+        level = col == m[None, :]
+        alive = level if alive is None else alive & level
+        cols.append(m)
+    return xp.stack(cols, axis=-1)
 
 
 class ReducePlan:
@@ -257,6 +368,7 @@ class ReducePlan:
             lie = os.environ.get("JAXTLC_DEBUG_SYM_LIE", "") == "1"
         if lie:
             self._inject_lie()
+        self.form = _array_form(self.programs, cdc.n_fields)
 
     def _build(self, pmap: Dict[str, str]) -> _PermProgram:
         prog = {
@@ -294,25 +406,16 @@ class ReducePlan:
     # -- canonicalization --------------------------------------------------
 
     def _canon(self, flat, xp):
-        F = self.cdc.n_fields
-        best = [flat[..., j] for j in range(F)]
-        for prog in self.programs:
-            cand = _apply_program(prog, flat, xp)
-            lt = xp.zeros(flat.shape[:-1], bool)
-            eq = xp.ones(flat.shape[:-1], bool)
-            for j in range(F):
-                lt = lt | (eq & (cand[j] < best[j]))
-                eq = eq & (cand[j] == best[j])
-            best = [xp.where(lt, c, b) for c, b in zip(cand, best)]
-        return xp.stack(best, axis=-1)
+        return _lexmin(flat, _images(self.form, flat, xp), xp)
 
     def canon(self, flat):
-        """Orbit-canonical form of flat [N, F] int32 on device: running
+        """Orbit-canonical form of flat [N, F] int32 on device: the
         lexicographic minimum over every group element applied to the
         ORIGINAL fields (group property - no composition needed)."""
         if not self.programs:
             return flat
-        return self._canon(flat, jnp)
+        with jax.named_scope("jaxtlc.canon"):
+            return self._canon(flat, jnp)
 
     def canon_host(self, flat: np.ndarray) -> np.ndarray:
         """Numpy twin of `canon` - seeds the initial frontier and backs
@@ -322,6 +425,11 @@ class ReducePlan:
             return arr
         return np.asarray(self._canon(arr, np), np.int32)
 
+    def images_host(self, flat: np.ndarray) -> np.ndarray:
+        """[Pm, N, F]: every non-identity program's image of flat's
+        rows, on the host (the oracle tests' orbit enumeration)."""
+        return _image_rows(self.form, np.asarray(flat, np.int32), np)
+
     # -- runtime orbit certification ---------------------------------------
 
     def orbit_check(self, flat, fvalid):
@@ -330,21 +438,18 @@ class ReducePlan:
         flag any mismatch - if the programs are a true group action
         the canonical form is orbit-invariant, so a trip means the
         plan (or the kernel under it) is lying.  Checking the whole
-        orbit of the sample (P^2 single-row program applications,
-        P <= PERM_LIMIT) rather than one element keeps the
+        orbit of the sample (P images of one row, each canonicalized
+        again, P <= PERM_LIMIT) rather than one element keeps the
         certificate sharp: a corrupted table that touches only a few
         codes still trips the first time the sample's orbit crosses
         them.  Returns a bool scalar."""
         if not self.programs:
             return jnp.zeros((), bool)
-        i = jnp.argmax(fvalid)
-        row = flat[i][None, :]  # [1, F]
-        variants = jnp.concatenate([
-            jnp.stack(_apply_program(p, row, jnp), axis=-1)
-            for p in self.programs
-        ], axis=0)  # [P, F]
-        recanon = self._canon(variants, jnp)  # [P, F]
-        ok = (recanon == row).all()
+        with jax.named_scope("jaxtlc.canon"):
+            row = flat[jnp.argmax(fvalid)][None, :]  # [1, F]
+            variants = _image_rows(self.form, row, jnp)[:, 0, :]  # [Pm, F]
+            recanon = self._canon(variants, jnp)
+            ok = (recanon == row).all()
         return fvalid.any() & ~ok
 
 

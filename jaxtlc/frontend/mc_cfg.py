@@ -1,8 +1,12 @@
 """TLC configuration (MC.cfg) parser.
 
 Parses the TLC config DSL as exercised by the reference
-(/root/reference/KubeAPI.toolbox/Model_1/MC.cfg:1-15): CONSTANT
-declarations/substitutions, SPECIFICATION, INVARIANT and PROPERTY lists.
+(/root/reference/KubeAPI.toolbox/Model_1/MC.cfg:1-15) and by the
+published models of `tlaplus/Examples`: CONSTANT declarations and
+substitutions, SPECIFICATION (or INIT / NEXT), INVARIANT and PROPERTY
+lists, `SYMMETRY <definition>` and `CHECK_DEADLOCK TRUE|FALSE`.
+CONSTRAINT, ACTION_CONSTRAINT and VIEW are recognised and refused by
+name: each changes which states a run visits, and none has a seam here.
 This file pair (MC.cfg + MC.tla) is "the plugin boundary the TPU backend
 must accept unchanged" (SURVEY.md §1 L4->L3); the reference artifacts parse
 as-is.
@@ -15,6 +19,10 @@ import re
 from typing import Dict, List, Optional
 
 
+class CfgError(ValueError):
+    """A cfg this parser reads but the checker cannot honour."""
+
+
 @dataclasses.dataclass
 class TLCConfig:
     constants: Dict[str, str]  # CONSTANT name = value
@@ -24,10 +32,14 @@ class TLCConfig:
     properties: List[str]
     init: Optional[str] = None
     next: Optional[str] = None
+    symmetry: Optional[str] = None  # SYMMETRY definition-name
+    check_deadlock: Optional[bool] = None  # CHECK_DEADLOCK, None = unsaid
 
 
+_REFUSED = ("CONSTRAINT", "ACTION_CONSTRAINT", "VIEW")
 _SECTION = re.compile(
-    r"^(CONSTANTS?|SPECIFICATION|INVARIANTS?|PROPERTY|PROPERTIES|INIT|NEXT)\b"
+    r"^(CONSTANTS?|SPECIFICATION|INVARIANTS?|PROPERTY|PROPERTIES|INIT|NEXT"
+    r"|SYMMETRY|CHECK_DEADLOCK|CONSTRAINTS?|ACTION_CONSTRAINTS?|VIEW)\b"
 )
 
 
@@ -41,6 +53,8 @@ def parse_cfg(text: str) -> TLCConfig:
         m = _SECTION.match(line)
         if m:
             section = m.group(1)
+            if section.rstrip("S") in _REFUSED:
+                raise CfgError(f"not supported: {section.rstrip('S')}")
             line = line[m.end():].strip()
             if not line:
                 continue
@@ -66,6 +80,17 @@ def parse_cfg(text: str) -> TLCConfig:
             cfg.init = line
         elif section == "NEXT":
             cfg.next = line
+        elif section == "SYMMETRY":
+            if cfg.symmetry is not None or len(line.split()) != 1:
+                raise CfgError(
+                    f"SYMMETRY names one definition, got {line!r}"
+                    + (f" after {cfg.symmetry!r}" if cfg.symmetry else ""))
+            cfg.symmetry = line
+        elif section == "CHECK_DEADLOCK":
+            if line not in ("TRUE", "FALSE"):
+                raise CfgError(
+                    f"CHECK_DEADLOCK is TRUE or FALSE, got {line!r}")
+            cfg.check_deadlock = line == "TRUE"
     return cfg
 
 

@@ -105,6 +105,19 @@ def resolve(
     if frontend not in ("auto", "hand", "gen", "struct"):
         raise ValueError(f"unknown -frontend {frontend!r}")
     cfg: TLCConfig = parse_cfg_file(cfg_path)
+    if cfg.check_deadlock is False:
+        # the cfg's `CHECK_DEADLOCK FALSE` or the caller's -nodeadlock:
+        # either switches the deadlock check off
+        check_deadlock = False
+    if cfg.symmetry:
+        # only the structural frontend reduces: a cfg that says SYMMETRY
+        # never gets an unreduced verdict from another one
+        if frontend in ("hand", "gen"):
+            raise ValueError(
+                f"the cfg declares SYMMETRY {cfg.symmetry}: only the "
+                "structural frontend reduces (re-run with -frontend "
+                "struct)")
+        frontend = "struct"
     model_dir = os.path.dirname(os.path.abspath(cfg_path))
     mc_tla_path = os.path.join(model_dir, "MC.tla")
     consts = dict(cfg.constants)

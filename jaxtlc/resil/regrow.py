@@ -194,6 +194,13 @@ def migrate_engine_carry(
             staged[f] = jnp.asarray(
                 np.asarray(getattr(carry, f)), jnp.uint32
             )
+    # state-space reduction leaves (ISSUE 18, 33): the sticky orbit
+    # flag, the canon counters and the POR count travel verbatim in
+    # their own dtypes - a reduced run regrows like any other
+    for f in ("sym_viol", "st_sym", "sym_stat", "st_sym_stat",
+              "por_pruned", "st_pruned"):
+        if getattr(carry, f, None) is not None:
+            staged[f] = jnp.asarray(np.asarray(getattr(carry, f)))
 
     return EngineCarry(
         fps=fps2,

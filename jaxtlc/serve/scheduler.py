@@ -965,6 +965,14 @@ class Scheduler:
         })
         model = sw.load_anchored(cfg_path, params,
                                  const_overrides=fixed or None)
+        if model.symmetry:
+            # the cfg declares SYMMETRY and the vmapped sweep engine
+            # does not reduce: each point through api.run_check, which
+            # does (no cfg with the line gets an unreduced verdict)
+            for j in batch:
+                self._run_supervised(j, frontend="struct")
+                self._release((j,))  # not behind the next job's run
+            return
         pre = self.pool.hits
         entry = self.pool.get_sweep(model, params, **self._geometry(head))
         hit = self.pool.hits > pre
@@ -1259,6 +1267,11 @@ class Scheduler:
                 )
         except (StructLoadError, StructParseError, JobError):
             self._run_supervised(job)
+            return
+        if model.symmetry:
+            # the cfg declares SYMMETRY: the pool's plain engines do not
+            # reduce, api.run_check does
+            self._run_supervised(job, frontend="struct")
             return
         with span("sched.cache_lookup"):
             geo = self._geometry(job)

@@ -254,15 +254,23 @@ def struct_backend(model: StructModel,
     reduce_ops = None
     if symmetry or por:
         from ..analysis.speclint import analyze_spec
-        from ..analysis.symfind import analyze_reduction
+        from ..analysis.symfind import analyze_reduction, require_declared
         from ..engine.reduce import ReduceOps, build_plan
 
-        rep = analyze_reduction(
-            model, analyze_spec(model, var_shapes=var_shapes)
-        )
-        plan, dropped = (None, {})
-        if symmetry:
-            plan, dropped = build_plan(cdc, rep.symmetric_sets)
+        # host span `build.struct.symmetry` (with the loader's one of
+        # the same name, which evaluates a cfg's SYMMETRY): the static
+        # verification of the sets and the plan's build; a set the cfg
+        # declares that either rejects is an error, never a silently
+        # unreduced run
+        with span("build.struct.symmetry") as sp:
+            rep = analyze_reduction(
+                model, analyze_spec(model, var_shapes=var_shapes)
+            )
+            plan, dropped = (None, {})
+            if symmetry:
+                plan, dropped = build_plan(cdc, rep.symmetric_sets)
+                require_declared(model, {**rep.rejected_sets, **dropped})
+            sp.attrs["perms"] = plan.n_perms if plan is not None else 1
         safe_ids: Tuple[int, ...] = ()
         if por:
             safe_ids = tuple(

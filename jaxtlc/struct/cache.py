@@ -165,6 +165,27 @@ def widen_slots(model, backend):
     return backend.n_lanes, new
 
 
+def wants_symmetry(model, symmetry=None, chunk: int = 0) -> bool:
+    """The RESOLVED symmetry mode of a check of `model`: on where the
+    model's cfg declares SYMMETRY (the second way in beside the
+    tri-state `-symmetry` flag, resolved to the same bool every memo
+    key holds), else the flag's (engine.bfs.resolve_symmetry).  A cfg
+    that says SYMMETRY and a result that ignores it would be a wrong
+    distinct count by the cfg's accounting, so `-no-symmetry` against
+    such a cfg is an error, not a way round it."""
+    from ..analysis.symfind import SymmetryError
+    from ..engine.bfs import resolve_symmetry
+
+    if getattr(model, "symmetry", ()):
+        if symmetry is False:
+            raise SymmetryError(
+                "the cfg declares SYMMETRY; -no-symmetry would report "
+                "counts the cfg does not ask for (take the line out of "
+                "the cfg instead)")
+        return True
+    return resolve_symmetry(symmetry, chunk)
+
+
 def get_backend(model, check_deadlock: bool = True, bounds=None,
                 elide: bool = True, coverage: bool = False,
                 symmetry: bool = False, por: bool = False):
@@ -236,7 +257,6 @@ def engine_key(
         resolve_deferred,
         resolve_por,
         resolve_sort_free,
-        resolve_symmetry,
     )
 
     spec = model_key(model)
@@ -246,7 +266,7 @@ def engine_key(
         bool(pipeline), int(obs_slots), _bounds_key(bounds),
         bool(coverage), resolve_sort_free(sort_free, chunk),
         resolve_deferred(deferred, chunk),
-        resolve_symmetry(symmetry, chunk), resolve_por(por, chunk),
+        wants_symmetry(model, symmetry, chunk), resolve_por(por, chunk),
         _SLOT_FLOOR.get(spec, 0),
     )
 
@@ -282,7 +302,6 @@ def get_engine(
     from ..engine.bfs import (
         make_backend_engine,
         resolve_por,
-        resolve_symmetry,
     )
 
     key = engine_key(
@@ -296,7 +315,8 @@ def get_engine(
     if hit is None:
         backend = get_backend(model, check_deadlock, bounds=bounds,
                               coverage=coverage,
-                              symmetry=resolve_symmetry(symmetry, chunk),
+                              symmetry=wants_symmetry(model, symmetry,
+                                                      chunk),
                               por=resolve_por(por, chunk))
         hit = make_backend_engine(
             backend, chunk, queue_capacity, fp_capacity, fp_index, seed,

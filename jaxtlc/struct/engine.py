@@ -75,7 +75,8 @@ def check_struct(
     `capture_fps` reads the final fingerprint table back to host on a
     clean verdict (CheckResult.fp_table - the artifact cache's
     reachable-set source, struct.artifacts)."""
-    from ..engine.bfs import resolve_por, resolve_symmetry
+    from ..engine.bfs import resolve_por
+    from .cache import wants_symmetry
 
     init_fn, run_fn, _ = get_engine(
         model, chunk, queue_capacity, fp_capacity, fp_index, seed,
@@ -86,7 +87,7 @@ def check_struct(
     )
     backend = get_backend(model, check_deadlock, bounds=bounds,
                           coverage=coverage,
-                          symmetry=resolve_symmetry(symmetry, chunk),
+                          symmetry=wants_symmetry(model, symmetry, chunk),
                           por=resolve_por(por, chunk))
     carry = init_fn()
     compiled = run_fn.lower(carry).compile()
@@ -135,12 +136,13 @@ def check_struct_sharded(
     canonicalization runs pre-fingerprint so representatives shard
     consistently (the fingerprint is a pure function of the canonical
     packed words on every device)."""
-    from ..engine.bfs import resolve_por, resolve_symmetry
+    from ..engine.bfs import resolve_por
+    from .cache import wants_symmetry
     from ..engine.sharded import check_sharded
 
     backend = get_backend(model, check_deadlock, bounds=bounds,
                           elide=False, coverage=coverage,
-                          symmetry=resolve_symmetry(symmetry, chunk),
+                          symmetry=wants_symmetry(model, symmetry, chunk),
                           por=resolve_por(por, chunk))
     return check_sharded(
         None, mesh, chunk=chunk, queue_capacity=queue_capacity,

@@ -21,6 +21,7 @@ translated here.
 
 from __future__ import annotations
 
+from itertools import permutations as _permutations
 from itertools import product as _product
 from typing import Dict, Optional
 
@@ -92,6 +93,25 @@ def canon(v):
         return tuple(canon(x) for x in v)
     if isinstance(v, frozenset):
         return frozenset(canon(x) for x in v)
+    return v
+
+
+def permute_value(v, pmap):
+    """Apply an atom permutation (`pmap`: atom -> atom, identity where
+    absent) to a value under this module's conventions: atoms are
+    strings, records/functions key-sorted tuples of (str, value) pairs,
+    sets frozensets, sequences plain tuples."""
+    if isinstance(v, str):
+        return pmap.get(v, v)
+    if isinstance(v, frozenset):
+        return frozenset(permute_value(x, pmap) for x in v)
+    if isinstance(v, tuple):
+        if v and is_fn(v):
+            return tuple(sorted(
+                (permute_value(k, pmap), permute_value(x, pmap))
+                for k, x in v
+            ))
+        return tuple(permute_value(x, pmap) for x in v)
     return v
 
 
@@ -454,6 +474,17 @@ class Evaluator:
             if not isinstance(s, tuple):
                 raise StructEvalError("Append expects a sequence")
             return s + (e,)
+        if name == "Permutations":
+            # TLC module: the set of all bijections of a finite set onto
+            # itself (what a cfg's SYMMETRY definition is built from)
+            (dom,) = vals
+            if not isinstance(dom, frozenset):
+                raise StructEvalError("Permutations expects a set")
+            base = sorted(dom, key=repr)
+            return frozenset(
+                _pairs_to_fn(list(zip(base, perm)))
+                for perm in _permutations(base)
+            )
         if name == "Assert":
             cond, msg = vals
             if cond is not True:

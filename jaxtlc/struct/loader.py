@@ -6,16 +6,19 @@ PROPERTY, MC.tla for the generated constant-override definitions, and
 the EXTENDS closure of real module files next to the config (Model_1
 carries its own KubeAPI.tla copy) - falling back to the toolbox parent
 directory for the root spec.  Standard modules (Naturals, FiniteSets,
-Sequences, TLC) are built into the evaluator.
+Sequences, TLC) are built into the evaluator.  A cfg's `SYMMETRY <def>`
+is evaluated here (`declared_symmetry`) to the constant sets whose full
+permutation groups it is the union of.
 """
 
 from __future__ import annotations
 
 import hashlib
+import math
 import os
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional, Tuple
 
-from ..frontend.mc_cfg import parse_cfg_file
+from ..frontend.mc_cfg import CfgError, parse_cfg_file
 from ..spec.labels import DEFAULT_INIT
 from .actions import ActionSystem
 from .eval import Evaluator
@@ -40,6 +43,12 @@ class StructModel(NamedTuple):
     # cache key component that changes iff the spec's meaning can
     # (struct.cache keys its memo and the checkpoint meta on it)
     source_digest: str = ""
+    # the cfg's SYMMETRY declaration, resolved: ((constant name, its
+    # sorted atoms), ...) for the sets whose full permutation groups the
+    # named definition is the union of; () where the cfg has no such
+    # line.  A model that declares one is only ever checked reduced
+    # (struct.cache.wants_symmetry); analysis.symfind verifies the sets
+    symmetry: Tuple[Tuple[str, Tuple[str, ...]], ...] = ()
 
 
 class StructLoadError(ValueError):
@@ -76,6 +85,55 @@ def _parse_const_literal(text: str):
     # TLC model value: an atom equal only to itself; the hand oracle
     # uses the same string-atom convention (spec/labels.py DEFAULT_INIT)
     return t
+
+
+def declared_symmetry(defname: str, module: Module,
+                      constants: Dict[str, object]
+                      ) -> Tuple[Tuple[str, Tuple[str, ...]], ...]:
+    """The constant sets a cfg's `SYMMETRY defname` declares symmetric:
+    the definition has to evaluate to a set of functions that is the
+    union, over some constant sets of model values, of ALL permutations
+    of each.  Anything else is an error that says what it met: the
+    orbit search takes the least image under the whole group, so a
+    proper subset of a set's permutations has no meaning here."""
+    from .eval import StructEvalError, is_fn
+
+    d = module.defs.get(defname)
+    if d is None:
+        raise StructLoadError(f"SYMMETRY {defname}: no such definition")
+    try:
+        val = Evaluator(module.defs, constants).eval(d.body, {})
+    except StructEvalError as e:
+        raise StructLoadError(f"SYMMETRY {defname}: {e}")
+    if not isinstance(val, frozenset) or not all(
+            f and is_fn(f) for f in val):
+        raise StructLoadError(
+            f"SYMMETRY {defname}: not a set of functions over model "
+            f"values (got {val!r:.80})")
+    by_dom: Dict[frozenset, list] = {}
+    for f in val:
+        by_dom.setdefault(frozenset(k for k, _ in f), []).append(f)
+    out = []
+    for dom, fns in sorted(by_dom.items(), key=lambda kv: sorted(kv[0])):
+        shown = "{" + ", ".join(sorted(dom)) + "}"
+        if any(frozenset(v for _, v in f) != dom for f in fns):
+            raise StructLoadError(
+                f"SYMMETRY {defname}: a function over {shown} is not a "
+                "permutation of it")
+        if len(fns) != math.factorial(len(dom)):
+            raise StructLoadError(
+                f"SYMMETRY {defname}: {len(fns)} of the "
+                f"{math.factorial(len(dom))} permutations of {shown}: a "
+                "proper subset of a set's permutations is not supported "
+                "(declare Permutations of the whole set)")
+        names = sorted(n for n, v in constants.items() if v == dom)
+        if not names:
+            raise StructLoadError(
+                f"SYMMETRY {defname}: {shown} is not the value of a "
+                "CONSTANT set of model values")
+        if len(dom) >= 2:  # a one-element set has only the identity
+            out.append((names[0], tuple(sorted(dom))))
+    return tuple(out)
 
 
 def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
@@ -142,7 +200,10 @@ def load(cfg_path: str,
 
 def _load(cfg_path: str,
           const_overrides: Optional[Dict[str, object]]) -> StructModel:
-    cfg = parse_cfg_file(cfg_path)
+    try:
+        cfg = parse_cfg_file(cfg_path)
+    except CfgError as e:
+        raise StructLoadError(str(e))
     model_dir = os.path.dirname(os.path.abspath(cfg_path))
     toolbox_parent = os.path.dirname(os.path.dirname(model_dir))
     search_dirs = (model_dir, toolbox_parent)
@@ -202,6 +263,14 @@ def _load(cfg_path: str,
         constants[name] = Evaluator(module.defs, constants).eval(d.body, {})
 
     ev = Evaluator(module.defs, constants)
+    symmetry = ()
+    if cfg.symmetry:
+        from ..obs.spans import span
+
+        with span("build.struct.symmetry") as sp:
+            symmetry = declared_symmetry(cfg.symmetry, module, constants)
+            sp.attrs["perms"] = math.prod(
+                math.factorial(len(atoms)) for _, atoms in symmetry)
 
     spec_name = cfg.specification or "Spec"
     spec_def = module.defs.get(spec_name)
@@ -232,4 +301,5 @@ def _load(cfg_path: str,
         fairness=fairness,
         root_name=root_name,
         source_digest=digest.hexdigest(),
+        symmetry=symmetry,
     )

@@ -92,7 +92,7 @@ def test_canon_matches_host_permutation_oracle(model):
     import jax
     import jax.numpy as jnp
 
-    from jaxtlc.engine.reduce import _apply_program
+    from perbit_canon import apply_program
 
     b = cache.get_backend(model, check_deadlock=False, symmetry=True)
     plan = b.reduce.plan
@@ -122,7 +122,7 @@ def test_canon_matches_host_permutation_oracle(model):
     def orbit(row):
         mem = {tuple(int(v) for v in row)}
         for p in plan.programs:
-            cols = _apply_program(p, row[None, :], np)
+            cols = apply_program(p, row[None, :])
             mem.add(tuple(int(c[0]) for c in cols))
         return mem
 
@@ -350,6 +350,27 @@ def test_sym_lie_trips_certificate_exit1(tmp_path, monkeypatch):
     t = out.getvalue()
     assert outcome.exit_code == 1, t
     assert "orbit-certificate violation" in t, t
+
+
+def test_reduced_run_regrows_like_any_other(ab_runs):
+    """A reduced run whose queue is too small regrows and ends in the
+    reduced counts: resil.regrow carries the reduction's carry leaves
+    (sticky orbit flag, canon counters) into the wider geometry."""
+    from jaxtlc.api import CheckRequest, run_check
+
+    out = io.StringIO()
+    outcome = run_check(CheckRequest(
+        config=SYM_CFG, workers="cpu", frontend="struct", noTool=True,
+        nodeadlock=True, chunk=128, qcap=8, fpcap=1 << 14,
+        symmetry=True, out=out, err=out,
+    ))
+    t = out.getvalue()
+    assert outcome.verdict == "ok", t
+    assert "regrowing queue_capacity" in t, t
+    r, ref = outcome.result, ab_runs[True]
+    assert (r.generated, r.distinct, r.depth) == EXPECT_REDUCED
+    assert (r.canon_rows, r.canon_moved, r.sym_cert_trips) == (
+        ref.canon_rows, ref.canon_moved, 0)
 
 
 # ---------------------------------------------------------------------------
