@@ -1,15 +1,20 @@
-"""Entry `check_with_checkpoints`: the call chip_smoke.py L2 and bench.py
-make for a scaled KubeAPI configuration -
-engine.checkpoint.check_with_checkpoints(make_scaled(...), geometry).
-Scaled constants cannot be loaded from an MC.cfg yet, so this is the
-entry a user has.  It writes no run journal: the job's facts are the
-CheckResult alone (config `"journal": false`).
+"""Entry `check_with_checkpoints`: the call chip_smoke.py L2 makes for a
+scaled KubeAPI configuration -
+engine.checkpoint.check_with_checkpoints(make_scaled(...), geometry) -
+with no run journal: the job's facts are the CheckResult alone (config
+`"journal": false`).  (Since PR 27 the hand frontend also takes scaled
+constants from an MC.cfg through api.run_check; this cell keeps the
+direct call.)
 
 Every call re-traces and re-lowers its segment program and takes the
 executable from the persistent cache; the caller's wall includes that.
 """
 
 from __future__ import annotations
+
+# set-up's warm job is one segment, not a check: its `loop` span says
+# nothing of a whole job's, and a traced run places no slice by it
+WARM_JOB_IS_WHOLE = False
 
 
 def setup(ctx):

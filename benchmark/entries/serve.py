@@ -1,6 +1,8 @@
 """Entry `serve`: an in-process jaxtlc.serve server on 127.0.0.1 with
 every default of start_server, driven over HTTP with serve.client as
-shipped (submit, then poll every 50 ms).  A job's class options (chunk,
+shipped: two requests a job, the POST and one `GET /jobs/<id>?wait=`
+that the server holds until the verdict exists (PR 32; until then the
+client polled every 50 ms).  A job's class options (chunk,
 qcap, fpcap, ...) come from the traffic mix; set-up sends two jobs of
 each class the mix has, so the engine each class runs on is warm before
 the window.

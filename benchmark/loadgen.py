@@ -89,18 +89,27 @@ def schedule(traffic: dict, seed: int, seconds: float) -> List[Draw]:
 
 
 def drive_closed(run_job: Callable[[Draw], dict], draws: List[Draw],
-                 seconds: float, clock=time.time) -> List[dict]:
-    """One caller, back to back, under the window rule."""
+                 seconds: float, clock=time.time,
+                 hooks=None) -> List[dict]:
+    """One caller, back to back, under the window rule.  `hooks`, a
+    traced run's (before(index), after(index, start, done)), are called
+    on the caller's thread outside the job's own time; what they take
+    comes off the window."""
     t0 = clock()
     records: List[dict] = []
     last = 0.0
+    before, after = hooks or (None, None)
     for d in draws:
         now = clock() - t0
         if records and (now >= seconds or seconds - now < last):
             break
+        if before:
+            before(d.index)
         start = clock()
         rec = dict(run_job(d))
         done = clock()
+        if after:
+            after(d.index, start, done)
         rec.update(index=d.index, klass=d.klass, tenant=d.tenant,
                    due_t=start, start_t=start, done_t=done)
         last = done - start
@@ -153,10 +162,10 @@ def drive_open(run_job: Callable[[Draw], dict], draws: List[Draw],
 
 
 def drive(run_job: Callable[[Draw], dict], traffic: dict, seed: int,
-          seconds: float) -> List[dict]:
+          seconds: float, hooks=None) -> List[dict]:
     draws = schedule(traffic, seed, seconds)
     if traffic["loop"] == "closed":
-        return drive_closed(run_job, draws, seconds)
+        return drive_closed(run_job, draws, seconds, hooks=hooks)
     if traffic["loop"] == "open":
         return drive_open(run_job, draws, seconds,
                           threads=int(traffic.get("client_threads", 8)),

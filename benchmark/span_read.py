@@ -19,6 +19,13 @@ median over those jobs, as the other readers take theirs.
 A recorded run_view (benchmark/tests) carries the rows under
 `run["spans"]` and the dropped count under `run["spans_dropped"]`; a
 live run has neither key and the recorder is asked.
+
+The harness itself reads the recorder through three more doors here:
+`job_shape`, a finished job's host seconds before its `loop` span and
+that loop's length (what benchmark/placement.py places a traced slice
+by), `loop_started`, the instant a running job's loop began (what a
+slice inside a loop waits for), and `rows_between`, the closed spans that overlap a traced slice
+(what trace_reduce.py names the idle gaps by).
 """
 
 from __future__ import annotations
@@ -42,6 +49,44 @@ def _recorded(run) -> Optional[tuple]:
             return None
         rows, dropped = spans.snapshot(), spans.dropped
     return [dict(zip(FIELDS, r)) for r in rows], dropped
+
+
+def rows_between(t0: float, t1: float) -> Optional[List[Dict]]:
+    """The recorder's closed spans that overlap [t0, t1] on the host
+    clock, oldest first; None without a recorder."""
+    try:
+        from jaxtlc.obs import spans
+    except ImportError:
+        return None
+    return [dict(zip(FIELDS, r)) for r in spans.snapshot(since=t0)
+            if r[2] <= t1]
+
+
+def loop_started(since: float) -> Optional[float]:
+    """When the loop of the job that began at `since` started: the start
+    of its first closed `loop.dispatch` span (the loop's first segment
+    dispatches as the loop opens; the `loop` span itself closes only at
+    the loop's end).  None until one has closed, or without a recorder."""
+    rows = rows_between(since, float("inf")) or []
+    starts = [r["t0"] for r in rows if r["name"] == "loop.dispatch"
+              and r["t0"] >= since]
+    return min(starts) if starts else None
+
+
+def job_shape(start_t: float, done_t: float, rows=None) -> Optional[tuple]:
+    """(h, loop_s) of the job the caller ran from start_t to done_t:
+    the seconds from its start to the start of its first `loop` span,
+    and the summed length of its `loop` spans.  None without a recorder
+    or where the job closed no `loop` span.  `rows`: spans already read
+    (one snapshot for many jobs), else the recorder is asked."""
+    if rows is None:
+        rows = rows_between(start_t, done_t)
+    loops = [r for r in rows or [] if r["name"] == "loop"
+             and r["t0"] >= start_t and r["t1"] <= done_t]
+    if not loops:
+        return None
+    return (min(r["t0"] for r in loops) - start_t,
+            sum(r["t1"] - r["t0"] for r in loops))
 
 
 def job_spans(run) -> Optional[List[List[Dict]]]:

@@ -97,7 +97,38 @@ def patch_fp32():
     return undo
 
 
-PATCHES = {"fp32": patch_fp32}
+def patch_generators_only():
+    """The orbit tournament over the permutations the cfg's SYMMETRY set
+    LISTS (those that move one of its constant sets and fix the others)
+    and not over the group they generate: engine.reduce.ReducePlan keeps
+    only those programs.  A state whose least image needs a product of
+    two listed permutations then keeps two representatives: more
+    "orbits" than the pin.  Returns the undo."""
+    import itertools
+
+    from jaxtlc.engine import reduce
+
+    orig = reduce.ReducePlan.__init__
+
+    def init(self, cdc, sym_sets, lie=None):
+        orig(self, cdc, sym_sets, lie)
+        bases = [tuple(sorted(a)) for a in self.sym_sets.values()]
+        combos = list(itertools.product(
+            *[list(itertools.permutations(b)) for b in bases]))[1:]
+        keep = [k for k, combo in enumerate(combos)
+                if sum(p != b for p, b in zip(combo, bases)) == 1]
+        self.programs = [self.programs[k] for k in keep]
+        self.form = reduce._array_form(self.programs, cdc.n_fields)
+
+    reduce.ReducePlan.__init__ = init
+
+    def undo():
+        reduce.ReducePlan.__init__ = orig
+
+    return undo
+
+
+PATCHES = {"fp32": patch_fp32, "generators-only": patch_generators_only}
 
 
 def load_run(root: str, tag: str = "control"):

@@ -495,6 +495,32 @@ def test_narrowed_fingerprints_underneath_the_engine_drop_states(tiny):
         next(ln for ln in text.splitlines() if "compare distinct" in ln))
 
 
+def test_generators_only_control_keeps_more_than_one_state_an_orbit():
+    """controls.json `generators-only` at a size a test can hold
+    (Ballot == 0..1): the program with the patch finds more "orbits" (460;
+    the plain reference's --generators-only, in its own order of
+    comparison, 457) than the 443 the full group leaves, and the orbit
+    certificate, which samples the kept programs, does not trip: only
+    the pins catch it."""
+    import io
+
+    sys.path.insert(0, REPO)
+    from jaxtlc.api import CheckRequest, run_check
+
+    undo = control.PATCHES["generators-only"]()
+    try:
+        o = run_check(CheckRequest(
+            config=os.path.join(REPO, "specs", "Paxos.toolbox", "Model_sym",
+                                "MC.cfg"),
+            constants={"Ballot": frozenset({0, 1})}, frontend="struct",
+            workers="cpu", noTool=True, chunk=256, qcap=4096, fpcap=16384,
+            out=io.StringIO()))
+    finally:
+        undo()
+    assert o.verdict == "ok" and o.result.sym_cert_trips == 0
+    assert o.result.distinct == 460 > 443 and o.result.depth == 17
+
+
 def test_controls_file_names_real_cells_and_edits():
     bench = load("../BENCHMARK.json")
     cells = {w["name"] for w in bench["workloads"]}

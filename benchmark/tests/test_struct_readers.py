@@ -109,8 +109,8 @@ def test_cell_configuration_and_traffic_follow_the_contract():
         "Phase1a", "Phase1b", "Phase2a", "Phase2b"}
     assert sum(config["pins"]["action_generated"].values()) == (
         config["pins"]["generated"] - 1)
-    assert traffic["loop"] == "closed" and traffic["trace"]["slice_s"] == 2.0
-    assert "PLACEHOLDER" not in traffic["trace_why"]
+    assert traffic["loop"] == "closed" and traffic["trace"]["busy_budget_s"] == 2.0
+    assert "inside" in traffic["trace_why"]
     e2e = {m["name"] for m in metrics_of(bench, "end_to_end", CELL)}
     assert e2e == {"states_per_s", "setup_s"}
     layers = {m["name"] for m in metrics_of(bench, "per_layer", CELL)}

@@ -97,9 +97,52 @@ def test_recorded_tpu_trace():
     assert [k for k, _ in r["device_ops"]] == [
         k for k, _ in want["device_ops"]]
     assert len(r["device_ops"]) <= 10 and len(r["idle_gaps"]) <= 10
-    # two checks, each inside the harness's own span
+    # two checks, each inside the harness's own span (recorded before
+    # the program had spans of its own)
     assert {k for k, _ in r["idle_gaps"]} <= {
         "bench:check_with_checkpoints", trace_reduce.NO_SPAN}
+
+
+@pytest.mark.skipif(not os.path.exists(TRACE),
+                    reason="no recorded trace in benchmark/testdata")
+def test_recorded_trace_gaps_take_the_programs_innermost_span():
+    """The program's closed spans, as the harness feeds them from the
+    recorder on the host clock: the recorded trace's two long gaps
+    (0.47-1.94 s and 2.56-4.20 s of the slice, each a check's host part)
+    are named by the innermost `jaxtlc:` span over their midpoints, and
+    a gap no program span covers keeps the harness's."""
+    t = 5000.0  # the slice's start on the host clock
+    spans = [("bench:check_with_checkpoints", t + 0.0, t + 1.96),
+             ("jaxtlc:check", t + 0.01, t + 1.95),
+             ("jaxtlc:build", t + 0.40, t + 1.93),
+             ("jaxtlc:build.lower", t + 0.90, t + 1.50),  # midpoint 1.20
+             ("jaxtlc:build", t + 2.50, t + 4.19),
+             ("jaxtlc:build.trace", t + 2.60, t + 3.00),  # midpoint 3.38:
+             ("jaxtlc:build.compile", t + 3.10, t + 4.15)]  # this one
+    r = trace_reduce.reduce_file(TRACE, host_spans=spans, slice_t0=t)
+    plain = trace_reduce.reduce_file(TRACE)
+    assert [g[1] for g in r["idle_gaps"]] == [
+        g[1] for g in plain["idle_gaps"]]  # the same gaps, renamed
+    assert r["idle_gaps"][0] == ["jaxtlc:build.compile",
+                                 pytest.approx(1.644034023)]
+    assert r["idle_gaps"][1] == ["jaxtlc:build.lower",
+                                 pytest.approx(1.470546383)]
+    # 0.17-0.36 s: inside `check`, before `build`
+    assert r["idle_gaps"][2] == ["jaxtlc:check", pytest.approx(0.190951246)]
+    # 2.17-2.32 s: the second check, which the fed spans do not cover
+    assert r["idle_gaps"][3] == ["bench:check_with_checkpoints",
+                                 pytest.approx(0.152391067)]
+    assert (r["busy_s"], r["window_s"]) == (plain["busy_s"],
+                                            plain["window_s"])
+
+
+def test_innermost_span_wins_and_the_trace_holds_both_kinds():
+    r = trace_reduce.reduce_planes(planes(
+        [(0.0, 1e9, "fusion.1"), (9e9, 10e9, "fusion.2")],
+        [(0.0, 10e9, "bench:trace_slice"), (0.5e9, 9.5e9, "bench:run_check"),
+         (0.6e9, 9.4e9, "jaxtlc:check"), (2e9, 8e9, "jaxtlc:build"),
+         (4e9, 6e9, "jaxtlc:build.lower"), (0.0, 20e9, "other:span")]))
+    assert r["idle_gaps"] == [["jaxtlc:build.lower", pytest.approx(8.0)]]
 
 
 def test_a_span_that_began_before_the_slice_still_names_the_gap():

@@ -9,12 +9,14 @@ has no such line, of all its lines but "Steps" and "XLA Modules", whose
 events span whole programs).  Busy is the union of those events'
 intervals, clipped to the traced window; operations nest (a `while`
 holds its body), so an operation's own time is its duration less its
-children's.  An idle gap is named by the harness's own span
-(`bench:*`, put around submit, wait and the call into the program) that
-covers its midpoint on the host: the spans the trace holds, and those the
-harness kept on the host clock itself (`host_spans`, mapped onto the
-trace's clock at the slice's start), because a span that began before the
-profiler did is not in the trace.
+children's.  An idle gap is named by the innermost host span that covers
+its midpoint: the program's own (`jaxtlc:*`, jaxtlc/obs/spans.py:
+`jaxtlc:build.lower`, `jaxtlc:loop.wait`, ...) inside the harness's
+(`bench:*`, put around submit, wait and the call into the program).  Both
+kinds are taken from the trace, and from what the harness and the
+program's recorder kept on the host clock itself (`host_spans`, mapped
+onto the trace's clock at the slice's start), because a span that began
+before the profiler did is not in the trace.
 
 The window is the harness's own `bench:trace_slice` annotation, which
 it opens right after the profiler starts and closes right before it
@@ -24,6 +26,8 @@ from its first to its last event.
 
 from __future__ import annotations
 
+import bisect
+import functools
 import glob
 import gzip
 import os
@@ -33,7 +37,7 @@ from typing import Dict, List, Optional, Tuple
 OPS_LINE = "XLA Ops"
 SKIP_LINES = ("Steps", "XLA Modules", "XLA TraceMe", "Framework Ops",
               "Framework Name Scope", "Source code")
-HARNESS_PREFIX = "bench:"
+HARNESS_PREFIX = ("bench:", "jaxtlc:")  # the harness's, the program's
 SLICE_SPAN = "bench:trace_slice"
 NO_SPAN = "no harness span (no job in flight)"
 
@@ -47,6 +51,7 @@ def find_xplane(trace_dir: str) -> Optional[str]:
 _OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
 
 
+@functools.lru_cache(maxsize=None)
 def short_name(name: str) -> str:
     """XLA names a device event by its whole HLO instruction; keep the
     instruction's name and its opcode ("%fusion.11 fusion")."""
@@ -84,6 +89,27 @@ def _self_times(events: List[Tuple[float, float, str]]) -> Dict[str, float]:
     return own
 
 
+def _innermost(spans: List[Tuple[float, float, str]]):
+    """(a, b) -> the name of the shortest span that covers the midpoint
+    of [a, b], NO_SPAN where none does.  The spans' edges cut the clock
+    into pieces with one answer each, found once; a look-up is a
+    bisection (a slice of served traffic holds thousands of gaps and
+    hundreds of spans)."""
+    edges = sorted({x for s in spans for x in s[:2]})
+    names = []
+    by_length = sorted(spans, key=lambda s: s[1] - s[0])
+    for lo, hi in zip(edges, edges[1:]):
+        mid = (lo + hi) / 2
+        names.append(next((s[2] for s in by_length
+                           if s[0] <= mid <= s[1]), NO_SPAN))
+
+    def host_doing(a: float, b: float) -> str:
+        i = bisect.bisect_right(edges, (a + b) / 2) - 1
+        return names[i] if 0 <= i < len(names) else NO_SPAN
+
+    return host_doing
+
+
 def reduce_planes(planes: List[dict], top: int = 10,
                   host_spans: Optional[List[Tuple[str, float, float]]] = None,
                   slice_t0: Optional[float] = None) -> dict:
@@ -110,8 +136,7 @@ def reduce_planes(planes: List[dict], top: int = 10,
         # the harness's own log, host seconds -> the trace's ns
         spans_ns += [(w0 + (a - slice_t0) * 1e9, w0 + (b - slice_t0) * 1e9,
                       name) for name, a, b in host_spans]
-    spans_ns = sorted((h for h in spans_ns if h[1] > w0 and h[0] < w1),
-                      key=lambda e: e[1] - e[0])  # innermost first
+    host_doing = _innermost([h for h in spans_ns if h[1] > w0 and h[0] < w1])
     busy_each: List[float] = []
     own: Dict[str, float] = {}
     gaps: List[Tuple[float, float]] = []
@@ -130,13 +155,6 @@ def reduce_planes(planes: List[dict], top: int = 10,
                  if edges[i + 1] > edges[i]]
     n = len(device)
     busy = sum(busy_each) / n if n else 0.0
-
-    def host_doing(a: float, b: float) -> str:
-        mid = (a + b) / 2
-        for s in spans_ns:
-            if s[0] <= mid <= s[1]:
-                return s[2]
-        return NO_SPAN
 
     by_what: Dict[str, float] = {}
     for a, b in gaps:
