@@ -843,7 +843,7 @@ def _close_journal(args, log, j, r, sup, verdict, wall_s) -> None:
             v = verdict or ("violation" if r.violation != 0 else "ok")
             from .engine.bfs import mesh_counters
 
-            j.event("spans", rows=spans.journal_rows())
+            j.event("spans", **spans.journal_event())
             j.event("final", verdict=v, generated=r.generated,
                     distinct=r.distinct, depth=r.depth,
                     queue=r.queue_left, wall_s=round(wall_s, 6),

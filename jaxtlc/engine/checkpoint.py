@@ -276,7 +276,7 @@ def check_with_checkpoints(
     jax.block_until_ready only at the next boundary - checkpoint/coverage
     readback stays off the device critical path (PERF.md round 7).
     """
-    from ..runtime import aot_build
+    from ..runtime import aot_build, engine_key
     from .bfs import resolve_deferred, resolve_sort_free
 
     sort_free = resolve_sort_free(sort_free, chunk)
@@ -306,7 +306,10 @@ def check_with_checkpoints(
 
         return init_fn, step_fn.segment(ckpt_every)
 
-    template, compiled_segment = aot_build(make)
+    # kept under the meta a resume compares, and the cadence: read
+    # only (donate=False), fed back below as the first carry
+    template, compiled_segment = aot_build(
+        make, key=engine_key("ckpt", cfg, meta, ckpt_every))
     t0 = time.time()
     if resume:
         if ckpt_path is None or not os.path.exists(ckpt_path):

@@ -1895,19 +1895,13 @@ def check_sharded_with_checkpoints(
     from .bfs import resolve_deferred, resolve_sort_free
     from .checkpoint import _meta, load_checkpoint, save_checkpoint
 
+    program = cfg if backend is None else backend
     if backend is None:
         backend = kubeapi_backend(cfg)
     sort_free = resolve_sort_free(sort_free, chunk)
     deferred = resolve_deferred(deferred, chunk)
-    from ..runtime import aot_build
+    from ..runtime import aot_build, engine_key
 
-    # the one AOT build (its `build*` spans), as the supervisor's
-    template, compiled = aot_build(lambda: make_sharded_engine(
-        cfg, mesh, chunk, queue_capacity, fp_capacity,
-        route_factor=route_factor, segment=ckpt_every, backend=backend,
-        pipeline=pipeline, obs_slots=obs_slots, sort_free=sort_free,
-        deferred=deferred,
-    ))
     # the reduction flags ride on the backend; a reduced run explores a
     # DIFFERENT (smaller) frontier, so resuming a reduced checkpoint
     # without the flags (or vice versa) must mismatch loudly
@@ -1925,6 +1919,16 @@ def check_sharded_with_checkpoints(
         symmetry=bool(red is not None and red.plan is not None),
         por=bool(red is not None and red.por and red.safe_ids),
     )
+    # the one AOT build (its `build*` spans), as the supervisor's; kept
+    # under the meta and what the meta leaves out (a backend built
+    # afresh a call, as the gen frontend's, is a new key a call)
+    template, compiled = aot_build(lambda: make_sharded_engine(
+        cfg, mesh, chunk, queue_capacity, fp_capacity,
+        route_factor=route_factor, segment=ckpt_every, backend=backend,
+        pipeline=pipeline, obs_slots=obs_slots, sort_free=sort_free,
+        deferred=deferred,
+    ), key=engine_key("sharded-ckpt", program, meta, mesh, chunk,
+                      route_factor, ckpt_every))
     t0 = time.time()
     if resume:
         if ckpt_path is None or not os.path.exists(ckpt_path):

@@ -114,7 +114,9 @@ EVENTS = {
     # [name, t0 (epoch s), dur_s, parent_index] (parent_index -1 = the
     # parent is still open - `check`, `sched.run` - or is not a row).
     # views.phase_totals folds them into the per-phase totals by name;
-    # the trace exporter renders them as host slices
+    # the trace exporter renders them as host slices.  `attrs` (extra
+    # field): {str(row index): the span's attributes}, where it has any
+    # (`build`: engine_cache hit | miss | off)
     "spans": {"rows": (list,)},
     # -- preflight analysis (jaxtlc.analysis) ------------------------------
     # one event per finding, severity in ("error", "warning", "info")

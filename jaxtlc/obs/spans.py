@@ -18,7 +18,7 @@ nothing per level, step or state.  PERF.md section 3 lists every name
 with the metric it is for.
 
 Once per check the rows closed so far go into the run journal as ONE
-`spans` event (`journal_rows`), which `obs.views.phase_totals` folds
+`spans` event (`journal_event`), which `obs.views.phase_totals` folds
 into `/metrics` and tlcstat; the recorder stays the complete source.
 """
 
@@ -174,13 +174,19 @@ def self_time(rows) -> dict:
     return {k: max(v, 0.0) for k, v in out.items()}
 
 
-def journal_rows() -> list:
-    """The `spans` journal event's `rows` for the job of this context:
-    [[name, t0, dur_s, parent_index], ...] over its spans closed by
-    now; parent_index is -1 where the parent is still open (`check`,
-    `sched.run`) or is no row of this job."""
+def journal_event() -> dict:
+    """The `spans` journal event's fields for the job of this context:
+    `rows` = [[name, t0, dur_s, parent_index], ...] over its spans
+    closed by now (parent_index is -1 where the parent is still open -
+    `check`, `sched.run` - or is no row of this job), and `attrs` =
+    {str(row index): that span's attributes} for the rows that carry
+    any (`build`'s `engine_cache`, `build.compile`'s meter deltas, a
+    journal close's cost)."""
     ctx = _job.get()
     rows = list(ctx.rows) if ctx is not None else []
     index = {r.id: i for i, r in enumerate(rows)}
-    return [[r.name, r.t0, round(r.t1 - r.t0, 6), index.get(r.parent, -1)]
-            for r in rows]
+    return dict(
+        rows=[[r.name, r.t0, round(r.t1 - r.t0, 6),
+               index.get(r.parent, -1)] for r in rows],
+        attrs={str(i): dict(r.attrs) for i, r in enumerate(rows)
+               if r.attrs})

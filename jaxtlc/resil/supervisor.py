@@ -87,7 +87,7 @@ from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
 from ..engine.spill import SpillWriteError
 from ..obs import spans
 from ..obs.spans import span
-from ..runtime import aot_build
+from ..runtime import aot_build, drop_kept_engines, engine_key
 from .faults import FaultInjector, FaultPlan, TransientFault
 from .regrow import (
     GROWABLE,
@@ -295,6 +295,9 @@ class SingleDeviceAdapter:
         self.fp_index = fp_index
         self.seed = seed
         self.fp_highwater = fp_highwater
+        # what a kept engine is named by (runtime.engine_key): the frozen
+        # config the hand kernel is built from, else the backend object
+        self.program = cfg if backend is None else backend
         if backend is None and coverage:
             # the KubeAPI path with the device coverage plane: build
             # the covered backend once so sites/meta/engine agree
@@ -350,8 +353,12 @@ class SingleDeviceAdapter:
         # async contract: seg_fn DISPATCHES and returns in-flight arrays;
         # the supervision loop overlaps host work (checkpoint write,
         # stats readback of the previous carry) with the running segment
-        # and fences with jax.block_until_ready
-        return aot_build(make)
+        # and fences with jax.block_until_ready.  The build is kept under
+        # everything that shapes it: a regrow, a chunk shrink or a resume
+        # into another geometry is another key
+        return aot_build(make, key=engine_key(
+            self.kind, self.program, self.meta(params),
+            self.check_deadlock, ckpt_every))
 
     def meta(self, params: dict) -> dict:
         return ckpt._meta(
@@ -492,6 +499,7 @@ class ShardedAdapter:
         self.chunk = chunk
         self.sort_free = resolve_sort_free(sort_free, chunk)
         self.deferred = resolve_deferred(deferred, chunk)
+        self.program = cfg if backend is None else backend
         self.backend = (backend if backend is not None
                         else kubeapi_backend(cfg, coverage=coverage))
         self.meta_config = meta_config
@@ -514,7 +522,8 @@ class ShardedAdapter:
             backend=self.backend, fp_highwater=self.fp_highwater,
             pipeline=self.pipeline, obs_slots=self.obs_slots,
             sort_free=self.sort_free, deferred=self.deferred,
-        ))
+        ), key=engine_key(self.kind, self.program, self.meta(params),
+                          self.mesh, ckpt_every))
 
     def meta(self, params: dict) -> dict:
         return ckpt._meta(
@@ -991,6 +1000,13 @@ def supervise(adapter, params: dict,
 
             if oom is not None:
                 rollback_store()
+                if drop_kept_engines(keep=seg_fn):
+                    # other checks' kept engines held device memory:
+                    # they go first and the segment runs again, before
+                    # any rung is taken (nothing left to drop next time)
+                    _emit(opts, "degrade", rung="oom", resource="segment",
+                          action="drop-kept-engines", reason=str(oom))
+                    continue
                 can = _can_shrink(adapter, opts.min_chunk)
                 _emit(opts, "degrade", rung="oom", resource="segment",
                       action="shrink" if can else "halt",
@@ -1032,6 +1048,11 @@ def supervise(adapter, params: dict,
                         denial = _probe_grow(
                             resource, new_params[resource], faults
                         )
+                        if denial is not None and drop_kept_engines(
+                                keep=seg_fn):
+                            denial = _probe_grow(
+                                resource, new_params[resource], faults
+                            )
                     if denial is None:
                         t = time.time()
                         # route_factor is an engine-geometry-only knob
@@ -1220,7 +1241,7 @@ def supervise(adapter, params: dict,
             )
     # the host spans of this check that have closed by now (build, loop
     # and their children; `check` itself is still open), once
-    _emit(opts, "spans", rows=spans.journal_rows())
+    _emit(opts, "spans", **spans.journal_event())
     _emit(opts, "final", verdict=verdict, generated=result.generated,
           distinct=result.distinct, depth=result.depth,
           queue=result.queue_left, wall_s=round(wall, 6),

@@ -5,7 +5,9 @@ persist.
 `aot_build` is the one place an engine's program is built ahead of time
 (the supervisor's adapters, `check_with_checkpoints`, the serve pool),
 under the `build` spans of obs.spans; `CompileMeter` counts what XLA did
-meanwhile.
+meanwhile.  Given a key it keeps what it built (`EngineCache`, one a
+process): a second check of the same spec and geometry goes straight to
+its loop.
 
 Each entry point (`api.run_check`, `jaxtlc.serve` start-up, the
 `jaxtlc.dist` worker, `chip_smoke.py`) calls
@@ -19,8 +21,10 @@ checkout) put it - not under $HOME.
 
 from __future__ import annotations
 
+import json
 import os
 import threading
+from collections import OrderedDict
 from typing import Optional
 
 from .obs.spans import span
@@ -166,7 +170,145 @@ class CompileMeter:
                     self.retrieval_s)
 
 
-def aot_build(make):
+# Kept engines hold device memory (a template carry is the fingerprint
+# table and the queue: ~0.2 GB for a 2^24-slot table on one chip, 1.1 GB
+# for the four-chip flagship, whole on device 0 where the mesh engine's
+# init_fn leaves it; and ~50 MB a device of loaded executable), so the
+# cap is small and fixed
+ENGINE_CACHE_CAP = 4
+
+
+class by_identity:
+    """A key part that names an object by identity (a SpecBackend: a
+    tuple of closures with no value to hash).  It holds the object, so
+    the id cannot be reused while a kept entry carries the key."""
+
+    __slots__ = ("obj",)
+
+    def __init__(self, obj):
+        self.obj = obj
+
+    def __hash__(self):
+        return id(self.obj)
+
+    def __eq__(self, other):
+        return isinstance(other, by_identity) and other.obj is self.obj
+
+
+def engine_key(kind: str, program, meta: dict, *more) -> tuple:
+    """The key a per-call builder states for `aot_build`: the route's
+    kind, the program's identity (the frozen ModelConfig the hand kernel
+    is built from, else the SpecBackend object itself, by identity), the
+    checkpoint meta its geometry resolves to (every parameter that
+    shapes the program or the carry: a resume compares the same dict),
+    and whatever else the build reads (`check_deadlock`, the segment
+    length, the Mesh).  Never derived from the `make` closure."""
+    from .config import ModelConfig
+
+    if not isinstance(program, ModelConfig):
+        program = by_identity(program)
+    return (kind, program, json.dumps(meta, sort_keys=True)) + more
+
+
+class EngineCache:
+    """Bounded, thread-safe LRU of built engines: key -> (template
+    carry, compiled executable).  A build runs outside the lock, one
+    builder a key: `claim` gives the kept pair, or None to the one
+    caller who is to build it and `settle` it (a second caller of a key
+    being built waits for the first).  A build that raises settles
+    nothing and keeps nothing."""
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self._lock = threading.Lock()
+        self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()
+        self._building: dict = {}  # key -> Event set when its build ends
+        self.hits = self.misses = self.evictions = 0
+
+    def claim(self, key) -> Optional[tuple]:
+        while True:
+            with self._lock:
+                pair = self._entries.get(key)
+                if pair is not None:
+                    self._entries.move_to_end(key)
+                    self.hits += 1
+                    return pair
+                ended = self._building.get(key)
+                if ended is None:
+                    self._building[key] = threading.Event()
+                    self.misses += 1
+                    return None
+            ended.wait()
+
+    def settle(self, key, pair: Optional[tuple]) -> None:
+        """The claimed build ended: with `pair`, or (None) by raising."""
+        with self._lock:
+            if pair is not None:
+                self._entries[key] = pair
+                while len(self._entries) > self.cap:
+                    self._entries.popitem(last=False)
+                    self.evictions += 1
+            ended = self._building.pop(key)
+        ended.set()
+
+    def drop(self, keep=None) -> int:
+        """Forget every entry but the one whose executable is `keep`;
+        the number dropped (they count as evictions)."""
+        with self._lock:
+            gone = [k for k, (_, compiled) in self._entries.items()
+                    if compiled is not keep]
+            for k in gone:
+                del self._entries[k]
+            self.evictions += len(gone)
+        return len(gone)
+
+    def clear(self) -> None:
+        """Forget every entry and zero the counters (a build in flight
+        still settles)."""
+        with self._lock:
+            self._entries.clear()
+            self.hits = self.misses = self.evictions = 0
+
+    def stats(self) -> dict:
+        with self._lock:
+            return dict(hits=self.hits, misses=self.misses,
+                        evictions=self.evictions,
+                        size=len(self._entries), cap=self.cap)
+
+
+_ENGINES = EngineCache(ENGINE_CACHE_CAP)
+
+
+def engine_cache_stats() -> dict:
+    """hits, misses, evictions, size, cap of the kept engines (`/pool`
+    republishes it beside the struct memo's)."""
+    return _ENGINES.stats()
+
+
+def drop_kept_engines(keep=None) -> int:
+    """Free the device memory the kept engines hold, all but the entry
+    whose executable is `keep` (the supervisor's first answer to a
+    device out-of-memory: other checks' engines go before its ladder
+    takes a rung).  Returns how many went."""
+    return _ENGINES.drop(keep)
+
+
+def clear_engine_cache() -> None:
+    """Forget every kept engine and zero the counters (tests)."""
+    _ENGINES.clear()
+
+
+def _debug_build() -> bool:
+    """Either variable a build reads from outside its arguments is set
+    (engine/reduce.py's lying remap table, analysis/donation.py's
+    poisoning wrapper): such a build is not kept."""
+    from .analysis.donation import debug_donation_enabled
+
+    return (os.environ.get("JAXTLC_DEBUG_SYM_LIE", "") == "1"
+            or debug_donation_enabled())
+
+
+def aot_build(make, key=None):
     """Build one engine program ahead of time: `make()` gives (init_fn,
     jitted program of one carry); returns (template carry, compiled
     executable).  The `build` span and its five children say where a
@@ -174,23 +316,53 @@ def aot_build(make):
     trace to a jaxpr, the lowering to MLIR, and `.compile()`, which on a
     warm process is the persistent cache's fetch plus the load of the
     executable onto the device (`requests`, `cache_hits`, `backend_s`,
-    `retrieval_s` on `build.compile` are CompileMeter's deltas)."""
+    `retrieval_s` on `build.compile` are CompileMeter's deltas).
+
+    With a `key` (`engine_key`) the pair is kept process-wide and a
+    second build under the same key returns it without calling `make`:
+    the caller must build with donate=False and never consume the
+    template (the four per-call builders feed it back as the first carry
+    and read it only).  `build` says which it was (`engine_cache`: hit,
+    miss, or off: no key, or a debug variable set); a hit still closes
+    `build.trace`, `build.lower` and `build.compile` (`requests` 0), each
+    the microseconds it took, so what reads them reads what this check
+    paid.  A miss builds right here, no frame deeper than an unkeyed
+    build: a trace's host seconds move with the depth it starts from
+    (PERF.md section 6, PR 24)."""
+    if key is not None and _debug_build():
+        key = None
     meter = CompileMeter.instance()
-    with span("build"):
-        with span("build.engine"):
-            init_fn, program = make()
-        with span("build.init"):
-            template = init_fn()
-        with span("build.trace"):
-            traced = program.trace(template)
-        with span("build.lower"):
-            lowered = traced.lower()
-        before = meter.read()
-        with span("build.compile") as s:
-            compiled = lowered.compile()
-            n, hits, backend_s, retrieval_s = (
-                b - a for a, b in zip(before, meter.read()))
-            s.attrs.update(requests=n, cache_hits=hits,
-                           backend_s=round(backend_s, 6),
-                           retrieval_s=round(retrieval_s, 6))
-    return template, compiled
+    with span("build") as whole:
+        kept = _ENGINES.claim(key) if key is not None else None
+        whole.attrs["engine_cache"] = (
+            "off" if key is None else "miss" if kept is None else "hit")
+        if kept is not None:
+            for name in ("build.trace", "build.lower"):
+                with span(name):
+                    pass
+            with span("build.compile", requests=0):
+                pass
+            return kept
+        built = None
+        try:
+            with span("build.engine"):
+                init_fn, program = make()
+            with span("build.init"):
+                template = init_fn()
+            with span("build.trace"):
+                traced = program.trace(template)
+            with span("build.lower"):
+                lowered = traced.lower()
+            before = meter.read()
+            with span("build.compile") as s:
+                compiled = lowered.compile()
+                n, hits, backend_s, retrieval_s = (
+                    b - a for a, b in zip(before, meter.read()))
+                s.attrs.update(requests=n, cache_hits=hits,
+                               backend_s=round(backend_s, 6),
+                               retrieval_s=round(retrieval_s, 6))
+            built = (template, compiled)
+        finally:
+            if key is not None:
+                _ENGINES.settle(key, built)
+    return built

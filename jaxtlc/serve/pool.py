@@ -83,7 +83,10 @@ class _SingleRunner:
         # (lower().compile() bypasses the jit call cache, and an EAGER
         # init_fn re-compiles its fpset while_loop per call - both
         # would make every submit of a memo-hit engine pay fresh XLA
-        # compiles; the zero-compile warm contract pins this)
+        # compiles; the zero-compile warm contract pins this).  No key:
+        # this whole-run program takes a fresh carry a job and the pool's
+        # own table already keeps the executable (folding that table
+        # under runtime's kept engines is ROADMAP C7)
         _, self._aot = aot_build(make)
 
     def run(self, capture_fps: bool = False):
@@ -356,7 +359,9 @@ class EnginePool:
     # -- introspection -----------------------------------------------------
 
     def stats(self) -> dict:
-        """Pool + memo + compile-meter counters (the /pool endpoint)."""
+        """Pool + memo + kept-engine + compile-meter counters (the
+        /pool endpoint)."""
+        from ..runtime import engine_cache_stats
         from ..struct import cache as struct_cache
 
         meter = CompileMeter.instance()
@@ -383,6 +388,7 @@ class EnginePool:
                 xla_meter="ok" if meter.available else "unavailable",
                 sweep_width=self.sweep_width,
                 memo=struct_cache.stats(),
+                engines=engine_cache_stats(),
                 entries=entries,
             )
 

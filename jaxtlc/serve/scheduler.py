@@ -1330,7 +1330,7 @@ class Scheduler:
                              journal=jr)
                 except OSError:
                     pass  # a full disk must not fail the job
-            jr.event("spans", rows=spans.journal_rows())
+            jr.event("spans", **spans.journal_event())
             jr.event("final",
                      verdict="ok" if r.violation == 0 else "violation",
                      generated=r.generated, distinct=r.distinct,

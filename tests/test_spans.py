@@ -113,7 +113,7 @@ def test_two_threads_and_the_job_identifier():
             with span("sched.run"):
                 with span("pool.run"):
                     time.sleep(0.01)
-            seen["rows"] = spans.journal_rows()
+            seen["rows"] = spans.journal_event()["rows"]
             seen["kept"] = [r.name for r in ctx.rows]
 
     th = threading.Thread(target=scheduler)
@@ -130,7 +130,7 @@ def test_two_threads_and_the_job_identifier():
     assert seen["kept"] == ["pool.run", "sched.run"]  # not the other's
     assert [(r[0], r[3]) for r in seen["rows"]] == [
         ("pool.run", 1), ("sched.run", -1)]
-    assert spans.journal_rows() == []  # no job in this context
+    assert spans.journal_event() == dict(rows=[], attrs={})  # no job here
 
 
 def test_many_threads_lose_no_row(monkeypatch):
