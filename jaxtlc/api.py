@@ -21,6 +21,7 @@ transcript as the job's output.  Exit-code conventions are unchanged
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import sys
@@ -326,7 +327,7 @@ def _run_check(args) -> int:
 
     sup = None  # SupervisedResult when the resil supervisor ran
     try:
-        with _xprof(args):
+        with _xprof(args, log):
             r, sup = _dispatch_check(args, spec, log)
     except SlotOverflowError as e:
         log.msg(1000, f"Run stopped: {e}", severity=1)
@@ -352,6 +353,7 @@ def _run_check(args) -> int:
     with span("check.verdict"):
         violated, liveness_violated = _render_verdict(args, spec, log, r,
                                                       t0)
+    _report_scopes(args, log)
     _finish_journal(
         args, log, r=r, sup=sup,
         verdict="liveness_violation" if liveness_violated else None,
@@ -467,17 +469,57 @@ def _render_verdict(args, spec, log, r, t0):
     return violated, liveness_violated
 
 
-def _xprof(args):
-    """jax.profiler trace context for `-xprof DIR` (the ground-truth
-    device timeline; the journal's -trace-out is the cheap host view).
-    A no-op context when the flag is off."""
-    import contextlib
-
+@contextlib.contextmanager
+def _xprof(args, log):
+    """`-xprof DIR`: the check under jax.profiler (the ground-truth
+    device timeline; the journal's -trace-out is the cheap host view),
+    and the trace finished when the profiler has stopped.  On a TPU the
+    engine's `jaxtlc.*` scopes are not in the trace, only in the loaded
+    executables, so (obs.scopes): the instruction -> scope tables of the
+    engines this process holds go beside the trace
+    (`DIR/jaxtlc_scopes.json`: the join survives the process), the trace
+    is reduced by scope into ONE `device_scopes` journal event, and the
+    table is kept for the verdict to print (`_report_scopes`).  The
+    table is optional reporting and the verdict is not: whatever fails
+    after the profiler has stopped (a full disk under the sidecar, a
+    trace the reader cannot parse) is a warning, and the check goes on
+    to its verdict without the table.  A no-op when the flag is off; a
+    check that raises stops the profiler and reduces nothing."""
     if not args.xprof:
-        return contextlib.nullcontext()
+        yield
+        return
     import jax
 
-    return jax.profiler.trace(args.xprof)
+    from .obs import scopes
+
+    t0 = time.time()
+    with jax.profiler.trace(args.xprof):
+        yield
+    t1 = time.time()
+    try:
+        sidecar = scopes.write_sidecar(args.xprof)
+        t2 = time.time()
+        reduced = scopes.reduce_dir(args.xprof, scopes.tables())
+        reduced.update(sidecar=sidecar, tables_s=round(t2 - t1, 6),
+                       reduce_s=round(time.time() - t2, 6))
+        j = getattr(args, "_journal", None)
+        if j is not None:
+            j.event("device_scopes", t0=t0, t1=t1, **reduced)
+    except Exception as e:  # noqa: BLE001 - must not fail the verdict
+        log.msg(1000, "Warning: no device scope table for the trace in "
+                f"{args.xprof}: {type(e).__name__}: {e}", severity=1)
+        return
+    args._device_scopes = reduced
+
+
+def _report_scopes(args, log) -> None:
+    """Under the verdict of an `-xprof` run: the device's time by
+    scope."""
+    reduced = getattr(args, "_device_scopes", None)
+    if reduced is not None:
+        from .obs.scopes import render
+
+        log.msg(1000, "\n".join(render(reduced)))
 
 
 def _dispatch_check(args, spec, log):
@@ -1947,7 +1989,7 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
     from .resil import SlotOverflowError
 
     try:
-        with _xprof(args):
+        with _xprof(args, log):
             r, sup = kit.check()
     except SlotOverflowError as e:
         log.msg(1000, f"Run stopped: {e}", severity=1)
@@ -2057,6 +2099,7 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
         except OSError as e:  # a full disk must not fail the verdict
             log.msg(1000, f"Warning: artifact cache write failed: {e}",
                     severity=1)
+    _report_scopes(args, log)
     _finish_journal(
         args, log, r=r, sup=sup,
         verdict="liveness_violation" if liveness_violated else None,

@@ -306,7 +306,11 @@ def main(argv=None) -> int:
     c.add_argument("-xprof", default="", metavar="DIR",
                    help="wrap the check in a jax.profiler trace writing "
                         "to DIR (the ground-truth device timeline; "
-                        "view with TensorBoard/XProf)")
+                        "view with TensorBoard/XProf); the run ends in "
+                        "the device's time by jaxtlc.* scope (one "
+                        "`device_scopes` journal event, the tables in "
+                        "DIR/jaxtlc_scopes.json; again later: python -m "
+                        "jaxtlc.obs.scopes DIR)")
     c.add_argument("-narrow", dest="narrow", action="store_true",
                    default=False,
                    help="struct frontend: run on the certified-bound "

@@ -636,7 +636,6 @@ def run_pod(
         ShardedSpillRuntime,
     )
     from ..obs.coverage import coverage_delta_event
-    from ..obs.phases import segment_phases
 
     fp_index = DEFAULT_FP_INDEX if fp_index is None else fp_index
     seed = DEFAULT_SEED if seed is None else seed
@@ -854,12 +853,6 @@ def run_pod(
             # obs at EVERY fence (checkpoint cadence, NOT progress
             # cadence): resume replays from the same fence the journal
             # last recorded, so the cursors give exactly-once rows
-            emit("segment", index=segments - 1, host=host,
-                 t_dispatch=t_dispatch, t_fence=t_fence,
-                 wall_s=round(t_fence - t_dispatch, 6))
-            for row in segment_phases(segments - 1,
-                                      t_fence - t_dispatch):
-                emit("phase", host=host, **row)
             if obs_slots:
                 rows, obs_since = obs_rows_sharded_local(
                     carry, labels=backend.labels, since=obs_since,
@@ -885,6 +878,11 @@ def run_pod(
                     emit("coverage", host=host, visited=cov_visited,
                          sites=len(cov_plane.sites), delta={},
                          saturated=True, level=cov_level)
+            # the last of the fence's rows, as the supervisor writes it:
+            # the trace exporter draws the levels above at this fence
+            emit("segment", index=segments - 1, host=host,
+                 t_dispatch=t_dispatch, t_fence=t_fence,
+                 wall_s=round(t_fence - t_dispatch, 6))
             if progress_every and segments % progress_every == 0:
                 st = gather(carry)
                 emit("progress", depth=int(st.depth.max()),

@@ -367,7 +367,10 @@ def check_with_checkpoints(
                 carry = jax.block_until_ready(in_flight)
             segments += 1
             pending = carry
-            with span("loop.readback"):
+            # the supervisor's vocabulary: this loop's one device read;
+            # its progress goes out in loop.overlap (owed), so it has no
+            # loop.readback.emit
+            with span("loop.readback"), span("loop.readback.get"):
                 done = carry_done(carry)
         # the last boundary has no next segment to hide behind
         if pending is not None and ckpt_path is not None:

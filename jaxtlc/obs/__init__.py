@@ -14,12 +14,13 @@ Three tiers, one source of truth:
   the tlcstat dashboard are derived views (obs.views).
 * **Timeline tier** (obs.trace): Chrome-trace/Perfetto export of the
   journal (`-trace-out`), plus the `-xprof DIR` jax.profiler hook in
-  the CLI for ground-truth device timelines.
+  the CLI for ground-truth device timelines, which ends in the device's
+  time by `jaxtlc.*` scope (obs.scopes).
 
-The live ops plane rides on top (ISSUE 8): **phase attribution**
-(obs.phases - segment-scope walls at every fence), the check's **host
-spans** (obs.spans) and the **run-monitoring server** (obs.serve -
-/metrics Prometheus text, /events SSE journal tail, /runs registry;
+The live ops plane rides on top (ISSUE 8): the **segment walls** at
+every fence (the `segment` event's `wall_s` / `readback_s`), the check's
+**host spans** (obs.spans) and the **run-monitoring server** (obs.serve
+- /metrics Prometheus text, /events SSE journal tail, /runs registry;
 `-serve PORT` or `python -m jaxtlc.obs.serve`).
 """
 
@@ -31,7 +32,6 @@ from .counters import (  # noqa: F401
     shard_rows_from_ring,
 )
 from .journal import RunJournal, read as read_journal  # noqa: F401
-from .phases import segment_phases  # noqa: F401
 from .schema import (  # noqa: F401
     SCHEMA_VERSION,
     JournalSchemaError,

@@ -41,7 +41,12 @@ EVENTS = {
                   "device": _STR, "params": (dict,)},
     # -recover appended to an existing journal (one continuous history)
     "run_resume": {"version": _STR, "path": _STR},
-    # one supervised segment fenced: host-observed dispatch/fence times
+    # one supervised segment fenced: host-observed dispatch/fence times.
+    # Written last of its fence's events (after the `progress`, `level`
+    # and `coverage` rows read back there), so the supervisor's carries
+    # `readback_s` (extra field): the host wall of that readback, device
+    # reads and journal writes, behind `t_fence`.  views.phase_totals
+    # folds `wall_s` / `readback_s` into the phases `device` / `readback`
     "segment": {"index": _NUM, "t_dispatch": _NUM, "t_fence": _NUM,
                 "wall_s": _NUM},
     # one BFS level completed (decoded from the device counter ring).
@@ -102,10 +107,9 @@ EVENTS = {
     # folds siblings into one summed site table (visited/saturation
     # recomputed from the folded totals)
     "coverage": {"visited": _NUM, "sites": _NUM, "delta": (dict,)},
-    # -- phase attribution (obs.phases) ------------------------------------
-    # one measured wall per (scope, index, phase): scope "segment" rows
-    # come free at the fences the supervisor and the pod driver already
-    # pay (phase "device"/"readback")
+    # -- phase attribution -------------------------------------------------
+    # no writer since ISSUE 37 (a segment's walls are on its `segment`
+    # event); the kind stays so that older journals still validate
     "phase": {"scope": _STR, "index": _NUM, "phase": _STR,
               "wall_s": _NUM},
     # -- host spans (obs.spans) --------------------------------------------
@@ -118,6 +122,23 @@ EVENTS = {
     # field): {str(row index): the span's attributes}, where it has any
     # (`build`: engine_cache hit | miss | off)
     "spans": {"rows": (list,)},
+    # -- device time by scope (obs.scopes, ISSUE 37) -----------------------
+    # ONE per check run under `-xprof DIR`, written when the profiler
+    # has stopped: the trace reduced by `jaxtlc.*` scope.  t0 / t1 = the
+    # profiled interval on the recorder's clock (time.time()); window_s
+    # / busy_s in device seconds (mean over n_devices); scopes = rows
+    # {scope, own_s, pct_of_busy, incl_s, events, top}, longest first,
+    # the rows "unscoped" and "unmatched" among them, their seconds
+    # also unscoped_s / unmatched_s; fallback_s = the scoped seconds
+    # placed through a fusion's agreeing instructions; chains =
+    # inclusive seconds by chain.  Extra fields: devices (a mesh),
+    # sidecar (the tables' path), tables_s / reduce_s (what the tables
+    # and the reduction cost)
+    "device_scopes": {"t0": _NUM, "t1": _NUM, "window_s": _NUM,
+                      "busy_s": _NUM, "n_devices": _NUM,
+                      "unscoped_s": _NUM, "unmatched_s": _NUM,
+                      "fallback_s": _NUM, "scopes": (list,),
+                      "chains": (dict,)},
     # -- preflight analysis (jaxtlc.analysis) ------------------------------
     # one event per finding, severity in ("error", "warning", "info")
     "analysis": {"layer": _STR, "check": _STR, "severity": _STR,

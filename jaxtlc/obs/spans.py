@@ -13,8 +13,12 @@ the same span on the device trace's clock.
 
 Always on: no flag, no environment variable, no sampling.  What keeps
 that honest is the budget - at most 16 spans per pooled job on the
-scheduler thread, at most 13 plus 4 per segment per supervised check,
-nothing per level, step or state.  PERF.md section 3 lists every name
+scheduler thread, at most 13 plus 6 per segment per supervised check
+(`loop.dispatch`, `loop.overlap`, `loop.wait`, and `loop.readback` over
+its two halves: `loop.readback.get`, the device reads and their
+decoding, and `loop.readback.emit`, the journal lines and fsyncs; 5 in
+`check_with_checkpoints`, which emits in `loop.overlap`), nothing per
+level, step or state.  PERF.md section 3 lists every name
 with the metric it is for.
 
 Once per check the rows closed so far go into the run journal as ONE

@@ -7,8 +7,11 @@ https://ui.perfetto.dev JSON file (`-trace-out run.trace.json`):
   The segment slice is all the journal knows about the device: nothing
   is drawn inside it (the per-level counters feed the counter tracks,
   at the fence they were read back at).  Ground-truth device timelines
-  come from `-xprof DIR` (jax.profiler), where the engine's stages are
-  the `jaxtlc.*` named scopes.
+  come from `-xprof DIR` (jax.profiler); the engine's stages, the
+  `jaxtlc.*` named scopes, are not in that trace on a TPU: the check
+  joins them to it (obs.scopes) and says where the device's time went
+  in one `device_scopes` journal event, with the instruction -> scope
+  tables beside the trace in `DIR/jaxtlc_scopes.json`.
 * pid "host": the check's host spans (the `spans` event, obs.spans:
   `build` with its trace / lower / compile children, `loop` with its
   per-segment dispatch / overlap / wait / readback) as nested slices on
@@ -110,22 +113,25 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                     "ts": us(ev["t"]), "pid": pid_host(h),
                     "tid": TID_CKPT, "args": args or {}})
 
-    # level events journal at the fence AFTER the segment they ran in:
-    # walk in order, buffering levels against the most recent segment -
-    # PER HOST KEY, so a merged pod stream's interleaved hosts never
-    # cross-attribute
+    # a fence's level rows journal BEFORE its `segment` event, which
+    # closes the fence: walk in order, buffering levels until their
+    # segment arrives - PER HOST KEY, so a merged pod stream's
+    # interleaved hosts never cross-attribute.  A journal from before
+    # ISSUE 37 wrote the segment FIRST, then its `phase` rows (no
+    # writer emits them since), then its levels: there the levels
+    # belong to the segment before them
     pending_levels: dict = {}  # host key -> [level rows]
     last_segment: dict = {}  # host key -> segment event
+    segment_first: dict = {}  # host key -> the last segment had phase rows
     prev_level: dict = {}  # host key -> last level event
 
-    def flush_levels(h):
+    def flush_levels(h, seg):
         """Emit host `h`'s buffered levels' counter tracks at the fence
-        of its last segment: the journal does not know where in the
+        of their segment: the journal does not know where in the
         segment a level ran, so no slice is drawn for it."""
-        seg = last_segment.get(h)
-        levels = pending_levels.pop(h, [])
-        if seg is None or not levels:
+        if seg is None:
             return
+        levels = pending_levels.pop(h, [])
         pid = pid_device(h)
         end = us(seg["t_dispatch"]) + max(seg["wall_s"] * 1e6, 1.0)
         for lv in levels:
@@ -144,7 +150,8 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
             "segment", "level", "phase", "checkpoint", "spill") else None
         if kind == "segment":
             ensure(h)
-            flush_levels(h)
+            flush_levels(h, last_segment.get(h)
+                         if segment_first.pop(h, False) else ev)
             last_segment[h] = ev
             out.append({
                 "name": f"segment {ev['index']}", "ph": "X",
@@ -154,6 +161,18 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                 "args": {"index": ev["index"],
                          "wall_s": ev["wall_s"]},
             })
+            if "readback_s" in ev:
+                # the host readback wall behind the fence
+                out.append({
+                    "name": "readback", "ph": "X",
+                    "ts": us(ev["t_fence"]),
+                    "dur": max(ev["readback_s"] * 1e6, 1.0),
+                    "pid": pid_host(h), "tid": TID_CKPT,
+                    "args": {"segment": ev["index"]},
+                })
+        elif kind == "phase":
+            if ev.get("scope") == "segment":
+                segment_first[h] = True
         elif kind == "level":
             prev = prev_level.get(h)
             if prev is not None and prev["level"] == ev["level"]:
@@ -162,16 +181,6 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                 continue
             prev_level[h] = ev
             pending_levels.setdefault(h, []).append(ev)
-        elif kind == "phase":
-            if ev["scope"] == "segment" and ev["phase"] == "readback":
-                ensure(h)
-                out.append({
-                    "name": "readback", "ph": "X",
-                    "ts": us(ev["t"] - ev["wall_s"]),
-                    "dur": max(ev["wall_s"] * 1e6, 1.0),
-                    "pid": pid_host(h), "tid": TID_CKPT,
-                    "args": {"segment": ev["index"]},
-                })
         elif kind == "spans":
             # the recorder's host spans, at the times it measured
             # (nested complete events on one thread: Perfetto stacks a
@@ -249,7 +258,7 @@ def chrome_trace_events(events: List[dict]) -> List[dict]:
                      "distinct": ev["distinct"],
                      "wall_s": ev["wall_s"]})
     for h in list(pending_levels):
-        flush_levels(h)
+        flush_levels(h, last_segment.get(h))
     return out
 
 
@@ -280,12 +289,6 @@ def _tiny_journal(path: str) -> None:
                        params={"pipeline": True, "chunk": 128})["t"]
         for s in range(2):
             td = base + 0.1 * s
-            j.event("segment", index=s, t_dispatch=td,
-                    t_fence=td + 0.09, wall_s=0.09)
-            j.event("phase", scope="segment", index=s, phase="device",
-                    wall_s=0.09)
-            j.event("phase", scope="segment", index=s, phase="readback",
-                    wall_s=0.002)
             for i in range(2):
                 lvl = 2 * s + i + 1
                 j.event("level", level=lvl, generated=100 * lvl,
@@ -293,6 +296,8 @@ def _tiny_journal(path: str) -> None:
                         expanded=50 * lvl, fp_load=0.01 * lvl)
             j.event("progress", depth=2 * s + 2, generated=200 * (s + 1),
                     distinct=120 * (s + 1), queue=30)
+            j.event("segment", index=s, t_dispatch=td,
+                    t_fence=td + 0.09, wall_s=0.09, readback_s=0.002)
         j.event("checkpoint", path="ck.g000001.npz", seconds=0.004,
                 label="periodic")
         j.event("spans", rows=[
@@ -301,6 +306,16 @@ def _tiny_journal(path: str) -> None:
             ["loop.wait", base + 0.1, 0.09, 3],
             ["loop", base, 0.2, -1],
         ])
+        j.event("device_scopes", t0=base, t1=base + 0.2, window_s=0.18,
+                busy_s=0.17, n_devices=1, unscoped_s=0.01,
+                unmatched_s=0.0, fallback_s=0.0,
+                chains={"jaxtlc.dedup": 0.16},
+                scopes=[dict(scope="jaxtlc.dedup", own_s=0.16,
+                             pct_of_busy=94.12, incl_s=0.16, events=4,
+                             top=[["fusion.1", "fusion", "u32[8]", 0.1]]),
+                        dict(scope="unscoped", own_s=0.01,
+                             pct_of_busy=5.88, incl_s=0.01, events=2,
+                             top=[["copy.2", "copy", "u32[8]", 0.01]])])
         j.event("regrow", resource="fp_capacity", old=1 << 11,
                 new=1 << 12, violation="fpset full", seconds=0.01)
         j.event("degrade", rung="regrow", resource="fp_capacity",

@@ -156,17 +156,19 @@ def eta_s(prev: Optional[dict], cur: dict) -> Optional[float]:
 
 
 def phase_totals(events) -> dict:
-    """Cumulative measured wall seconds per phase name from the `phase`
-    events (obs.phases) of a journal: {phase: seconds} (the
-    device/readback fence intervals of every segment).
+    """Cumulative measured wall seconds per phase name of a journal:
+    {phase: seconds}.  `device` and `readback` are the fence intervals
+    of every segment (the `segment` event's `wall_s` and `readback_s`).
     The check's host spans (the `spans` event, obs.spans) fold into the
     same totals under their own names (`build`, `loop.wait`, ...), so
     /metrics and tlcstat show them with no exporter of their own."""
     out = {}
     for ev in events:
-        if ev.get("event") == "phase":
-            key = ev["phase"]
-            out[key] = out.get(key, 0.0) + float(ev["wall_s"])
+        if ev.get("event") == "segment":
+            out["device"] = out.get("device", 0.0) + float(ev["wall_s"])
+            if "readback_s" in ev:
+                out["readback"] = (out.get("readback", 0.0)
+                                   + float(ev["readback_s"]))
         elif ev.get("event") == "spans":
             for name, _t0, dur_s, _parent in ev["rows"]:
                 out[name] = out.get(name, 0.0) + float(dur_s)

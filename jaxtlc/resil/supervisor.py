@@ -1137,60 +1137,63 @@ def supervise(adapter, params: dict,
             if spill_rt is not None:
                 good_store = spill_rt.store.snapshot()
             segments += 1
-            # timeline telemetry: the host-observed dispatch -> fence
-            # interval of the segment just completed (the trace
-            # exporter's device-track slices come from these)
-            _emit(opts, "segment", index=segments - 1,
-                  t_dispatch=t_dispatch, t_fence=t_fence,
-                  wall_s=round(t_fence - t_dispatch, 6))
             if opts.ckpt_path:
                 pending_save = (good, good_store)
             with span("loop.readback") as readback:
-                if adapter.viol(carry) == OK and not adapter.done(carry):
-                    d, g, di, q = adapter.progress(carry)
-                    _emit(opts, "progress", depth=d, generated=g,
-                          distinct=di, queue=q)
-                if obs_read is not None:
-                    # decode the counter ring's new per-level rows (the
-                    # same fence the progress readback already paid for)
-                    rows, obs_seen = obs_read(carry, obs_seen, params)
+                # the device reads the fence already paid for, and their
+                # decoding: progress counters, the counter ring's new
+                # per-level rows, the coverage plane's totals
+                with span("loop.readback.get"):
+                    progress = payload = None
+                    rows = ()
+                    if (adapter.viol(carry) == OK
+                            and not adapter.done(carry)):
+                        progress = adapter.progress(carry)
+                    if obs_read is not None:
+                        rows, obs_seen = obs_read(carry, obs_seen, params)
+                    if cov_sites is not None:
+                        from ..obs.coverage import coverage_delta_event
+
+                        totals = adapter.cov_totals(carry)
+                        payload = coverage_delta_event(cov_sites, totals,
+                                                       cov_seen)
+                # what they say, written: journal lines and fsyncs
+                with span("loop.readback.emit"):
+                    if progress is not None:
+                        d, g, di, q = progress
+                        _emit(opts, "progress", depth=d, generated=g,
+                              distinct=di, queue=q)
                     for row in rows:
                         _emit(opts, "level", **row)
                     if rows:
                         cov_level = max(cov_level, rows[-1]["level"])
-                if cov_sites is not None:
-                    # device coverage readback at the fence already paid:
-                    # per-site DELTAS journal as one `coverage` event, and
-                    # a run that stops visiting NEW sites for N levels
-                    # journals the saturation signal once
-                    from ..obs.coverage import coverage_delta_event
-
-                    totals = adapter.cov_totals(carry)
-                    payload = coverage_delta_event(cov_sites, totals,
-                                                   cov_seen)
-                    if payload is not None:
-                        _emit(opts, "coverage", **payload)
-                        cov_seen = totals
-                        if payload["visited"] > cov_visited:
-                            cov_visited = payload["visited"]
-                            cov_last_new_level = cov_level
-                    if (not cov_saturated and cov_visited
-                            and cov_level - cov_last_new_level
-                            >= opts.coverage_sat_levels):
-                        cov_saturated = True
-                        _emit(opts, "coverage", visited=cov_visited,
-                              sites=len(cov_sites), delta={},
-                              saturated=True, level=cov_level)
-            # phase attribution (obs.phases): the fence-scope rows
-            # (device wall + the host readback wall just measured) -
-            # pure host arithmetic over syncs already paid
-            from ..obs.phases import segment_phases
-
-            for row in segment_phases(
-                segments - 1, t_fence - t_dispatch,
-                readback_s=readback.seconds,
-            ):
-                _emit(opts, "phase", **row)
+                    if cov_sites is not None:
+                        # per-site DELTAS journal as one `coverage`
+                        # event, and a run that stops visiting NEW sites
+                        # for N levels journals the saturation signal
+                        # once
+                        if payload is not None:
+                            _emit(opts, "coverage", **payload)
+                            cov_seen = totals
+                            if payload["visited"] > cov_visited:
+                                cov_visited = payload["visited"]
+                                cov_last_new_level = cov_level
+                        if (not cov_saturated and cov_visited
+                                and cov_level - cov_last_new_level
+                                >= opts.coverage_sat_levels):
+                            cov_saturated = True
+                            _emit(opts, "coverage", visited=cov_visited,
+                                  sites=len(cov_sites), delta={},
+                                  saturated=True, level=cov_level)
+                    # timeline telemetry, last of the fence's events: the
+                    # host-observed dispatch -> fence interval of the
+                    # segment just completed and the readback wall behind
+                    # it (the trace exporter's slices, /metrics' and
+                    # tlcstat's phase walls come from these)
+                    _emit(opts, "segment", index=segments - 1,
+                          t_dispatch=t_dispatch, t_fence=t_fence,
+                          wall_s=round(t_fence - t_dispatch, 6),
+                          readback_s=round(readback.seconds, 6))
 
         # the final segment's snapshot has no next segment to hide
         # behind: write it at the fence

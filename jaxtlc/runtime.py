@@ -27,6 +27,7 @@ import threading
 from collections import OrderedDict
 from typing import Optional
 
+from .obs import scopes
 from .obs.spans import span
 
 # the in-checkout default, derived from the package location: the cache
@@ -328,7 +329,10 @@ def aot_build(make, key=None):
     the microseconds it took, so what reads them reads what this check
     paid.  A miss builds right here, no frame deeper than an unkeyed
     build: a trace's host seconds move with the depth it starts from
-    (PERF.md section 6, PR 24)."""
+    (PERF.md section 6, PR 24).  Every executable built here, keyed or
+    not, is registered with obs.scopes (a weak reference, no parse): its
+    instruction -> `jaxtlc.*` scope table is derived the first time a
+    profile asks for it."""
     if key is not None and _debug_build():
         key = None
     meter = CompileMeter.instance()
@@ -361,6 +365,7 @@ def aot_build(make, key=None):
                 s.attrs.update(requests=n, cache_hits=hits,
                                backend_s=round(backend_s, 6),
                                retrieval_s=round(retrieval_s, 6))
+            scopes.register(compiled)  # its scope table, if ever asked
             built = (template, compiled)
         finally:
             if key is not None:

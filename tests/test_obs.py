@@ -28,7 +28,7 @@ from jaxtlc.obs.schema import (
     JournalSchemaError,
     validate_event,
 )
-from jaxtlc.obs.trace import export_chrome_trace
+from jaxtlc.obs.trace import chrome_trace_events, export_chrome_trace
 from jaxtlc.resil import FaultPlan, SupervisorOptions, check_supervised
 
 FF = ModelConfig(False, False)
@@ -120,14 +120,16 @@ def test_journal_schema_golden(obs_run):
     kinds = [e["event"] for e in events]
     assert kinds[0] == "run_start"
     assert kinds.count("final") == 1 and kinds[-1] == "final"
-    # the fence-mode phase tier: device + readback walls per segment,
-    # free at the syncs the supervisor already pays
-    seg_phases = [e for e in events if e["event"] == "phase"]
-    assert seg_phases and all(e["scope"] == "segment"
-                              for e in seg_phases)
-    assert {e["phase"] for e in seg_phases} == {"device", "readback"}
-    n_segments = kinds.count("segment")
-    assert len(seg_phases) == 2 * n_segments
+    # one interval, one record (ISSUE 37): the device and readback
+    # walls of a fence are on its `segment` event, the last of the
+    # fence's rows; no `phase` row repeats them
+    assert "phase" not in kinds
+    segs = [e for e in events if e["event"] == "segment"]
+    assert len(segs) == sr.segments
+    assert all(e["wall_s"] > 0 and e["readback_s"] > 0 for e in segs)
+    last = len(kinds) - 1 - kinds[::-1].index("segment")
+    assert not {"level", "progress", "coverage"} & set(kinds[last:])
+    assert kinds.index("level") < kinds.index("segment")
     fin = events[-1]
     assert fin["verdict"] == "ok" and not fin["interrupted"]
     assert (fin["generated"], fin["distinct"], fin["depth"]) == EXPECT_FF
@@ -141,7 +143,7 @@ def test_supervised_check_leaves_every_span(obs_run):
     and their ONE durable copy in the journal: a `spans` event right
     before `final`, equal to the recorder's rows closed by then, for
     exactly one fsync."""
-    from test_spans import BUILD, SEGMENT, assert_tree
+    from test_spans import BUILD, SUPERVISED, assert_tree
 
     sr, path, _ = obs_run
     rows = [r for r in RECORDED["rows"] if r.job is not None]
@@ -149,9 +151,15 @@ def test_supervised_check_leaves_every_span(obs_run):
     names = [r.name for r in rows]
     for n in ("build", "loop", "check.result", *BUILD):
         assert names.count(n) == 1, n
-    for n in SEGMENT:
+    for n in SUPERVISED:
         assert names.count(n) == sr.segments, n
-    assert len(rows) <= 13 + 4 * sr.segments  # the budget
+    assert len(rows) <= 13 + 6 * sr.segments  # the budget
+    # ISSUE 37: the readback's two halves lie inside it, and every
+    # `level` row was written inside an `emit`
+    by_id = {r.id: r for r in rows}
+    halves = [r for r in rows if r.name in ("loop.readback.get",
+                                            "loop.readback.emit")]
+    assert all(by_id[r.parent].name == "loop.readback" for r in halves)
     events = jr.read(path)  # validates the new kind too
     kinds = [e["event"] for e in events]
     assert kinds.count("spans") == 1
@@ -180,7 +188,20 @@ def test_supervised_check_leaves_every_span(obs_run):
     assert totals["loop.wait"] == pytest.approx(
         sum(r.t1 - r.t0 for r in rows if r.name == "loop.wait"),
         abs=1e-4)
-    assert {"device", "readback"} <= set(totals)  # phase rows still fold
+    # the segment events' walls fold as the `phase` rows did
+    segs = [e for e in events if e["event"] == "segment"]
+    assert totals["device"] == pytest.approx(
+        sum(e["wall_s"] for e in segs))
+    assert totals["readback"] == pytest.approx(
+        sum(e["readback_s"] for e in segs))
+    # ... and are the `loop.readback` spans less each `segment` event's
+    # own write, which closes its `emit` (one fsync: no fixed time)
+    emits = [r for r in rows if r.name == "loop.readback.emit"]
+    own_write = totals["loop.readback"] - totals["readback"]
+    assert -1e-5 * len(segs) <= own_write < sum(r.t1 - r.t0
+                                                 for r in emits)
+    assert all(any(r.t0 <= e["t"] <= r.t1 + 1e-3 for r in emits)
+               for e in events if e["event"] in ("level", "segment"))
     assert "build.compile" in metrics_from_events(events)[
         "phase_wall_seconds"]
 
@@ -301,6 +322,33 @@ def test_trace_export_from_golden_journal(obs_run, tmp_path):
     assert "states" in names  # counter track (ph: C)
     phases = {e.get("ph") for e in doc["traceEvents"]}
     assert {"X", "C", "M"} <= phases
+
+
+@pytest.mark.parametrize("segment_first", [False, True],
+                         ids=["levels-then-segment", "pre-37-journal"])
+def test_trace_export_places_levels_at_their_own_fence(segment_first):
+    """A fence's level rows journal before its `segment` event; a
+    journal from before ISSUE 37 wrote the segment first, then its
+    `phase` rows, then the levels.  Either way each level's counters
+    are drawn at its OWN segment's fence, the last fence's included."""
+    events = [dict(v=1, t=100.0, event="run_start")]
+    for s in range(2):
+        td = 100.0 + s
+        seg = [dict(v=1, t=td + 0.9, event="segment", index=s,
+                    t_dispatch=td, t_fence=td + 0.9, wall_s=0.9)]
+        lvl = [dict(v=1, t=td + 0.9, event="level", level=s + 1,
+                    generated=10, distinct=10 * (s + 1), queue=1,
+                    outdegree_sum=1, expanded=1)]
+        if segment_first:
+            seg.append(dict(v=1, t=td + 0.9, event="phase",
+                            scope="segment", index=s, phase="readback",
+                            wall_s=0.001))
+        else:
+            seg[0]["readback_s"] = 0.001
+        events += seg + lvl if segment_first else lvl + seg
+    got = {e["args"]["distinct"]: e["ts"]
+           for e in chrome_trace_events(events) if e["name"] == "states"}
+    assert got == {10: 0.9e6, 20: 1.9e6}
 
 
 def test_progress_lost_still_emits_final(tmp_path):

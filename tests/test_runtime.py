@@ -163,6 +163,11 @@ def kept(monkeypatch):
     runtime.clear_engine_cache()
 
 
+class _Compiled(str):
+    """A stand-in executable: equal to its name, and (unlike a plain
+    str) weakly referenceable, as obs.scopes registers what is built."""
+
+
 class _FakeProgram:
     """What `aot_build` asks of a jitted program, compiling nothing."""
 
@@ -176,7 +181,7 @@ class _FakeProgram:
         return self
 
     def compile(self):
-        return "compiled-%d" % self.n
+        return _Compiled("compiled-%d" % self.n)
 
 
 @pytest.fixture()

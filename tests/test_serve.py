@@ -176,9 +176,9 @@ def test_spans_event_reaches_metrics_and_tlcstat(tmp_path):
     with jr.RunJournal(path) as j:
         j.event("run_start", version="t", workload="FF", engine="single",
                 device="cpu", params={})
-        j.event("phase", scope="segment", index=0, phase="device",
-                wall_s=0.25)
         t = time.time()
+        j.event("segment", index=0, t_dispatch=t - 0.75, t_fence=t - 0.5,
+                wall_s=0.25, readback_s=0.125)
         j.event("spans", rows=[["build.trace", t - 3.0, 1.5, 2],
                                ["build.lower", t - 1.5, 0.5, 2],
                                ["build", t - 3.0, 2.25, -1],
@@ -188,7 +188,8 @@ def test_spans_event_reaches_metrics_and_tlcstat(tmp_path):
         j.event("final", verdict="ok", generated=1, distinct=1, depth=1,
                 queue=0, wall_s=0.75, interrupted=False)
     totals = phase_totals(jr.read(path))
-    assert totals == {"device": 0.25, "build.trace": 1.5,
+    assert totals == {"device": 0.25, "readback": 0.125,
+                      "build.trace": 1.5,
                       "build.lower": 0.5, "build": 2.25,
                       "loop.wait": 0.25, "loop": 0.75}
     srv = obs_serve.start_server(str(tmp_path))
@@ -198,7 +199,8 @@ def test_spans_event_reaches_metrics_and_tlcstat(tmp_path):
         srv.shutdown()
     for needle in ('jaxtlc_phase_wall_seconds{phase="build.trace"} 1.5',
                    'jaxtlc_phase_wall_seconds{phase="loop.wait"} 0.25',
-                   'jaxtlc_phase_wall_seconds{phase="device"} 0.25'):
+                   'jaxtlc_phase_wall_seconds{phase="device"} 0.25',
+                   'jaxtlc_phase_wall_seconds{phase="readback"} 0.125'):
         assert needle in metrics, (needle, metrics)
     spec = importlib.util.spec_from_file_location(
         "tlcstat", os.path.join(os.path.dirname(__file__), "..",

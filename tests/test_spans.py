@@ -26,7 +26,11 @@ SCOPES = ("jaxtlc.expand", "jaxtlc.pack_fp", "jaxtlc.dedup",
           "jaxtlc.fpset", "jaxtlc.enqueue", "jaxtlc.level")
 BUILD = ("build.engine", "build.init", "build.trace", "build.lower",
          "build.compile")
-SEGMENT = ("loop.dispatch", "loop.overlap", "loop.wait", "loop.readback")
+# a segment's spans (ISSUE 37: `loop.readback` over its two halves);
+# `check_with_checkpoints` emits in `loop.overlap` and has no `.emit`
+SEGMENT = ("loop.dispatch", "loop.overlap", "loop.wait", "loop.readback",
+           "loop.readback.get")
+SUPERVISED = SEGMENT + ("loop.readback.emit",)
 
 
 def assert_tree(rows, root_name, min_cover=0.95):
@@ -267,12 +271,16 @@ def test_check_with_checkpoints_leaves_every_span(ckpt_calls):
         assert names.count(n) == 1, n
     for n in SEGMENT:
         assert names.count(n) == r.iterations == 3, n
-    assert len(rows) <= 13 + 4 * r.iterations  # the budget
+    assert len(rows) <= 13 + 5 * r.iterations  # the budget
+    assert "loop.readback.emit" not in names
     by_name = {x.name: x for x in rows}
+    by_id = {x.id: x for x in rows}
     for n in BUILD:
         assert by_name[n].parent == by_name["build"].id
     for x in rows:
-        if x.name in SEGMENT:
+        if x.name == "loop.readback.get":
+            assert by_id[x.parent].name == "loop.readback"
+        elif x.name in SEGMENT:
             assert x.parent == by_name["loop"].id
     assert by_name["build"].parent == by_name["loop"].parent == root.id
     c = by_name["build.compile"].attrs

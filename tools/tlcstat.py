@@ -237,8 +237,8 @@ def render(events) -> str:
                 if k in acts
             ) + (f"  |  queue {depth}" if depth is not None else "")
         )
-    # phase attribution (obs.phases): cumulative measured walls per
-    # phase - device/readback at every fence, the host spans by name
+    # cumulative measured walls per phase - device/readback at every
+    # fence (the `segment` events), the host spans by name
     phases = phase_totals(events)
     if phases:
         lines.append("phase walls: " + "  ".join(
@@ -256,6 +256,13 @@ def render(events) -> str:
             + (f"  |  SATURATED at level {sat} (no new site since)"
                if sat is not None else "")
         )
+    # an -xprof run's device time by jaxtlc.* scope (obs.scopes)
+    scoped = next((e for e in reversed(events)
+                   if e["event"] == "device_scopes"), None)
+    if scoped is not None:
+        from jaxtlc.obs.scopes import render as render_scopes
+
+        lines.extend(render_scopes(scoped))
     last = events[-1]
     age = time.time() - last["t"]
     lines.append(f"last event: {last['event']} ({age:.1f}s ago)")
@@ -328,6 +335,7 @@ def main(argv=None) -> int:
             frame = render(jr.read(path))
         assert "VERDICT: interrupted" in frame and "ds/min" in frame
         assert "phase walls:" in frame and "readback" in frame
+        assert "Device time by scope:" in frame and "jaxtlc.dedup" in frame
         print(frame)
         print("tlcstat tiny OK")
         return 0
