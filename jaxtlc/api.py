@@ -54,7 +54,8 @@ class CheckRequest:
     chunk: int = 1024
     pipeline: bool = False
     # tri-state -sort-free/-no-sort-free: None = auto (the engines
-    # resolve it against the chunk, engine.bfs.resolve_sort_free)
+    # resolve it, engine.bfs.resolve_sort_free: the sorted dedup at
+    # every chunk, or a resumed checkpoint's recorded mode)
     sortfree: Optional[bool] = None
     # tri-state -deferred-inv/-no-deferred-inv (ISSUE 15): None = auto
     # (resolved against the chunk, engine.bfs.resolve_deferred) -
@@ -922,11 +923,13 @@ def _resume_command(args) -> str:
     if args.pipeline:
         parts += ["-pipeline"]  # checkpoints only resume in the same mode
     if getattr(args, "sortfree", None) is not None:
-        # auto re-resolves identically from the chunk; only an explicit
-        # override must travel so the meta mode check stays satisfied
+        # auto continues in the checkpoint's recorded mode; only an
+        # explicit override must travel so the meta mode check stays
+        # satisfied
         parts += ["-sort-free" if args.sortfree else "-no-sort-free"]
     if getattr(args, "deferredinv", None) is not None:
-        # same contract as -sort-free: auto re-resolves from the chunk
+        # auto re-resolves identically from the chunk; only an explicit
+        # override must travel
         parts += ["-deferred-inv" if args.deferredinv
                   else "-no-deferred-inv"]
     if getattr(args, "symmetry", None) is not None:

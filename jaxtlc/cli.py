@@ -103,15 +103,21 @@ def main(argv=None) -> int:
                         "Results are bit-for-bit the sorted path's - "
                         "full signature AND fpset table words "
                         "(tests/test_sortfree.py::test_ff_bit_for_bit "
-                        "pins it).  Default auto: on at -chunk >= "
-                        "2048, off below.  Each side runs in one "
-                        "benchmark cell (sorted at chunk 1024, sort-"
-                        "free at 16384); the two were never compared "
-                        "on a chip (ROADMAP A3).  A checkpoint records "
-                        "the resolved mode: -recover must match")
+                        "pins it).  Default auto: OFF at every "
+                        "-chunk - measured on a TPU v5e in every batch "
+                        "cell of the benchmark (PERF.md section 5, PR "
+                        "38): the two sorts cost a tenth of the slab's "
+                        "element gathers and scatters at 65,536 to "
+                        "196,608 candidate lanes, a check 1.7-1.9x "
+                        "shorter.  A checkpoint records the resolved "
+                        "mode: -recover on auto continues in it, an "
+                        "explicit flag must match")
     c.add_argument("-no-sort-free", dest="sortfree", action="store_const",
                    const=False,
-                   help="force the sorted dedup commit at any chunk")
+                   help="force the sorted dedup commit (what auto "
+                        "resolves to; against a checkpoint cut by the "
+                        "slab it is the mismatch, where auto follows "
+                        "the checkpoint)")
     c.add_argument("-deferred-inv", dest="deferredinv",
                    action="store_const", const=True, default=None,
                    help="distinct-first expand (ISSUE 15): evaluate "

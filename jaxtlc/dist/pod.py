@@ -651,8 +651,6 @@ def run_pod(
         backend = kubeapi_backend(cfg, coverage=coverage)
     if cfg is None and meta_config is None:
         meta_config = {"backend": "custom"}
-    sort_free = resolve_sort_free(sort_free, chunk)
-    deferred = resolve_deferred(deferred, chunk)
     spill_on = spill == "on"
     if spill_on and reshard:
         raise ValueError(
@@ -660,6 +658,23 @@ def run_pod(
             "keyed to the width that cut them - resume at the original "
             "width (ROADMAP #1 residue)"
         )
+    # a resume reads its snapshot's meta FIRST: an auto caller continues
+    # in the dedup ordering it records (bfs.resolve_sort_free), and a
+    # wrong-width or wrong-mode snapshot is refused below, before the
+    # engine pays its AOT compile
+    resume_meta = resume_full = None
+    if resume:
+        if ckpt_path is None:
+            raise ValueError("resume requires a checkpoint base path")
+        my_path = host_checkpoint_path(ckpt_path, host)
+        if reshard:
+            resume_full = load_pod_full(ckpt_path)
+        else:
+            resume_meta = read_checkpoint_meta(my_path)
+    sort_free = resolve_sort_free(
+        sort_free, chunk,
+        resume_full[0] if resume_full is not None else resume_meta)
+    deferred = resolve_deferred(deferred, chunk)
     red = getattr(backend, "reduce", None)
     meta = _meta(
         cfg if cfg is not None else ModelConfig(),
@@ -690,24 +705,16 @@ def run_pod(
         if on_event is not None:
             on_event(kind, dict(fields))
 
-    # resume validation FIRST: a wrong-width or wrong-mode snapshot
-    # must refuse before the engine pays its AOT compile, not after
-    resume_meta = resume_full = None
-    if resume:
-        if ckpt_path is None:
-            raise ValueError("resume requires a checkpoint base path")
-        my_path = host_checkpoint_path(ckpt_path, host)
-        if reshard:
-            resume_full = load_pod_full(ckpt_path)
-            _validate_pod_meta(resume_full[0], meta, reshard=True)
-            if resume_full[0].get("spill"):
-                raise ValueError(
-                    "reshard of a spill-mode pod checkpoint is "
-                    "unsupported - resume at the original width"
-                )
-        else:
-            resume_meta = read_checkpoint_meta(my_path)
-            _validate_pod_meta(resume_meta, meta, reshard=False)
+    # resume validation before the build
+    if resume_full is not None:
+        _validate_pod_meta(resume_full[0], meta, reshard=True)
+        if resume_full[0].get("spill"):
+            raise ValueError(
+                "reshard of a spill-mode pod checkpoint is "
+                "unsupported - resume at the original width"
+            )
+    elif resume_meta is not None:
+        _validate_pod_meta(resume_meta, meta, reshard=False)
 
     # engine: the fused AOT segment loop, or the spill runtime's
     # expand/probe/commit protocol when the per-host lifeboat is on

@@ -330,31 +330,38 @@ def carry_done(carry: EngineCarry) -> bool:
 
 DEFAULT_FP_HIGHWATER = 0.85
 
-# -sort-free auto threshold.  Each side of it runs in one benchmark
-# cell - sorted at chunk 1024 in `kubeapi-model1.recheck`, sort-free at
-# 16,384 in the wide and four-chip cells; the two were never compared
-# on a chip (ROADMAP A3).
-SORT_FREE_AUTO_CHUNK = 2048
 
-
-def resolve_sort_free(sort_free, chunk: int) -> bool:
+def resolve_sort_free(sort_free, chunk: int, saved: dict = None) -> bool:
     """Resolve the tri-state -sort-free flag (None = auto) for an
-    engine popping `chunk` states per step.  Deterministic in the
-    geometry alone, so every layer that needs the resolved mode -
-    engine factories, struct engine memos, checkpoint meta, the resume
-    path - computes the same answer without coordination."""
+    engine popping `chunk` states per step.  Auto is the SORTED
+    ordering at every chunk: on the chip a sort over all chunk * L
+    candidate lanes costs a fraction of ONE element gather over them
+    (PERF.md section 5, PR 38: the measured pair in every batch cell),
+    and the hash slab spends five such gathers and scatters to avoid
+    two sorts.  `chunk` stays in the signature: every layer that needs
+    the resolved mode - engine factories, struct engine memos,
+    checkpoint meta - calls this with its geometry and computes the
+    same answer without coordination.
+
+    `saved` is the meta of the checkpoint a resume loads: the carry is
+    the same in both modes (the slab is a per-commit temporary) and
+    both are exact, so an auto caller continues in the mode the
+    checkpoint records (a snapshot cut at chunk >= 2048 before PR 38
+    says `sort_free: true`); an explicit flag is itself, and one that
+    contradicts the checkpoint is the caller's loud mismatch."""
     if sort_free is not None:
         return bool(sort_free)
-    return chunk >= SORT_FREE_AUTO_CHUNK
+    if saved is not None:
+        return bool(saved.get("sort_free", False))
+    return False
 
 
 # -deferred-inv auto threshold (ISSUE 15): deferring the invariant
 # sweep from the chunk*L candidate lanes to the ~2*chunk fresh-insert
-# claimants is the distinct-first collapse.  Deliberately the same
-# threshold as the sort-free auto rule, and as little compared: each
-# side runs in one benchmark cell - immediate at chunk 1024 in
-# `kubeapi-model1.recheck`, deferred at 16,384 in the wide and four-chip
-# cells; the two were never compared on a chip (ROADMAP A3).
+# claimants is the distinct-first collapse.  Each side runs in one
+# benchmark cell - immediate at chunk 1024 in `kubeapi-model1.recheck`,
+# deferred at 16,384 in the wide and four-chip cells; the two were
+# never compared on a chip (ROADMAP A3).
 DEFERRED_AUTO_CHUNK = 2048
 
 
@@ -447,14 +454,16 @@ def make_stage_pair(
     and the host spill driver (engine.spill) interleaves a host-tier
     membership check between them.
 
-    sort_free=True (a RESOLVED bool here; factories resolve the
-    tri-state flag via resolve_sort_free) commits through the hash-slab
-    dedup (fpset.fpset_insert_slab) instead of the two full-width
-    stable sorts - bit-identical results by contract, so every engine
-    composed from this seam (fused, pipelined, spill, narrowed,
-    covered) inherits the mode with no per-engine code.  The slab is an
-    ephemeral per-commit tensor derived from this pair's geometry, so
-    regrow/chunk-shrink rebuilds migrate it by construction.
+    sort_free (a RESOLVED bool here; factories resolve the tri-state
+    flag via resolve_sort_free, whose auto is False at every chunk)
+    picks the in-batch dedup's ordering: False orders the candidates by
+    two stable sorts at candidate width (fpset.fpset_insert_sorted),
+    True through the hash slab (fpset.fpset_insert_slab) -
+    bit-identical results by contract, so every engine composed from
+    this seam (fused, pipelined, spill, narrowed, covered) inherits the
+    mode with no per-engine code.  The slab is an ephemeral per-commit
+    tensor derived from this pair's geometry, so regrow/chunk-shrink
+    rebuilds migrate it by construction.
 
     deferred=True (a RESOLVED bool; factories resolve the tri-state
     flag via resolve_deferred) moves invariant + certificate
@@ -882,14 +891,14 @@ def make_backend_engine(
     bit-for-bit those of an obs-off run (tests/test_obs.py::
     test_obs_bit_identical_and_ring pins it).
 
-    sort_free (tri-state: None = auto, resolve_sort_free) selects the
-    hash-slab commit dedup in place of the two full-width stable sorts
-    (ISSUE 12).  Results are BIT-FOR-BIT the sorted path's - full
-    signature plus fpset TABLE words (tests/test_sortfree.py::
-    test_ff_bit_for_bit pins it) -
-    the flag is purely a performance mode, but it is still recorded in
-    engine memos and checkpoint meta so a resume can never silently
-    cross modes.
+    sort_free (tri-state: None = auto = the two stable sorts at
+    candidate width, resolve_sort_free) selects the hash-slab commit
+    dedup in their place (ISSUE 12).  Results are BIT-FOR-BIT the
+    sorted path's - full signature plus fpset TABLE words
+    (tests/test_sortfree.py::test_ff_bit_for_bit pins it) - the flag
+    is purely a performance mode, but it is still recorded in engine
+    memos and checkpoint meta: an explicit flag never silently crosses
+    modes on a resume, an auto caller continues in the checkpoint's.
 
     deferred (tri-state: None = auto, resolve_deferred) moves
     invariant + certificate evaluation to the commit stage, over the

@@ -127,6 +127,18 @@ def read_checkpoint_meta(path: str) -> dict:
         raise CheckpointCorruptError(f"unreadable checkpoint {path!r}: {e}")
 
 
+def resume_meta(ckpt_path: Optional[str], resume: bool) -> Optional[dict]:
+    """The meta of the checkpoint a `resume` is about to load, None for
+    a fresh run; read BEFORE the engine is built, because an auto
+    caller resumes in the dedup ordering the checkpoint records
+    (bfs.resolve_sort_free)."""
+    if not resume:
+        return None
+    if ckpt_path is None or not os.path.exists(ckpt_path):
+        raise FileNotFoundError(f"no checkpoint at {ckpt_path!r}")
+    return read_checkpoint_meta(ckpt_path)
+
+
 def load_checkpoint(path: str, template: EngineCarry):
     """Load + verify a snapshot into the structure of `template` (an
     EngineCarry from the same engine geometry).  Returns (meta, carry).
@@ -279,7 +291,8 @@ def check_with_checkpoints(
     from ..runtime import aot_build, engine_key
     from .bfs import resolve_deferred, resolve_sort_free
 
-    sort_free = resolve_sort_free(sort_free, chunk)
+    sort_free = resolve_sort_free(sort_free, chunk,
+                                  resume_meta(ckpt_path, resume))
     deferred = resolve_deferred(deferred, chunk)
     meta = _meta(
         cfg,
@@ -312,8 +325,6 @@ def check_with_checkpoints(
         make, key=engine_key("ckpt", cfg, meta, ckpt_every))
     t0 = time.time()
     if resume:
-        if ckpt_path is None or not os.path.exists(ckpt_path):
-            raise FileNotFoundError(f"no checkpoint at {ckpt_path!r}")
         saved_meta, carry = load_checkpoint(ckpt_path, template)
         # every parameter that shapes the carry or the fingerprint function
         # must match - including chunk, which sizes the queue padding and

@@ -561,11 +561,18 @@ def _probe_segments(table, c_lo, c_hi, active, n_rows, R: int, C: int):
 
 
 def _sorted_order(lo, hi):
-    """The sorted dedup over already MIXED, remapped, mask-zeroed
-    fingerprint words: (c_lo, c_hi, c_idx int32, nreps), the distinct
-    representatives compacted fp-ascending into the first nreps rows
-    (the ordering half of fpset_insert_sorted, lifted so the sort-free
-    slab path can fall back to the exact same computation)."""
+    """The in-batch dedup's ordering, and every engine's default at
+    every chunk (bfs.resolve_sort_free): two stable sorts over all n
+    candidate lanes of already MIXED, remapped, mask-zeroed fingerprint
+    words give (c_lo, c_hi, c_idx int32, nreps), the distinct
+    representatives compacted fp-ascending into the first nreps rows.
+    On the chip a sort at candidate width is cheap and an element
+    gather or scatter there is not (PERF.md section 5, PR 38, one
+    TPU v5e: at 196,608 lanes the two sorts cost 0.20 and 0.24 ms a
+    step, ONE element gather 0.92 ms, and the slab below spent five
+    such instructions, 4.4 ms of a 9.25 ms step, to avoid the sorts).
+    It is also the slab path's fallback, so both paths meet in the
+    exact same computation."""
     n = lo.shape[0]
     # sort 1: group duplicates.  Invalid lanes are encoded as the RESERVED
     # (0,0) word pair - _remap guarantees no real fingerprint is (0,0) -
@@ -624,11 +631,16 @@ def fpset_insert_sorted(
 
 
 # ---------------------------------------------------------------------------
-# sort-free commit path (ISSUE 12): hash-slab in-batch dedup + the
+# sort-free commit path (ISSUE 12; explicit `sort_free=True` /
+# `-sort-free` only since ISSUE 38): hash-slab in-batch dedup + the
 # bucketized rank-claim probe over a compacted claimant slice, replacing
 # the two full-width stable dedup sorts above with scatter/gather
-# primitives per the BLEST frontier-membership formulation (on the chip
-# since PR 26: PERF.md section 5, `jaxtlc.dedup`).  Exactness is the contract:
+# primitives per the BLEST frontier-membership formulation.  It was the
+# auto rule at chunk >= 2048 from PR 12 to PR 37, shaped by a
+# microprofile of XLA-CPU; the chip prices the trade the other way
+# (PERF.md section 5, PR 38: `jaxtlc.dedup` 52 % of the wide cell's
+# device time through the slab, 9 % through the sorts), so nothing takes
+# this path unasked and ROADMAP C2 deletes it.  Exactness is the contract:
 # identical is_new verdicts, identical compacted-prefix order, identical
 # TABLE words - where the slab cannot guarantee that cheaply (residue /
 # width overflow) it falls back to the sorted path wholesale.
@@ -645,14 +657,16 @@ def _slab_dedup_core(lo, hi, mask, R: int, slab_factor: int,
     yet - see _order_and_dedup).  lo/hi are RAW words; mixing happens
     here.
 
-    Every operation is chosen for scatter economy (XLA-CPU scatters
-    cost ~50 ns per index-array element; the r15 microprofile drove
-    this shape): one scatter-max per pass, ONE element scatter for the
-    compaction (the lane index only - fingerprint words are re-read by
-    R-wide gathers), and the collision residue is NOT dedup'd here at
-    all - unresolved lanes ride into the claimant slice verbatim and
-    the R-wide ordering sort the path already pays groups their
-    duplicates for the last-of-group rep rule (_order_and_dedup).
+    The shape: one scatter-max per pass, three element gathers of the
+    winner's lane and words, ONE element scatter for the compaction
+    (the lane index only - fingerprint words are re-read by R-wide
+    gathers), and the collision residue is NOT dedup'd here at all -
+    unresolved lanes ride into the claimant slice verbatim and the
+    R-wide ordering sort the path already pays groups their duplicates
+    for the last-of-group rep rule (_order_and_dedup).  Each of the
+    five indexes all n lanes, live or dead, at ~4.7 ns a lane on a
+    TPU v5e (PERF.md section 5, PR 38: 1.06, 3 x 0.92 and 0.60 ms at
+    196,608 lanes) - ten times the two sorts they stand in for.
 
     Returns (mixed lo, mixed hi, c_lane [R] int32, n_cand, fallback):
     the claimant lanes (slab winners + unresolved residue lanes)
@@ -750,9 +764,10 @@ def slab_dedup(lo, hi, mask, probe_width: int = 0, slab_factor: int = 4,
     ride into the probe-width claimant compaction, where the ordering
     sort groups their duplicates adjacently and last-of-group picks
     the exact rep - the residue dedup is absorbed by a sort the path
-    pays anyway, which is what keeps the whole dedup at ONE scatter-max
-    plus ONE element scatter plus ONE R-wide sort (the r15
-    microprofile: XLA-CPU scatters at full batch width are the cost).
+    pays anyway, which is what keeps the whole dedup at ONE scatter-max,
+    three element gathers, ONE element scatter and ONE R-wide sort (on
+    the chip the n-wide gathers and scatters are the cost, not the
+    sorts: _slab_dedup_core).
 
     The ordered claimants preserve the bucketized rank-claim invariant
     (same-bucket claimants take occupancy + rank-in-run slots in
@@ -839,11 +854,12 @@ def fpset_insert_dedup(
     s: FPSet, lo, hi, mask, probe_width: int = 0, claim_width: int = 0,
     sort_free: bool = False,
 ) -> Tuple[FPSet, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """The engine seam's insert: the sorted dedup path or the sort-free
-    hash-slab path, one flag (bfs.make_stage_pair threads the resolved
-    -sort-free mode here, so every stage composition - fused,
-    pipelined, spill - and the sharded owner-side insert share
-    one dispatch point).  Contract identical either way."""
+    """The engine seam's insert: the sorted dedup path (auto, at every
+    chunk) or the explicit sort-free hash-slab path, one flag
+    (bfs.make_stage_pair threads the resolved -sort-free mode here, so
+    every stage composition - fused, pipelined, spill - and the sharded
+    owner-side insert share one dispatch point).  Contract identical
+    either way."""
     # device scope of the in-batch dedup (sorts or slab); the probe /
     # claim inside it is `jaxtlc.fpset` (_probe_block), so a trace
     # attributes an op to the innermost of the two

@@ -1890,15 +1890,19 @@ def check_sharded_with_checkpoints(
     analog under distribution: one snapshot covers every shard's partition
     of the fingerprint space + frontier).  Same contract as
     checkpoint.check_with_checkpoints, over the mesh engine."""
-    import os
-
     from .bfs import resolve_deferred, resolve_sort_free
-    from .checkpoint import _meta, load_checkpoint, save_checkpoint
+    from .checkpoint import (
+        _meta,
+        load_checkpoint,
+        resume_meta,
+        save_checkpoint,
+    )
 
     program = cfg if backend is None else backend
     if backend is None:
         backend = kubeapi_backend(cfg)
-    sort_free = resolve_sort_free(sort_free, chunk)
+    sort_free = resolve_sort_free(sort_free, chunk,
+                                  resume_meta(ckpt_path, resume))
     deferred = resolve_deferred(deferred, chunk)
     from ..runtime import aot_build, engine_key
 
@@ -1931,8 +1935,6 @@ def check_sharded_with_checkpoints(
                       route_factor, ckpt_every))
     t0 = time.time()
     if resume:
-        if ckpt_path is None or not os.path.exists(ckpt_path):
-            raise FileNotFoundError(f"no checkpoint at {ckpt_path!r}")
         saved_meta, carry = load_checkpoint(ckpt_path, template)
         for key in ("format", "config", "queue_capacity", "fp_capacity",
                     "devices", "pipeline", "obs_slots", "sort_free",

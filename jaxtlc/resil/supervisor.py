@@ -289,7 +289,10 @@ class SingleDeviceAdapter:
         self.chunk = chunk
         # resolved once, against the INITIAL chunk: a later ladder
         # chunk-shrink keeps the mode (the slab is rebuilt from the new
-        # stage-pair geometry; meta stays consistent across the resume)
+        # stage-pair geometry; meta stays consistent across the resume).
+        # An auto caller's resume takes the checkpoint's recorded mode
+        # (_params_from_meta)
+        self.sort_free_auto = sort_free is None
         self.sort_free = resolve_sort_free(sort_free, chunk)
         self.deferred = resolve_deferred(deferred, chunk)
         self.fp_index = fp_index
@@ -497,6 +500,7 @@ class ShardedAdapter:
         self.cfg = cfg
         self.mesh = mesh
         self.chunk = chunk
+        self.sort_free_auto = sort_free is None
         self.sort_free = resolve_sort_free(sort_free, chunk)
         self.deferred = resolve_deferred(deferred, chunk)
         self.program = cfg if backend is None else backend
@@ -625,7 +629,14 @@ def _params_from_meta(adapter, meta: dict, params: dict) -> dict:
     """Resume geometry resolution: fixed keys (config, codec-shaping
     parameters) must match what this process would write; growable
     geometry keys are TAKEN FROM THE CHECKPOINT (auto-grown capacities
-    travel with the snapshot, so the resume command needs none of them)."""
+    travel with the snapshot, so the resume command needs none of them).
+    The dedup ordering of an auto caller is taken from the checkpoint
+    as well (bfs.resolve_sort_free: one carry, both modes exact); an
+    explicit flag that contradicts it stays the mismatch below."""
+    from ..engine.bfs import resolve_sort_free
+
+    if getattr(adapter, "sort_free_auto", False):
+        adapter.sort_free = resolve_sort_free(None, adapter.chunk, meta)
     want = adapter.meta(params)
     for key in adapter.FIXED_KEYS:
         # pre-pipeline/pre-obs/pre-coverage/pre-sort-free/pre-

@@ -33,6 +33,7 @@ from jaxtlc.engine.sharded import (
 )
 from jaxtlc.frontend.model import resolve
 from jaxtlc.runtime import fp_mesh
+from jaxpr_walk import scoped_eqns
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MC_CFG = os.path.join(REPO, "specs", "KubeAPI.toolbox", "Model_1", "MC.cfg")
@@ -243,8 +244,8 @@ PARENT_DIGEST = (
 # over all D x B received lanes); sorted and wide paths alike
 PARENT_DIGEST_LESS_TABLE = (
     "6e6ca3fbac083d5103dc07096f006006d08356f272c91a0a7364b3bbc6256fd9")
-# the paths a 16384-wide chunk takes on the chip (sort-free slab,
-# owner-side deferred invariants), forced at chunk 128
+# the explicit slab (a 16384-wide chunk's auto until ISSUE 38) and the
+# owner-side deferred invariants (its auto still), forced at chunk 128
 WIDE = dict(sort_free=True, deferred=True)
 
 
@@ -588,16 +589,6 @@ def test_masked_hist_is_the_scatter_add_less_its_dump_bin(n_bins, n, live):
     assert got.dtype == np.uint32
     assert got.tolist() == want[:n_bins].tolist()
     assert int(got.sum()) == live
-
-
-def scoped_eqns(jaxpr, stack=""):
-    """(name stack, equation) of every equation of a traced program,
-    the bodies of its loops, maps and calls included."""
-    for eqn in jaxpr.eqns:
-        here = f"{stack}/{eqn.source_info.name_stack}"
-        yield here, eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from scoped_eqns(sub, here)
 
 
 def test_source_side_indexes_no_element_at_candidate_width():

@@ -19,7 +19,6 @@ import pytest
 from jaxtlc.config import MODEL_1, ModelConfig
 from jaxtlc.engine import checkpoint as ck
 from jaxtlc.engine.bfs import (
-    SORT_FREE_AUTO_CHUNK,
     make_engine,
     resolve_sort_free,
     result_from_carry,
@@ -38,6 +37,15 @@ def signature(r):
             tuple(sorted(r.action_generated.items())),
             tuple(sorted(r.action_distinct.items())),
             r.outdegree)
+
+
+def _same_leaves(a, b) -> bool:
+    import jax
+
+    la = jax.tree_util.tree_leaves(a)
+    lb = jax.tree_util.tree_leaves(b)
+    return len(la) == len(lb) and all(
+        (np.asarray(x) == np.asarray(y)).all() for x, y in zip(la, lb))
 
 
 @pytest.fixture(scope="module")
@@ -160,13 +168,24 @@ def test_slab_overflow_takes_sorted_fallback_exact():
 
 
 def test_auto_resolution_and_memo_key():
-    assert resolve_sort_free(None, SORT_FREE_AUTO_CHUNK) is True
-    assert resolve_sort_free(None, SORT_FREE_AUTO_CHUNK // 2) is False
+    """Auto is the sorted ordering at every chunk (ISSUE 38): at chunk
+    64 and at the wide cell's 16,384 an auto caller shares the explicit
+    `False` caller's entry in every key the resolved mode is material
+    of, and `True` differs in every one."""
+    for chunk in (64, 2048, 16384, 1 << 20):
+        assert resolve_sort_free(None, chunk) is False
     assert resolve_sort_free(True, 64) is True
     assert resolve_sort_free(False, 1 << 20) is False
+    # a resume: auto takes the checkpoint's recorded mode, a snapshot
+    # from before the flag existed reads as sorted, an explicit flag is
+    # itself (and the caller's mismatch where it contradicts)
+    assert resolve_sort_free(None, 16384, {"sort_free": True}) is True
+    assert resolve_sort_free(None, 16384, {}) is False
+    assert resolve_sort_free(False, 16384, {"sort_free": True}) is False
+    assert resolve_sort_free(True, 64, {"sort_free": False}) is True
 
-    # struct engine memo identity: the resolved flag is key material,
-    # and an auto caller shares the explicit caller's entry
+    from jaxtlc.resil.supervisor import SingleDeviceAdapter
+    from jaxtlc.runtime import engine_key as kept_key
     from jaxtlc.struct.cache import engine_key
     from jaxtlc.struct.loader import load
 
@@ -174,13 +193,23 @@ def test_auto_resolution_and_memo_key():
         os.path.dirname(__file__), os.pardir, "specs",
         "TwoPhase.toolbox", "Model_1", "MC.cfg",
     ))
-    base = dict(chunk=64, queue_capacity=1 << 10, fp_capacity=1 << 12,
-                fp_index=0, seed=0, fp_highwater=0.85)
-    k_auto = engine_key(model, **base, sort_free=None)
-    k_off = engine_key(model, **base, sort_free=False)
-    k_on = engine_key(model, **base, sort_free=True)
-    assert k_auto == k_off  # chunk 64 < auto threshold
-    assert k_on != k_off
+    for chunk in (64, 16384):
+        geo = dict(queue_capacity=1 << 10, fp_capacity=1 << 12)
+        base = dict(chunk=chunk, fp_index=0, seed=0, fp_highwater=0.85,
+                    **geo)
+        # struct engine memo
+        k_auto, k_off, k_on = (
+            engine_key(model, **base, sort_free=sf)
+            for sf in (None, False, True))
+        assert k_auto == k_off and k_on != k_off
+        # checkpoint meta, and the kept engines' key built from it
+        metas = [SingleDeviceAdapter(FF, chunk=chunk, sort_free=sf
+                                     ).meta(geo)
+                 for sf in (None, False, True)]
+        assert metas[0] == metas[1] and metas[0]["sort_free"] is False
+        assert metas[2]["sort_free"] is True
+        keys = [kept_key("single", FF, m, True, 8) for m in metas]
+        assert keys[0] == keys[1] and keys[2] != keys[1]
 
 
 # ---------------------------------------------------------------------------
@@ -211,15 +240,8 @@ def test_segment_that_ends_with_the_check_exact(ab_runs, sort_free):
         calls += 1
     assert calls >= 2  # whole segments, then one that ends early
     ref = ab_runs[sort_free][0]
-
-    def same(a, b):
-        la = jax.tree_util.tree_leaves(a)
-        lb = jax.tree_util.tree_leaves(b)
-        return len(la) == len(lb) and all(
-            (np.asarray(x) == np.asarray(y)).all() for x, y in zip(la, lb))
-
-    assert same(carry, ref)
-    assert same(segment(carry), ref)  # nothing left to do: unchanged
+    assert _same_leaves(carry, ref)
+    assert _same_leaves(segment(carry), ref)  # nothing left: unchanged
 
 
 def test_segment_program_crosses_no_conditional_with_its_buffers():
@@ -252,6 +274,43 @@ def test_segment_program_crosses_no_conditional_with_its_buffers():
         assert re.fullmatch(r"tensor<\d+x1xi32>", idx), idx
         assert re.fullmatch(r"tensor<\d+x16xui32>", upd), upd
     assert f"tensor<{table}>" in text and f"tensor<{queue}>" in text
+
+
+def test_auto_dedup_indexes_no_element_at_candidate_width():
+    """What ISSUE 38 took out of the step, pinned on the traced segment
+    program at chunk 2,048 on auto: under `jaxtlc.dedup` nothing
+    gathers or scatters with chunk * L indices (the slab's scatter-max,
+    its three element gathers and the claimant scatter did); what
+    indexes there is the probe's, under `jaxtlc.fpset`, at probe width.
+    The explicit slab still holds its five."""
+    import jax
+    from jaxpr_walk import scoped_eqns
+
+    from jaxtlc.engine.backend import kubeapi_backend
+
+    chunk = 2048
+    ncand = chunk * kubeapi_backend(FF).n_lanes
+    wide = {}
+    for sf in (None, True):
+        init_fn, _, step_fn = make_engine(
+            FF, chunk=chunk, queue_capacity=1 << 13, fp_capacity=1 << 16,
+            donate=False, sort_free=sf,
+        )
+        traced = jax.make_jaxpr(step_fn.segment(8))(
+            jax.eval_shape(init_fn))
+        # (primitive, index rows, name stack) of what indexes in the dedup
+        ops = [(eqn.primitive.name, eqn.invars[1].aval.shape[0], stack)
+               for stack, eqn in scoped_eqns(traced.jaxpr)
+               if "jaxtlc.dedup" in stack
+               and eqn.primitive.name.startswith(("gather", "scatter"))]
+        assert ops  # the scope reaches the program
+        wide[sf] = sorted(op for op, rows, _ in ops if rows == ncand)
+        if sf is None:
+            for op, rows, stack in ops:
+                assert "jaxtlc.fpset" in stack, (op, rows, stack)
+                assert rows <= 2 * chunk, (op, rows)
+    assert wide[None] == []
+    assert wide[True] == ["gather"] * 3 + ["scatter", "scatter-max"]
 
 
 # the two-tier nest on stub bodies (the tier threshold is chunk / 2 =
@@ -403,14 +462,8 @@ def test_sigterm_recover_mode_continuity(tmp_path, ab_runs):
             opts=SupervisorOptions(ckpt_path=p, resume=True),
             **KW,
         )
-    # auto at chunk 128 resolves to sorted - also a loud mismatch, not
-    # a silent mode flip
-    with pytest.raises(ValueError, match="sort_free mismatch"):
-        check_supervised(
-            FF,
-            opts=SupervisorOptions(ckpt_path=p, resume=True),
-            **KW,
-        )
+    # (a caller on auto takes the checkpoint's mode since ISSUE 38:
+    # test_recorded_mode_resumes_under_auto)
 
     # same mode resumes to the exact clean-run statistics
     sr2 = check_supervised(
@@ -420,6 +473,110 @@ def test_sigterm_recover_mode_continuity(tmp_path, ab_runs):
     )
     assert not sr2.interrupted
     assert signature(sr2.result) == signature(ab_runs[False][1])
+
+
+def _live(c):
+    """A one-chip carry with its queue cut to the rows in use: what is
+    left of the level being popped, and what the next level holds so
+    far, in order (the enqueue writes whole segments, so rows past
+    `next_n` hold whatever the ordering left behind its new states)."""
+    q = np.asarray(c.queue)
+    par, qh = int(c.parity), int(c.qhead)
+    return c._replace(queue=(q[par, qh:int(c.level_n)],
+                             q[1 - par, :int(c.next_n)]))
+
+
+@pytest.mark.parametrize("route", ["one-chip", "mesh-2dev"])
+def test_auto_at_wide_chunk_is_the_slab_run_bit_for_bit(route):
+    """Auto at chunk 2,048 (the sorted ordering since ISSUE 38, the
+    slab before it) against the explicit slab at the same geometry, at
+    every boundary of 16-step segments to the end of the check:
+    counters, the queue's rows in their order, the fingerprint table's
+    words - one-chip and through the owner-side insert of a 2-device
+    mesh."""
+    from jaxtlc.engine.bfs import carry_done
+
+    kw = dict(chunk=2048, queue_capacity=1 << 13, fp_capacity=1 << 16)
+    carry, seg = {}, {}
+    for sf in (None, True):
+        if route == "one-chip":
+            init_fn, _, step_fn = make_engine(FF, **kw, donate=False,
+                                              sort_free=sf)
+            seg[sf] = step_fn.segment(16)
+        else:
+            from jaxtlc.engine.sharded import make_sharded_engine
+            from jaxtlc.runtime import fp_mesh
+
+            init_fn, seg[sf] = make_sharded_engine(
+                FF, fp_mesh(2), **kw, segment=16, sort_free=sf)
+        carry[sf] = init_fn()
+    if route == "one-chip":
+        done, view = carry_done, _live
+    else:
+        def done(c):
+            return not bool(np.asarray(c.cont).any())
+
+        def view(c):  # less the queue's dump row, written pop or not
+            return c._replace(queue=c.queue[:, :kw["queue_capacity"]])
+    boundaries = 0
+    while not done(carry[None]):
+        for sf in (None, True):
+            carry[sf] = seg[sf](carry[sf])
+        assert _same_leaves(view(carry[None]), view(carry[True]))
+        boundaries += 1
+    assert boundaries >= 4 and done(carry[True])
+    assert int(np.asarray(carry[None].distinct).sum()) == EXPECT_FF[1]
+
+
+@pytest.mark.parametrize("entry", ["ckpt", "supervised", "sharded-ckpt"])
+def test_recorded_mode_resumes_under_auto(tmp_path, ab_runs, entry):
+    """A checkpoint whose meta says `sort_free: true` - what every run
+    at chunk >= 2,048 wrote before ISSUE 38 - resumes under a caller
+    that leaves the flag on auto, in the recorded mode, and ends exact;
+    an explicit `sort_free=False` against it stays the loud mismatch."""
+    p = str(tmp_path / "ck.npz")
+    if entry == "supervised":
+        def run(resume, sort_free, cut):
+            return check_supervised(
+                FF, sort_free=sort_free, **KW,
+                opts=SupervisorOptions(
+                    ckpt_path=p, ckpt_every=16, resume=resume,
+                    faults=FaultPlan.parse("sigterm@2") if cut else None,
+                )).result
+    elif entry == "ckpt":
+        def run(resume, sort_free, cut):
+            return ck.check_with_checkpoints(
+                FF, **KW, ckpt_path=p, ckpt_every=16, resume=resume,
+                max_segments=2 if cut else None, sort_free=sort_free)
+    else:
+        import jax
+        from jax.sharding import Mesh
+
+        from jaxtlc.engine.sharded import check_sharded_with_checkpoints
+
+        mesh = Mesh(np.array(jax.devices()[:2]), ("fp",))
+
+        def run(resume, sort_free, cut):
+            return check_sharded_with_checkpoints(
+                FF, mesh, **KW, ckpt_path=p, ckpt_every=16, resume=resume,
+                max_segments=2 if cut else None, sort_free=sort_free)
+
+    def recorded():  # the newest snapshot's mode (the supervisor
+        # writes generations beside the plain path)
+        path = p if os.path.exists(p) else ck.list_generations(p)[-1][1]
+        return ck.read_checkpoint_meta(path)["sort_free"]
+
+    run(False, True, cut=True)
+    assert recorded() is True
+    with pytest.raises(ValueError, match="sort_free mismatch"):
+        run(True, False, cut=False)
+    r = run(True, None, cut=False)
+    assert (r.generated, r.distinct, r.depth) == EXPECT_FF
+    assert r.violation == 0 and r.queue_left == 0
+    if entry != "sharded-ckpt":  # in-batch attribution follows the mesh
+        assert signature(r) == signature(ab_runs[False][1])
+    # and the run went on in the checkpoint's mode, not the rule's
+    assert recorded() is True
 
 
 def test_twophase_struct_bit_for_bit():

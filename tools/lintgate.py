@@ -33,9 +33,10 @@ REQUIRED_FACTORIES = (
 
 
 def check_factories() -> int:
-    """Engine-free registry pin: every REQUIRED factory (the sort-free
-    commit engine, ISSUE 12, and the deferred-evaluation engine,
-    ISSUE 15, included) must be registered for the
+    """Engine-free registry pin: every REQUIRED factory (the explicit
+    sort-free commit engine, ISSUE 12 - no engine's default since
+    ISSUE 38, its deletion is ROADMAP C2 - and the deferred-evaluation
+    engine, ISSUE 15, included) must be registered for the
     `python -m jaxtlc.analysis --self-check` audit - a commit that
     drops one fails here before any engine builds."""
     from jaxtlc.analysis.selfcheck import FACTORIES
