@@ -72,6 +72,9 @@ class AnalysisReport:
     # empty on reports that did not run the abstract interpretation, so
     # pre-existing golden reports render byte-identically
     bound_lines: List[str] = dataclasses.field(default_factory=list)
+    # the cfg's CONSTRAINT, leaf by leaf (speclint.constraint_report);
+    # empty for a model without one
+    constraint_lines: List[str] = dataclasses.field(default_factory=list)
     wall_s: float = 0.0
 
     def extend(self, findings) -> None:

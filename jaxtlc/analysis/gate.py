@@ -37,21 +37,31 @@ def gate_one(cfg_path: str) -> Tuple[str, Optional[AnalysisReport], str]:
     from ..struct.parser import StructParseError
     from ..struct.shapes import ShapeError
     from .absint import analyze_bounds
-    from .speclint import analyze_spec
+    from .speclint import analyze_spec, constraint_report, inferred_shapes
 
     label = os.path.relpath(cfg_path)
     try:
         model = load(cfg_path)
-        spec = analyze_spec(model)
-        bounds = analyze_bounds(model)
+        shapes = inferred_shapes(model)
+        spec = analyze_spec(model, var_shapes=shapes)
+        # a model bounded by its cfg's CONSTRAINT is not narrowed (the
+        # certified bounds know nothing of the constraint): its section
+        # is the constraint's own, leaf by leaf
+        bounds = None if model.constraints else analyze_bounds(model)
+        con_lines, con_findings = (
+            constraint_report(model, var_shapes=shapes)
+            if model.constraints else ([], []))
     except (StructLoadError, StructParseError, ShapeError,
             RecursionError, ValueError, OSError) as e:
         return label, None, f"{type(e).__name__}: {e}"
     rep = AnalysisReport(name=f"struct:{model.root_name}",
                          spec=spec,
                          findings=list(spec.findings))
-    rep.bound_lines = bounds.render_lines()
-    rep.extend(bounds.findings())
+    if bounds is not None:
+        rep.bound_lines = bounds.render_lines()
+        rep.extend(bounds.findings())
+    rep.constraint_lines = con_lines
+    rep.extend(con_findings)
     return label, rep, ""
 
 

@@ -33,15 +33,26 @@ def preflight_struct(model, *, fp_capacity: int, chunk: int,
     run already reduces by symmetry, which silences the unreduced-
     symmetry nudge.  `const_hints` / `extra_init_systems` widen the
     analysis over a sweep constants CLASS (jaxtlc.analysis --sweep)."""
-    from .speclint import analyze_spec
+    from .speclint import analyze_spec, inferred_shapes
 
     t0 = time.time()
     report = AnalysisReport(name=f"struct:{model.root_name}")
     dynamic = frozenset(const_hints or ())
-    spec = analyze_spec(model, dynamic_consts=dynamic,
+    # inferred once: the lints and the constraint report both read them
+    shapes = inferred_shapes(model, const_hints)
+    spec = analyze_spec(model, var_shapes=shapes, dynamic_consts=dynamic,
                         const_hints=const_hints)
     report.spec = spec
     report.extend(spec.findings)
+    if model.constraints:
+        # the cfg's CONSTRAINT: its names, the bound it gives each
+        # integer leaf, the leaves inference alone bounds, and an ERROR
+        # (the run is refused before a build) for a leaf neither bounds
+        from .speclint import constraint_report
+
+        report.constraint_lines, refused = constraint_report(
+            model, var_shapes=shapes)
+        report.extend(refused)
     if not symmetry:
         # the spec qualifies for orbit dedup but the run is not taking
         # it: one warning per SYMMETRY-eligible constant set (ISSUE 18)

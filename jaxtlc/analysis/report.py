@@ -62,6 +62,8 @@ def render_report(report: AnalysisReport) -> str:
         lines.extend(render_spec_section(report.spec))
     if report.bound_lines:
         lines.extend(report.bound_lines)
+    if report.constraint_lines:
+        lines.extend(report.constraint_lines)
     if report.engine_lines:
         lines.append("engine layer:")
         lines.extend(f"  {ln}" for ln in report.engine_lines)

@@ -52,7 +52,7 @@ from ..struct.shapes import (
     typeok_hints,
     universe,
 )
-from . import SEV_INFO, SEV_WARNING, Finding
+from . import SEV_ERROR, SEV_INFO, SEV_WARNING, Finding
 
 # the LaneCompiler's fan-out constants (struct/compile.py); imported
 # rather than duplicated so the audit can never drift from the compiler
@@ -460,7 +460,9 @@ class _SpecWalker:
                 env = self._shape_env(br)
                 for p, a in zip(d.params, args):
                     b2.senv[p] = self._abs(a, env)
-                inner = dname if label is None and d.body[0] != "or" \
+                from ..struct.actions import names_action
+
+                inner = dname if label is None and names_action(d.body) \
                     else label
                 self._seq([d.body] + rest, 0, b2, inner)
                 return
@@ -492,6 +494,94 @@ class _SpecWalker:
 # ---------------------------------------------------------------------------
 
 
+def inferred_shapes(model, const_hints=None) -> dict:
+    """The shapes struct.backend infers for `model` (its TypeOK hints,
+    and under a cfg's CONSTRAINT the constraint's leaf bounds)."""
+    from ..struct.shapes import constraint_bounds
+
+    system = model.system
+    hints = typeok_hints(system.ev, model.invariants, system.variables)
+    return infer_shapes(
+        system.ev, system.variables, system.init_ast, system.next_ast,
+        hints=hints, const_hints=const_hints,
+        kept=constraint_bounds(system.ev, model.constraints,
+                               system.variables))
+
+
+def constraint_report(model, var_shapes: Optional[dict] = None
+                      ) -> Tuple[List[str], List[Finding]]:
+    """What a cfg's CONSTRAINT does to the layout, for the preflight
+    report: the constraints' names; for every integer leaf of every
+    variable the bound the constraint gives it (shapes.
+    constraint_bounds) beside the range the codec takes, and which
+    leaves inference bounds alone.  A leaf NEITHER bounds - the
+    constraint says nothing of it and the inference widened it to its
+    last threshold - is an error finding: the run is refused before a
+    build, by the leaf's name (bound it in the constraint or in
+    TypeOK).  `var_shapes`: the model's inferred shapes where the
+    caller has them (`inferred_shapes`), else inferred here."""
+    from ..struct.shapes import (
+        _INT_THRESHOLDS,
+        SInt,
+        SRec,
+        constraint_bounds,
+    )
+
+    system = model.system
+    bounds = {(b.var, b.path): b for b in constraint_bounds(
+        system.ev, model.constraints, system.variables)}
+    shapes = var_shapes or inferred_shapes(model)
+    top = _INT_THRESHOLDS[-1]
+    lines = ["Constraint report: CONSTRAINT "
+             + " ".join(model.constraints)
+             + " (kept: a successor that fails it counts as generated "
+             "and is dropped)"]
+    findings: List[Finding] = []
+
+    def leaves(sh, path):
+        if isinstance(sh, SInt):
+            yield path, sh
+        elif isinstance(sh, SRec):
+            for f, s, _ in sh.fields:
+                yield from leaves(s, path + (f,))
+
+    def name(var, path):
+        return var + "".join(
+            f".{k}" if isinstance(k, str) else f"[{k}]" for k in path)
+
+    for var in system.variables:
+        for path, sh in leaves(shapes[var], ()):
+            b = bounds.get((var, path))
+            leaf = name(var, path)
+            if b is not None:
+                said = " and ".join(
+                    x for x in (f">= {b.lo}" if b.lo is not None else "",
+                                f"<= {b.hi}" if b.hi is not None else "")
+                    if x)
+                open_side = ("" if b.lo is not None and b.hi is not None
+                             else "; the other side is capped by a "
+                             "guess the range trap guards")
+                lines.append(f"  {leaf}: {said} by {b.by}; codec "
+                             f"{sh.lo}..{sh.hi} (a kept state and one "
+                             f"step outside{open_side})")
+            elif sh.lo <= -top - 1 or sh.hi >= top:
+                lines.append(f"  {leaf}: bounded by NEITHER (inference "
+                             f"widened it to {sh.lo}..{sh.hi})")
+                findings.append(Finding(
+                    layer="spec", check="unbounded-leaf",
+                    severity=SEV_ERROR, subject=leaf,
+                    detail=(f"integer leaf {leaf} is bounded neither by "
+                            f"CONSTRAINT {' '.join(model.constraints)} "
+                            "nor by inference (widened to its last "
+                            f"threshold, {sh.lo}..{sh.hi}): bound it in "
+                            "the constraint or in TypeOK"),
+                ))
+            else:
+                lines.append(f"  {leaf}: {sh.lo}..{sh.hi} by inference "
+                             "alone")
+    return lines, findings
+
+
 def analyze_spec(model, var_shapes: Optional[dict] = None,
                  dynamic_consts=frozenset(),
                  const_hints=None) -> SpecAnalysis:
@@ -503,12 +593,12 @@ def analyze_spec(model, var_shapes: Optional[dict] = None,
     whole sweep constants class instead of its anchor configuration."""
     system = model.system
     if var_shapes is None:
-        hints = typeok_hints(system.ev, model.invariants,
-                             system.variables)
-        var_shapes = infer_shapes(system.ev, system.variables,
-                                  system.init_ast, system.next_ast,
-                                  hints=hints, const_hints=const_hints)
-    cdc = StructCodec(system.variables, var_shapes)
+        var_shapes = inferred_shapes(model, const_hints)
+    from ..struct.shapes import constraint_bounds
+
+    cdc = StructCodec(system.variables, var_shapes, structural=frozenset(
+        b.var for b in constraint_bounds(system.ev, model.constraints,
+                                         system.variables)))
 
     w = _SpecWalker(model, var_shapes, dynamic_consts=dynamic_consts,
                     const_hints=const_hints)

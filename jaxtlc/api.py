@@ -795,6 +795,41 @@ def _resolve_struct_symmetry(args, spec, sm, bounds):
     return None
 
 
+def _constraint_refusal(args, spec, sm):
+    """A model whose cfg declares CONSTRAINT runs on the single-device
+    exhaustive engine, whose expand stage applies it (engine.backend.
+    make_expand_stage), and nowhere else: every other route is refused
+    here BY NAME, before anything is built - none ever runs the model
+    unconstrained.  (The engines refuse again where they are built:
+    engine.backend.require_unconstrained.)"""
+    if not sm.constraints:
+        return None
+    routes = [flag for flag, on in (
+        ("-sharded (the mesh engine's own expand half does not part "
+         "kept from counted)", args.sharded),
+        ("-simulate (a random walk has no constrained frontier)",
+         getattr(args, "simulate", False)),
+        ("-infer (the evidence walks and the induction step are "
+         "unconstrained)", getattr(args, "infer", False)),
+        ("-liveness / the cfg's PROPERTY lines (the behavior graph is "
+         "captured unconstrained)", args.liveness or spec.properties),
+        ("-narrow (the certified bounds know nothing of the "
+         "constraint)", args.narrow),
+        ("-symmetry / the cfg's SYMMETRY (the constraint is not "
+         "verified symmetric)",
+         getattr(args, "symmetry", None) or sm.symmetry),
+        ("-por (the ample sets ignore the constraint)",
+         getattr(args, "por", None)),
+    ) if on]
+    if not routes:
+        return None
+    return ("the cfg declares CONSTRAINT "
+            f"{' '.join(sm.constraints)}, which is not honoured by "
+            + "; ".join(routes)
+            + ": a constrained model runs on the single-device "
+            "exhaustive engine only")
+
+
 def _por(args) -> bool:
     """The RESOLVED -por mode this run's engines will use."""
     from .engine.bfs import resolve_por
@@ -1141,6 +1176,10 @@ def _run_check_struct(args, spec) -> int:
     if args.recover and not args.checkpoint:
         print("Error: -recover requires -checkpoint PATH", file=_err(args))
         return 1
+    refusal = _constraint_refusal(args, spec, sm)
+    if refusal:
+        print(f"Error: {refusal}", file=_err(args))
+        return 1
     if getattr(args, "simulate", False):
         # the simulation tier (jaxtlc.sim, ISSUE 14): random-walk
         # smoke checking instead of exhaustive BFS
@@ -1199,9 +1238,27 @@ def _run_check_struct(args, spec) -> int:
                 elide=not args.sharded, coverage=args.coverage,
                 symmetry=_symmetry(args), por=_por(args)))
             if wider is None:
-                if halt is not None:
-                    raise halt
-                return r, sup
+                # not compacted: the trap is a range trap.  Under a
+                # cfg's CONSTRAINT the side of a leaf the constraint
+                # leaves open was capped by a guess: 16 times further
+                # out, and the check again
+                from .struct.cache import widen_open_sides
+
+                further = widen_open_sides(sm, get_backend(
+                    sm, spec.check_deadlock, bounds=bounds,
+                    elide=not args.sharded, coverage=args.coverage,
+                    symmetry=_symmetry(args), por=_por(args)))
+                if further is None:
+                    if halt is not None:
+                        raise halt
+                    return r, sup
+                _sup_opts(args, log_holder[0]).on_event("degrade", dict(
+                    rung="widen", resource="open_side_factor",
+                    action="%d->%d" % further,
+                    reason="a kept state left the range guessed for a "
+                           "leaf the CONSTRAINT bounds on one side; "
+                           "the check starts again"))
+                continue
             _sup_opts(args, log_holder[0]).on_event("degrade", dict(
                 rung="widen", resource="step_slots",
                 action="%d->%d" % wider,
@@ -1349,7 +1406,8 @@ def _run_check_struct(args, spec) -> int:
         state_to_tla=lambda st: so.state_to_tla(system, st),
         state_env=lambda st: so.state_env(system, st),
         violation_trace=lambda: so.violation_trace(
-            system, sm.invariants, check_deadlock=spec.check_deadlock
+            system, sm.invariants, check_deadlock=spec.check_deadlock,
+            constraints=sm.constraints,
         ),
         action_order=action_order,
         preflight=lambda deep: _struct_preflight(args, spec, sm, deep),
@@ -1357,6 +1415,7 @@ def _run_check_struct(args, spec) -> int:
         dead_site_lint=dead_site_lint,
         artifact_plan=art_plan,
         reduce_info=reduce_info,
+        constraints=tuple(sm.constraints),
     )
     return _run_check_interp(args, spec, kit, log_holder=log_holder)
 
@@ -1884,8 +1943,10 @@ class _InterpKit:
                  state_to_tla, state_env, violation_trace,
                  coverage=None, action_order=None, preflight=None,
                  coverage_device=None, dead_site_lint=None,
-                 artifact_plan=None, reduce_info=None):
+                 artifact_plan=None, reduce_info=None, constraints=()):
         self.kind = kind
+        # the cfg's CONSTRAINT names (run_start.params names them)
+        self.constraints = constraints
         self.extra_unsupported = extra_unsupported
         self.check = check  # () -> (CheckResult, SupervisedResult | None)
         self.init_count = init_count
@@ -1955,6 +2016,9 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
                     deferred=_deferred(args),
                     symmetry=_symmetry(args), por=_por(args),
                     obs_slots=_obs_slots(args),
+                    # a constrained run names the cfg's constraints
+                    **({"constraints": list(kit.constraints)}
+                       if kit.constraints else {}),
                     # a reduced run names its sets and the group's order
                     **({} if red is None else dict(
                         symmetric_sets={k: list(v)

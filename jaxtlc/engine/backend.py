@@ -89,6 +89,32 @@ class SpecBackend(NamedTuple):
     # inside the expand stage - every make_stage_pair consumer inherits
     # both.  None keeps pre-reduction pytree layouts exactly
     reduce: object = None
+    # optional state constraint (a cfg's CONSTRAINT, ISSUE 39):
+    # fn([F] int32 RAW successor fields) -> bool "kept".  TLC's rule at
+    # the expand / commit seam (make_expand_stage): a successor that
+    # fails it counts as generated and is then dropped - never
+    # fingerprinted, enqueued or checked.  An engine that does not run
+    # make_expand_stage calls require_unconstrained and refuses the
+    # model by name: a constrained model never runs unconstrained
+    constraint: object = None
+    constraint_names: tuple = ()  # the cfg's names, for messages
+
+
+class ConstraintUnsupported(ValueError):
+    """A route that cannot honour a cfg's CONSTRAINT was asked to run a
+    constrained model."""
+
+
+def require_unconstrained(backend, route: str) -> None:
+    """Called by every engine and driver that expands states without
+    make_expand_stage: refuses a constrained backend, naming the route
+    and the constraint, instead of visiting states the cfg excludes."""
+    if getattr(backend, "constraint", None) is not None:
+        raise ConstraintUnsupported(
+            f"{route} does not honour the cfg's CONSTRAINT "
+            f"{' '.join(backend.constraint_names)}: a constrained model "
+            "runs only on the single-device exhaustive engine (drop "
+            f"the option that selected {route})")
 
 
 class ExpandOut(NamedTuple):
@@ -137,6 +163,13 @@ class ExpandOut(NamedTuple):
     # uint32 scalar: candidate transitions pruned by the POR ample-set
     # mask in this block (None when POR is off)
     pruned: jnp.ndarray = None
+    # [2] uint32: this block's valid successors the backend's state
+    # constraint judged, and those it rejected (None on a backend
+    # without one).  `valid` above is then what is KEPT; what counts as
+    # generated is `valid` plus the rejected, which the commit adds
+    # (carry `con_stat`; CheckResult.constraint_rows,
+    # constraint_discarded)
+    con_stat: jnp.ndarray = None
 
 
 def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
@@ -186,6 +219,7 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
     gen_counts_fn = backend.gen_counts
     if check_deadlock is None:
         check_deadlock = backend.check_deadlock
+    constraint = backend.constraint
     red = backend.reduce
     sym_plan = red.plan if red is not None else None
     por_on = bool(
@@ -219,6 +253,18 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
             valid = keep
 
         flat = succs.reshape(ncand, F)
+        # the state constraint: `counted` is what was generated, `valid`
+        # from here on what is kept
+        counted = valid
+        con_stat = None
+        if constraint is not None:
+            with jax.named_scope("jaxtlc.constraint"):
+                keep = jax.vmap(constraint)(flat).reshape(chunk, L)
+                con_stat = jnp.stack(
+                    [valid.sum(), (valid & ~keep).sum()]
+                ).astype(jnp.uint32)
+                valid = valid & keep
+                ovf = ovf & keep
         fvalid = valid.reshape(-1)
         faction = action.reshape(-1)
 
@@ -265,7 +311,7 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         # the hook sees exactly the lane validity the counters see
         cov = None
         if backend.coverage is not None:
-            cov = backend.coverage.count(batch, mask, valid).astype(
+            cov = backend.coverage.count(batch, mask, counted).astype(
                 jnp.uint32
             )
 
@@ -287,9 +333,9 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         # dispatches (gen/struct compilers), a per-candidate
         # compare-reduce otherwise
         if gen_counts_fn is not None:
-            gen = gen_counts_fn(batch, valid)
+            gen = gen_counts_fn(batch, counted)
         elif lane_action is not None:
-            lane_counts = valid.sum(axis=0).astype(jnp.uint32)
+            lane_counts = counted.sum(axis=0).astype(jnp.uint32)
             gen = (
                 (lane_action[:, None] == label_ids[None, :])
                 * lane_counts[:, None]
@@ -297,7 +343,7 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         else:
             gen = (
                 (faction[:, None] == label_ids[None, :])
-                & fvalid[:, None]
+                & counted.reshape(-1)[:, None]
             ).sum(axis=0).astype(jnp.uint32)
 
         # expand-stage violations, first wins (priority: invariant >
@@ -330,7 +376,7 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
             gen=gen, viol=viol, viol_state=viol_state,
             viol_action=viol_action, cert=cert, cov=cov,
             flat=flat if deferred else None,
-            sym=sym, sym_stat=sym_stat, pruned=pruned,
+            sym=sym, sym_stat=sym_stat, pruned=pruned, con_stat=con_stat,
         )
 
     return expand

@@ -172,6 +172,12 @@ class EngineCarry(NamedTuple):
     # delta; state counts legitimately shrink under POR)
     por_pruned: jnp.ndarray = None  # uint32
     st_pruned: jnp.ndarray = None  # staged block's pruned count
+    # --- state constraint (None without backend.constraint, ISSUE 39)
+    # Cumulative [2] uint32 (ExpandOut.con_stat summed): valid
+    # successors the cfg's CONSTRAINT judged, and those it rejected -
+    # counted as generated, never kept
+    con_stat: jnp.ndarray = None
+    st_con_stat: jnp.ndarray = None  # staged block's two
 
 
 class CheckResult(NamedTuple):
@@ -270,6 +276,15 @@ class CheckResult(NamedTuple):
     canon_moved: int = None
     sym_cert_checks: int = None
     sym_cert_trips: int = None
+    # constrained runs only (a cfg's CONSTRAINT; None elsewhere): valid
+    # successors the constraint judged (generated less the initial
+    # states) and those it rejected, which count as generated and are
+    # never fingerprinted, enqueued or checked (`constraint_discarded /
+    # constraint_rows`: a constant of the model, and 0 the day the
+    # constraint stops engaging); and the cfg's names for it
+    constraint_rows: int = None
+    constraint_discarded: int = None
+    constraint_names: tuple = None
 
 
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
@@ -278,7 +293,8 @@ MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
 STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
                  "states_expanded", "lane_fires", "struct_traps",
                  "sym_perms", "sym_sets", "canon_rows", "canon_moved",
-                 "sym_cert_checks", "sym_cert_trips")
+                 "sym_cert_checks", "sym_cert_trips", "constraint_rows",
+                 "constraint_discarded", "constraint_names")
 
 
 def mesh_counters(result: CheckResult) -> dict:
@@ -297,6 +313,9 @@ def with_step_counters(result: CheckResult, backend) -> CheckResult:
     static = getattr(getattr(backend, "cdc", None), "static_lanes", None)
     if static is None:
         return result
+    if getattr(backend, "constraint", None) is not None:
+        result = result._replace(
+            constraint_names=tuple(backend.constraint_names))
     plan = getattr(backend.reduce, "plan", None)
     if plan is not None:
         result = result._replace(sym_perms=plan.n_perms,
@@ -667,6 +686,9 @@ def make_stage_pair(
 
             act_gen = c.act_gen.at[:n_labels].add(ex.gen)
             generated = c.generated + ex.valid.sum().astype(jnp.uint32)
+            if ex.con_stat is not None:
+                # what the state constraint rejected was generated too
+                generated = generated + ex.con_stat[1]
             distinct = c.distinct + n_new.astype(jnp.uint32)
 
             # violations, first wins: carried > deferred invariant (when
@@ -732,6 +754,8 @@ def make_stage_pair(
                 extra["sym_stat"] = c.sym_stat + ex.sym_stat
             if ex.pruned is not None and c.por_pruned is not None:
                 extra["por_pruned"] = c.por_pruned + ex.pruned
+            if ex.con_stat is not None and c.con_stat is not None:
+                extra["con_stat"] = c.con_stat + ex.con_stat
             if ex.cov is not None and c.cov_counts is not None:
                 # device coverage plane: fold this block's per-site visit
                 # increments into the cumulative counters (telemetry only)
@@ -933,6 +957,7 @@ def make_backend_engine(
     )
     cov_plane = backend.coverage
     n_sites = cov_plane.n_sites if cov_plane is not None else 0
+    has_con = backend.constraint is not None
     cdc = backend.cdc
     F = cdc.n_fields
     W = (cdc.nbits + 31) // 32
@@ -973,6 +998,15 @@ def make_backend_engine(
             inits = red.plan.canon(inits)
         n0 = inits.shape[0]
         assert n0 <= chunk and n0 <= qcap, "raise chunk/queue_capacity"
+        kept0 = jnp.ones(n0, bool)
+        if has_con:
+            # an initial state outside the cfg's CONSTRAINT counts as
+            # generated and is invariant-checked below like the others,
+            # and is then not kept: the kept ones move to the front of
+            # the first level, in their order
+            kept0 = jax.vmap(backend.constraint)(inits)
+            inits = inits[jnp.argsort(~kept0, stable=True)]
+            kept0 = jnp.arange(n0) < kept0.sum()
         packed0 = cdc.pack(inits)
         queue = (
             jnp.zeros((2, qcap + 2 * chunk, W), jnp.uint32)
@@ -981,7 +1015,7 @@ def make_backend_engine(
         )
         lo, hi = fp64_words_mxu(packed0, nbits, fp_index, seed)
         fps, is_new_c, _, _ = fpset_insert_sorted(
-            fpset_new(fp_capacity), lo, hi, jnp.ones(n0, bool)
+            fpset_new(fp_capacity), lo, hi, kept0
         )
         distinct0 = is_new_c.sum().astype(jnp.uint32)
         # invariants hold on the initial states too (TLC checks them
@@ -1019,6 +1053,10 @@ def make_backend_engine(
                 staged["st_sym_stat"] = jnp.zeros(4, jnp.uint32)
             if has_por:
                 staged["st_pruned"] = jnp.uint32(0)
+            if has_con:
+                staged["st_con_stat"] = jnp.zeros(2, jnp.uint32)
+        if has_con:
+            staged["con_stat"] = jnp.zeros(2, jnp.uint32)
         if has_cert:
             staged["cert_viol"] = jnp.bool_(False)
         if has_sym:
@@ -1045,7 +1083,7 @@ def make_backend_engine(
             queue=queue,
             parity=jnp.int32(0),
             qhead=jnp.int32(0),
-            level_n=jnp.int32(n0),
+            level_n=kept0.sum().astype(jnp.int32),
             next_n=jnp.int32(0),
             level=jnp.int32(1),
             depth=jnp.int32(1),
@@ -1099,6 +1137,8 @@ def make_backend_engine(
                 extra["st_sym_stat"] = ex.sym_stat
             if has_por:
                 extra["st_pruned"] = ex.pruned
+            if has_con:
+                extra["st_con_stat"] = ex.con_stat
             return c._replace(
                 st_packed=ex.packed, st_lo=ex.lo, st_hi=ex.hi,
                 st_valid=ex.valid, st_action=ex.action, st_gen=ex.gen,
@@ -1118,6 +1158,7 @@ def make_backend_engine(
                 sym=c.st_sym if has_sym else None,
                 sym_stat=c.st_sym_stat if has_sym else None,
                 pruned=c.st_pruned if has_por else None,
+                con_stat=c.st_con_stat if has_con else None,
             )
 
         # The two-deep pipeline body, bubble-free: the staged block k-1
@@ -1303,6 +1344,10 @@ def make_enumerator(
     (the caller's cue to raise it or spill), VIOL_FPSET_FULL /
     VIOL_SLOT_OVERFLOW as in the exhaustive engine.
     """
+    from .backend import require_unconstrained
+
+    require_unconstrained(backend, "the reachable-set enumerator")
+
     from ..obs.counters import pack_row, ring_new, ring_update
 
     cdc = backend.cdc
@@ -1497,6 +1542,11 @@ def result_from_carry(
     sym_counts = {} if stat is None else dict(zip(
         ("canon_rows", "canon_moved", "sym_cert_checks",
          "sym_cert_trips"), map(int, np.asarray(stat))))
+    con = getattr(carry, "con_stat", None)
+    if con is not None:
+        sym_counts.update(zip(
+            ("constraint_rows", "constraint_discarded"),
+            map(int, np.asarray(con))))
     pruned = getattr(carry, "por_pruned", None)
     if pruned is not None:
         pruned = int(np.asarray(pruned).sum())  # shards carry partials

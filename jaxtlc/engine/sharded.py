@@ -520,6 +520,14 @@ def make_sharded_engine(
     column, so the checker runs invariants only - exactly like the
     immediate mesh body, which never called cert_check either.
     """
+    from .backend import require_unconstrained
+
+    if backend is not None:
+        # the mesh body has its own expand half (expand_half), which
+        # does not part kept from counted: a constrained model is
+        # refused by name, never run unconstrained
+        require_unconstrained(backend, "the mesh-sharded engine (-sharded)")
+
     from ..obs.counters import (
         pack_row,
         ring_cols,

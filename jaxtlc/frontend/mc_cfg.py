@@ -4,9 +4,12 @@ Parses the TLC config DSL as exercised by the reference
 (/root/reference/KubeAPI.toolbox/Model_1/MC.cfg:1-15) and by the
 published models of `tlaplus/Examples`: CONSTANT declarations and
 substitutions, SPECIFICATION (or INIT / NEXT), INVARIANT and PROPERTY
-lists, `SYMMETRY <definition>` and `CHECK_DEADLOCK TRUE|FALSE`.
-CONSTRAINT, ACTION_CONSTRAINT and VIEW are recognised and refused by
-name: each changes which states a run visits, and none has a seam here.
+lists, `SYMMETRY <definition>`, `CHECK_DEADLOCK TRUE|FALSE` and
+`CONSTRAINT <definitions>` (one or several names, on one line or many:
+their conjunction bounds the states a run keeps; the seam is the expand
+stage of engine.backend).  ACTION_CONSTRAINT and VIEW are recognised
+and refused by name: each changes which states a run visits, and
+neither has a seam here.
 This file pair (MC.cfg + MC.tla) is "the plugin boundary the TPU backend
 must accept unchanged" (SURVEY.md §1 L4->L3); the reference artifacts parse
 as-is.
@@ -34,9 +37,11 @@ class TLCConfig:
     next: Optional[str] = None
     symmetry: Optional[str] = None  # SYMMETRY definition-name
     check_deadlock: Optional[bool] = None  # CHECK_DEADLOCK, None = unsaid
+    # CONSTRAINT definition-names, in the cfg's order (their conjunction)
+    constraints: List[str] = dataclasses.field(default_factory=list)
 
 
-_REFUSED = ("CONSTRAINT", "ACTION_CONSTRAINT", "VIEW")
+_REFUSED = ("ACTION_CONSTRAINT", "VIEW")
 _SECTION = re.compile(
     r"^(CONSTANTS?|SPECIFICATION|INVARIANTS?|PROPERTY|PROPERTIES|INIT|NEXT"
     r"|SYMMETRY|CHECK_DEADLOCK|CONSTRAINTS?|ACTION_CONSTRAINTS?|VIEW)\b"
@@ -54,7 +59,10 @@ def parse_cfg(text: str) -> TLCConfig:
         if m:
             section = m.group(1)
             if section.rstrip("S") in _REFUSED:
-                raise CfgError(f"not supported: {section.rstrip('S')}")
+                raise CfgError(
+                    f"not supported: {section.rstrip('S')} (of the cfg "
+                    "keywords that bound a run only CONSTRAINT is "
+                    "honoured)")
             line = line[m.end():].strip()
             if not line:
                 continue
@@ -86,6 +94,9 @@ def parse_cfg(text: str) -> TLCConfig:
                     f"SYMMETRY names one definition, got {line!r}"
                     + (f" after {cfg.symmetry!r}" if cfg.symmetry else ""))
             cfg.symmetry = line
+        elif section.startswith("CONSTRAINT"):
+            cfg.constraints.extend(
+                n for n in line.split() if n not in cfg.constraints)
         elif section == "CHECK_DEADLOCK":
             if line not in ("TRUE", "FALSE"):
                 raise CfgError(

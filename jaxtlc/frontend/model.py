@@ -118,6 +118,15 @@ def resolve(
                 "structural frontend reduces (re-run with -frontend "
                 "struct)")
         frontend = "struct"
+    if cfg.constraints:
+        # likewise a CONSTRAINT: only the structural frontend compiles
+        # the predicate, and no other ever runs the model unconstrained
+        if frontend in ("hand", "gen"):
+            raise ValueError(
+                f"the cfg declares CONSTRAINT {' '.join(cfg.constraints)}"
+                ": only the structural frontend honours it (re-run with "
+                "-frontend struct)")
+        frontend = "struct"
     model_dir = os.path.dirname(os.path.abspath(cfg_path))
     mc_tla_path = os.path.join(model_dir, "MC.tla")
     consts = dict(cfg.constants)

@@ -41,6 +41,10 @@ def make_certify_fn(backend, inv_fns: list):
     own expand step under vmap), returning the [P] escaped-bits of the
     block - True means some pre-state satisfying the candidate has an
     enabled successor that does not."""
+    from ..engine.backend import require_unconstrained
+
+    require_unconstrained(backend, "invariant inference (-infer)")
+
     import jax
     import jax.numpy as jnp
 

@@ -149,6 +149,10 @@ def capture_edges(
     gen_backend), so any spec the sharded engine can run gets its graph
     captured with zero per-state host work.
     """
+    from ..engine.backend import require_unconstrained
+
+    require_unconstrained(backend, "the liveness graph capture (PROPERTY / -liveness)")
+
     cdc = backend.cdc
     F = cdc.n_fields
     W = (cdc.nbits + 31) // 32

@@ -198,7 +198,7 @@ def migrate_engine_carry(
     # flag, the canon counters and the POR count travel verbatim in
     # their own dtypes - a reduced run regrows like any other
     for f in ("sym_viol", "st_sym", "sym_stat", "st_sym_stat",
-              "por_pruned", "st_pruned"):
+              "por_pruned", "st_pruned", "con_stat", "st_con_stat"):
         if getattr(carry, f, None) is not None:
             staged[f] = jnp.asarray(np.asarray(getattr(carry, f)))
 

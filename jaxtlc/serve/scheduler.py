@@ -965,10 +965,12 @@ class Scheduler:
         })
         model = sw.load_anchored(cfg_path, params,
                                  const_overrides=fixed or None)
-        if model.symmetry:
+        if model.symmetry or model.constraints:
             # the cfg declares SYMMETRY and the vmapped sweep engine
             # does not reduce: each point through api.run_check, which
-            # does (no cfg with the line gets an unreduced verdict)
+            # does (no cfg with the line gets an unreduced verdict).
+            # Likewise CONSTRAINT: the pool's journal knows nothing of
+            # it, api.run_check names it and counts what it discards
             for j in batch:
                 self._run_supervised(j, frontend="struct")
                 self._release((j,))  # not behind the next job's run
@@ -1268,9 +1270,11 @@ class Scheduler:
         except (StructLoadError, StructParseError, JobError):
             self._run_supervised(job)
             return
-        if model.symmetry:
+        if model.symmetry or model.constraints:
             # the cfg declares SYMMETRY: the pool's plain engines do not
-            # reduce, api.run_check does
+            # reduce, api.run_check does.  A cfg that declares
+            # CONSTRAINT goes the same way (the pool route hands the
+            # model on by rule, it never runs it on its own)
             self._run_supervised(job, frontend="struct")
             return
         with span("sched.cache_lookup"):

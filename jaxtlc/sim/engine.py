@@ -144,6 +144,10 @@ def make_sim_engine(
     states.  Pure telemetry - it feeds no control flow, and it
     SATURATES (sticky ``fp_sat``) instead of halting the walk.
     """
+    from ..engine.backend import require_unconstrained
+
+    require_unconstrained(backend, "random-walk simulation (-simulate)")
+
     cdc = backend.cdc
     F = cdc.n_fields
     L = backend.n_lanes

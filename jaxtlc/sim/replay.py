@@ -69,6 +69,10 @@ def replay_lane(
     Stops early at the first violation on the walked path (invariant >
     assert > deadlock > slot overflow - the engine's own priority, so
     the replay lands on the same state the device reported)."""
+    from ..engine.backend import require_unconstrained
+
+    require_unconstrained(backend, "random-walk simulation (-simulate)")
+
     if check_deadlock is None:
         check_deadlock = backend.check_deadlock
     key = jax.random.PRNGKey(seed)

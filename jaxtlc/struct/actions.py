@@ -18,7 +18,9 @@ names the fired action - the PlusCal label attribution TLC's coverage
 output uses (MC.out:44-1092 lists DoRequest/DoReply/... as the action
 names); an action operator applied inside a named action (Paxos's
 Send(m) inside Phase1a(b)) does not rename it, as TLC splits Next into
-actions by its disjuncts only.
+actions by its disjuncts only - through bounded `\\E` as through `\\/`
+(`names_action`: EWD998's `Environment == \\E i \\in Node : SendMsg(i)
+\\/ RecvMsg(i) \\/ Deactivate(i)` names none, its three disjuncts do).
 """
 
 from __future__ import annotations
@@ -31,6 +33,15 @@ from .parser import Definition
 
 class StructActionError(ValueError):
     pass
+
+
+def names_action(body) -> bool:
+    """Does a definition with this body, expanded on the way down from
+    Next, name the fired action?  Not where it only splits further: a
+    disjunction, or bounded quantifiers over one."""
+    while body[0] == "exists":
+        body = body[3]
+    return body[0] != "or"
 
 
 def expand_unchanged(names, defs, variables) -> List[str]:
@@ -240,7 +251,7 @@ class ActionSystem:
                 for p, a in zip(d.params, args):
                     env2[p] = self.ev.eval(a, env, primed)
                 inner_label = label
-                if label is None and d.body[0] != "or":
+                if label is None and names_action(d.body):
                     inner_label = dname
                 self._enum(d.body, env2, primed, inner_label, outs)
                 return

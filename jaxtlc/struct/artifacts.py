@@ -135,8 +135,8 @@ def verdict_key(model, check_deadlock: bool = True,
 
 def behavior_digest(model) -> str:
     """Digest of what shapes the REACHABLE SET: variables, constants,
-    Init and Next ASTs, and every definition transitively referenced
-    from them (by name, over-approximated: any AST string that names a
+    Init and Next ASTs, the cfg's CONSTRAINT predicates, and every
+    definition transitively referenced from them (by name, over-approximated: any AST string that names a
     module definition counts - over-inclusion can only make the key
     more conservative, never wrong).  Invariant/property definitions
     that the behavior does not reference drop out, which is exactly
@@ -156,6 +156,10 @@ def behavior_digest(model) -> str:
     sys_ = model.system
     scan(sys_.init_ast)
     scan(sys_.next_ast)
+    # a cfg's CONSTRAINT shapes the reachable set like Init and Next do
+    constraints = getattr(model, "constraints", None) or {}
+    for ast in constraints.values():
+        scan(ast)
     while queue:
         d = defs[queue.pop()]
         scan(d.body)
@@ -165,6 +169,7 @@ def behavior_digest(model) -> str:
         repr(sys_.init_ast),
         repr(sys_.next_ast),
     ]
+    parts += [f"CONSTRAINT {n}={ast!r}" for n, ast in constraints.items()]
     for n in sorted(seen):
         d = defs[n]
         parts.append(f"{n}{tuple(d.params)!r}={d.body!r}")

@@ -29,7 +29,12 @@ from ..engine.bfs import VIOL_ASSERT
 from .codec import StructCodec
 from .compile import LaneCompiler, TrapPolicy, compact_lanes, compact_width
 from .loader import StructModel
-from .shapes import infer_shapes, typeok_hints
+from .shapes import (
+    OPEN_SIDE_FACTOR,
+    constraint_bounds,
+    infer_shapes,
+    typeok_hints,
+)
 
 VIOL_INVARIANT_BASE = 100
 
@@ -93,7 +98,8 @@ def struct_backend(model: StructModel,
                    coverage: bool = False,
                    symmetry: bool = False,
                    por: bool = False,
-                   slots: int = 0) -> SpecBackend:
+                   slots: int = 0,
+                   open_side_factor: int = OPEN_SIDE_FACTOR) -> SpecBackend:
     """Compile `model` into a SpecBackend: parse -> shape-infer ->
     lane-compile, the pipeline struct.cache memoizes in-process.
 
@@ -132,12 +138,30 @@ def struct_backend(model: StructModel,
 
     `slots` is the least a compacted step keeps a state (0: what
     compile.compact_width gives): struct.cache.widen_slots raises it
-    after a run met a state with more live lanes."""
+    after a run met a state with more live lanes.
+
+    A model whose cfg declares CONSTRAINT (model.constraints, ISSUE 39)
+    gets the conjunction compiled once, here, as a predicate on raw
+    successor fields (SpecBackend.constraint; host span
+    `build.struct.constraint`), which the engine's expand stage
+    applies: no flag switches it on or off, the cfg does.  Its leaf
+    bounds (shapes.constraint_bounds) are the upper side of the shape
+    inference - successors are read off kept states alone, so the
+    codec holds a kept state and one step outside it, which is what
+    the predicate has to be able to judge - and the certified
+    narrowing is not taken: it knows nothing of the constraint.
+    `open_side_factor` is how far out the
+    side of a leaf that the constraint leaves open is capped:
+    struct.cache.widen_open_sides raises it after a range trap."""
     from ..obs.spans import span
 
     system = model.system
     trap_policy = None
     cert = False
+    kept = constraint_bounds(system.ev, model.constraints,
+                             system.variables)
+    if model.constraints:
+        bounds = None
     with span("build.struct.shapes"):
         if bounds is not None and getattr(bounds, "certified", False):
             var_shapes = {v: bounds.bounds[v] for v in system.variables}
@@ -150,8 +174,10 @@ def struct_backend(model: StructModel,
                                  system.variables)
             var_shapes = infer_shapes(system.ev, system.variables,
                                       system.init_ast, system.next_ast,
-                                      hints=hints)
-        cdc = StructCodec(system.variables, var_shapes)
+                                      hints=hints, kept=kept,
+                                      open_side_factor=open_side_factor)
+        cdc = StructCodec(system.variables, var_shapes,
+                          structural=frozenset(b.var for b in kept))
     compiler = LaneCompiler(system.ev, system.variables, var_shapes,
                             cdc, trap_policy=trap_policy)
     # jitted at the [1, F] shape the per-row seam calls them with: the
@@ -205,6 +231,20 @@ def struct_backend(model: StructModel,
     def initial_vectors():
         inits = system.initial_states()
         return np.stack([cdc.encode(st) for st in inits])
+
+    constraint = None
+    if model.constraints:
+        # the cfg's CONSTRAINT: one predicate over a successor's raw
+        # fields, walked once here (like the lanes) and replayed by
+        # every engine trace
+        with span("build.struct.constraint") as sp:
+            sp.attrs["names"] = " ".join(model.constraints)
+            con_fn = jax.jit(compiler.build_invariant(
+                ("and", list(model.constraints.values()))))
+            jax.eval_shape(con_fn, jax.ShapeDtypeStruct((1, F), jnp.int32))
+
+        def constraint(vec):
+            return con_fn(vec[None])[0]
 
     cert_check = None
     if cert:
@@ -314,6 +354,8 @@ def struct_backend(model: StructModel,
         cert_check=cert_check,
         coverage=plane,
         reduce=reduce_ops,
+        constraint=constraint,
+        constraint_names=tuple(model.constraints),
     )
     # trap-audit surface (preflight renders which traps remain and why)
     backend.cdc.trap_stats = trap_stats

@@ -165,6 +165,37 @@ def widen_slots(model, backend):
     return backend.n_lanes, new
 
 
+# model key -> the factor of shapes.cap_open_sides, where a run of a
+# constrained model tripped a range trap (widen_open_sides); part of
+# every backend and engine key, like the slot floor
+_OPEN_SIDE: dict = {}
+OPEN_SIDE_LIMIT = 1 << 16
+
+
+def _open_side(spec) -> int:
+    from .shapes import OPEN_SIDE_FACTOR
+
+    return _OPEN_SIDE.get(spec, OPEN_SIDE_FACTOR)
+
+
+def widen_open_sides(model, backend):
+    """The rung a range trap of a CONSTRAINED model's step takes: the
+    sides of its integer leaves that the constraint leaves open are
+    capped 16 times further out (shapes.cap_open_sides), for every
+    backend of `model` built from now on (this process).  Returns
+    (old, new) factors, or None where the model has no constraint or
+    the cap has passed the widening's own last threshold: the trap is
+    then the codec's, which no factor cures."""
+    if getattr(backend, "constraint", None) is None:
+        return None
+    key = model_key(model)
+    old = _open_side(key)
+    if old >= OPEN_SIDE_LIMIT:
+        return None
+    _OPEN_SIDE[key] = old * 16
+    return old, old * 16
+
+
 def wants_symmetry(model, symmetry=None, chunk: int = 0) -> bool:
     """The RESOLVED symmetry mode of a check of `model`: on where the
     model's cfg declares SYMMETRY (the second way in beside the
@@ -205,8 +236,10 @@ def get_backend(model, check_deadlock: bool = True, bounds=None,
 
     spec = model_key(model)
     slots = _SLOT_FLOOR.get(spec, 0)
+    open_side = _open_side(spec)
     key = (spec, bool(check_deadlock), _bounds_key(bounds),
-           bool(elide), bool(coverage), bool(symmetry), bool(por), slots)
+           bool(elide), bool(coverage), bool(symmetry), bool(por), slots,
+           open_side)
     # host span `build.struct`: the memo's look-up and, on a miss, the
     # shape inference and the lane walk inside it (`build.struct.shapes`,
     # `build.struct.lanes`) - the struct path's own part of a build
@@ -217,7 +250,8 @@ def get_backend(model, check_deadlock: bool = True, bounds=None,
             hit = struct_backend(model, check_deadlock=check_deadlock,
                                  bounds=bounds, elide=elide,
                                  coverage=coverage, symmetry=symmetry,
-                                 por=por, slots=slots)
+                                 por=por, slots=slots,
+                                 open_side_factor=open_side)
             _BACKEND_MEMO.put(key, hit)
     return hit
 
@@ -267,7 +301,7 @@ def engine_key(
         bool(coverage), resolve_sort_free(sort_free, chunk),
         resolve_deferred(deferred, chunk),
         wants_symmetry(model, symmetry, chunk), resolve_por(por, chunk),
-        _SLOT_FLOOR.get(spec, 0),
+        _SLOT_FLOOR.get(spec, 0), _open_side(spec),
     )
 
 

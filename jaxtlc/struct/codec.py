@@ -246,9 +246,20 @@ class StructCodec:
     """Whole-state codec: variable order -> concatenated field layout."""
 
     def __init__(self, variables: Tuple[str, ...],
-                 var_shapes: Dict[str, Shape]):
+                 var_shapes: Dict[str, Shape],
+                 structural: frozenset = frozenset()):
+        """`structural`: variables whose record / function shape is laid
+        out field by field (RecNode) whatever the size of its universe.
+        The variables a cfg's CONSTRAINT reads (struct.backend): its
+        predicate decodes every candidate successor, and a field of an
+        enum-coded record is a table gather a candidate where a field
+        of a structural one is the column itself (PERF.md section 5,
+        PR 39: the gathers were 35 % of the EWD998 loop)."""
         self.variables = variables
-        self.layouts = [layout_of(var_shapes[v]) for v in variables]
+        self.layouts = [
+            RecNode(var_shapes[v]) if v in structural and isinstance(
+                var_shapes[v], SRec) else layout_of(var_shapes[v])
+            for v in variables]
         self.offsets: Dict[str, int] = {}
         self.widths: List[int] = []
         for v, lay in zip(variables, self.layouts):

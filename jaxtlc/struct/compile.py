@@ -49,7 +49,16 @@ import numpy as np
 
 from ..spec.labels import DEFAULT_INIT
 from .codec import EnumLeaf, MaskLeaf, RecNode, SeqNode, StructCodec, layout_of
-from .eval import _SORT_KEY, BUILTIN_SETS, Evaluator, StructEvalError, is_fn
+from .eval import (
+    _SORT_KEY,
+    BUILTIN_SETS,
+    Evaluator,
+    LazySet,
+    StructEvalError,
+    fold_args,
+    is_fn,
+    is_int,
+)
 from .parser import Definition
 from .shapes import (
     SAtoms,
@@ -591,6 +600,10 @@ class LaneCompiler:
             radices.append(n + 1 if opt else n)
         idx = None
         depth = 0
+        # a required field whose value has no code (a range trap: -1)
+        # makes the whole record's code -1 - folded into the mixed radix
+        # it would alias another record in silence
+        bad, bad_d = jnp.zeros((1,), bool), 0
         for (f, s, opt), radix in zip(sh.fields, radices):
             p, v = lv.get(f)
             fleaf = self._leaf_of_shape(s)
@@ -603,6 +616,9 @@ class LaneCompiler:
                 fe = self.to_leaf(v, fleaf)
                 code = fe.arr + (1 if opt else 0)
                 pd = fe.depth
+                if not opt:
+                    ba, fa, bad_d = _binop_arrs(bad, bad_d, fe.arr < 0, pd)
+                    bad = ba | fa
                 if opt and not (isinstance(p, LC) and p.value is True):
                     # dynamic presence
                     parr = p.arr if isinstance(p, LB) else jnp.full(
@@ -618,7 +634,8 @@ class LaneCompiler:
                 idx = ia * radix + ca
         if idx is None:
             idx = jnp.zeros((1,), jnp.int32)
-        return LE(idx + off, leaf, depth)
+        ia, ba, depth = _binop_arrs(idx + off, depth, bad, bad_d)
+        return LE(jnp.where(ba, -1, ia), leaf, depth)
 
     def _mask_to_leaf(self, lv: LM, leaf: EnumLeaf) -> LE:
         off, set_leaf = self._resolve_alt(leaf, SSet)
@@ -908,10 +925,16 @@ class LaneCompiler:
             ])
         if op == "recset":
             doms = [self.comp(x, env, ctx) for _, x in ast[1]]
+            names = [f for f, _ in ast[1]]
+            if all(isinstance(d, LC) for d in doms) and any(
+                    not isinstance(d.value, frozenset) for d in doms):
+                # a field over Nat / Int (`Token == [pos : Node, q :
+                # Int, ...]`): only ever asked for membership
+                return LC(LazySet("recset", tuple(
+                    zip(names, (d.value for d in doms)))))
             if not all(isinstance(d, LC) and isinstance(d.value, frozenset)
                        for d in doms):
                 raise CompileError("record set over a dynamic field set")
-            names = [f for f, _ in ast[1]]
             return LC(frozenset(
                 tuple(sorted(zip(names, combo))) for combo in _product(
                     *(sorted(d.value, key=_SORT_KEY) for d in doms))
@@ -1324,6 +1347,22 @@ class LaneCompiler:
                     hit = self.look_up(tab, a)
                     return LB(hit if a.universe else hit & (a.arr >= 0),
                               a.depth)
+            if bv is BUILTIN_SETS["Nat"] or bv is BUILTIN_SETS["Int"]:
+                # an integer lane is an integer; Nat asks for its sign
+                nat = bv is BUILTIN_SETS["Nat"]
+                if isinstance(a, LC):
+                    return LC(is_int(a.value)
+                              and (a.value >= 0 or not nat))
+                if isinstance(a, LI) or (isinstance(a, LE) and isinstance(
+                        a.leaf.shape, SInt)):
+                    arr, d = self._int_arr(a)
+                    ok = arr >= 0 if nat else jnp.ones_like(arr, bool)
+                    if isinstance(a, LE):
+                        ok = ok & (a.arr >= 0)
+                    return LB(ok, d)
+                return LC(False)
+            if isinstance(bv, LazySet):
+                return self._member_lazy(a, bv)
             raise CompileError(f"\\in over constant {bv!r}")
         if isinstance(b, LM):
             const = _const_record(a)
@@ -1342,6 +1381,29 @@ class LaneCompiler:
             onehot = jnp.arange(len(b.elem_leaf.values)) == idx[..., None]
             return LB((onehot & bits).any(axis=-1) & (idx >= 0), d)
         raise CompileError(f"\\in over {type(b).__name__}")
+
+    def _member_lazy(self, a, lazy: LazySet) -> LV:
+        """a \\in a record or function set with an infinite part (the
+        evaluator's LazySet): field by field, key by key."""
+        if isinstance(a, LC):
+            return LC(Evaluator._member(a.value, lazy))
+        if isinstance(a, LE):
+            a = self.explode(a)
+        if not isinstance(a, LRec):
+            return LC(False)
+        have = {f for f, _, _ in a.entries}
+        parts = lazy.fields()
+        want = {f for f, _ in parts}
+        out = LC(True)
+        for extra in have - want:
+            out = self._land(out, self._lnot(a.get(extra)[0]))
+        for f, dom in parts:
+            p, v = a.get(f)
+            if v is None:
+                return LC(False)
+            out = self._land(self._land(out, p),
+                             self._member_lv(v, LC(dom)))
+        return out
 
     def _subseteq_lv(self, a, b) -> LV:
         if isinstance(b, LM):
@@ -1767,13 +1829,22 @@ class LaneCompiler:
             # state-dependent filter over a constant set (quorum
             # counting: {n \\in Nodes : Len(log[n]) >= k}): a mask over
             # the atom universe with per-element predicate bits
-            if not all(isinstance(v, str) for v, _ in results):
-                raise CompileError(
-                    "state-dependent filter over non-atom constant set"
+            if all(isinstance(v, str) for v, _ in results):
+                leaf = self._leaf_of_shape(
+                    SAtoms(frozenset(v for v, _ in results))
                 )
-            leaf = self._leaf_of_shape(
-                SAtoms(frozenset(v for v, _ in results))
-            )
+            elif all(is_int(v) for v, _ in results):
+                # over integers (`{i \\in Node : a <= i /\\ i <= b}`):
+                # the hull's universe; a gap in the set is never a member
+                ints = {v for v, _ in results}
+                leaf = self._leaf_of_shape(SInt(min(ints), max(ints)))
+                results += [(v, LC(False)) for v in leaf.values
+                            if v not in ints]
+            else:
+                raise CompileError(
+                    "state-dependent filter over a constant set that is "
+                    "neither atoms nor integers"
+                )
             depth = max((r.depth for _, r in results
                          if isinstance(r, LB)), default=0)
             cols = [None] * len(leaf.values)
@@ -1943,6 +2014,8 @@ class LaneCompiler:
             for p, a in zip(d.params, args):
                 env2[p] = self.comp(a, env, ctx)
             return self.comp(d.body, env2, ctx)
+        if name in ("FoldFunctionOnSet", "FoldFunction"):
+            return self._comp_fold(name, args, env, ctx)
         vals = [self.comp(a, env, ctx) for a in args]
         if name == "Cardinality":
             (s,) = vals
@@ -1997,6 +2070,65 @@ class LaneCompiler:
                 ctx.afail = self._lor(ctx.afail, self._lnot(cond))
             return LC(True)
         raise CompileError(f"unknown operator {name!r}")
+
+    def _comp_fold(self, name, args, env, ctx) -> LV:
+        """The community module Functions' folds with + or * over a
+        function of integers: a masked sum (product) over the
+        function's static keys - the key's membership bit selects its
+        value or the operator's unit.  `Sum(counter, Rng(token.pos + 1,
+        N - 1))`: the set is state-dependent, the keys are not."""
+        sym, base_ast, f_ast, set_ast = fold_args(name, args)
+        base = self.comp(base_ast, env, ctx)
+        f = self.comp(f_ast, env, ctx)
+        keys = None if set_ast is None else self.comp(set_ast, env, ctx)
+        if isinstance(f, LC):
+            f = LRec([(k, LC(True), LC(v)) for k, v in f.value])
+        if isinstance(f, LE):
+            f = self.explode(f)
+        if not isinstance(f, LRec):
+            raise CompileError(f"{name} over {type(f).__name__}")
+        if isinstance(keys, LC) and not isinstance(keys.value, frozenset):
+            raise CompileError(f"{name} over a non-set")
+        if not isinstance(keys, (LC, LM, type(None))):
+            raise CompileError(
+                f"{name} over a {type(keys).__name__} set")
+        unit = 0 if sym == "+" else 1
+        acc, depth = self._int_arr(base)
+        bounds = _int_bounds(base)
+        for k, p, v in f.entries:
+            if keys is None:
+                member = LC(True)
+            elif isinstance(keys, LC):
+                member = LC(k in keys.value)
+            else:
+                i = keys.elem_leaf.index.get(k)
+                member = LC(False) if i is None or (
+                    keys.support is not None and not keys.support[i]
+                ) else LB(keys.bits[..., i], keys.depth)
+            # a key the function lacks (an exploded record's presence
+            # bit) adds the unit, like one outside the set
+            member = self._land(member, p)
+            if isinstance(member, LC) and not member.value:
+                continue
+            arr, d = self._int_arr(v)
+            vb = _int_bounds(v)
+            if isinstance(member, LB):
+                m, arr, d = _binop_arrs(member.arr, member.depth, arr, d)
+                arr = jnp.where(m, arr, unit)
+                if vb is not None:
+                    vb = (min(vb[0], unit), max(vb[1], unit))
+            acc, arr, depth = _binop_arrs(acc, depth, arr, d)
+            acc = acc + arr if sym == "+" else acc * arr
+            if bounds is not None and vb is not None:
+                cs = ([bounds[0] + vb[0], bounds[1] + vb[1]] if sym == "+"
+                      else [a * b for a in bounds for b in vb])
+                bounds = (min(cs), max(cs))
+            else:
+                bounds = None
+        if bounds is not None and bounds[0] == bounds[1] \
+                and isinstance(acc, np.ndarray):
+            return LC(int(acc[0]))  # every operand a host constant
+        return LI(jnp.asarray(acc), depth, bounds=bounds)
 
     def _comp_fnlit(self, ast, env, ctx) -> LV:
         _, var, dom_ast, body = ast
@@ -2247,7 +2379,9 @@ class LaneCompiler:
                 env2 = dict(env)
                 for p, a in zip(d.params, args):
                     env2[p] = self.comp(a, env, ctx)
-                inner = dname if label is None and d.body[0] != "or" \
+                from .actions import names_action
+
+                inner = dname if label is None and names_action(d.body) \
                     else label
                 self._walk_seq([d.body] + rest, 0, env2, ctx, inner, out)
                 return

@@ -59,6 +59,28 @@ STRING = _Sentinel("STRING")
 NAT = _Sentinel("Nat")
 INT = _Sentinel("Int")
 
+class LazySet:
+    """A set that is only ever asked for membership, because a part of
+    it is infinite: `[Node -> Int]`, `[pos : Node, q : Int]` (the type
+    invariants of specs over unbounded integers).  `kind` is "funcset"
+    (parts: domain, range) or "recset" (parts: ((field, set), ...))."""
+
+    def __init__(self, kind, parts):
+        self.kind = kind
+        self.parts = parts
+
+    def fields(self):
+        """[(key or field, the set its value lies in), ...] in key
+        order: what a member is checked against, part by part."""
+        if self.kind == "recset":
+            return sorted(self.parts, key=lambda p: p[0])
+        dom, rng = self.parts
+        return [(k, rng) for k in sorted(dom, key=_SORT_KEY)]
+
+    def __repr__(self):
+        return f"<{self.kind} with an infinite part>"
+
+
 BUILTIN_SETS = {
     "STRING": STRING,
     "Nat": NAT,
@@ -115,14 +137,27 @@ def permute_value(v, pmap):
     return v
 
 
+def is_int(x) -> bool:
+    return isinstance(x, int) and not isinstance(x, bool)
+
+
 def is_fn(v) -> bool:
-    """Function/record: non-empty tuple of (str, value) pairs.  The empty
-    tuple is both the empty function and the empty sequence - all its
-    uses below are consistent for either reading."""
-    return isinstance(v, tuple) and all(
-        isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], str)
-        for x in v
-    )
+    """Function/record: non-empty tuple of (key, value) pairs whose keys
+    are all strings (records, functions over model values), or all
+    integers making one interval lo..hi in order that does not start at
+    1 (`[0 .. N-1 -> ...]`: a function over 1..n IS a sequence, and is
+    kept as one).  The empty tuple is both the empty function and the
+    empty sequence - all its uses below are consistent for either
+    reading.  A sequence of pairs whose first components happen to be
+    such an interval reads as a function: the one shape the value model
+    cannot tell apart, as with string-first pairs (`canon`)."""
+    if not isinstance(v, tuple) or not all(
+            isinstance(x, tuple) and len(x) == 2 for x in v):
+        return False
+    if all(isinstance(k, str) for k, _ in v):
+        return True
+    return bool(v) and all(is_int(k) for k, _ in v) and v[0][0] != 1 \
+        and all(k == v[0][0] + i for i, (k, _) in enumerate(v))
 
 
 def fn_apply(f, arg):
@@ -250,6 +285,9 @@ class Evaluator:
             return self.eval(ast[2], env2, primed)
         if op == "recset":
             names = [f for f, _ in ast[1]]
+            parts = [self.eval(x, env, primed) for _, x in ast[1]]
+            if any(not isinstance(d, frozenset) for d in parts):
+                return LazySet("recset", tuple(zip(names, parts)))
             doms = [sorted(self._set(x, env, primed), key=_SORT_KEY)
                     for _, x in ast[1]]
             return frozenset(
@@ -313,6 +351,10 @@ class Evaluator:
                 pairs.append((x, self.eval(body, env2, primed)))
             return _pairs_to_fn(pairs)
         if op == "funcset":
+            rng = self.eval(ast[2], env, primed)
+            if not isinstance(rng, frozenset):
+                return LazySet("funcset",
+                               (self._set(ast[1], env, primed), rng))
             dom = sorted(self._set(ast[1], env, primed), key=_SORT_KEY)
             rng = sorted(self._set(ast[2], env, primed), key=_SORT_KEY)
             fns = []
@@ -386,6 +428,13 @@ class Evaluator:
             return isinstance(a, int) and not isinstance(a, bool) and a >= 0
         if b is INT:
             return isinstance(a, int) and not isinstance(a, bool)
+        if isinstance(b, LazySet):
+            if not (isinstance(a, tuple) and a and is_fn(a)):
+                return False
+            want = b.fields()
+            return [k for k, _ in a] == [f for f, _ in want] and all(
+                Evaluator._member(x, dom)
+                for (_, x), (_, dom) in zip(a, want))
         raise StructEvalError(f"\\in over non-set {b!r}")
 
     def _binop(self, ast, env, primed):
@@ -431,6 +480,16 @@ class Evaluator:
             return f[: idx - 1] + (val,) + f[idx:]
         raise StructEvalError("EXCEPT on a non-function")
 
+    def _fold(self, name, args, env, primed):
+        sym, base_ast, f_ast, set_ast = fold_args(name, args)
+        acc = self.eval(base_ast, env, primed)
+        f = self.eval(f_ast, env, primed)
+        keys = fn_domain(f) if set_ast is None else self._set(
+            set_ast, env, primed)
+        for k in sorted(keys, key=_SORT_KEY):
+            acc = FOLD_OPS[sym](acc, fn_apply(f, k))
+        return acc
+
     def _call(self, ast, env, primed):
         _, name, args = ast
         target = None
@@ -448,6 +507,8 @@ class Evaluator:
             for p, a in zip(target.params, args):
                 env2[p] = self.eval(a, env, primed)
             return self.eval(target.body, env2, primed)
+        if name in ("FoldFunctionOnSet", "FoldFunction"):
+            return self._fold(name, args, env, primed)
         vals = [self.eval(a, env, primed) for a in args]
         if name == "Cardinality":
             (s,) = vals
@@ -493,6 +554,26 @@ class Evaluator:
         raise StructEvalError(f"unknown operator {name!r}")
 
 
+FOLD_OPS = {"+": lambda a, b: a + b, "*": lambda a, b: a * b}
+
+
+def fold_args(name, args):
+    """(operator symbol, base AST, function AST, set AST or None) of a
+    call of the community module Functions' folds:
+    `FoldFunctionOnSet(op, base, f, S)` folds f's values over the
+    elements of S, `FoldFunction(op, base, f)` over DOMAIN f.  `op` is
+    written as the bare infix symbol (`+`, `*`): commutative and
+    associative, so the order TLC's CHOOSE would pick does not show."""
+    want = 4 if name == "FoldFunctionOnSet" else 3
+    if len(args) != want:
+        raise StructEvalError(f"{name} expects {want} arguments")
+    if args[0][0] != "opsym" or args[0][1] not in FOLD_OPS:
+        raise StructEvalError(
+            f"{name}: the folded operator has to be + or * here")
+    return (args[0][1], args[1], args[2],
+            args[3] if want == 4 else None)
+
+
 def _pairs_to_fn(pairs):
     """Key-typed function literal: string keys -> sorted pairs; 1..n ->
     sequence; empty -> () (empty function == empty sequence)."""
@@ -503,6 +584,12 @@ def _pairs_to_fn(pairs):
     keys = {k for k, _ in pairs}
     if keys == set(range(1, len(pairs) + 1)):
         return tuple(v for _, v in sorted(pairs))
+    if all(is_int(k) for k in keys) and len(keys) == len(pairs) \
+            and keys == set(range(min(keys), max(keys) + 1)):
+        # an integer interval that is not 1..n (`Node == 0 .. N-1`):
+        # key-sorted pairs, like a record
+        return tuple(sorted(pairs))
     raise StructEvalError(
-        "function domains must be strings or 1..n here"
+        "function domains must be strings, 1..n or an integer "
+        "interval here"
     )

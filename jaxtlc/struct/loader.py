@@ -6,9 +6,12 @@ PROPERTY, MC.tla for the generated constant-override definitions, and
 the EXTENDS closure of real module files next to the config (Model_1
 carries its own KubeAPI.tla copy) - falling back to the toolbox parent
 directory for the root spec.  Standard modules (Naturals, FiniteSets,
-Sequences, TLC) are built into the evaluator.  A cfg's `SYMMETRY <def>`
+Sequences, TLC; of the community modules `Functions`, whose folds the
+evaluator knows) are built into the evaluator.  A cfg's `SYMMETRY <def>`
 is evaluated here (`declared_symmetry`) to the constant sets whose full
-permutation groups it is the union of.
+permutation groups it is the union of; a cfg's `CONSTRAINT <defs>` is
+resolved here (`declared_constraints`) to the state predicates whose
+conjunction every kept state satisfies.
 """
 
 from __future__ import annotations
@@ -26,7 +29,7 @@ from .parser import Definition, Module, StructParseError, parse_module
 
 _BUILTIN_MODULES = {
     "TLC", "Naturals", "Integers", "Reals", "Sequences", "FiniteSets",
-    "Bags", "TLAPS", "Toolbox",
+    "Bags", "TLAPS", "Toolbox", "Functions",
 }
 
 
@@ -49,6 +52,13 @@ class StructModel(NamedTuple):
     # line.  A model that declares one is only ever checked reduced
     # (struct.cache.wants_symmetry); analysis.symfind verifies the sets
     symmetry: Tuple[Tuple[str, Tuple[str, ...]], ...] = ()
+    # the cfg's CONSTRAINT definitions, resolved: name -> AST, in the
+    # cfg's order; {} where the cfg has no such line.  A model that
+    # declares one is only ever checked constrained: the backend
+    # carries the predicate (struct.backend), the engines that share
+    # the expand stage apply it, and every other route refuses the
+    # model by name (engine.backend.require_unconstrained)
+    constraints: Dict[str, tuple] = {}
 
 
 class StructLoadError(ValueError):
@@ -134,6 +144,31 @@ def declared_symmetry(defname: str, module: Module,
         if len(dom) >= 2:  # a one-element set has only the identity
             out.append((names[0], tuple(sorted(dom))))
     return tuple(out)
+
+
+def declared_constraints(names, module: Module) -> Dict[str, tuple]:
+    """The state predicates a cfg's `CONSTRAINT names` declares: each
+    has to be a defined operator without parameters whose body reads
+    the current state alone.  An undefined name, an operator with
+    parameters or a primed variable is a load error that names it."""
+    from .shapes import _mentions_prime_static
+
+    out: Dict[str, tuple] = {}
+    for n in names:
+        d = module.defs.get(n)
+        if d is None:
+            raise StructLoadError(f"CONSTRAINT {n}: no such definition")
+        if d.params:
+            raise StructLoadError(
+                f"CONSTRAINT {n}: an operator with parameters "
+                f"({', '.join(d.params)}) is not a state predicate")
+        if _mentions_prime_static(d.body, module.defs):
+            raise StructLoadError(
+                f"CONSTRAINT {n}: mentions a primed variable or "
+                "UNCHANGED (a state constraint reads one state; "
+                "ACTION_CONSTRAINT is not supported)")
+        out[n] = d.body
+    return out
 
 
 def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
@@ -272,6 +307,17 @@ def _load(cfg_path: str,
             sp.attrs["perms"] = math.prod(
                 math.factorial(len(atoms)) for _, atoms in symmetry)
 
+    constraints: Dict[str, tuple] = {}
+    if cfg.constraints:
+        from ..obs.spans import span
+
+        # host span `build.struct.constraint` (with the backend's one of
+        # the same name, which compiles the predicate where the memo
+        # misses): a check pays the resolution on every call
+        with span("build.struct.constraint") as sp:
+            sp.attrs["names"] = " ".join(cfg.constraints)
+            constraints = declared_constraints(cfg.constraints, module)
+
     spec_name = cfg.specification or "Spec"
     spec_def = module.defs.get(spec_name)
     if spec_def is not None and spec_def.body[0] == "spec":
@@ -302,4 +348,5 @@ def _load(cfg_path: str,
         root_name=root_name,
         source_digest=digest.hexdigest(),
         symmetry=symmetry,
+        constraints=constraints,
     )

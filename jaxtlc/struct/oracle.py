@@ -8,6 +8,14 @@ at level 1 (MC.out:1101); deadlock = a state with no successor at all
 distinct state.  Action attribution uses the PlusCal label names
 (MC.out:44-1092), so per-action generated counts diff directly against
 the hand oracle and the TLC log.
+
+Under a cfg's CONSTRAINT (`constraints`, the loader's name -> AST) a
+successor that fails the conjunction counts as generated and toward its
+action's total and is then dropped: not kept, not checked, never
+expanded; deadlock is judged on the successors before the constraint;
+an initial state outside it is checked and not kept (the rule of
+engine.backend's expand stage, which this search is the host oracle
+of).
 """
 
 from __future__ import annotations
@@ -31,6 +39,10 @@ class StructBFSResult(NamedTuple):
     levels: List[int]
     parents: Optional[Dict[tuple, Tuple[Optional[tuple], Optional[str]]]]
     states: Optional[Dict[tuple, int]]  # state -> level (collect_states)
+    # successors the cfg's CONSTRAINT rejected (0 without one), and the
+    # initial states it rejected (checked, never kept)
+    discarded: int = 0
+    discarded_inits: int = 0
 
 
 def bfs(
@@ -41,6 +53,7 @@ def bfs(
     keep_parents: bool = False,
     stop_on_violation: bool = True,
     collect_states: bool = False,
+    constraints: Optional[Dict[str, tuple]] = None,
 ) -> StructBFSResult:
     ev = system.ev
     inits = system.initial_states()
@@ -68,8 +81,23 @@ def bfs(
             if not ok:
                 violations.append((name, st))
 
+    def kept(st: tuple) -> bool:
+        if not constraints:
+            return True
+        env = dict(ev.constants)
+        env.update(zip(system.variables, st))
+        return all(ev.eval(ast, env) is True
+                   for ast in constraints.values())
+
+    discarded = discarded_inits = 0
     for s in inits:
         generated += 1
+        if not kept(s):
+            discarded_inits += 1
+            if keep_parents:
+                parents.setdefault(s, (None, None))
+            check_invs(s)
+            continue
         if s not in seen:
             seen[s] = 1
             frontier.append(s)
@@ -98,6 +126,9 @@ def bfs(
                 violations.append(("deadlock", s))
             for label, t in succs:
                 act_gen[label] = act_gen.get(label, 0) + 1
+                if t not in seen and not kept(t):
+                    discarded += 1
+                    continue
                 if t not in seen:
                     if len(seen) >= max_states:
                         raise RuntimeError("state-space bound exceeded")
@@ -123,6 +154,8 @@ def bfs(
         levels=levels,
         parents=parents,
         states=seen if collect_states else None,
+        discarded=discarded,
+        discarded_inits=discarded_inits,
     )
 
 
@@ -252,11 +285,13 @@ def _alive_tail(edges, start, alive):
 
 def violation_trace(system: ActionSystem, invariants: Dict[str, tuple],
                     check_deadlock: bool = True,
-                    max_states: int = 10_000_000):
+                    max_states: int = 10_000_000,
+                    constraints: Optional[Dict[str, tuple]] = None):
     """(kind, [(state, label|None), ...]) for the first violation, or
     None - the trace-explorer re-run over the structural relation."""
     r = bfs(system, invariants, check_deadlock=check_deadlock,
-            max_states=max_states, keep_parents=True)
+            max_states=max_states, keep_parents=True,
+            constraints=constraints)
     if not r.violations:
         return None
     kind, bad = r.violations[0]

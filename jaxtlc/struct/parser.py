@@ -26,7 +26,8 @@ AST nodes are plain tuples (texpr-compatible where the form overlaps):
   ("cmp", op, a, b)            op in = # < > <= >= \\in \\notin \\subseteq
   ("binop", op, a, b)          op in \\cup \\cap \\ + - .. \\o @@ :>
   ("apply", f, arg)            f[arg] and r.field (field as ("str", f))
-  ("call", name, [args])       operator application Foo(a, b)
+  ("call", name, [args])       operator application Foo(a, b); a bare
+                               infix operator argument is ("opsym", "+")
   ("setlit", [..]) ("setfilter", var, dom, pred) ("setmap", e, var, dom)
   ("tuple", [..]) ("record", [(f, e), ..]) ("recset", [(f, S), ..])
   ("fnlit", var, dom, body) ("funcset", dom, rng)
@@ -742,13 +743,26 @@ class _ExprParser:
             return ("domain", self.parse_postfix())
         if self.peek().kind == "sym" and self.peek().val == "(":
             self.next()
-            args = [self.parse_expr()]
+            args = [self._parse_arg()]
             while self.peek().kind == "sym" and self.peek().val == ",":
                 self.next()
-                args.append(self.parse_expr())
+                args.append(self._parse_arg())
             self.expect(")")
             return ("call", v, args)
         return ("name", v)
+
+    def _parse_arg(self) -> tuple:
+        """One argument of an operator application: an expression, or a
+        bare infix operator handed to a higher-order operator
+        (`FoldFunctionOnSet(+, 0, f, S)`): ("opsym", "+")."""
+        t = self.peek()
+        nxt = self.toks[self.i + 1] if self.i + 1 < len(self.toks) \
+            else _EOF
+        if t.kind == "sym" and t.val in ("+", "*", "-") \
+                and nxt.kind == "sym" and nxt.val in (",", ")"):
+            self.next()
+            return ("opsym", t.val)
+        return self.parse_expr()
 
     def _looks_like_let_def(self) -> bool:
         """After one LET binding, is the next token run another

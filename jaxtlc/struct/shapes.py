@@ -27,10 +27,10 @@ from __future__ import annotations
 
 import dataclasses
 from itertools import product as _product
-from typing import Dict, FrozenSet, List, Optional, Tuple
+from typing import Dict, FrozenSet, List, NamedTuple, Optional, Tuple
 
 from ..spec.labels import DEFAULT_INIT
-from .eval import BUILTIN_SETS, Evaluator, is_fn
+from .eval import BUILTIN_SETS, Evaluator, LazySet, is_fn, is_int
 from .parser import Definition
 
 
@@ -364,6 +364,11 @@ class ShapeInference:
     # swept constant to its whole lo..hi interval here, so one abstract
     # pass covers every configuration of the class
     const_hints: Dict[str, Shape] = {}
+    # a cfg's CONSTRAINT (infer_shapes' `kept`): {var: [(path, lo, hi)]},
+    # the leaf bounds every kept state lies inside, and how far out the
+    # side of a leaf they leave open is capped (cap_open_sides)
+    kept_bounds: Dict[str, list] = {}
+    open_side_factor: int = 8  # OPEN_SIDE_FACTOR, below
 
     def __init__(self, ev: Evaluator, variables: Tuple[str, ...],
                  init_ast, next_ast):
@@ -409,12 +414,22 @@ class ShapeInference:
                 # of slack, see typeok_hints); clamping LAST keeps the
                 # widen/clamp pair convergent
                 self.var_shapes[v] = _clamp(self.var_shapes[v], hint)
+            for v, bs in self.kept_bounds.items():
+                self.var_shapes[v] = cap_open_sides(
+                    self.var_shapes[v], bs, self.open_side_factor)
             if self.var_shapes == before:
                 return {v: s for v, s in self.var_shapes.items()}
         raise ShapeError("shape inference did not converge")
 
     def _pass_next(self):
-        env = {v: s for v, s in self.var_shapes.items()}
+        # under a cfg's CONSTRAINT only kept states are expanded: the
+        # read side is the shapes met with the constraint's leaf
+        # bounds, the written side (var_shapes) what one step from a
+        # kept state can hold - a successor outside the constraint has
+        # to be representable as raw fields long enough to be judged
+        kept = self.kept_bounds
+        env = {v: apply_leaf_bounds(s, kept[v]) if v in kept else s
+               for v, s in self.var_shapes.items()}
         self._walk_action(self.next_ast, dict(env))
 
     # -- action walk: collect var' = rhs joins -----------------------------
@@ -867,6 +882,18 @@ class ShapeInference:
             for p, a in zip(d.params, args):
                 env2[p] = self._abstract(a, env)
             return self._abstract(d.body, env2)
+        if name in ("FoldFunctionOnSet", "FoldFunction"):
+            # + or * over a function of integers (eval.fold_args): the
+            # hull over any subset of the keys
+            f = self._abstract(args[2], env)
+            base = self._abstract(args[1], env)
+            vals = [s for _, s, _ in f.fields] if isinstance(f, SRec) \
+                else []
+            if args[0] == ("opsym", "+") and isinstance(base, SInt) \
+                    and vals and all(isinstance(s, SInt) for s in vals):
+                return SInt(base.lo + sum(min(s.lo, 0) for s in vals),
+                            base.hi + sum(max(s.hi, 0) for s in vals))
+            return SInt(-(1 << 30), 1 << 30)
         if name in ("Cardinality", "Len"):
             return SInt(0, 64)
         if name == "Head":
@@ -889,6 +916,9 @@ class ShapeInference:
             return SBool()
         raise ShapeError(f"cannot abstract call {name}")
 
+
+# a hint's "no bound on this side" (Nat's upper side)
+UNBOUNDED = 1 << 30
 
 _INT_THRESHOLDS = (1, 3, 7, 15, 31, 63, 127, 255, 511, 1023, 4095,
                    16383, 65535)
@@ -956,18 +986,31 @@ def typeok_hints(ev: Evaluator, invariants: Dict[str, tuple],
     hit the runtime range trap instead)."""
     hints: Dict[str, Shape] = {}
 
+    def set_shape(v) -> Optional[Shape]:
+        """ELEMENT shape of a constant set value, with int slack.  Nat
+        bounds from below alone, Int and STRING say nothing; a record
+        or function set with such a part (eval.LazySet) is taken field
+        by field, a part that says nothing left out."""
+        if isinstance(v, frozenset):
+            sh = None
+            for x in v:
+                sh = join(sh, shape_of_value(x))
+            return _slack(sh)
+        if v is BUILTIN_SETS["Nat"]:
+            return SInt(-1, UNBOUNDED)
+        if isinstance(v, LazySet):
+            fields = [(f, set_shape(d)) for f, d in v.fields()]
+            if not all(isinstance(f, str) or is_int(f) for f, _ in fields):
+                return None
+            return SRec(tuple(
+                (f, sh, False) for f, sh in fields if sh is not None))
+        return None
+
     def dom_shape(ast) -> Optional[Shape]:
-        """ELEMENT shape of a constant set expression, with int slack."""
         try:
-            v = ev.eval(ast, {})
+            return set_shape(ev.eval(ast, {}))
         except Exception:
             return None
-        if not isinstance(v, frozenset):
-            return None
-        sh = None
-        for x in v:
-            sh = join(sh, shape_of_value(x))
-        return _slack(sh)
 
     def visit(ast):
         if not isinstance(ast, tuple):
@@ -981,12 +1024,18 @@ def typeok_hints(ev: Evaluator, invariants: Dict[str, tuple],
             var = ast[2][1]
             rhs = ast[3]
             if rhs[0] == "funcset":
-                keys_sh = dom_shape(rhs[1])
+                # key by key, without enumerating the function space
+                try:
+                    keys = ev.eval(rhs[1], {})
+                except Exception:
+                    keys = None
                 val_sh = dom_shape(rhs[2])
-                if val_sh is not None and isinstance(keys_sh, SAtoms):
+                if val_sh is not None and isinstance(keys, frozenset) \
+                        and keys and (
+                            all(isinstance(k, str) for k in keys)
+                            or all(is_int(k) for k in keys)):
                     hints[var] = SRec(tuple(
-                        (k, val_sh, False)
-                        for k in sorted(keys_sh.atoms)
+                        (k, val_sh, False) for k in sorted(keys)
                     ))
             else:
                 sh = dom_shape(rhs)
@@ -1007,6 +1056,152 @@ def typeok_hints(ev: Evaluator, invariants: Dict[str, tuple],
     for ast in invariants.values():
         visit(ast)
     return hints
+
+
+class LeafBound(NamedTuple):
+    """One integer leaf a cfg's CONSTRAINT bounds: the variable, the
+    path of keys / fields down to the leaf, the bound on each side
+    (None: that side is not bounded) and the constraint that gives it."""
+
+    var: str
+    path: tuple
+    lo: Optional[int]
+    hi: Optional[int]
+    by: str
+
+
+def constraint_bounds(ev: Evaluator, constraints: Dict[str, tuple],
+                      variables) -> List[LeafBound]:
+    """What the constraints' conjunction says, leaf by leaf, about
+    integer leaves: conjuncts `leaf <= c`, `<`, `>=`, `>`, `=` with a
+    constant side, under `\\A x \\in S` over constant sets, where `leaf`
+    is a variable or a path of constant keys and fields into one
+    (`counter[i]`, `token.q`).  Anything else in a constraint (a
+    disjunction, a sum) bounds no leaf by itself and is left to the
+    predicate.  Every KEPT state lies inside these bounds: the shape
+    inference reads successors' shapes off kept states alone
+    (ShapeInference.run), and the preflight report shows them."""
+    found: Dict[tuple, list] = {}
+
+    def const(ast, env):
+        try:
+            return ev.eval(ast, env)
+        except Exception:
+            return _NOVAL
+
+    def leaf_of(ast, env):
+        path = []
+        while ast[0] == "apply":
+            k = const(ast[2], env)
+            if not (isinstance(k, str) or is_int(k)):
+                return None
+            path.append(k)
+            ast = ast[1]
+        if ast[0] == "name" and ast[1] in variables and ast[1] not in env:
+            return ast[1], tuple(reversed(path))
+        return None
+
+    flip = {"<": ">", ">": "<", "<=": ">=", ">=": "<=", "=": "="}
+
+    def visit(ast, env, by):
+        if not isinstance(ast, tuple) or not ast:
+            return
+        if ast[0] == "and":
+            for x in ast[1]:
+                visit(x, env, by)
+        elif ast[0] == "forall":
+            _, names, dom_ast, body = ast
+            dom = const(dom_ast, env)
+            if isinstance(dom, frozenset) and len(dom) ** len(names) <= 4096:
+                for combo in _product(sorted(dom, key=repr),
+                                      repeat=len(names)):
+                    visit(body, {**env, **dict(zip(names, combo))}, by)
+        elif ast[0] in ("name", "call"):
+            d = ev.defs.get(ast[1])
+            if d is not None and len(d.params) == len(
+                    ast[2] if ast[0] == "call" else ()):
+                args = [const(a, env) for a in (
+                    ast[2] if ast[0] == "call" else ())]
+                if all(a is not _NOVAL for a in args):
+                    visit(d.body, {**env, **dict(zip(d.params, args))}, by)
+        elif ast[0] == "cmp" and ast[1] in flip:
+            for lhs, rhs, sym in ((ast[2], ast[3], ast[1]),
+                                  (ast[3], ast[2], flip[ast[1]])):
+                leaf, c = leaf_of(lhs, env), const(rhs, env)
+                if leaf is None or not is_int(c):
+                    continue
+                lo, hi = {"<": (None, c - 1), "<=": (None, c),
+                          ">": (c + 1, None), ">=": (c, None),
+                          "=": (c, c)}[sym]
+                cur = found.setdefault(leaf, [None, None, by])
+                if lo is not None:
+                    cur[0] = lo if cur[0] is None else max(cur[0], lo)
+                if hi is not None:
+                    cur[1] = hi if cur[1] is None else min(cur[1], hi)
+                break
+
+    for name, ast in constraints.items():
+        visit(ast, {}, name)
+    return [LeafBound(v, path, lo, hi, by)
+            for (v, path), (lo, hi, by) in sorted(
+                found.items(), key=lambda kv: (kv[0][0], repr(kv[0][1])))]
+
+
+_NOVAL = object()
+
+
+def apply_leaf_bounds(sh: Optional[Shape], bounds, path=()):
+    """`sh` met with the LeafBounds of one variable (`bounds`: (path,
+    lo, hi) triples): the shape of the variable over KEPT states."""
+    if isinstance(sh, SInt):
+        lo, hi = sh.lo, sh.hi
+        for p, blo, bhi in bounds:
+            if p == path:
+                lo = lo if blo is None else max(lo, blo)
+                hi = hi if bhi is None else min(hi, bhi)
+        # a leaf the constraint empties here keeps one value: the
+        # abstract pass has then not reached a kept state yet
+        return SInt(min(lo, hi), hi) if lo <= hi else SInt(hi, hi)
+    if isinstance(sh, SRec):
+        return SRec(tuple(
+            (f, apply_leaf_bounds(s, bounds, path + (f,)), o)
+            for f, s, o in sh.fields))
+    return sh
+
+
+OPEN_SIDE_FACTOR = 8
+
+
+def cap_open_sides(sh: Optional[Shape], bounds, factor: int, path=()):
+    """An integer leaf a cfg's CONSTRAINT bounds on ONE side is, in the
+    abstract, unbounded on the other (`counter[i] <= 3` with a
+    decrement no guard of which an interval sees): left alone the
+    widening runs that side to its last threshold, 17 bits a leaf.  It
+    is capped instead at the first threshold at least `factor` times
+    the bounded side's magnitude - a guess, not a proof: the range trap
+    guards it, and a run that trips the trap starts again with the
+    factor 16 times as large (struct.cache.widen_open_sides)."""
+    if isinstance(sh, SInt):
+        lo, hi = sh.lo, sh.hi
+        for p, blo, bhi in bounds:
+            if p != path or (blo is None) == (bhi is None):
+                continue
+            if blo is None:
+                t = factor * max(1, abs(hi))
+                cap = -next((x for x in _INT_THRESHOLDS if x >= t),
+                            _INT_THRESHOLDS[-1]) - 1
+                lo = max(lo, min(cap, hi))
+            else:
+                t = factor * max(1, abs(lo))
+                cap = next((x for x in _INT_THRESHOLDS if x >= t),
+                           _INT_THRESHOLDS[-1])
+                hi = min(hi, max(cap, lo))
+        return SInt(lo, hi)
+    if isinstance(sh, SRec):
+        return SRec(tuple(
+            (f, cap_open_sides(s, bounds, factor, path + (f,)), o)
+            for f, s, o in sh.fields))
+    return sh
 
 
 def _slack(sh: Optional[Shape]) -> Optional[Shape]:
@@ -1112,10 +1307,19 @@ def shape_leq(a: Optional[Shape], b: Optional[Shape]) -> bool:
 
 def infer_shapes(ev: Evaluator, variables, init_ast, next_ast,
                  hints: Optional[Dict[str, Shape]] = None,
-                 const_hints: Optional[Dict[str, Shape]] = None
+                 const_hints: Optional[Dict[str, Shape]] = None,
+                 kept: Optional[List[LeafBound]] = None,
+                 open_side_factor: int = OPEN_SIDE_FACTOR
                  ) -> Dict[str, Shape]:
+    """`kept` (constraint_bounds of a cfg's CONSTRAINT): successors are
+    only ever taken of states inside these leaf bounds, and a leaf they
+    bound on one side is capped on the other (cap_open_sides)."""
     inf = ShapeInference(ev, variables, init_ast, next_ast)
     inf.hints = hints or {}
+    inf.open_side_factor = open_side_factor
+    inf.kept_bounds = {}
+    for b in kept or ():
+        inf.kept_bounds.setdefault(b.var, []).append((b.path, b.lo, b.hi))
     if const_hints:
         inf.const_hints = dict(const_hints)
     return inf.run()

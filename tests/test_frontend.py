@@ -103,3 +103,32 @@ def test_resolve_outside_gen_subset_falls_back_to_struct(tmp_path):
     assert spec.structmodel.system.initial_states() == [(2,)]
     with pytest.raises(ValueError, match="PlusCal-translation subset"):
         resolve(str(tmp_path / "MC.cfg"), frontend="gen")
+
+
+# -- the cfg's keywords that bound a run (ISSUE 39) --------------------------
+
+
+@pytest.mark.parametrize("text,want", [
+    ("CONSTRAINT StateConstraint\n", ["StateConstraint"]),
+    ("CONSTRAINT A B\nINVARIANT I\nCONSTRAINTS\n  C\n", ["A", "B", "C"]),
+    ("CONSTRAINT A \\* the bound\nCONSTRAINT A\n", ["A"]),
+    ("INVARIANT I\n", []),
+])
+def test_constraint_is_a_section_of_names(text, want):
+    from jaxtlc.frontend.mc_cfg import parse_cfg
+
+    cfg = parse_cfg(text)
+    assert cfg.constraints == want
+    assert "StateConstraint" not in cfg.invariants
+
+
+@pytest.mark.parametrize("keyword", ["ACTION_CONSTRAINT", "VIEW",
+                                     "ACTION_CONSTRAINTS"])
+def test_the_refused_keywords_are_refused_by_name(keyword):
+    from jaxtlc.frontend.mc_cfg import CfgError, parse_cfg
+
+    with pytest.raises(CfgError) as e:
+        parse_cfg(f"INVARIANT I\n{keyword} X\n")
+    said = str(e.value)
+    assert said.startswith(f"not supported: {keyword.rstrip('S')}")
+    assert "only CONSTRAINT is honoured" in said

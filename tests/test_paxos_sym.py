@@ -107,8 +107,10 @@ def checked(model, backend, tmp_path_factory):
      lambda c: c.check_deadlock is True and c.specification == "Spec"),
     ("SPECIFICATION Spec\n",
      lambda c: c.symmetry is None and c.check_deadlock is None),
-    ("INVARIANT A\nCONSTRAINT Bound\n", "not supported: CONSTRAINT"),
-    ("INVARIANT A\nCONSTRAINTS\nBound\n", "not supported: CONSTRAINT"),
+    ("INVARIANT A\nCONSTRAINT Bound\n",
+     lambda c: c.constraints == ["Bound"] and c.invariants == ["A"]),
+    ("INVARIANT A\nCONSTRAINTS\nBound Other\nThird\n",
+     lambda c: c.constraints == ["Bound", "Other", "Third"]),
     ("INVARIANT A\nACTION_CONSTRAINT Step\n",
      "not supported: ACTION_CONSTRAINT"),
     ("INVARIANT A\nVIEW v\n", "not supported: VIEW"),
@@ -116,7 +118,7 @@ def checked(model, backend, tmp_path_factory):
     ("SYMMETRY A\nSYMMETRY B\n", "names one definition"),
 ])
 def test_parse_cfg_sections(text, check):
-    """SYMMETRY and CHECK_DEADLOCK are sections; CONSTRAINT,
+    """SYMMETRY, CHECK_DEADLOCK and CONSTRAINT are sections;
     ACTION_CONSTRAINT and VIEW are recognised and refused by name, not
     read as members of the section above them."""
     if isinstance(check, str):
@@ -207,7 +209,7 @@ _SYM_CFG = ("CONSTANTS\na = a\nb = b\nc = c\nRM = {a, b, c}\n"
     ("SYMMETRY Gone\n", "SYMMETRY Gone: no such definition"),
     ("SYMMETRY Inv\n", "SYMMETRY Inv: unknown name 'voted'"),
     ("SYMMETRY Atoms\n", "not a set of functions"),
-    ("CONSTRAINT Inv\n", "not supported: CONSTRAINT"),
+    ("ACTION_CONSTRAINT Inv\n", "not supported: ACTION_CONSTRAINT"),
 ])
 def test_a_symmetry_set_the_loader_cannot_take_is_a_load_error(
         tmp_path, tail, why):

@@ -39,6 +39,11 @@ ROUTES = {
                      frontend="struct", chunk=256, nodeadlock=True,
                      symmetry=True),
                 COMMIT + ("jaxtlc.step.struct", "jaxtlc.canon")),
+    "constrained": (dict(config=os.path.join(
+        REPO, "specs", "EWD998.toolbox", "Model_1", "MC.cfg"),
+        frontend="struct", chunk=256, qcap=1 << 13, fpcap=1 << 15,
+        constants={"N": 2}),
+        COMMIT + ("jaxtlc.step.struct", "jaxtlc.constraint")),
     "mesh": (dict(config=KUBEAPI, frontend="hand", sharded=4, chunk=128,
                   qcap=1 << 11, fpcap=1 << 13,
                   constants=dict(FF, N_RECONCILERS=1, N_BINDERS=1)),
