@@ -411,7 +411,7 @@ def reshard_carry(carry, backend, d_new: int,
         DEFAULT_FP_INDEX, DEFAULT_SEED, fp64_words,
     )
     from ..engine.fpset import host_insert, unmix_host
-    from ..engine.sharded import ShardCarry
+    from ..engine.sharded import ROUTE_STAT_COLS, ShardCarry
 
     import jax.numpy as jnp
 
@@ -460,7 +460,8 @@ def reshard_carry(carry, backend, d_new: int,
     cur_rows, nxt_rows = [], []
     for d in range(d_old):
         qh, qt, le = int(qhead[d]), int(qtail[d]), int(lend[d])
-        live = queue[d, qh:qt]
+        # the queue is a ring of its first queue.shape[1] - 1 rows
+        live = queue[d, (qh + np.arange(qt - qh)) % (queue.shape[1] - 1)]
         ncur = max(0, min(le, qt) - qh)
         cur_rows.append(live[:ncur])
         nxt_rows.append(live[ncur:])
@@ -537,8 +538,15 @@ def reshard_carry(carry, backend, d_new: int,
         extra["obs_expanded"] = row0(carry.obs_expanded)
     if getattr(carry, "route_stat", None) is not None:
         # owner-routing telemetry (fullest bucket, bodies run, insert
-        # segments run): the same maxima on every new row
+        # segments and enqueue blocks run): the same maxima on every
+        # new row
         stat = np.asarray(carry.route_stat)
+        if stat.shape[1] != ROUTE_STAT_COLS:
+            raise ValueError(
+                f"checkpoint leaf 'route_stat' has {stat.shape[1]} "
+                f"columns, this engine's {ROUTE_STAT_COLS} - cut by "
+                "another version"
+            )
         extra["route_stat"] = np.tile(stat.max(axis=0), (d_new, 1))
     return ShardCarry(
         table=table2,
@@ -777,6 +785,12 @@ def run_pod(
                         f"checkpoint leaf {f!r} has no home in this "
                         "engine's carry - meta validation should have "
                         "caught this (corrupt shard?)"
+                    )
+                if arr.shape[1:] != leaf.shape[1:]:
+                    raise ValueError(
+                        f"checkpoint leaf {f!r} shape {arr.shape[1:]} a "
+                        f"device != engine {leaf.shape[1:]} - cut at "
+                        "other capacities, or by another version"
                     )
                 carry = carry._replace(**{f: shard_replace_rows(
                     leaf, {i: arr[k] for k, i in enumerate(ids)}

@@ -239,13 +239,16 @@ class CheckResult(NamedTuple):
     # shapes and the step count (engine.sharded.route_geometry); and
     # the segments of `commit_rows` compacted candidates each device's
     # owner-side insert ran over the check (a body runs as many as
-    # what it received needs: none for nothing, one in the common case)
+    # what it received needs: none for nothing, one in the common case);
+    # and the blocks of `commit_rows` rows each device's enqueue wrote
+    # onto its queue (as many a body as its NEW rows need)
     shard_generated: tuple = None
     route_max_fill: int = None
     route_bucket: int = None
     route_bytes: int = None
     commit_segments: tuple = None
     commit_rows: int = None
+    enqueue_segments: tuple = None
     # struct-compiled step only (telemetry; None elsewhere): the static
     # lane fan of the compiled step (`step_lanes`) and the slots a
     # state keeps of it when it leaves the step (`step_slots`, the
@@ -289,7 +292,7 @@ class CheckResult(NamedTuple):
 
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
                  "route_bucket", "route_bytes", "commit_segments",
-                 "commit_rows")
+                 "commit_rows", "enqueue_segments")
 STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
                  "states_expanded", "lane_fires", "struct_traps",
                  "sym_perms", "sym_sets", "canon_rows", "canon_moved",

@@ -167,21 +167,25 @@ def load_checkpoint(path: str, template: EngineCarry):
                     f"checkpoint {path!r} leaf_{i} CRC mismatch "
                     f"({got} != {want}) - torn write or bit rot"
                 )
-    t_leaves, treedef = jax.tree_util.tree_flatten(template)
-    if len(leaves) != len(t_leaves):
+    t_paths, treedef = jax.tree_util.tree_flatten_with_path(template)
+    if len(leaves) != len(t_paths):
         raise ValueError(
             f"checkpoint has {len(leaves)} leaves, engine expects "
-            f"{len(t_leaves)} - geometry mismatch"
+            f"{len(t_paths)} - geometry mismatch"
         )
-    for got, want in zip(leaves, t_leaves):
+    for got, (path, want) in zip(leaves, t_paths):
+        # the carry's own name for the leaf (`.route_stat`): a snapshot
+        # cut by an engine whose leaf had another shape is refused by it
+        name = jax.tree_util.keystr(path)
         if got.shape != want.shape:
             raise ValueError(
-                f"checkpoint leaf shape {got.shape} != engine {want.shape} "
-                "- was the engine built with different capacities?"
+                f"checkpoint leaf {name} shape {got.shape} != engine "
+                f"{want.shape} - was the engine built with different "
+                "capacities, or by another version?"
             )
         if got.dtype != np.asarray(want).dtype:
             raise ValueError(
-                f"checkpoint leaf dtype {got.dtype} != engine "
+                f"checkpoint leaf {name} dtype {got.dtype} != engine "
                 f"{np.asarray(want).dtype} - corrupt or version-skewed file"
             )
     return meta, jax.tree_util.tree_unflatten(treedef, leaves)

@@ -33,7 +33,9 @@ Capacity ladder note: the sharded engine now HAS a host spill tier
 (SPILL_CAPABLE below, ISSUE 19 closing ROADMAP #1's pinned gap): the
 fused body is split at the owner seam into `expand_half` (pop, expand,
 route, owner-side `fpset_member` filter) and `commit_half` (owner-side
-slab insert, deferred invariants, verdict return, level fences), and
+slab insert, deferred invariants, the new rows compacted and written
+onto the queue's ring as contiguous blocks, verdict return, level
+fences), and
 `ShardedSpillRuntime` drives the two jitted halves from the host with a
 per-host SpillStore probe in between - each host's local device tables
 flush into that host's store at the fp_highwater load, exactly the
@@ -97,6 +99,12 @@ from .fpset import (
 # the frontend -> engine seam now lives in engine.backend (shared with
 # the single-device fused engine); re-exported here for compatibility
 from .backend import SpecBackend, gen_backend, kubeapi_backend  # noqa: F401,E402
+
+
+# columns of ShardCarry.route_stat (below); a snapshot whose leaf has
+# another count was cut by another version and is refused by the
+# leaf's name (checkpoint.load_checkpoint, dist.pod)
+ROUTE_STAT_COLS = 4
 
 
 class ShardCarry(NamedTuple):
@@ -167,8 +175,10 @@ class ShardCarry(NamedTuple):
     # VIOL_ROUTE_OVERFLOW); column 1: bodies run, each of which hands
     # the two all_to_alls their static shapes (route_geometry);
     # column 2: segments this device's owner-side insert has run
-    # (commit_width rows each: over column 1, the trips a body)
-    route_stat: jnp.ndarray = None  # [D, 3] int32
+    # (commit_width rows each: over column 1, the trips a body);
+    # column 3: blocks of as many rows this device's enqueue has
+    # written (enqueue_new_rows: they follow a body's new rows)
+    route_stat: jnp.ndarray = None  # [D, 4] int32
 
 
 class ShardEx(NamedTuple):
@@ -227,13 +237,17 @@ def route_bucket_width(chunk: int, n_lanes: int, D: int,
 def commit_width(chunk: int, D: int, bucket: int) -> int:
     """Rows of one segment of the owner-side insert: the received
     candidates are inserted as a compacted stream, this many at a time
-    (insert_compacted).  One per-device chunk: on the chip a gathered,
+    (insert_compacted), and of one block of the enqueue
+    (enqueue_new_rows).  One per-device chunk: on the chip a gathered,
     scattered or sorted row costs the same live or dead, so what a
-    body's insert and enqueue cost follows their rows, trips x width,
-    and a narrow segment wastes the least of its last trip; the trips
-    themselves cost nothing that shows down to half a chunk (PERF.md
-    section 6, PR 28: a 2x1FF body that received 18k candidates takes
-    19.8 ms at 4 x chunk, 9.2 at one chunk, 8.7 at half)."""
+    body's insert costs follows its rows, trips x width, and a narrow
+    segment wastes the least of its last trip; the trips themselves
+    cost nothing that shows down to half a chunk (PERF.md section 6,
+    PR 28: a 2x1FF body that received 18k candidates takes 19.8 ms at
+    4 x chunk, 9.2 at one chunk, 8.7 at half - numbers of the program
+    whose enqueue scattered every claimant row into the queue, most of
+    what moved there; since PR 40 the enqueue walks a body's new rows
+    and not the claimants, one block in the common case)."""
     return min(chunk, D * bucket)
 
 
@@ -368,7 +382,8 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int,
     claimants of every segment end to end, each segment's up to its
     last new row - received lane (D * B on the rows between) and
     verdict, `c_rows` rows in use of a whole number of segments - for
-    the enqueue and the deferred checker, and the segments run."""
+    the deferred checker (the enqueue reads is_new alone), and the
+    segments run."""
     (D,) = cnt.shape
     DB = r_lo.shape[0]
     bucket = DB // D
@@ -413,6 +428,68 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int,
          jnp.full(cap, DB, jnp.int32), jnp.zeros(cap, bool),
          jnp.int32(0)))
     return table, is_new, c_lane, c_new, c_rows, trips
+
+
+def enqueue_new_rows(queue, r_flat, is_new, qtail, n_go, width: int):
+    """The new rows of one body onto the ring: `queue` is [qcap + 1, F]
+    (the last row is the old scatter's dump row and is never written),
+    `r_flat` [D * B, F] the received rows, `is_new` [D * B], `qtail`
+    the tail before the body and `n_go` the rows to write: is_new's
+    count, or 0 where the body halts on a full queue, which then
+    leaves every row as it was.  Row `(qtail + k) % qcap` takes the
+    k-th new lane in ascending lane order, what
+    `pos = qtail + cumsum(is_new) - 1` gave the scatter this replaces.
+
+    A compaction and contiguous writes, as engine.bfs's enqueue: one
+    single-operand sort at D * B lanes brings the new lanes to the
+    front in order; then `width` rows a trip, as many trips as the new
+    rows need and not as the claimants, one row gather each and two
+    slice writes, in place in the loop whose carry is the queue - no
+    conditional holds it and nothing indexes it by row.  A block lies
+    anywhere on the ring: the trip's lanes are rotated BEFORE the
+    gather so that block row i already holds the row of ring position
+    `start + i` (or, where the block passes row qcap - 1, of position
+    i: what wrapped), and the two writes - at `start`, moved back to
+    keep the block inside [0, qcap), and at row 0 - select with
+    complementary masks against the slices read back from the same
+    places, so a lane past the new rows, or on the other side of the
+    seam, changes nothing.
+
+    Returns (queue, trips)."""
+    qcap = queue.shape[0] - 1
+    F = queue.shape[1]
+    (DB,) = is_new.shape
+    A = min(width, qcap)
+    lane = jnp.arange(DB, dtype=jnp.uint32)
+    # the keys differ, so the sort need not be stable (a stable one
+    # carries a second operand on the chip to break its ties)
+    e_lane = lax.sort(jnp.where(is_new, lane, lane + jnp.uint32(DB)),
+                      is_stable=False)
+    e_lane = jnp.concatenate([e_lane, jnp.zeros(A, jnp.uint32)])
+    i = jnp.arange(A, dtype=jnp.int32)
+
+    def write_block(st):
+        s, q = st
+        offs = s * A
+        p = (qtail + offs) % qcap
+        # rows of the block in front of the ring's end; the rest wraps
+        t = jnp.minimum(qcap - p, A)
+        idx = lax.dynamic_slice(e_lane, (offs,), (A,))
+        idx = lax.dynamic_slice(jnp.concatenate([idx, idx]), (t,), (A,))
+        rows = r_flat[jnp.minimum(idx, DB - 1).astype(jnp.int32)]
+        # block row i holds new row (i + t) % A of this trip
+        wrapped = i < A - t
+        live = offs + (i + t) % A < n_go
+        for start, mask in ((p - (A - t), live & ~wrapped),
+                            (jnp.int32(0), live & wrapped)):
+            old = lax.dynamic_slice(q, (start, 0), (A, F))
+            q = lax.dynamic_update_slice(
+                q, jnp.where(mask[:, None], rows, old), (start, 0))
+        return s + 1, q
+
+    trips, queue = lax.while_loop(
+        lambda st: st[0] * A < n_go, write_block, (jnp.int32(0), queue))
+    return queue, trips
 
 
 def route_geometry(backend: SpecBackend, chunk: int, D: int,
@@ -488,8 +565,14 @@ def make_sharded_engine(
     received batch holds its live rows as a prefix, so the candidates
     are inserted as a compacted stream, commit_width (= one per-device
     chunk) rows a segment behind a trip count; every gather, scatter
-    and sort of the insert, and the enqueue and deferred checker that
-    walk its claimants, are that wide.  The highest segment goes first,
+    and sort of the insert, and the deferred checker that walks its
+    claimants, are that wide.  The enqueue walks neither the slots nor
+    the claimants but what is new (enqueue_new_rows): one sort of the
+    D*B verdicts brings the new lanes to the front in lane order, and
+    their rows go onto the ring as blocks of the same width, a row
+    gather and two contiguous slice writes each, as many blocks as the
+    new rows need (`enqueue_segments`, route_stat[:, 3]: one a body in
+    the common case).  The highest segment goes first,
     which keeps the dedup's highest-lane representative across
     segments: counts, queue rows, per-action and outdegree statistics
     are bit-for-bit those of one insert over all D*B lanes, and the
@@ -580,8 +663,8 @@ def make_sharded_engine(
             [a in red.safe_ids for a in range(n_labels)], bool
         ))
     # the owner inserts what it received as a compacted stream, W rows
-    # at a time (commit_half); W is also the width the enqueue and the
-    # deferred checker walk the insert's claimants at
+    # at a time (commit_half); W is also the width the deferred checker
+    # walks the insert's claimants at, and of a block of the enqueue
     DB = D * B
     W = commit_width(chunk, D, B)
     # owner-side deferred invariant checker (ISSUE 15)
@@ -675,7 +758,7 @@ def make_sharded_engine(
             viol_state=jnp.zeros((D, F), jnp.int32),
             viol_local=jnp.zeros(D, bool),
             cont=jnp.ones(D, bool),
-            route_stat=jnp.zeros((D, 3), jnp.int32),
+            route_stat=jnp.zeros((D, ROUTE_STAT_COLS), jnp.int32),
             **pv,
             **obs,
         )
@@ -915,24 +998,15 @@ def make_sharded_engine(
         with jax.named_scope("jaxtlc.enqueue"):
             n_new = is_new.sum().astype(jnp.int32)
             q_full = (qtail - qhead) + n_new > qcap
-            pos = qtail + jnp.cumsum(is_new.astype(jnp.int32)) - 1
-
-            # only the insert's claimants can be new: write those rows
-            # alone, each at its lane's position, W at a time.  A
-            # scattered row costs the chip ~140 ns live or not, and all
-            # D * B lanes aimed mostly at the dump row were half of a
-            # 2x1FF step (PERF.md section 5, Step 0 of PR 27)
-            def enqueue_segment(st):
-                k, q = st
-                lane = jnp.minimum(
-                    lax.dynamic_slice(c_lane, (k * W,), (W,)), DB - 1)
-                new = lax.dynamic_slice(c_new, (k * W,), (W,))
-                tgt = jnp.where(new & ~q_full, pos[lane] % qcap, qcap)
-                return k + 1, q.at[tgt].set(r_flat[lane])
-
-            _, queue = lax.while_loop(
-                lambda st: st[0] * W < c_rows, enqueue_segment,
-                (jnp.int32(0), queue))
+            # the new rows in lane order from the tail on, as a
+            # compaction and contiguous writes, W rows a trip: the
+            # trips follow what is new, not the insert's claimants (a
+            # row scattered into the queue cost the chip ~140 ns live
+            # or aimed at the dump row: 6.3 ms of a 2x1FF body for
+            # ~11.5k new rows, PERF.md section 5, PR 37 and PR 40)
+            queue, enq_trips = enqueue_new_rows(
+                queue, r_flat, is_new, qtail,
+                jnp.where(q_full, 0, n_new), W)
 
         # ---- route verdicts back to the source (second all_to_all) ----
         # back[d, p] = is_new of the candidate this device placed in bucket
@@ -1127,6 +1201,7 @@ def make_sharded_engine(
             jnp.maximum(c.route_stat[0, 0], ex.route_fill),
             c.route_stat[0, 1] + 1,
             c.route_stat[0, 2] + trips,
+            c.route_stat[0, 3] + enq_trips,
         ])
 
         return ShardCarry(
@@ -1604,6 +1679,7 @@ def result_from_shard_carry(
             # every device runs every body: column 1 is the same on all
             route_bytes=int(stat[:, 1].max()) * int(route["step_bytes"]),
             commit_segments=tuple(int(v) for v in stat[:, 2]),
+            enqueue_segments=tuple(int(v) for v in stat[:, 3]),
             commit_rows=int(route["commit_rows"]),
         )
     act_gen = np.asarray(out.act_gen).sum(axis=0)[: len(labels)]

@@ -322,8 +322,9 @@ def migrate_shard_carry(
         )
     if getattr(carry, "route_stat", None) is not None:
         # owner-routing telemetry: the fullest bucket is counted in
-        # candidates, not slots, and the insert's segments in chunks,
-        # so both survive a route_factor change
+        # candidates, not slots, and the insert's segments and the
+        # enqueue's blocks in chunks, so all survive a route_factor
+        # change
         pv["route_stat"] = jnp.asarray(
             np.asarray(carry.route_stat), jnp.int32
         )

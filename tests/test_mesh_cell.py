@@ -22,6 +22,7 @@ from jaxtlc.engine.sharded import (
     commit_width,
     compact_lanes,
     compact_rows,
+    enqueue_new_rows,
     make_sharded_engine,
     masked_hist,
     owner_counts,
@@ -156,9 +157,13 @@ def test_journal_records_the_process_set_and_the_counts(
     # this rung's ~2 candidates a popped state)
     assert final["commit_rows"] == r.commit_rows == GEOM["chunk"]
     assert final["commit_segments"] == list(r.commit_segments)
+    assert final["enqueue_segments"] == list(r.enqueue_segments)
     bodies = r.route_bytes // route_geometry(
         kubeapi_backend(FF), GEOM["chunk"], 4, 2.0)["step_bytes"]
     assert all(0 < s <= 2 * bodies for s in r.commit_segments)
+    # the enqueue's blocks follow what is NEW: under 128 rows a body
+    # here, so one block in every body that found a new state
+    assert all(0 < s <= bodies for s in r.enqueue_segments)
 
 
 # -- (c) the shares add up ------------------------------------------------
@@ -486,7 +491,7 @@ def test_commit_counters_cross_a_regrow_and_a_reshard(mesh_run):
 
     carry, _ = mesh_run
     stat = np.asarray(carry.route_stat)
-    assert stat.shape == (4, 3) and (stat[:, 2] > 0).all()
+    assert stat.shape == (4, 4) and (stat[:, 2:] > 0).all()
     old = dict(queue_capacity=GEOM["queue_capacity"],
                fp_capacity=GEOM["fp_capacity"], route_factor=2.0)
     grown = migrate_shard_carry(carry, old, dict(
@@ -495,12 +500,256 @@ def test_commit_counters_cross_a_regrow_and_a_reshard(mesh_run):
     r = result_from_shard_carry(
         grown, 1.0, route=route_geometry(kubeapi_backend(FF), 128, 4, 4.0))
     assert r.commit_segments == tuple(int(v) for v in stat[:, 2])
+    assert r.enqueue_segments == tuple(int(v) for v in stat[:, 3])
     assert r.commit_rows == 128
     halved = reshard_carry(
         jax.tree.map(np.asarray, carry), kubeapi_backend(FF), 2)
     # a pod's new rows all start from the old pod's maxima
     assert (np.asarray(halved.route_stat) == stat.max(axis=0)).all()
-    assert np.asarray(halved.route_stat).shape == (2, 3)
+    assert np.asarray(halved.route_stat).shape == (2, 4)
+
+
+# -- (g) the enqueue: a compaction and contiguous writes onto the ring ----
+
+# the block alone: W rows a trip onto a ring of QR rows (+ the dump
+# row), D x B = 64 received lanes of 3 words
+EQ_W, EQ_QCAP, EQ_DB, EQ_F = 8, 40, 64, 3
+
+
+def scatter_replay(queue, r_flat, is_new, qtail, go):
+    """The parent's rule in numpy: lane l, where new, goes to row
+    (qtail + cumsum(is_new)[l] - 1) % qcap; a halting body writes
+    nothing."""
+    q = np.array(queue)
+    qcap = q.shape[0] - 1
+    if go:
+        pos = qtail + np.cumsum(is_new.astype(np.int32)) - 1
+        q[pos[is_new] % qcap] = r_flat[is_new]
+    return q
+
+
+enqueue_block = jax.jit(enqueue_new_rows, static_argnames="width")
+
+
+# (new rows, tail before the body): the ring's last row is 39
+ENQUEUE_CASES = {
+    "nothing-new": (0, 5),
+    "one": (1, 5),
+    "a-row-short-of-a-block": (EQ_W - 1, 5),
+    "a-block": (EQ_W, 5),
+    "a-block-and-a-row": (EQ_W + 1, 5),
+    "several-trips": (3 * EQ_W + 3, 2),
+    "ends-on-the-last-row": (EQ_W, EQ_QCAP - EQ_W),
+    "wraps-in-the-first-row": (EQ_W + 2, EQ_QCAP - 1),
+    "wraps-in-a-middle-row": (EQ_W + 2, EQ_QCAP - 3),
+    "wraps-in-the-last-row": (EQ_W, EQ_QCAP - EQ_W + 1),
+    "wraps-in-the-second-trip": (2 * EQ_W + 5, EQ_QCAP - EQ_W - 4),
+    "fills-the-ring": (EQ_QCAP, 17),
+    "a-tail-many-laps-on": (EQ_W + 3, 7 * EQ_QCAP + EQ_QCAP - 2),
+    "every-lane-new": (EQ_DB, 0),  # past the ring: only with go False
+}
+
+
+@pytest.mark.parametrize("n_new,qtail,go", [
+    pytest.param(n, t, go, id=f"{name}-{'go' if go else 'queue-full'}")
+    for name, (n, t) in ENQUEUE_CASES.items() for go in (True, False)
+    if n <= EQ_QCAP or not go])
+def test_enqueue_block_is_the_scatter_it_replaces(n_new, qtail, go):
+    """Rows [0, qcap) after the block equal the parent's per-lane
+    scatter, wherever the block lies on the ring; a body that halts on
+    a full queue leaves them all; the trips follow the new rows."""
+    rng = np.random.default_rng(n_new * 1000 + qtail)
+    is_new = np.zeros(EQ_DB, bool)
+    is_new[rng.choice(EQ_DB, n_new, replace=False)] = True
+    r_flat = rng.integers(1, 1 << 20, (EQ_DB, EQ_F)).astype(np.int32)
+    queue = -rng.integers(1, 1 << 20, (EQ_QCAP + 1, EQ_F)).astype(np.int32)
+    got, trips = enqueue_block(
+        queue, r_flat, is_new, np.int32(qtail),
+        np.int32(n_new if go else 0), width=EQ_W)
+    want = scatter_replay(queue, r_flat, is_new, qtail, go)
+    assert (np.asarray(got)[:EQ_QCAP] == want[:EQ_QCAP]).all()
+    # the dump row has fallen idle
+    assert (np.asarray(got)[EQ_QCAP] == queue[EQ_QCAP]).all()
+    assert int(trips) == (-(-n_new // EQ_W) if go else 0)
+
+
+def test_enqueue_block_wider_than_the_ring():
+    """A ring shorter than a segment (tiny geometries) takes blocks of
+    its own length."""
+    rng = np.random.default_rng(7)
+    qcap, n_new, qtail = 6, 5, 4
+    is_new = np.zeros(EQ_DB, bool)
+    is_new[rng.choice(EQ_DB, n_new, replace=False)] = True
+    r_flat = rng.integers(1, 99, (EQ_DB, EQ_F)).astype(np.int32)
+    queue = np.zeros((qcap + 1, EQ_F), np.int32)
+    got, trips = enqueue_block(
+        queue, r_flat, is_new, np.int32(qtail), np.int32(n_new),
+        width=EQ_W)
+    assert (np.asarray(got) == scatter_replay(
+        queue, r_flat, is_new, qtail, True)).all()
+    assert int(trips) == 1
+
+
+# a ring short enough to turn over six to fifteen times in the check, and
+# NARROW rows a block, so that bodies take several
+RING = 192
+
+
+@pytest.fixture(scope="module")
+def ring_engine():
+    with segments_of(NARROW):
+        return make_sharded_engine(
+            FF, fp_mesh(4), segment=1, **dict(GEOM, queue_capacity=RING))
+
+
+@pytest.fixture(scope="module")
+def ring_run(ring_engine):
+    """The check a body at a time: every carry but the queue's and the
+    table's own words (tails and counters a body), and the last."""
+    init_fn, step_fn = ring_engine
+    carry = init_fn()
+    tails, stats, mid = [np.asarray(carry.qtail)], [], None
+    while bool(np.asarray(carry.cont).any()):
+        carry = jax.block_until_ready(step_fn(carry))
+        tails.append(np.asarray(carry.qtail))
+        stats.append(np.asarray(carry.route_stat))
+        if len(stats) == 60:
+            mid = carry
+    return carry, np.stack(tails), np.stack(stats), mid
+
+
+def test_a_ring_that_wraps_gives_the_same_check(
+        ring_run, mesh_run, reference_1x1):
+    carry, tails, _, _ = ring_run
+    ref, wide = reference_1x1, mesh_run[0]
+    r = result_from_shard_carry(
+        carry, 1.0, labels=kubeapi_backend(FF).labels)
+    assert (r.generated, r.distinct, r.depth, r.queue_left, r.violation
+            ) == (ref.generated, ref.distinct, ref.depth, 0, 0)
+    # the ring turned over many times on every device ...
+    assert (tails[-1] > 6 * RING).all()
+    # ... and the statistics are the long queue's (the parent's, by
+    # its digest above) bit for bit: same rows in the same order
+    for name in ("act_gen", "act_dist", "outdeg_hist", "qtail", "qhead",
+                 "distinct", "generated"):
+        assert (np.asarray(getattr(carry, name))[..., :-1]
+                == np.asarray(getattr(wide, name))[..., :-1]).all(), name
+    # what the ring still holds is what the long queue holds at the
+    # same positions (it turned over once itself, by a few rows)
+    long_q, ring_q = np.asarray(wide.queue), np.asarray(carry.queue)
+    for d in range(4):
+        pos = np.arange(tails[-1][d] - RING, tails[-1][d])
+        assert (ring_q[d][pos % RING]
+                == long_q[d][pos % GEOM["queue_capacity"]]).all()
+
+
+def test_enqueue_segments_against_the_hand_count(ring_run):
+    """Blocks of NARROW rows, as many a body as its NEW rows need:
+    the tails say how many those were."""
+    _, tails, stats, _ = ring_run
+    new = np.diff(tails, axis=0)  # [bodies, 4]
+    assert (new > 2 * NARROW).any()  # bodies of three blocks and more
+    want = np.cumsum(-(-new // NARROW), axis=0)
+    assert (stats[:, :, 3] == want).all()
+    # the insert's segments follow what arrived, so they are more
+    assert (stats[-1, :, 2] > stats[-1, :, 3]).all()
+
+
+def test_a_full_queue_halts_and_leaves_the_ring(ring_engine, ring_run):
+    """The body that finds no room writes no row of [0, qcap), moves no
+    tail and halts the run by name."""
+    from jaxtlc.engine.bfs import VIOL_QUEUE_FULL
+
+    _, step_fn = ring_engine
+    mid = ring_run[3]
+    # stale rows counted in behind the tail, up to a full ring: any
+    # new row is one too many, and the pop still takes the frontier
+    full = mid._replace(qtail=mid.qhead + RING)
+    halted = jax.block_until_ready(step_fn(full))
+    assert (np.asarray(halted.viol) == VIOL_QUEUE_FULL).all()
+    assert not np.asarray(halted.cont).any()
+    assert (np.asarray(halted.queue)[:, :RING]
+            == np.asarray(full.queue)[:, :RING]).all()
+    assert (np.asarray(halted.qtail) == np.asarray(full.qtail)).all()
+    assert (np.asarray(halted.route_stat)[:, 3]
+            == np.asarray(full.route_stat)[:, 3]).all()
+    # the same body with room enqueues
+    went = jax.block_until_ready(step_fn(mid))
+    assert (np.asarray(went.qtail) > np.asarray(mid.qtail)).any()
+
+
+def test_a_reshard_reads_the_ring_where_it_has_wrapped(ring_run):
+    """A pod's reshard takes the live window off the ring, not off rows
+    [qhead, qtail) of a queue that long."""
+    from jaxtlc.dist.pod import reshard_carry
+
+    mid = jax.tree.map(np.asarray, ring_run[3])
+    assert (mid.qtail > RING).all()  # every ring has turned over
+    want = np.concatenate([
+        mid.queue[d][np.arange(mid.qhead[d], mid.qtail[d]) % RING]
+        for d in range(4)])
+    halved = reshard_carry(mid, kubeapi_backend(FF), 2)
+    got = np.concatenate([
+        np.asarray(halved.queue)[d][:int(np.asarray(halved.qtail)[d])]
+        for d in range(2)])
+    assert len(got) == len(want) > 0
+    assert sorted(map(tuple, got)) == sorted(map(tuple, want))
+
+
+def test_no_scatter_holds_the_queue():
+    """The lowered body writes the queue by dynamic_update_slice alone:
+    no scatter's operand has the queue's shape, on any composition of
+    commit_half, and jaxtlc.enqueue holds none at all."""
+    F = kubeapi_backend(FF).cdc.n_fields
+    qshape = (GEOM["queue_capacity"] + 1, F)
+
+    def scatters(fn, *args):
+        traced = jax.make_jaxpr(fn)(*args)
+        out = []
+        for stack, eqn in scoped_eqns(traced.jaxpr):
+            if eqn.primitive.name.startswith("scatter"):
+                out.append((stack, eqn.invars[0].aval.shape))
+        return out
+
+    found = []
+    # fused, pipelined, the slab and deferred paths, and the one-device
+    # mesh, where B is all of ncand
+    for D, kw in ((4, {}), (4, dict(pipeline=True)), (4, WIDE), (1, {})):
+        init_fn, seg_fn = make_sharded_engine(
+            FF, fp_mesh(D), segment=16, **GEOM, **kw)
+        found += scatters(seg_fn, init_fn())
+    from jaxtlc.engine.sharded import ShardedSpillRuntime
+
+    rt = ShardedSpillRuntime(FF, fp_mesh(4), **GEOM)
+    carry = rt.init_fn()
+    ex = rt._expand_fn(carry)
+    found += scatters(rt._commit_fn, carry, ex,
+                      np.zeros((4, rt._DB), bool))
+    assert found  # the table's and is_new's are there
+    assert not [f for f in found if f[1][-2:] == qshape], found
+    assert not [f for f in found if "jaxtlc.enqueue" in f[0]], found
+
+
+def test_a_snapshot_from_before_the_column_is_refused_by_name(
+        mesh_run, tmp_path):
+    """route_stat grew a column with enqueue_segments: a checkpoint cut
+    by the engine before (three columns) does not resume, and the
+    refusal names the leaf - whole-carry snapshots and a pod's reshard
+    alike."""
+    from jaxtlc.dist.pod import reshard_carry
+    from jaxtlc.engine.checkpoint import load_checkpoint, save_checkpoint
+
+    carry = jax.tree.map(np.asarray, mesh_run[0])
+    old = carry._replace(route_stat=carry.route_stat[:, :3])
+    path = str(tmp_path / "old.npz")
+    save_checkpoint(path, old, {})
+    with pytest.raises(ValueError, match=r"\.route_stat shape \(4, 3\)"):
+        load_checkpoint(path, carry)
+    with pytest.raises(ValueError, match="'route_stat' has 3 columns"):
+        reshard_carry(old, kubeapi_backend(FF), 2)
+    save_checkpoint(path, carry, {})
+    _, back = load_checkpoint(path, carry)
+    assert (np.asarray(back.route_stat) == carry.route_stat).all()
 
 
 # -- (f) the source side without per-element indexing at candidate width --
