@@ -688,7 +688,7 @@ def _preflight(args, log, build_report):
     return None
 
 
-def _sup_opts(args, log, capture_fps: bool = False):
+def _sup_opts(args, log, capture_fps: bool = False, finish=None):
     """SupervisorOptions from the request.  Every supervisor event is
     written to the run journal FIRST (the single source of truth), then
     the TLC-style banner is rendered as a derived view of that journal
@@ -725,6 +725,7 @@ def _sup_opts(args, log, capture_fps: bool = False):
         capture_fps=capture_fps,
         on_event=on_event,
         drain=getattr(args, "drain", None),
+        finish=finish,
     )
 
 
@@ -1265,12 +1266,32 @@ def _run_check_struct(args, spec) -> int:
                 reason="a state fired more lanes than the compacted "
                        "step keeps; the check starts again"))
 
+    qcap = []
+
+    def queue_capacity():
+        """-qcap, or the power of two that holds Init where Init alone
+        is wider (EWD840's is 2^(2N) N states: the engine's init_fn
+        seats them in one level buffer before any segment could ask the
+        ladder for room): the ladder's regrow rung taken before the
+        build, said as one `regrow` event.  Under -no-auto-grow the
+        engine's own refusal stands."""
+        if not qcap:
+            n0 = system.initial_count()
+            qcap.append(args.qcap)
+            if n0 > args.qcap and args.autogrow:
+                qcap[0] = 1 << (n0 - 1).bit_length()
+                _sup_opts(args, log_holder[0]).on_event("regrow", dict(
+                    resource="queue_capacity", old=args.qcap, new=qcap[0],
+                    violation="initial states exceed the queue",
+                    regrows=0, seconds=0.0))
+        return qcap[0]
+
     def check_once():
         log = log_holder[0]
         ckd = spec.check_deadlock
         cov = args.coverage
         sym, por = _symmetry(args), _por(args)
-        kw = dict(chunk=args.chunk, queue_capacity=args.qcap,
+        kw = dict(chunk=args.chunk, queue_capacity=queue_capacity(),
                   fp_capacity=args.fpcap)
         if args.sharded:
             from .runtime import fp_mesh
@@ -1290,7 +1311,7 @@ def _run_check_struct(args, spec) -> int:
                     obs_slots=_obs_slots(args),
                     sort_free=args.sortfree,
                     deferred=args.deferredinv,
-                    opts=_sup_opts(args, log), **kw,
+                    opts=_sup_opts(args, log, finish=liveness), **kw,
                 )
                 return sup.result, sup
             return check_struct_sharded(
@@ -1315,7 +1336,8 @@ def _run_check_struct(args, spec) -> int:
                 obs_slots=_obs_slots(args),
                 sort_free=args.sortfree,
                 deferred=args.deferredinv,
-                opts=_sup_opts(args, log, capture_fps=capture), **kw,
+                opts=_sup_opts(args, log, capture_fps=capture,
+                               finish=liveness), **kw,
             )
             return sup.result, sup
         return check_struct(
@@ -1388,21 +1410,97 @@ def _run_check_struct(args, spec) -> int:
             safe_actions=len(red.safe_ids),
         )
 
+    def liveness(r):
+        """The cfg's PROPERTY lines, once the safety search is clean
+        (SupervisorOptions.finish on the supervised routes, else right
+        after the check): `P ~> Q` under the fairness the SPECIFICATION
+        formula states (sm.fairness), on the device route
+        (live.check.check_struct_properties, sized from what `r` has
+        just counted) - or through struct.oracle where the flags ask
+        for the host, the run was reduced (its counts are not the
+        graph's) or the relation does not fit the device.  One
+        `liveness` journal event a property; the results wait on the
+        kit for the transcript.  Returns (r with the route's counters,
+        "liveness_violation" | None)."""
+        if kit.live_results is not None or not spec.properties \
+                or r.violation != 0 or r.queue_left:
+            return r, None
+        from .live.check import (
+            LIVE_COUNTERS, LiveTooLarge, check_struct_properties)
+
+        listed = list(props())
+        todo = [(name, p_ast, q_ast)
+                for name, p_ast, q_ast, skip in listed if skip is None]
+        route = "device"
+        why_host = ("-liveness-host" if args.liveness_host else
+                    "a reduced run's counts are not the graph's"
+                    if _symmetry(args) or _por(args) else None)
+        found = None
+        if why_host is None and todo:
+            try:
+                with span("live"):
+                    found = check_struct_properties(
+                        sm, get_backend(sm, spec.check_deadlock,
+                                        bounds=bounds,
+                                        coverage=args.coverage),
+                        todo, n_states=r.distinct,
+                        n_edges=r.generated - system.initial_count(),
+                        chunk=args.chunk, fp_capacity=args.fpcap,
+                        fp_index=spec.fp_index)
+            except LiveTooLarge as e:
+                why_host = str(e)
+        if found is None:
+            route = "host"
+            if why_host and log_holder:
+                log_holder[0].msg(
+                    1000, f"Temporal properties on the host: {why_host}.")
+            found = [so.check_leads_to(system, p, q, name,
+                                       fairness=sm.fairness)
+                     for name, p, q in todo]
+        by_name = {res.name: res for res in found}
+        kit.live_results = [(name, skip, by_name.get(name))
+                            for name, _p, _q, skip in listed]
+        kit.live_route = route
+        j = getattr(args, "_journal", None)
+        fairness = [[a, list(labels)] for a, labels in sm.fairness]
+        for res in found:
+            counters = getattr(res, "counters", None) or {}
+            if j is not None:
+                j.event("liveness", property=res.name,
+                        holds=bool(res.holds), route=route,
+                        fairness=fairness,
+                        **{f"live_{k}": counters[k] for k in LIVE_COUNTERS
+                           if k in counters})
+        if route == "device" and found:
+            once = ("states", "edges", "changed_edges", "edge_bytes")
+            r = r._replace(**{
+                f"live_{k}": found[0].counters[k] if k in once
+                else sum(res.counters[k] for res in found)
+                for k in LIVE_COUNTERS})
+        violated = any(not res.holds for res in found)
+        return r, ("liveness_violation" if violated else None)
+
+    stated = " /\\ ".join(f"WF_vars({a})" for a, _ in sm.fairness)
     kit = _InterpKit(
         kind="structural",
-        # the structural liveness graph is wf_next-only so far
+        # the struct route takes its fairness from the SPECIFICATION
+        # formula; -fairness is the hand path's flag
         extra_unsupported=(
-            ("-fairness wf_process", args.fairness == "wf_process"),
+            ("-fairness wf_process (a struct spec is judged under the "
+             "fairness its SPECIFICATION formula states"
+             + (f": {stated})" if stated else ": none)"),
+             args.fairness == "wf_process"),
         ),
+        liveness=liveness,
         check=check,
         # lazy: Init enumeration is real work on struct specs and must
         # not run when the flags are about to be rejected
-        init_count=lambda: len(system.initial_states()),
+        init_count=system.initial_count,
         properties=props,
         check_leads_to=lambda name, p, q, **_kw: so.check_leads_to(
-            system, p, q, name
+            system, p, q, name, fairness=sm.fairness
         ),
-        fairness_label="wf_next",
+        fairness_label=stated or "none",
         state_to_tla=lambda st: so.state_to_tla(system, st),
         state_env=lambda st: so.state_env(system, st),
         violation_trace=lambda: so.violation_trace(
@@ -1943,8 +2041,17 @@ class _InterpKit:
                  state_to_tla, state_env, violation_trace,
                  coverage=None, action_order=None, preflight=None,
                  coverage_device=None, dead_site_lint=None,
-                 artifact_plan=None, reduce_info=None, constraints=()):
+                 artifact_plan=None, reduce_info=None, constraints=(),
+                 liveness=None):
         self.kind = kind
+        # (r) -> (r, "liveness_violation" | None): the temporal
+        # properties, run once a clean safety verdict is in hand and
+        # before the `final` event (the struct route); its results
+        # [(name, skip reason | None, result | None), ...] and route
+        # wait here for the transcript.  None: check_leads_to, after
+        self.liveness = liveness
+        self.live_results = None
+        self.live_route = None
         # the cfg's CONSTRAINT names (run_start.params names them)
         self.constraints = constraints
         self.extra_unsupported = extra_unsupported
@@ -2048,6 +2155,7 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
                 kit.check_leads_to = (
                     lambda name, p, q, **_kw: _PropertyHolds()
                 )
+                kit.liveness = None
     if kit.preflight is not None and cache_tier != "verdict":
         rc = _preflight_gate(args, log, kit.preflight)
         if rc is not None:
@@ -2066,6 +2174,12 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
         print(f"Error: {e}", file=_err(args))
         _finish_journal(args, log)
         return 1
+    if kit.liveness is not None and not (
+            sup is not None and (sup.interrupted
+                                 or getattr(sup, "exhausted", False))):
+        # the supervised routes ran it before their `final` event
+        # (SupervisorOptions.finish); any other, here
+        r, _ = kit.liveness(r)
     args._result = r
     n_init = kit.init_count()
     log.init_done(n_init)
@@ -2177,6 +2291,22 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
     return 13 if liveness_violated else 0
 
 
+def _render_lasso(log, kit, name, res) -> None:
+    """A violated temporal property's transcript: the prefix, then the
+    cycle."""
+    log.msg(2116, f"Temporal properties were violated: {name}",
+            severity=1)
+    idx = 1
+    for st in res.lasso_prefix:
+        log.trace_state(idx, None, kit.state_to_tla(st))
+        idx += 1
+    log.msg(1000, "-- The following states form a cycle "
+                  "(back to the first of them) --")
+    for st in res.lasso_cycle:
+        log.trace_state(idx, None, kit.state_to_tla(st))
+        idx += 1
+
+
 def _render_verdict_interp(args, spec, kit, log, r, n_init, t0):
     """The interpreted frontends after the engine: temporal properties,
     the violation trace or the success and coverage report, final
@@ -2184,37 +2314,37 @@ def _render_verdict_interp(args, spec, kit, log, r, n_init, t0):
     violated = r.violation != 0
     liveness_violated = False
     if not violated and spec.properties:
-        from .live.check import use_device_path
+        if kit.live_results is not None:
+            # judged before the `final` event (the struct route)
+            route = kit.live_route
+        else:
+            from .live.check import use_device_path
 
-        log.checking_temporal(
-            r.distinct,
-            "device" if kit.kind == "generic" and use_device_path(
+            route = "device" if kit.kind == "generic" and use_device_path(
                 r.distinct, args.fairness, args.liveness_host
-            ) else "host",
-        )
-        for name, p_ast, q_ast, skip in kit.properties():
+            ) else "host"
+
+        def judged():
+            if kit.live_results is not None:
+                yield from kit.live_results
+                return
+            for name, p_ast, q_ast, skip in kit.properties():
+                yield name, skip, (None if skip is not None else
+                                   kit.check_leads_to(name, p_ast, q_ast,
+                                                      distinct=r.distinct))
+
+        log.checking_temporal(r.distinct, route)
+        for name, skip, res in judged():
             if skip is not None:
                 log.msg(1000, f"Temporal property {name} skipped: "
                               f"{skip}.", severity=1)
                 continue
-            res = kit.check_leads_to(name, p_ast, q_ast,
-                                     distinct=r.distinct)
             if res.holds:
                 log.msg(1000, f"Temporal property {name} holds "
                               f"(fairness: {kit.fairness_label}).")
                 continue
             liveness_violated = True
-            log.msg(2116, f"Temporal properties were violated: {name}",
-                    severity=1)
-            idx = 1
-            for st in res.lasso_prefix:
-                log.trace_state(idx, None, kit.state_to_tla(st))
-                idx += 1
-            log.msg(1000, "-- The following states form a cycle "
-                          "(back to the first of them) --")
-            for st in res.lasso_cycle:
-                log.trace_state(idx, None, kit.state_to_tla(st))
-                idx += 1
+            _render_lasso(log, kit, name, res)
     if violated:
         log.msg(2110 if r.violation >= 100 else 1000,
                 r.violation_name, severity=1)

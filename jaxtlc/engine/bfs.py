@@ -288,6 +288,27 @@ class CheckResult(NamedTuple):
     constraint_rows: int = None
     constraint_discarded: int = None
     constraint_names: tuple = None
+    # a struct check with a PROPERTY only (live.check, ISSUE 41; None
+    # elsewhere): the behaviour graph the liveness route analysed on
+    # the device - its states and successor rows (what the safety run
+    # counted: distinct; generated less the initial states), the rows
+    # that change the state, the bytes of the edge store - and, summed
+    # over the cfg's properties, the rows of the fairness constraints'
+    # actions, the states of H = ~Q and of P, the P-states the fair
+    # fixpoint kept (0 iff every property holds), its outer passes and
+    # sweeps, and the bytes of states or rows read to the host (0
+    # unless a property is violated: the lasso)
+    live_states: int = None
+    live_edges: int = None
+    live_changed_edges: int = None
+    live_fair_edges: int = None
+    live_h_states: int = None
+    live_p_states: int = None
+    live_survivors: int = None
+    live_outer: int = None
+    live_sweeps: int = None
+    live_edge_bytes: int = None
+    live_host_bytes: int = None
 
 
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
@@ -297,7 +318,11 @@ STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
                  "states_expanded", "lane_fires", "struct_traps",
                  "sym_perms", "sym_sets", "canon_rows", "canon_moved",
                  "sym_cert_checks", "sym_cert_trips", "constraint_rows",
-                 "constraint_discarded", "constraint_names")
+                 "constraint_discarded", "constraint_names",
+                 "live_states", "live_edges", "live_changed_edges",
+                 "live_fair_edges", "live_h_states", "live_p_states",
+                 "live_survivors", "live_outer", "live_sweeps",
+                 "live_edge_bytes", "live_host_bytes")
 
 
 def mesh_counters(result: CheckResult) -> dict:
@@ -1000,7 +1025,7 @@ def make_backend_engine(
             # from the canonicalized seeds
             inits = red.plan.canon(inits)
         n0 = inits.shape[0]
-        assert n0 <= chunk and n0 <= qcap, "raise chunk/queue_capacity"
+        assert n0 <= qcap, "raise queue_capacity"
         kept0 = jnp.ones(n0, bool)
         if has_con:
             # an initial state outside the cfg's CONSTRAINT counts as
@@ -1368,7 +1393,7 @@ def make_enumerator(
     def init_fn() -> EnumCarry:
         inits = jnp.asarray(backend.initial_vectors())
         n0 = inits.shape[0]
-        assert n0 <= chunk and n0 <= cap, "raise chunk/state_capacity"
+        assert n0 <= cap, "raise state_capacity"
         packed0 = cdc.pack(inits)
         states = jnp.zeros((cap + A, W), jnp.uint32).at[:n0].set(packed0)
         lo, hi = fp64_words_mxu(packed0, nbits, fp_index, seed)

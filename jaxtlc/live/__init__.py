@@ -26,5 +26,6 @@ from .check import (  # noqa: F401
     HOST_PATH_MAX,
     check_leads_to_device,
     check_properties_device,
+    check_struct_properties,
     use_device_path,
 )

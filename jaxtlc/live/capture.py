@@ -253,6 +253,154 @@ def capture_edges(
     )
 
 
+# ---------------------------------------------------------------------------
+# The relation kept on the device (struct route, ISSUE 41)
+# ---------------------------------------------------------------------------
+
+# a packed state of at most this many bits is its own index into a
+# direct id table (2^bits int32: 256 MB at 26): one element gather a
+# successor where the sorted-fingerprint search is 2 log2(V) of them
+DIRECT_ID_BITS = 26
+
+
+class DeviceGraph(NamedTuple):
+    """What the capture program leaves on the device: the STATE-CHANGING
+    successor rows in source order as a CSR (a self-loop carries nothing
+    the analysis reads: `a_k` needs a changed step, and a state reaches
+    itself), and the counts of everything it walked."""
+
+    dst: jnp.ndarray  # [E + slack] int32: row e's destination id
+    act: jnp.ndarray  # [E + slack] int8: its action label id
+    row_start: jnp.ndarray  # [V + 1] int32: state s's rows are
+    #                         row_start[s] .. row_start[s + 1]
+    n_rows: jnp.ndarray  # int32: every successor row walked (= E)
+    n_changed: jnp.ndarray  # int32: rows stored (src != dst)
+    missing: jnp.ndarray  # bool: a successor outside the enumerated set
+
+
+def make_device_capture(backend, chunk: int, n_states: int, n_edges: int,
+                        fp_index: int = DEFAULT_FP_INDEX,
+                        seed: int = DEFAULT_SEED):
+    """(init_fn, program) of the capture over an enumerated state array,
+    for `runtime.aot_build`: the program takes the enumerator's
+    `states` ([n_states + A, W], id = row) and gives a DeviceGraph.
+
+    `n_states` and `n_edges` are what the safety run counted (distinct;
+    generated less the initial states): the stores are sized from them,
+    nothing regrows and nothing leaves the device.  One `lax.fori_loop`
+    over blocks of `chunk` states: each is expanded through the
+    backend's own step, every successor's id resolved (a direct table
+    where the packed state is small, else the search over the sorted
+    fingerprints), and the block's changed rows, compacted by one
+    stable sort that carries them, are written where the last block's
+    ended: contiguous blocks, no row scatter."""
+    from ..engine.backend import require_unconstrained
+
+    require_unconstrained(backend, "the liveness graph capture (PROPERTY)")
+    cdc = backend.cdc
+    F = cdc.n_fields
+    W = (cdc.nbits + 31) // 32
+    L = backend.n_lanes
+    nbits = cdc.nbits
+    V = n_states
+    ncand = chunk * L
+    n_blocks = -(-V // chunk)
+    rows_in = V + min(2 * chunk, ncand)  # the enumerator's `cap + A`
+    e_cap = n_edges + ncand
+    step = backend.step
+    direct = nbits <= DIRECT_ID_BITS
+    assert len(backend.labels) < 128, "action ids are kept as int8"
+
+    def init_fn():
+        return jnp.zeros((rows_in, W), jnp.uint32)
+
+    def program(states) -> DeviceGraph:
+        with jax.named_scope("jaxtlc.live.capture"):
+            return capture(states)
+
+    def capture(states) -> DeviceGraph:
+        ids = jnp.arange(rows_in, dtype=jnp.int32)
+        if direct:
+            key = jnp.where(ids < V, states[:, 0].astype(jnp.int32),
+                            jnp.int32(1 << nbits))
+            table = jnp.full(1 << nbits, -1, jnp.int32).at[key].set(
+                ids, mode="drop")
+
+            def id_of(packed):
+                return table[packed[:, 0].astype(jnp.int32)]
+        else:
+            lo, hi = fp64_words_mxu(states[:V], nbits, fp_index, seed)
+            s_hi, s_lo, perm = lax.sort(
+                (hi, lo, jnp.arange(V, dtype=jnp.int32)), num_keys=2)
+
+            def id_of(packed):
+                q_lo, q_hi = fp64_words_mxu(packed, nbits, fp_index, seed)
+                at = _pair_searchsorted(s_hi, s_lo, q_hi, q_lo, V)
+                at_c = jnp.minimum(at, V - 1)
+                found = (s_hi[at_c] == q_hi) & (s_lo[at_c] == q_lo) \
+                    & (at < V)
+                return jnp.where(found, perm[at_c], -1)
+
+        # rows_in = V + A >= n_blocks * chunk: the last block reads
+        # inside the array, its rows past V masked
+        rows = jnp.arange(chunk, dtype=jnp.int32)
+        lane_src = jnp.arange(ncand, dtype=jnp.int32) // L
+
+        def block(i, st):
+            dst, act, deg, n_rows, n_changed, missing = st
+            offset = i * chunk
+            batch = cdc.unpack(lax.dynamic_slice(
+                states, (offset, jnp.int32(0)), (chunk, W)))
+            succs, valid, action, _afail, _ovf = jax.vmap(step)(batch)
+            valid = (valid & ((offset + rows) < V)[:, None]).reshape(-1)
+            to = id_of(cdc.pack(succs.reshape(ncand, F)))
+            missing = missing | (valid & (to < 0)).any()
+            changed = valid & (to != offset + lane_src)
+            # the changed rows to the front, carried by the sort itself
+            _, to_c, act_c = lax.sort(
+                ((~changed).astype(jnp.uint8), to,
+                 jnp.broadcast_to(action, (chunk, L)).reshape(-1)
+                 .astype(jnp.int8)),
+                num_keys=1, is_stable=True)
+            dst = lax.dynamic_update_slice(dst, to_c, (n_changed,))
+            act = lax.dynamic_update_slice(act, act_c, (n_changed,))
+            deg = lax.dynamic_update_slice(
+                deg, changed.reshape(chunk, L).sum(axis=1, dtype=jnp.int32),
+                (offset,))
+            return (dst, act, deg,
+                    n_rows + valid.sum(dtype=jnp.int32),
+                    n_changed + changed.sum(dtype=jnp.int32), missing)
+
+        dst, act, deg, n_rows, n_changed, missing = lax.fori_loop(
+            0, n_blocks, block,
+            (jnp.zeros(e_cap, jnp.int32), jnp.zeros(e_cap, jnp.int8),
+             jnp.zeros(n_blocks * chunk, jnp.int32), jnp.int32(0),
+             jnp.int32(0), jnp.bool_(False)))
+        row_start = jnp.concatenate(
+            [jnp.zeros(1, jnp.int32), jnp.cumsum(deg[:V], dtype=jnp.int32)])
+        return DeviceGraph(dst, act, row_start, n_rows, n_changed, missing)
+
+    return init_fn, jax.jit(program)
+
+
+def make_scoped_enumerator(backend, chunk: int, n_states: int,
+                           fp_capacity: int,
+                           fp_index: int = DEFAULT_FP_INDEX,
+                           seed: int = DEFAULT_SEED):
+    """(init_fn, program) of the fused enumerator (engine.bfs.
+    make_enumerator) with its state array sized to what the safety run
+    counted, under the device scope `jaxtlc.live.enumerate`."""
+    init_fn, run_fn = make_enumerator(
+        backend, chunk=chunk, state_capacity=n_states,
+        fp_capacity=fp_capacity, fp_index=fp_index, seed=seed)
+
+    def program(carry):
+        with jax.named_scope("jaxtlc.live.enumerate"):
+            return run_fn(carry)
+
+    return init_fn, jax.jit(program)
+
+
 def eval_state_masks(graph: CapturedGraph, cdc, fns, chunk: int = 8192):
     """Evaluate per-state bool predicates over the captured states.
 

@@ -21,14 +21,14 @@ bottleneck); `-liveness-host` forces the old path.
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import numpy as np
 
 from ..engine.liveness import LivenessResult as KubeLivenessResult
 from .capture import CapturedGraph, capture_edges, eval_state_masks
 from .fixpoint import has_nonself, surviving_set
-from .lasso import build_lasso, replay_lasso
+from .lasso import LassoError, build_lasso, replay_lasso
 
 # above this many distinct states the host liveness graph (one Python
 # dict entry + adjacency list per state) stops being viable; the device
@@ -235,3 +235,256 @@ def check_leads_to_device(
         decode, lambda s: s == init, is_transition,
     )
     return go.LivenessResult(name, False, prefix, cycle)
+
+
+# ---------------------------------------------------------------------------
+# Structural frontend: the relation stays on the device (ISSUE 41)
+# ---------------------------------------------------------------------------
+
+# the counters of one property's analysis, as the `liveness` journal
+# event, CheckResult and the `final` event carry them (`live_` + name)
+LIVE_COUNTERS = ("states", "edges", "changed_edges", "fair_edges",
+                 "h_states", "p_states", "survivors", "outer", "sweeps",
+                 "edge_bytes", "host_bytes")
+
+
+class StructLiveResult(NamedTuple):
+    """One property's verdict on the device route.  The lasso (decoded
+    state tuples) only where it is violated; `counters` by LIVE_COUNTERS;
+    `alive` (the fixpoint's set as a host mask) only where asked for."""
+
+    name: str
+    holds: bool
+    lasso_prefix: Optional[list]
+    lasso_cycle: Optional[list]
+    counters: dict
+    fairness: tuple
+    alive: Optional[np.ndarray] = None
+
+
+class LiveTooLarge(RuntimeError):
+    """The relation does not fit the device: the caller's cue for the
+    host tier."""
+
+
+def device_budget_bytes() -> Optional[int]:
+    """What the first device can still hold, or None where it does not
+    say (the CPU)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def check_struct_properties(
+    model,
+    backend,
+    properties,
+    n_states: int,
+    n_edges: int,
+    chunk: int = 1024,
+    fp_capacity: int = 1 << 20,
+    fp_index: Optional[int] = None,
+    seed: Optional[int] = None,
+    keep_alive: bool = False,
+) -> List[StructLiveResult]:
+    """`P ~> Q` for each of `properties` ((name, p_ast, q_ast), ...) of a
+    struct model, under the fairness its SPECIFICATION formula states
+    (`model.fairness`: WF_vars(A_1) /\\ ... /\\ WF_vars(A_K), resolved
+    to action labels by the loader), on the device.
+
+    `n_states` and `n_edges` are what the safety run has just counted
+    (distinct; generated less the initial states): the state array and
+    the edge store are sized from them and never regrow.  Three kept
+    programs (`runtime.aot_build`, kinds `live-enum`, `live-capture`,
+    `live-fixpoint`: a second check of the process builds nothing):
+    the enumerator leaves the states on the device in id order, the
+    capture their changed successor rows as a CSR (live.capture), the
+    fixpoint Emerson and Lei's fair set (live.fixpoint).  P and Q are
+    the struct compiler's predicates, as the invariants are compiled,
+    evaluated over the state array on the device.  What comes to the
+    host is one stats vector a property and three scalars of the
+    capture; only a violation brings states and rows (the lasso, which
+    is replayed through the evaluator and whose cycle is held to the
+    fairness rule before it is returned).
+
+    Host spans `live.enumerate`, `live.capture`, and per property
+    `live.masks`, `live.fixpoint`, `live.verdict`; the caller opens
+    `live` around the call.  Raises LiveTooLarge where the relation
+    cannot fit the device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..engine.bfs import OK, VIOLATION_NAMES
+    from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
+    from ..obs.spans import span
+    from ..runtime import aot_build, engine_key
+    from .capture import make_device_capture, make_scoped_enumerator
+    from .fixpoint import FAIR_STATS, PREFIX_BLOCK, make_fair_fixpoint
+
+    fp_index = DEFAULT_FP_INDEX if fp_index is None else fp_index
+    seed = DEFAULT_SEED if seed is None else seed
+    cdc = backend.cdc
+    V, E = int(n_states), int(n_edges)
+    W = (cdc.nbits + 31) // 32
+    e_cap = E + chunk * backend.n_lanes
+    # dst + act of the store, the fixpoint's cut of them, and a sweep's
+    # few vectors at row width; the state array and its masks
+    need = e_cap * 5 * 2 + E * 14 + (V + 2 * chunk) * (4 * W + 16)
+    budget = device_budget_bytes()
+    if budget is not None and need > 0.8 * budget:
+        raise LiveTooLarge(
+            f"the liveness relation ({E} successor rows, {V} states: "
+            f"~{need / 1e9:.2f} GB) does not fit the device "
+            f"({budget / 1e9:.2f} GB free)")
+    geometry = dict(chunk=int(chunk), fp_capacity=int(fp_capacity),
+                    fp_index=int(fp_index), seed=int(seed), states=V,
+                    edges=E)
+
+    with span("live.enumerate") as sp:
+        template, enum = aot_build(
+            lambda: make_scoped_enumerator(backend, chunk, V, fp_capacity,
+                                           fp_index, seed),
+            key=engine_key("live-enum", backend, geometry))
+        carry = enum(template)
+        code, tail = (int(x) for x in jax.device_get(
+            (carry.viol, carry.tail)))
+        sp.attrs["states"] = tail
+    if code != OK or tail != V:
+        raise RuntimeError(
+            "liveness enumeration disagrees with the safety run: "
+            + (VIOLATION_NAMES[code] if code != OK else
+               f"{tail} states enumerated, {V} distinct counted"))
+    states = carry.states
+    del carry
+
+    with span("live.capture") as sp:
+        _, capture = aot_build(
+            lambda: make_device_capture(backend, chunk, V, E, fp_index,
+                                        seed),
+            key=engine_key("live-capture", backend, geometry))
+        graph = capture(states)
+        n_rows, n_changed, missing = (int(x) for x in jax.device_get(
+            (graph.n_rows, graph.n_changed, graph.missing)))
+        sp.attrs.update(edges=n_rows, changed=n_changed,
+                        sweeps=-(-V // chunk))
+    if missing or n_rows != E:
+        raise RuntimeError(
+            "liveness capture disagrees with the safety run: "
+            + ("a successor outside the enumerated set" if missing else
+               f"{n_rows} successor rows walked, {E} counted"))
+    # the analysis reads the changed rows alone: its programs are built
+    # for their count (a constant of the model), in whole prefix blocks
+    e_rows = max(PREFIX_BLOCK, -(-n_changed // PREFIX_BLOCK) * PREFIX_BLOCK)
+    dst, act = graph.dst[:e_rows], graph.act[:e_rows]
+    row_start = graph.row_start
+    del graph
+    fairness = tuple(model.fairness)
+    groups = tuple(tuple(backend.labels.index(lab) for lab in labels
+                         if lab in backend.labels)
+                   for _, labels in fairness)
+    n_rows_states = int(states.shape[0])
+    edge_bytes = e_rows * 5 + (V + 1) * 4
+
+    out: List[StructLiveResult] = []
+    for name, p_ast, q_ast in properties:
+        with span("live.masks", property=name):
+            p, h = _struct_masks(backend, name, p_ast, q_ast, V)(states)
+        with span("live.fixpoint", property=name) as sp:
+            _, fix = aot_build(
+                lambda: make_fair_fixpoint(V, n_rows_states, e_rows, groups),
+                key=engine_key(
+                    "live-fixpoint", backend,
+                    dict(states=V, rows=n_rows_states, e_rows=e_rows,
+                         groups=[list(g) for g in groups])))
+            z, stats = fix((dst, act, row_start, jnp.int32(n_changed),
+                            p, h))
+            stats = dict(zip(FAIR_STATS, (int(x) for x in
+                                          jax.device_get(stats))))
+            sp.attrs.update(outer=stats["outer"], sweeps=stats["sweeps"])
+        with span("live.verdict", property=name):
+            counters = dict(
+                states=V, edges=n_rows, changed_edges=n_changed,
+                fair_edges=stats["fair_edges"], h_states=stats["h_states"],
+                p_states=stats["p_states"], survivors=stats["survivors"],
+                outer=stats["outer"], sweeps=stats["sweeps"],
+                edge_bytes=edge_bytes, host_bytes=0)
+            alive = np.asarray(z) if keep_alive else None
+            if stats["survivors"] == 0:
+                out.append(StructLiveResult(name, True, None, None,
+                                            counters, fairness, alive))
+                continue
+            prefix, cycle, counters["host_bytes"] = _struct_lasso(
+                model, backend, states, dst, act, row_start, n_changed,
+                z, p, V, groups)
+            out.append(StructLiveResult(name, False, prefix, cycle,
+                                        counters, fairness, alive))
+    return out
+
+
+def _struct_masks(backend, name: str, p_ast, q_ast, n_states: int):
+    """The jitted (states -> P, H = ~Q over the enumerator's rows, the
+    rows past the states False) of one property, kept on the backend
+    (the struct memo keeps that): a second check compiles nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    kept = backend.cdc.__dict__.setdefault("_live_masks", {})
+    key = (name, repr(p_ast), repr(q_ast), n_states)
+    if key not in kept:
+        cdc = backend.cdc
+        p_fn = cdc.compile_predicate(p_ast)
+        q_fn = cdc.compile_predicate(q_ast)
+
+        @jax.jit
+        def masks(states):
+            fields = cdc.unpack(states)
+            real = jnp.arange(states.shape[0]) < n_states
+            return p_fn(fields) & real, ~q_fn(fields) & real
+
+        kept[key] = masks
+    return kept[key]
+
+
+def _struct_lasso(model, backend, states, dst, act, row_start, n_changed,
+                  z, p, V, groups):
+    """A violation's lasso, on the host: the one case that reads states
+    and rows back.  (prefix, cycle as decoded state tuples, bytes
+    read.)"""
+    import jax.numpy as jnp
+
+    from .lasso import cycle_is_fair, fair_lasso
+
+    system = model.system
+    cdc = backend.cdc
+    dst_h = np.asarray(dst)[:n_changed]
+    act_h = np.asarray(act)[:n_changed].astype(np.int32)
+    rs = np.asarray(row_start)
+    src_h = np.repeat(np.arange(V, dtype=np.int32), np.diff(rs))
+    z_h, p_h = np.asarray(z), np.asarray(p)[:V]
+    n_init = system.initial_count()
+    prefix_ids, cycle_ids, _, _ = fair_lasso(
+        V, n_init, src_h, dst_h, act_h, z_h, p_h, groups)
+    if not cycle_is_fair(cycle_ids, src_h, dst_h, act_h, groups):
+        raise LassoError("the reconstructed cycle is not fair")
+    ids = prefix_ids + cycle_ids
+    rows = np.asarray(cdc.unpack(states[jnp.asarray(ids)]))
+    decoded = [cdc.decode(r) for r in rows]
+    prefix, cycle = decoded[:len(prefix_ids)], decoded[len(prefix_ids):]
+    inits = None
+
+    def is_initial(st):
+        nonlocal inits
+        if inits is None:
+            inits = set(system.initial_states())
+        return st in inits
+
+    def is_transition(sa, sb):
+        return any(nxt == sb for _, nxt in system.successors(sa))
+
+    replay_lasso(prefix, cycle, is_initial, is_transition)
+    read = (dst_h.nbytes + n_changed + rs.nbytes + z_h.nbytes
+            + p_h.nbytes + rows.nbytes)
+    return prefix, cycle, int(read)

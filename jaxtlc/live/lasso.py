@@ -133,6 +133,124 @@ def build_lasso(
     )
 
 
+def fair_lasso(
+    n_states: int,
+    init_count: int,
+    src: np.ndarray,
+    dst: np.ndarray,
+    action: np.ndarray,
+    in_z: np.ndarray,
+    trigger: np.ndarray,
+    fair_labels,
+) -> Tuple[List[int], List[int], List[Optional[int]], List[Optional[int]]]:
+    """(prefix_ids, cycle_ids, prefix_action_ids, cycle_action_ids) of a
+    violation under WF_vars(A_1) /\\ ... /\\ WF_vars(A_K).
+
+    `src`, `dst`, `action` are the STATE-CHANGING rows of the graph;
+    `in_z` the fixpoint's set (the H-states that reach, inside H, a
+    fair component; live.fixpoint.make_fair_fixpoint); `trigger` the
+    P-states;
+    `fair_labels[k]` the label ids of A_k.  The prefix runs from an
+    initial state to the nearest trigger state of Z and on, inside Z,
+    to a fair strongly connected component of Z's subgraph (one exists
+    below every state of Z: a bottom component is fair); the cycle
+    lies in that component and is fair by construction - for each k it
+    passes a state where A_k is not enabled, or takes an A_k step - and
+    a single id where that state may stutter forever.  Action ids label
+    the edge INTO each position (None for initial states / stutter).
+    Host-side: a violation is the one case that brings the relation to
+    the host."""
+    from scipy.sparse import csr_matrix
+    from scipy.sparse.csgraph import connected_components
+
+    full = _CSR(n_states, src, dst, action)
+    bad = trigger & in_z
+    prefix_ids = _bfs_path(full, range(init_count), bad)
+    inside = in_z[src] & in_z[dst]
+    zs, zd, za = src[inside], dst[inside], action[inside]
+    _, comp = connected_components(
+        csr_matrix((np.ones(len(zs), np.int8), (zs, zd)),
+                   shape=(n_states, n_states)),
+        directed=True, connection="strong")
+    n_comp = int(comp.max()) + 1
+    fair = np.ones(n_comp, bool)
+    enabled, steps = [], []
+    same = comp[zs] == comp[zd]
+    for labels in fair_labels:
+        of_k = np.isin(action, np.asarray(labels, np.int32))
+        en = np.zeros(n_states, bool)
+        en[src[of_k]] = True
+        met = np.zeros(n_comp, bool)
+        met[comp[in_z & ~en]] = True
+        step = same & np.isin(za, np.asarray(labels, np.int32))
+        met[comp[zs[step]]] = True
+        fair &= met
+        enabled.append(en)
+        steps.append(step)
+    z_csr = _CSR(n_states, zs, zd, za)
+    down = _bfs_path(z_csr, [prefix_ids[-1]], in_z & fair[comp])
+    prefix_ids = prefix_ids + down[1:]
+    start = prefix_ids[-1]
+    here = comp == comp[start]
+    sel = here[zs] & here[zd]
+    c_csr = _CSR(n_states, zs[sel], zd[sel], za[sel])
+    cycle = [start]
+
+    def go(target_mask):
+        cycle.extend(_bfs_path(c_csr, [cycle[-1]], target_mask)[1:])
+
+    for en, step in zip(enabled, steps):
+        if not en[cycle].all():
+            continue  # a state of the cycle so far has A_k not enabled
+        idle = here & in_z & ~en
+        if idle.any():
+            go(idle)
+            continue
+        e = int(np.flatnonzero(step & sel)[0])
+        at = np.zeros(n_states, bool)
+        at[zs[e]] = True
+        go(at)
+        cycle.append(int(zd[e]))
+    if cycle[-1] != start:
+        at = np.zeros(n_states, bool)
+        at[start] = True
+        go(at)
+        cycle.pop()  # the start closes the cycle: not written twice
+    prefix = prefix_ids[:-1]
+
+    def acts(ids: List[int], pred0: Optional[int]) -> List[Optional[int]]:
+        preds = [pred0] + ids[:-1]
+        return [
+            None if p is None or p == i else full.edge_action(p, i)
+            for p, i in zip(preds, ids)
+        ]
+
+    return (
+        prefix,
+        cycle,
+        acts(prefix, None),
+        acts(cycle, prefix[-1] if prefix else cycle[-1]),
+    )
+
+
+def cycle_is_fair(cycle_ids: List[int], src, dst, action,
+                  fair_labels) -> bool:
+    """Is this cycle (ids, closing back to the first) fair under every
+    WF_vars(A_k): an A_k step on it, or a state of it where A_k is not
+    enabled?  What the tests and the route's own check hold a reported
+    lasso to, by the rule and not by construction."""
+    pairs = set(zip(cycle_ids, cycle_ids[1:] + cycle_ids[:1]))
+    on = np.isin(src, cycle_ids)
+    for labels in fair_labels:
+        of_k = np.isin(action, np.asarray(labels, np.int32))
+        idle = set(cycle_ids) - set(src[of_k & on].tolist())
+        stepped = any((int(u), int(v)) in pairs for u, v in
+                      zip(src[of_k & on], dst[of_k & on]))
+        if not idle and not stepped:
+            return False
+    return True
+
+
 def replay_lasso(
     prefix_states: List,
     cycle_states: List,

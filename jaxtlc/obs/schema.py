@@ -91,6 +91,15 @@ EVENTS = {
                   "wall_s": _NUM},
     # -- verdicts ----------------------------------------------------------
     "violation": {"code": _NUM, "name": _STR},
+    # one per temporal property of a struct check (ISSUE 41), before
+    # the `final` event: its verdict, the route that gave it (`device`:
+    # live.check.check_struct_properties; `host`: struct.oracle), and
+    # the fairness it was judged under, [[A, [labels]], ...] as the
+    # SPECIFICATION formula states it.  The device route adds its
+    # counters (extra fields `live_states` .. `live_host_bytes`:
+    # live.check.LIVE_COUNTERS)
+    "liveness": {"property": _STR, "holds": _BOOL, "route": _STR,
+                 "fairness": (list,)},
     # the structured final event: EVERY run (clean, violated, interrupted,
     # progress-lost) ends its journal with exactly one of these.  Mesh
     # runs add shard_distinct (extra field): per-device table occupancy

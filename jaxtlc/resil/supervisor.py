@@ -206,6 +206,13 @@ class SupervisorOptions:
     # / regrow / retry / interrupted / progress / spill / degrade /
     # exhausted - the tlc_log banner seam
     on_event: Optional[Callable[[str, dict], None]] = None
+    # finish(result) -> (result, verdict | None): what a clean verdict
+    # still owes before it is final - the temporal properties of a
+    # struct check (api._run_check_struct: the liveness route's `live`
+    # span and counters) - run after the loop, inside `check`, BEFORE
+    # the `spans` and `final` events, so both carry it.  Called only
+    # where the verdict so far is "ok"; a verdict it returns replaces it
+    finish: Optional[Callable] = None
 
 
 class SupervisedResult(NamedTuple):
@@ -1253,6 +1260,9 @@ def supervise(adapter, params: dict,
             result = result._replace(
                 fp_table=np.asarray(jax.device_get(carry.fps.table))
             )
+    if opts.finish is not None and verdict == "ok":
+        result, later = opts.finish(result)
+        verdict = later or verdict
     # the host spans of this check that have closed by now (build, loop
     # and their children; `check` itself is still open), once
     _emit(opts, "spans", **spans.journal_event())

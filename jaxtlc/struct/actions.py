@@ -44,6 +44,26 @@ def names_action(body) -> bool:
     return body[0] != "or"
 
 
+def _mentions_names(ast, defs, names) -> bool:
+    """Does `ast`, through the definitions it calls, read one of
+    `names`?  (A bound variable that shadows a name counts: the answer
+    errs to True.)"""
+    seen = set()
+    stack = [ast]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, tuple) and node and node[0] in ("call", "name"):
+            if node[1] in names:
+                return True
+            d = defs.get(node[1])
+            if d is not None and node[1] not in seen:
+                seen.add(node[1])
+                stack.append(d.body)
+        if isinstance(node, (tuple, list)):
+            stack.extend(x for x in node if isinstance(x, (tuple, list)))
+    return False
+
+
 def expand_unchanged(names, defs, variables) -> List[str]:
     """UNCHANGED accepts state variables AND tuple-of-variables
     definitions (the universal `vars == <<...>>` convention TLC
@@ -82,6 +102,7 @@ class ActionSystem:
         self.init_ast = ev.defs[init_name].body
         self.next_ast = ev.defs[next_name].body
         self._mentions_cache: Dict[int, bool] = {}
+        self._init_product = False  # not asked yet (init_product)
 
     def with_constants(self, constants: Dict[str, object]) -> "ActionSystem":
         """The same Init/Next under different CONSTANT values - the
@@ -94,6 +115,7 @@ class ActionSystem:
         clone.init_ast = self.init_ast
         clone.next_ast = self.next_ast
         clone._mentions_cache = {}
+        clone._init_product = False
         return clone
 
     # -- prime detection ---------------------------------------------------
@@ -110,9 +132,86 @@ class ActionSystem:
 
     # -- initial states ----------------------------------------------------
 
+    def init_product(self) -> Optional[List[Tuple[str, list]]]:
+        """Init as a product of independent per-variable domains, where
+        it is one: a conjunction whose every conjunct is `v = e` or
+        `v \\in S` for a distinct variable `v`, with `e` / `S` reading no
+        variable.  [(variable, its canonical values in enumeration
+        order), ...] in the conjuncts' order - `initial_states` is then
+        their cartesian product in that order, first conjunct slowest -
+        or None where Init is anything else.  EWD840's Init is 2^(2N) N
+        states by four such conjuncts: shape inference, the codec's
+        encoding and the count read the domains, not the product."""
+        if self._init_product is not False:
+            return self._init_product
+        self._init_product = None
+        items = list(self.init_ast[1]) if self.init_ast[0] == "and" \
+            else [self.init_ast]
+        env = dict(self.ev.constants)
+        doms: List[Tuple[str, list]] = []
+        for ast in items:
+            if not (ast[0] == "cmp" and ast[1] in ("=", r"\in")
+                    and ast[2][0] == "name"
+                    and ast[2][1] in self.variables
+                    and ast[2][1] not in [v for v, _ in doms]
+                    and not _mentions_names(ast[3], self.ev.defs,
+                                            self.variables)):
+                return None
+            val = self.ev.eval(ast[3], env)
+            if ast[1] == "=":
+                doms.append((ast[2][1], [canon(val)]))
+            elif isinstance(val, frozenset):
+                doms.append((ast[2][1],
+                             [canon(x) for x in sorted(val, key=repr)]))
+            else:
+                return None
+        if {v for v, _ in doms} != set(self.variables):
+            return None
+        self._init_product = doms
+        return doms
+
+    def initial_count(self) -> int:
+        """len(initial_states()), without the states where Init is a
+        product."""
+        doms = self.init_product()
+        if doms is None:
+            return len(self.initial_states())
+        n = 1
+        for _, vals in doms:
+            n *= len(vals)
+        return n
+
+    def initial_corners(self, limit: int = 64) -> List[tuple]:
+        """A few initial states that span Init: where it is a product,
+        the combinations of each variable's first and last value (all
+        nodes passive / all active, all white / all black, ...); else
+        the first `limit` states.  What struct.backend sizes a
+        compacted step's first guess against."""
+        doms = self.init_product()
+        if doms is None:
+            return self.initial_states()[:limit]
+        from itertools import product as _product
+
+        at = [[v for v, _ in doms].index(v) for v in self.variables]
+        ends = [list(dict.fromkeys((vals[0], vals[-1])))
+                for _, vals in doms]
+        out = []
+        for combo in _product(*ends):
+            out.append(tuple(combo[i] for i in at))
+            if len(out) >= limit:
+                break
+        return out
+
     def initial_states(self) -> List[tuple]:
         """All Init-satisfying assignments, as state tuples in variable
         declaration order."""
+        doms = self.init_product()
+        if doms is not None:
+            from itertools import product as _product
+
+            at = [[v for v, _ in doms].index(v) for v in self.variables]
+            return [tuple(combo[i] for i in at)
+                    for combo in _product(*(vals for _, vals in doms))]
         outs: List[Dict[str, object]] = []
         self._enum_init(self.init_ast, {}, outs)
         states = []

@@ -206,8 +206,20 @@ def struct_backend(model: StructModel,
     # leaves the step compacted to `width` slots a state, so the
     # engine's candidate width follows the live lanes.  The coverage
     # plane and POR read static lanes, so they keep the full fan
+    guess = compact_width(len(labels))
+    if guess < len(labels) and not (coverage or por):
+        # a first guess an initial state already refutes is not built:
+        # the widest fan among a few initial states that span Init (the
+        # host evaluator's successor rows are the lanes that fire),
+        # taken up the same doubling ladder an overflow would take
+        # (struct.cache.widen_slots) - EWD840's all-active initial
+        # state fires 67 of 87 lanes at N = 8 against a guess of 32
+        fan = max((len(system.successors(st))
+                   for st in system.initial_corners()), default=0)
+        while guess < min(len(labels), fan):
+            guess = min(len(labels), 2 * guess)
     width = len(labels) if coverage or por else min(
-        len(labels), max(compact_width(len(labels)), slots))
+        len(labels), max(guess, slots))
     compacted = width < len(labels)
     trap_stats = (compiler.trap_sites + int(compacted),
                   compiler.elided_traps)
@@ -229,8 +241,26 @@ def struct_backend(model: StructModel,
         return bits
 
     def initial_vectors():
-        inits = system.initial_states()
-        return np.stack([cdc.encode(st) for st in inits])
+        doms = system.init_product()
+        if doms is None:
+            inits = system.initial_states()
+            return np.stack([cdc.encode(st) for st in inits])
+        # Init is a product of per-variable domains (EWD840: 2^(2N) N
+        # states): each variable's values are encoded once, and the
+        # rows are their product in initial_states' order
+        names = [v for v, _ in doms]
+        at = np.indices([len(vals) for _, vals in doms]).reshape(
+            len(doms), -1)
+        parts = []
+        for v, lay in zip(system.variables, cdc.layouts):
+            k = names.index(v)
+            codes = []
+            for val in doms[k][1]:
+                out: List[int] = []
+                lay.encode(val, out)
+                codes.append(out)
+            parts.append(np.asarray(codes, np.int32)[at[k]])
+        return np.concatenate(parts, axis=1)
 
     constraint = None
     if model.constraints:
@@ -361,6 +391,9 @@ def struct_backend(model: StructModel,
     backend.cdc.trap_stats = trap_stats
     # the static fan before compaction (CheckResult.step_lanes)
     backend.cdc.static_lanes = len(labels)
+    # a state predicate compiled as the invariants are ([B, F] -> bool
+    # [B]): the liveness route's P and Q (live.check)
+    backend.cdc.compile_predicate = compiler.build_invariant
     return backend
 
 

@@ -229,6 +229,21 @@ def _int_bounds(lv) -> Optional[Tuple[int, int]]:
     return None
 
 
+def _int_hull(lv) -> Optional[Tuple[int, int]]:
+    """The least and greatest integer `lv` can hold, where that is
+    static: a constant, certified bounds, or an enum leaf of integers."""
+    if isinstance(lv, LE):
+        vals = [v for v in lv.leaf.values
+                if isinstance(v, int) and not isinstance(v, bool)]
+        if vals and len(vals) == len(lv.leaf.values):
+            return (min(vals), max(vals))
+        return None
+    if isinstance(lv, (LC, LI)) and (not isinstance(lv, LC) or (
+            isinstance(lv.value, int) and not isinstance(lv.value, bool))):
+        return _int_bounds(lv)
+    return None
+
+
 class LE(LV):
     """Enum-coded value: arr holds indices into leaf.values; -1 = absent
     / invalid (guard-unreachable paths)."""
@@ -1754,6 +1769,27 @@ class LaneCompiler:
         if not names:
             return self.comp(body, env, ctx)
         name, rest = names[0], names[1:]
+        if dom_ast[0] == "binop" and dom_ast[1] == "..":
+            # a range with a bound read off the state (EWD840's
+            # `\E j \in 0 .. tpos`): the quantifier over the constant
+            # hull of the two bounds, membership as a guard
+            ends = [self.comp(x, env, ctx) for x in dom_ast[2:4]]
+            if not all(isinstance(x, LC) for x in ends):
+                hulls = [_int_hull(x) for x in ends]
+                if None in hulls:
+                    raise CompileError(
+                        "dynamic .. range whose bounds have no static "
+                        "hull")
+                inside = ("and", [("cmp", "<=", dom_ast[2], ("name", name)),
+                                  ("cmp", "<=", ("name", name), dom_ast[3])])
+                inner = (kind, rest, dom_ast, body) if rest else body
+                return self._quant_rec(
+                    [name],
+                    ("binop", "..", ("num", hulls[0][0]),
+                     ("num", hulls[1][1])),
+                    ("implies", inside, inner) if kind == "forall"
+                    else ("and", [inside, inner]),
+                    env, ctx, None, kind)
         desc = self._dom_descriptor(dom_ast, env, ctx)
         if desc[0] == "const":
             acc = None

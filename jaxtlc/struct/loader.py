@@ -39,7 +39,12 @@ class StructModel(NamedTuple):
     properties: Dict[str, tuple]  # name -> AST (leadsto shapes)
     constants: Dict[str, object]
     module: Module
-    fairness: Optional[str]  # "wf_next" | None
+    # the fairness conjuncts of the SPECIFICATION formula, resolved:
+    # ((A, the action labels A is a disjunction of), ...), one entry a
+    # `WF_<vars>(A)`; () where the formula states none.  Read by the
+    # liveness route alone (declared_fairness): a conjunct it cannot
+    # honour is a load error whenever the cfg has a PROPERTY
+    fairness: Tuple[Tuple[str, Tuple[str, ...]], ...]
     root_name: str
     # sha256 over every source text this model was loaded from (cfg +
     # module closure) plus the constant overrides - the step-compile
@@ -169,6 +174,86 @@ def declared_constraints(names, module: Module) -> Dict[str, tuple]:
                 "ACTION_CONSTRAINT is not supported)")
         out[n] = d.body
     return out
+
+
+def _action_labels(ast, defs, stop: Optional[str] = None):
+    """The action labels under `ast`, an action on the way down from
+    Next: through `\\/`, bounded `\\E` and definitions that only split
+    further, to the definitions that name the fired action
+    (actions.names_action: the labels the engines count by).  Returns
+    (labels, definitions passed on the way), or None where a disjunct
+    is no named action of the module.  `stop`: a definition whose
+    subtree is left out."""
+    from .actions import names_action
+    from .shapes import _mentions_prime_static
+
+    labels, passed = set(), set()
+    stack = [ast]
+    while stack:
+        node = stack.pop()
+        if node[0] == "or":
+            stack.extend(node[1])
+        elif node[0] == "exists":
+            stack.append(node[3])
+        elif node[0] in ("call", "name") and node[1] in defs \
+                and _mentions_prime_static(defs[node[1]].body, defs):
+            if node[1] == stop:
+                continue
+            passed.add(node[1])
+            if names_action(defs[node[1]].body):
+                labels.add(node[1])
+            else:
+                stack.append(defs[node[1]].body)
+        else:
+            return None
+    return labels, passed
+
+
+def declared_fairness(conjuncts, next_name: str, subscript: str,
+                      module: Module
+                      ) -> Tuple[Tuple[str, Tuple[str, ...]], ...]:
+    """The fairness a SPECIFICATION formula states, as data: every
+    conjunct after Init and [][Next]_sub has to be `WF_sub(A)` with the
+    subscript of the [][Next]_ and `A` either Next or a definition met
+    on the way down from Next, a disjunction of the spec's actions; it
+    resolves to (A, the labels of the actions under it).  Anything else
+    - `SF_`, another subscript, a body that is no such definition, an
+    action that also fires outside `A` (the labels could not tell its
+    steps apart), any other conjunct - is an error that names the
+    conjunct: a property is never judged under a fairness the spec did
+    not state."""
+    defs = module.defs
+    out = []
+    whole = _action_labels(("name", next_name), defs)
+    for kind, sub, body, text in conjuncts:
+        def refuse(why):
+            raise StructLoadError(
+                f"SPECIFICATION conjunct `{text}`: {why}")
+
+        if kind == "other":
+            refuse("not a fairness condition this checker knows "
+                   "(WF_vars(A) for a named action A)")
+        if kind != "WF":
+            refuse("strong fairness (SF_) is not supported")
+        if sub != subscript:
+            refuse(f"its subscript is not the [][{next_name}]_"
+                   f"{subscript} one")
+        if body not in defs or defs[body].params:
+            refuse(f"`{body}` is not a defined action without "
+                   "parameters")
+        mine = _action_labels(("name", body), defs)
+        if mine is None or not mine[0]:
+            refuse(f"`{body}` is not a disjunction of the spec's "
+                   "actions")
+        if whole is None or body not in whole[1]:
+            refuse(f"`{body}` is not {next_name} or a disjunct of it")
+        rest = _action_labels(("name", next_name), defs, stop=body)
+        both = sorted(mine[0] & rest[0]) if body != next_name else []
+        if both:
+            refuse(f"the action {both[0]} also fires outside `{body}`: "
+                   "its steps cannot be told apart by label")
+        out.append((body, tuple(sorted(mine[0]))))
+    return tuple(out)
 
 
 def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
@@ -320,14 +405,29 @@ def _load(cfg_path: str,
 
     spec_name = cfg.specification or "Spec"
     spec_def = module.defs.get(spec_name)
+    conjuncts, subscript = (), None
     if spec_def is not None and spec_def.body[0] == "spec":
-        _, init_name, next_name, fairness = spec_def.body
+        _, init_name, next_name, subscript, conjuncts = spec_def.body
     else:
-        init_name, next_name, fairness = "Init", "Next", None
+        init_name, next_name = "Init", "Next"
     if init_name not in module.defs or next_name not in module.defs:
         raise StructLoadError(
             f"cannot resolve Init/Next ({init_name}/{next_name})"
         )
+    # host span `build.struct.fairness`: the formula's fairness
+    # conjuncts resolved to action labels.  Only a PROPERTY reads them:
+    # a safety-only check loads whatever the formula says
+    from ..obs.spans import span
+
+    with span("build.struct.fairness") as sp:
+        try:
+            fairness = declared_fairness(conjuncts, next_name, subscript,
+                                         module)
+        except StructLoadError:
+            if cfg.properties:
+                raise
+            fairness = ()
+        sp.attrs["names"] = " ".join(a for a, _ in fairness)
 
     def _named_defs(names):
         out = {}

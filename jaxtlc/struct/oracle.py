@@ -183,12 +183,19 @@ class LivenessResult(NamedTuple):
 
 
 def check_leads_to(system: ActionSystem, p_ast, q_ast, name: str = "",
-                   max_states: int = 1_000_000) -> LivenessResult:
-    """P ~> Q under WF_vars(Next) over the structural relation - the
-    same greatest-fixpoint peeling as the generic path (gen.oracle):
-    survive(s) iff ~Q(s) and (no state-changing successor, or some
-    state-changing successor survives); a violation is a reachable
-    surviving P-state."""
+                   max_states: int = 1_000_000,
+                   fairness=None) -> LivenessResult:
+    """P ~> Q over the structural relation under the spec's fairness:
+    `fairness` is StructModel.fairness, ((A, labels), ...) for
+    WF_vars(A_1) /\\ ... /\\ WF_vars(A_K); None is WF_vars(Next), () no
+    fairness at all.  The CPU tests' oracle of the device route
+    (live.check.check_struct_properties), by the same rule in plain
+    sets: H = ~Q; Z, the states of H that reach inside H a fair cycle,
+    is the greatest Z
+    with Z = Z /\\ AND_k pre*_Z(acc_k), acc_k the states of Z where A_k
+    is not enabled and the sources of A_k steps that stay in Z; a
+    violation is a reachable P-state of Z, shown as a lasso whose cycle
+    is fair (live.lasso.fair_lasso)."""
     ev = system.ev
 
     def holds(ast, st) -> bool:
@@ -196,23 +203,18 @@ def check_leads_to(system: ActionSystem, p_ast, q_ast, name: str = "",
         env.update(zip(system.variables, st))
         return ev.eval(ast, env) is True
 
-    init_states = system.initial_states()
     states: Dict[tuple, int] = {}
     order: List[tuple] = []
-    edges: Dict[int, List[int]] = {}
-    frontier = deque()
-    init_ids = []
-    for st in init_states:
+    edges: List[Tuple[int, int, str]] = []  # state-changing (src, dst, label)
+    for st in system.initial_states():
         if st not in states:
-            init_ids.append(len(order))
             states[st] = len(order)
             order.append(st)
-            frontier.append(st)
-    while frontier:
-        st = frontier.popleft()
-        sid = states[st]
-        outs = []
-        for _, nxt in system.successors(st):
+    n_init = len(order)
+    head = 0
+    while head < len(order):
+        st = order[head]
+        for label, nxt in system.successors(st):
             if nxt == st:
                 continue
             if nxt not in states:
@@ -220,67 +222,52 @@ def check_leads_to(system: ActionSystem, p_ast, q_ast, name: str = "",
                     raise RuntimeError("liveness graph bound exceeded")
                 states[nxt] = len(order)
                 order.append(nxt)
-                frontier.append(nxt)
-            outs.append(states[nxt])
-        edges[sid] = outs
+            edges.append((head, states[nxt], label))
+        head += 1
     n = len(order)
-    alive = [not holds(q_ast, s) for s in order]
-    changed = True
-    while changed:
-        changed = False
-        for i in range(n):
-            if not alive[i]:
-                continue
-            outs = edges[i]
-            if outs and not any(alive[j] for j in outs):
-                alive[i] = False
-                changed = True
-    for i in range(n):
-        if alive[i] and holds(p_ast, order[i]):
-            prefix = _path_to(edges, init_ids, i)
-            cycle = _alive_tail(edges, i, alive)
-            return LivenessResult(
-                name, False,
-                [order[j] for j in prefix],
-                [order[j] for j in cycle],
-            )
-    return LivenessResult(name, True, None, None)
-
-
-def _path_to(edges, srcs, dst):
-    """BFS path from ANY of `srcs` to dst (multi-initial-state specs)."""
-    if isinstance(srcs, int):
-        srcs = [srcs]
-    prev = {s: None for s in srcs}
-    q = deque(srcs)
-    while q:
-        u = q.popleft()
-        if u == dst:
-            break
-        for v in edges[u]:
-            if v not in prev:
-                prev[v] = u
-                q.append(v)
-    path, cur = [], dst
-    while cur is not None:
-        path.append(cur)
-        cur = prev[cur]
-    return list(reversed(path))
-
-
-def _alive_tail(edges, start, alive):
-    seen = {start: 0}
-    seq = [start]
-    cur = start
+    every = sorted({lab for _, _, lab in edges})
+    groups = ([frozenset(every)] if fairness is None
+              else [frozenset(labels) for _, labels in fairness])
+    preds: List[List[int]] = [[] for _ in range(n)]
+    for u, v, _ in edges:
+        preds[v].append(u)
+    enabled = [{u for u, _, lab in edges if lab in g} for g in groups]
+    alive = {i for i in range(n) if not holds(q_ast, order[i])}
     while True:
-        outs = [j for j in edges[cur] if alive[j]]
-        if not outs:
-            return seq
-        cur = outs[0]
-        if cur in seen:
-            return seq[seen[cur]:]
-        seen[cur] = len(seq)
-        seq.append(cur)
+        keep = set(alive)
+        for g, en in zip(groups, enabled):
+            reach = (alive - en) | {u for u, v, lab in edges if lab in g
+                                    and u in alive and v in alive}
+            stack = list(reach)
+            while stack:
+                for u in preds[stack.pop()]:
+                    if u in alive and u not in reach:
+                        reach.add(u)
+                        stack.append(u)
+            keep &= reach
+        if keep == alive:
+            break
+        alive = keep
+    bad = [i for i in sorted(alive) if holds(p_ast, order[i])]
+    if not bad:
+        return LivenessResult(name, True, None, None)
+    import numpy as np
+
+    from ..live.lasso import fair_lasso
+
+    mask = np.zeros(n, bool)
+    mask[sorted(alive)] = True
+    trigger = np.zeros(n, bool)
+    trigger[bad] = True
+    prefix, cycle, _, _ = fair_lasso(
+        n, n_init,
+        np.asarray([e[0] for e in edges], np.int32),
+        np.asarray([e[1] for e in edges], np.int32),
+        np.asarray([every.index(e[2]) for e in edges], np.int32),
+        mask, trigger,
+        [[every.index(lab) for lab in g if lab in every] for g in groups])
+    return LivenessResult(name, False, [order[j] for j in prefix],
+                          [order[j] for j in cycle])
 
 
 def violation_trace(system: ActionSystem, invariants: Dict[str, tuple],

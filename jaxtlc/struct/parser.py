@@ -327,24 +327,54 @@ def parse_module(src: str) -> Module:
 
 
 def _parse_spec_body(toks: List[Tok]) -> tuple:
-    """Spec == /\\ Init /\\ [][Next]_vars /\\ WF_vars(Next): extract the
-    temporal normal form structurally (("spec", init, next, fairness));
-    fairness is "wf_next" | None."""
-    text = " ".join(t.val for t in toks)
-    init = next_ = None
-    fairness = None
-    m = re.search(r"\[\]\s*\[\s*(\w+)\s*\]\s*_", text)
-    if m:
-        next_ = m.group(1)
-    m = re.search(r"WF_\w*\s*\(\s*(\w+)\s*\)", text)
-    if m and next_ and m.group(1) == next_:
-        fairness = "wf_next"
+    """Spec == /\\ Init /\\ [][Next]_vars /\\ WF_vars(A) ...: the
+    temporal normal form, structurally: ("spec", init, next, subscript,
+    conjuncts).  `conjuncts` is EVERY conjunct after Init and
+    [][Next]_sub, none dropped, each as (kind, subscript, body, text):
+    kind "WF" / "SF" with the formula's subscript and the text of its
+    body (`WF_vars(System)` -> ("WF", "vars", "System", ...)), or
+    "other" for anything else; `text` is the conjunct as written.  What
+    each means is the loader's to say (loader.declared_fairness)."""
+    parts: List[List[Tok]] = [[]]
+    depth = 0
     for t in toks:
-        if t.kind == "name" and t.val not in ("WF_vars", "SF_vars") \
-                and t.val != next_:
-            init = t.val
-            break
-    return ("spec", init, next_, fairness)
+        if t.kind == "land" and depth == 0:
+            parts.append([])
+            continue
+        if t.kind == "ltup" or (t.kind == "sym" and t.val in "([{"):
+            depth += 1
+        elif t.kind == "rtup" or (t.kind == "sym" and t.val in ")]}"):
+            depth -= 1
+        parts[-1].append(t)
+
+    def text(ts) -> str:
+        return " ".join(t.val for t in ts)
+
+    init = next_ = sub = None
+    conjuncts = []
+    for ts in parts:
+        if not ts:
+            continue  # the leading bullet
+        if next_ is None and ts[0].kind == "box" and len(ts) >= 5 \
+                and ts[1].val == "[" and ts[3].val == "]" \
+                and ts[4].val.startswith("_"):
+            next_ = ts[2].val
+            sub = (ts[4].val[1:] + text(ts[5:])).replace(" ", "")
+            continue
+        if init is None and len(ts) == 1 and ts[0].kind == "name":
+            init = ts[0].val
+            continue
+        head = ts[0].val
+        opens = next((k for k, t in enumerate(ts) if t.val == "("), None)
+        if ts[0].kind == "name" and head[:3] in ("WF_", "SF_") \
+                and opens is not None and ts[-1].val == ")":
+            conjuncts.append((
+                head[:2],
+                (head[3:] + text(ts[1:opens])).replace(" ", ""),
+                text(ts[opens + 1:-1]), text(ts)))
+        else:
+            conjuncts.append(("other", None, None, text(ts)))
+    return ("spec", init, next_, sub, tuple(conjuncts))
 
 
 # ---------------------------------------------------------------------------

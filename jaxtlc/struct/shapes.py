@@ -392,8 +392,15 @@ class ShapeInference:
         system.init_ast = self.init_ast
         system.next_ast = self.next_ast
         system._mentions_cache = {}
-        for st in system.initial_states():
-            for v, val in zip(self.variables, st):
+        system._init_product = False
+        # where Init is a product of per-variable domains, a variable's
+        # shape is the join over its own values: not one join a state
+        doms = system.init_product()
+        seeds = doms if doms is not None else [
+            (v, [st[k] for st in system.initial_states()])
+            for k, v in enumerate(self.variables)]
+        for v, vals in seeds:
+            for val in vals:
                 self.var_shapes[v] = join(
                     self.var_shapes[v], shape_of_value(val)
                 )

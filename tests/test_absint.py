@@ -420,7 +420,7 @@ def test_lintgate_specs_tree_clean():
     rc = run_gate("specs", out=out)
     text = out.getvalue()
     assert rc == 0, text
-    assert "lint gate: 10 spec(s)" in text
+    assert "lint gate: 11 spec(s)" in text
     # a model bounded by its cfg's CONSTRAINT passes with no finding
     assert "EWD998.toolbox/Model_1/MC.cfg: ok" in text
     assert "0 new error(s)" in text

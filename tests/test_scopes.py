@@ -204,6 +204,32 @@ def test_every_scope_of_the_route_is_in_its_table(route_tables):
     assert whiles and all(r[3] == 1 for r in whiles)
 
 
+def test_the_liveness_programs_bring_their_scopes(kept):
+    """A struct check with a PROPERTY (ISSUE 41) keeps four programs:
+    the segment engine and the liveness route's three, each under a
+    device scope of its own - `-xprof` lists them like the others."""
+    runtime.clear_engine_cache()
+    check(config=os.path.join(REPO, "specs", "EWD840.toolbox", "Model_1",
+                              "MC.cfg"),
+          frontend="struct", chunk=256, qcap=1 << 12, fpcap=1 << 14,
+          constants={"N": 3})
+    assert runtime.engine_cache_stats()["misses"] == 4
+    tables = scopes.tables()
+    outer = {chain[0] for t in tables for chain in t["chains"] if chain}
+    assert {"jaxtlc.live.enumerate", "jaxtlc.live.capture",
+            "jaxtlc.live.fixpoint", "jaxtlc.expand"} <= outer
+    # the capture runs the backend's own step, inside its scope
+    chains = {tuple(c) for t in tables for c in t["chains"]}
+    assert ("jaxtlc.live.capture", "jaxtlc.step.struct") in chains
+    # a second check of the process builds nothing
+    check(config=os.path.join(REPO, "specs", "EWD840.toolbox", "Model_1",
+                              "MC.cfg"),
+          frontend="struct", chunk=256, qcap=1 << 12, fpcap=1 << 14,
+          constants={"N": 3})
+    stats = runtime.engine_cache_stats()
+    assert (stats["hits"], stats["misses"], stats["evictions"]) == (4, 4, 0)
+
+
 # -- the reduction on synthetic planes --------------------------------------
 
 

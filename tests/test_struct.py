@@ -110,7 +110,7 @@ def test_assert_raises():
 def test_reference_initial_states():
     m = load(REF_CFG)
     assert m.root_name == "KubeAPI"
-    assert m.fairness == "wf_next"
+    assert [a for a, _ in m.fairness] == ["Next"]  # WF_vars(Next)
     assert m.constants["REQUESTS_CAN_FAIL"] is True
     assert m.constants["REQUESTS_CAN_TIMEOUT"] is True
     inits = m.system.initial_states()

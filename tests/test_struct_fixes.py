@@ -251,3 +251,67 @@ def test_canon_unaffected_cases():
     assert canon((("k", frozenset({2, 1})),)) == (("k", frozenset({1, 2})),)
     # the empty tuple stays the empty function/sequence
     assert canon(()) == ()
+
+
+# ---------------------------------------------------------------------------
+# 5. what Dijkstra's EWD840 needs of the compiler (ISSUE 41): a
+#    quantifier over a range with a bound read off the state; set
+#    difference with a bound variable; `@` under IF inside EXCEPT
+# ---------------------------------------------------------------------------
+
+_RING = """
+---- MODULE Ring ----
+EXTENDS Naturals
+VARIABLES pos, mark
+
+Nodes == 0 .. 3
+
+Init == pos = 3 /\\ mark = [i \\in Nodes |-> FALSE]
+
+Step(i) == /\\ pos = i
+           /\\ pos' = i - 1
+           /\\ \\E j \\in Nodes \\ {i} :
+                 mark' = [mark EXCEPT ![j] = IF j > i THEN TRUE ELSE @]
+
+Next == \\E i \\in Nodes \\ {0} : Step(i)
+
+Spec == Init /\\ [][Next]_<<pos, mark>>
+
+Below == \\E j \\in 0 .. pos : ~ mark[j]
+Above == \\A j \\in pos + 1 .. 3 : j > pos
+Pair == \\A i, j \\in pos .. 2 : i + j >= 2 * pos
+====
+"""
+
+
+def test_quantifier_over_a_range_with_a_state_bound(tmp_path):
+    """`\\E j \\in 0 .. pos`, `\\A j \\in pos + 1 .. 3` and a two-name
+    binder over `pos .. 2` (which is empty at pos = 3) compile to the
+    quantifier over the bounds' constant hull with membership as a
+    guard; the step's `Nodes \\ {i}` under a binder and `IF .. ELSE @` under EXCEPT
+    ride along.  Counts and verdict equal the host evaluator's."""
+    cfg = _write_model(tmp_path, "Ring", _RING,
+                       "SPECIFICATION\nSpec\nINVARIANT\nBelow\nAbove\n"
+                       "Pair\n")
+    m = load(cfg)
+    ro = bfs(m.system, m.invariants, check_deadlock=False)
+    assert not ro.violations and ro.distinct > 4
+    rd = check_struct(m, chunk=16, queue_capacity=64, fp_capacity=1024,
+                      check_deadlock=False)
+    assert rd.violation == 0
+    assert (rd.generated, rd.distinct, rd.depth) == (
+        ro.generated, ro.distinct, ro.depth)
+
+
+def test_a_false_range_quantifier_is_reported(tmp_path):
+    """The guard is real: `\\A j \\in 0 .. pos : mark[j]` fails on the
+    initial state, on the device as on the host."""
+    module = _RING.replace("Below ==", "Bad == \\A j \\in 0 .. pos : "
+                           "mark[j]\nBelow ==")
+    cfg = _write_model(tmp_path, "Ring", module,
+                       "SPECIFICATION\nSpec\nINVARIANT\nBad\n")
+    m = load(cfg)
+    assert bfs(m.system, m.invariants, check_deadlock=False).violations
+    rd = check_struct(m, chunk=16, queue_capacity=64, fp_capacity=1024,
+                      check_deadlock=False)
+    assert rd.violation != 0
