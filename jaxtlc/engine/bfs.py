@@ -296,8 +296,10 @@ class CheckResult(NamedTuple):
     # over the cfg's properties, the rows of the fairness constraints'
     # actions, the states of H = ~Q and of P, the P-states the fair
     # fixpoint kept (0 iff every property holds), its outer passes and
-    # sweeps, and the bytes of states or rows read to the host (0
-    # unless a property is violated: the lasso)
+    # sweeps, the rows at which it read a set at a row's destination
+    # (ISSUE 42: (outer + sweeps) x the store's rows where every sweep
+    # reads every row), and the bytes of states or rows read to the
+    # host (0 unless a property is violated: the lasso)
     live_states: int = None
     live_edges: int = None
     live_changed_edges: int = None
@@ -307,6 +309,7 @@ class CheckResult(NamedTuple):
     live_survivors: int = None
     live_outer: int = None
     live_sweeps: int = None
+    live_swept_rows: int = None
     live_edge_bytes: int = None
     live_host_bytes: int = None
 
@@ -322,7 +325,7 @@ STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
                  "live_states", "live_edges", "live_changed_edges",
                  "live_fair_edges", "live_h_states", "live_p_states",
                  "live_survivors", "live_outer", "live_sweeps",
-                 "live_edge_bytes", "live_host_bytes")
+                 "live_swept_rows", "live_edge_bytes", "live_host_bytes")
 
 
 def mesh_counters(result: CheckResult) -> dict:

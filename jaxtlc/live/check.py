@@ -245,7 +245,7 @@ def check_leads_to_device(
 # event, CheckResult and the `final` event carry them (`live_` + name)
 LIVE_COUNTERS = ("states", "edges", "changed_edges", "fair_edges",
                  "h_states", "p_states", "survivors", "outer", "sweeps",
-                 "edge_bytes", "host_bytes")
+                 "swept_rows", "edge_bytes", "host_bytes")
 
 
 class StructLiveResult(NamedTuple):
@@ -322,7 +322,7 @@ def check_struct_properties(
     from ..obs.spans import span
     from ..runtime import aot_build, engine_key
     from .capture import make_device_capture, make_scoped_enumerator
-    from .fixpoint import FAIR_STATS, PREFIX_BLOCK, make_fair_fixpoint
+    from .fixpoint import SWEEP_BLOCK, fair_stats, make_fair_fixpoint
 
     fp_index = DEFAULT_FP_INDEX if fp_index is None else fp_index
     seed = DEFAULT_SEED if seed is None else seed
@@ -330,9 +330,13 @@ def check_struct_properties(
     V, E = int(n_states), int(n_edges)
     W = (cdc.nbits + 31) // 32
     e_cap = E + chunk * backend.n_lanes
+    K = len(model.fairness)
     # dst + act of the store, the fixpoint's cut of them, and a sweep's
-    # few vectors at row width; the state array and its masks
-    need = e_cap * 5 * 2 + E * 14 + (V + 2 * chunk) * (4 * W + 16)
+    # few vectors at row width; the state array and its masks; a
+    # fairness group's compacted destinations and row bounds, and their
+    # sort's operands once
+    need = (e_cap * 5 * 2 + E * 14 + (V + 2 * chunk) * (4 * W + 16)
+            + K * (4 * E + 4 * (V + 1)) + 16 * E * bool(K))
     budget = device_budget_bytes()
     if budget is not None and need > 0.8 * budget:
         raise LiveTooLarge(
@@ -376,9 +380,11 @@ def check_struct_properties(
             + ("a successor outside the enumerated set" if missing else
                f"{n_rows} successor rows walked, {E} counted"))
     # the analysis reads the changed rows alone: its programs are built
-    # for their count (a constant of the model), in whole prefix blocks
-    e_rows = max(PREFIX_BLOCK, -(-n_changed // PREFIX_BLOCK) * PREFIX_BLOCK)
+    # for their count (a constant of the model), in whole sweep blocks
+    e_rows = max(1, -(-n_changed // SWEEP_BLOCK)) * SWEEP_BLOCK
     dst, act = graph.dst[:e_rows], graph.act[:e_rows]
+    if e_rows > e_cap:  # a store shorter than its last block
+        dst, act = (jnp.pad(x, (0, e_rows - e_cap)) for x in (dst, act))
     row_start = graph.row_start
     del graph
     fairness = tuple(model.fairness)
@@ -401,16 +407,17 @@ def check_struct_properties(
                          groups=[list(g) for g in groups])))
             z, stats = fix((dst, act, row_start, jnp.int32(n_changed),
                             p, h))
-            stats = dict(zip(FAIR_STATS, (int(x) for x in
-                                          jax.device_get(stats))))
-            sp.attrs.update(outer=stats["outer"], sweeps=stats["sweeps"])
+            stats = fair_stats(stats)
+            sp.attrs.update(outer=stats["outer"], sweeps=stats["sweeps"],
+                            swept_rows=stats["swept_rows"])
         with span("live.verdict", property=name):
             counters = dict(
                 states=V, edges=n_rows, changed_edges=n_changed,
                 fair_edges=stats["fair_edges"], h_states=stats["h_states"],
                 p_states=stats["p_states"], survivors=stats["survivors"],
                 outer=stats["outer"], sweeps=stats["sweeps"],
-                edge_bytes=edge_bytes, host_bytes=0)
+                swept_rows=stats["swept_rows"], edge_bytes=edge_bytes,
+                host_bytes=0)
             alive = np.asarray(z) if keep_alive else None
             if stats["survivors"] == 0:
                 out.append(StructLiveResult(name, True, None, None,

@@ -94,13 +94,26 @@ def surviving_set(
 # ---------------------------------------------------------------------------
 
 PREFIX_BLOCK = 512  # rows of the prefix count's triangular product
+# rows a block trip of a sweep reads (a multiple of PREFIX_BLOCK; the
+# edge store is cut to whole blocks).  On a TPU v5e a trip is 2.3 us
+# and 7.5 ns a row: 32 us here (PERF.md section 6, PR 42, Step 0)
+SWEEP_BLOCK = 4096
 # what a sweep gathers from: one word a state (an element gather's price
 # is per element, whatever its width)
 REACH_DTYPE = jnp.int32
 
-# the stats vector the fair fixpoint gives beside Z
+# what the fair fixpoint gives beside Z (`fair_stats`)
 FAIR_STATS = ("survivors", "z_states", "h_states", "p_states",
-              "fair_edges", "outer", "sweeps")
+              "fair_edges", "outer", "sweeps", "swept_rows")
+
+
+def fair_stats(stats) -> dict:
+    """The program's stats vector on the host, by FAIR_STATS.  The
+    device counts the rows it read in blocks of SWEEP_BLOCK (an int32
+    of rows would wrap at 118 reads of the benchmark's whole store)."""
+    out = dict(zip(FAIR_STATS, (int(x) for x in jax.device_get(stats))))
+    out["swept_rows"] *= SWEEP_BLOCK
+    return out
 
 
 def prefix_counts(x) -> jnp.ndarray:
@@ -126,10 +139,10 @@ def make_fair_fixpoint(n_states: int, n_rows: int, e_rows: int,
     """(init_fn, program) of the fair-cycle analysis, for
     `runtime.aot_build`.  The program takes (dst [e_rows], act [e_rows],
     row_start [n_states + 1], n_changed, p [n_rows], h [n_rows]) - the
-    capture's changed rows cut to `e_rows`, and the property's masks
-    over the enumerator's rows - and gives (Z [n_states] bool, stats
-    [len(FAIR_STATS)] int32).  `label_groups[k]` are the label ids of
-    A_k (static).
+    capture's changed rows cut to `e_rows` (whole SWEEP_BLOCKs), and the
+    property's masks over the enumerator's rows - and gives (Z
+    [n_states] bool, stats int32: `fair_stats`).  `label_groups[k]` are
+    the label ids of A_k (static).
 
     G = the states, their changed rows and a stuttering self-loop at
     every state; a_k[e] = act[e] in A_k; en_k[s] = some row out of s
@@ -145,13 +158,36 @@ def make_fair_fixpoint(n_states: int, n_rows: int, e_rows: int,
     constraint WF_vars(Next) it is `surviving_set`'s set to the bit.
     `survivors` = |P /\\ Z|: 0 iff P ~> Q holds.
 
-    A sweep is one element gather at row width (a state's word of the
-    set at each row's destination), the rows' prefix counts
-    (`prefix_counts`) and one gather of those at state width (rows are
-    in source order: a state's support is the count between its row
-    bounds) - no scatter.  An outer pass costs one more such gather
-    (which rows stay in Z)."""
-    V, E = n_states, e_rows
+    A set is read at a row's destination (an element gather, the one
+    expensive operation here: its price is per row) only for rows whose
+    answer the equations use, `read_at`'s blocks of SWEEP_BLOCK rows:
+
+    * acc_k reads Z at A_k's rows alone (`stays` is never used off an
+      a_k row).  Their destinations are compacted once a call, in source
+      order (one sort), with their own row bounds (the prefix counts of
+      a_k at `row_start`); a pass reads them as far as there are any.
+    * a sweep of pre* (r2 = r \\/ (z /\\ the sources of rows into r))
+      reads r only in the blocks that hold a row out of a candidate, a
+      state of z /\\ ~r: for s in r or s outside z the term adds nothing
+      to r2, whatever its rows say.  Rows are in source order, so the
+      blocks are found from the prefix counts of the candidates at each
+      block's first and last source state - no scatter, no gather at
+      row width.
+
+    Where every block is read (fairness on every row; an accepting set
+    so small that most of Z is a candidate) the trips cost 142.2 ms at
+    18.2M rows against 137.1 for one gather at every row (the same
+    Step 0; inside the program 9.5 ms a read, review round): no second
+    path for that.  Z, `outer` and `sweeps` are those
+    of the gather at every row, to the bit; `swept_rows` says how many
+    rows were read (with one group, (outer + sweeps) * e_rows where
+    every read takes every block).
+    The by-source reduction is the rows' prefix counts (`prefix_counts`)
+    gathered at the states' row bounds - no scatter."""
+    V, E, B = n_states, e_rows, SWEEP_BLOCK
+    if E % B:
+        raise ValueError(f"e_rows {E} is not whole blocks of {B} rows")
+    NB = E // B
     groups = tuple(tuple(int(a) for a in g) for g in label_groups)
 
     def init_fn():
@@ -164,52 +200,99 @@ def make_fair_fixpoint(n_states: int, n_rows: int, e_rows: int,
             return analyse(*carry)
 
     def analyse(dst, act, row_start, n_changed, p, h):
-        live = jnp.arange(E, dtype=jnp.int32) < n_changed
+        rows = jnp.arange(E, dtype=jnp.int32)
+        lane = jnp.arange(B, dtype=jnp.int32)
+        natural = jnp.arange(NB, dtype=jnp.int32)
         p, h = p[:V], h[:V]
 
-        def by_source(rows_mask):
-            """[V] bool: the state has a row of the mask."""
-            c = prefix_counts(rows_mask)[row_start]
+        def by_source(rows_mask, bounds):
+            """[V] bool: the state has a row of the mask between its
+            bounds."""
+            c = prefix_counts(rows_mask)[bounds]
             return c[1:] > c[:-1]
 
-        def at_dst(states_mask):
-            """[E] bool: the row is live and ends in the set."""
-            return live & (states_mask.astype(REACH_DTYPE)[dst] != 0)
+        def read_at(states_mask, at, n_live, blocks, n_blocks):
+            """[E] bool: row j < n_live ends in the set (`at[j]` its
+            destination), for the rows of the first `n_blocks` entries
+            of `blocks`; False at the rows of every other block."""
+            words = states_mask.astype(REACH_DTYPE)
 
-        a = [live & functools.reduce(
-            jnp.logical_or, [act == lab for lab in g],
-            jnp.zeros(E, bool)) for g in groups]
-        en = [by_source(a_k) for a_k in a]
+            def trip(st):
+                mask, t = st
+                start = blocks[t] * B
+                ends = words[lax.dynamic_slice(at, (start,), (B,))] != 0
+                return lax.dynamic_update_slice(
+                    mask, ends & (start + lane < n_live), (start,)), t + 1
 
-        def reach_back(z, acc, sweeps):
+            mask, _ = lax.while_loop(
+                lambda st: st[1] < n_blocks, trip,
+                (jnp.zeros(E, bool), jnp.int32(0)))
+            return mask
+
+        # site 1, once a call and group: A_k's rows' destinations to
+        # the front in source order, and the states' bounds among them
+        live = rows < n_changed
+        fair = []
+        for g in groups:
+            a_k = live & functools.reduce(
+                jnp.logical_or, [act == lab for lab in g],
+                jnp.zeros(E, bool))
+            bounds_k = prefix_counts(a_k)[row_start]
+            _, dst_k = lax.sort(
+                (jnp.where(a_k, rows, jnp.int32(E)), dst), num_keys=1,
+                is_stable=False)
+            fair.append((dst_k, bounds_k, bounds_k[V],
+                         bounds_k[1:] > bounds_k[:-1]))
+
+        # site 2, once a call: each block's first and last source state
+        # (the V past the live rows), as bounds into the candidates'
+        # prefix counts
+        first = jnp.searchsorted(row_start, natural * B, side="right") - 1
+        last = jnp.searchsorted(row_start, natural * B + (B - 1),
+                                side="right") - 1
+        lo, hi = jnp.minimum(first, V), jnp.minimum(last + 1, V)
+        has_row = row_start[1:] > row_start[:-1]
+
+        def reach_back(z, acc, sweeps, swept):
             """pre*_z(acc): the states of z with a path inside z to acc."""
             def body(st):
-                r, _, n = st
-                r2 = r | (z & by_source(at_dst(r)))
-                return r2, (r2 != r).any(), n + 1
+                r, _, n, swept = st
+                cand = z & ~r & has_row
+                c = prefix_counts(cand)
+                hit = c[hi] > c[lo]
+                n_hit = hit.sum(dtype=jnp.int32)
+                into_r = read_at(
+                    r, dst, n_changed,
+                    lax.sort(jnp.where(hit, natural, jnp.int32(NB))), n_hit)
+                r2 = r | (cand & by_source(into_r, row_start))
+                return r2, (r2 != r).any(), n + 1, swept + n_hit
 
-            r, _, sweeps = lax.while_loop(
-                lambda st: st[1], body, (acc, jnp.bool_(True), sweeps))
-            return r, sweeps
+            r, _, sweeps, swept = lax.while_loop(
+                lambda st: st[1], body,
+                (acc, jnp.bool_(True), sweeps, swept))
+            return r, sweeps, swept
 
         def outer(st):
-            z, _, n_outer, sweeps = st
-            stays = at_dst(z)
+            z, _, n_outer, sweeps, swept = st
             keep = z
-            for a_k, en_k in zip(a, en):
-                acc = z & (~en_k | by_source(a_k & stays))
-                r, sweeps = reach_back(z, acc, sweeps)
+            for dst_k, bounds_k, n_k, en_k in fair:
+                n_fair = (n_k + (B - 1)) // B
+                stays = read_at(z, dst_k, n_k, natural, n_fair)
+                acc = z & (~en_k | by_source(stays, bounds_k))
+                r, sweeps, swept = reach_back(z, acc, sweeps,
+                                              swept + n_fair)
                 keep = keep & r
-            return keep, (keep != z).any(), n_outer + 1, sweeps
+            return keep, (keep != z).any(), n_outer + 1, sweeps, swept
 
-        z, _, n_outer, sweeps = lax.while_loop(
+        z, _, n_outer, sweeps, swept = lax.while_loop(
             lambda st: st[1], outer,
-            (h, jnp.bool_(bool(groups)), jnp.int32(0), jnp.int32(0)))
+            (h, jnp.bool_(bool(groups)), jnp.int32(0), jnp.int32(0),
+             jnp.int32(0)))
         stats = jnp.stack([
             (p & z).sum(dtype=jnp.int32), z.sum(dtype=jnp.int32),
             h.sum(dtype=jnp.int32), p.sum(dtype=jnp.int32),
-            sum((a_k.sum(dtype=jnp.int32) for a_k in a), jnp.int32(0)),
-            n_outer, sweeps])
+            sum((n_k for _, _, n_k, _ in fair), jnp.int32(0)),
+            n_outer, sweeps, swept])
         return z, stats
 
     return init_fn, jax.jit(program)

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from jaxtlc.api import CheckRequest, run_check
+from jaxtlc.live.fixpoint import SWEEP_BLOCK
 from jaxtlc.struct.loader import StructLoadError, load
 from jaxtlc.struct import oracle as so
 
@@ -161,7 +162,18 @@ def test_counts_and_liveness_equal_the_reference(n, pins, tmp_path):
     assert ev["live_host_bytes"] == 0 == r.live_host_bytes
     final = next(e for e in events if e["event"] == "final")
     assert final["verdict"] == "ok"
-    assert final["live_sweeps"] == r.live_sweeps > 0
+    # 3N - 1 passes peel the ring back from terminationDetected, each
+    # pre* closing in its one sweep; the rows read are counted once and
+    # carried to all three places, well under a read of every row a
+    # sweep (ISSUE 42)
+    assert final["live_sweeps"] == r.live_sweeps == 3 * n - 1
+    assert final["live_outer"] == r.live_outer == ev["live_outer"] \
+        == 3 * n - 1
+    e_rows = -(-r.live_changed_edges // SWEEP_BLOCK) * SWEEP_BLOCK
+    assert 0 < r.live_swept_rows < (r.live_sweeps + r.live_outer) * e_rows
+    assert final["live_swept_rows"] == ev["live_swept_rows"] \
+        == r.live_swept_rows
+    assert r.live_swept_rows % SWEEP_BLOCK == 0
     assert final["live_edges"] == r.generated - 2 ** (2 * n) * n
     # the spans of the route, inside the journal's one `spans` event and
     # in order: `live` after `loop`
