@@ -1425,6 +1425,7 @@ def _run_check_struct(args, spec) -> int:
         if kit.live_results is not None or not spec.properties \
                 or r.violation != 0 or r.queue_left:
             return r, None
+        from .engine.bfs import lookup_counters
         from .live.check import (
             LIVE_COUNTERS, LiveTooLarge, check_struct_properties)
 
@@ -1439,11 +1440,11 @@ def _run_check_struct(args, spec) -> int:
         if why_host is None and todo:
             try:
                 with span("live"):
+                    backend = get_backend(sm, spec.check_deadlock,
+                                          bounds=bounds,
+                                          coverage=args.coverage)
                     found = check_struct_properties(
-                        sm, get_backend(sm, spec.check_deadlock,
-                                        bounds=bounds,
-                                        coverage=args.coverage),
-                        todo, n_states=r.distinct,
+                        sm, backend, todo, n_states=r.distinct,
                         n_edges=r.generated - system.initial_count(),
                         chunk=args.chunk, fp_capacity=args.fpcap,
                         fp_index=spec.fp_index)
@@ -1476,7 +1477,9 @@ def _run_check_struct(args, spec) -> int:
             r = r._replace(**{
                 f"live_{k}": found[0].counters[k] if k in once
                 else sum(res.counters[k] for res in found)
-                for k in LIVE_COUNTERS})
+                for k in LIVE_COUNTERS},
+                # P and Q were compiled by now: their field reads count
+                **lookup_counters(backend))
         violated = any(not res.holds for res in found)
         return r, ("liveness_violation" if violated else None)
 

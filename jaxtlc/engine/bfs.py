@@ -258,13 +258,22 @@ class CheckResult(NamedTuple):
     # of an exhaustive run, once); lanes that fired (the per-action
     # generated totals summed: generated less the initial states); and
     # whether a trap of the compiled step (range, declared-universe or
-    # compaction overflow: VIOL_SLOT_OVERFLOW) halted the run
+    # compaction overflow: VIOL_SLOT_OVERFLOW) halted the run; and the
+    # forms in which the compiled functions read a field of an
+    # enum-coded value (struct.compile.table_form; distinct (table,
+    # value) pairs of the step, the invariants, the constraint and the
+    # liveness predicates): a literal, arithmetic on the code, or a
+    # gather from a host table - 0 of the last while every table a
+    # model looks up is a digit of its code or a constant
     step_lanes: int = None
     step_slots: int = None
     state_words: int = None
     states_expanded: int = None
     lane_fires: int = None
     struct_traps: int = None
+    lookup_const: int = None
+    lookup_arith: int = None
+    lookup_gather: int = None
     # symmetry-reduced single-device runs only (telemetry; None
     # elsewhere): the order of the group the tournament minimises over
     # (identity included) and the constant sets it permutes; valid
@@ -319,6 +328,7 @@ MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
                  "commit_rows", "enqueue_segments")
 STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
                  "states_expanded", "lane_fires", "struct_traps",
+                 "lookup_const", "lookup_arith", "lookup_gather",
                  "sym_perms", "sym_sets", "canon_rows", "canon_moved",
                  "sym_cert_checks", "sym_cert_trips", "constraint_rows",
                  "constraint_discarded", "constraint_names",
@@ -358,7 +368,16 @@ def with_step_counters(result: CheckResult, backend) -> CheckResult:
         states_expanded=result.distinct - result.queue_left,
         lane_fires=sum(result.action_generated.values()),
         struct_traps=int(result.violation == VIOL_SLOT_OVERFLOW),
+        **lookup_counters(backend),
     )
+
+
+def lookup_counters(backend) -> dict:
+    """`lookup_const` / `lookup_arith` / `lookup_gather` of a struct
+    backend as they stand: read after the functions that count were
+    traced (the engine's build; the liveness route's predicates)."""
+    return {f"lookup_{form}": n
+            for form, n in backend.cdc.lookup_counts().items()}
 
 
 def carry_done(carry: EngineCarry) -> bool:

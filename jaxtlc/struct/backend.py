@@ -391,6 +391,8 @@ def struct_backend(model: StructModel,
     backend.cdc.trap_stats = trap_stats
     # the static fan before compaction (CheckResult.step_lanes)
     backend.cdc.static_lanes = len(labels)
+    # the forms its field reads took (CheckResult.lookup_*)
+    backend.cdc.lookup_counts = compiler.lookup_counts
     # a state predicate compiled as the invariants are ([B, F] -> bool
     # [B]): the liveness route's P and Q (live.check)
     backend.cdc.compile_predicate = compiler.build_invariant

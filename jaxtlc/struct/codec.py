@@ -251,10 +251,13 @@ class StructCodec:
         """`structural`: variables whose record / function shape is laid
         out field by field (RecNode) whatever the size of its universe.
         The variables a cfg's CONSTRAINT reads (struct.backend): its
-        predicate decodes every candidate successor, and a field of an
-        enum-coded record is a table gather a candidate where a field
-        of a structural one is the column itself (PERF.md section 5,
-        PR 39: the gathers were 35 % of the EWD998 loop)."""
+        predicate decodes every candidate successor, and a field of a
+        structural record is the column itself.  A field of an
+        enum-coded one was a table gather a candidate when this was
+        added (PERF.md section 5, PR 39: 35 % of the EWD998 loop);
+        since ISSUE 43 it is arithmetic on the code wherever the
+        universe is the product of its fields' (compile.table_form),
+        and a gather only where it is not."""
         self.variables = variables
         self.layouts = [
             RecNode(var_shapes[v]) if v in structural and isinstance(

@@ -9,7 +9,11 @@ compiler feed, now derived from the module text alone.
 TPU-first design decisions (vs TLC's heap interpreter):
 
 * Enumerated universes become integer lanes; record field access is a
-  precomputed table gather ([U] int32 per (record-universe, field)).
+  host table ([U] int32 per (record-universe, field)) read in the form
+  its values allow (table_form, LaneCompiler.look_up): a mixed-radix
+  digit of the code by two integer operations where the universe is
+  the product of its fields', a literal where every entry is equal,
+  and a gather from the table only where it is neither.
 * Sets over record universes are bitmask planes; set algebra is
   bitwise; quantifiers/filters/maps/CHOOSE over them LIFT the bound
   variable onto a fresh trailing tensor axis (the binder becomes the
@@ -46,6 +50,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..spec.labels import DEFAULT_INIT
 from .codec import EnumLeaf, MaskLeaf, RecNode, SeqNode, StructCodec, layout_of
@@ -95,6 +100,36 @@ def compact_width(n_lanes: int) -> int:
     if n_lanes <= COMPACT_MIN_LANES:
         return n_lanes
     return max(32, n_lanes // 8)
+
+
+# the forms LaneCompiler.look_up reads a host table in
+LOOKUP_FORMS = ("const", "arith", "gather")
+
+
+def table_form(table: np.ndarray) -> tuple:
+    """How a host table over a universe of U codes is read at a code,
+    decided by ALL of its values: ("const",) where every entry is equal
+    (a record's presence table); ("arith", s, r) where the whole table
+    is `(arange(U) // s) % r`, one mixed-radix digit of the code (a
+    field of a function or record whose universe is the product of its
+    fields' - the codec's own order; the identity is s = 1, r >= U);
+    ("gather",) for anything else (a translation between unrelated
+    universes, a CHOOSE rank, an arbitrary predicate)."""
+    t = np.asarray(table).astype(np.int64)
+    if (t == t[0]).all():
+        return ("const",)
+    # a digit reads 0 below s and 1 at s, and is back at 0 at s * r
+    s = int(np.argmax(t != 0))
+    if s:
+        back = np.flatnonzero(t[s::s] == 0)
+        r = int(back[0]) + 1 if back.size else (len(t) - 1) // s + 1
+        if np.array_equal(t, (np.arange(len(t)) // s) % r):
+            return ("arith", s, r)
+    return ("gather",)
+
+
+def _pow2(n: int) -> bool:
+    return n & (n - 1) == 0
 
 
 class TrapPolicy:
@@ -324,6 +359,18 @@ def _binop_arrs(a_arr, a_d, b_arr, b_d):
     return _align(a_arr, a_d, d), _align(b_arr, b_d, d), d
 
 
+def _digit(code, n_codes: int, s: int, r: int):
+    """`(code // s) % r` of an enum code clamped into its universe of
+    `n_codes` (shift and mask where s, r are powers of two; no `% r`
+    for the leading digit)."""
+    a = jnp.clip(code, 0, n_codes - 1)
+    if s > 1:
+        a = a >> (s.bit_length() - 1) if _pow2(s) else lax.div(a, s)
+    if (n_codes - 1) // s >= r:
+        a = a & (r - 1) if _pow2(r) else lax.rem(a, r)
+    return a
+
+
 # ---------------------------------------------------------------------------
 # Compiler
 # ---------------------------------------------------------------------------
@@ -353,7 +400,13 @@ class LaneCompiler:
         # never primes them, so build_step passes them through verbatim
         self.sweep_vars = frozenset(sweep_vars)
         self._field_tables: Dict = {}
-        self._look_ups: Dict = {}  # per trace: (table, value) -> gather
+        self._table_forms: Dict = {}  # id(table) -> (table, table_form)
+        self._look_ups: Dict = {}  # per trace: (table, value) -> read
+        # look-ups emitted by form (LOOKUP_FORMS), one tally a built
+        # function (step, invariant, constraint, coverage walk,
+        # predicate), in distinct (table, value) pairs of its last trace
+        self._tallies: List[Dict[str, int]] = []
+        self._tally: Dict[str, int] = self._new_tally()
         self._trans_tables: Dict = {}
         self._pred_tables: Dict = {}
         self.trap = None  # LB set when a guard-unreachable encode happens
@@ -439,18 +492,56 @@ class LaneCompiler:
         """table[le] for a host table over le's universe (absent codes
         read entry 0; callers mask them).  A lifted binder is the
         universe's arange, so its look-up is the table laid on its
-        axis: a constant, no gather."""
+        axis: a constant, no gather.  Any other value is read in the
+        form the table's own values allow (table_form): a literal, the
+        code's mixed-radix digit by two integer operations, or - only
+        where the table is neither - a gather from the host constant.
+        All three agree at every code a gather could be handed: -1
+        reads entry 0 and a code past the universe the last entry, as
+        the index's clamp does."""
         if le.universe:
             return jnp.asarray(table.reshape(le.arr.shape))
-        # one gather per (table, value) a trace: a state variable's
+        # one read per (table, value) a trace: a state variable's
         # field is read by many lanes
         key = (id(table), id(le.arr))
         hit = self._look_ups.get(key)
         if hit is None:
-            hit = (table, le.arr,
-                   jnp.asarray(table)[jnp.maximum(le.arr, 0)])
+            form = self._table_forms.get(id(table))
+            if form is None:
+                form = (table, table_form(table))
+                self._table_forms[id(table)] = form
+            kind, *digit = form[1]
+            self._tally[kind] += 1
+            if kind == "const":
+                out = jnp.full(le.arr.shape, table[0])
+            elif kind == "arith":
+                out = _digit(le.arr, len(table), *digit).astype(table.dtype)
+            else:
+                out = jnp.asarray(table)[jnp.maximum(le.arr, 0)]
+            hit = (table, le.arr, out)
             self._look_ups[key] = hit
         return hit[2]
+
+    def _new_tally(self) -> Dict[str, int]:
+        tally = dict.fromkeys(LOOKUP_FORMS, 0)
+        self._tallies.append(tally)
+        return tally
+
+    def _begin_trace(self, tally: Dict[str, int], fields) -> Dict[str, LV]:
+        """A trace of one built function starts: its tally restarts (a
+        retrace reports one walk's numbers, not a running sum) and the
+        state's variables are decoded."""
+        tally.update(dict.fromkeys(LOOKUP_FORMS, 0))
+        self._tally = tally
+        return dict(self.decode_state(fields))
+
+    def lookup_counts(self) -> Dict[str, int]:
+        """Look-ups emitted by form over every function this compiler
+        has built and traced (CheckResult.lookup_const / _arith /
+        _gather): `gather` is 0 while every table a model looks up is a
+        digit of its code or a constant."""
+        return {form: sum(t[form] for t in self._tallies)
+                for form in LOOKUP_FORMS}
 
     def trans_table(self, src: EnumLeaf, dst: EnumLeaf) -> np.ndarray:
         key = (id(src), id(dst))
@@ -2636,6 +2727,7 @@ class LaneCompiler:
         (succs [B,L,F], valid [B,L], ovf [B,L], afail [B,L]); also sets
         self.labels (per-lane action names) on first run."""
         self.labels: Optional[List[str]] = None
+        tally = self._new_tally()
 
         def step(fields):
             B = fields.shape[0]
@@ -2643,7 +2735,7 @@ class LaneCompiler:
             # then jit) report one compile's numbers, not a running sum
             self.trap_sites = 0
             self.elided_traps = 0
-            env0 = dict(self.decode_state(fields))
+            env0 = self._begin_trace(tally, fields)
             lanes = self.walk_lanes(next_ast, env0)
             self.static_lanes = len(lanes)
             labels = []
@@ -2739,13 +2831,14 @@ class LaneCompiler:
         across retraces.  Pure telemetry - the result feeds no control
         flow."""
         self.cov = CovCollector()
+        tally = self._new_tally()
 
         def cov_fn(fields, mask, valid):
             B = fields.shape[0]
             saved = (self.trap_sites, self.elided_traps)
             self.cov.begin()
             try:
-                env0 = dict(self.decode_state(fields))
+                env0 = self._begin_trace(tally, fields)
                 self.walk_lanes(next_ast, env0)
             finally:
                 contribs = self.cov.end()
@@ -2783,10 +2876,11 @@ class LaneCompiler:
 
     def build_invariant(self, ast):
         """inv(fields [B,F]) -> ok [B] bool."""
+        tally = self._new_tally()
 
         def inv(fields):
             B = fields.shape[0]
-            env = dict(self.decode_state(fields))
+            env = self._begin_trace(tally, fields)
             ctx = LaneCtx()
             r = self.comp(ast, env, ctx)
             return self._guard_arr(r, B)

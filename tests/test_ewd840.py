@@ -175,6 +175,13 @@ def test_counts_and_liveness_equal_the_reference(n, pins, tmp_path):
         == r.live_swept_rows
     assert r.live_swept_rows % SWEEP_BLOCK == 0
     assert final["live_edges"] == r.generated - 2 ** (2 * n) * n
+    # every field read of `active` / `color` is a digit of its code
+    # (ISSUE 43): the step's, the invariants' and P's and Q's, which
+    # are compiled after the safety run and counted all the same
+    assert (final["lookup_const"], final["lookup_arith"],
+            final["lookup_gather"]) == (
+        r.lookup_const, r.lookup_arith, r.lookup_gather)
+    assert r.lookup_gather == 0 and r.lookup_arith > 2 * n
     # the spans of the route, inside the journal's one `spans` event and
     # in order: `live` after `loop`
     names = [row[0] for e in events if e["event"] == "spans"

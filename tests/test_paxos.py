@@ -261,6 +261,10 @@ def test_run_check_counts_spans_and_counters(tmp_path, model, backend):
                                   "lane_fires", "struct_traps")} == dict(
         step_lanes=80, step_slots=32, state_words=3,
         states_expanded=3921, lane_fires=23562, struct_traps=0)
+    # maxBal, maxVBal, maxVal at three acceptors: digits of their codes
+    assert (final["lookup_const"], final["lookup_arith"],
+            final["lookup_gather"]) == (21, 18, 0) == (
+        r.lookup_const, r.lookup_arith, r.lookup_gather)
     names = [row[0] for e in events if e["event"] == "spans"
              for row in e["rows"]]
     for want in ("build.struct.load", "build.struct", "build",
