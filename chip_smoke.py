@@ -129,7 +129,7 @@ def leg_l1():
 
 def leg_l2():
     from jaxtlc.config import scaled_config
-    from jaxtlc.engine.bfs import resolve_deferred, resolve_sort_free
+    from jaxtlc.engine.bfs import resolve_deferred
     from jaxtlc.engine.checkpoint import check_with_checkpoints
 
     cfg, kw = scaled_config()
@@ -141,7 +141,6 @@ def leg_l2():
     return dict(
         counts=list(got), run_s=round(r.wall_s, 3),
         segments=r.iterations, geometry=kw,
-        sort_free=resolve_sort_free(None, kw["chunk"]),
         deferred_inv=resolve_deferred(None, kw["chunk"]),
     )
 

@@ -168,31 +168,6 @@ def _build_covsharded():
                 n_lanes=b.n_lanes, fp_capacity=_TINY["fp_capacity"])
 
 
-def _build_sortfree():
-    # the hash-slab commit engine (ISSUE 12): the same TwoPhase model
-    # as "struct" but committed through the sort-free dedup, with the
-    # obs ring + coverage plane riding along - the slab scatter/gather
-    # path and its sorted-fallback cond cannot ship unaudited
-    import os
-
-    from ..engine.bfs import make_backend_engine
-    from ..struct.cache import get_backend
-    from ..struct.loader import load
-
-    d = _specs_dir()
-    if d is None:
-        raise FileNotFoundError("specs/ directory not found")
-    model = load(os.path.join(d, "TwoPhase.toolbox", "Model_1",
-                              "MC.cfg"))
-    b = get_backend(model, True, coverage=True)
-    assert b.coverage is not None, "sortfree factory must carry a plane"
-    init_fn, run_fn, step_fn = make_backend_engine(
-        b, donate=False, obs_slots=8, sort_free=True, **_TINY
-    )
-    return dict(init_fn=init_fn, run_fn=run_fn, step_fn=step_fn,
-                n_lanes=b.n_lanes, fp_capacity=_TINY["fp_capacity"])
-
-
 def _build_deferred():
     # the distinct-first deferred-evaluation engine (ISSUE 15): the
     # same TwoPhase model as "struct" but with invariant + certificate
@@ -493,7 +468,6 @@ FACTORIES: Dict[str, Callable[[], dict]] = {
     "sharded": _build_sharded,
     "shardspill": _build_shardspill,
     "sim": _build_sim,
-    "sortfree": _build_sortfree,
     "spill": _build_spill,
     "struct": _build_struct,
     "sweep": _build_sweep,

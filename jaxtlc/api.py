@@ -53,10 +53,6 @@ class CheckRequest:
     sharded: int = 0
     chunk: int = 1024
     pipeline: bool = False
-    # tri-state -sort-free/-no-sort-free: None = auto (the engines
-    # resolve it, engine.bfs.resolve_sort_free: the sorted dedup at
-    # every chunk, or a resumed checkpoint's recorded mode)
-    sortfree: Optional[bool] = None
     # tri-state -deferred-inv/-no-deferred-inv (ISSUE 15): None = auto
     # (resolved against the chunk, engine.bfs.resolve_deferred) -
     # invariant/certificate evaluation on the fresh-insert claimants
@@ -303,7 +299,6 @@ def _run_check(args) -> int:
         params=dict(chunk=args.chunk, queue_capacity=args.qcap,
                     fp_capacity=args.fpcap, sharded=args.sharded,
                     pipeline=args.pipeline,
-                    sort_free=_sort_free(args),
                     deferred=_deferred(args),
                     obs_slots=_obs_slots(args)),
         # handed over whole, after the dict above: this frame lies
@@ -550,7 +545,6 @@ def _dispatch_check(args, spec, log):
                 pipeline=args.pipeline,
                 obs_slots=_obs_slots(args),
                 coverage=args.coverage,
-                sort_free=args.sortfree,
                 deferred=args.deferredinv,
                 opts=_sup_opts(args, log),
             )
@@ -568,7 +562,6 @@ def _dispatch_check(args, spec, log):
                                     coverage=args.coverage),
             pipeline=args.pipeline,
             obs_slots=_obs_slots(args),
-            sort_free=args.sortfree,
             deferred=args.deferredinv,
         ), None
     if args.fpset == "DiskFPSet":
@@ -606,7 +599,6 @@ def _dispatch_check(args, spec, log):
             pipeline=args.pipeline,
             obs_slots=_obs_slots(args),
             coverage=args.coverage,
-            sort_free=args.sortfree,
             deferred=args.deferredinv,
             opts=_sup_opts(args, log),
         )
@@ -622,7 +614,6 @@ def _dispatch_check(args, spec, log):
         pipeline=args.pipeline,
         obs_slots=_obs_slots(args),
         coverage=args.coverage,
-        sort_free=args.sortfree,
         deferred=args.deferredinv,
     ), None
 
@@ -734,14 +725,6 @@ def _obs_slots(args) -> int:
     entirely (the A/B baseline; also the shape pre-obs checkpoints
     expect), otherwise -obs-slots levels of history ride the carry."""
     return args.obsslots if args.obs else 0
-
-
-def _sort_free(args) -> bool:
-    """The RESOLVED -sort-free mode this run's engines will use (the
-    run_start journal manifest records the fact, not the tri-state)."""
-    from .engine.bfs import resolve_sort_free
-
-    return resolve_sort_free(getattr(args, "sortfree", None), args.chunk)
 
 
 def _deferred(args) -> bool:
@@ -958,11 +941,6 @@ def _resume_command(args) -> str:
         parts += ["-sharded", str(args.sharded)]
     if args.pipeline:
         parts += ["-pipeline"]  # checkpoints only resume in the same mode
-    if getattr(args, "sortfree", None) is not None:
-        # auto continues in the checkpoint's recorded mode; only an
-        # explicit override must travel so the meta mode check stays
-        # satisfied
-        parts += ["-sort-free" if args.sortfree else "-no-sort-free"]
     if getattr(args, "deferredinv", None) is not None:
         # auto re-resolves identically from the chunk; only an explicit
         # override must travel
@@ -1078,7 +1056,6 @@ def _run_check_gen(args, spec) -> int:
             backend=backend,
             pipeline=args.pipeline,
             obs_slots=_obs_slots(args),
-            sort_free=args.sortfree,
             deferred=args.deferredinv,
         )
         if args.checkpoint:
@@ -1309,7 +1286,6 @@ def _run_check_struct(args, spec) -> int:
                     route_factor=args.routefactor,
                     pipeline=args.pipeline,
                     obs_slots=_obs_slots(args),
-                    sort_free=args.sortfree,
                     deferred=args.deferredinv,
                     opts=_sup_opts(args, log, finish=liveness), **kw,
                 )
@@ -1318,9 +1294,8 @@ def _run_check_struct(args, spec) -> int:
                 sm, mesh, route_factor=args.routefactor,
                 check_deadlock=ckd, pipeline=args.pipeline,
                 obs_slots=_obs_slots(args), bounds=bounds,
-                coverage=cov, sort_free=args.sortfree,
-                deferred=args.deferredinv, symmetry=args.symmetry,
-                por=args.por, **kw,
+                coverage=cov, deferred=args.deferredinv,
+                symmetry=args.symmetry, por=args.por, **kw,
             ), None
         if args.checkpoint or args.autogrow:
             from .resil import check_supervised
@@ -1334,7 +1309,6 @@ def _run_check_struct(args, spec) -> int:
                 check_deadlock=ckd,
                 pipeline=args.pipeline,
                 obs_slots=_obs_slots(args),
-                sort_free=args.sortfree,
                 deferred=args.deferredinv,
                 opts=_sup_opts(args, log, capture_fps=capture,
                                finish=liveness), **kw,
@@ -1343,9 +1317,9 @@ def _run_check_struct(args, spec) -> int:
         return check_struct(
             sm, fp_index=spec.fp_index, check_deadlock=ckd,
             pipeline=args.pipeline, obs_slots=_obs_slots(args),
-            bounds=bounds, coverage=cov, sort_free=args.sortfree,
-            deferred=args.deferredinv, symmetry=args.symmetry,
-            por=args.por, capture_fps=capture, **kw,
+            bounds=bounds, coverage=cov, deferred=args.deferredinv,
+            symmetry=args.symmetry, por=args.por, capture_fps=capture,
+            **kw,
         ), None
 
     def props():
@@ -2122,7 +2096,6 @@ def _run_check_interp(args, spec, kit: "_InterpKit",
         params=dict(chunk=args.chunk, queue_capacity=args.qcap,
                     fp_capacity=args.fpcap, sharded=args.sharded,
                     pipeline=args.pipeline, frontend=kit.kind,
-                    sort_free=_sort_free(args),
                     deferred=_deferred(args),
                     symmetry=_symmetry(args), por=_por(args),
                     obs_slots=_obs_slots(args),

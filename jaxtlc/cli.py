@@ -92,32 +92,6 @@ def main(argv=None) -> int:
                         "must use the same mode")
     c.add_argument("-no-pipeline", dest="pipeline", action="store_false",
                    help="(default) the fused single-stage step bodies")
-    c.add_argument("-sort-free", dest="sortfree", action="store_const",
-                   const=True, default=None,
-                   help="commit through the hash-slab dedup instead of "
-                        "the two full-width stable sorts (ISSUE 12): "
-                        "scatter-max in-batch dedup + a probe-width "
-                        "claimant compaction, inherited by every engine "
-                        "at the expand/commit seam (fused, -pipeline, "
-                        "-sharded, spill, -narrow, -coverage).  "
-                        "Results are bit-for-bit the sorted path's - "
-                        "full signature AND fpset table words "
-                        "(tests/test_sortfree.py::test_ff_bit_for_bit "
-                        "pins it).  Default auto: OFF at every "
-                        "-chunk - measured on a TPU v5e in every batch "
-                        "cell of the benchmark (PERF.md section 5, PR "
-                        "38): the two sorts cost a tenth of the slab's "
-                        "element gathers and scatters at 65,536 to "
-                        "196,608 candidate lanes, a check 1.7-1.9x "
-                        "shorter.  A checkpoint records the resolved "
-                        "mode: -recover on auto continues in it, an "
-                        "explicit flag must match")
-    c.add_argument("-no-sort-free", dest="sortfree", action="store_const",
-                   const=False,
-                   help="force the sorted dedup commit (what auto "
-                        "resolves to; against a checkpoint cut by the "
-                        "slab it is the mismatch, where auto follows "
-                        "the checkpoint)")
     c.add_argument("-deferred-inv", dest="deferredinv",
                    action="store_const", const=True, default=None,
                    help="distinct-first expand (ISSUE 15): evaluate "

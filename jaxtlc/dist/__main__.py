@@ -67,7 +67,6 @@ def _parser() -> argparse.ArgumentParser:
     p.add_argument("--queue-capacity", type=int, default=1 << 14)
     p.add_argument("--fp-capacity", type=int, default=1 << 18)
     p.add_argument("--route-factor", type=float, default=2.0)
-    p.add_argument("--sort-free", choices=tuple(_TRI), default="auto")
     p.add_argument("--deferred", choices=tuple(_TRI), default="auto")
     p.add_argument("--obs-slots", type=int, default=0,
                    help="device counter-ring slots (per-host `level` "
@@ -115,7 +114,6 @@ def _worker(args) -> int:
         queue_capacity=args.queue_capacity,
         fp_capacity=args.fp_capacity,
         route_factor=args.route_factor,
-        sort_free=_TRI[args.sort_free],
         deferred=_TRI[args.deferred],
         obs_slots=args.obs_slots,
         coverage=args.coverage,

@@ -77,8 +77,8 @@ COVERAGE_SAT_LEVELS = 8
 
 # engine keys a pod resume must always match (mirrors
 # check_sharded_with_checkpoints; "spill" shapes the carry leaves)
-_ENGINE_KEYS = ("format", "config", "pipeline", "obs_slots", "sort_free",
-                "deferred", "symmetry", "por", "spill")
+_ENGINE_KEYS = ("format", "config", "pipeline", "obs_slots", "deferred",
+                "symmetry", "por", "spill")
 # geometry keys only a --reshard resume may change
 _GEOM_KEYS = ("queue_capacity", "fp_capacity", "devices", "num_hosts")
 
@@ -362,9 +362,9 @@ def _validate_pod_meta(saved: dict, want: dict, reshard: bool) -> None:
     engine AND geometry keys (a snapshot only reloads at its own pod
     width); --reshard relaxes exactly the geometry keys that
     reshard_carry re-derives."""
-    defaults = {"pipeline": False, "sort_free": False, "deferred": False,
-                "symmetry": False, "por": False, "spill": False,
-                "obs_slots": 0, "num_hosts": 1}
+    defaults = {"pipeline": False, "deferred": False, "symmetry": False,
+                "por": False, "spill": False, "obs_slots": 0,
+                "num_hosts": 1}
     for key in _ENGINE_KEYS + (() if reshard else _GEOM_KEYS):
         s = saved.get(key, defaults.get(key))
         if s != want[key]:
@@ -597,7 +597,6 @@ def run_pod(
     fp_index: int = None,
     seed: int = None,
     route_factor: float = 2.0,
-    sort_free: bool = None,
     deferred: bool = None,
     obs_slots: int = 0,
     coverage: bool = False,
@@ -634,7 +633,7 @@ def run_pod(
     (tests/test_multihost.py::test_pod_obs_coverage_parity)."""
     import jax
 
-    from ..engine.bfs import resolve_deferred, resolve_sort_free
+    from ..engine.bfs import resolve_deferred
     from ..engine.checkpoint import _meta, read_checkpoint_meta
     from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
     from ..engine.sharded import (
@@ -666,22 +665,6 @@ def run_pod(
             "keyed to the width that cut them - resume at the original "
             "width (ROADMAP #1 residue)"
         )
-    # a resume reads its snapshot's meta FIRST: an auto caller continues
-    # in the dedup ordering it records (bfs.resolve_sort_free), and a
-    # wrong-width or wrong-mode snapshot is refused below, before the
-    # engine pays its AOT compile
-    resume_meta = resume_full = None
-    if resume:
-        if ckpt_path is None:
-            raise ValueError("resume requires a checkpoint base path")
-        my_path = host_checkpoint_path(ckpt_path, host)
-        if reshard:
-            resume_full = load_pod_full(ckpt_path)
-        else:
-            resume_meta = read_checkpoint_meta(my_path)
-    sort_free = resolve_sort_free(
-        sort_free, chunk,
-        resume_full[0] if resume_full is not None else resume_meta)
     deferred = resolve_deferred(deferred, chunk)
     red = getattr(backend, "reduce", None)
     meta = _meta(
@@ -692,7 +675,6 @@ def run_pod(
         devices=D,
         pipeline=False,
         obs_slots=obs_slots,
-        sort_free=sort_free,
         deferred=deferred,
         symmetry=bool(red is not None and red.plan is not None),
         por=bool(red is not None and red.por and red.safe_ids),
@@ -713,16 +695,24 @@ def run_pod(
         if on_event is not None:
             on_event(kind, dict(fields))
 
-    # resume validation before the build
-    if resume_full is not None:
-        _validate_pod_meta(resume_full[0], meta, reshard=True)
-        if resume_full[0].get("spill"):
-            raise ValueError(
-                "reshard of a spill-mode pod checkpoint is "
-                "unsupported - resume at the original width"
-            )
-    elif resume_meta is not None:
-        _validate_pod_meta(resume_meta, meta, reshard=False)
+    # resume validation FIRST: a wrong-width or wrong-mode snapshot
+    # must refuse before the engine pays its AOT compile, not after
+    resume_full = None
+    if resume:
+        if ckpt_path is None:
+            raise ValueError("resume requires a checkpoint base path")
+        my_path = host_checkpoint_path(ckpt_path, host)
+        if reshard:
+            resume_full = load_pod_full(ckpt_path)
+            _validate_pod_meta(resume_full[0], meta, reshard=True)
+            if resume_full[0].get("spill"):
+                raise ValueError(
+                    "reshard of a spill-mode pod checkpoint is "
+                    "unsupported - resume at the original width"
+                )
+        else:
+            _validate_pod_meta(read_checkpoint_meta(my_path), meta,
+                               reshard=False)
 
     # engine: the fused AOT segment loop, or the spill runtime's
     # expand/probe/commit protocol when the per-host lifeboat is on
@@ -736,8 +726,7 @@ def run_pod(
             cfg, mesh, chunk, queue_capacity, fp_capacity,
             fp_index=fp_index, seed=seed, route_factor=route_factor,
             backend=backend, fp_highwater=fp_highwater,
-            obs_slots=obs_slots, sort_free=sort_free,
-            deferred=deferred, store=store,
+            obs_slots=obs_slots, deferred=deferred, store=store,
             on_event=lambda kind, info: emit(kind, host=host, **info),
         )
         template = rt.init_fn()
@@ -746,8 +735,8 @@ def run_pod(
         init_fn, seg_fn = make_sharded_engine(
             cfg, mesh, chunk, queue_capacity, fp_capacity,
             fp_index=fp_index, seed=seed, route_factor=route_factor,
-            segment=ckpt_every, backend=backend, sort_free=sort_free,
-            deferred=deferred, obs_slots=obs_slots,
+            segment=ckpt_every, backend=backend, deferred=deferred,
+            obs_slots=obs_slots,
         )
         template = init_fn()
         if hosts > 1:
@@ -809,7 +798,7 @@ def run_pod(
              params=dict(chunk=chunk, queue_capacity=queue_capacity,
                          fp_capacity=fp_capacity, devices=D,
                          hosts=hosts, route_factor=route_factor,
-                         sort_free=sort_free, deferred=deferred,
+                         deferred=deferred,
                          spill=spill_on, obs_slots=obs_slots,
                          coverage=(getattr(backend, "coverage", None)
                                    is not None)))

@@ -404,9 +404,8 @@ def make_deferred_checker(backend: SpecBackend, n: int,
 
     Violation-lane attribution rule (pinned, layout-independent): the
     reported state is the violating fresh claimant with the HIGHEST
-    original candidate lane - the same rep convention as the PR 12
-    dedup (in-batch duplicates resolve to the highest lane), identical
-    across the sorted and slab commit layouts because it is defined on
+    original candidate lane - the same rep convention as the in-batch
+    dedup (duplicates resolve to the highest lane), defined on
     original lanes, not compacted positions.  The immediate path
     reports the FIRST violating candidate instead; everything else
     (verdict code, counters, table words, rendered traces) is
@@ -444,8 +443,9 @@ def make_deferred_checker(backend: SpecBackend, n: int,
             off = seg * R
             idx = lax.dynamic_slice(idx_p, (off,), (R,))
             fresh = lax.dynamic_slice(new_p, (off,), (R,))
-            # slab padding rows carry the sentinel lane n (fresh is
-            # False there, so the clamped gather is never consumed)
+            # this checker's own padding rows carry the sentinel lane
+            # n (fresh is False there, so the clamped gather is never
+            # consumed)
             lanes = jnp.clip(idx, 0, n - 1)
             rows = flat[lanes]  # [R, F]: the one per-claimant gather
             if n_codes:

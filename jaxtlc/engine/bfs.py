@@ -17,8 +17,8 @@ that takes the carry copies it):
   slice, an append one row gather of the new states and a contiguous
   dynamic-update-slice.  States are unpacked to field vectors only at
   the kernel boundary (codec.unpack); fingerprints ride the MXU.
-* The commit dedups the chunk*L candidates (fpset.fpset_insert_dedup:
-  two stable sorts, or the hash slab), probes only the unique ones and
+* The commit dedups the chunk*L candidates (fpset.fpset_insert_sorted:
+  two stable sorts), probes only the unique ones and
   writes the table by one scatter-add of whole bucket rows; enqueue and
   per-new-state statistics run over compacted probe-width segments.
 * Every loop is a `while` (`run_steps`; the two pop widths at chunk >=
@@ -53,7 +53,7 @@ from jax import lax
 from ..config import ModelConfig
 from ..spec.labels import LABELS
 from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED, fp64_words_mxu
-from .fpset import fpset_insert_dedup, fpset_insert_sorted, fpset_new
+from .fpset import fpset_insert_sorted, fpset_new
 
 # violation codes
 OK = 0
@@ -400,31 +400,6 @@ def carry_done(carry: EngineCarry) -> bool:
 DEFAULT_FP_HIGHWATER = 0.85
 
 
-def resolve_sort_free(sort_free, chunk: int, saved: dict = None) -> bool:
-    """Resolve the tri-state -sort-free flag (None = auto) for an
-    engine popping `chunk` states per step.  Auto is the SORTED
-    ordering at every chunk: on the chip a sort over all chunk * L
-    candidate lanes costs a fraction of ONE element gather over them
-    (PERF.md section 5, PR 38: the measured pair in every batch cell),
-    and the hash slab spends five such gathers and scatters to avoid
-    two sorts.  `chunk` stays in the signature: every layer that needs
-    the resolved mode - engine factories, struct engine memos,
-    checkpoint meta - calls this with its geometry and computes the
-    same answer without coordination.
-
-    `saved` is the meta of the checkpoint a resume loads: the carry is
-    the same in both modes (the slab is a per-commit temporary) and
-    both are exact, so an auto caller continues in the mode the
-    checkpoint records (a snapshot cut at chunk >= 2048 before PR 38
-    says `sort_free: true`); an explicit flag is itself, and one that
-    contradicts the checkpoint is the caller's loud mismatch."""
-    if sort_free is not None:
-        return bool(sort_free)
-    if saved is not None:
-        return bool(saved.get("sort_free", False))
-    return False
-
-
 # -deferred-inv auto threshold (ISSUE 15): deferring the invariant
 # sweep from the chunk*L candidate lanes to the ~2*chunk fresh-insert
 # claimants is the distinct-first collapse.  Each side runs in one
@@ -437,10 +412,9 @@ DEFERRED_AUTO_CHUNK = 2048
 def resolve_deferred(deferred, chunk: int) -> bool:
     """Resolve the tri-state -deferred-inv flag (None = auto) for an
     engine popping `chunk` states per step.  Deterministic in the
-    geometry alone - exactly like resolve_sort_free - so engine memos,
-    EnginePool keys, checkpoint meta, resume commands and journal
-    run_start params all compute the same answer without
-    coordination."""
+    geometry alone, so engine memos, EnginePool keys, checkpoint meta,
+    resume commands and journal run_start params all compute the same
+    answer without coordination."""
     if deferred is not None:
         return bool(deferred)
     return chunk >= DEFERRED_AUTO_CHUNK
@@ -449,10 +423,10 @@ def resolve_deferred(deferred, chunk: int) -> bool:
 def resolve_symmetry(symmetry, chunk: int = 0) -> bool:
     """Resolve the tri-state -symmetry flag (None = auto).  Auto is
     OFF: orbit dedup legitimately SHRINKS the distinct-state count, so
-    unlike sort-free/deferred it is not a pure performance mode and
-    must be opted into.  Same resolver shape as resolve_sort_free so
-    engine memos, checkpoint meta, resume commands and journal params
-    all agree without coordination (`chunk` is accepted for signature
+    unlike deferred it is not a pure performance mode and must be
+    opted into.  Same resolver shape as resolve_deferred so engine
+    memos, checkpoint meta, resume commands and journal params all
+    agree without coordination (`chunk` is accepted for signature
     symmetry; the answer does not depend on it)."""
     if symmetry is not None:
         return bool(symmetry)
@@ -480,7 +454,6 @@ def make_engine(
     donate: bool = True,
     obs_slots: int = 0,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
 ):
     """Build (init_fn, run_fn, step_fn) for one KubeAPI configuration.
@@ -497,7 +470,7 @@ def make_engine(
         kubeapi_backend(cfg, coverage=coverage), chunk, queue_capacity,
         fp_capacity, fp_index, seed, fp_highwater=fp_highwater,
         pipeline=pipeline, donate=donate, obs_slots=obs_slots,
-        sort_free=sort_free, deferred=deferred,
+        deferred=deferred,
     )
 
 
@@ -513,7 +486,6 @@ def make_stage_pair(
     seed: int = DEFAULT_SEED,
     obs_slots: int = 0,
     spill: bool = False,
-    sort_free: bool = False,
     deferred: bool = False,
 ):
     """(pop_expand, commit) at pop width `ck` - the two halves of one
@@ -522,17 +494,6 @@ def make_stage_pair(
     block's staged ExpandOut while pop_expand works on the next block,
     and the host spill driver (engine.spill) interleaves a host-tier
     membership check between them.
-
-    sort_free (a RESOLVED bool here; factories resolve the tri-state
-    flag via resolve_sort_free, whose auto is False at every chunk)
-    picks the in-batch dedup's ordering: False orders the candidates by
-    two stable sorts at candidate width (fpset.fpset_insert_sorted),
-    True through the hash slab (fpset.fpset_insert_slab) -
-    bit-identical results by contract, so every engine composed from
-    this seam (fused, pipelined, spill, narrowed, covered) inherits the
-    mode with no per-engine code.  The slab is an ephemeral per-commit
-    tensor derived from this pair's geometry, so regrow/chunk-shrink
-    rebuilds migrate it by construction.
 
     deferred=True (a RESOLVED bool; factories resolve the tri-state
     flag via resolve_deferred) moves invariant + certificate
@@ -598,7 +559,7 @@ def make_stage_pair(
         # device scopes (jax.named_scope, trace-time metadata only):
         # the same layer names a profiler trace shows for the host
         # spans of obs.spans - expand, pack_fp (inside the backend's
-        # expand), dedup and fpset (fpset_insert_dedup), enqueue, level
+        # expand), dedup and fpset (the commit's insert), enqueue, level
         with jax.named_scope("jaxtlc.expand"):
             avail = c.level_n - c.qhead
             n = jnp.clip(avail, 0, ck)
@@ -631,10 +592,14 @@ def make_stage_pair(
                 fp_capacity * fp_highwater
             )
             insert_mask = ex.valid & ~fp_full
-        fps, is_new_c, c_idx, nreps = fpset_insert_dedup(
-            c.fps, ex.lo, ex.hi, insert_mask,
-            probe_width=R, claim_width=CW, sort_free=sort_free,
-        )
+        # the in-batch dedup (two sorts at candidate width); the probe /
+        # claim inside it is `jaxtlc.fpset` (_probe_block), so a trace
+        # attributes an op to the innermost of the two
+        with jax.named_scope("jaxtlc.dedup"):
+            fps, is_new_c, c_idx, nreps = fpset_insert_sorted(
+                c.fps, ex.lo, ex.hi, insert_mask,
+                probe_width=R, claim_width=CW,
+            )
         n_new = is_new_c.sum().astype(jnp.int32)
         q_full = c.next_n + n_new > qcap
 
@@ -913,7 +878,6 @@ def make_backend_engine(
     pipeline: bool = False,
     donate: bool = True,
     obs_slots: int = 0,
-    sort_free: bool = None,
     deferred: bool = None,
 ):
     """Build (init_fn, run_fn, step_fn) over any SpecBackend.
@@ -965,32 +929,22 @@ def make_backend_engine(
     bit-for-bit those of an obs-off run (tests/test_obs.py::
     test_obs_bit_identical_and_ring pins it).
 
-    sort_free (tri-state: None = auto = the two stable sorts at
-    candidate width, resolve_sort_free) selects the hash-slab commit
-    dedup in their place (ISSUE 12).  Results are BIT-FOR-BIT the
-    sorted path's - full signature plus fpset TABLE words
-    (tests/test_sortfree.py::test_ff_bit_for_bit pins it) - the flag
-    is purely a performance mode, but it is still recorded in engine
-    memos and checkpoint meta: an explicit flag never silently crosses
-    modes on a resume, an auto caller continues in the checkpoint's.
-
     deferred (tri-state: None = auto, resolve_deferred) moves
     invariant + certificate evaluation to the commit stage, over the
     fresh-insert claimants only (ISSUE 15; make_stage_pair docstring).
     Verdict, full counter signature, fpset TABLE words and rendered
     traces are bit-for-bit the immediate path's (tests/test_deferred.py::
     test_ff_bit_for_bit pins it); violation-LANE attribution follows the pinned
-    highest-lane rule.  Like sort_free, the resolved mode is engine-
-    memo and checkpoint-meta material - a wrong-mode -recover is a
-    loud pre-build rejection - because the pipelined staged-block
-    layout changes (st_flat replaces st_cert) and attribution must
-    never silently flip across a resume.
+    highest-lane rule.  The resolved mode is engine-memo and
+    checkpoint-meta material - a wrong-mode -recover is a loud
+    pre-build rejection - because the pipelined staged-block layout
+    changes (st_flat replaces st_cert) and attribution must never
+    silently flip across a resume.
     """
     from ..obs.counters import ring_new
     from .backend import ExpandOut
 
     assert 0.0 < fp_highwater <= 1.0, "fp_highwater must be in (0, 1]"
-    sort_free = resolve_sort_free(sort_free, chunk)
     deferred = resolve_deferred(deferred, chunk)
     has_cert = backend.cert_check is not None
     # in deferred mode the staged ExpandOut carries the raw fields
@@ -1158,7 +1112,7 @@ def make_backend_engine(
             backend, ck, queue_capacity=qcap, fp_capacity=fp_capacity,
             fp_highwater=fp_highwater, check_deadlock=check_deadlock,
             fp_index=fp_index, seed=seed, obs_slots=obs_slots,
-            sort_free=sort_free, deferred=deferred,
+            deferred=deferred,
         )
 
     def make_body(ck: int):
@@ -1298,7 +1252,6 @@ def check(
     pipeline: bool = False,
     obs_slots: int = 0,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
 ) -> CheckResult:
     """Run an exhaustive check; the single-device engine entry point.
@@ -1313,7 +1266,7 @@ def check(
     init_fn, run_fn, _ = make_backend_engine(
         backend, chunk, queue_capacity, fp_capacity, fp_index, seed,
         fp_highwater=fp_highwater, pipeline=pipeline, obs_slots=obs_slots,
-        sort_free=sort_free, deferred=deferred,
+        deferred=deferred,
     )
     carry = init_fn()
     compiled = run_fn.lower(carry).compile()

@@ -127,16 +127,11 @@ def read_checkpoint_meta(path: str) -> dict:
         raise CheckpointCorruptError(f"unreadable checkpoint {path!r}: {e}")
 
 
-def resume_meta(ckpt_path: Optional[str], resume: bool) -> Optional[dict]:
-    """The meta of the checkpoint a `resume` is about to load, None for
-    a fresh run; read BEFORE the engine is built, because an auto
-    caller resumes in the dedup ordering the checkpoint records
-    (bfs.resolve_sort_free)."""
-    if not resume:
-        return None
-    if ckpt_path is None or not os.path.exists(ckpt_path):
-        raise FileNotFoundError(f"no checkpoint at {ckpt_path!r}")
-    return read_checkpoint_meta(ckpt_path)
+def require_checkpoint(path: Optional[str]) -> None:
+    """A resume with no file to load says so BEFORE the engine is
+    built, not after a minute of compile."""
+    if path is None or not os.path.exists(path):
+        raise FileNotFoundError(f"no checkpoint at {path!r}")
 
 
 def load_checkpoint(path: str, template: EngineCarry):
@@ -272,7 +267,6 @@ def check_with_checkpoints(
     fp_highwater: float = DEFAULT_FP_HIGHWATER,
     pipeline: bool = False,
     obs_slots: int = 0,
-    sort_free: bool = None,
     deferred: bool = None,
 ) -> CheckResult:
     """Exhaustive check with periodic checkpoints every `ckpt_every` chunks.
@@ -293,10 +287,10 @@ def check_with_checkpoints(
     readback stays off the device critical path (PERF.md round 7).
     """
     from ..runtime import aot_build, engine_key
-    from .bfs import resolve_deferred, resolve_sort_free
+    from .bfs import resolve_deferred
 
-    sort_free = resolve_sort_free(sort_free, chunk,
-                                  resume_meta(ckpt_path, resume))
+    if resume:
+        require_checkpoint(ckpt_path)
     deferred = resolve_deferred(deferred, chunk)
     meta = _meta(
         cfg,
@@ -308,7 +302,6 @@ def check_with_checkpoints(
         fp_highwater=fp_highwater,
         pipeline=pipeline,
         obs_slots=obs_slots,
-        sort_free=sort_free,
         deferred=deferred,
     )
 
@@ -318,7 +311,7 @@ def check_with_checkpoints(
         init_fn, _, step_fn = make_engine(
             cfg, chunk, queue_capacity, fp_capacity, fp_index, seed,
             fp_highwater=fp_highwater, pipeline=pipeline, donate=False,
-            obs_slots=obs_slots, sort_free=sort_free, deferred=deferred,
+            obs_slots=obs_slots, deferred=deferred,
         )
 
         return init_fn, step_fn.segment(ckpt_every)
@@ -336,12 +329,11 @@ def check_with_checkpoints(
         # across a resume)
         for key in ("format", "config", "chunk", "queue_capacity",
                     "fp_capacity", "fp_index", "seed", "fp_highwater",
-                    "pipeline", "obs_slots", "sort_free", "deferred"):
-            # pre-pipeline/pre-obs/pre-sort-free/pre-deferred
-            # snapshots carry no key: treat as off
+                    "pipeline", "obs_slots", "deferred"):
+            # pre-pipeline/pre-obs/pre-deferred snapshots carry no
+            # key: treat as off
             saved = saved_meta.get(
-                key, False if key in ("pipeline", "sort_free",
-                                      "deferred")
+                key, False if key in ("pipeline", "deferred")
                 else 0 if key == "obs_slots" else None)
             if saved != meta[key]:
                 raise ValueError(

@@ -522,15 +522,14 @@ def _probe_segments(table, c_lo, c_hi, active, n_rows, R: int, C: int):
     """Probe / claim the ordered candidates c_lo/c_hi [n] (fp-ascending,
     `active` marking the dup-free representatives, all of them within
     the first `n_rows` rows) in R-wide blocks.  Returns (table,
-    is_new [n]).  The one place the commit's table is written: both
-    orderings (sorted, slab) hand their candidates here, so the table
-    is never the operand of a conditional."""
+    is_new [n]).  The one place the commit's table is written, and
+    never under a conditional."""
     n = c_lo.shape[0]
     if R == n:
         return _probe_block(table, c_lo, c_hi, active, C)
 
-    # block loop: one trip unless a chunk is nearly all-distinct (the
-    # slab ordering always fits one block); each block stays fp-sorted.
+    # block loop: one trip unless a chunk is nearly all-distinct; each
+    # block stays fp-sorted.
     # Pad to a whole number of blocks: dynamic_slice CLAMPS out-of-
     # bounds start offsets, so an unpadded final partial block would
     # re-probe earlier entries and never probe the tail.
@@ -561,18 +560,14 @@ def _probe_segments(table, c_lo, c_hi, active, n_rows, R: int, C: int):
 
 
 def _sorted_order(lo, hi):
-    """The in-batch dedup's ordering, and every engine's default at
-    every chunk (bfs.resolve_sort_free): two stable sorts over all n
+    """The in-batch dedup's ordering: two stable sorts over all n
     candidate lanes of already MIXED, remapped, mask-zeroed fingerprint
     words give (c_lo, c_hi, c_idx int32, nreps), the distinct
     representatives compacted fp-ascending into the first nreps rows.
     On the chip a sort at candidate width is cheap and an element
     gather or scatter there is not (PERF.md section 5, PR 38, one
     TPU v5e: at 196,608 lanes the two sorts cost 0.20 and 0.24 ms a
-    step, ONE element gather 0.92 ms, and the slab below spent five
-    such instructions, 4.4 ms of a 9.25 ms step, to avoid the sorts).
-    It is also the slab path's fallback, so both paths meet in the
-    exact same computation."""
+    step, ONE element gather 0.92 ms): index nothing at this width."""
     n = lo.shape[0]
     # sort 1: group duplicates.  Invalid lanes are encoded as the RESERVED
     # (0,0) word pair - _remap guarantees no real fingerprint is (0,0) -
@@ -630,253 +625,7 @@ def fpset_insert_sorted(
     return FPSet(table), is_new_c, c_idx, nreps
 
 
-# ---------------------------------------------------------------------------
-# sort-free commit path (ISSUE 12; explicit `sort_free=True` /
-# `-sort-free` only since ISSUE 38): hash-slab in-batch dedup + the
-# bucketized rank-claim probe over a compacted claimant slice, replacing
-# the two full-width stable dedup sorts above with scatter/gather
-# primitives per the BLEST frontier-membership formulation.  It was the
-# auto rule at chunk >= 2048 from PR 12 to PR 37, shaped by a
-# microprofile of XLA-CPU; the chip prices the trade the other way
-# (PERF.md section 5, PR 38: `jaxtlc.dedup` 52 % of the wide cell's
-# device time through the slab, 9 % through the sorts), so nothing takes
-# this path unasked and ROADMAP C2 deletes it.  Exactness is the contract:
-# identical is_new verdicts, identical compacted-prefix order, identical
-# TABLE words - where the slab cannot guarantee that cheaply (residue /
-# width overflow) it falls back to the sorted path wholesale.
-# ---------------------------------------------------------------------------
-
-# per-pass slab hash constants (odd, high-entropy; the words are already
-# avalanche-mixed by _mix, the constant only decorrelates the passes)
-_SLAB_CONSTS = (0x9E3779B9, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F)
-
-
-def _slab_dedup_core(lo, hi, mask, R: int, slab_factor: int,
-                     slab_passes: int, slab_bits: int):
-    """The hash-slab passes + CLAIMANT compaction (no ordering sort
-    yet - see _order_and_dedup).  lo/hi are RAW words; mixing happens
-    here.
-
-    The shape: one scatter-max per pass, three element gathers of the
-    winner's lane and words, ONE element scatter for the compaction
-    (the lane index only - fingerprint words are re-read by R-wide
-    gathers), and the collision residue is NOT dedup'd here at all -
-    unresolved lanes ride into the claimant slice verbatim and the
-    R-wide ordering sort the path already pays groups their duplicates
-    for the last-of-group rep rule (_order_and_dedup).  Each of the
-    five indexes all n lanes, live or dead, at ~4.7 ns a lane on a
-    TPU v5e (PERF.md section 5, PR 38: 1.06, 3 x 0.92 and 0.60 ms at
-    196,608 lanes) - ten times the two sorts they stand in for.
-
-    Returns (mixed lo, mixed hi, c_lane [R] int32, n_cand, fallback):
-    the claimant lanes (slab winners + unresolved residue lanes)
-    compacted in lane order into the first n_cand rows (sentinel N
-    beyond); fallback=True when they exceed R and the batch must take
-    the sorted path."""
-    n = lo.shape[0]
-    lo, hi = _mix(lo, hi)
-    lo, hi = _remap(lo, hi)
-    lo = jnp.where(mask, lo, 0)
-    hi = jnp.where(mask, hi, 0)
-    if slab_bits:
-        m = 1 << slab_bits
-    else:
-        m = 1 << max((slab_factor * n - 1).bit_length(), 3)
-    mmask = jnp.uint32(m - 1)
-    lane = jnp.arange(n, dtype=jnp.uint32)
-    lane_i = lane.astype(jnp.int32)
-
-    # slab passes (static unroll; default ONE - each extra pass costs
-    # a full scatter-max to shrink a residue the ordering sort absorbs
-    # for free).  Scatter-max by lane index: the cell winner is the
-    # highest unresolved lane that hashed there (max is order-free, so
-    # the scatter is deterministic on every backend).
-    rep = jnp.zeros(n, bool)
-    unres = mask
-    for p in range(max(slab_passes, 1)):
-        c = jnp.uint32(_SLAB_CONSTS[p % len(_SLAB_CONSTS)])
-        h = ((_fmix32(lo + c) ^ hi) & mmask).astype(jnp.int32)
-        slab = jnp.zeros(m, jnp.uint32).at[
-            jnp.where(unres, h, m)
-        ].max(lane + 1, mode="drop")
-        win = slab[h].astype(jnp.int32)  # winner lane + 1 per cell
-        wl = jnp.clip(win - 1, 0, n - 1)
-        # a class resolves ATOMICALLY: the winner shares my fingerprint
-        # iff it is my class's own max lane (equal fps always share a
-        # cell, so either the whole class resolves or none of it does)
-        same = unres & (lo[wl] == lo) & (hi[wl] == hi)
-        rep = rep | (same & (wl == lane_i))
-        unres = unres & ~same
-
-    # claimants = resolved winners + EVERY lane of an unresolved class
-    # (their dedup is deferred to the ordering sort); compact the lane
-    # indices alone - one element scatter, words gathered at R width
-    cand = rep | unres
-    n_cand = cand.sum().astype(jnp.int32)
-    pos = jnp.cumsum(cand.astype(jnp.int32)) - 1
-    tgt = jnp.where(cand & (pos < R), pos, R)
-    c_lane = jnp.full(R, n, jnp.int32).at[tgt].set(lane_i, mode="drop")
-    fallback = n_cand > R
-    return lo, hi, c_lane, n_cand, fallback
-
-
-def _order_and_dedup(m_lo, m_hi, c_lane, n_cand, R: int, n: int):
-    """Order the claimant slice ascending by (hi, lo) and finish the
-    dedup: the one remaining sort of the sort-free path, at probe
-    width instead of batch width (the entire point: R ~ 2*chunk while
-    the batch is chunk*L candidates).  Unresolved-class duplicates
-    sort adjacent and lane-ascending (stable sort over the lane-order
-    compaction), so last-of-group IS the highest lane - the stable
-    dedup sort's exact rep rule.  Returns (c_lo, c_hi, c_idx, active)
-    where `active` marks the dup-free representative rows (NOT a
-    prefix: dup rows sit interspersed; _probe_block's rank-claim math
-    only needs fp-ascending dup-free ACTIVES, which this is)."""
-    filled = jnp.arange(R) < n_cand  # cumsum compaction fills a prefix
-    safe = jnp.clip(c_lane, 0, n - 1)
-    k_lo = jnp.where(filled, m_lo[safe], 0)
-    k_hi = jnp.where(filled, m_hi[safe], 0)
-    k_ix = jnp.where(filled, c_lane, n)
-    inval = (~filled).astype(jnp.uint32)
-    _, c_hi, c_lo, c_idx = lax.sort(
-        (inval, k_hi, k_lo, k_ix), num_keys=3, is_stable=True
-    )
-    # last row of each (hi, lo) group among the filled rows (padding
-    # sorts behind them and is (0, 0) - never equal to a real
-    # remapped fingerprint, so the final group closes correctly)
-    last = jnp.concatenate(
-        [(c_hi[1:] != c_hi[:-1]) | (c_lo[1:] != c_lo[:-1]),
-         jnp.ones(1, bool)]
-    )
-    active = (jnp.arange(R) < n_cand) & last
-    return c_lo, c_hi, c_idx, active
-
-
-def slab_dedup(lo, hi, mask, probe_width: int = 0, slab_factor: int = 4,
-               slab_passes: int = 1, slab_bits: int = 0):
-    """In-batch hash-slab dedup (the sort-free replacement of the two
-    full-width dedup sorts): scatter-max the lane index into a hash
-    slab of ``slab_factor * N`` cells (power-of-two rounded; override
-    the cell count with ``slab_bits`` - tests force collisions that
-    way), so the surviving representative of every fingerprint class
-    is the HIGHEST lane index - exactly the semantics the stable dedup
-    sort guarantees.  Classes whose slab cell was won by a different
-    fingerprint (a slab collision) are NOT retried: all their lanes
-    ride into the probe-width claimant compaction, where the ordering
-    sort groups their duplicates adjacently and last-of-group picks
-    the exact rep - the residue dedup is absorbed by a sort the path
-    pays anyway, which is what keeps the whole dedup at ONE scatter-max,
-    three element gathers, ONE element scatter and ONE R-wide sort (on
-    the chip the n-wide gathers and scatters are the cost, not the
-    sorts: _slab_dedup_core).
-
-    The ordered claimants preserve the bucketized rank-claim invariant
-    (same-bucket claimants take occupancy + rank-in-run slots in
-    ascending fp order), so the TABLE words match the sorted path
-    bit-for-bit.
-
-    Returns (c_lo, c_hi, c_idx, active, fallback): [R]-wide ordered
-    claimant words (MIXED domain), their original lanes (sentinel = N
-    on padding), the dup-free representative row mask (NOT a prefix -
-    duplicate rows of slab-collision classes sit interspersed, rep
-    False), and the sorted-path fallback flag (claimants exceeded R)."""
-    n = lo.shape[0]
-    R = min(probe_width or n, n)
-    m_lo, m_hi, c_lane, n_cand, fallback = _slab_dedup_core(
-        lo, hi, mask, R, slab_factor, slab_passes, slab_bits
-    )
-    c_lo, c_hi, c_idx, active = _order_and_dedup(
-        m_lo, m_hi, c_lane, n_cand, R, n
-    )
-    return c_lo, c_hi, c_idx, active, fallback
-
-
-def fpset_insert_slab(
-    s: FPSet, lo, hi, mask, probe_width: int = 0, claim_width: int = 0,
-    slab_factor: int = 4, slab_passes: int = 1, slab_bits: int = 0,
-) -> Tuple[FPSet, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Sort-free insert-or-find: fpset_insert_sorted's engine-facing
-    contract (same per-lane is_new verdicts, same (lane, is_new) rep
-    pairs, bit-identical TABLE words) through the hash-slab dedup
-    above.  LAYOUT differs from the sorted path: representatives are
-    fp-ascending but NOT compacted to a prefix (slab-collision
-    duplicate rows sit interspersed with is_new False and their real
-    lane in c_idx; padding rows carry the out-of-range sentinel N).
-    Every engine consumer is layout-blind - commit re-orders by
-    (is_new, lane) and masks on n_new - so results are bit-for-bit.
-
-    Falls back to the sorted ORDERING wholesale (one lax.cond; only the
-    taken branch executes) when the claimants exceed the probe width -
-    the all-distinct-burst regime where the sorted path would run its
-    block loop anyway.  The conditional chooses the ordered candidates
-    and nothing else; the probe that writes the table runs after it, on
-    either ordering (_probe_segments), so the table crosses no
-    conditional.  Each ordering sort runs INSIDE its branch with
-    explicit operands: raw sort outputs crossing INTO a cond mis-wire
-    under shard_map (see _slab_dedup_core)."""
-    n = lo.shape[0]
-    R = min(probe_width or n, n)
-    C = min(claim_width or R, R)
-    m_lo, m_hi, c_lane, n_cand, fallback = _slab_dedup_core(
-        lo, hi, mask, R, slab_factor, slab_passes, slab_bits
-    )
-
-    # both orderings give n-wide (c_lo, c_hi, c_idx, active, rows in
-    # use); the conditional carries candidates only, never the table
-    def slab_order(op):
-        mlo, mhi, lanes, nc = op
-        c_lo, c_hi, c_idx, active = _order_and_dedup(
-            mlo, mhi, lanes, nc, R, n
-        )
-        wide = n - R
-        return (
-            jnp.concatenate([c_lo, jnp.zeros(wide, jnp.uint32)]),
-            jnp.concatenate([c_hi, jnp.zeros(wide, jnp.uint32)]),
-            jnp.concatenate([c_idx, jnp.full(wide, n, jnp.int32)]),
-            jnp.concatenate([active, jnp.zeros(wide, bool)]),
-            nc,
-        )
-
-    def sorted_order(op):
-        mlo, mhi, _lanes, _nc = op
-        c_lo, c_hi, c_idx, nreps = _sorted_order(mlo, mhi)
-        return c_lo, c_hi, c_idx, jnp.arange(n) < nreps, nreps
-
-    c_lo, c_hi, c_idx, active, n_rows = lax.cond(
-        fallback, sorted_order, slab_order, (m_lo, m_hi, c_lane, n_cand)
-    )
-    table, is_new_c = _probe_segments(
-        s.table, c_lo, c_hi, active, n_rows, R, C
-    )
-    return FPSet(table), is_new_c, c_idx, active.sum().astype(jnp.int32)
-
-
-def fpset_insert_dedup(
-    s: FPSet, lo, hi, mask, probe_width: int = 0, claim_width: int = 0,
-    sort_free: bool = False,
-) -> Tuple[FPSet, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """The engine seam's insert: the sorted dedup path (auto, at every
-    chunk) or the explicit sort-free hash-slab path, one flag
-    (bfs.make_stage_pair threads the resolved -sort-free mode here, so
-    every stage composition - fused, pipelined, spill - and the sharded
-    owner-side insert share one dispatch point).  Contract identical
-    either way."""
-    # device scope of the in-batch dedup (sorts or slab); the probe /
-    # claim inside it is `jaxtlc.fpset` (_probe_block), so a trace
-    # attributes an op to the innermost of the two
-    with jax.named_scope("jaxtlc.dedup"):
-        if not sort_free:
-            return fpset_insert_sorted(
-                s, lo, hi, mask, probe_width=probe_width,
-                claim_width=claim_width,
-            )
-        return fpset_insert_slab(
-            s, lo, hi, mask, probe_width=probe_width,
-            claim_width=claim_width,
-        )
-
-
-def fpset_insert(s: FPSet, lo, hi, mask, sort_free: bool = False,
-                 probe_width: int = 0) -> Tuple[FPSet, jnp.ndarray]:
+def fpset_insert(s: FPSet, lo, hi, mask) -> Tuple[FPSet, jnp.ndarray]:
     """Insert-or-find a batch of fingerprints.
 
     lo/hi: [N] uint32 lanes; mask: [N] bool (candidates to consider).
@@ -885,17 +634,10 @@ def fpset_insert(s: FPSet, lo, hi, mask, sort_free: bool = False,
     (the highest lane index), keeping the committed outdegree statistics
     (max 4 on Model_1, as TLC reports, MC.out:1104) stable across fpset
     generations.  The caller must keep occupancy + N below capacity (the
-    engine checks before calling).
-
-    sort_free takes the hash-slab dedup path (bit-identical results;
-    probe_width then bounds the compacted claimant slice - the sharded
-    engine's owner-side insert passes ~4x its chunk)."""
+    engine checks before calling)."""
     n = lo.shape[0]
-    s2, is_new_c, c_idx, _ = fpset_insert_dedup(
-        s, lo, hi, mask, probe_width=probe_width if sort_free else 0,
-        sort_free=sort_free,
-    )
-    # drop-mode: the slab path pads c_idx with the out-of-range
-    # sentinel N (the sorted path's c_idx is a permutation - unaffected)
+    with jax.named_scope("jaxtlc.dedup"):
+        s2, is_new_c, c_idx, _ = fpset_insert_sorted(s, lo, hi, mask)
+    # c_idx is a permutation of the lanes: nothing is dropped
     is_new = jnp.zeros(n, bool).at[c_idx].set(is_new_c, mode="drop")
     return s2, is_new

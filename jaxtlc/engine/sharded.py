@@ -33,7 +33,7 @@ Capacity ladder note: the sharded engine now HAS a host spill tier
 (SPILL_CAPABLE below, ISSUE 19 closing ROADMAP #1's pinned gap): the
 fused body is split at the owner seam into `expand_half` (pop, expand,
 route, owner-side `fpset_member` filter) and `commit_half` (owner-side
-slab insert, deferred invariants, the new rows compacted and written
+insert, deferred invariants, the new rows compacted and written
 onto the queue's ring as contiguous blocks, verdict return, level
 fences), and
 `ShardedSpillRuntime` drives the two jitted halves from the host with a
@@ -41,7 +41,7 @@ per-host SpillStore probe in between - each host's local device tables
 flush into that host's store at the fp_highwater load, exactly the
 engine.spill lifeboat, shard by shard.  The fused engine composes the
 same two halves back into one `lax.while_loop` body, so there is one
-implementation and no drift; the PR 12 owner-side slab insert and the
+implementation and no drift; the owner-side insert and the
 PR 15 owner-side distinct-first deferred invariant evaluation both live
 in `commit_half` and therefore run identically on the fused, spill and
 pod paths.
@@ -90,7 +90,7 @@ from . import backend as _backend
 from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
 from .fpset import (
     FPSet,
-    fpset_insert_dedup,
+    fpset_insert_sorted,
     fpset_member,
     host_insert,
 )
@@ -186,7 +186,7 @@ class ShardEx(NamedTuple):
     level leaves, no mesh axis).  `expand_half` pops a chunk, expands,
     canonicalizes, fingerprints and routes candidates to their owners
     (the candidate-routing all_to_all is INSIDE expand); `commit_half`
-    performs the owner-side slab insert + deferred invariants +
+    performs the owner-side insert + deferred invariants +
     enqueue + verdict return + level fencing.  The fused engine
     composes the two back into one while_loop body (bit-identical op
     graph); ShardedSpillRuntime runs them as separate jits with a
@@ -356,8 +356,7 @@ def masked_hist(ids, mask, n_bins: int):
         axis=1, dtype=jnp.uint32)
 
 
-def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int,
-                     sort_free: bool):
+def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int):
     """The owner-side insert of one body: the received candidates as a
     compacted stream (compact_lanes over the per-bucket live counts
     `cnt` [D]), `width` rows a segment behind a trip count - no
@@ -401,13 +400,12 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int,
             lo_k, hi_k, mask_k = (
                 compact_rows(a, cnt, k * width, width)
                 for a in (p_lo, p_hi, p_mask))
-        fset, new_k, idx_k, _ = fpset_insert_dedup(
-            FPSet(table), lo_k, hi_k, mask_k,
-            probe_width=width if sort_free else 0, sort_free=sort_free,
-        )
+        with jax.named_scope("jaxtlc.dedup"):
+            fset, new_k, idx_k, _ = fpset_insert_sorted(
+                FPSet(table), lo_k, hi_k, mask_k)
         with jax.named_scope("jaxtlc.compact"):
-            # the slab pads its claimants with the out-of-range row
-            # `width`
+            # idx_k is a permutation of the segment's rows: the compare
+            # and the drop below keep nothing out (ROADMAP C10)
             lane_k = jnp.where(
                 idx_k < width,
                 compact_lanes(cnt, k * width + idx_k, bucket), DB)
@@ -521,7 +519,6 @@ def make_sharded_engine(
     fp_highwater: float = None,
     pipeline: bool = False,
     obs_slots: int = 0,
-    sort_free: bool = None,
     deferred: bool = None,
     _parts: dict = None,
 ):
@@ -581,14 +578,6 @@ def make_sharded_engine(
     device, carry leaf route_stat[:, 2]) and `commit_rows` on the
     result and in the journal's `final` event say how often it ran.
 
-    sort_free (tri-state, resolved against the PER-DEVICE chunk by
-    bfs.resolve_sort_free) takes the hash-slab dedup on the owner-side
-    insert - the all_to_all routing argsort is untouched (it orders by
-    OWNER, not fingerprint; a different problem than dedup).  Each
-    segment's slab, claimant compaction and probe run at the segment's
-    width (which is also its probe width, so no segment can take the
-    sorted fallback); results are bit-for-bit the sorted engine's.
-
     deferred (tri-state, resolved against the PER-DEVICE chunk by
     bfs.resolve_deferred) moves invariant evaluation OWNER-SIDE and
     POST-ROUTING (ISSUE 15): instead of every source device sweeping
@@ -640,9 +629,8 @@ def make_sharded_engine(
     # per-destination bucket size: O(ncand/D) so send-buffer bytes stay
     # constant as the mesh grows (VERDICT round 2, weak #5)
     B = route_bucket_width(chunk, L, D, route_factor)
-    from .bfs import resolve_deferred, resolve_sort_free
+    from .bfs import resolve_deferred
 
-    sort_free = resolve_sort_free(sort_free, chunk)
     deferred = resolve_deferred(deferred, chunk)
     # state-space reduction (ISSUE 18) rides on the backend: orbit
     # canonicalization runs BEFORE fingerprinting so representatives
@@ -993,7 +981,7 @@ def make_sharded_engine(
             ins_mask = r_valid & ~fp_full
         cnt = r_valid.reshape(D, B).sum(axis=1).astype(jnp.int32)
         table, is_new, c_lane, c_new, c_rows, trips = insert_compacted(
-            table, r_lo, r_hi, ins_mask, cnt, W, sort_free)
+            table, r_lo, r_hi, ins_mask, cnt, W)
 
         with jax.named_scope("jaxtlc.enqueue"):
             n_new = is_new.sum().astype(jnp.int32)
@@ -1410,9 +1398,8 @@ class ShardedSpillRuntime:
                  fp_capacity: int, fp_index: int = DEFAULT_FP_INDEX,
                  seed: int = DEFAULT_SEED, route_factor: float = 2.0,
                  backend: SpecBackend = None, fp_highwater: float = None,
-                 obs_slots: int = 0, sort_free: bool = None,
-                 deferred: bool = None, store=None, on_event=None,
-                 spill_write_hook=None):
+                 obs_slots: int = 0, deferred: bool = None, store=None,
+                 on_event=None, spill_write_hook=None):
         from .spill import SpillStore
 
         if backend is None:
@@ -1426,7 +1413,7 @@ class ShardedSpillRuntime:
             cfg, mesh, chunk, queue_capacity, fp_capacity,
             fp_index=fp_index, seed=seed, route_factor=route_factor,
             backend=backend, fp_highwater=fp_highwater, pipeline=False,
-            obs_slots=obs_slots, sort_free=sort_free, deferred=deferred,
+            obs_slots=obs_slots, deferred=deferred,
             _parts=parts,
         )
         self.backend = backend
@@ -1924,7 +1911,6 @@ def check_sharded(
     backend: SpecBackend = None,
     pipeline: bool = False,
     obs_slots: int = 0,
-    sort_free: bool = None,
     deferred: bool = None,
 ) -> CheckResult:
     """Exhaustive sharded check; returns globally-reduced statistics.
@@ -1936,7 +1922,7 @@ def check_sharded(
     init_fn, run_fn = make_sharded_engine(
         cfg, mesh, chunk, queue_capacity, fp_capacity,
         route_factor=route_factor, backend=backend, pipeline=pipeline,
-        obs_slots=obs_slots, sort_free=sort_free, deferred=deferred,
+        obs_slots=obs_slots, deferred=deferred,
     )
     carry = init_fn()
     compiled = run_fn.lower(carry).compile()
@@ -1967,26 +1953,25 @@ def check_sharded_with_checkpoints(
     meta_config: dict = None,
     pipeline: bool = False,
     obs_slots: int = 0,
-    sort_free: bool = None,
     deferred: bool = None,
 ) -> CheckResult:
     """Sharded check with periodic whole-carry checkpoints (TLC checkpoint
     analog under distribution: one snapshot covers every shard's partition
     of the fingerprint space + frontier).  Same contract as
     checkpoint.check_with_checkpoints, over the mesh engine."""
-    from .bfs import resolve_deferred, resolve_sort_free
+    from .bfs import resolve_deferred
     from .checkpoint import (
         _meta,
         load_checkpoint,
-        resume_meta,
+        require_checkpoint,
         save_checkpoint,
     )
 
     program = cfg if backend is None else backend
     if backend is None:
         backend = kubeapi_backend(cfg)
-    sort_free = resolve_sort_free(sort_free, chunk,
-                                  resume_meta(ckpt_path, resume))
+    if resume:
+        require_checkpoint(ckpt_path)
     deferred = resolve_deferred(deferred, chunk)
     from ..runtime import aot_build, engine_key
 
@@ -2002,7 +1987,6 @@ def check_sharded_with_checkpoints(
         devices=int(mesh.devices.size),
         pipeline=pipeline,
         obs_slots=obs_slots,
-        sort_free=sort_free,
         deferred=deferred,
         symmetry=bool(red is not None and red.plan is not None),
         por=bool(red is not None and red.por and red.safe_ids),
@@ -2013,22 +1997,21 @@ def check_sharded_with_checkpoints(
     template, compiled = aot_build(lambda: make_sharded_engine(
         cfg, mesh, chunk, queue_capacity, fp_capacity,
         route_factor=route_factor, segment=ckpt_every, backend=backend,
-        pipeline=pipeline, obs_slots=obs_slots, sort_free=sort_free,
-        deferred=deferred,
+        pipeline=pipeline, obs_slots=obs_slots, deferred=deferred,
     ), key=engine_key("sharded-ckpt", program, meta, mesh, chunk,
                       route_factor, ckpt_every))
     t0 = time.time()
     if resume:
         saved_meta, carry = load_checkpoint(ckpt_path, template)
         for key in ("format", "config", "queue_capacity", "fp_capacity",
-                    "devices", "pipeline", "obs_slots", "sort_free",
-                    "deferred", "symmetry", "por"):
-            # pre-pipeline/pre-obs/pre-sort-free/pre-deferred/
-            # pre-reduction snapshots carry no key: treat as off -
-            # they were cut from engines without those features
+                    "devices", "pipeline", "obs_slots", "deferred",
+                    "symmetry", "por"):
+            # pre-pipeline/pre-obs/pre-deferred/pre-reduction
+            # snapshots carry no key: treat as off - they were cut
+            # from engines without those features
             saved = saved_meta.get(
-                key, False if key in ("pipeline", "sort_free",
-                                      "deferred", "symmetry", "por")
+                key, False if key in ("pipeline", "deferred",
+                                      "symmetry", "por")
                 else 0 if key == "obs_slots" else None
             )
             if saved != meta[key]:

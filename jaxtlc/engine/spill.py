@@ -267,17 +267,12 @@ class SpillRuntime:
                  seed: int = DEFAULT_SEED,
                  fp_highwater: float = 0.85,
                  check_deadlock: bool = None, obs_slots: int = 0,
-                 sort_free: bool = None, deferred: bool = None,
+                 deferred: bool = None,
                  store: Optional[SpillStore] = None,
                  on_event: Optional[Callable] = None,
                  spill_write_hook: Optional[Callable] = None):
-        from .bfs import (
-            make_backend_engine,
-            resolve_deferred,
-            resolve_sort_free,
-        )
+        from .bfs import make_backend_engine, resolve_deferred
 
-        sort_free = resolve_sort_free(sort_free, chunk)
         deferred = resolve_deferred(deferred, chunk)
 
         self.backend = backend
@@ -299,8 +294,7 @@ class SpillRuntime:
             backend, chunk, queue_capacity, fp_capacity, fp_index,
             seed, fp_highwater=fp_highwater,
             check_deadlock=check_deadlock, donate=False,
-            obs_slots=obs_slots, sort_free=sort_free,
-            deferred=deferred,
+            obs_slots=obs_slots, deferred=deferred,
         )
         self._base_init = init_fn
         pop_expand, commit = make_stage_pair(
@@ -308,7 +302,7 @@ class SpillRuntime:
             fp_capacity=fp_capacity, fp_highwater=fp_highwater,
             check_deadlock=check_deadlock, fp_index=fp_index,
             seed=seed, obs_slots=obs_slots, spill=True,
-            sort_free=sort_free, deferred=deferred,
+            deferred=deferred,
         )
 
         # filter walk cap: near the highwater load, ABSENT keys walk

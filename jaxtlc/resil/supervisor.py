@@ -281,7 +281,7 @@ class SingleDeviceAdapter:
     GEOM_KEYS = ("queue_capacity", "fp_capacity")
     FIXED_KEYS = ("format", "config", "chunk", "fp_index", "seed",
                   "fp_highwater", "pipeline", "obs_slots", "coverage",
-                  "sort_free", "deferred", "symmetry", "por")
+                  "deferred", "symmetry", "por")
 
     def __init__(self, cfg, chunk: int = 1024,
                  fp_index: int = DEFAULT_FP_INDEX, seed: int = DEFAULT_SEED,
@@ -289,18 +289,14 @@ class SingleDeviceAdapter:
                  backend=None, meta_config: dict = None,
                  check_deadlock: bool = True, pipeline: bool = False,
                  obs_slots: int = 0, coverage: bool = False,
-                 sort_free: bool = None, deferred: bool = None):
-        from ..engine.bfs import resolve_deferred, resolve_sort_free
+                 deferred: bool = None):
+        from ..engine.bfs import resolve_deferred
 
         self.cfg = cfg
         self.chunk = chunk
         # resolved once, against the INITIAL chunk: a later ladder
-        # chunk-shrink keeps the mode (the slab is rebuilt from the new
-        # stage-pair geometry; meta stays consistent across the resume).
-        # An auto caller's resume takes the checkpoint's recorded mode
-        # (_params_from_meta)
-        self.sort_free_auto = sort_free is None
-        self.sort_free = resolve_sort_free(sort_free, chunk)
+        # chunk-shrink keeps the mode (meta stays consistent across
+        # the resume)
         self.deferred = resolve_deferred(deferred, chunk)
         self.fp_index = fp_index
         self.seed = seed
@@ -345,8 +341,7 @@ class SingleDeviceAdapter:
                     fp_highwater=self.fp_highwater,
                     check_deadlock=self.check_deadlock,
                     pipeline=self.pipeline, donate=False,
-                    obs_slots=self.obs_slots, sort_free=self.sort_free,
-                    deferred=self.deferred,
+                    obs_slots=self.obs_slots, deferred=self.deferred,
                 )
             else:
                 init_fn, _, step_fn = make_engine(
@@ -354,8 +349,7 @@ class SingleDeviceAdapter:
                     params["fp_capacity"], self.fp_index, self.seed,
                     fp_highwater=self.fp_highwater,
                     pipeline=self.pipeline, donate=False,
-                    obs_slots=self.obs_slots, sort_free=self.sort_free,
-                    deferred=self.deferred,
+                    obs_slots=self.obs_slots, deferred=self.deferred,
                 )
 
             return init_fn, step_fn.segment(ckpt_every)
@@ -376,7 +370,7 @@ class SingleDeviceAdapter:
             fp_index=self.fp_index, seed=self.seed,
             fp_highwater=self.fp_highwater, pipeline=self.pipeline,
             obs_slots=self.obs_slots, coverage=self.coverage,
-            sort_free=self.sort_free, deferred=self.deferred,
+            deferred=self.deferred,
             symmetry=self.symmetry, por=self.por,
             **params,
         )
@@ -450,7 +444,7 @@ class SingleDeviceAdapter:
             params["fp_capacity"], fp_index=self.fp_index,
             seed=self.seed, fp_highwater=self.fp_highwater,
             check_deadlock=check_deadlock, obs_slots=self.obs_slots,
-            sort_free=self.sort_free, deferred=self.deferred,
+            deferred=self.deferred,
             store=store, on_event=on_event,
             spill_write_hook=spill_write_hook,
         )
@@ -492,23 +486,20 @@ class ShardedAdapter:
     kind = "sharded"
     GEOM_KEYS = ("queue_capacity", "fp_capacity", "route_factor")
     FIXED_KEYS = ("format", "config", "devices", "fp_highwater",
-                  "pipeline", "obs_slots", "coverage", "sort_free",
-                  "deferred", "symmetry", "por")
+                  "pipeline", "obs_slots", "coverage", "deferred",
+                  "symmetry", "por")
 
     def __init__(self, cfg, mesh, chunk: int = 512, backend=None,
                  meta_config: dict = None,
                  fp_highwater: float = DEFAULT_FP_HIGHWATER,
                  pipeline: bool = False, obs_slots: int = 0,
-                 coverage: bool = False, sort_free: bool = None,
-                 deferred: bool = None):
-        from ..engine.bfs import resolve_deferred, resolve_sort_free
+                 coverage: bool = False, deferred: bool = None):
+        from ..engine.bfs import resolve_deferred
         from ..engine.sharded import kubeapi_backend
 
         self.cfg = cfg
         self.mesh = mesh
         self.chunk = chunk
-        self.sort_free_auto = sort_free is None
-        self.sort_free = resolve_sort_free(sort_free, chunk)
         self.deferred = resolve_deferred(deferred, chunk)
         self.program = cfg if backend is None else backend
         self.backend = (backend if backend is not None
@@ -532,7 +523,7 @@ class ShardedAdapter:
             route_factor=params["route_factor"], segment=ckpt_every,
             backend=self.backend, fp_highwater=self.fp_highwater,
             pipeline=self.pipeline, obs_slots=self.obs_slots,
-            sort_free=self.sort_free, deferred=self.deferred,
+            deferred=self.deferred,
         ), key=engine_key(self.kind, self.program, self.meta(params),
                           self.mesh, ckpt_every))
 
@@ -542,7 +533,7 @@ class ShardedAdapter:
             devices=int(self.mesh.devices.size),
             fp_highwater=self.fp_highwater, pipeline=self.pipeline,
             obs_slots=self.obs_slots, coverage=self.coverage,
-            sort_free=self.sort_free, deferred=self.deferred,
+            deferred=self.deferred,
             symmetry=self.symmetry, por=self.por,
             **params,
         )
@@ -605,7 +596,7 @@ class ShardedAdapter:
             params["queue_capacity"], params["fp_capacity"],
             route_factor=params["route_factor"], backend=self.backend,
             fp_highwater=self.fp_highwater, obs_slots=self.obs_slots,
-            sort_free=self.sort_free, deferred=self.deferred,
+            deferred=self.deferred,
             store=store, on_event=on_event,
             spill_write_hook=spill_write_hook,
         )
@@ -636,22 +627,18 @@ def _params_from_meta(adapter, meta: dict, params: dict) -> dict:
     """Resume geometry resolution: fixed keys (config, codec-shaping
     parameters) must match what this process would write; growable
     geometry keys are TAKEN FROM THE CHECKPOINT (auto-grown capacities
-    travel with the snapshot, so the resume command needs none of them).
-    The dedup ordering of an auto caller is taken from the checkpoint
-    as well (bfs.resolve_sort_free: one carry, both modes exact); an
-    explicit flag that contradicts it stays the mismatch below."""
-    from ..engine.bfs import resolve_sort_free
-
-    if getattr(adapter, "sort_free_auto", False):
-        adapter.sort_free = resolve_sort_free(None, adapter.chunk, meta)
+    travel with the snapshot, so the resume command needs none of them)."""
     want = adapter.meta(params)
     for key in adapter.FIXED_KEYS:
-        # pre-pipeline/pre-obs/pre-coverage/pre-sort-free/pre-
-        # deferred/pre-reduction snapshots carry no key: they were cut
-        # from engines without those features, so missing means off
+        # pre-pipeline/pre-obs/pre-coverage/pre-deferred/pre-reduction
+        # snapshots carry no key: they were cut from engines without
+        # those features, so missing means off.  A `sort_free` key (every
+        # snapshot before ISSUE 44 has one, true or false) is not
+        # compared: the hash slab it names was a per-commit temporary and
+        # the carry was the sorted ordering's bit for bit
         have = meta.get(key, False if key in ("pipeline", "coverage",
-                                              "sort_free", "deferred",
-                                              "symmetry", "por")
+                                              "deferred", "symmetry",
+                                              "por")
                         else 0 if key == "obs_slots" else None)
         if have != want.get(key):
             raise ValueError(
@@ -1308,7 +1295,6 @@ def check_supervised(
     pipeline: bool = False,
     obs_slots: int = 0,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
     opts: SupervisorOptions = None,
 ) -> SupervisedResult:
@@ -1323,7 +1309,7 @@ def check_supervised(
         fp_highwater=fp_highwater, backend=backend,
         meta_config=meta_config, check_deadlock=check_deadlock,
         pipeline=pipeline, obs_slots=obs_slots, coverage=coverage,
-        sort_free=sort_free, deferred=deferred,
+        deferred=deferred,
     )
     return supervise(
         adapter,
@@ -1345,7 +1331,6 @@ def check_sharded_supervised(
     pipeline: bool = False,
     obs_slots: int = 0,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
     opts: SupervisorOptions = None,
 ) -> SupervisedResult:
@@ -1353,8 +1338,7 @@ def check_sharded_supervised(
     adapter = ShardedAdapter(
         cfg, mesh, chunk=chunk, backend=backend, meta_config=meta_config,
         fp_highwater=fp_highwater, pipeline=pipeline,
-        obs_slots=obs_slots, coverage=coverage, sort_free=sort_free,
-        deferred=deferred,
+        obs_slots=obs_slots, coverage=coverage, deferred=deferred,
     )
     return supervise(
         adapter,

@@ -58,7 +58,7 @@ class _SingleRunner:
 
     def __init__(self, model, chunk, queue_capacity, fp_capacity,
                  fp_index, seed, check_deadlock, pipeline, obs_slots,
-                 sort_free=None, deferred=None):
+                 deferred=None):
         import jax
 
         from ..engine.bfs import DEFAULT_FP_HIGHWATER
@@ -73,7 +73,7 @@ class _SingleRunner:
                 model, chunk, queue_capacity, fp_capacity, fp_index,
                 seed, DEFAULT_FP_HIGHWATER, check_deadlock=check_deadlock,
                 pipeline=pipeline, obs_slots=obs_slots,
-                sort_free=sort_free, deferred=deferred,
+                deferred=deferred,
             )
             self._mk_carry = jax.jit(lambda: init_fn())
             return self._mk_carry, run_fn
@@ -179,7 +179,6 @@ class EnginePool:
         check_deadlock: bool = True,
         pipeline: bool = False,
         obs_slots: int = 0,
-        sort_free: bool = None,
         deferred: bool = None,
     ) -> PoolEntry:
         """Warm plain engine for (model meaning, geometry) - keyed on
@@ -190,15 +189,14 @@ class EnginePool:
         key = engine_key(
             model, chunk, queue_capacity, fp_capacity, fp_index, seed,
             DEFAULT_FP_HIGHWATER, check_deadlock=check_deadlock,
-            pipeline=pipeline, obs_slots=obs_slots, sort_free=sort_free,
-            deferred=deferred,
+            pipeline=pipeline, obs_slots=obs_slots, deferred=deferred,
         )
         return self._get_or_build(
             key,
             lambda: _SingleRunner(
                 model, chunk, queue_capacity, fp_capacity, fp_index,
                 seed, check_deadlock, pipeline, obs_slots,
-                sort_free=sort_free, deferred=deferred,
+                deferred=deferred,
             ),
             "single",
             dict(workload=model.root_name, chunk=chunk,
@@ -215,18 +213,16 @@ class EnginePool:
         fp_index: int = DEFAULT_FP_INDEX,
         seed: int = DEFAULT_SEED,
         check_deadlock: bool = True,
-        sort_free: bool = None,
         deferred: bool = None,
     ) -> PoolEntry:
         """Warm constants-class sweep engine: one entry per CLASS (the
         swept values are runtime data, not key material)."""
-        from ..engine.bfs import resolve_deferred, resolve_sort_free
+        from ..engine.bfs import resolve_deferred
         from .sweep import SweepEngine, class_key
 
         key = ("sweep", class_key(model, params), chunk, queue_capacity,
                fp_capacity, fp_index, seed, bool(check_deadlock),
-               int(self.sweep_width), resolve_sort_free(sort_free, chunk),
-               resolve_deferred(deferred, chunk))
+               int(self.sweep_width), resolve_deferred(deferred, chunk))
         return self._get_or_build(
             key,
             lambda: SweepEngine(
@@ -234,7 +230,7 @@ class EnginePool:
                 queue_capacity=queue_capacity, fp_capacity=fp_capacity,
                 fp_index=fp_index, seed=seed,
                 check_deadlock=check_deadlock, width=self.sweep_width,
-                sort_free=sort_free, deferred=deferred,
+                deferred=deferred,
             ),
             "sweep",
             dict(workload=model.root_name, chunk=chunk,

@@ -112,7 +112,7 @@ TERMINAL_STATES = ("done", "error", "expired", "canceled", "quarantined")
 # job options forwarded to api.CheckRequest on the supervised path
 _REQUEST_OPTIONS = (
     "workers", "frontend", "chunk", "qcap", "fpcap", "pipeline",
-    "sortfree", "deferredinv", "symmetry", "por",
+    "deferredinv", "symmetry", "por",
     "sharded", "checkpoint", "checkpointevery",
     "recover", "liveness",
     "fairness", "nodeadlock", "faults", "retry", "maxregrow", "spill",
@@ -943,7 +943,6 @@ class Scheduler:
             queue_capacity=int(o.get("qcap", DEFAULT_QCAP)),
             fp_capacity=int(o.get("fpcap", DEFAULT_FPCAP)),
             check_deadlock=not o.get("nodeadlock", False),
-            sort_free=o.get("sortfree", None),
             deferred=o.get("deferredinv", None),
         )
 

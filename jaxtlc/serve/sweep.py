@@ -228,7 +228,6 @@ class SweepEngine:
         seed: int = DEFAULT_SEED,
         check_deadlock: bool = True,
         width: int = DEFAULT_WIDTH,
-        sort_free: bool = None,
         deferred: bool = None,
     ):
         self.model = model
@@ -240,14 +239,10 @@ class SweepEngine:
         # donate=False: the vmap traces THROUGH run_fn (donation would
         # alias a carry the sequential parity baseline reuses), and the
         # JAXTLC_DEBUG_DONATION poisoner must not wrap a vmapped callee
-        # NOTE on sort_free under vmap: lax.cond batches to both
-        # branches, so a sort-free sweep engine pays the sorted
-        # fallback alongside the slab - correct, just not the perf win
-        # (auto keeps sweeps sorted at their small default chunks)
         init_fn, run_fn, _ = make_backend_engine(
             self.backend, chunk, queue_capacity, fp_capacity,
             fp_index, seed, check_deadlock=check_deadlock, donate=False,
-            sort_free=sort_free, deferred=deferred,
+            deferred=deferred,
         )
         # jitted seeding: an eager init_fn recompiles its fpset
         # while_loop per call; under jit the (per-Init-set-shape)

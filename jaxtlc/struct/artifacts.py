@@ -12,7 +12,7 @@ This module amortizes the CHECK itself with two on-disk tiers under
   source digest, canonical constants, invariant selection, property
   selection, the deadlock flag, and :data:`ENGINE_SEMVER`.  The key
   deliberately EXCLUDES engine geometry (chunk / queue / fp capacity),
-  pipeline, sort_free, obs and narrowing: verdict and counters are
+  pipeline, obs and narrowing: verdict and counters are
   pinned geometry-invariant by the existing parity tests, so one
   artifact answers every geometry.  An unchanged spec returns its
   cached ``CheckOutcome`` without building (let alone compiling) an
@@ -119,7 +119,7 @@ def verdict_key(model, check_deadlock: bool = True,
     """The semantic digest of one check: spec text digest (constant
     overrides included - the loader folds them in), canonical
     constants, invariant + property selection, deadlock flag, engine
-    semver.  Geometry/pipeline/sort-free/obs/narrowing are deliberately
+    semver.  Geometry/pipeline/obs/narrowing are deliberately
     absent: verdict and counters are geometry-invariant (pinned by the
     engine parity tests), so one artifact answers every geometry."""
     blob = json.dumps([

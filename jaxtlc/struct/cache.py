@@ -269,37 +269,30 @@ def engine_key(
     obs_slots: int = 0,
     bounds=None,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
     symmetry: bool = None,
     por: bool = None,
 ) -> tuple:
     """The full engine-memo key: spec meaning (digest + canonical
-    constants + invariants) x engine geometry x pipeline/obs/coverage/
-    sort-free flags x the certified-bound digest (a narrowed engine is
-    a DIFFERENT compile - its codec, lanes and traps all change with
-    the bounds; a covered engine carries the coverage leaves; a
-    sort-free engine compiles the hash-slab commit; a deferred
+    constants + invariants) x engine geometry x pipeline/obs/coverage
+    flags x the certified-bound digest (a narrowed engine is a
+    DIFFERENT compile - its codec, lanes and traps all change with the
+    bounds; a covered engine carries the coverage leaves; a deferred
     engine moves invariant/cert evaluation to the commit stage, ISSUE
     15; a symmetry/POR-reduced engine canonicalizes and prunes in the
     expand stage, ISSUE 18).  The serve EnginePool keys its warm AOT
     entries on exactly this tuple so pool identity and memo identity
-    cannot drift.  `sort_free`/`deferred`/`symmetry`/`por` are
-    resolved (tri-state auto -> bool) against the chunk so the key
-    never depends on who asked."""
-    from ..engine.bfs import (
-        resolve_deferred,
-        resolve_por,
-        resolve_sort_free,
-    )
+    cannot drift.  `deferred`/`symmetry`/`por` are resolved (tri-state
+    auto -> bool) against the chunk so the key never depends on who
+    asked."""
+    from ..engine.bfs import resolve_deferred, resolve_por
 
     spec = model_key(model)
     return (
         spec, "single", chunk, queue_capacity, fp_capacity,
         fp_index, seed, fp_highwater, bool(check_deadlock),
         bool(pipeline), int(obs_slots), _bounds_key(bounds),
-        bool(coverage), resolve_sort_free(sort_free, chunk),
-        resolve_deferred(deferred, chunk),
+        bool(coverage), resolve_deferred(deferred, chunk),
         wants_symmetry(model, symmetry, chunk), resolve_por(por, chunk),
         _SLOT_FLOOR.get(spec, 0), _open_side(spec),
     )
@@ -318,7 +311,6 @@ def get_engine(
     obs_slots: int = 0,
     bounds=None,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
     symmetry: bool = None,
     por: bool = None,
@@ -329,10 +321,8 @@ def get_engine(
     engine is a different compile than an obs-off one.  `bounds`
     selects the narrowed engine (certificate check on, keyed on the
     bound digest); `coverage` the covered engine (per-site counter
-    leaves on the carry); `sort_free` the hash-slab commit (resolved
-    against the chunk, so an auto caller and an explicit caller at the
-    same geometry share one memo entry); `symmetry`/`por` the reduced
-    engine (orbit canonicalization + ample-set pruning, ISSUE 18)."""
+    leaves on the carry); `symmetry`/`por` the reduced engine (orbit
+    canonicalization + ample-set pruning, ISSUE 18)."""
     from ..engine.bfs import (
         make_backend_engine,
         resolve_por,
@@ -342,8 +332,7 @@ def get_engine(
         model, chunk, queue_capacity, fp_capacity, fp_index, seed,
         fp_highwater, check_deadlock=check_deadlock, pipeline=pipeline,
         obs_slots=obs_slots, bounds=bounds, coverage=coverage,
-        sort_free=sort_free, deferred=deferred, symmetry=symmetry,
-        por=por,
+        deferred=deferred, symmetry=symmetry, por=por,
     )
     hit = _ENGINE_MEMO.get(key)
     if hit is None:
@@ -355,8 +344,7 @@ def get_engine(
         hit = make_backend_engine(
             backend, chunk, queue_capacity, fp_capacity, fp_index, seed,
             fp_highwater=fp_highwater, pipeline=pipeline,
-            obs_slots=obs_slots, sort_free=sort_free,
-            deferred=deferred,
+            obs_slots=obs_slots, deferred=deferred,
         )
         _ENGINE_MEMO.put(key, hit)
     return hit

@@ -57,7 +57,6 @@ def check_struct(
     obs_slots: int = 0,
     bounds=None,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
     symmetry: bool = None,
     por: bool = None,
@@ -68,7 +67,6 @@ def check_struct(
     (a certified analysis.absint.BoundReport) runs the NARROWED engine
     with the runtime certificate check on; `coverage` the covered
     engine (device per-site coverage on CheckResult.site_coverage);
-    `sort_free` the hash-slab commit (bit-identical results);
     `symmetry`/`por` the state-space-reduced engine (orbit
     canonicalization with the runtime orbit certificate + ample-set
     pruning - same verdict, legitimately fewer states, ISSUE 18);
@@ -82,8 +80,7 @@ def check_struct(
         model, chunk, queue_capacity, fp_capacity, fp_index, seed,
         fp_highwater, check_deadlock=check_deadlock, pipeline=pipeline,
         obs_slots=obs_slots, bounds=bounds, coverage=coverage,
-        sort_free=sort_free, deferred=deferred, symmetry=symmetry,
-        por=por,
+        deferred=deferred, symmetry=symmetry, por=por,
     )
     backend = get_backend(model, check_deadlock, bounds=bounds,
                           coverage=coverage,
@@ -120,7 +117,6 @@ def check_struct_sharded(
     obs_slots: int = 0,
     bounds=None,
     coverage: bool = False,
-    sort_free: bool = None,
     deferred: bool = None,
     symmetry: bool = None,
     por: bool = None,
@@ -148,5 +144,5 @@ def check_struct_sharded(
         None, mesh, chunk=chunk, queue_capacity=queue_capacity,
         fp_capacity=fp_capacity, route_factor=route_factor,
         backend=backend, pipeline=pipeline, obs_slots=obs_slots,
-        sort_free=sort_free, deferred=deferred,
+        deferred=deferred,
     )

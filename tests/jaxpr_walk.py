@@ -1,5 +1,5 @@
 """Shared by the structural pins (tests/test_mesh_cell.py,
-tests/test_sortfree.py): a traced program's equations with the name
+tests/test_commit_dedup.py): a traced program's equations with the name
 stack each stands under."""
 
 import jax

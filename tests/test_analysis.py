@@ -544,8 +544,7 @@ def test_selfcheck_registry_pinned():
     assert sorted(FACTORIES) == [
         "covered", "covsharded", "deferred", "enumerator", "fused",
         "infer", "narrowed", "pipelined", "por", "sharded",
-        "shardspill", "sim", "sortfree", "spill", "struct", "sweep",
-        "symmetry",
+        "shardspill", "sim", "spill", "struct", "sweep", "symmetry",
     ]
 
 

@@ -7,13 +7,12 @@ The tri-state flag rides engine memos / checkpoint meta so a resume can
 never silently cross modes, and the sim tier ignores it entirely.
 
 Compile budget (tier-1 runs ~800 s of its 870 s hard timeout): ONE
-module-scoped fixture owns the two FF engine compiles - and it crosses
-BOTH mode axes at once (immediate+sorted vs deferred+SLAB commit), so
-the slab-layout claimant path is covered without a third engine.  The
-attribution / exit-12 / cert-lie tests run tiny synthetic or struct
-engines (seconds); the supervised-interrupt and sharded tests each pay
-their own small FF compile like tests/test_sortfree.py does; the dense
-claim-walk parity tests are fpset-level (no engine)."""
+module-scoped fixture owns the two FF engine compiles (immediate and
+deferred).  The attribution / exit-12 / cert-lie tests run tiny
+synthetic or struct engines (seconds); the supervised-interrupt and
+sharded tests each pay their own small FF compile like
+tests/test_commit_dedup.py does; the dense claim-walk parity test is
+fpset-level (no engine)."""
 
 import dataclasses
 import io
@@ -50,18 +49,14 @@ def signature(r):
 @pytest.fixture(scope="module")
 def ab_runs():
     """The module's ONLY full engine compiles: the FF corner through
-    the immediate engine (sorted commit) and the deferred engine
-    (SLAB commit - the deferred checker then consumes the interspersed
-    slab claimant layout, not just the sorted prefix), final carries
-    kept for TABLE-word comparison.  Bit-for-bit across BOTH mode axes
-    at once: test_sortfree pins sorted==slab, this fixture pins
-    immediate==deferred on top of it."""
+    the immediate engine and the deferred engine, final carries kept
+    for TABLE-word comparison."""
     import jax
 
     out = {}
-    for df, sf in ((False, False), (True, True)):
+    for df in (False, True):
         init_fn, run_fn, _ = make_engine(
-            FF, **KW, donate=False, sort_free=sf, deferred=df,
+            FF, **KW, donate=False, deferred=df,
         )
         carry = jax.block_until_ready(run_fn(init_fn()))
         out[df] = (carry, result_from_carry(carry, 0.0))
@@ -400,20 +395,18 @@ def _hot_bucket_batch(seed: int, n: int):
     return np.array(lo), np.array(hi), mask
 
 
-@pytest.mark.parametrize("path", ["sorted", "slab"])
-def test_dense_walk_matches_host_replay(path):
+def test_dense_walk_matches_host_replay():
     """The straggler walk's own pin, against a reference that shares no
     code with it (a Python set and fpset.host_insert's one-at-a-time
     linear walk): under hot-bucket pressure with a narrow round-0 claim
-    width, on either insert path, the verdicts name the highest lane of
-    every fresh fingerprint, the table holds each of them exactly once
-    (the host replay's words, wherever in a bucket they sit), and every
-    stored word is where a lookup's walk finds it."""
+    width the verdicts name the highest lane of every fresh
+    fingerprint, the table holds each of them exactly once (the host
+    replay's words, wherever in a bucket they sit), and every stored
+    word is where a lookup's walk finds it."""
     import jax.numpy as jnp
 
     from jaxtlc.engine import fpset
 
-    insert = getattr(fpset, f"fpset_insert_{path}")
     n, cap = 384, 1 << 11
     s = fpset.fpset_new(cap)
     ref = np.zeros_like(np.asarray(s.table))
@@ -421,7 +414,7 @@ def test_dense_walk_matches_host_replay(path):
     for step in range(3):
         lo, hi, mask = _hot_bucket_batch(100 + step, n)
         lo[::7], hi[::7] = lo[1::7][:len(lo[::7])], hi[1::7][:len(hi[::7])]
-        s, is_new_c, c_idx, _ = insert(
+        s, is_new_c, c_idx, _ = fpset.fpset_insert_sorted(
             s, jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(mask),
             probe_width=n, claim_width=64,
         )

@@ -118,16 +118,15 @@ def test_high_load():
     assert int(np.asarray(new).sum()) == 700
 
 
-@pytest.mark.parametrize("path", ["sorted", "slab"])
-def test_blocked_write_bit_for_bit(monkeypatch, path):
+def test_blocked_write_bit_for_bit(monkeypatch):
     """A wide write goes block by block behind a trip count (ISSUE 26:
     `_blocked`, round 0 over its compacted claimers, the straggler walk
     over its live prefix): the table words and verdicts are those of
     the one whole-width scatter, with and without straggler pressure,
-    on either insert path, and the distinct count is the set's."""
+    and the distinct count is the set's."""
     from jaxtlc.engine import fpset
 
-    insert = getattr(fpset, f"fpset_insert_{path}")
+    insert = fpset.fpset_insert_sorted
     n = 1024
     assert fpset._blocked(n)
     got = {}

@@ -249,9 +249,9 @@ PARENT_DIGEST = (
 # over all D x B received lanes); sorted and wide paths alike
 PARENT_DIGEST_LESS_TABLE = (
     "6e6ca3fbac083d5103dc07096f006006d08356f272c91a0a7364b3bbc6256fd9")
-# the explicit slab (a 16384-wide chunk's auto until ISSUE 38) and the
-# owner-side deferred invariants (its auto still), forced at chunk 128
-WIDE = dict(sort_free=True, deferred=True)
+# the owner-side deferred invariants (a 16384-wide chunk's auto),
+# forced at chunk 128
+WIDE = dict(deferred=True)
 
 
 @contextlib.contextmanager
@@ -712,8 +712,8 @@ def test_no_scatter_holds_the_queue():
         return out
 
     found = []
-    # fused, pipelined, the slab and deferred paths, and the one-device
-    # mesh, where B is all of ncand
+    # fused, pipelined, the deferred path, and the one-device mesh,
+    # where B is all of ncand
     for D, kw in ((4, {}), (4, dict(pipeline=True)), (4, WIDE), (1, {})):
         init_fn, seg_fn = make_sharded_engine(
             FF, fp_mesh(D), segment=16, **GEOM, **kw)

@@ -287,7 +287,7 @@ def _single(**over):
     from jaxtlc.resil.supervisor import SingleDeviceAdapter
 
     kw = dict(chunk=128, fp_index=3, seed=7, check_deadlock=True,
-              obs_slots=0, sort_free=False, deferred=False)
+              obs_slots=0, deferred=False)
     kw.update(over)
     return SingleDeviceAdapter(kw.pop("cfg", FF), **kw)
 
@@ -311,7 +311,6 @@ SINGLE_CASES = {
     "ckpt_every": lambda: (_single(), BASE, 16),
     "check_deadlock": lambda: (_single(check_deadlock=False), BASE, 8),
     "obs_slots": lambda: (_single(obs_slots=64), BASE, 8),
-    "sort_free": lambda: (_single(sort_free=True), BASE, 8),
     "deferred": lambda: (_single(deferred=True), BASE, 8),
     "config": lambda: (_single(cfg=ModelConfig(True, False)), BASE, 8),
 }
@@ -361,8 +360,7 @@ def test_changing_one_key_field_misses(field, kept, fake, mesh4,
                 dict(mesh_params, route_factor=3.0), 8),
             # one backend, one geometry, the other adapter
             "route-kind": lambda: (_single(
-                backend=backend, chunk=512, sort_free=False,
-                deferred=False), BASE, 8),
+                backend=backend, chunk=512, deferred=False), BASE, 8),
         }[field]()
     for n, (adapter, params, every) in enumerate([base, base, other]):
         adapter.build(dict(params), every)

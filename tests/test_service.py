@@ -261,6 +261,21 @@ def test_warm_job_verdict_in_one_get_without_a_pause(
     assert client.status(server.url, jid) == st
 
 
+def test_job_whose_options_still_name_the_slab_runs(server, sweep_jobs):
+    """ISSUE 44: `sortfree` is no job option any more.  A client that
+    still sends it is answered as it always was for an option the
+    scheduler does not know - ignored - and the job ends on the pinned
+    verdict through the pool."""
+    jid = client.submit(server.url, _TPB, _cfg(2), name="old-client",
+                        options=dict(_OPTS, sortfree=True))
+    st = client.wait(server.url, jid, timeout=600)
+    assert st["state"] == "done", st
+    assert st["result"]["engine"] == "pool"
+    assert (st["result"]["generated"], st["result"]["distinct"],
+            st["result"]["depth"]) == _EXPECT[2][:3]
+    assert st["options"]["sortfree"] is True  # kept as sent, not read
+
+
 def test_pooled_job_leaves_every_span(server, sweep_jobs):
     """ISSUE 24: a pooled job's host spans - the scheduler thread's
     eleven (since PR 31: `build.struct.load` inside `sched.load`)

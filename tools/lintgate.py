@@ -28,17 +28,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 REQUIRED_FACTORIES = (
     "covered", "covsharded", "deferred", "enumerator", "fused",
     "infer", "narrowed", "pipelined", "por", "sharded", "shardspill",
-    "sim", "sortfree", "spill", "struct", "sweep", "symmetry",
+    "sim", "spill", "struct", "sweep", "symmetry",
 )
 
 
 def check_factories() -> int:
-    """Engine-free registry pin: every REQUIRED factory (the explicit
-    sort-free commit engine, ISSUE 12 - no engine's default since
-    ISSUE 38, its deletion is ROADMAP C2 - and the deferred-evaluation
-    engine, ISSUE 15, included) must be registered for the
-    `python -m jaxtlc.analysis --self-check` audit - a commit that
-    drops one fails here before any engine builds."""
+    """Engine-free registry pin: every REQUIRED factory (the
+    deferred-evaluation engine, ISSUE 15, included) must be registered
+    for the `python -m jaxtlc.analysis --self-check` audit - a commit
+    that drops one fails here before any engine builds."""
     from jaxtlc.analysis.selfcheck import FACTORIES
 
     missing = sorted(set(REQUIRED_FACTORIES) - set(FACTORIES))
