@@ -51,6 +51,8 @@ from ..struct.shapes import (
     ShapeError,
     ShapeInference,
     _clamp,
+    cap_sequences,
+    caps_by_var,
     infer_shapes,
     shape_leq,
     shape_of_value,
@@ -663,13 +665,24 @@ def _init_shapes(system, const_hints=None,
     return out
 
 
-def _step_writes(system, env, const_hints=None) -> Dict[str, Shape]:
+def _settled(shapes, seq_caps) -> Dict[str, Optional[Shape]]:
+    """`shapes` with every growing sequence held to its capacity (what
+    the spec declares, else the first guess: cap_sequences), as
+    infer_shapes holds its own passes' - the abstract Append itself
+    gives one slot more each pass."""
+    caps = caps_by_var(seq_caps)
+    return {v: cap_sequences(sh, caps.get(v, ()))
+            for v, sh in shapes.items()}
+
+
+def _step_writes(system, env, const_hints=None,
+                 seq_caps=()) -> Dict[str, Shape]:
     st = _Stepper(system.ev, system.variables, system.init_ast,
                   system.next_ast, env, const_hints=const_hints)
-    return st.run_step()
+    return _settled(st.run_step(), seq_caps)
 
 
-def _certify(system, bounds, init, const_hints=None) -> bool:
+def _certify(system, bounds, init, const_hints=None, seq_caps=()) -> bool:
     """Machine-check that `bounds` is a post-fixpoint: Init ⊑ bounds
     and step#(bounds) ⊑ bounds."""
     for v in system.variables:
@@ -677,7 +690,7 @@ def _certify(system, bounds, init, const_hints=None) -> bool:
             return False
     try:
         writes = _step_writes(system, dict(bounds),
-                              const_hints=const_hints)
+                              const_hints=const_hints, seq_caps=seq_caps)
     except (ShapeError, RecursionError):
         return False
     for v, sh in writes.items():
@@ -708,12 +721,15 @@ def analyze_bounds(model, const_hints: Optional[Dict[str, Shape]] = None,
     t0 = time.time()
     system = model.system
     hints = typeok_hints(system.ev, model.invariants, system.variables)
+    seq_caps = list(model.seq_caps)
     baseline = infer_shapes(system.ev, system.variables,
                             system.init_ast, system.next_ast,
-                            hints=hints, const_hints=const_hints)
+                            hints=hints, const_hints=const_hints,
+                            seq_caps=seq_caps)
 
-    init = _init_shapes(system, const_hints=const_hints,
-                        extra_systems=extra_init_systems)
+    init = _settled(_init_shapes(system, const_hints=const_hints,
+                                 extra_systems=extra_init_systems),
+                    seq_caps)
 
     # descending narrowing from the widened baseline (joined with every
     # configuration's Init seed: the anchor's ascending run only saw its
@@ -737,7 +753,8 @@ def analyze_bounds(model, const_hints: Optional[Dict[str, Shape]] = None,
             iters += 1
             try:
                 writes = _step_writes(system, dict(cur),
-                                      const_hints=const_hints)
+                                      const_hints=const_hints,
+                                      seq_caps=seq_caps)
             except (ShapeError, RecursionError):
                 return None
             nxt = {}
@@ -760,7 +777,8 @@ def analyze_bounds(model, const_hints: Optional[Dict[str, Shape]] = None,
         iters += 1
         try:
             writes = _step_writes(system, dict(cur_a),
-                                  const_hints=const_hints)
+                                  const_hints=const_hints,
+                                  seq_caps=seq_caps)
         except (ShapeError, RecursionError):
             break
         nxt = {
@@ -780,7 +798,8 @@ def analyze_bounds(model, const_hints: Optional[Dict[str, Shape]] = None,
     for cand in (ascend, descend, baseline):
         if cand is None:
             continue
-        if _certify(system, cand, init, const_hints=const_hints):
+        if _certify(system, cand, init, const_hints=const_hints,
+                    seq_caps=seq_caps):
             cur = dict(cand)
             certified = True
             break

@@ -505,7 +505,8 @@ def inferred_shapes(model, const_hints=None) -> dict:
         system.ev, system.variables, system.init_ast, system.next_ast,
         hints=hints, const_hints=const_hints,
         kept=constraint_bounds(system.ev, model.constraints,
-                               system.variables))
+                               system.variables),
+        seq_caps=list(model.seq_caps))
 
 
 def constraint_report(model, var_shapes: Optional[dict] = None
@@ -524,6 +525,7 @@ def constraint_report(model, var_shapes: Optional[dict] = None
         _INT_THRESHOLDS,
         SInt,
         SRec,
+        STup,
         constraint_bounds,
     )
 
@@ -544,6 +546,9 @@ def constraint_report(model, var_shapes: Optional[dict] = None
         elif isinstance(sh, SRec):
             for f, s, _ in sh.fields:
                 yield from leaves(s, path + (f,))
+        elif isinstance(sh, STup):
+            for k, s in enumerate(sh.items, start=1):
+                yield from leaves(s, path + (k,))
 
     def name(var, path):
         return var + "".join(

@@ -1194,14 +1194,15 @@ def _run_check_struct(args, spec) -> int:
     capture = art_plan is not None and not args.sharded
 
     def check():
-        # a compacted step (struct.compile.compact_width) halts loudly on
-        # a state with more live lanes than slots; the rung: every
-        # backend of the model with twice the slots, and the check again
-        # from its initial states, until the step is as wide as its
-        # static fan - there the overflow is a trap of the codec
+        # a run that halts on a trap of its step (an Append on a full
+        # sequence, more live lanes than the compacted step's slots, a
+        # value out of a guessed range) takes the rung that cures it -
+        # struct.cache.widen: every backend of the model rebuilt wider -
+        # and the check starts again from its initial states; where
+        # none does, the trap is the codec's
         from .engine.bfs import VIOL_SLOT_OVERFLOW
         from .resil import SlotOverflowError
-        from .struct.cache import widen_slots
+        from .struct.cache import widen
 
         while True:
             halt = None
@@ -1211,37 +1212,19 @@ def _run_check_struct(args, spec) -> int:
                 halt = e
             if halt is None and r.violation != VIOL_SLOT_OVERFLOW:
                 return r, sup
-            wider = widen_slots(sm, get_backend(
+            rung = widen(sm, get_backend(
                 sm, spec.check_deadlock, bounds=bounds,
                 elide=not args.sharded, coverage=args.coverage,
-                symmetry=_symmetry(args), por=_por(args)))
-            if wider is None:
-                # not compacted: the trap is a range trap.  Under a
-                # cfg's CONSTRAINT the side of a leaf the constraint
-                # leaves open was capped by a guess: 16 times further
-                # out, and the check again
-                from .struct.cache import widen_open_sides
-
-                further = widen_open_sides(sm, get_backend(
-                    sm, spec.check_deadlock, bounds=bounds,
-                    elide=not args.sharded, coverage=args.coverage,
-                    symmetry=_symmetry(args), por=_por(args)))
-                if further is None:
-                    if halt is not None:
-                        raise halt
-                    return r, sup
-                _sup_opts(args, log_holder[0]).on_event("degrade", dict(
-                    rung="widen", resource="open_side_factor",
-                    action="%d->%d" % further,
-                    reason="a kept state left the range guessed for a "
-                           "leaf the CONSTRAINT bounds on one side; "
-                           "the check starts again"))
-                continue
+                symmetry=_symmetry(args), por=_por(args)),
+                halt.state if halt is not None else r.violation_state)
+            if rung is None:
+                if halt is not None:
+                    raise halt
+                return r, sup
+            resource, step, reason = rung
             _sup_opts(args, log_holder[0]).on_event("degrade", dict(
-                rung="widen", resource="step_slots",
-                action="%d->%d" % wider,
-                reason="a state fired more lanes than the compacted "
-                       "step keeps; the check starts again"))
+                rung="widen", resource=resource, action="%d->%d" % step,
+                reason=reason))
 
     qcap = []
 

@@ -268,6 +268,18 @@ class CheckResult(NamedTuple):
     step_lanes: int = None
     step_slots: int = None
     state_words: int = None
+    # the bits of a packed state (`state_bits / (32 state_words)`: how
+    # full the row is that the dedup sorts, fingerprints and enqueues);
+    # the static slots of all its sequences, where their capacities
+    # came from ("declared": an invariant or the CONSTRAINT bounds
+    # every one; "guess": some capacity is the first guess or a
+    # rung's; None: no growing sequence) and the capacity rungs taken
+    # (struct.cache.widen_seq_caps: 0 unless an Append met a full
+    # sequence and the check started again)
+    state_bits: int = None
+    seq_slots: int = None
+    seq_cap_from: str = None
+    seq_widen: int = None
     states_expanded: int = None
     lane_fires: int = None
     struct_traps: int = None
@@ -326,7 +338,8 @@ class CheckResult(NamedTuple):
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
                  "route_bucket", "route_bytes", "commit_segments",
                  "commit_rows", "enqueue_segments")
-STEP_COUNTERS = ("step_lanes", "step_slots", "state_words",
+STEP_COUNTERS = ("step_lanes", "step_slots", "state_words", "state_bits",
+                 "seq_slots", "seq_cap_from", "seq_widen",
                  "states_expanded", "lane_fires", "struct_traps",
                  "lookup_const", "lookup_arith", "lookup_gather",
                  "sym_perms", "sym_sets", "canon_rows", "canon_moved",
@@ -365,6 +378,10 @@ def with_step_counters(result: CheckResult, backend) -> CheckResult:
         step_lanes=static,
         step_slots=backend.n_lanes,
         state_words=backend.cdc.n_words,
+        state_bits=backend.cdc.nbits,
+        seq_slots=getattr(backend.cdc, "seq_slots", 0),
+        seq_cap_from=getattr(backend.cdc, "seq_cap_from", None),
+        seq_widen=getattr(backend.cdc, "seq_widen", 0),
         states_expanded=result.distinct - result.queue_left,
         lane_fires=sum(result.action_generated.values()),
         struct_traps=int(result.violation == VIOL_SLOT_OVERFLOW),

@@ -42,6 +42,7 @@ __all__ = [
     "collision_probability",
     "find_polys",
     "is_irreducible",
+    "POLY_SEED",
 ]
 
 MASK64 = (1 << 64) - 1
@@ -49,31 +50,62 @@ MASK64 = (1 << 64) - 1
 # Table of c such that x^64 + p(c) is irreducible over GF(2), where p(c) is
 # the polynomial with coefficient bits c (bit i = coefficient of x^i).  131
 # entries, matching the size/indexing convention of TLC's polynomial table so
-# .launch fpIndex values (0..130) stay meaningful.  Generated by find_polys();
-# regenerate with `python -m jaxtlc.engine.fingerprint`.
+# .launch fpIndex values (0..130) stay meaningful.  DENSE (ROADMAP M10): 24 to
+# 40 of the low 64 coefficients are set.  The fingerprint is GF(2)-affine in
+# the state's bits, so two states whose XOR is a multiple of the polynomial
+# of degree under the state's width collide; a polynomial that is x^64 plus
+# a dozen low terms (the table until PR 45: index 51 was 0xc8b) has such
+# multiples that are shifted copies of itself, a pattern of a few bits that
+# a dense product space past 64 bits is full of (528 of 400,000 states at an
+# 87-bit EWD998 layout).  A dense polynomial's low multiples are dense too.
+# At <= 64 message bits the map is injective for ANY irreducible entry.
+# Generated by find_polys(); `python -m jaxtlc.engine.fingerprint` verifies
+# the table against it.
 POLYS = [
-    0x1b, 0x1d, 0x8d, 0xf5, 0x173, 0x175,
-    0x1a1, 0x1b3, 0x1cd, 0x1df, 0x23d, 0x251,
-    0x283, 0x289, 0x2a1, 0x2a7, 0x2cb, 0x2d3,
-    0x32b, 0x347, 0x3a5, 0x3bb, 0x3c9, 0x3cf,
-    0x3f3, 0x3ff, 0x40d, 0x425, 0x50f, 0x533,
-    0x68b, 0x6af, 0x6c3, 0x6f5, 0x779, 0x77f,
-    0x807, 0x861, 0x89d, 0x8e5, 0x90f, 0x939,
-    0x993, 0xa0f, 0xa11, 0xa87, 0xad7, 0xaf9,
-    0xbad, 0xc05, 0xc1d, 0xc8b, 0xcb1, 0xcf5,
-    0xdcb, 0xe07, 0xe23, 0xee3, 0xf09, 0xf69,
-    0xf93, 0xfeb, 0x103d, 0x107f, 0x1083, 0x10ad,
-    0x10d3, 0x1139, 0x116f, 0x11a9, 0x11d7, 0x11ed,
-    0x1281, 0x12a5, 0x12af, 0x12d7, 0x12db, 0x12ed,
-    0x150d, 0x151f, 0x1619, 0x1645, 0x1649, 0x1657,
-    0x165b, 0x166d, 0x1689, 0x16ef, 0x1709, 0x1747,
-    0x174d, 0x1755, 0x175f, 0x177b, 0x1787, 0x17e7,
-    0x1979, 0x19b5, 0x19e5, 0x1a1f, 0x1a31, 0x1a4f,
-    0x1a75, 0x1a97, 0x1ae3, 0x1b35, 0x1b55, 0x1b7d,
-    0x1bbb, 0x1bc9, 0x1c3b, 0x1c5d, 0x1c61, 0x1cb9,
-    0x1ccd, 0x1d05, 0x1d3f, 0x1d41, 0x1d6f, 0x1d87,
-    0x1df5, 0x1f67, 0x1f83, 0x1fc7, 0x2037, 0x2057,
-    0x2067, 0x206d, 0x208f, 0x20f1, 0x2141,
+    0x5f4e14147961f2e9, 0x52aead5ef08ac45f, 0x2f597787e8c75c47,
+    0x6a06d8f31cc8127b, 0xe1460d5af30b3df5, 0x12d82d1c233b6a89,
+    0x56c2dbc758d198b1, 0x9a4311b95a6a991d, 0x1b4ecbbf8e54cf3d,
+    0x613143aef10f037f, 0xd29b84ddd8e3c053, 0x8006bdf6cb4518d9,
+    0x870de46cf6010981, 0xb6aec7073042e657, 0x45a234c595d28e3b,
+    0xb651de1c0f036271, 0x1e44bd3adc1f31cb, 0x74150821214d6d19,
+    0xae66d9c9ed35cb75, 0x17b4506d91c11f09, 0x1ef381471882459d,
+    0x15d296b65b10ed59, 0xca4f5ea6aea466e7, 0x0a13c4dcdb40f663,
+    0x457f2a8688d8b03b, 0x9a2c36ee9be78d1d, 0xd312bc02cc6c8311,
+    0x9d47f3d9610d4d55, 0x96ec43c2680631b3, 0xd81c8b9f164d0bd9,
+    0x9f6715a3c8797a7f, 0x9b18920312fc39bb, 0xeb96c94bb566f4b7,
+    0x7744e0eb26de95dd, 0xd1dbb2a1f21a4bdf, 0x8d5812b02211f6cf,
+    0x48a566055b4ca627, 0xd7a4ae4f2042b923, 0x26cda44e28ab3873,
+    0x02a67819614e6aab, 0x9a24ec9e6a65dc0b, 0xdb609cdb0440b5b3,
+    0xa9a927d2616dfce1, 0xc533150e98913695, 0xfb1278a4836cc403,
+    0xf761229f95978179, 0x44381783ce9490ff, 0xa736d79354a2bb4f,
+    0xd06ecbf50de0c833, 0x9a3f24f1bbac10e7, 0xae148dd2b296009b,
+    0x755d64d039122153, 0x86e9d9ed6fe2382b, 0x8ceb9ec0da9b9e83,
+    0x9191be792822db5d, 0x913bf9c7a38d2339, 0x1519e40836464fb9,
+    0x40697b0f17d1da69, 0xc7d58eeaeb7bc66d, 0x52b52ec56083f5b3,
+    0x9f0290337ec9a4db, 0x548f60d5ed2622b1, 0xcd64276b582d9c95,
+    0x40e27de20c22ae0f, 0x10a0cd5b85de16e9, 0xa36a2df71c8a638f,
+    0x60fa6a135941a397, 0xaa4abc9a190c0fad, 0xd5ed0c2d8aaf1d4b,
+    0xb8941c6e22c6a4f5, 0x3ff97507f50ee223, 0x60b0597084c5e961,
+    0x8b923a29839a0379, 0xeddc7a61479c9bbd, 0x041ea91d649ec6f7,
+    0xb2d901c3f669bd7d, 0xabd2db8dce369e8f, 0xee858fc07cd3d079,
+    0x3501b062bcd9e891, 0x33fb44206361f895, 0xcb3f628790cf7c97,
+    0xb99b8676e5f22391, 0x0dbd28a6a022f39b, 0xfadb9ae2725fc5bb,
+    0x70d41a17a3eccdbb, 0x073b443b11625fa5, 0xfcaa7ef6c41050d7,
+    0x43cc66143e0bffa7, 0x262e76295c8c7623, 0x78069b83a33661e3,
+    0x2e95c5a9567f7dad, 0xc8301bd3ae65a1c3, 0xd605731c2e8c3dc1,
+    0xba5f85573269cad1, 0x001a663d8482c9cf, 0xca131e77f78f3fa7,
+    0x3c5862bace0b027b, 0x9e69aad8bffd3545, 0xedf1f872f3c682dd,
+    0x1f7a3dbaaf3efa07, 0x3ba562aed4b91319, 0x02c7c3a90ce855ed,
+    0x9a55dec108781d59, 0x4d31be0a4b72578b, 0x4c3651a335502ad3,
+    0x90f92c1d524e30cf, 0xb305f96e1fd294bb, 0x9944ea6da4307de5,
+    0x469bf393fbe21077, 0x7ec103a2519f4289, 0xa2024a00b98d5ee9,
+    0x71dc47e1f2cce4bd, 0x829a3f5058f3fddd, 0x6b423b9c1e9f1bed,
+    0x20127cea37e1e313, 0xb5d3fc89a3938e57, 0x8fef5e57a2430dd9,
+    0x91d63bc8a9609a93, 0x4ab98e96e1eec03f, 0x1aa3f67e6e4dd6e9,
+    0x0d5a61c0df35dceb, 0xd0c94613968c7f43, 0x67e88320c93f656b,
+    0x7397f8389fc5683d, 0x7b1f9a5db0ceb1a5, 0xf1ada11335ea49d7,
+    0xa6032841a7a1f963, 0x62f3549f54ade86f, 0x9d69155abcbd61a3,
+    0xcef53f1120df9b8d, 0xf6d3447fc352e45b,
 ]
 
 DEFAULT_FP_INDEX = 51  # the reference run's fpIndex (launch:8)
@@ -118,11 +150,24 @@ def is_irreducible(p: int) -> bool:
     return t == 2
 
 
-def find_polys(n: int = 131):
-    """Scan odd constants upward collecting irreducible x^64 + p(c)."""
-    out, c = [], 1
+POLY_SEED = 0x6A09E667F3BCC908  # the fraction of sqrt(2): no choice in it
+
+
+def find_polys(n: int = 131, seed: int = POLY_SEED):
+    """The first `n` irreducible x^64 + p(c) of a seeded stream of dense
+    candidates: c the splitmix64 sequence from `seed`, made odd (a zero
+    constant term is a factor x), kept where it has an even number of
+    terms (an odd one with x^64 is a multiple of x + 1) between 24 and
+    40: about half of the low 64 coefficients."""
+    out, x = [], seed
     while len(out) < n:
-        c += 2
+        x = (x + 0x9E3779B97F4A7C15) & MASK64
+        z = ((x ^ (x >> 30)) * 0xBF58476D1CE4E5B9) & MASK64
+        z = ((z ^ (z >> 27)) * 0x94D049BB133111EB) & MASK64
+        c = (z ^ (z >> 31)) | 1
+        terms = bin(c).count("1")
+        if terms % 2 or not 24 <= terms <= 40 or c in out:
+            continue
         if is_irreducible((1 << 64) | c):
             out.append(c)
     return out

@@ -145,8 +145,11 @@ class SlotOverflowError(RuntimeError):
     with wider ModelConfig bounds - so the supervisor checkpoints the
     last good carry and raises this with the resume story attached."""
 
-    def __init__(self, ckpt_path: Optional[str]):
+    def __init__(self, ckpt_path: Optional[str], state=None):
         self.ckpt_path = ckpt_path
+        # the [F] field vector of the state whose expansion trapped
+        # (the struct path reads the trap's cause off it)
+        self.state = state
         hint = (
             f"; last good carry checkpointed at {ckpt_path!r} - after "
             "raising the bounds, restart (a recompiled codec changes the "
@@ -1135,7 +1138,10 @@ def supervise(adapter, params: dict,
                                 store_snap=good_store)
                 except OSError:
                     pass
-                raise SlotOverflowError(path)
+                # (a carry of another engine's shape names no state)
+                state = getattr(carry2, "viol_state", None)
+                raise SlotOverflowError(
+                    path, state=None if state is None else np.asarray(state))
 
             carry = carry2
             good = carry2

@@ -33,6 +33,7 @@ from .shapes import (
     OPEN_SIDE_FACTOR,
     constraint_bounds,
     infer_shapes,
+    seq_summary,
     typeok_hints,
 )
 
@@ -99,7 +100,8 @@ def struct_backend(model: StructModel,
                    symmetry: bool = False,
                    por: bool = False,
                    slots: int = 0,
-                   open_side_factor: int = OPEN_SIDE_FACTOR) -> SpecBackend:
+                   open_side_factor: int = OPEN_SIDE_FACTOR,
+                   seq_cap_floor: int = 0) -> SpecBackend:
     """Compile `model` into a SpecBackend: parse -> shape-infer ->
     lane-compile, the pipeline struct.cache memoizes in-process.
 
@@ -152,7 +154,14 @@ def struct_backend(model: StructModel,
     narrowing is not taken: it knows nothing of the constraint.
     `open_side_factor` is how far out the
     side of a leaf that the constraint leaves open is capped:
-    struct.cache.widen_open_sides raises it after a range trap."""
+    struct.cache.widen_open_sides raises it after a range trap.
+
+    A sequence that grows has a capacity (ISSUE 45): what an invariant
+    or the CONSTRAINT declares for it (`Len(network[p][q]) <= 3`:
+    model.seq_caps, the loader's `build.struct.seqcap`), else a first
+    guess; `seq_cap_floor` is the least capacity of every sequence once
+    an Append met a full one and the run started again
+    (struct.cache.widen_seq_caps)."""
     from ..obs.spans import span
 
     system = model.system
@@ -162,6 +171,7 @@ def struct_backend(model: StructModel,
                              system.variables)
     if model.constraints:
         bounds = None
+    seq_caps = list(model.seq_caps)
     with span("build.struct.shapes"):
         if bounds is not None and getattr(bounds, "certified", False):
             var_shapes = {v: bounds.bounds[v] for v in system.variables}
@@ -175,7 +185,9 @@ def struct_backend(model: StructModel,
             var_shapes = infer_shapes(system.ev, system.variables,
                                       system.init_ast, system.next_ast,
                                       hints=hints, kept=kept,
-                                      open_side_factor=open_side_factor)
+                                      open_side_factor=open_side_factor,
+                                      seq_caps=seq_caps,
+                                      seq_cap_floor=seq_cap_floor)
         cdc = StructCodec(system.variables, var_shapes,
                           structural=frozenset(b.var for b in kept))
     compiler = LaneCompiler(system.ev, system.variables, var_shapes,
@@ -391,6 +403,13 @@ def struct_backend(model: StructModel,
     backend.cdc.trap_stats = trap_stats
     # the static fan before compaction (CheckResult.step_lanes)
     backend.cdc.static_lanes = len(labels)
+    # the sequences' static slots, where their capacities came from
+    # (CheckResult.seq_slots / seq_cap_from) and the largest (the rung's
+    # start); None under certified bounds, whose capacities are the
+    # report's
+    (backend.cdc.seq_slots, backend.cdc.seq_cap_from,
+     backend.cdc.seq_cap_max) = seq_summary(
+        var_shapes, seq_caps) if bounds is None else (0, None, 0)
     # the forms its field reads took (CheckResult.lookup_*)
     backend.cdc.lookup_counts = compiler.lookup_counts
     # a state predicate compiled as the invariants are ([B, F] -> bool

@@ -145,55 +145,134 @@ def _bounds_key(bounds) -> str:
     return bounds.digest()
 
 
-# model key -> slots a state of the model's compacted step keeps, where
-# a run met a state with more live lanes than `compile.compact_width`
-# gave it (widen_slots); part of every backend and engine key
-_SLOT_FLOOR: dict = {}
+# (model key, resource) -> what a rung raised the resource to, for
+# every backend of the model built from now on (this process); part of
+# every backend and engine key.  The resources (a `degrade` event's
+# `resource`):
+#   step_slots        the slots a state of the compacted step keeps
+#   open_side_factor  the factor of shapes.cap_open_sides
+#   seq_cap           the least capacity of every sequence
+#   seq_widen         the capacity rungs taken (CheckResult.seq_widen)
+_FLOORS: dict = {}
+OPEN_SIDE_LIMIT = 1 << 16
+SEQ_CAP_RUNG_LIMIT = 1 << 10
+
+
+def _floor(spec, resource: str) -> int:
+    """The floor of `resource` for the model keyed `spec`: 0 where no
+    rung was taken (the open side's factor: shapes.OPEN_SIDE_FACTOR)."""
+    from .shapes import OPEN_SIDE_FACTOR
+
+    return _FLOORS.get((spec, resource), OPEN_SIDE_FACTOR
+                       if resource == "open_side_factor" else 0)
 
 
 def widen_slots(model, backend):
     """The rung a compaction overflow takes: twice the slots `backend`
-    kept a state, at most its static fan, for every backend of `model`
-    built from now on (this process).  Returns (old, new), or None
+    kept a state, at most its static fan.  Returns (old, new), or None
     where `backend` is not compacted: its overflow is a trap of the
     codec, which no width cures."""
     static = getattr(backend.cdc, "static_lanes", backend.n_lanes)
     if backend.n_lanes >= static:
         return None
     new = min(static, 2 * backend.n_lanes)
-    _SLOT_FLOOR[model_key(model)] = new
+    _FLOORS[model_key(model), "step_slots"] = new
     return backend.n_lanes, new
-
-
-# model key -> the factor of shapes.cap_open_sides, where a run of a
-# constrained model tripped a range trap (widen_open_sides); part of
-# every backend and engine key, like the slot floor
-_OPEN_SIDE: dict = {}
-OPEN_SIDE_LIMIT = 1 << 16
-
-
-def _open_side(spec) -> int:
-    from .shapes import OPEN_SIDE_FACTOR
-
-    return _OPEN_SIDE.get(spec, OPEN_SIDE_FACTOR)
 
 
 def widen_open_sides(model, backend):
     """The rung a range trap of a CONSTRAINED model's step takes: the
     sides of its integer leaves that the constraint leaves open are
-    capped 16 times further out (shapes.cap_open_sides), for every
-    backend of `model` built from now on (this process).  Returns
+    capped 16 times further out (shapes.cap_open_sides).  Returns
     (old, new) factors, or None where the model has no constraint or
     the cap has passed the widening's own last threshold: the trap is
     then the codec's, which no factor cures."""
     if getattr(backend, "constraint", None) is None:
         return None
     key = model_key(model)
-    old = _open_side(key)
+    old = _floor(key, "open_side_factor")
     if old >= OPEN_SIDE_LIMIT:
         return None
-    _OPEN_SIDE[key] = old * 16
+    _FLOORS[key, "open_side_factor"] = old * 16
     return old, old * 16
+
+
+def widen_seq_caps(model, backend):
+    """The rung an Append on a full sequence takes: every sequence of
+    the model at least half as large again as the largest the layout
+    holds (a declared capacity that did not hold is overridden: its
+    invariant then fails as an invariant).  Returns (old, new)
+    capacities, or None where the layout's capacities are not this
+    ladder's (no growing sequence; a certified-bound layout)."""
+    if getattr(backend.cdc, "seq_cap_from", None) is None:
+        return None
+    old = backend.cdc.seq_cap_max
+    if old >= SEQ_CAP_RUNG_LIMIT:
+        return None
+    key = model_key(model)
+    new = old + max(1, old // 2)
+    _FLOORS[key, "seq_cap"] = new
+    _FLOORS[key, "seq_widen"] = _floor(key, "seq_widen") + 1
+    return old, new
+
+
+def _append_trapped(model, backend, vec) -> bool:
+    """Whether the trap that halted a run at the state `vec` (its [F]
+    field vector) is an Append on a full sequence: some successor of
+    the state, by the host evaluator, holds a sequence longer than the
+    layout's capacity.  A constrained model's trap fires on kept
+    successors alone, as the engine's does.  (The step folds its two
+    flags, an Append's overflow and a value out of range, into one
+    violation code: PERF.md section 7-21e.)"""
+    from .codec import SeqCapError
+
+    if vec is None or getattr(backend.cdc, "seq_cap_from", None) is None:
+        return False  # no state named; no sequence this ladder widens
+    system = model.system
+    try:
+        for _, succ in system.successors(backend.cdc.decode(vec)):
+            env = dict(system.ev.constants)
+            env.update(zip(system.variables, succ))
+            if not all(system.ev.eval(ast, env) is True
+                       for ast in model.constraints.values()):
+                continue
+            try:
+                backend.cdc.encode(succ)
+            except SeqCapError:
+                return True
+            except ValueError:
+                pass  # a successor out of a leaf's range: another rung's
+    except (ValueError, IndexError):
+        # the host cannot take the step again (the evaluator or the
+        # decode refuses the state): not this rung's to judge
+        pass
+    return False
+
+
+def widen(model, backend, state=None):
+    """The rung a run of `model` takes that halted on a trap
+    (VIOL_SLOT_OVERFLOW) expanding `state`, in the ladder's order: an
+    Append on a full sequence -> longer sequences; a compacted step ->
+    more slots; a constrained model -> its open sides further out.
+    Returns (resource, (old, new), reason) - the check then starts
+    again from its initial states - or None where no rung cures the
+    trap: it is the codec's."""
+    step = _append_trapped(model, backend, state) and widen_seq_caps(
+        model, backend)
+    if step:
+        return "seq_cap", step, (
+            "an Append met a full sequence; the check starts again")
+    step = widen_slots(model, backend)
+    if step:
+        return "step_slots", step, (
+            "a state fired more lanes than the compacted step keeps; "
+            "the check starts again")
+    step = widen_open_sides(model, backend)
+    if step:
+        return "open_side_factor", step, (
+            "a kept state left the range guessed for a leaf the "
+            "CONSTRAINT bounds on one side; the check starts again")
+    return None
 
 
 def wants_symmetry(model, symmetry=None, chunk: int = 0) -> bool:
@@ -235,11 +314,11 @@ def get_backend(model, check_deadlock: bool = True, bounds=None,
     from .backend import struct_backend
 
     spec = model_key(model)
-    slots = _SLOT_FLOOR.get(spec, 0)
-    open_side = _open_side(spec)
+    slots, open_side, seq_floor = (_floor(spec, r) for r in (
+        "step_slots", "open_side_factor", "seq_cap"))
     key = (spec, bool(check_deadlock), _bounds_key(bounds),
            bool(elide), bool(coverage), bool(symmetry), bool(por), slots,
-           open_side)
+           open_side, seq_floor)
     # host span `build.struct`: the memo's look-up and, on a miss, the
     # shape inference and the lane walk inside it (`build.struct.shapes`,
     # `build.struct.lanes`) - the struct path's own part of a build
@@ -251,7 +330,9 @@ def get_backend(model, check_deadlock: bool = True, bounds=None,
                                  bounds=bounds, elide=elide,
                                  coverage=coverage, symmetry=symmetry,
                                  por=por, slots=slots,
-                                 open_side_factor=open_side)
+                                 open_side_factor=open_side,
+                                 seq_cap_floor=seq_floor)
+            hit.cdc.seq_widen = _floor(spec, "seq_widen")
             _BACKEND_MEMO.put(key, hit)
     return hit
 
@@ -294,7 +375,8 @@ def engine_key(
         bool(pipeline), int(obs_slots), _bounds_key(bounds),
         bool(coverage), resolve_deferred(deferred, chunk),
         wants_symmetry(model, symmetry, chunk), resolve_por(por, chunk),
-        _SLOT_FLOOR.get(spec, 0), _open_side(spec),
+        _floor(spec, "step_slots"), _floor(spec, "open_side_factor"),
+        _floor(spec, "seq_cap"),
     )
 
 
@@ -355,4 +437,4 @@ def clear() -> None:
     _BACKEND_MEMO.clear()
     _ENGINE_MEMO.clear()
     _BOUNDS_MEMO.clear()
-    _SLOT_FLOOR.clear()
+    _FLOORS.clear()

@@ -14,7 +14,12 @@ int32 fields, composing four layout forms:
               field-wise (canonical zero).
 * SeqNode   - bounded sequence: a length field + cap slot fields, each
               slot an EnumLeaf of the element universe (procedure call
-              stacks, /root/reference/KubeAPI.tla:466).
+              stacks, /root/reference/KubeAPI.tla:466; a FIFO channel of
+              message records).  A sequence nothing is ever appended to
+              (capacity 0) has no field at all.
+* TupNode   - a function over 1..n (a tuple): its components' layouts
+              one after the other, each its own (`network[p][q]`: a
+              tuple of tuples of SeqNodes); no presence bit, no length.
 
 Packing to uint32 words reuses the bit-concatenation scheme of the
 KubeAPI and generic codecs, so the MXU fingerprint path and fingerprint
@@ -33,6 +38,7 @@ from .shapes import (
     SRec,
     SSeq,
     SSet,
+    STup,
     ShapeError,
     universe,
 )
@@ -144,28 +150,39 @@ class RecNode:
         return tuple(sorted(pairs)), pos
 
 
+class SeqCapError(ValueError):
+    """A sequence longer than its layout's capacity: what the Append
+    trap of the compiled step halts a run on (api._run_check_struct
+    reads the halt's cause off the host encode of the successors)."""
+
+
 class SeqNode:
     def __init__(self, shape: SSeq):
         self.shape = shape
         self.cap = shape.cap
         self.elem = EnumLeaf(shape.elem)
-        self.widths = [_bits_for(self.cap + 1)] + \
-            self.elem.widths * self.cap
+        # always empty: the length is no information
+        self.widths = ([_bits_for(self.cap + 1)] + \
+                       self.elem.widths * self.cap) if self.cap else []
         self.n_fields = len(self.widths)
 
     def encode(self, v, out: List[int]):
         if not isinstance(v, tuple):
             raise ValueError(f"expected sequence, got {v!r}")
         if len(v) > self.cap:
-            raise ValueError(
+            raise SeqCapError(
                 f"sequence longer than inferred cap {self.cap}: {v!r}"
             )
+        if not self.cap:
+            return
         out.append(len(v))
         for x in v:
             self.elem.encode(x, out)
         out.extend([0] * ((self.cap - len(v)) * self.elem.n_fields))
 
     def decode(self, fields, pos: int) -> Tuple[object, int]:
+        if not self.cap:
+            return (), pos
         n = int(fields[pos])
         pos += 1
         items = []
@@ -174,6 +191,30 @@ class SeqNode:
             pos = pos2
             if k < n:
                 items.append(val)
+        return tuple(items), pos
+
+
+class TupNode:
+    def __init__(self, shape: STup):
+        self.shape = shape
+        self.children = [layout_of(s) for s in shape.items]
+        self.widths: List[int] = []
+        for child in self.children:
+            self.widths.extend(child.widths)
+        self.n_fields = len(self.widths)
+
+    def encode(self, v, out: List[int]):
+        if not isinstance(v, tuple) or len(v) != len(self.children):
+            raise ValueError(
+                f"expected a {len(self.children)}-tuple, got {v!r}")
+        for child, x in zip(self.children, v):
+            child.encode(x, out)
+
+    def decode(self, fields, pos: int) -> Tuple[object, int]:
+        items = []
+        for child in self.children:
+            val, pos = child.decode(fields, pos)
+            items.append(val)
         return tuple(items), pos
 
 
@@ -194,9 +235,14 @@ def _layout_max_codes(lay, out: List[int]) -> None:
             _layout_max_codes(child, out)
         return
     if isinstance(lay, SeqNode):
-        out.append(lay.cap)
+        if lay.cap:
+            out.append(lay.cap)
         for _ in range(lay.cap):
             out.append(len(lay.elem.values) - 1)
+        return
+    if isinstance(lay, TupNode):
+        for child in lay.children:
+            _layout_max_codes(child, out)
         return
     raise ShapeError(f"no max codes for layout {type(lay).__name__}")
 
@@ -233,6 +279,11 @@ def _build_layout(shape: Optional[Shape]):
         # the universe (nested inside enumerated records they still
         # enum-encode via universe())
         return SeqNode(shape)
+    if isinstance(shape, STup):
+        # component by component, whatever the size of the universe: a
+        # component is then a column (or a sequence's columns) of its
+        # own, read and written with no decode of the others
+        return TupNode(shape)
     try:
         return EnumLeaf(shape)
     except ShapeError:

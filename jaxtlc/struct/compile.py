@@ -53,7 +53,15 @@ import numpy as np
 from jax import lax
 
 from ..spec.labels import DEFAULT_INIT
-from .codec import EnumLeaf, MaskLeaf, RecNode, SeqNode, StructCodec, layout_of
+from .codec import (
+    EnumLeaf,
+    MaskLeaf,
+    RecNode,
+    SeqNode,
+    StructCodec,
+    TupNode,
+    layout_of,
+)
 from .eval import (
     _SORT_KEY,
     BUILTIN_SETS,
@@ -113,11 +121,17 @@ def table_form(table: np.ndarray) -> tuple:
     is `(arange(U) // s) % r`, one mixed-radix digit of the code (a
     field of a function or record whose universe is the product of its
     fields' - the codec's own order; the identity is s = 1, r >= U);
-    ("gather",) for anything else (a translation between unrelated
-    universes, a CHOOSE rank, an arbitrary predicate)."""
+    ("bits", m) where the table is a predicate over at most 32 codes
+    (`m.type = "req"`, `m \\in Message` for a slot of a channel): bit
+    `code` of the integer m, a shift and a mask - arithmetic on the
+    code, and counted as such; ("gather",) for anything else (a
+    translation between unrelated universes, a CHOOSE rank, a predicate
+    over a larger universe)."""
     t = np.asarray(table).astype(np.int64)
     if (t == t[0]).all():
         return ("const",)
+    if np.asarray(table).dtype == bool and len(t) <= 32:
+        return ("bits", int(sum(1 << i for i in np.flatnonzero(t))))
     # a digit reads 0 below s and 1 at s, and is back at 0 at s * r
     s = int(np.argmax(t != 0))
     if s:
@@ -511,9 +525,13 @@ class LaneCompiler:
                 form = (table, table_form(table))
                 self._table_forms[id(table)] = form
             kind, *digit = form[1]
-            self._tally[kind] += 1
+            self._tally["arith" if kind == "bits" else kind] += 1
             if kind == "const":
                 out = jnp.full(le.arr.shape, table[0])
+            elif kind == "bits":
+                code = jnp.clip(le.arr, 0, len(table) - 1)
+                out = (jnp.uint32(digit[0]) >> code.astype(jnp.uint32)
+                       ) & 1 == 1
             elif kind == "arith":
                 out = _digit(le.arr, len(table), *digit).astype(table.dtype)
             else:
@@ -1049,6 +1067,12 @@ class LaneCompiler:
             return self._comp_apply(ast, env, ctx)
         if op == "domain":
             return self._comp_domain(self.comp(ast[1], env, ctx))
+        if op == "subset":
+            base = self.comp(ast[1], env, ctx)
+            if not isinstance(base, LC):
+                raise CompileError("SUBSET of a dynamic set")
+            # only ever asked for membership (`crit \in SUBSET Proc`)
+            return LC(LazySet("subset", base.value))
         if op == "not":
             v = self.comp(ast[1], env, ctx)
             if isinstance(v, LC):
@@ -1245,6 +1269,19 @@ class LaneCompiler:
                                      oe.arr, oe.depth)
             oe = LE(jnp.where(oka == 1, oa, -1), base.leaf, d)
             return self._from_leaf(oe, base.leaf.shape)
+        if isinstance(base, LRec) and isinstance(arg, LI) and [
+                f for f, _, _ in base.entries] == list(
+                    range(1, len(base.entries) + 1)):
+            # a tuple (a function over 1..n) at an index read off the
+            # state: a where-chain over its components; an index outside
+            # 1..n traps, as the sequence's does
+            out = base.entries[-1][2]
+            for k, _, v in reversed(base.entries[:-1]):
+                out = self.select(self.eq(arg, LC(k)), v, out)
+            bad = self._lor(self._int_cmp(arg, "<", 1),
+                            self._int_cmp(arg, ">", len(base.entries)))
+            ctx.trap = self._lor(ctx.trap, _flatten(bad))
+            return out
         if not isinstance(arg, LC):
             raise CompileError("dynamic function application index")
         key = arg.value
@@ -1349,6 +1386,12 @@ class LaneCompiler:
                        ">=": x >= y}[sym], d)
         raise CompileError(f"cannot compile cmp {sym}")
 
+    def _int_cmp(self, a, sym, k: int) -> LV:
+        """`a sym k` for an integer lane value and a host integer."""
+        av, ad = self._int_arr(a)
+        return LB({"<": av < k, ">": av > k, "<=": av <= k,
+                   ">=": av >= k}[sym], ad)
+
     def _int_arr(self, lv):
         """(arr, depth) int view of a lane value (LI, int LC, or an
         enum-coded SInt)."""
@@ -1404,6 +1447,16 @@ class LaneCompiler:
         return out
 
     def _eq_lv(self, a, b) -> LV:
+        if isinstance(a, LTuple) and isinstance(b, LSeq):
+            a, b = b, a
+        if isinstance(a, LSeq) and isinstance(b, LTuple):
+            # `network[q][p] # << >>`: the length, and slot by slot
+            if len(b.items) > a.cap:
+                return LC(False)
+            out = self.eq(a.length, LC(len(b.items)))
+            for slot, item in zip(a.slots, b.items):
+                out = self._land(out, self.eq(slot, item))
+            return out
         if isinstance(a, (LSetLit, LTuple)) or isinstance(b, (LSetLit,
                                                               LTuple)):
             raise CompileError("structural literal equality unsupported")
@@ -1493,6 +1546,22 @@ class LaneCompiler:
         evaluator's LazySet): field by field, key by key."""
         if isinstance(a, LC):
             return LC(Evaluator._member(a.value, lazy))
+        if lazy.kind == "seq":
+            # every live slot of the sequence is a member
+            if not isinstance(a, LSeq):
+                return LC(False)
+            out = LC(True)
+            for i, slot in enumerate(a.slots):
+                dead = self._lnot(self._int_cmp(a.length, ">", i))
+                out = self._land(out, self._lor(
+                    dead, self._member_lv(slot, LC(lazy.parts))))
+            return out
+        if lazy.kind == "subset":
+            return self._subseteq_lv(a, LC(lazy.parts))
+        if lazy.kind == "diff":
+            return self._land(
+                self._member_lv(a, LC(lazy.parts[0])),
+                self._lnot(self._member_lv(a, LC(lazy.parts[1]))))
         if isinstance(a, LE):
             a = self.explode(a)
         if not isinstance(a, LRec):
@@ -1544,6 +1613,12 @@ class LaneCompiler:
         a = self.comp(la, env, ctx)
         b = self.comp(ra, env, ctx)
         if sym in (r"\cup", r"\cap", "\\"):
+            ca, cb = _const_set(a), _const_set(b)
+            if ca is not None and cb is not None and not (
+                    isinstance(a, LC) and isinstance(b, LC)):
+                # literals of host constants on both sides (`Message ==
+                # {AckMessage, RelMessage} \union {ReqMessage(c) : ..}`)
+                a, b = LC(ca), LC(cb)
             am, bm = self._two_masks(a, b)
             if am is None:  # both constant
                 from .eval import Evaluator as _E
@@ -2049,6 +2124,15 @@ class LaneCompiler:
     def _comp_setmap(self, ast, env, ctx) -> LV:
         _, expr, var, dom_ast = ast
         desc = self._dom_descriptor(dom_ast, env, ctx)
+        if desc[0] == "const":
+            # over a constant set (`{ReqMessage(c) : c \in Clock}`): an
+            # element a value, a literal of them
+            items = []
+            for v in desc[1]:
+                env2 = dict(env)
+                env2[var] = LC(v)
+                items.append(self.comp(expr, env2, ctx))
+            return LSetLit(items)
         if desc[0] != "mask":
             raise CompileError("set map over non-mask domain")
         m: LM = desc[1]
@@ -2144,6 +2228,11 @@ class LaneCompiler:
         if name in ("FoldFunctionOnSet", "FoldFunction"):
             return self._comp_fold(name, args, env, ctx)
         vals = [self.comp(a, env, ctx) for a in args]
+        if name == "Seq":
+            dom = _const_set(vals[0])
+            if dom is None:
+                raise CompileError("Seq of a dynamic set")
+            return LC(LazySet("seq", dom))
         if name == "Cardinality":
             (s,) = vals
             if isinstance(s, LC):
@@ -2282,6 +2371,10 @@ class LaneCompiler:
         # that IS its source column (by identity) is not written back
         self._src_cols = [fields[:, j] for j in range(fields.shape[1])]
         self._look_ups = {}
+        # decoded value -> (its layout, its source columns): a value
+        # that reaches an encode as the object the decode made (a
+        # component no EXCEPT touched) is written back as its columns
+        self._decoded: Dict[int, tuple] = {}
         for v, lay in zip(self.variables, self.codec.layouts):
             lv, pos = self._decode_layout(lay, fields, pos,
                                           self.var_shapes[v])
@@ -2289,6 +2382,11 @@ class LaneCompiler:
         return out
 
     def _decode_layout(self, lay, fields, pos, shape):
+        lv, end = self._decode_node(lay, fields, pos, shape)
+        self._decoded[id(lv)] = (lay, self._src_cols[pos:end], lv)
+        return lv, end
+
+    def _decode_node(self, lay, fields, pos, shape):
         if isinstance(lay, EnumLeaf):
             lv = LE(fields[:, pos], lay, 0)
             # committed-state fields hold legal codes (encode traps
@@ -2318,6 +2416,16 @@ class LaneCompiler:
                 val, pos = self._decode_layout(child, fields, pos, fsh)
                 entries.append((f, pres, val))
             return LRec(entries), pos
+        if isinstance(lay, TupNode):
+            entries = []
+            for k, (child, csh) in enumerate(
+                    zip(lay.children, lay.shape.items), start=1):
+                val, pos = self._decode_layout(child, fields, pos, csh)
+                entries.append((k, LC(True), val))
+            return LRec(entries), pos
+        if isinstance(lay, SeqNode) and not lay.cap:
+            return LSeq(LI(jnp.zeros_like(fields[:, 0]), 0, bounds=(0, 0)),
+                        [], lay.elem, 0), pos
         if isinstance(lay, SeqNode):
             length = LI(fields[:, pos], 0, bounds=(0, lay.cap))
             pos += 1
@@ -2330,8 +2438,27 @@ class LaneCompiler:
 
     def encode_var(self, lv, lay, shape, B, ctx) -> List:
         """LV -> list of [B] int32 field arrays matching the layout."""
-        if lv == "passthrough":
+        if isinstance(lv, str):
             raise CompileError("passthrough handled by caller")
+        hit = self._decoded.get(id(lv))
+        if hit is not None and hit[0] is lay:
+            return list(hit[1])
+        if isinstance(lay, TupNode):
+            if isinstance(lv, LC) and isinstance(lv.value, tuple) \
+                    and not (lv.value and is_fn(lv.value)):
+                lv = LRec([(k, LC(True), LC(x))
+                           for k, x in enumerate(lv.value, start=1)])
+            if not isinstance(lv, LRec):
+                raise CompileError(
+                    f"cannot encode {type(lv).__name__} as a tuple")
+            out = []
+            for k, (child, csh) in enumerate(
+                    zip(lay.children, lay.shape.items), start=1):
+                _, v = lv.get(k)
+                if v is None:
+                    raise CompileError(f"component {k} of a tuple absent")
+                out.extend(self.encode_var(v, child, csh, B, ctx))
+            return out
         if isinstance(lay, EnumLeaf):
             le = self.to_leaf(lv, lay)
             arr = jnp.broadcast_to(_to_b(le.arr, B), (B,))
@@ -2404,8 +2531,15 @@ class LaneCompiler:
                     out.extend(sub)
             return out
         if isinstance(lay, SeqNode):
+            if isinstance(lv, LC) and lv.value == ():
+                return [jnp.zeros((B,), jnp.int32)] * lay.n_fields
             if not isinstance(lv, LSeq):
                 raise CompileError("cannot encode non-sequence")
+            if not lay.cap:
+                # a sequence the layout keeps no element of
+                ctx.trap = self._lor(ctx.trap, self._int_cmp(
+                    lv.length, ">", 0))
+                return []
             ln = jnp.broadcast_to(_to_b(lv.length.arr, B), (B,))
             ln = jnp.clip(ln, 0, lay.cap)
             out = [ln.astype(jnp.int32)]
@@ -2988,6 +3122,18 @@ def _mask_align(a_bits, a_pre, b_bits, b_pre):
 
 ENUM_LEAF_LIMIT_TABLE = 1 << 20
 _NOCONST = object()
+
+
+def _const_set(lv):
+    """The host value of a set that is a constant or a literal of host
+    constants, else None."""
+    if isinstance(lv, LC) and isinstance(lv.value, frozenset):
+        return lv.value
+    if isinstance(lv, LSetLit):
+        items = [_const_record(x) for x in lv.items]
+        if all(x is not _NOCONST for x in items):
+            return frozenset(items)
+    return None
 
 
 def _const_record(lv):

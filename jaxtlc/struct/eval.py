@@ -61,9 +61,12 @@ INT = _Sentinel("Int")
 
 class LazySet:
     """A set that is only ever asked for membership, because a part of
-    it is infinite: `[Node -> Int]`, `[pos : Node, q : Int]` (the type
-    invariants of specs over unbounded integers).  `kind` is "funcset"
-    (parts: domain, range) or "recset" (parts: ((field, set), ...))."""
+    it is infinite or its enumeration is beside the point: `[Node ->
+    Int]`, `[pos : Node, q : Int]` (the type invariants of specs over
+    unbounded integers), `Seq(Message)`, `Nat \\ {0}`, the powerset of
+    a large set.  `kind` is "funcset" (parts: domain, range), "recset"
+    (parts: ((field, set), ...)), "seq" (parts: the element set),
+    "subset" (parts: the base set) or "diff" (parts: left, right)."""
 
     def __init__(self, kind, parts):
         self.kind = kind
@@ -80,6 +83,10 @@ class LazySet:
     def __repr__(self):
         return f"<{self.kind} with an infinite part>"
 
+
+# SUBSET S is enumerated up to this many elements of S, and is a set
+# asked for membership alone (LazySet) beyond
+SUBSET_ENUM_LIMIT = 12
 
 BUILTIN_SETS = {
     "STRING": STRING,
@@ -249,6 +256,15 @@ class Evaluator:
             )
         if op == "domain":
             return fn_domain(self.eval(ast[1], env, primed))
+        if op == "subset":
+            base = self.eval(ast[1], env, primed)
+            if not isinstance(base, frozenset) \
+                    or len(base) > SUBSET_ENUM_LIMIT:
+                return LazySet("subset", base)
+            elems = sorted(base, key=_SORT_KEY)
+            return frozenset(
+                frozenset(x for i, x in enumerate(elems) if bits >> i & 1)
+                for bits in range(1 << len(elems)))
         if op == "not":
             return not self._bool(ast[1], env, primed)
         if op == "and":
@@ -429,12 +445,23 @@ class Evaluator:
         if b is INT:
             return isinstance(a, int) and not isinstance(a, bool)
         if isinstance(b, LazySet):
-            if not (isinstance(a, tuple) and a and is_fn(a)):
+            if b.kind == "seq":
+                return isinstance(a, tuple) and not (a and is_fn(a)) \
+                    and all(Evaluator._member(x, b.parts) for x in a)
+            if b.kind == "subset":
+                return isinstance(a, frozenset) and all(
+                    Evaluator._member(x, b.parts) for x in a)
+            if b.kind == "diff":
+                return Evaluator._member(a, b.parts[0]) \
+                    and not Evaluator._member(a, b.parts[1])
+            if not isinstance(a, tuple):
                 return False
+            # a function over 1..n is kept as a sequence
+            pairs = a if a and is_fn(a) else tuple(enumerate(a, 1))
             want = b.fields()
-            return [k for k, _ in a] == [f for f, _ in want] and all(
+            return [k for k, _ in pairs] == [f for f, _ in want] and all(
                 Evaluator._member(x, dom)
-                for (_, x), (_, dom) in zip(a, want))
+                for (_, x), (_, dom) in zip(pairs, want))
         raise StructEvalError(f"\\in over non-set {b!r}")
 
     def _binop(self, ast, env, primed):
@@ -442,6 +469,9 @@ class Evaluator:
         a = self.eval(la, env, primed)
         b = self.eval(ra, env, primed)
         if sym in (r"\cup", r"\cap", "\\"):
+            if sym == "\\" and isinstance(b, frozenset) and (
+                    a in (NAT, INT, STRING) or isinstance(a, LazySet)):
+                return LazySet("diff", (a, b))  # `Nat \ {0}`
             if not (isinstance(a, frozenset) and isinstance(b, frozenset)):
                 raise StructEvalError(f"{sym} expects sets")
             return {r"\cup": a | b, r"\cap": a & b, "\\": a - b}[sym]
@@ -535,6 +565,10 @@ class Evaluator:
             if not isinstance(s, tuple):
                 raise StructEvalError("Append expects a sequence")
             return s + (e,)
+        if name == "Seq":
+            # the finite sequences over a set: membership only
+            (dom,) = vals
+            return LazySet("seq", dom)
         if name == "Permutations":
             # TLC module: the set of all bijections of a finite set onto
             # itself (what a cfg's SYMMETRY definition is built from)

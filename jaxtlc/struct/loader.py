@@ -64,6 +64,12 @@ class StructModel(NamedTuple):
     # the expand stage apply it, and every other route refuses the
     # model by name (engine.backend.require_unconstrained)
     constraints: Dict[str, tuple] = {}
+    # the capacities the spec declares for its sequences
+    # (shapes.seq_cap_bounds over the cfg's invariants and CONSTRAINT:
+    # `Len(network[p][q]) <= 3`), resolved once a load; () where it
+    # declares none.  A growing sequence without one takes a first
+    # guess; both are guarded by the Append trap (struct.backend)
+    seq_caps: tuple = ()
 
 
 class StructLoadError(ValueError):
@@ -438,9 +444,21 @@ def _load(cfg_path: str,
             out[n] = d.body
         return out
 
+    invariants = _named_defs(cfg.invariants)
+    # host span `build.struct.seqcap`: the sequence capacities the
+    # invariants and the constraint declare, settled here so that the
+    # shape inference (on a backend-memo miss) only reads them; a check
+    # pays the walk on every call, like the constraint's resolution
+    with span("build.struct.seqcap") as sp:
+        from .shapes import seq_cap_bounds
+
+        seq_caps = tuple(seq_cap_bounds(
+            ev, {**invariants, **constraints}, module.variables))
+        sp.attrs["declared"] = len(seq_caps)
+
     return StructModel(
         system=ActionSystem(ev, module.variables, init_name, next_name),
-        invariants=_named_defs(cfg.invariants),
+        invariants=invariants,
         properties=_named_defs(cfg.properties),
         constants=constants,
         module=module,
@@ -449,4 +467,5 @@ def _load(cfg_path: str,
         source_digest=digest.hexdigest(),
         symmetry=symmetry,
         constraints=constraints,
+        seq_caps=seq_caps,
     )

@@ -36,7 +36,7 @@ AST nodes are plain tuples (texpr-compatible where the form overlaps):
   ("let", [(name, params, body), ..], e)
   ("choose", var, dom|None, pred)
   ("forall", [vars], dom, body) ("exists", [vars], dom, body)
-  ("unchanged", [names]) ("domain", e) ("atref",)
+  ("unchanged", [names]) ("domain", e) ("subset", e) ("atref",)
 """
 
 from __future__ import annotations
@@ -132,7 +132,7 @@ _TOKEN_RE = re.compile(
   | (?P<exists>\\E\b)
   | (?P<ge>\\geq\b)
   | (?P<le>\\leq\b|=<)
-  | (?P<op>\\(?:in|notin|subseteq|cup|cap|o)\b)
+  | (?P<op>\\(?:in|notin|subseteq|cup|cap|union|o)\b)
   | (?P<setminus>\\)
   | (?P<leadsto>~>)
   | (?P<implies>=>)
@@ -160,6 +160,10 @@ _TOKEN_RE = re.compile(
 )
 
 
+# the ASCII spellings TLA+ gives one operator: one token value each
+_OP_ALIASES = {r"\union": r"\cup"}
+
+
 def tokenize(text: str) -> List[Tok]:
     toks: List[Tok] = []
     for line_no, line in enumerate(text.split("\n"), start=1):
@@ -171,7 +175,9 @@ def tokenize(text: str) -> List[Tok]:
                     f"line {line_no}: cannot tokenize {line[pos:pos+20]!r}"
                 )
             if m.lastgroup != "ws":
-                toks.append(Tok(m.lastgroup, m.group(), line_no, pos))
+                toks.append(Tok(m.lastgroup,
+                                _OP_ALIASES.get(m.group(), m.group()),
+                                line_no, pos))
             pos = m.end()
     return toks
 
@@ -771,6 +777,9 @@ class _ExprParser:
             return ("unchanged", [self.expect("name").val])
         if v == "DOMAIN":
             return ("domain", self.parse_postfix())
+        if v == "SUBSET":
+            # the powerset, a prefix operator that binds like DOMAIN
+            return ("subset", self.parse_postfix())
         if self.peek().kind == "sym" and self.peek().val == "(":
             self.next()
             args = [self._parse_arg()]

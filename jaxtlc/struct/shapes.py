@@ -8,8 +8,8 @@ dynamic value representations:
 
   SBool | SInt(lo,hi) | SAtoms(strings/model values) |
   SRec(field -> (shape, optional)) | SSet(elem) |
-  SFun(keys, val, partial) | SSeq(elem, cap) | SUnion(alts) |
-  SEnum(values)
+  SFun(keys, val, partial) | SSeq(elem, cap) | STup(items) |
+  SUnion(alts) | SEnum(values)
 
 Records with optional fields become presence-tagged products; sets of
 records become bitmasks over the record universe (KubeAPI's apiState,
@@ -18,9 +18,18 @@ per-key presence bits; procedure frames/stacks (:466) become bounded
 sequences.  The abstract domains over-approximate reachable values -
 over-approximation costs lanes, never soundness, because the codec can
 then represent every reachable value.  Fixpoint iteration with range
-hulls for ints and a configurable cap for sequence growth (the kernel
-flags overflow at runtime if a run exceeds it, like the hand kernel's
-slot-overflow code).
+hulls for ints.  A function over 1..n (`[p \\in Proc |-> ...]` with
+`Proc == 1 .. N`; in TLA+ a tuple) is STup: exactly n components, each
+with its own shape, so that `network[p][p]`, which no action appends
+to, costs nothing.  A sequence that grows (`Append`) has a CAPACITY,
+and the abstract step cannot find one (every pass appends once more):
+it is read off the spec where an invariant or the cfg's CONSTRAINT
+declares it (`Len(network[p][q]) <= 3`: seq_cap_bounds), and is a first
+guess (SEQ_CAP_GUESS) where nothing does.  Either way it is guarded:
+an `Append` on a full sequence is a trap that halts the run (never a
+shorter sequence), and the run starts again with the capacity a rung
+higher (struct.cache.widen_seq_caps) - a declared bound that does not
+hold then fails as the invariant it is.
 """
 
 from __future__ import annotations
@@ -95,6 +104,14 @@ class SSeq(Shape):
 
 
 @dataclasses.dataclass(frozen=True)
+class STup(Shape):
+    """A function over 1..n, which TLA+ and the value model keep as a
+    tuple: exactly len(items) components, each with its own shape (an
+    item None: no value seen there yet)."""
+    items: Tuple[Optional[Shape], ...]
+
+
+@dataclasses.dataclass(frozen=True)
 class SUnion(Shape):
     alts: Tuple[Shape, ...]  # at most one alt per shape class
 
@@ -132,7 +149,20 @@ def enum_fields(sh: SEnum) -> List[str]:
     return sorted(names)
 
 
-SEQ_CAP_LIMIT = 2  # widening clamp; kernel checks overflow at runtime
+# a growing sequence's capacity where the spec declares none: a first
+# guess, guarded by the Append trap and raised by a rung
+SEQ_CAP_GUESS = 2
+# `join`, `Append` and `\\o` give a capacity as they find it; whoever
+# drives abstract passes (ShapeInference.run, analysis.absint) settles
+# the capacities after each pass (cap_sequences)
+
+
+def _as_seq(sh: "STup") -> "SSeq":
+    """A tuple read as a sequence (it met one of another length)."""
+    elem = None
+    for x in sh.items:
+        elem = join(elem, x)
+    return SSeq(elem, len(sh.items))
 
 
 # ---------------------------------------------------------------------------
@@ -148,6 +178,13 @@ def join(a: Optional[Shape], b: Optional[Shape]) -> Optional[Shape]:
     # the empty tuple value is both the empty function and the empty
     # sequence (eval._pairs_to_fn); its shape SSeq(None, 0) coerces to
     # whatever container it joins with
+    if isinstance(a, STup) and isinstance(b, STup) \
+            and len(a.items) == len(b.items):
+        return STup(tuple(join(x, y) for x, y in zip(a.items, b.items)))
+    if isinstance(a, STup) and isinstance(b, (STup, SSeq)):
+        a = _as_seq(a)
+    if isinstance(b, STup) and isinstance(a, SSeq):
+        b = _as_seq(b)
     if a == SSeq(None, 0) and not isinstance(b, SSeq):
         a = _empty_as(b)
     if b == SSeq(None, 0) and not isinstance(a, SSeq):
@@ -189,8 +226,7 @@ def join(a: Optional[Shape], b: Optional[Shape]) -> Optional[Shape]:
         partial = a.partial or b.partial or set(a.keys) != set(b.keys)
         return SFun(keys, join(a.val, b.val), partial)
     if isinstance(a, SSeq):
-        return SSeq(join(a.elem, b.elem), min(max(a.cap, b.cap),
-                                              SEQ_CAP_LIMIT))
+        return SSeq(join(a.elem, b.elem), max(a.cap, b.cap))
     raise ShapeError(f"cannot join {a} and {b}")
 
 
@@ -210,8 +246,10 @@ def _empty_as(like: Shape) -> Shape:
 def _merge_alt(alts: List[Shape], x: Shape) -> List[Shape]:
     out = []
     merged = False
+    seqs = (SSeq, STup)
     for alt in alts:
-        if type(alt) is type(x):
+        if type(alt) is type(x) or (isinstance(alt, seqs)
+                                    and isinstance(x, seqs)):
             out.append(join(alt, x))
             merged = True
         else:
@@ -247,10 +285,9 @@ def shape_of_value(v) -> Shape:
             return SRec(tuple(
                 (k, shape_of_value(x), False) for k, x in v
             ))
-        elem = None
-        for x in v:
-            elem = join(elem, shape_of_value(x))
-        return SSeq(elem, len(v))
+        if v:
+            return STup(tuple(shape_of_value(x) for x in v))
+        return SSeq(None, 0)
     raise ShapeError(f"cannot shape value {v!r}")
 
 
@@ -259,6 +296,9 @@ def shape_of_value(v) -> Shape:
 # ---------------------------------------------------------------------------
 
 ENUM_LIMIT = 1 << 21
+# a binder over a constant set of at most this many integers is bound
+# one value at a time in the abstract pass (ShapeInference._const)
+CONST_BINDER_LIMIT = 64
 
 
 def universe(shape: Optional[Shape], limit: int = ENUM_LIMIT) -> List:
@@ -318,6 +358,15 @@ def universe(shape: Optional[Shape], limit: int = ENUM_LIMIT) -> List:
             layer = [t + (e,) for t in layer for e in eu]
             out.extend(layer)
         return out
+    if isinstance(shape, STup):
+        per_item = []
+        total = 1
+        for x in shape.items:
+            per_item.append(universe(x, limit))
+            total *= max(len(per_item[-1]), 1)
+            if total > limit:
+                raise ShapeError("tuple universe too large")
+        return list(_product(*per_item))
     if isinstance(shape, SFun):
         per_key = []
         total = 1
@@ -356,6 +405,9 @@ def enumerable(shape: Optional[Shape], limit: int = ENUM_LIMIT) -> bool:
 # ---------------------------------------------------------------------------
 
 
+_NOVAL = object()  # "no host value": _const, constraint_bounds
+
+
 class ShapeInference:
     """Infers per-variable shapes from Init + all primed updates."""
 
@@ -369,6 +421,11 @@ class ShapeInference:
     # side of a leaf they leave open is capped (cap_open_sides)
     kept_bounds: Dict[str, list] = {}
     open_side_factor: int = 8  # OPEN_SIDE_FACTOR, below
+    # the capacities the spec declares for its sequences (infer_shapes'
+    # `seq_caps`): {var: [(path, cap)]}, and the least capacity a rung
+    # has raised every sequence to (0: none taken)
+    seq_caps: Dict[str, list] = {}
+    seq_cap_floor: int = 0
 
     def __init__(self, ev: Evaluator, variables: Tuple[str, ...],
                  init_ast, next_ast):
@@ -408,6 +465,10 @@ class ShapeInference:
         for it in range(max_iters):
             before = dict(self.var_shapes)
             self._pass_next()
+            for v in self.variables:
+                self.var_shapes[v] = cap_sequences(
+                    self.var_shapes[v], self.seq_caps.get(v, ()),
+                    self.seq_cap_floor)
             if it >= 2:
                 # widen growing int ranges up a threshold ladder so
                 # counter-style specs (x' = x + 1 under a guard the
@@ -439,6 +500,44 @@ class ShapeInference:
                for v, s in self.var_shapes.items()}
         self._walk_action(self.next_ast, dict(env))
 
+    # -- what is a host constant under the binders so far ------------------
+
+    def _const(self, ast, env):
+        """The value of `ast` where it reads nothing but constants and
+        binders whose value the walk knows (a binder over a constant
+        set of integers is walked one value at a time, as the lane
+        compiler binds it: the `p` of `Proc \\ {p}`, of `s = r`, of
+        `![p][q]`); _NOVAL where it reads anything else.  The binder's
+        SHAPE stays its whole domain (arithmetic on it is as wide as it
+        was): the value is kept beside it, under ("#", name)."""
+        cenv = {k[1]: v for k, v in env.items()
+                if isinstance(k, tuple) and k[0] == "#"}
+        cenv.update((k, v) for k, v in env.items()
+                    if isinstance(v, Definition))
+        try:
+            return self.ev.eval(ast, cenv)
+        except Exception:
+            return _NOVAL
+
+    @staticmethod
+    def _bind(env, name, shape, value=_NOVAL):
+        """`name` bound to `shape` in `env`, and to `value` where the
+        walk knows it (a name bound again loses the old value)."""
+        env[name] = shape
+        if value is _NOVAL:
+            env.pop(("#", name), None)
+        else:
+            env[("#", name)] = value
+
+    def _int_set(self, ast, env):
+        """The constant, non-empty, small set of integers `ast` is
+        under `env`, sorted; else None."""
+        dom = self._const(ast, env)
+        if isinstance(dom, frozenset) and 0 < len(dom) <= CONST_BINDER_LIMIT \
+                and all(is_int(x) for x in dom):
+            return sorted(dom)
+        return None
+
     # -- action walk: collect var' = rhs joins -----------------------------
 
     def _walk_action(self, ast, env):
@@ -449,24 +548,37 @@ class ShapeInference:
             return
         if op == "exists":
             _, names, dom_ast, body = ast
+            ints = self._int_set(dom_ast, env)
             dom_sh = self._abstract(dom_ast, env)
             elem = self._elem_shape(dom_sh)
+            if ints is not None:
+                inner = ("exists", names[1:], dom_ast, body) \
+                    if len(names) > 1 else body
+                for x in ints:
+                    env2 = dict(env)
+                    self._bind(env2, names[0], elem, x)
+                    self._walk_action(inner, env2)
+                return
             env2 = dict(env)
             for nm in names:
-                env2[nm] = elem
+                self._bind(env2, nm, elem)
             self._walk_action(body, env2)
             return
         if op == "if":
-            self._walk_action(ast[2], env)
-            self._walk_action(ast[3], env)
+            cond = self._const(ast[1], env)
+            if cond is not False:
+                self._walk_action(ast[2], env)
+            if cond is not True:
+                self._walk_action(ast[3], env)
             return
         if op == "let":
             env2 = dict(env)
             for name, params, body in ast[1]:
                 if params:
-                    env2[name] = Definition(name, params, body)
+                    self._bind(env2, name, Definition(name, params, body))
                 else:
-                    env2[name] = self._abstract(body, env2)
+                    self._bind(env2, name, self._abstract(body, env2),
+                               self._const(body, env2))
             self._walk_action(ast[2], env2)
             return
         if op in ("call", "name"):
@@ -480,7 +592,8 @@ class ShapeInference:
                 args = ast[2] if op == "call" else []
                 env2 = dict(env)
                 for p, a in zip(d.params, args):
-                    env2[p] = self._abstract(a, env)
+                    self._bind(env2, p, self._abstract(a, env),
+                               self._const(a, env))
                 self._walk_action(d.body, env2)
             return
         if op == "cmp" and ast[1] in ("=", r"\in") and ast[2][0] == "prime":
@@ -566,7 +679,11 @@ class ShapeInference:
             keys = self._domain_atoms(base)
             if keys is not None:
                 return SSet(SAtoms(frozenset(keys)))
-            return SSet(SInt(1, SEQ_CAP_LIMIT))
+            n = len(base.items) if isinstance(base, STup) else \
+                base.cap if isinstance(base, SSeq) else SEQ_CAP_GUESS
+            return SSet(SInt(1, max(n, 1)))
+        if op == "subset":
+            return SSet(self._abstract(ast[1], env))
         if op in ("not", "and", "or", "implies", "forall", "exists"):
             return SBool()
         if op == "cmp":
@@ -574,6 +691,9 @@ class ShapeInference:
         if op == "binop":
             return self._binop_shape(ast, env)
         if op == "if":
+            cond = self._const(ast[1], env)
+            if isinstance(cond, bool):
+                return self._abstract(ast[2 if cond else 3], env)
             return join(self._abstract(ast[2], env),
                         self._abstract(ast[3], env))
         if op == "case":
@@ -587,9 +707,10 @@ class ShapeInference:
             env2 = dict(env)
             for name, params, body in ast[1]:
                 if params:
-                    env2[name] = Definition(name, params, body)
+                    self._bind(env2, name, Definition(name, params, body))
                 else:
-                    env2[name] = self._abstract(body, env2)
+                    self._bind(env2, name, self._abstract(body, env2),
+                               self._const(body, env2))
             return self._abstract(ast[2], env2)
         if op == "choose":
             _, var, dom_ast, _ = ast
@@ -604,14 +725,28 @@ class ShapeInference:
             _, expr, var, dom_ast = ast
             dom = self._abstract(dom_ast, env)
             env2 = dict(env)
-            env2[var] = self._elem_shape(dom)
+            self._bind(env2, var, self._elem_shape(dom))
             return SSet(self._abstract(expr, env2))
         if op == "fnlit":
             _, var, dom_ast, body = ast
+            ints = self._int_set(dom_ast, env)
+            if ints is not None and ints == list(
+                    range(ints[0], ints[-1] + 1)):
+                # over an integer interval: key by key, each body under
+                # its own key
+                vals = []
+                for k in ints:
+                    env2 = dict(env)
+                    self._bind(env2, var, SInt(k, k), k)
+                    vals.append(self._abstract(body, env2))
+                if ints[0] == 1:
+                    return STup(tuple(vals))
+                return SRec(tuple(
+                    (k, v, False) for k, v in zip(ints, vals)))
             dom = self._abstract(dom_ast, env)
             elem = self._elem_shape(dom)
             env2 = dict(env)
-            env2[var] = elem
+            self._bind(env2, var, elem)
             val = self._abstract(body, env2)
             keys = self._atoms_of(elem)
             if keys is None:
@@ -684,7 +819,14 @@ class ShapeInference:
         shapes = base.alts if isinstance(base, SUnion) else (base,)
         out = None
         for sh in shapes:
-            if isinstance(sh, SRec):
+            if isinstance(sh, STup):
+                k = self._const(arg_ast, env)
+                if is_int(k) and 1 <= k <= len(sh.items):
+                    out = join(out, sh.items[k - 1])
+                elif k is _NOVAL:
+                    for x in sh.items:
+                        out = join(out, x)
+            elif isinstance(sh, SRec):
                 if arg_ast[0] == "str":
                     f = sh.field(arg_ast[1])
                     if f is not None:
@@ -716,6 +858,8 @@ class ShapeInference:
                 return SSet(join(ea, eb))
             return SSet(ea)
         if sym in ("+", "-", "*"):
+            if a is None or b is None:
+                return None  # an operand no pass has given a value yet
             if isinstance(a, SInt) and isinstance(b, SInt):
                 if sym == "+":
                     return SInt(a.lo + b.lo, a.hi + b.hi)
@@ -730,10 +874,10 @@ class ShapeInference:
                 return SSet(SInt(a.lo, b.hi))
             raise ShapeError(".. over non-ints")
         if sym == r"\o":
-            sa = a if isinstance(a, SSeq) else SSeq(None, 0)
-            sb = b if isinstance(b, SSeq) else SSeq(None, 0)
-            return SSeq(join(sa.elem, sb.elem),
-                        min(sa.cap + sb.cap, SEQ_CAP_LIMIT))
+            sa, sb = (_as_seq(x) if isinstance(x, STup) else x
+                      if isinstance(x, SSeq) else SSeq(None, 0)
+                      for x in (a, b))
+            return SSeq(join(sa.elem, sb.elem), sa.cap + sb.cap)
         if sym == ":>":
             keys = self._atoms_of(a)
             if keys is None:
@@ -859,6 +1003,24 @@ class ShapeInference:
             if not seen:
                 fields.append((idx_ast[1], new, True))
             return SRec(tuple(sorted(fields)))
+        if isinstance(sh, STup):
+            k = self._const(idx_ast, env)
+            items = []
+            for i, old in enumerate(sh.items, start=1):
+                if k is not _NOVAL and k != i:
+                    items.append(old)
+                    continue
+                if len(path_asts) > 1:
+                    new = self._except_one(old, path_asts[1:], val_ast,
+                                           env)
+                else:
+                    env2 = dict(env)
+                    env2["@"] = old
+                    new = self._abstract(val_ast, env2)
+                # the one component a constant index names is replaced;
+                # any component an unknown index may name is joined
+                items.append(new if k is not _NOVAL else join(old, new))
+            return STup(tuple(items))
         if isinstance(sh, SFun):
             old = sh.val
             if len(path_asts) > 1:
@@ -887,7 +1049,8 @@ class ShapeInference:
         if isinstance(d, Definition):
             env2 = dict(env)
             for p, a in zip(d.params, args):
-                env2[p] = self._abstract(a, env)
+                self._bind(env2, p, self._abstract(a, env),
+                           self._const(a, env))
             return self._abstract(d.body, env2)
         if name in ("FoldFunctionOnSet", "FoldFunction"):
             # + or * over a function of integers (eval.fold_args): the
@@ -903,22 +1066,23 @@ class ShapeInference:
             return SInt(-(1 << 30), 1 << 30)
         if name in ("Cardinality", "Len"):
             return SInt(0, 64)
-        if name == "Head":
+        if name in ("Head", "Tail", "Append"):
             sh = self._abstract(args[0], env)
+            if isinstance(sh, STup):
+                sh = _as_seq(sh)
+        if name == "Head":
             if isinstance(sh, SSeq):
                 return sh.elem
             return None
         if name == "Tail":
-            sh = self._abstract(args[0], env)
             if isinstance(sh, SSeq):
                 return SSeq(sh.elem, max(sh.cap - 1, 0))
             return sh
         if name == "Append":
-            sh = self._abstract(args[0], env)
             el = self._abstract(args[1], env)
             cap = sh.cap if isinstance(sh, SSeq) else 0
             elem = sh.elem if isinstance(sh, SSeq) else None
-            return SSeq(join(elem, el), min(cap + 1, SEQ_CAP_LIMIT))
+            return SSeq(join(elem, el), cap + 1)
         if name == "Assert":
             return SBool()
         raise ShapeError(f"cannot abstract call {name}")
@@ -955,6 +1119,10 @@ def _widen(old: Optional[Shape], new: Optional[Shape]) -> Optional[Shape]:
         return SSet(_widen(old.elem, new.elem))
     if isinstance(new, SSeq) and isinstance(old, SSeq):
         return SSeq(_widen(old.elem, new.elem), new.cap)
+    if isinstance(new, STup) and isinstance(old, STup) \
+            and len(new.items) == len(old.items):
+        return STup(tuple(_widen(o, n)
+                          for o, n in zip(old.items, new.items)))
     if isinstance(new, SUnion) and isinstance(old, SUnion):
         olds = {type(a): a for a in old.alts}
         return SUnion(tuple(
@@ -1006,9 +1174,17 @@ def typeok_hints(ev: Evaluator, invariants: Dict[str, tuple],
         if v is BUILTIN_SETS["Nat"]:
             return SInt(-1, UNBOUNDED)
         if isinstance(v, LazySet):
+            if v.kind == "seq":
+                return SSeq(set_shape(v.parts), 0)
+            if v.kind == "subset":
+                return SSet(set_shape(v.parts))
+            if v.kind == "diff":
+                return set_shape(v.parts[0])
             fields = [(f, set_shape(d)) for f, d in v.fields()]
             if not all(isinstance(f, str) or is_int(f) for f, _ in fields):
                 return None
+            if [f for f, _ in fields] == list(range(1, len(fields) + 1)):
+                return STup(tuple(sh for _, sh in fields))
             return SRec(tuple(
                 (f, sh, False) for f, sh in fields if sh is not None))
         return None
@@ -1041,9 +1217,10 @@ def typeok_hints(ev: Evaluator, invariants: Dict[str, tuple],
                         and keys and (
                             all(isinstance(k, str) for k in keys)
                             or all(is_int(k) for k in keys)):
-                    hints[var] = SRec(tuple(
-                        (k, val_sh, False) for k in sorted(keys)
-                    ))
+                    hints[var] = STup((val_sh,) * len(keys)) \
+                        if sorted(keys) == list(range(1, len(keys) + 1)) \
+                        else SRec(tuple(
+                            (k, val_sh, False) for k in sorted(keys)))
             else:
                 sh = dom_shape(rhs)
                 if sh is not None:
@@ -1078,7 +1255,7 @@ class LeafBound(NamedTuple):
 
 
 def constraint_bounds(ev: Evaluator, constraints: Dict[str, tuple],
-                      variables) -> List[LeafBound]:
+                      variables, of_len: bool = False) -> List[LeafBound]:
     """What the constraints' conjunction says, leaf by leaf, about
     integer leaves: conjuncts `leaf <= c`, `<`, `>=`, `>`, `=` with a
     constant side, under `\\A x \\in S` over constant sets, where `leaf`
@@ -1087,7 +1264,10 @@ def constraint_bounds(ev: Evaluator, constraints: Dict[str, tuple],
     disjunction, a sum) bounds no leaf by itself and is left to the
     predicate.  Every KEPT state lies inside these bounds: the shape
     inference reads successors' shapes off kept states alone
-    (ShapeInference.run), and the preflight report shows them."""
+    (ShapeInference.run), and the preflight report shows them.
+    `of_len`: the same walk for what the predicates say about the
+    LENGTH of a sequence leaf (`Len(network[p][q]) <= 3`) and nothing
+    else: seq_cap_bounds."""
     found: Dict[tuple, list] = {}
 
     def const(ast, env):
@@ -1098,6 +1278,11 @@ def constraint_bounds(ev: Evaluator, constraints: Dict[str, tuple],
 
     def leaf_of(ast, env):
         path = []
+        if (ast[0] == "call" and ast[1] == "Len"
+                and len(ast[2]) == 1) != of_len:
+            return None
+        if of_len:
+            ast = ast[2][0]
         while ast[0] == "apply":
             k = const(ast[2], env)
             if not (isinstance(k, str) or is_int(k)):
@@ -1154,7 +1339,88 @@ def constraint_bounds(ev: Evaluator, constraints: Dict[str, tuple],
                 found.items(), key=lambda kv: (kv[0][0], repr(kv[0][1])))]
 
 
-_NOVAL = object()
+def _over_components(sh: Optional[Shape], fn, path):
+    """`sh` rebuilt with `fn(component, its path)` in place of every
+    component of a tuple (the keys 1..n) or a record (its fields); `sh`
+    itself where it is neither."""
+    if isinstance(sh, SRec):
+        return SRec(tuple((f, fn(s, path + (f,)), o)
+                          for f, s, o in sh.fields))
+    if isinstance(sh, STup):
+        return STup(tuple(fn(s, path + (k,))
+                          for k, s in enumerate(sh.items, start=1)))
+    return sh
+
+
+def seq_cap_bounds(ev: Evaluator, predicates: Dict[str, tuple],
+                   variables) -> List[LeafBound]:
+    """The capacities the spec DECLARES for its sequences: conjuncts
+    `Len(leaf) <= c` (`<`, `=`) of the invariants and the cfg's
+    CONSTRAINT, under `\\A` over constant sets, `leaf` a variable or a
+    path of constant keys into one (`BoundedNetwork == \\A p, q \\in
+    Proc : Len(network[p][q]) <= 3`).  An invariant is a claim, not a
+    fact: the capacity read off it is guarded by the Append trap like a
+    guessed one, and a run that trips it starts again a rung higher -
+    where the invariant then fails as the invariant it is."""
+    return [b for b in constraint_bounds(ev, predicates, variables,
+                                         of_len=True)
+            if b.hi is not None and b.hi >= 0]
+
+
+def caps_by_var(seq_caps) -> Dict[str, list]:
+    """seq_cap_bounds' LeafBounds as cap_sequences takes them: {var:
+    [(path, cap)]}."""
+    out: Dict[str, list] = {}
+    for b in seq_caps or ():
+        out.setdefault(b.var, []).append((b.path, b.hi))
+    return out
+
+
+def cap_sequences(sh: Optional[Shape], caps, floor: int = 0, path=()):
+    """`sh` with every growing sequence held to its capacity: what the
+    spec declares at its path (`caps`: (path, cap) pairs), else the
+    first guess, and at least `floor` (the rung).  A tuple's components
+    are its paths' next keys, a record's its fields."""
+    if isinstance(sh, SSeq):
+        declared = [c for p, c in caps if p == path]
+        cap = max(min(declared) if declared else SEQ_CAP_GUESS, floor)
+        return sh if sh.cap <= cap else SSeq(sh.elem, cap)
+    if isinstance(sh, SUnion):
+        return SUnion(tuple(cap_sequences(a, caps, floor, path)
+                            for a in sh.alts))
+    return _over_components(
+        sh, lambda s, p: cap_sequences(s, caps, floor, p), path)
+
+
+def seq_summary(var_shapes: Dict[str, Shape], caps: List[LeafBound]):
+    """(slots, from, largest) of the sequences of a model's layout: the
+    static slots of all of them; where their capacities came from -
+    "declared" where the spec declares one for every sequence that can
+    hold an element, "guess" where some capacity is the first guess or a
+    rung's, None where the model has no such sequence; and the largest
+    capacity (what a rung widens from: struct.cache.widen_seq_caps)."""
+    declared = {(b.var, b.path): b.hi for b in caps}
+    slots, guessed = [], []
+
+    def visit(var, sh, path):
+        if isinstance(sh, SSeq):
+            if sh.cap:
+                slots.append(sh.cap)
+                guessed.append(declared.get((var, path), -1) < sh.cap)
+        elif isinstance(sh, STup):
+            for k, x in enumerate(sh.items, start=1):
+                visit(var, x, path + (k,))
+        elif isinstance(sh, SRec):
+            for f, x, _ in sh.fields:
+                visit(var, x, path + (f,))
+        elif isinstance(sh, SUnion):
+            for a in sh.alts:
+                visit(var, a, path)
+
+    for v, sh in var_shapes.items():
+        visit(v, sh, ())
+    return sum(slots), (None if not slots else "guess" if any(guessed)
+                        else "declared"), max(slots, default=0)
 
 
 def apply_leaf_bounds(sh: Optional[Shape], bounds, path=()):
@@ -1169,11 +1435,8 @@ def apply_leaf_bounds(sh: Optional[Shape], bounds, path=()):
         # a leaf the constraint empties here keeps one value: the
         # abstract pass has then not reached a kept state yet
         return SInt(min(lo, hi), hi) if lo <= hi else SInt(hi, hi)
-    if isinstance(sh, SRec):
-        return SRec(tuple(
-            (f, apply_leaf_bounds(s, bounds, path + (f,)), o)
-            for f, s, o in sh.fields))
-    return sh
+    return _over_components(
+        sh, lambda s, p: apply_leaf_bounds(s, bounds, p), path)
 
 
 OPEN_SIDE_FACTOR = 8
@@ -1204,11 +1467,8 @@ def cap_open_sides(sh: Optional[Shape], bounds, factor: int, path=()):
                            _INT_THRESHOLDS[-1])
                 hi = min(hi, max(cap, lo))
         return SInt(lo, hi)
-    if isinstance(sh, SRec):
-        return SRec(tuple(
-            (f, cap_open_sides(s, bounds, factor, path + (f,)), o)
-            for f, s, o in sh.fields))
-    return sh
+    return _over_components(
+        sh, lambda s, p: cap_open_sides(s, bounds, factor, p), path)
 
 
 def _slack(sh: Optional[Shape]) -> Optional[Shape]:
@@ -1245,6 +1505,10 @@ def _clamp(sh: Optional[Shape], hint: Optional[Shape]) -> Optional[Shape]:
             return sh if n is not None and n <= len(hint.elem.values) \
                 else hint
         return SSet(_clamp(sh.elem, hint.elem))
+    if isinstance(sh, STup) and isinstance(hint, STup) \
+            and len(sh.items) == len(hint.items):
+        return STup(tuple(_clamp(s, h)
+                          for s, h in zip(sh.items, hint.items)))
     if isinstance(sh, SSeq):
         elem_hint = hint.elem if isinstance(hint, SSeq) else (
             hint if isinstance(hint, SInt) else None)
@@ -1303,6 +1567,9 @@ def shape_leq(a: Optional[Shape], b: Optional[Shape]) -> bool:
         return shape_leq(a.elem, b.elem)
     if isinstance(a, SSeq):
         return a.cap <= b.cap and shape_leq(a.elem, b.elem)
+    if isinstance(a, STup):
+        return len(a.items) == len(b.items) and all(
+            shape_leq(x, y) for x, y in zip(a.items, b.items))
     if isinstance(a, SFun):
         if not set(a.keys) <= set(b.keys):
             return False
@@ -1316,12 +1583,18 @@ def infer_shapes(ev: Evaluator, variables, init_ast, next_ast,
                  hints: Optional[Dict[str, Shape]] = None,
                  const_hints: Optional[Dict[str, Shape]] = None,
                  kept: Optional[List[LeafBound]] = None,
-                 open_side_factor: int = OPEN_SIDE_FACTOR
-                 ) -> Dict[str, Shape]:
+                 open_side_factor: int = OPEN_SIDE_FACTOR,
+                 seq_caps: Optional[List[LeafBound]] = None,
+                 seq_cap_floor: int = 0) -> Dict[str, Shape]:
     """`kept` (constraint_bounds of a cfg's CONSTRAINT): successors are
     only ever taken of states inside these leaf bounds, and a leaf they
-    bound on one side is capped on the other (cap_open_sides)."""
+    bound on one side is capped on the other (cap_open_sides).
+    `seq_caps` (seq_cap_bounds): the capacities the spec declares for
+    its sequences; `seq_cap_floor`: the least capacity of every
+    sequence once a rung was taken (cap_sequences)."""
     inf = ShapeInference(ev, variables, init_ast, next_ast)
+    inf.seq_cap_floor = seq_cap_floor
+    inf.seq_caps = caps_by_var(seq_caps)
     inf.hints = hints or {}
     inf.open_side_factor = open_side_factor
     inf.kept_bounds = {}

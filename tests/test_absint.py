@@ -420,9 +420,11 @@ def test_lintgate_specs_tree_clean():
     rc = run_gate("specs", out=out)
     text = out.getvalue()
     assert rc == 0, text
-    assert "lint gate: 11 spec(s)" in text
+    assert "lint gate: 12 spec(s)" in text
     # a model bounded by its cfg's CONSTRAINT passes with no finding
     assert "EWD998.toolbox/Model_1/MC.cfg: ok" in text
+    # so does one whose state is FIFO channels of records (ISSUE 45)
+    assert "LamportMutex.toolbox/Model_1/MC.cfg: ok" in text
     assert "0 new error(s)" in text
     # the hand-kernel model boundary ships without KubeAPI.tla: the
     # struct-frontend gate says so instead of failing or hiding it

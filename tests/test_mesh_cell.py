@@ -184,8 +184,12 @@ def _raw_fps(table) -> set:
 def mesh_run():
     """The FF corner through the mesh engine's segment program on four
     devices, to the end: (final carry, bodies a segment could hold)."""
-    init_fn, seg_fn = make_sharded_engine(FF, fp_mesh(4), segment=16,
-                                          **GEOM)
+    # 96 rows an insert segment, not the geometry's 128: under the
+    # fingerprints since PR 45 no owner of this rung receives more than
+    # 128 rows a body (one did before), and some bodies are to take two
+    with segments_of(96):
+        init_fn, seg_fn = make_sharded_engine(FF, fp_mesh(4), segment=16,
+                                              **GEOM)
     carry, segments = init_fn(), 0
     while bool(np.asarray(carry.cont).any()):
         carry = jax.block_until_ready(seg_fn(carry))
@@ -242,13 +246,16 @@ def carry_digest(c, qcap: int, table: bool = True) -> str:
 
 # sha256 of the same run's final carry on the parent commit (258ba50,
 # before the counters, the scopes and the `while` segment), by
-# carry_digest above: fused loop and 16-step segments alike
+# carry_digest above: fused loop and 16-step segments alike.  Pinned
+# again in PR 45, whose dense polynomial table changes every fingerprint
+# (the table's words, the owner of a state, the queues' order): the
+# values are PR 44's engine with the new table alone
 PARENT_DIGEST = (
-    "4142a18eacd18e5acd01b68649707a98b517141b0dbb981f5ce3758e37573e7a")
+    "835822bce992e15ac00906e3d1a9232c353e0a31ae473bc55bf42afd0b51d424")
 # the same less the table's words (9bca0db, PR 27's engine: one insert
 # over all D x B received lanes); sorted and wide paths alike
 PARENT_DIGEST_LESS_TABLE = (
-    "6e6ca3fbac083d5103dc07096f006006d08356f272c91a0a7364b3bbc6256fd9")
+    "1131d3733bc69dc30f3fdb8609c027d9df54ce4a0c312eb62c3f912dcf07ebde")
 # the owner-side deferred invariants (a 16384-wide chunk's auto),
 # forced at chunk 128
 WIDE = dict(deferred=True)

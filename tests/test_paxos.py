@@ -309,7 +309,8 @@ def test_a_state_with_more_live_lanes_than_slots_widens_the_step(
         "CONSTANT N = 80\nSPECIFICATION Spec\nINVARIANT TypeOK\n")
     cfg = str(tmp_path / "MC.cfg")
     wide = load(cfg)
-    cache._SLOT_FLOOR.pop(cache.model_key(wide), None)  # the other route's
+    cache._FLOORS.pop((cache.model_key(wide), "step_slots"),
+                      None)  # the other route's
     want = bfs(wide.system, wide.invariants, check_deadlock=False)
     assert (want.generated, want.distinct, want.depth) == (82, 82, 3)
     out = io.StringIO()

@@ -303,7 +303,8 @@ def test_pooled_job_leaves_every_span(server, sweep_jobs):
     assert len({r.thread for r in sched}) == 1
     assert sorted(r.name for r in sched) == sorted([
         "sched.run", "sched.jobdir", "sched.load", "build.struct.load",
-        "build.struct.fairness", "sched.cache_lookup", "pool.get", "pool.carry", "pool.run",
+        "build.struct.fairness", "build.struct.seqcap", "sched.cache_lookup",
+        "pool.get", "pool.carry", "pool.run",
         "pool.readback", "sched.journal", "sched.finish"])
     assert len(sched) <= 16  # the budget
     by_name = {r.name: r for r in sched}
@@ -318,8 +319,8 @@ def test_pooled_job_leaves_every_span(server, sweep_jobs):
     assert kinds.count("spans") == 1 and kinds[-2:] == ["spans", "final"]
     assert [row[0] for row in events[-2]["rows"]] == [
         r.name for r in sched if r.t1 <= events[-2]["t"]] == [
-        "sched.jobdir", "build.struct.fairness", "build.struct.load",
-        "sched.load", "sched.cache_lookup", "pool.get", "pool.carry", "pool.run",
+        "sched.jobdir", "build.struct.fairness", "build.struct.seqcap",
+        "build.struct.load", "sched.load", "sched.cache_lookup", "pool.get", "pool.carry", "pool.run",
         "pool.readback"]
     # the journal reports what it cost itself on its closing span
     closing = by_name["sched.journal"].attrs

@@ -193,7 +193,12 @@ U = np.arange
     # near misses
     ((U(125) // 5) % 5 + 1, ("gather",)),
     (np.where(U(27) == 26, 0, (U(27) // 3) % 3), ("gather",)),
-    ((U(8) // 2) % 2 == 0, ("gather",)),
+    # a predicate over at most 32 codes that is no digit: a bit test
+    # on a constant (ISSUE 45: `m \in Message` for a channel's slot)
+    ((U(8) // 2) % 2 == 0, ("bits", 0b00110011)),
+    (np.isin(U(24), [0, 1, 5, 8, 11, 23]),
+     ("bits", sum(1 << i for i in (0, 1, 5, 8, 11, 23)))),
+    (np.isin(U(33), [0, 1, 5, 32]), ("gather",)),
     (np.asarray([0, -1, 0, -1]), ("gather",)),
     (np.asarray([0, 2, 4, 6]), ("gather",)),
     (np.asarray([0, 0, 0, 5]), ("gather",)),
@@ -211,7 +216,9 @@ def test_forms_of_synthetic_tables_and_their_reads(table, form):
     assert got.dtype == want.dtype
     assert np.array_equal(got, want)
     counts = compiler.lookup_counts()
-    assert counts[form[0]] == 1 and sum(counts.values()) == 1
+    # a bit test is arithmetic on the code, and counted as such
+    tallied = "arith" if form[0] == "bits" else form[0]
+    assert counts[tallied] == 1 and sum(counts.values()) == 1
     # a second read of the same value is the memo's: not counted again
     assert compiler.look_up(table, LE(codes, None)) is got
     assert compiler.lookup_counts() == counts
