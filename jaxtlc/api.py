@@ -1967,29 +1967,49 @@ def _struct_dead_sites(args, spec, sm, bounds, r):
 
 
 def _struct_preflight(args, spec, sm, deep):
+    """The struct path's preflight report.  The lite one is a pure
+    function of the model and the request integers `preflight_struct`
+    reads, so it is kept under them (struct.cache's `preflight` memo)
+    and `_preflight` journals and renders the kept report into THIS
+    check's journal; `check.preflight` says `memo` = hit | miss.  What
+    is handed out is a copy (its `wall_s` the look-up's own): nothing
+    writes to the kept one.  The deep audit (-analyze) traces the
+    engine and always builds; a report that raised is not kept."""
     from .analysis.preflight import preflight_struct
+    from .struct import cache
 
+    key = None
+    if not deep and sm.source_digest:
+        t0 = time.time()
+        key = (cache.model_key(sm), args.fpcap, args.chunk, args.qcap,
+               spec.check_deadlock, bool(args.narrow), _symmetry(args))
+        kept = cache.spec_kept("preflight", key)
+        spans.annotate(memo="miss" if kept is None else "hit")
+        if kept is not None:
+            return dataclasses.replace(
+                kept, wall_s=time.time() - t0,
+                findings=list(kept.findings),
+                engine_lines=list(kept.engine_lines),
+                bound_lines=list(kept.bound_lines),
+                constraint_lines=list(kept.constraint_lines))
     backend = None
     if deep:
         # the same memoized backend the run is about to use: the deep
         # audit adds a jaxpr trace, never a second lane compile
-        from .struct.cache import get_backend
-
-        backend = get_backend(sm, spec.check_deadlock)
+        backend = cache.get_backend(sm, spec.check_deadlock)
     # the certified bound report rides along in deep mode (-analyze)
     # and whenever -narrow is in play (the user should see what the
     # narrowed codec is built from / why narrowing was refused)
-    bounds = None
-    if deep or args.narrow:
-        from .struct.cache import get_bounds
-
-        bounds = get_bounds(sm)
-    return preflight_struct(
+    bounds = cache.get_bounds(sm) if deep or args.narrow else None
+    report = preflight_struct(
         sm, fp_capacity=args.fpcap, chunk=args.chunk,
         queue_capacity=args.qcap, check_deadlock=spec.check_deadlock,
         deep=deep, backend=backend, bounds=bounds,
         narrow=args.narrow, symmetry=_symmetry(args),
     )
+    if key is not None:
+        cache.spec_keep("preflight", key, report)
+    return report
 
 
 class _InterpKit:

@@ -101,6 +101,16 @@ class span:
         return (self.t1 if self.t1 is not None else time.time()) - self.t0
 
 
+def annotate(**attrs) -> None:
+    """Add `attrs` to the innermost span open in this context; with none
+    open, nothing.  For what only a callee knows about its caller's
+    span: `struct.loader.load` tells the span around it (`sched.load`,
+    `check.resolve`) whether the model was kept, `memo` = hit | miss."""
+    outer = _current.get()
+    if outer is not None:
+        outer.attrs.update(attrs)
+
+
 class job:
     """Every span opened in this context belongs to job `job_id`; the
     context keeps that job's closed rows, so writing them into the

@@ -23,6 +23,7 @@ it.
 from __future__ import annotations
 
 import os
+import threading
 from collections import OrderedDict
 from typing import Tuple
 
@@ -85,9 +86,11 @@ _ENGINE_MEMO = _LRUMemo(_env_cap("JAXTLC_ENGINE_MEMO_CAP", 32))
 def stats() -> dict:
     """Hit/miss/size/eviction counters for the memos (cumulative per
     process; the serve /pool endpoint republishes them)."""
+    with _SPEC_LOCK:
+        spec = {name: memo.stats() for name, memo in _SPEC_MEMOS.items()}
     return {"backend": _BACKEND_MEMO.stats(),
             "engine": _ENGINE_MEMO.stats(),
-            "bounds": _BOUNDS_MEMO.stats()}
+            "bounds": _BOUNDS_MEMO.stats(), **spec}
 
 
 def set_caps(backend: int = None, engine: int = None) -> None:
@@ -135,6 +138,39 @@ def get_bounds(model):
         hit = analyze_bounds(model)
         _BOUNDS_MEMO.put(key, hit)
     return hit
+
+
+# What is a pure function of a spec's text, in front of `model_key`
+# (ISSUE 46): a check of a text this process has seen goes from the
+# bytes it reads to its kept backend and engine.  Keys are content,
+# never a path or an mtime (the served path writes every job's spec
+# into a fresh job directory); no verdict, count or journal row is ever
+# kept.  Fixed caps; `api.run_check` is a public entry, so these three
+# are read and written under one lock (`spec_kept` / `spec_keep`).
+#   text       struct.loader: a parsed cfg or module, by (parser, sha256
+#              of its own text) - the closure is only known by parsing
+#   model      struct.loader: (the StructModel, the child spans its load
+#              recorded), by (source_digest, layout): every text `load`
+#              reads plus the overrides
+#   preflight  api._struct_preflight: the lite AnalysisReport, by
+#              (model_key, every request integer preflight_struct reads)
+_SPEC_MEMOS = {"text": _LRUMemo(256), "model": _LRUMemo(64),
+               "preflight": _LRUMemo(128)}
+_SPEC_LOCK = threading.Lock()
+
+
+def spec_kept(memo: str, key):
+    """The entry of the `text` / `model` / `preflight` memo under `key`,
+    or None (counted as that memo's hit or miss)."""
+    with _SPEC_LOCK:
+        return _SPEC_MEMOS[memo].get(key)
+
+
+def spec_keep(memo: str, key, value) -> None:
+    """Keep `value` under `key`.  The builder of a value that raised
+    never gets here: an error is built again by the next call."""
+    with _SPEC_LOCK:
+        _SPEC_MEMOS[memo].put(key, value)
 
 
 def _bounds_key(bounds) -> str:
@@ -438,3 +474,6 @@ def clear() -> None:
     _ENGINE_MEMO.clear()
     _BOUNDS_MEMO.clear()
     _FLOORS.clear()
+    with _SPEC_LOCK:
+        for memo in _SPEC_MEMOS.values():
+            memo.clear()

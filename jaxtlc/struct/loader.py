@@ -21,8 +21,10 @@ import math
 import os
 from typing import Dict, NamedTuple, Optional, Tuple
 
-from ..frontend.mc_cfg import CfgError, parse_cfg_file
+from ..frontend.mc_cfg import CfgError, parse_cfg
+from ..obs.spans import annotate, span
 from ..spec.labels import DEFAULT_INIT
+from . import cache
 from .actions import ActionSystem
 from .eval import Evaluator
 from .parser import Definition, Module, StructParseError, parse_module
@@ -262,6 +264,20 @@ def declared_fairness(conjuncts, next_name: str, subscript: str,
     return tuple(out)
 
 
+def _parsed(kind: str, src: str):
+    """The text of a `cfg` or a `module`, parsed; kept by the text's own
+    digest (struct.cache's `text` memo): a text this process has parsed
+    is not parsed again, whatever file it is read from.  A text that
+    does not parse raises again every time.  What is kept is shared:
+    nothing writes to a parsed cfg or Module."""
+    key = (kind, hashlib.sha256(src.encode()).hexdigest())
+    hit = cache.spec_kept("text", key)
+    if hit is None:
+        hit = parse_cfg(src) if kind == "cfg" else parse_module(src)
+        cache.spec_keep("text", key, hit)
+    return hit
+
+
 def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
     """Parse `path` and fold in its non-builtin EXTENDS (depth-first,
     extended defs first so the extender can override).  `texts`, when
@@ -270,7 +286,7 @@ def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
         src = f.read()
     if texts is not None:
         texts.append((path, src))
-    root = parse_module(src)
+    root = _parsed("module", src)
     defs: Dict[str, Definition] = {}
     def_order = []
     variables = []
@@ -315,28 +331,47 @@ def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
 
 def load(cfg_path: str,
          const_overrides: Optional[Dict[str, object]] = None) -> StructModel:
-    """Parse the model's cfg and module closure and resolve its
-    constants (host span `build.struct.load`: a check pays it on every
-    call, before its backend is looked up)."""
-    from ..obs.spans import span
+    """The model of a cfg and its module closure, its constants
+    resolved.  A pure function of the texts it reads and the overrides,
+    so it is computed once a process (struct.cache's `text` and `model`
+    memos): the first load of a text parses the cfg and every module,
+    evaluates the constants and resolves SYMMETRY, CONSTRAINT, the
+    fairness and the sequence capacities; a later load, from whatever
+    directory, reads and hashes the same files in the same search order
+    and returns the model kept under their digest - no parser, no
+    evaluator.  A changed byte anywhere, or another override, is another
+    key; an error is raised again by every call.  The kept model is
+    shared: a check reads it and writes nothing to it (what a check
+    learns lives in struct.cache under `model_key`).
 
-    with span("build.struct.load"):
-        return _load(cfg_path, const_overrides)
+    Host span `build.struct.load`, `memo` = hit | miss, which the span
+    open around the call (`sched.load`, `check.resolve`) is told too;
+    on a hit the children the model's load recorded
+    (`build.struct.symmetry` / `.constraint` / `.fairness` / `.seqcap`)
+    are opened again around no work, `memo` = hit."""
+    with span("build.struct.load") as sp:
+        model, memo = _load(cfg_path, const_overrides)
+        sp.attrs["memo"] = memo
+    annotate(memo=memo)
+    return model
 
 
-def _load(cfg_path: str,
-          const_overrides: Optional[Dict[str, object]]) -> StructModel:
+def _load(cfg_path: str, const_overrides: Optional[Dict[str, object]]
+          ) -> Tuple[StructModel, str]:
+    with open(cfg_path, encoding="utf-8") as f:
+        cfg_text = f.read()
     try:
-        cfg = parse_cfg_file(cfg_path)
+        cfg = _parsed("cfg", cfg_text)
     except CfgError as e:
         raise StructLoadError(str(e))
     model_dir = os.path.dirname(os.path.abspath(cfg_path))
     toolbox_parent = os.path.dirname(os.path.dirname(model_dir))
     search_dirs = (model_dir, toolbox_parent)
-    texts = [(cfg_path, open(cfg_path).read())]
+    texts = [(cfg_path, cfg_text)]
 
     mc_path = os.path.join(model_dir, "MC.tla")
-    if os.path.exists(mc_path):
+    mc_layout = os.path.exists(mc_path)
+    if mc_layout:
         module = _load_module_closure(mc_path, search_dirs, texts)
         root_name = next(
             (e for e in module.extends if e not in _BUILTIN_MODULES), "MC"
@@ -363,6 +398,20 @@ def _load(cfg_path: str,
     if const_overrides:
         for k in sorted(const_overrides):
             digest.update(f"{k}={const_overrides[k]!r};".encode())
+    # everything below reads the texts hashed above and the overrides
+    # alone (root_name besides, which of the two layouts named it)
+    key = (digest.hexdigest(), mc_layout)
+    kept = cache.spec_kept("model", key)
+    if kept is not None:
+        for name, attrs in kept[1]:
+            with span(name, **attrs, memo="hit"):
+                pass
+        return kept[0], "hit"
+    children = []
+
+    def child(name: str) -> span:
+        children.append(span(name, memo="miss"))
+        return children[-1]
 
     constants: Dict[str, object] = {}
     for name, val in cfg.constants.items():
@@ -391,21 +440,17 @@ def _load(cfg_path: str,
     ev = Evaluator(module.defs, constants)
     symmetry = ()
     if cfg.symmetry:
-        from ..obs.spans import span
-
-        with span("build.struct.symmetry") as sp:
+        with child("build.struct.symmetry") as sp:
             symmetry = declared_symmetry(cfg.symmetry, module, constants)
             sp.attrs["perms"] = math.prod(
                 math.factorial(len(atoms)) for _, atoms in symmetry)
 
     constraints: Dict[str, tuple] = {}
     if cfg.constraints:
-        from ..obs.spans import span
-
         # host span `build.struct.constraint` (with the backend's one of
         # the same name, which compiles the predicate where the memo
-        # misses): a check pays the resolution on every call
-        with span("build.struct.constraint") as sp:
+        # misses): the resolution, paid where the model is not kept
+        with child("build.struct.constraint") as sp:
             sp.attrs["names"] = " ".join(cfg.constraints)
             constraints = declared_constraints(cfg.constraints, module)
 
@@ -423,9 +468,7 @@ def _load(cfg_path: str,
     # host span `build.struct.fairness`: the formula's fairness
     # conjuncts resolved to action labels.  Only a PROPERTY reads them:
     # a safety-only check loads whatever the formula says
-    from ..obs.spans import span
-
-    with span("build.struct.fairness") as sp:
+    with child("build.struct.fairness") as sp:
         try:
             fairness = declared_fairness(conjuncts, next_name, subscript,
                                          module)
@@ -447,16 +490,16 @@ def _load(cfg_path: str,
     invariants = _named_defs(cfg.invariants)
     # host span `build.struct.seqcap`: the sequence capacities the
     # invariants and the constraint declare, settled here so that the
-    # shape inference (on a backend-memo miss) only reads them; a check
-    # pays the walk on every call, like the constraint's resolution
-    with span("build.struct.seqcap") as sp:
+    # shape inference (on a backend-memo miss) only reads them; the
+    # walk is paid where the model is not kept, like the constraint's
+    with child("build.struct.seqcap") as sp:
         from .shapes import seq_cap_bounds
 
         seq_caps = tuple(seq_cap_bounds(
             ev, {**invariants, **constraints}, module.variables))
         sp.attrs["declared"] = len(seq_caps)
 
-    return StructModel(
+    model = StructModel(
         system=ActionSystem(ev, module.variables, init_name, next_name),
         invariants=invariants,
         properties=_named_defs(cfg.properties),
@@ -464,8 +507,12 @@ def _load(cfg_path: str,
         module=module,
         fairness=fairness,
         root_name=root_name,
-        source_digest=digest.hexdigest(),
+        source_digest=key[0],
         symmetry=symmetry,
         constraints=constraints,
         seq_caps=seq_caps,
     )
+    cache.spec_keep("model", key, (model, tuple(
+        (c.name, {k: v for k, v in c.attrs.items() if k != "memo"})
+        for c in children)))
+    return model, "miss"

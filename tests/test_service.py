@@ -328,6 +328,46 @@ def test_pooled_job_leaves_every_span(server, sweep_jobs):
     assert 0 < closing["seconds"] < root.t1 - root.t0
 
 
+def test_pooled_jobs_of_one_spec_load_it_once(server, sweep_jobs):
+    """ISSUE 46: every job's spec is written into a job directory of
+    its own, and the loaded model is kept by the bytes read, not by
+    where they lie: the second job of a spec says `hit` on its
+    `sched.load` (and on the `build.struct.load` inside it), `/pool`
+    counts the `model` memo's hit, and a third job with one byte of
+    the spec changed misses."""
+    from jaxtlc.obs import spans
+
+    def job(spec, name):
+        before = client.pool_stats(server.url)["pool"]["memo"]["model"]
+        t = time.time()
+        st = client.check(server.url, spec, _cfg(2), name=name,
+                          options=_OPTS)
+        assert st["result"]["engine"] == "pool"
+        assert (st["result"]["generated"], st["result"]["distinct"],
+                st["result"]["depth"]) == _EXPECT[2][:3]
+        after = client.pool_stats(server.url)["pool"]["memo"]["model"]
+        memo = {r.name: r.attrs.get("memo")
+                for r in spans.snapshot(since=t) if r.job == st["id"]
+                and r.name in ("sched.load", "build.struct.load",
+                               "build.struct.fairness")}
+        return st["id"], memo, (after["hits"] - before["hits"],
+                                after["misses"] - before["misses"])
+
+    first, _, _ = job(_TPB, "memo-1")
+    second, memo, counted = job(_TPB, "memo-2")
+    cfgs = [os.path.join(server.root, "jobs", j, "TwoPhaseB.cfg")
+            for j in (first, second)]
+    assert cfgs[0] != cfgs[1] and all(map(os.path.exists, cfgs))
+    assert memo == {"sched.load": "hit", "build.struct.load": "hit",
+                    "build.struct.fairness": "hit"}
+    assert counted == (1, 0)
+    # one byte more, after the module's end: another text, another model
+    _, memo, counted = job(_TPB + " ", "memo-3")
+    assert memo == {"sched.load": "miss", "build.struct.load": "miss",
+                    "build.struct.fairness": "miss"}
+    assert counted == (0, 1)
+
+
 # ---------------------------------------------------------------------------
 # smoke job class: sim submits fold, reuse the warm engine (ISSUE 14)
 # ---------------------------------------------------------------------------
