@@ -90,7 +90,11 @@ class SpecBackend(NamedTuple):
     # both.  None keeps pre-reduction pytree layouts exactly
     reduce: object = None
     # optional state constraint (a cfg's CONSTRAINT, ISSUE 39):
-    # fn([F] int32 RAW successor fields) -> bool "kept".  TLC's rule at
+    # fn([N, F] int32 RAW successor rows) -> [N] bool "kept", at batch
+    # width: the stage calls it on the candidate rows as they lie (a
+    # per-row predicate mapped over them left a unit axis and cost a
+    # second relayout of the whole array a step: PERF.md section 6, PR
+    # 47).  TLC's rule at
     # the expand / commit seam (make_expand_stage): a successor that
     # fails it counts as generated and is then dropped - never
     # fingerprinted, enqueued or checked.  An engine that does not run
@@ -259,7 +263,7 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         con_stat = None
         if constraint is not None:
             with jax.named_scope("jaxtlc.constraint"):
-                keep = jax.vmap(constraint)(flat).reshape(chunk, L)
+                keep = constraint(flat).reshape(chunk, L)
                 con_stat = jnp.stack(
                     [valid.sum(), (valid & ~keep).sum()]
                 ).astype(jnp.uint32)
@@ -352,24 +356,23 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         viol = jnp.int32(OK)
         viol_state = jnp.zeros(F, jnp.int32)
         viol_action = jnp.int32(-1)
-        for code, vmask, states, acts in (
-            *((code, bad, flat, faction)
+        # each mask with the rows its first hit reports and how many
+        # candidates share one of them: a candidate's own row, or the
+        # SOURCE state `at // L` of candidate `at` read off the block
+        for code, vmask, states, per, acts in (
+            *((code, bad, flat, 1, faction)
               for code, bad in zip(inv_codes, inv_bad)),
-            (VIOL_ASSERT, afail.reshape(-1),
-             jnp.repeat(batch, L, axis=0), faction),
-            (VIOL_DEADLOCK, dead, batch,
+            (VIOL_ASSERT, afail.reshape(-1), batch, L, faction),
+            (VIOL_DEADLOCK, dead, batch, 1,
              jnp.full(chunk, -1, jnp.int32)),
-            (VIOL_SLOT_OVERFLOW, ovf.reshape(-1),
-             jnp.repeat(batch, L, axis=0), faction),
+            (VIOL_SLOT_OVERFLOW, ovf.reshape(-1), batch, L, faction),
         ):
+            at = jnp.argmax(vmask)
             hit = vmask.any() & (viol == OK)
             viol = jnp.where(hit, code, viol)
-            viol_state = jnp.where(
-                hit, states[jnp.argmax(vmask)], viol_state
-            )
+            viol_state = jnp.where(hit, states[at // per], viol_state)
             viol_action = jnp.where(
-                hit, acts[jnp.argmax(vmask)].astype(jnp.int32),
-                viol_action,
+                hit, acts[at].astype(jnp.int32), viol_action,
             )
         return ExpandOut(
             packed=packed, lo=lo, hi=hi, valid=fvalid, action=faction,

@@ -1025,7 +1025,7 @@ def make_backend_engine(
             # generated and is invariant-checked below like the others,
             # and is then not kept: the kept ones move to the front of
             # the first level, in their order
-            kept0 = jax.vmap(backend.constraint)(inits)
+            kept0 = backend.constraint(inits)
             inits = inits[jnp.argsort(~kept0, stable=True)]
             kept0 = jnp.arange(n0) < kept0.sum()
         packed0 = cdc.pack(inits)

@@ -1057,15 +1057,19 @@ def make_sharded_engine(
             hit = d_viol != OK
             new_viol = jnp.where(hit, d_viol, new_viol)
             new_vstate = jnp.where(hit, d_state, new_vstate)
-        for code, vmask, states in (
-            *((c, b, flat) for c, b in zip(backend.inv_codes, inv_bad)),
-            (VIOL_ASSERT, afail.reshape(-1), jnp.repeat(batch, L, axis=0)),
-            (VIOL_DEADLOCK, dead, batch),
-            (VIOL_SLOT_OVERFLOW, ovf.reshape(-1), jnp.repeat(batch, L, axis=0)),
+        # `per` candidates share a reported row: a lane's assert or
+        # overflow reports its SOURCE state, `batch[at // L]` (as
+        # engine.backend.make_expand_stage reads it)
+        for code, vmask, states, per in (
+            *((c, b, flat, 1) for c, b in zip(backend.inv_codes, inv_bad)),
+            (VIOL_ASSERT, afail.reshape(-1), batch, L),
+            (VIOL_DEADLOCK, dead, batch, 1),
+            (VIOL_SLOT_OVERFLOW, ovf.reshape(-1), batch, L),
         ):
             hit = vmask.any() & (new_viol == OK)
             new_viol = jnp.where(hit, code, new_viol)
-            new_vstate = jnp.where(hit, states[jnp.argmax(vmask)], new_vstate)
+            new_vstate = jnp.where(
+                hit, states[jnp.argmax(vmask) // per], new_vstate)
         new_viol = jnp.where(
             (new_viol == OK) & fp_full & r_valid.any(), VIOL_FPSET_FULL, new_viol
         )

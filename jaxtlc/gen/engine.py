@@ -216,15 +216,15 @@ def make_gen_engine(
         generated = c.generated + valid.sum().astype(jnp.uint32)
         distinct = c.distinct + n_new.astype(jnp.uint32)
 
-        for code, vmask, states in (
-            (VIOL_SLOT_OVERFLOW, ovf.reshape(-1),
-             jnp.repeat(batch, L, axis=0)),
-            (VIOL_DEADLOCK, dead, batch),
+        # an overflowing lane reports its SOURCE state, `batch[at // L]`
+        for code, vmask, per in (
+            (VIOL_SLOT_OVERFLOW, ovf.reshape(-1), L),
+            (VIOL_DEADLOCK, dead, 1),
         ):
             hit = vmask.any() & (viol == OK)
             viol = jnp.where(hit, code, viol)
             viol_state = jnp.where(
-                hit, states[jnp.argmax(vmask)], viol_state
+                hit, batch[jnp.argmax(vmask) // per], viol_state
             )
         hit = fp_full & fvalid.any() & (viol == OK)
         viol = jnp.where(hit, VIOL_FPSET_FULL, viol)

@@ -281,12 +281,10 @@ def struct_backend(model: StructModel,
         # every engine trace
         with span("build.struct.constraint") as sp:
             sp.attrs["names"] = " ".join(model.constraints)
-            con_fn = jax.jit(compiler.build_invariant(
+            constraint = jax.jit(compiler.build_invariant(
                 ("and", list(model.constraints.values()))))
-            jax.eval_shape(con_fn, jax.ShapeDtypeStruct((1, F), jnp.int32))
-
-        def constraint(vec):
-            return con_fn(vec[None])[0]
+            jax.eval_shape(constraint,
+                           jax.ShapeDtypeStruct((1, F), jnp.int32))
 
     cert_check = None
     if cert:

@@ -99,7 +99,7 @@ def built():
                 rows), "step": jax.make_jaxpr(jax.vmap(backend.step))(rows)}
             if backend.constraint is not None:
                 traced["constraint"] = jax.make_jaxpr(
-                    jax.vmap(backend.constraint))(rows)
+                    backend.constraint)(rows)
             tables = list({id(t): (t, leaf) for t, leaf in seen}.values())
             out[name] = (backend, tables, traced, model)
     return out
