@@ -780,38 +780,76 @@ def _resolve_struct_symmetry(args, spec, sm, bounds):
 
 
 def _constraint_refusal(args, spec, sm):
-    """A model whose cfg declares CONSTRAINT runs on the single-device
-    exhaustive engine, whose expand stage applies it (engine.backend.
-    make_expand_stage), and nowhere else: every other route is refused
-    here BY NAME, before anything is built - none ever runs the model
-    unconstrained.  (The engines refuse again where they are built:
+    """A model whose cfg declares CONSTRAINT, or a PROPERTY that is an
+    action property `I /\\ [][A]_v`, runs on the single-device
+    exhaustive engine, whose expand stage applies the one and judges
+    the other (engine.backend.make_expand_stage), and nowhere else:
+    every other route is refused here BY NAME, before anything is built
+    - none ever runs the model unconstrained, or with its edges
+    unjudged.  (The engines refuse again where they are built:
     engine.backend.require_unconstrained.)"""
-    if not sm.constraints:
+    from .engine.backend import seam_only
+
+    what = seam_only(tuple(sm.constraints), tuple(sm.action_props))
+    if not what:
         return None
+    con = bool(sm.constraints)
     routes = [flag for flag, on in (
-        ("-sharded (the mesh engine's own expand half does not part "
-         "kept from counted)", args.sharded),
-        ("-simulate (a random walk has no constrained frontier)",
-         getattr(args, "simulate", False)),
+        ("-sharded (the mesh engine's own expand half neither parts "
+         "kept from counted nor judges an edge)", args.sharded),
+        ("-simulate (a random walk has no constrained frontier, and "
+         "walks a sample of the edges)", getattr(args, "simulate", False)),
         ("-infer (the evidence walks and the induction step are "
-         "unconstrained)", getattr(args, "infer", False)),
-        ("-liveness / the cfg's PROPERTY lines (the behavior graph is "
-         "captured unconstrained)", args.liveness or spec.properties),
+         "unconstrained, and judge no edge)", getattr(args, "infer", False)),
+        ("-liveness / the cfg's temporal PROPERTY lines (the behavior "
+         "graph is captured unconstrained, by an engine of its own)",
+         args.liveness or spec.properties),
         ("-narrow (the certified bounds know nothing of the "
-         "constraint)", args.narrow),
-        ("-symmetry / the cfg's SYMMETRY (the constraint is not "
-         "verified symmetric)",
+         "constraint, nor of the property's reads)", args.narrow),
+        ("-symmetry / the cfg's SYMMETRY (neither the constraint nor "
+         "the property is verified symmetric)",
          getattr(args, "symmetry", None) or sm.symmetry),
-        ("-por (the ample sets ignore the constraint)",
-         getattr(args, "por", None)),
+        ("-por (the ample sets ignore the constraint, and prune edges "
+         "the property has to see)", getattr(args, "por", None)),
+        # a failing edge is reported beside its source: the deferred
+        # checker sees fresh states, not edges; and the two together
+        # have no defined order of judgement here
+        ("CONSTRAINT together with an action property (which edges "
+         "are judged is not settled here)", con and sm.action_props),
     ) if on]
     if not routes:
         return None
-    return ("the cfg declares CONSTRAINT "
-            f"{' '.join(sm.constraints)}, which is not honoured by "
+    return (f"the cfg declares {what}, which is not honoured by "
             + "; ".join(routes)
-            + ": a constrained model runs on the single-device "
-            "exhaustive engine only")
+            + ": such a model runs on the single-device exhaustive "
+            "engine only")
+
+
+def _action_property_events(args, sm, r, n_init: int):
+    """The verdict of the model's action properties (sm.action_props),
+    which the safety search itself judged (engine.backend.
+    make_expand_stage, on every edge it generated): where the search
+    ran to its end without a violation, ONE `action_property` journal
+    event a property, before `final` - `holds`, the route, the edges
+    judged, those on which the subscript changed, the initial states I
+    was judged on.  Returns `r` with `action_prop_init_states`."""
+    if not sm.action_props or r.action_prop_edges is None \
+            or r.action_prop_init_states is not None:
+        # none, a result the engine did not make, or said already (a
+        # supervised route runs this before its `final` event, and the
+        # runner calls it again)
+        return r
+    r = r._replace(action_prop_init_states=int(n_init))
+    j = getattr(args, "_journal", None)
+    if j is None or r.violation != 0 or r.queue_left:
+        return r
+    for prop in sm.action_props.values():
+        j.event("action_property", property=prop.name, holds=True,
+                route="device", formula=prop.text,
+                edges=r.action_prop_edges, moved=r.action_prop_moved,
+                init_states=int(n_init),
+                src_cols=r.action_prop_src_cols)
+    return r
 
 
 def _por(args) -> bool:
@@ -1310,7 +1348,8 @@ def _run_check_struct(args, spec) -> int:
             ast = sm.properties[name]
             if ast[0] != "leadsto" or ast[1][0] == "box":
                 yield name, None, None, (
-                    "only plain P ~> Q is checked on the structural path"
+                    "only plain P ~> Q and a specification I /\\ [][A]_v "
+                    "are checked on the structural path"
                 )
                 continue
             yield name, ast[1], ast[2], None
@@ -1379,6 +1418,7 @@ def _run_check_struct(args, spec) -> int:
         `liveness` journal event a property; the results wait on the
         kit for the transcript.  Returns (r with the route's counters,
         "liveness_violation" | None)."""
+        r = _action_property_events(args, sm, r, system.initial_count())
         if kit.live_results is not None or not spec.properties \
                 or r.violation != 0 or r.queue_left:
             return r, None
@@ -1419,6 +1459,11 @@ def _run_check_struct(args, spec) -> int:
         kit.live_results = [(name, skip, by_name.get(name))
                             for name, _p, _q, skip in listed]
         kit.live_route = route
+        unjudged = tuple(name for name, _p, _q, skip in listed if skip)
+        if unjudged:
+            # on the result and the `final` event: a clean verdict says
+            # nothing of these
+            r = r._replace(properties_skipped=unjudged)
         j = getattr(args, "_journal", None)
         fairness = [[a, list(labels)] for a, labels in sm.fairness]
         for res in found:
@@ -1465,7 +1510,7 @@ def _run_check_struct(args, spec) -> int:
         state_env=lambda st: so.state_env(system, st),
         violation_trace=lambda: so.violation_trace(
             system, sm.invariants, check_deadlock=spec.check_deadlock,
-            constraints=sm.constraints,
+            constraints=sm.constraints, action_props=sm.action_props,
         ),
         action_order=action_order,
         preflight=lambda deep: _struct_preflight(args, spec, sm, deep),
@@ -1893,6 +1938,11 @@ def _artifact_plan(args, spec, sm, bounds):
         # depth: a simulation verdict is from INCOMPLETE search, an
         # inference verdict is about CANDIDATES - neither may publish
         # to the verdict tier
+        return None
+    if sm.action_props:
+        # an action property is judged on EDGES, by the search itself:
+        # the reachable-set tier replays states and a cached verdict
+        # replays nothing, so such a model always reaches the engine
         return None
     if _symmetry(args) or _por(args):
         # a reduced run's fp table is the REDUCED reachable set: its
@@ -2366,9 +2416,16 @@ def _render_verdict_interp(args, spec, kit, log, r, n_init, t0):
                     )
                 log.msg(2217, head + "\n" + text, severity=1)
     elif not liveness_violated:
+        for name in getattr(r, "action_prop_names", None) or ():
+            log.msg(1000, f"Action property {name} holds on all "
+                          f"{r.action_prop_edges} generated edges "
+                          f"({r.action_prop_moved} change its subscript) "
+                          f"and {r.action_prop_init_states} initial "
+                          "state(s).")
         log.success(r.generated, r.distinct,
                     getattr(r, "actual_fp_collision", None),
-                    occupancy=getattr(r, "fp_occupancy", None))
+                    occupancy=getattr(r, "fp_occupancy", None),
+                    unjudged=getattr(r, "properties_skipped", None) or ())
         dev_lines = None
         if args.coverage and kit.coverage_device is not None:
             dev_lines = kit.coverage_device(r, n_init)

@@ -102,21 +102,64 @@ class SpecBackend(NamedTuple):
     # model by name: a constrained model never runs unconstrained
     constraint: object = None
     constraint_names: tuple = ()  # the cfg's names, for messages
+    # optional action properties (a cfg PROPERTY `I /\\ [][A]_v`: a
+    # specification as a property, ISSUE 48): an ActionPropSeam.  TLC's
+    # rule for an action property: it is a property of TRANSITIONS, so
+    # it is judged on every edge the search generates, to new and to
+    # seen states alike - at candidate width in make_expand_stage,
+    # before the dedup, beside the constraint.  The same rule as for
+    # the constraint: an engine without that stage refuses the model
+    # by name (require_unconstrained)
+    action_prop: object = None
+
+
+class ActionPropSeam(NamedTuple):
+    """What the expand stage needs of a model's action properties."""
+
+    names: tuple  # the cfg's names, in its order (P of them)
+    # fn(src [N, K] int32, succ [N, F] int32) -> (ok, moved), [P, N]
+    # bool each: `[A]_v` on the edge, and `v' # v`
+    step: object
+    # fn(rows [N, F] int32) -> [P, N] bool: I on an initial state
+    init: object
+    # the K source columns `step` reads (the columns of the variables A
+    # and v read unprimed): the block's other columns are not broadcast
+    # to candidate width
+    src_cols: tuple
+    # violation codes, P each: I fails on an initial state / an edge
+    # fails `[A]_v`
+    init_codes: tuple
+    step_codes: tuple
 
 
 class ConstraintUnsupported(ValueError):
-    """A route that cannot honour a cfg's CONSTRAINT was asked to run a
-    constrained model."""
+    """A route that cannot honour what a cfg declares for the expand
+    stage alone (CONSTRAINT, an action property) was asked to run such
+    a model."""
+
+
+def seam_only(constraint_names=(), action_props=()) -> str:
+    """What of a model only the single-device expand stage honours, as
+    the refusals name it; "" where there is nothing."""
+    return " and ".join(x for x in (
+        constraint_names and "CONSTRAINT " + " ".join(constraint_names),
+        action_props and "the action property PROPERTY "
+        + " ".join(action_props) + " (I /\\ [][A]_v)") if x)
 
 
 def require_unconstrained(backend, route: str) -> None:
     """Called by every engine and driver that expands states without
-    make_expand_stage: refuses a constrained backend, naming the route
-    and the constraint, instead of visiting states the cfg excludes."""
-    if getattr(backend, "constraint", None) is not None:
+    make_expand_stage: refuses a backend with a constraint or an action
+    property, naming the route and what the cfg declares, instead of
+    visiting states the cfg excludes or leaving edges unjudged."""
+    ap = getattr(backend, "action_prop", None)
+    what = seam_only(
+        getattr(backend, "constraint", None) is not None
+        and backend.constraint_names or (),
+        ap.names if ap is not None else ())
+    if what:
         raise ConstraintUnsupported(
-            f"{route} does not honour the cfg's CONSTRAINT "
-            f"{' '.join(backend.constraint_names)}: a constrained model "
+            f"{route} does not honour the cfg's {what}: such a model "
             "runs only on the single-device exhaustive engine (drop "
             f"the option that selected {route})")
 
@@ -174,6 +217,15 @@ class ExpandOut(NamedTuple):
     # (carry `con_stat`; CheckResult.constraint_rows,
     # constraint_discarded)
     con_stat: jnp.ndarray = None
+    # [2] uint32: this block's edges the backend's action properties
+    # judged (every kept successor, to new and to seen states alike)
+    # and those on which a subscript changed, summed over the
+    # properties (None on a backend without one; carry `ap_stat`;
+    # CheckResult.action_prop_edges, action_prop_moved); and the SOURCE
+    # row [F] int32 of the first edge of the block that fails one
+    # (zeros where none does; `viol_state` is then its successor)
+    ap_stat: jnp.ndarray = None
+    ap_src: jnp.ndarray = None
 
 
 def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
@@ -224,6 +276,8 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
     if check_deadlock is None:
         check_deadlock = backend.check_deadlock
     constraint = backend.constraint
+    ap = backend.action_prop
+    ap_cols = None if ap is None else jnp.asarray(ap.src_cols, jnp.int32)
     red = backend.reduce
     sym_plan = red.plan if red is not None else None
     por_on = bool(
@@ -271,6 +325,21 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
                 ovf = ovf & keep
         fvalid = valid.reshape(-1)
         faction = action.reshape(-1)
+
+        # the action properties, on every kept edge (source row
+        # `batch[c // L]` of candidate c: the columns the predicate
+        # reads, and only those, at candidate width)
+        ap_stat = ap_src = None
+        ap_bad = []
+        if ap is not None:
+            ap_src = jnp.zeros(F, jnp.int32)
+            with jax.named_scope("jaxtlc.actionprop"):
+                src = jnp.repeat(batch[:, ap_cols], L, axis=0)
+                ok, moved = ap.step(src, flat)
+                ap_stat = jnp.stack([
+                    fvalid.sum(), (moved & fvalid[None, :]).sum(),
+                ]).astype(jnp.uint32)
+                ap_bad = [fvalid & ~ok[k] for k in range(len(ap.names))]
 
         # symmetry reduction: replace every successor by its orbit
         # representative BEFORE invariants/pack/fingerprints, so the
@@ -362,6 +431,8 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
         for code, vmask, states, per, acts in (
             *((code, bad, flat, 1, faction)
               for code, bad in zip(inv_codes, inv_bad)),
+            *((code, bad, flat, 1, faction)
+              for code, bad in zip(ap.step_codes if ap else (), ap_bad)),
             (VIOL_ASSERT, afail.reshape(-1), batch, L, faction),
             (VIOL_DEADLOCK, dead, batch, 1,
              jnp.full(chunk, -1, jnp.int32)),
@@ -374,12 +445,15 @@ def make_expand_stage(backend: SpecBackend, chunk: int, check_deadlock,
             viol_action = jnp.where(
                 hit, acts[at].astype(jnp.int32), viol_action,
             )
+            if ap is not None and code in ap.step_codes:
+                ap_src = jnp.where(hit, batch[at // L], ap_src)
         return ExpandOut(
             packed=packed, lo=lo, hi=hi, valid=fvalid, action=faction,
             gen=gen, viol=viol, viol_state=viol_state,
             viol_action=viol_action, cert=cert, cov=cov,
             flat=flat if deferred else None,
             sym=sym, sym_stat=sym_stat, pruned=pruned, con_stat=con_stat,
+            ap_stat=ap_stat, ap_src=ap_src,
         )
 
     return expand

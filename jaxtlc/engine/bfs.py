@@ -178,6 +178,15 @@ class EngineCarry(NamedTuple):
     # counted as generated, never kept
     con_stat: jnp.ndarray = None
     st_con_stat: jnp.ndarray = None  # staged block's two
+    # --- action properties (None without backend.action_prop, ISSUE 48)
+    # Cumulative [2] uint32 (ExpandOut.ap_stat summed): edges judged,
+    # edges on which a property's subscript changed; and the SOURCE row
+    # [F] int32 of the edge that failed one (`viol_state` holds its
+    # successor), latched with the violation
+    ap_stat: jnp.ndarray = None
+    st_ap_stat: jnp.ndarray = None  # staged block's two
+    ap_src: jnp.ndarray = None
+    st_ap_src: jnp.ndarray = None  # staged block's failing source
 
 
 class CheckResult(NamedTuple):
@@ -309,6 +318,27 @@ class CheckResult(NamedTuple):
     constraint_rows: int = None
     constraint_discarded: int = None
     constraint_names: tuple = None
+    # a model with an action property only (a cfg PROPERTY `I /\\
+    # [][A]_v`, ISSUE 48; None elsewhere): the cfg's names for them;
+    # the initial states I was judged on; the edges `[A]_v` was judged
+    # on (every kept successor the search generated: generated less the
+    # initial states on an unconstrained model) and those on which v
+    # changed, where A itself decides (`action_prop_moved /
+    # action_prop_edges`: a constant of the model); the source columns
+    # the predicate reads, broadcast to candidate width; and, where a
+    # property failed on an edge, that edge's SOURCE row
+    # (`violation_state` is its successor)
+    action_prop_names: tuple = None
+    action_prop_init_states: int = None
+    action_prop_edges: int = None
+    action_prop_moved: int = None
+    action_prop_src_cols: int = None
+    action_prop_source: object = None
+    # the cfg's PROPERTY lines the run did NOT judge, by name (a shape
+    # the struct route does not check: `[]<>P`, `[]P ~> Q`, a
+    # specification with a fairness conjunct); None where every line
+    # was judged.  A verdict `ok` with a name here says nothing of it
+    properties_skipped: tuple = None
     # a struct check with a PROPERTY only (live.check, ISSUE 41; None
     # elsewhere): the behaviour graph the liveness route analysed on
     # the device - its states and successor rows (what the safety run
@@ -345,6 +375,9 @@ STEP_COUNTERS = ("step_lanes", "step_slots", "state_words", "state_bits",
                  "sym_perms", "sym_sets", "canon_rows", "canon_moved",
                  "sym_cert_checks", "sym_cert_trips", "constraint_rows",
                  "constraint_discarded", "constraint_names",
+                 "action_prop_names", "action_prop_init_states",
+                 "action_prop_edges", "action_prop_moved",
+                 "action_prop_src_cols", "properties_skipped",
                  "live_states", "live_edges", "live_changed_edges",
                  "live_fair_edges", "live_h_states", "live_p_states",
                  "live_survivors", "live_outer", "live_sweeps",
@@ -370,6 +403,11 @@ def with_step_counters(result: CheckResult, backend) -> CheckResult:
     if getattr(backend, "constraint", None) is not None:
         result = result._replace(
             constraint_names=tuple(backend.constraint_names))
+    ap = getattr(backend, "action_prop", None)
+    if ap is not None:
+        result = result._replace(
+            action_prop_names=tuple(ap.names),
+            action_prop_src_cols=len(ap.src_cols))
     plan = getattr(backend.reduce, "plan", None)
     if plan is not None:
         result = result._replace(sym_perms=plan.n_perms,
@@ -788,6 +826,14 @@ def make_stage_pair(
                 extra["por_pruned"] = c.por_pruned + ex.pruned
             if ex.con_stat is not None and c.con_stat is not None:
                 extra["con_stat"] = c.con_stat + ex.con_stat
+            if ex.ap_stat is not None and c.ap_stat is not None:
+                extra["ap_stat"] = c.ap_stat + ex.ap_stat
+                # the failing edge's source, latched with its violation
+                # (first wins, as `viol_state`: ex.viol is the property's
+                # code only where ex.ap_src was set)
+                extra["ap_src"] = jnp.where(
+                    (c.viol == OK) & (viol == ex.viol) & (ex.viol != OK),
+                    ex.ap_src, c.ap_src)
             if ex.cov is not None and c.cov_counts is not None:
                 # device coverage plane: fold this block's per-site visit
                 # increments into the cumulative counters (telemetry only)
@@ -979,6 +1025,8 @@ def make_backend_engine(
     cov_plane = backend.coverage
     n_sites = cov_plane.n_sites if cov_plane is not None else 0
     has_con = backend.constraint is not None
+    ap = backend.action_prop
+    has_ap = ap is not None
     cdc = backend.cdc
     F = cdc.n_fields
     W = (cdc.nbits + 31) // 32
@@ -1049,6 +1097,16 @@ def make_backend_engine(
             hit = bad.any() & (viol == OK)
             viol = jnp.where(hit, code, viol)
             viol_state = jnp.where(hit, inits[jnp.argmax(bad)], viol_state)
+        if has_ap:
+            # the first half of an action property `I /\\ [][A]_v`: I on
+            # every initial state
+            init_ok = ap.init(inits)
+            for k, code in enumerate(ap.init_codes):
+                bad = ~init_ok[k]
+                hit = bad.any() & (viol == OK)
+                viol = jnp.where(hit, code, viol)
+                viol_state = jnp.where(hit, inits[jnp.argmax(bad)],
+                                       viol_state)
         staged = {}
         if pipeline:
             staged = dict(
@@ -1076,8 +1134,14 @@ def make_backend_engine(
                 staged["st_pruned"] = jnp.uint32(0)
             if has_con:
                 staged["st_con_stat"] = jnp.zeros(2, jnp.uint32)
+            if has_ap:
+                staged["st_ap_stat"] = jnp.zeros(2, jnp.uint32)
+                staged["st_ap_src"] = jnp.zeros(F, jnp.int32)
         if has_con:
             staged["con_stat"] = jnp.zeros(2, jnp.uint32)
+        if has_ap:
+            staged["ap_stat"] = jnp.zeros(2, jnp.uint32)
+            staged["ap_src"] = jnp.zeros(F, jnp.int32)
         if has_cert:
             staged["cert_viol"] = jnp.bool_(False)
         if has_sym:
@@ -1160,6 +1224,9 @@ def make_backend_engine(
                 extra["st_pruned"] = ex.pruned
             if has_con:
                 extra["st_con_stat"] = ex.con_stat
+            if has_ap:
+                extra["st_ap_stat"] = ex.ap_stat
+                extra["st_ap_src"] = ex.ap_src
             return c._replace(
                 st_packed=ex.packed, st_lo=ex.lo, st_hi=ex.hi,
                 st_valid=ex.valid, st_action=ex.action, st_gen=ex.gen,
@@ -1180,6 +1247,8 @@ def make_backend_engine(
                 sym_stat=c.st_sym_stat if has_sym else None,
                 pruned=c.st_pruned if has_por else None,
                 con_stat=c.st_con_stat if has_con else None,
+                ap_stat=c.st_ap_stat if has_ap else None,
+                ap_src=c.st_ap_src if has_ap else None,
             )
 
         # The two-deep pipeline body, bubble-free: the staged block k-1
@@ -1567,6 +1636,12 @@ def result_from_carry(
         sym_counts.update(zip(
             ("constraint_rows", "constraint_discarded"),
             map(int, np.asarray(con))))
+    aps = getattr(carry, "ap_stat", None)
+    if aps is not None:
+        sym_counts.update(zip(
+            ("action_prop_edges", "action_prop_moved"),
+            map(int, np.asarray(aps))))
+        sym_counts["action_prop_source"] = np.asarray(carry.ap_src)
     pruned = getattr(carry, "por_pruned", None)
     if pruned is not None:
         pruned = int(np.asarray(pruned).sum())  # shards carry partials

@@ -189,6 +189,19 @@ def resolve(
         return _resolve_struct(cfg_path, cfg, launch, spec_name,
                                check_deadlock, workers, fp_index,
                                model_dir, const_overrides)
+    if frontend in ("hand", "gen") and cfg.properties and (
+            frontend == "gen" or spec_name not in ("", "KubeAPI")):
+        # likewise a PROPERTY that states a specification (an action
+        # property `I /\\ [][A]_v`): only the structural frontend judges
+        # it, and no other gives a verdict that leaves it out
+        from ..struct.loader import action_property_names
+
+        stated = action_property_names(cfg_path)
+        if stated:
+            raise ValueError(
+                f"the cfg's PROPERTY {' '.join(stated)} is an action "
+                "property (I /\\ [][A]_v): only the structural frontend "
+                "judges it (re-run with -frontend struct)")
     if frontend == "hand" and spec_name not in ("", "KubeAPI"):
         raise ValueError(
             f"-frontend hand supports only the KubeAPI root spec, "
@@ -315,7 +328,10 @@ def _resolve_struct(cfg_path, cfg, launch, spec_name, check_deadlock,
     return StructRunSpec(
         structmodel=sm,
         invariants=list(cfg.invariants),
-        properties=list(cfg.properties),
+        # the temporal ones; a PROPERTY that states a specification is
+        # the model's (sm.action_props), judged by the safety search
+        properties=[p for p in cfg.properties
+                    if p not in sm.action_props],
         check_deadlock=check_deadlock,
         workers=workers,
         fp_index=DEFAULT_FP_INDEX if fp_index is None else fp_index,

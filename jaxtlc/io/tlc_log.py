@@ -185,7 +185,8 @@ class TLCLog:
         return re.sub(r"E([+-])0+(\d)", r"E\1\2", f"{v:.1E}")
 
     def success(self, generated: int, distinct: int,
-                actual: float = None, occupancy: float = None) -> None:
+                actual: float = None, occupancy: float = None,
+                unjudged: tuple = ()) -> None:
         """The full 2193 success text (MC.out:38-42): both collision
         estimates when the engine computed the actual-fingerprint one,
         plus the final fingerprint-table load fraction (the auto-grow
@@ -193,7 +194,11 @@ class TLCLog:
         how close a run came to regrowing)."""
         p = collision_probability(generated, distinct)
         body = (
-            "Model checking completed. No error has been found.\n"
+            "Model checking completed. No error has been found."
+            # the verdict line says what it does not cover: a PROPERTY
+            # the run skipped was not judged
+            + (f" NOT JUDGED: PROPERTY {' '.join(unjudged)} (skipped)."
+               if unjudged else "") + "\n"
             "  Estimates of the probability that TLC did not check all "
             "reachable states\n"
             "  because two distinct states had the same fingerprint:\n"

@@ -100,6 +100,15 @@ EVENTS = {
     # live.check.LIVE_COUNTERS)
     "liveness": {"property": _STR, "holds": _BOOL, "route": _STR,
                  "fairness": (list,)},
+    # one per action property of a struct check (a cfg PROPERTY `I /\\
+    # [][A]_v`, ISSUE 48) that holds, before the `final` event: the
+    # safety search judged it itself (`route` "device": the expand
+    # stage, on every generated edge).  `edges` = the edges judged,
+    # `moved` = those on which the subscript changed, `init_states` =
+    # the initial states I was judged on; `formula`, `src_cols` extra
+    "action_property": {"property": _STR, "holds": _BOOL, "route": _STR,
+                        "edges": _NUM, "moved": _NUM,
+                        "init_states": _NUM},
     # the structured final event: EVERY run (clean, violated, interrupted,
     # progress-lost) ends its journal with exactly one of these.  Mesh
     # runs add shard_distinct (extra field): per-device table occupancy

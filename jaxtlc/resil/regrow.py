@@ -198,7 +198,8 @@ def migrate_engine_carry(
     # flag, the canon counters and the POR count travel verbatim in
     # their own dtypes - a reduced run regrows like any other
     for f in ("sym_viol", "st_sym", "sym_stat", "st_sym_stat",
-              "por_pruned", "st_pruned", "con_stat", "st_con_stat"):
+              "por_pruned", "st_pruned", "con_stat", "st_con_stat",
+              "ap_stat", "st_ap_stat", "ap_src", "st_ap_src"):
         if getattr(carry, f, None) is not None:
             staged[f] = jnp.asarray(np.asarray(getattr(carry, f)))
 

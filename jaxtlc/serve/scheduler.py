@@ -964,7 +964,7 @@ class Scheduler:
         })
         model = sw.load_anchored(cfg_path, params,
                                  const_overrides=fixed or None)
-        if model.symmetry or model.constraints:
+        if model.symmetry or model.constraints or model.action_props:
             # the cfg declares SYMMETRY and the vmapped sweep engine
             # does not reduce: each point through api.run_check, which
             # does (no cfg with the line gets an unreduced verdict).
@@ -1269,7 +1269,7 @@ class Scheduler:
         except (StructLoadError, StructParseError, JobError):
             self._run_supervised(job)
             return
-        if model.symmetry or model.constraints:
+        if model.symmetry or model.constraints or model.action_props:
             # the cfg declares SYMMETRY: the pool's plain engines do not
             # reduce, api.run_check does.  A cfg that declares
             # CONSTRAINT goes the same way (the pool route hands the

@@ -24,10 +24,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..engine.backend import SpecBackend
+from ..engine.backend import ActionPropSeam, SpecBackend
 from ..engine.bfs import VIOL_ASSERT
 from .codec import StructCodec
-from .compile import LaneCompiler, TrapPolicy, compact_lanes, compact_width
+from .compile import (
+    LaneCompiler,
+    TrapPolicy,
+    compact_lanes,
+    compact_width,
+    state_vars_read,
+)
 from .loader import StructModel
 from .shapes import (
     OPEN_SIDE_FACTOR,
@@ -38,6 +44,10 @@ from .shapes import (
 )
 
 VIOL_INVARIANT_BASE = 100
+# an action property `I /\\ [][A]_v` (model.action_props, in the cfg's
+# order): 200 + 2k where I fails on an initial state, 201 + 2k where an
+# edge fails `[A]_v`
+VIOL_ACTION_PROP_BASE = 200
 
 
 def struct_viol_names(model: StructModel) -> Dict[int, str]:
@@ -46,6 +56,15 @@ def struct_viol_names(model: StructModel) -> Dict[int, str]:
     names = {VIOL_ASSERT: "Failure of PlusCal assertion"}
     for k, name in enumerate(model.invariants):
         names[VIOL_INVARIANT_BASE + k] = f"Invariant {name} is violated"
+    for k, prop in enumerate(model.action_props.values()):
+        names[VIOL_ACTION_PROP_BASE + 2 * k] = (
+            f"Property {prop.name} is violated: an initial state does "
+            f"not satisfy {prop.init_name}")
+        names[VIOL_ACTION_PROP_BASE + 2 * k + 1] = (
+            f"Property {prop.name} is violated: a step is neither a "
+            f"{prop.action_name} step nor leaves {prop.sub_text} "
+            f"unchanged (action property [][{prop.action_name}]_"
+            f"{prop.sub_text})")
     return names
 
 
@@ -286,6 +305,47 @@ def struct_backend(model: StructModel,
             jax.eval_shape(constraint,
                            jax.ShapeDtypeStruct((1, F), jnp.int32))
 
+    action_prop = None
+    if model.action_props:
+        # the cfg's action properties `I /\\ [][A]_v`: each `[A]_v` one
+        # two-state predicate over (the source columns it reads, the
+        # successor row) and each I one state predicate, walked once
+        # here (like the lanes) and replayed by every engine trace
+        with span("build.struct.actionprop") as sp:
+            props = list(model.action_props.values())
+            sp.attrs["names"] = " ".join(p.name for p in props)
+            read = state_vars_read(
+                [p.action for p in props] + [list(p.sub) for p in props],
+                system.ev.defs, system.variables)
+            src_cols = tuple(j for v in read for j in cdc.columns(v))
+            sp.attrs["src_cols"] = len(src_cols)
+            steps = [jax.jit(compiler.build_two_state(
+                p.action, p.sub, src_cols)) for p in props]
+            inits_ok = [jax.jit(compiler.build_invariant(p.init))
+                        for p in props]
+            for fn in steps:
+                jax.eval_shape(
+                    fn, jax.ShapeDtypeStruct((1, len(src_cols)), jnp.int32),
+                    jax.ShapeDtypeStruct((1, F), jnp.int32))
+            for fn in inits_ok:
+                jax.eval_shape(fn, jax.ShapeDtypeStruct((1, F), jnp.int32))
+
+        def ap_step(src, succ):
+            both = [fn(src, succ) for fn in steps]
+            return (jnp.stack([ok for ok, _ in both]),
+                    jnp.stack([moved for _, moved in both]))
+
+        action_prop = ActionPropSeam(
+            names=tuple(p.name for p in props),
+            step=ap_step,
+            init=lambda rows: jnp.stack([fn(rows) for fn in inits_ok]),
+            src_cols=src_cols,
+            init_codes=tuple(VIOL_ACTION_PROP_BASE + 2 * k
+                             for k in range(len(props))),
+            step_codes=tuple(VIOL_ACTION_PROP_BASE + 2 * k + 1
+                             for k in range(len(props))),
+        )
+
     cert_check = None
     if cert:
         cert_check = make_cert_check(
@@ -396,6 +456,7 @@ def struct_backend(model: StructModel,
         reduce=reduce_ops,
         constraint=constraint,
         constraint_names=tuple(model.constraints),
+        action_prop=action_prop,
     )
     # trap-audit surface (preflight renders which traps remain and why)
     backend.cdc.trap_stats = trap_stats

@@ -335,6 +335,11 @@ class StructCodec:
         assert len(out) == self.n_fields
         return out
 
+    def columns(self, var: str) -> range:
+        """The field columns variable `var` is laid out in."""
+        lay = self.layouts[self.variables.index(var)]
+        return range(self.offsets[var], self.offsets[var] + lay.n_fields)
+
     def encode(self, st: tuple) -> np.ndarray:
         out: List[int] = []
         for lay, val in zip(self.layouts, st):

@@ -263,10 +263,13 @@ class LI(LV):
     dynamic sequence indexing - carry no bounds, so the range-trap
     elision (analysis.absint TrapPolicy) can never fire on them."""
 
-    def __init__(self, arr, depth=0, bounds=None):
+    def __init__(self, arr, depth=0, bounds=None, leaf=None):
         self.arr = arr
         self.depth = depth
         self.bounds = bounds  # Optional[(lo, hi)]
+        # the integer leaf the value was decoded from, where it was (a
+        # field read): the universe a set of such values is a mask over
+        self.leaf = leaf
 
 
 def _int_bounds(lv) -> Optional[Tuple[int, int]]:
@@ -854,7 +857,8 @@ class LaneCompiler:
         decodes - whose int view therefore carries certified bounds."""
         if isinstance(shape, SInt):
             return LI(lv.arr + shape.lo, lv.depth,
-                      bounds=(shape.lo, shape.hi) if trusted else None)
+                      bounds=(shape.lo, shape.hi) if trusted else None,
+                      leaf=lv.leaf if lv.leaf.shape == shape else None)
         if isinstance(shape, SBool):
             return LB(lv.arr == 1, lv.depth)
         if isinstance(shape, SSet):
@@ -1152,6 +1156,17 @@ class LaneCompiler:
             return self._comp_call(ast, env, ctx)
         if op == "fnlit":
             return self._comp_fnlit(ast, env, ctx)
+        if op == "unchanged":
+            # an action read as a predicate on two states
+            # (build_two_state): v' = v, variable by variable
+            from .actions import expand_unchanged
+
+            out = LC(True)
+            for v in expand_unchanged(ast[1], self.ev.defs,
+                                      set(self.variables)):
+                nxt = self.comp(("prime", v), env, ctx)
+                out = self._land(out, self._eq_lv(env[v], nxt))
+            return out
         raise CompileError(f"cannot compile node {op!r}")
 
     def _comp_name(self, name, env, ctx) -> LV:
@@ -2140,14 +2155,22 @@ class LaneCompiler:
         env2 = dict(env)
         env2[var] = lifted
         r = self.comp(expr, env2, ctx)
-        re = self.to_leaf(r, m.elem_leaf)
+        # the image's universe: the domain's own, or the leaf an
+        # integer or enum-coded image was read from (`{m.bal : m \\in
+        # mset}`: ballots, not messages)
+        leaf = m.elem_leaf
+        if isinstance(r, LI) and r.leaf is not None:
+            leaf = r.leaf
+        elif isinstance(r, LE):
+            leaf = r.leaf
+        re = self.to_leaf(r, leaf)
         idx = _align(re.arr, re.depth, level)
         mbits = _mask_align(m.bits, m.depth, idx, level - 1)[0]
-        n = len(m.elem_leaf.values)
+        n = len(leaf.values)
         # scatter: out[t] = any_u (bits[u] & idx[u] == t)
         onehot = idx[..., None] == jnp.arange(n)
         bits = (onehot & mbits[..., None]).any(axis=-2)
-        return LM(bits, m.elem_leaf, level - 1)
+        return LM(bits, leaf, level - 1)
 
     def _comp_choose(self, ast, env, ctx) -> LV:
         _, var, dom_ast, pred = ast
@@ -2224,6 +2247,8 @@ class LaneCompiler:
             env2 = dict(env)
             for p, a in zip(d.params, args):
                 env2[p] = self.comp(a, env, ctx)
+            if _has_recfn(d.body):
+                return self._call_by_table(d, [env2[p] for p in d.params])
             return self.comp(d.body, env2, ctx)
         if name in ("FoldFunctionOnSet", "FoldFunction"):
             return self._comp_fold(name, args, env, ctx)
@@ -2286,6 +2311,45 @@ class LaneCompiler:
                 ctx.afail = self._lor(ctx.afail, self._lnot(cond))
             return LC(True)
         raise CompileError(f"unknown operator {name!r}")
+
+    def _call_by_table(self, d: Definition, vals) -> LV:
+        """An operator whose body defines a function by recursion (a
+        LET's `f[x \\in S] == e`: PaxosCommit's Maximum) applied to one
+        small set of the state: the host evaluator's answer for every
+        subset of the set's universe, as a table read at the mask's
+        code (look_up, in the form the table's values allow).  The
+        recursion itself is the evaluator's (eval.RecFn)."""
+        if len(vals) == 1 and isinstance(vals[0], LC):
+            return LC(self.ev.eval(d.body, {d.params[0]: vals[0].value}))
+        if len(vals) != 1 or not isinstance(vals[0], LM) \
+                or len(vals[0].elem_leaf.values) > TABLE_CALL_BITS:
+            raise CompileError(
+                f"{d.name}: a recursively defined function compiles "
+                f"only as a table over one set of at most "
+                f"{TABLE_CALL_BITS} possible elements")
+        m = vals[0]
+        elems = m.elem_leaf.values
+        key = (id(m.elem_leaf), "#call", d.name)
+        table = self._pred_tables.get(key)
+        if table is None:
+            rows = []
+            for code in range(1 << len(elems)):
+                arg = frozenset(x for i, x in enumerate(elems)
+                                if code >> i & 1)
+                try:
+                    rows.append(self.ev.eval(d.body, {d.params[0]: arg}))
+                except StructEvalError as e:
+                    raise CompileError(f"{d.name}({set(arg)}): {e}")
+            if not all(is_int(x) for x in rows):
+                raise CompileError(
+                    f"{d.name}: a table call has to yield integers")
+            table = np.asarray(rows, np.int32)
+            self._pred_tables[key] = table
+        weights = jnp.asarray([1 << i for i in range(len(elems))],
+                              jnp.int32)
+        code = (m.bits.astype(jnp.int32) * weights).sum(axis=-1)
+        return LI(self.look_up(table, LE(code, m.elem_leaf, m.depth)),
+                  m.depth, bounds=(int(table.min()), int(table.max())))
 
     def _comp_fold(self, name, args, env, ctx) -> LV:
         """The community module Functions' folds with + or * over a
@@ -3008,6 +3072,46 @@ class LaneCompiler:
 
         return cov_fn
 
+    def build_two_state(self, ast, sub, read_cols):
+        """A TWO-STATE predicate, rows in and booleans out: an action
+        `ast` read as a formula over a source state and a successor,
+        whose primed variables are READ from the successor row where a
+        lane of the step assigns them (`rmState' = [rmState EXCEPT
+        ...]` is an equation to test), judged as `[ast]_sub`:
+
+            pred(src [N, K] int32, succ [N, F] int32)
+                -> (ok [N] bool, moved [N] bool)
+
+        `src` holds the source row's columns `read_cols` alone (the
+        columns of the variables `ast` and `sub` read unprimed:
+        state_vars_read), `succ` the whole successor row.  `moved` is
+        `sub' # sub` - on the raw columns: the codec gives a value one
+        code - and `ok` is `ast \\/ ~moved`.  The seam of an action
+        property (engine.backend.make_expand_stage); ACTION_CONSTRAINT
+        is this predicate with the other consequence."""
+        tally = self._new_tally()
+        at = {j: k for k, j in enumerate(read_cols)}
+        sub_cols = [j for v in sub for j in self.codec.columns(v)]
+        assert all(j in at for j in sub_cols)
+
+        def pred(src, succ):
+            B, F = succ.shape
+            zero = jnp.zeros((B,), jnp.int32)
+            whole = jnp.stack(
+                [src[:, at[j]] if j in at else zero for j in range(F)],
+                axis=1)
+            nxt = self._begin_trace(tally, succ)
+            env = dict(self.decode_state(whole))
+            for v in self.variables:
+                env[("'", v)] = nxt[v]
+            moved = jnp.zeros((B,), bool)
+            for j in sub_cols:
+                moved = moved | (succ[:, j] != src[:, at[j]])
+            r = self.comp(ast, env, LaneCtx())
+            return self._guard_arr(r, B) | ~moved, moved
+
+        return pred
+
     def build_invariant(self, ast):
         """inv(fields [B,F]) -> ok [B] bool."""
         tally = self._new_tally()
@@ -3121,6 +3225,44 @@ def _mask_align(a_bits, a_pre, b_bits, b_pre):
 
 
 ENUM_LEAF_LIMIT_TABLE = 1 << 20
+# a recursively defined function is a table over the subsets of a set
+# of at most this many possible elements (LaneCompiler._call_by_table)
+TABLE_CALL_BITS = 10
+
+
+def state_vars_read(asts, defs, variables) -> Tuple[str, ...]:
+    """The state variables `asts` may read UNPRIMED, through the
+    definitions they name: any name under them but a primed one
+    counts, so the answer errs to more (a bound variable that shadows
+    one, a string that spells one).  In declaration order."""
+    seen, found = set(), set()
+    stack = list(asts)
+    while stack:
+        node = stack.pop()
+        if isinstance(node, str):
+            if node in variables:
+                found.add(node)
+            elif node in defs and node not in seen:
+                seen.add(node)
+                stack.append(defs[node].body)
+        elif isinstance(node, (tuple, list)):
+            if node and node[0] == "prime":
+                continue
+            stack.extend(node)
+    return tuple(v for v in variables if v in found)
+
+
+def _has_recfn(ast) -> bool:
+    """Does `ast` itself (not the definitions it calls) define a
+    function by recursion in a LET?"""
+    stack = [ast]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, tuple) and node and node[0] == "recfn":
+            return True
+        if isinstance(node, (tuple, list)):
+            stack.extend(x for x in node if isinstance(x, (tuple, list)))
+    return False
 _NOCONST = object()
 
 

@@ -167,7 +167,36 @@ def is_fn(v) -> bool:
         and all(k == v[0][0] + i for i, (k, _) in enumerate(v))
 
 
+class RecFn:
+    """The value of a LET's `f[x \\in S] == e`, a function given by
+    recursion on its argument (PaxosCommit's `Max[T \\in SUBSET S]`,
+    which calls itself on `T \\ {n}`): applied by evaluating `e` with x
+    bound to the argument and f to this value, one evaluation an
+    argument.  Its domain is not enumerated - it may be a powerset -
+    so an argument is checked for membership, and the function is
+    never compared, stored in a state or asked for its DOMAIN."""
+
+    def __init__(self, ev, name, var, dom, body, env, primed):
+        self.ev, self.name, self.var = ev, name, var
+        self.dom, self.body = dom, body
+        self.env, self.primed = env, primed
+        self._memo: dict = {}
+
+    def __call__(self, arg):
+        if arg not in self._memo:
+            if not Evaluator._member(arg, self.dom):
+                raise StructEvalError(
+                    f"{arg!r} not in the domain of {self.name}")
+            env = dict(self.env)
+            env[self.var] = arg
+            env[self.name] = self
+            self._memo[arg] = self.ev.eval(self.body, env, self.primed)
+        return self._memo[arg]
+
+
 def fn_apply(f, arg):
+    if isinstance(f, RecFn):
+        return f(arg)
     if isinstance(f, tuple):
         if f and is_fn(f):
             for k, v in f:
@@ -389,10 +418,23 @@ class Evaluator:
             return env["@"]
         if op == "call":
             return self._call(ast, env, primed)
+        if op == "recfn":
+            _, name, var, dom_ast, body = ast
+            return RecFn(self, name, var, self.eval(dom_ast, env, primed),
+                         body, env, primed)
         if op == "unchanged":
-            raise StructEvalError(
-                "UNCHANGED outside an action conjunction"
-            )
+            if primed is None:
+                raise StructEvalError(
+                    "UNCHANGED outside an action conjunction"
+                )
+            # an action read as a predicate on a pair of states (an
+            # action property's `[A]_v`): v' = v, variable by variable
+            from .actions import expand_unchanged
+
+            return all(
+                self.eval(("prime", v), env, primed)
+                == self._resolve_name(v, env, primed)
+                for v in expand_unchanged(ast[1], self.defs, set(primed)))
         if op in ("box", "leadsto", "spec"):
             raise StructEvalError(
                 f"temporal operator {op} has no state-level value"
@@ -413,9 +455,28 @@ class Evaluator:
             raise StructEvalError(f"expected a set, got {v!r}")
         return v
 
+    def _in_funcset(self, a, ra, env, primed) -> bool:
+        """a \\in [S -> T] without the function space (18^3 functions
+        of PaxosCommit's aState a state, were it enumerated): the
+        domain is S and every value lies in T, T itself maybe one."""
+        dom = self._set(ra[1], env, primed)
+        if not isinstance(a, tuple):
+            return False
+        pairs = a if a and is_fn(a) else tuple(enumerate(a, 1))
+        if len(pairs) != len(dom) or {k for k, _ in pairs} != dom:
+            return False
+        if ra[2][0] == "funcset":
+            return all(self._in_funcset(v, ra[2], env, primed)
+                       for _, v in pairs)
+        rng = self.eval(ra[2], env, primed)
+        return all(self._member(v, rng) for _, v in pairs)
+
     def _cmp(self, ast, env, primed):
         _, sym, la, ra = ast
         a = self.eval(la, env, primed)
+        if sym in (r"\in", r"\notin") and ra[0] == "funcset":
+            inn = self._in_funcset(a, ra, env, primed)
+            return inn if sym == r"\in" else not inn
         b = self.eval(ra, env, primed)
         if sym == "=":
             return a == b

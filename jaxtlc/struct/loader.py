@@ -11,7 +11,14 @@ evaluator knows) are built into the evaluator.  A cfg's `SYMMETRY <def>`
 is evaluated here (`declared_symmetry`) to the constant sets whose full
 permutation groups it is the union of; a cfg's `CONSTRAINT <defs>` is
 resolved here (`declared_constraints`) to the state predicates whose
-conjunction every kept state satisfies.
+conjunction every kept state satisfies.  `N == INSTANCE M` (no WITH:
+M's constants and variables are the instancing module's of the same
+name) defines `N!Op` for every definition of M (`_instantiate`), M found
+on the same search path and hashed into the model's digest.  A cfg
+PROPERTY that unfolds to `I /\\ [][A]_v` - another specification, the
+module's refinement theorem - is an action property
+(`declared_action_properties`): I on the initial states, `A \\/ v' = v`
+on every edge the search generates.
 """
 
 from __future__ import annotations
@@ -72,6 +79,35 @@ class StructModel(NamedTuple):
     # declares none.  A growing sequence without one takes a first
     # guess; both are guarded by the Append trap (struct.backend)
     seq_caps: tuple = ()
+    # the cfg's PROPERTY lines of the shape `I /\\ [][A]_v` (a
+    # specification as a property: the refinement a module's closing
+    # theorem states), resolved: name -> ActionProperty, in the cfg's
+    # order; {} where the cfg has none.  `properties` holds the cfg's
+    # other PROPERTY lines alone.  A model that declares one is only
+    # ever checked with it: the backend carries the two-state predicate
+    # (struct.backend), the single-device expand stage judges it on
+    # every generated edge, and every other route refuses the model by
+    # name (engine.backend.require_unconstrained)
+    action_props: Dict[str, "ActionProperty"] = {}
+
+
+class ActionProperty(NamedTuple):
+    """A PROPERTY `I /\\ [][A]_v`, resolved."""
+
+    name: str  # the cfg's name for it
+    init: tuple  # I, a state predicate's AST
+    action: tuple  # A, an action's AST: primed variables are READ
+    sub: Tuple[str, ...]  # the variables of the subscript v
+    # the three as the formula names them (`TC!TCInit`, `TC!TCNext`,
+    # `rmState` or `<<x,y>>`), for the journal and the messages
+    init_name: str
+    action_name: str
+    sub_text: str
+
+    @property
+    def text(self) -> str:
+        return (f"{self.init_name} /\\ [][{self.action_name}]_"
+                f"{self.sub_text}")
 
 
 class StructLoadError(ValueError):
@@ -89,20 +125,32 @@ def _parse_const_literal(text: str):
     if t.lstrip("-").isdigit():
         return int(t)
     if t.startswith("{") and t.endswith("}"):
-        # model-value set: CONSTANT RM = {r1, r2}.  Flat sets of simple
-        # literals only - nested braces or quoted commas would split
-        # wrong, so they are a loud error, not a garbage constant.
+        # model-value set: CONSTANT RM = {r1, r2}, or a set of such sets
+        # (`Majority = {{a1, a2}, {a1, a3}, {a2, a3}}`): split at the
+        # commas of this pair of braces.  A quoted comma would split
+        # wrong, so a string inside is a loud error, not a garbage
+        # constant.
         inner = t[1:-1].strip()
         if not inner:
             return frozenset()
-        if "{" in inner or '"' in inner:
+        if '"' in inner:
             raise StructLoadError(
-                f"unsupported constant set literal {t!r} (flat "
-                "model-value/number sets only)"
+                f"unsupported constant set literal {t!r} (sets of "
+                "model values, numbers and such sets only)"
             )
-        return frozenset(
-            _parse_const_literal(x) for x in inner.split(",")
-        )
+        parts, depth, start = [], 0, 0
+        for k, ch in enumerate(inner):
+            depth += (ch == "{") - (ch == "}")
+            if depth < 0:
+                break
+            if ch == "," and depth == 0:
+                parts.append(inner[start:k])
+                start = k + 1
+        if depth != 0:
+            raise StructLoadError(
+                f"unbalanced braces in constant set literal {t!r}")
+        parts.append(inner[start:])
+        return frozenset(_parse_const_literal(x) for x in parts)
     if t == "defaultInitValue":
         return DEFAULT_INIT
     # TLC model value: an atom equal only to itself; the hand oracle
@@ -182,6 +230,82 @@ def declared_constraints(names, module: Module) -> Dict[str, tuple]:
                 "ACTION_CONSTRAINT is not supported)")
         out[n] = d.body
     return out
+
+
+def declared_action_properties(names, module: Module) -> Dict[
+        str, ActionProperty]:
+    """The cfg PROPERTY lines that state a specification, `I /\\
+    [][A]_v` with no further conjunct (through the names that only
+    stand for it: `TCSpec == TC!TCSpec`): name -> ActionProperty, in
+    the cfg's order.  I and A have to be defined without parameters and
+    v a variable, a tuple of variables or a definition of one.  A
+    specification with a fairness conjunct is no safety property and is
+    not taken here (the liveness route reads `P ~> Q` alone and says
+    what it skips)."""
+    from .actions import expand_unchanged
+    from .parser import parse_expression
+
+    out: Dict[str, ActionProperty] = {}
+    defs = module.defs
+    for n in names:
+        d = defs.get(n)
+        seen = set()
+        while d is not None and not d.params and d.body[0] == "name" \
+                and d.body[1] in defs and d.body[1] not in seen:
+            seen.add(d.body[1])
+            d = defs[d.body[1]]
+        if d is None or d.params or d.body[0] != "spec" or d.body[4]:
+            continue
+        _, init, next_, sub, _ = d.body
+
+        def refuse(why):
+            raise StructLoadError(f"PROPERTY {n} ({d.name}): {why}")
+
+        for part in (init, next_):
+            if part is None or part not in defs or defs[part].params:
+                refuse(f"`{part}` is not a definition without "
+                       "parameters")
+        try:
+            sub_ast = parse_expression(sub)
+        except StructParseError as e:
+            refuse(f"cannot read the subscript `{sub}`: {e}")
+        items = sub_ast[1] if sub_ast[0] == "tuple" else [sub_ast]
+        if not all(x[0] == "name" for x in items):
+            refuse(f"the subscript `{sub}` is not a tuple of variables")
+        subs = tuple(expand_unchanged([x[1] for x in items], defs,
+                                      module.variables))
+        stray = [v for v in subs if v not in module.variables]
+        if stray or not subs:
+            refuse(f"the subscript `{sub}` names {stray or 'nothing'}, "
+                   "no variable of the module")
+        out[n] = ActionProperty(
+            name=n, init=defs[init].body, action=defs[next_].body,
+            sub=subs, init_name=init, action_name=next_, sub_text=sub)
+    return out
+
+
+def action_property_names(cfg_path: str) -> Tuple[str, ...]:
+    """The names of the cfg's PROPERTY lines that are action properties
+    (`I /\\ [][A]_v`), read off the parsed texts alone - for a frontend
+    that cannot judge one and has to say so by name
+    (frontend.model.resolve); () where there is none, or where the cfg
+    or a module does not load here (that frontend's own error stands)."""
+    try:
+        with open(cfg_path, encoding="utf-8") as f:
+            cfg = _parsed("cfg", f.read())
+        if not cfg.properties:
+            return ()
+        model_dir = os.path.dirname(os.path.abspath(cfg_path))
+        dirs = (model_dir, os.path.dirname(os.path.dirname(model_dir)))
+        root = os.path.join(model_dir, "MC.tla")
+        if not os.path.exists(root):
+            root = os.path.join(
+                model_dir,
+                os.path.splitext(os.path.basename(cfg_path))[0] + ".tla")
+        module = _load_module_closure(root, dirs)
+        return tuple(declared_action_properties(cfg.properties, module))
+    except (OSError, CfgError, StructLoadError, StructParseError):
+        return ()
 
 
 def _action_labels(ast, defs, stop: Optional[str] = None):
@@ -278,10 +402,97 @@ def _parsed(kind: str, src: str):
     return hit
 
 
+def _find_module(name: str, search_dirs, why: str) -> str:
+    for d in search_dirs:
+        cand = os.path.join(d, f"{name}.tla")
+        if os.path.exists(cand):
+            return cand
+    raise StructLoadError(
+        f"{why} {name}: no {name}.tla in {list(search_dirs)}")
+
+
+def _prefixed(ast, names, prefix: str):
+    """`ast` of an instanced module with every reference to one of the
+    module's own definitions (`names`) renamed `prefix + name`: the
+    name and call nodes, UNCHANGED's list, and the names a
+    specification's normal form holds.  Nothing else is a reference: a
+    string, a record's field, a bound variable stay (TLA+ lets no
+    bound variable shadow a definition)."""
+    def ref(x):
+        return prefix + x if x in names else x
+
+    if isinstance(ast, list):
+        return [_prefixed(x, names, prefix) for x in ast]
+    if not isinstance(ast, tuple) or not ast:
+        return ast
+    op = ast[0]
+    if op in ("name", "call") and len(ast) >= 2 and isinstance(ast[1], str):
+        return (op, ref(ast[1])) + tuple(
+            _prefixed(x, names, prefix) for x in ast[2:])
+    if op == "unchanged" and len(ast) == 2 and isinstance(ast[1], list):
+        return (op, [ref(x) for x in ast[1]])
+    if op == "spec" and len(ast) == 5:
+        _, init, next_, sub, conjuncts = ast
+        return (op, ref(init), ref(next_), ref(sub), tuple(
+            (kind, csub if csub is None else ref(csub),
+             body if body is None else ref(body), text)
+            for kind, csub, body, text in conjuncts))
+    return tuple(_prefixed(x, names, prefix)
+                 if isinstance(x, (tuple, list)) else x for x in ast)
+
+
+def _instantiate(inst: Module, prefix: str, into: str, declared) -> list:
+    """The definitions `N == INSTANCE M` (prefix `N!`; a bare INSTANCE
+    has none) adds to the instancing module `into`: M's, each under its
+    prefixed name with its references to M's own definitions prefixed
+    alike.  Without a WITH clause M's constants and variables are the
+    instancing module's of the same name, so each has to be `declared`
+    there (a constant, a variable or a definition of that name)."""
+    missing = [x for x in inst.constants + inst.variables
+               if x not in declared]
+    if missing:
+        raise StructLoadError(
+            f"INSTANCE {inst.name} in {into}: {', '.join(missing)} of "
+            f"{inst.name} has no constant, variable or definition of "
+            f"that name in {into} (a WITH clause is not supported)")
+    names = set(inst.defs)
+    return [Definition(prefix + d, inst.defs[d].params,
+                       _prefixed(inst.defs[d].body, names, prefix))
+            for d in inst.def_order]
+
+
+def _require_instances(module: Module) -> None:
+    """Every `N!Op` a definition names has to be a definition an
+    INSTANCE made: an unknown N, or an Op the instanced module lacks, is
+    a load error that says so - not an unknown name the day a state
+    reaches it."""
+    known = {n for n, _ in module.instances if n}
+    stack = [(d.name, d.body) for d in module.defs.values()]
+    while stack:
+        where, node = stack.pop()
+        if isinstance(node, tuple) and len(node) >= 2 \
+                and node[0] in ("name", "call") \
+                and isinstance(node[1], str) and "!" in node[1] \
+                and node[1] not in module.defs:
+            n = node[1].split("!")[0]
+            raise StructLoadError(
+                f"{where}: {node[1]}: " + (
+                    f"the module {n} instances has no such definition"
+                    if n in known else
+                    f"no `{n} == INSTANCE ...` in the module (it has "
+                    f"{sorted(known) or 'none'})"))
+        if isinstance(node, (tuple, list)):
+            stack.extend((where, x) for x in node
+                         if isinstance(x, (tuple, list)))
+
+
 def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
     """Parse `path` and fold in its non-builtin EXTENDS (depth-first,
-    extended defs first so the extender can override).  `texts`, when
-    given, collects every (path, source) read - the digest input."""
+    extended defs first so the extender can override) and the modules
+    it instances (`_instantiate`; host span `build.struct.instance`, its
+    `memo` says whether this process had the substitution already).
+    `texts`, when given, collects every (path, source) read - the
+    digest input, instanced modules included."""
     with open(path) as f:
         src = f.read()
     if texts is not None:
@@ -291,8 +502,10 @@ def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
     def_order = []
     variables = []
     constants = []
+    instances = list(root.instances)
 
     def fold(mod: Module):
+        instances.extend(i for i in mod.instances if i not in instances)
         for d in mod.def_order:
             if d not in defs:
                 def_order.append(d)
@@ -307,18 +520,37 @@ def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
     for ext in root.extends:
         if ext in _BUILTIN_MODULES:
             continue
-        found = None
-        for d in search_dirs:
-            cand = os.path.join(d, f"{ext}.tla")
-            if os.path.exists(cand):
-                found = cand
-                break
-        if found is None:
-            raise StructLoadError(
-                f"EXTENDS {ext}: no {ext}.tla in {list(search_dirs)}"
-            )
-        fold(_load_module_closure(found, search_dirs, texts))
+        fold(_load_module_closure(
+            _find_module(ext, search_dirs, "EXTENDS"), search_dirs, texts))
     fold(root)
+    for as_name, modname in root.instances:
+        if modname in _BUILTIN_MODULES:
+            continue
+        with span("build.struct.instance") as sp:
+            sp.attrs["module"] = modname
+            mine: list = []
+            inst = _load_module_closure(
+                _find_module(modname, search_dirs, "INSTANCE"),
+                search_dirs, mine)
+            if texts is not None:
+                texts.extend(mine)
+            prefix = f"{as_name}!" if as_name else ""
+            declared = set(constants) | set(variables) | set(defs)
+            digest = hashlib.sha256()
+            for _, text in mine:
+                digest.update(text.encode() + b"\x00")
+            key = ("instance", digest.hexdigest(), prefix, root.name,
+                   tuple(sorted(declared & set(
+                       inst.constants + inst.variables))))
+            added = cache.spec_kept("text", key)
+            sp.attrs["memo"] = "miss" if added is None else "hit"
+            if added is None:
+                added = _instantiate(inst, prefix, root.name, declared)
+                cache.spec_keep("text", key, added)
+        for d in added:
+            if d.name not in defs:
+                def_order.append(d.name)
+            defs[d.name] = d
     return Module(
         name=root.name,
         extends=root.extends,
@@ -326,6 +558,7 @@ def _load_module_closure(path: str, search_dirs, texts=None) -> Module:
         variables=tuple(variables),
         defs=defs,
         def_order=tuple(def_order),
+        instances=tuple(instances),
     )
 
 
@@ -413,6 +646,7 @@ def _load(cfg_path: str, const_overrides: Optional[Dict[str, object]]
         children.append(span(name, memo="miss"))
         return children[-1]
 
+    _require_instances(module)
     constants: Dict[str, object] = {}
     for name, val in cfg.constants.items():
         constants[name] = _parse_const_literal(val)
@@ -465,15 +699,17 @@ def _load(cfg_path: str, const_overrides: Optional[Dict[str, object]]
         raise StructLoadError(
             f"cannot resolve Init/Next ({init_name}/{next_name})"
         )
+    action_props = declared_action_properties(cfg.properties, module)
     # host span `build.struct.fairness`: the formula's fairness
-    # conjuncts resolved to action labels.  Only a PROPERTY reads them:
-    # a safety-only check loads whatever the formula says
+    # conjuncts resolved to action labels.  Only a temporal PROPERTY
+    # reads them: a safety-only check - an action property is one -
+    # loads whatever the formula says
     with child("build.struct.fairness") as sp:
         try:
             fairness = declared_fairness(conjuncts, next_name, subscript,
                                          module)
         except StructLoadError:
-            if cfg.properties:
+            if any(p not in action_props for p in cfg.properties):
                 raise
             fairness = ()
         sp.attrs["names"] = " ".join(a for a, _ in fairness)
@@ -502,7 +738,9 @@ def _load(cfg_path: str, const_overrides: Optional[Dict[str, object]]
     model = StructModel(
         system=ActionSystem(ev, module.variables, init_name, next_name),
         invariants=invariants,
-        properties=_named_defs(cfg.properties),
+        properties=_named_defs(
+            p for p in cfg.properties if p not in action_props),
+        action_props=action_props,
         constants=constants,
         module=module,
         fairness=fairness,

@@ -16,6 +16,13 @@ expanded; deadlock is judged on the successors before the constraint;
 an initial state outside it is checked and not kept (the rule of
 engine.backend's expand stage, which this search is the host oracle
 of).
+
+Under action properties (`action_props`, the loader's name ->
+ActionProperty: a cfg PROPERTY `I /\\ [][A]_v`) I is judged on every
+initial state and `A \\/ v' = v` on EVERY kept successor row, to new and
+to seen states alike, A read as a formula over the pair of states by
+the evaluator itself (a primed variable is the successor's value); the
+first edge that fails is kept beside the violation (`bad_edge`).
 """
 
 from __future__ import annotations
@@ -43,6 +50,12 @@ class StructBFSResult(NamedTuple):
     # initial states it rejected (checked, never kept)
     discarded: int = 0
     discarded_inits: int = 0
+    # under action properties: the edges judged, those on which a
+    # property's subscript changed, and the first edge that failed one,
+    # (source, label, successor) - its violation names the property
+    edges: int = 0
+    moved: int = 0
+    bad_edge: Optional[Tuple[tuple, str, tuple]] = None
 
 
 def bfs(
@@ -54,6 +67,7 @@ def bfs(
     stop_on_violation: bool = True,
     collect_states: bool = False,
     constraints: Optional[Dict[str, tuple]] = None,
+    action_props: Optional[Dict[str, object]] = None,
 ) -> StructBFSResult:
     ev = system.ev
     inits = system.initial_states()
@@ -89,6 +103,26 @@ def bfs(
         return all(ev.eval(ast, env) is True
                    for ast in constraints.values())
 
+    edges = moved = 0
+    bad_edge = None
+
+    def judge_edge(s: tuple, label: str, t: tuple):
+        nonlocal edges, moved, bad_edge
+        edges += 1
+        env = dict(ev.constants)
+        env.update(zip(system.variables, s))
+        primed = dict(zip(system.variables, t))
+        for prop in action_props.values():
+            if all(env[v] == primed[v] for v in prop.sub):
+                continue
+            moved += 1
+            if ev.eval(prop.action, env, primed) is not True:
+                violations.append((
+                    f"Property {prop.name} is violated: a step is not "
+                    f"a step of {prop.text}", t))
+                if bad_edge is None:
+                    bad_edge = (s, label, t)
+
     discarded = discarded_inits = 0
     for s in inits:
         generated += 1
@@ -104,6 +138,13 @@ def bfs(
             if keep_parents:
                 parents[s] = (None, None)
             check_invs(s)
+            for prop in (action_props or {}).values():
+                env = dict(ev.constants)
+                env.update(zip(system.variables, s))
+                if ev.eval(prop.init, env) is not True:
+                    violations.append((
+                        f"Property {prop.name} is violated: an initial "
+                        f"state does not satisfy {prop.text}", s))
     depth = 1
     levels = [len(frontier)]
     max_out, min_out = 0, 1 << 30
@@ -129,6 +170,8 @@ def bfs(
                 if t not in seen and not kept(t):
                     discarded += 1
                     continue
+                if action_props:
+                    judge_edge(s, label, t)
                 if t not in seen:
                     if len(seen) >= max_states:
                         raise RuntimeError("state-space bound exceeded")
@@ -156,6 +199,9 @@ def bfs(
         states=seen if collect_states else None,
         discarded=discarded,
         discarded_inits=discarded_inits,
+        edges=edges,
+        moved=moved,
+        bad_edge=bad_edge,
     )
 
 
@@ -273,17 +319,26 @@ def check_leads_to(system: ActionSystem, p_ast, q_ast, name: str = "",
 def violation_trace(system: ActionSystem, invariants: Dict[str, tuple],
                     check_deadlock: bool = True,
                     max_states: int = 10_000_000,
-                    constraints: Optional[Dict[str, tuple]] = None):
+                    constraints: Optional[Dict[str, tuple]] = None,
+                    action_props: Optional[Dict[str, object]] = None):
     """(kind, [(state, label|None), ...]) for the first violation, or
-    None - the trace-explorer re-run over the structural relation."""
+    None - the trace-explorer re-run over the structural relation.  An
+    action property that fails on an edge ends the trace with that
+    edge: the path to its source, then its successor."""
     r = bfs(system, invariants, check_deadlock=check_deadlock,
             max_states=max_states, keep_parents=True,
-            constraints=constraints)
+            constraints=constraints, action_props=action_props)
     if not r.violations:
         return None
     kind, bad = r.violations[0]
     chain = []
     cur: Optional[tuple] = bad
+    if r.bad_edge is not None and bad == r.bad_edge[2] \
+            and kind.startswith("Property "):
+        # the successor may have been reached before, by another path:
+        # the trace is the failing edge's own
+        chain.append((bad, r.bad_edge[1]))
+        cur = r.bad_edge[0]
     while cur is not None:
         parent, label = r.parents[cur]
         chain.append((cur, label))

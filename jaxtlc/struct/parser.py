@@ -37,6 +37,12 @@ AST nodes are plain tuples (texpr-compatible where the form overlaps):
   ("choose", var, dom|None, pred)
   ("forall", [vars], dom, body) ("exists", [vars], dom, body)
   ("unchanged", [names]) ("domain", e) ("subset", e) ("atref",)
+  ("recfn", f, var, dom, body) a LET's `f[var \\in dom] == body`, which
+                               may apply f to smaller arguments
+  ("spec", init, next, subscript, conjuncts)  `Init /\\ [][Next]_sub ...`
+A name of an instanced module, `TC!TCSpec`, is one name: ("name",
+"TC!TCSpec") / ("call", "TC!Op", [args]); the loader defines it
+(loader._instantiate).
 """
 
 from __future__ import annotations
@@ -200,6 +206,9 @@ class Module(NamedTuple):
     variables: Tuple[str, ...]  # declaration order
     defs: Dict[str, Definition]
     def_order: Tuple[str, ...]
+    # `N == INSTANCE M` as (N, M), a bare `INSTANCE M` as (None, M), in
+    # the module's order; the loader finds M and defines N!Op
+    instances: Tuple[Tuple[Optional[str], str], ...] = ()
 
 
 _DECL_KEYWORDS = {
@@ -216,8 +225,20 @@ def parse_module(src: str) -> Module:
     variables: List[str] = []
     defs: Dict[str, Definition] = {}
     def_order: List[str] = []
+    instances: List[Tuple[Optional[str], str]] = []
 
     i, n = 0, len(toks)
+
+    def instance_of(ts: List[Tok], who: str) -> str:
+        """The module of `INSTANCE M` (ts starts after the keyword)."""
+        if not ts or ts[0].kind != "name":
+            raise StructParseError(f"{who}: INSTANCE names no module")
+        if len(ts) > 1:
+            raise StructParseError(
+                f"{who}: INSTANCE {ts[0].val} {ts[1].val} ...: a WITH "
+                "clause is not supported (constants and variables of "
+                "the same name are the only substitution)")
+        return ts[0].val
 
     def is_def_start(j: int) -> bool:
         """name at column 0 followed by `==` or `(p, ..) ==`."""
@@ -286,9 +307,15 @@ def parse_module(src: str) -> Module:
                     i += 1
                 else:
                     break
-        elif t.kind == "name" and t.val in ("ASSUME", "ASSUMPTION") \
-                and t.col == 0:
-            i = unit_end(i + 1)  # assumptions are not checked here
+        elif t.kind == "name" and t.val in ("ASSUME", "ASSUMPTION",
+                                            "THEOREM") and t.col == 0:
+            # assumptions are not checked here, theorems not proved
+            i = unit_end(i + 1)
+        elif t.kind == "name" and t.val == "INSTANCE" and t.col == 0:
+            end = unit_end(i + 1)
+            instances.append((None, instance_of(toks[i + 1:end],
+                                                "INSTANCE")))
+            i = end
         elif is_def_start(i):
             dname = t.val
             j = i + 1
@@ -309,7 +336,13 @@ def parse_module(src: str) -> Module:
             j += 1
             end = unit_end(j)
             body_toks = toks[j:end]
-            if dname == "Spec":
+            if body_toks and body_toks[0].kind == "name" \
+                    and body_toks[0].val == "INSTANCE" and not params:
+                instances.append((dname, instance_of(body_toks[1:],
+                                                     dname)))
+                i = end
+                continue
+            if dname == "Spec" or _spec_shaped(body_toks):
                 body = _parse_spec_body(body_toks)
             else:
                 body = _ExprParser(body_toks).parse_full()
@@ -329,7 +362,33 @@ def parse_module(src: str) -> Module:
         variables=tuple(variables),
         defs=defs,
         def_order=tuple(def_order),
+        instances=tuple(instances),
     )
+
+
+def _qualified(toks: List[Tok]) -> List[Tok]:
+    """`toks` with each `N ! Op` run of an instanced module's name as
+    one name token, `N!Op`."""
+    out: List[Tok] = []
+    for t in toks:
+        if len(out) >= 2 and t.kind == "name" and out[-1].val == "!" \
+                and out[-1].kind == "sym" and out[-2].kind == "name":
+            out[-2:] = [out[-2]._replace(val=out[-2].val + "!" + t.val)]
+        else:
+            out.append(t)
+    return out
+
+
+def _spec_shaped(toks: List[Tok]) -> bool:
+    """Does a definition's body hold `[][A]_sub` - is it a
+    specification, whatever its name (PCSpec, TCSpec)?"""
+    toks = _qualified(toks)
+    return any(
+        t.kind == "box" and k + 4 < len(toks)
+        and toks[k + 1].val == "[" and toks[k + 2].kind == "name"
+        and toks[k + 3].val == "]" and toks[k + 4].kind == "name"
+        and toks[k + 4].val.startswith("_")
+        for k, t in enumerate(toks))
 
 
 def _parse_spec_body(toks: List[Tok]) -> tuple:
@@ -343,7 +402,7 @@ def _parse_spec_body(toks: List[Tok]) -> tuple:
     each means is the loader's to say (loader.declared_fairness)."""
     parts: List[List[Tok]] = [[]]
     depth = 0
-    for t in toks:
+    for t in _qualified(toks):
         if t.kind == "land" and depth == 0:
             parts.append([])
             continue
@@ -732,6 +791,7 @@ class _ExprParser:
             while True:
                 dname = self.expect("name").val
                 params: List[str] = []
+                fn_of = None
                 if self.peek().kind == "sym" and self.peek().val == "(":
                     self.next()
                     while self.peek().kind == "name":
@@ -740,8 +800,22 @@ class _ExprParser:
                                 and self.peek().val == ",":
                             self.next()
                     self.expect(")")
+                elif self.peek().kind == "sym" and self.peek().val == "[":
+                    # `Max[T \in SUBSET S] == ...`: a function defined
+                    # by recursion on its argument
+                    self.next()
+                    var = self.expect("name").val
+                    op = self.next()
+                    if (op.kind, op.val) != ("op", r"\in"):
+                        raise StructParseError(
+                            f"expected \\in in the function definition "
+                            f"{dname}[...] (line {op.line})")
+                    fn_of = (var, self.parse_expr())
+                    self.expect("]")
                 self.expect("defeq", "==")
                 body = self.parse_expr()
+                if fn_of is not None:
+                    body = ("recfn", dname, fn_of[0], fn_of[1], body)
                 binds.append((dname, tuple(params), body))
                 nt = self.peek()
                 if nt.kind == "name" and nt.val == "IN":
@@ -780,6 +854,15 @@ class _ExprParser:
         if v == "SUBSET":
             # the powerset, a prefix operator that binds like DOMAIN
             return ("subset", self.parse_postfix())
+        while self.peek().kind == "sym" and self.peek().val == "!":
+            # `TC!TCSpec`: a definition of an instanced module, one name
+            # (an EXCEPT's `!` follows EXCEPT or a comma, never a name)
+            nxt = self.toks[self.i + 1] if self.i + 1 < len(self.toks) \
+                else _EOF
+            if nxt.kind != "name":
+                break
+            self.next()
+            v = v + "!" + self.next().val
         if self.peek().kind == "sym" and self.peek().val == "(":
             self.next()
             args = [self._parse_arg()]
@@ -811,12 +894,15 @@ class _ExprParser:
         if j >= len(toks) or toks[j].kind != "name":
             return False
         j += 1
-        if j < len(toks) and toks[j].kind == "sym" and toks[j].val == "(":
+        if j < len(toks) and toks[j].kind == "sym" \
+                and toks[j].val in ("(", "["):
+            opens = toks[j].val
+            closes = ")" if opens == "(" else "]"
             depth = 0
             while j < len(toks):
-                if toks[j].val == "(":
+                if toks[j].val == opens:
                     depth += 1
-                elif toks[j].val == ")":
+                elif toks[j].val == closes:
                     depth -= 1
                     if depth == 0:
                         j += 1

@@ -778,6 +778,22 @@ class ShapeInference:
             return self._call_shape(ast, env)
         if op == "unchanged":
             return SBool()
+        if op == "recfn":
+            # a LET's `f[x \\in S] == e` (PaxosCommit's Max): the shape
+            # of its values, e's own with f's applications inside it
+            # read at the join so far, to the fixpoint
+            _, name, var, dom_ast, body = ast
+            elem = self._elem_shape(self._abstract(dom_ast, env))
+            val = None
+            for _ in range(8):
+                env2 = dict(env)
+                self._bind(env2, var, elem)
+                self._bind(env2, name, SFun((), val, False))
+                new = join(val, self._abstract(body, env2))
+                if new == val:
+                    break
+                val = new
+            return SFun((), val, False)
         raise ShapeError(f"cannot abstract {op!r}")
 
     def _atoms_of(self, sh) -> Optional[FrozenSet[str]]:

@@ -6,6 +6,8 @@
 (* on a spec it did not birth (VERDICT r4 item 8).  A transaction manager  *)
 (* collects readiness votes from resource managers and broadcasts the      *)
 (* verdict; resource managers may unilaterally abort while still working.  *)
+(* This repo's own module: NOT tlaplus/Examples' TwoPhase.tla, whose       *)
+(* family (transaction_commit) sits in specs/PaxosCommit.toolbox.          *)
 (***************************************************************************)
 EXTENDS Naturals, FiniteSets, TLC
 

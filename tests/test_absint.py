@@ -420,7 +420,7 @@ def test_lintgate_specs_tree_clean():
     rc = run_gate("specs", out=out)
     text = out.getvalue()
     assert rc == 0, text
-    assert "lint gate: 12 spec(s)" in text
+    assert "lint gate: 13 spec(s)" in text
     # a model bounded by its cfg's CONSTRAINT passes with no finding
     assert "EWD998.toolbox/Model_1/MC.cfg: ok" in text
     # so does one whose state is FIFO channels of records (ISSUE 45)

@@ -44,6 +44,13 @@ ROUTES = {
         frontend="struct", chunk=256, qcap=1 << 13, fpcap=1 << 15,
         constants={"N": 2}),
         COMMIT + ("jaxtlc.step.struct", "jaxtlc.constraint")),
+    # a cfg whose PROPERTY is an action property (ISSUE 48): judged on
+    # every generated edge inside the expand stage
+    "refinement": (dict(config=os.path.join(
+        REPO, "specs", "PaxosCommit.toolbox", "Model_1", "MC.cfg"),
+        frontend="struct", chunk=256, qcap=1 << 12, fpcap=1 << 14,
+        constants={"RM": frozenset({"r1"})}),
+        COMMIT + ("jaxtlc.step.struct", "jaxtlc.actionprop")),
     "mesh": (dict(config=KUBEAPI, frontend="hand", sharded=4, chunk=128,
                   qcap=1 << 11, fpcap=1 << 13,
                   constants=dict(FF, N_RECONCILERS=1, N_BINDERS=1)),
