@@ -18,9 +18,12 @@ that takes the carry copies it):
   dynamic-update-slice.  States are unpacked to field vectors only at
   the kernel boundary (codec.unpack); fingerprints ride the MXU.
 * The commit dedups the chunk*L candidates (fpset.fpset_insert_sorted:
-  two stable sorts), probes only the unique ones and
-  writes the table by one scatter-add of whole bucket rows; enqueue and
-  per-new-state statistics run over compacted probe-width segments.
+  two stable sorts, the second over the valid lanes alone), probes only
+  the unique ones and writes the table by one scatter-add of whole
+  bucket rows; enqueue and per-new-state statistics run over compacted
+  probe-width segments, the enqueue's order sorted at the width of the
+  representatives (fpset.enqueue_order: a `lax.switch` that holds sorts
+  only).
 * Every loop is a `while` (`run_steps`; the two pop widths at chunk >=
   2^14 are two inner loops): no conditional holds the carry.  The stages
   are device scopes (`jaxtlc.expand`, `.dedup`, `.fpset`, `.enqueue`, `.level`).
@@ -53,7 +56,7 @@ from jax import lax
 from ..config import ModelConfig
 from ..spec.labels import LABELS
 from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED, fp64_words_mxu
-from .fpset import fpset_insert_sorted, fpset_new
+from .fpset import enqueue_order, fpset_insert_sorted, fpset_new
 
 # violation codes
 OK = 0
@@ -647,7 +650,8 @@ def make_stage_pair(
                 fp_capacity * fp_highwater
             )
             insert_mask = ex.valid & ~fp_full
-        # the in-batch dedup (two sorts at candidate width); the probe /
+        # the in-batch dedup (the grouping sort at candidate width, the
+        # compaction at the width of the valid lanes); the probe /
         # claim inside it is `jaxtlc.fpset` (_probe_block), so a trace
         # attributes an op to the innermost of the two
         with jax.named_scope("jaxtlc.dedup"):
@@ -665,34 +669,10 @@ def make_stage_pair(
             # and therefore in-batch attribution statistics (outdegree
             # min/max, MC.out:1104) are preserved bit-for-bit.  All new
             # entries sit in the first nreps compacted positions, so
-            # when nreps fits the probe width the sort runs at R width
-            # instead of ncand (~6x less comparator traffic); the
-            # full-width branch covers all-distinct bursts.
-            new_key = (~is_new_c).astype(jnp.uint32)
-            cidx_u = c_idx.astype(jnp.uint32)
-
-            def e_sorted_sliced(_):
-                _, e = lax.sort(
-                    (new_key[:R], cidx_u[:R]), num_keys=2, is_stable=True
-                )
-                return jnp.concatenate(
-                    [e, jnp.zeros(ncand - R, jnp.uint32)]
-                )
-
-            def e_sorted_full(_):
-                _, e = lax.sort(
-                    (new_key, cidx_u), num_keys=2, is_stable=True
-                )
-                return e
-
-            if R == ncand:
-                _, e_idx = lax.sort(
-                    (new_key, cidx_u), num_keys=2, is_stable=True
-                )
-            else:
-                e_idx = lax.cond(
-                    nreps <= R, e_sorted_sliced, e_sorted_full, 0
-                )
+            # the sort runs at the rung that holds nreps - the probe
+            # width R first (~6x less comparator traffic than ncand),
+            # the whole array last: all-distinct bursts stay exact.
+            e_idx = enqueue_order(is_new_c, c_idx, nreps, R)
             e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
 
             def enq_cond(st):
@@ -1494,7 +1474,7 @@ def make_enumerator(
         lo, hi = fp64_words_mxu(packed, nbits, fp_index, seed)
 
         fp_full = (c.tail + ncand) > int(fp_capacity * fp_highwater)
-        fps, is_new_c, c_idx, _ = fpset_insert_sorted(
+        fps, is_new_c, c_idx, nreps = fpset_insert_sorted(
             c.fps, lo, hi, fvalid & ~fp_full, probe_width=R, claim_width=R
         )
         n_new = is_new_c.sum().astype(jnp.int32)
@@ -1502,11 +1482,7 @@ def make_enumerator(
 
         # append new states at the tail in candidate order (the engines'
         # sort-compact + A-wide contiguous-write pattern)
-        _, e_idx = lax.sort(
-            ((~is_new_c).astype(jnp.uint32), c_idx.astype(jnp.uint32)),
-            num_keys=2,
-            is_stable=True,
-        )
+        e_idx = enqueue_order(is_new_c, c_idx, nreps, R)
         e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
 
         def enq_cond(st):

@@ -25,7 +25,16 @@ flattened by XLA first, two whole-table relayouts (6.1 ms) a call.
   fingerprint to (1,0) first), so validity costs no extra sort key -
   3 arrays / 2 keys per comparator pass.  A second stable 1-key sort
   compacts the group representatives to the front, so the probe phase
-  touches O(unique) rows, not O(batch).
+  touches O(unique) rows, not O(batch).  The grouping leaves the valid
+  lanes as the LAST rows of its order, so the compaction sorts that
+  tail alone, at the narrowest of a short ladder of static power-of-two
+  widths that holds it (`sort_ladder`, `sort_live`: a sort is priced by
+  the power of two above its width, and a fifth to a half of the lanes
+  are valid).  Rows past the `nreps` representatives are therefore not
+  a permutation's rest: inside the rung they hold the non-representative
+  lanes, past it (0, 0) words and the out-of-range lane n.  Nothing
+  reads them: the probe masks by `nreps`, a scatter by c_idx drops lane
+  n, the enqueue's key is 1 there.
 * **Conflict-free claims**: because compacted candidates arrive sorted,
   same-bucket claimants are adjacent runs; each claimant takes slot
   ``occupancy + rank-in-run``, so round-0 insertions cannot collide - no
@@ -559,11 +568,73 @@ def _probe_segments(table, c_lo, c_hi, active, n_rows, R: int, C: int):
     return table, is_new_p[:n]
 
 
+LADDER_FLOOR = 16384  # no power-of-two rung is narrower
+LADDER_RUNGS = 4  # a commit sort is instantiated at most this often
+
+
+def sort_ladder(n: int, first: int = 0) -> Tuple[int, ...]:
+    """The static widths an n-lane commit sort may run at, ascending;
+    the last is n, the whole array, so the worst case is the sort as it
+    stood before there was a ladder.  A sort is priced by the power of
+    two above its width (PERF.md section 5, PR 49), so the rungs in
+    front are powers of two from LADDER_FLOOR below n: with no `first`
+    (the dedup's compaction, whose live part is a share of n) the
+    widest LADDER_RUNGS - 1 of them; with `first` (the enqueue's order,
+    whose live part is a few chunks: `first` = the probe width) that
+    width and the doublings above it.  An array of 2 * LADDER_FLOOR
+    lanes or fewer gets no power of two: a whole sort there is tens of
+    microseconds, a conditional buys nothing, and under a vmap
+    (serve/sweep.py) a switch on a batched count runs every rung."""
+    pows = []
+    if n > 2 * LADDER_FLOOR:
+        w = LADDER_FLOOR
+        while w < n:
+            if w > first:
+                pows.append(w)
+            w *= 2
+    if 0 < first < n:
+        return (first,) + tuple(pows[: LADDER_RUNGS - 2]) + (n,)
+    return tuple(pows[-(LADDER_RUNGS - 1):]) + (n,)
+
+
+def sort_live(operands, num_keys: int, n_live, widths, fill, tail=False):
+    """Stable `lax.sort` of `operands` ([n] each) whose live part is
+    known to be the first - `tail`: the last - `n_live` rows, every
+    other row sorting behind the rows a reader looks at: run on the
+    static slice of the narrowest of `widths` (a `sort_ladder`) that
+    holds n_live, by `lax.switch` on the traced count.  Hands back, at
+    the old shape [n], the sorted operands whose `fill` is not None,
+    the rows past the rung holding that operand's `fill`.  One rung:
+    the plain sort, no conditional."""
+    n = operands[0].shape[0]
+    kept = [i for i, f in enumerate(fill) if f is not None]
+
+    def rung(w):
+        def run(ops):
+            cut = [o[n - w:] if tail else o[:w] for o in ops]
+            out = lax.sort(tuple(cut), num_keys=num_keys, is_stable=True)
+            return tuple(
+                out[i] if w == n else jnp.concatenate(
+                    [out[i], jnp.full(n - w, fill[i], out[i].dtype)])
+                for i in kept
+            )
+        return run
+
+    if len(widths) == 1:
+        return rung(n)(operands)
+    at = sum((n_live > w).astype(jnp.int32) for w in widths[:-1])
+    return lax.switch(at, [rung(w) for w in widths], operands)
+
+
 def _sorted_order(lo, hi):
-    """The in-batch dedup's ordering: two stable sorts over all n
-    candidate lanes of already MIXED, remapped, mask-zeroed fingerprint
-    words give (c_lo, c_hi, c_idx int32, nreps), the distinct
-    representatives compacted fp-ascending into the first nreps rows.
+    """The in-batch dedup's ordering: two stable sorts of already
+    MIXED, remapped, mask-zeroed fingerprint words give (c_lo, c_hi,
+    c_idx int32, nreps), the distinct representatives compacted
+    fp-ascending into the first nreps rows.  The grouping sort sees all
+    n candidate lanes; the compaction runs at the rung of
+    `sort_ladder(n)` that holds the valid lanes, so rows past nreps are
+    non-representatives and then, past the rung, (0, 0) words with the
+    out-of-range lane n - nobody reads them.
     On the chip a sort at candidate width is cheap and an element
     gather or scatter there is not (PERF.md section 5, PR 38, one
     TPU v5e: at 196,608 lanes the two sorts cost 0.20 and 0.24 ms a
@@ -583,16 +654,35 @@ def _sorted_order(lo, hi):
             jnp.ones(1, bool),
         ]
     )
-    rep = ((s_hi != 0) | (s_lo != 0)) & last
+    valid = (s_hi != 0) | (s_lo != 0)
+    rep = valid & last
 
     # sort 2: compact representatives to the front (stable single-key sort
-    # keeps them fingerprint-sorted - required by _probe_block's rank math)
+    # keeps them fingerprint-sorted - required by _probe_block's rank math).
+    # The valid lanes are the LAST valid.sum() rows of the grouped order,
+    # and a few tens of percent of it (the cells' lane_live_pct): the sort
+    # runs over that tail alone
     nonrep = (~rep).astype(jnp.uint32)
-    _, c_lo, c_hi, c_idx = lax.sort(
-        (nonrep, s_lo, s_hi, s_idx), num_keys=1, is_stable=True
+    c_lo, c_hi, c_idx = sort_live(
+        (nonrep, s_lo, s_hi, s_idx), 1, valid.sum(), sort_ladder(n),
+        fill=(None, 0, 0, n), tail=True,
     )
     nreps = rep.sum().astype(jnp.int32)
     return c_lo, c_hi, c_idx.astype(jnp.int32), nreps
+
+
+def enqueue_order(is_new_c, c_idx, nreps, first: int):
+    """The new rows' lanes in lane order, [n] uint32 (the engines' append
+    order: `fpset_insert_sorted`'s verdicts sorted by (not new, lane));
+    entries past the new rows are not to be read.  Every new row lies in
+    the first nreps compacted rows, so the sort runs at the rung of
+    `sort_ladder(n, first)` that holds nreps, `first` being the caller's
+    probe width."""
+    (e_idx,) = sort_live(
+        ((~is_new_c).astype(jnp.uint32), c_idx.astype(jnp.uint32)),
+        2, nreps, sort_ladder(c_idx.shape[0], first), fill=(None, 0),
+    )
+    return e_idx
 
 
 def fpset_insert_sorted(
@@ -604,7 +694,11 @@ def fpset_insert_sorted(
     c_idx [N] int32, nreps int32): entry j < nreps of the compacted order
     is the representative of a distinct masked fingerprint, originally at
     lane c_idx[j]; is_new_c[j] says whether it was new to the table.
-    Representatives are fingerprint-sorted (ascending (hi, lo)).
+    Representatives are fingerprint-sorted (ascending (hi, lo)).  Past
+    nreps is_new_c is False and c_idx is NOT the rest of a permutation:
+    a non-representative lane or, past the compaction's rung
+    (`_sorted_order`), the out-of-range lane N - index with a clamp or a
+    drop there, as every caller does.
 
     In-batch duplicates resolve to the highest lane index (stable dedup
     sort), keeping attribution deterministic across engines/backends.
@@ -638,6 +732,7 @@ def fpset_insert(s: FPSet, lo, hi, mask) -> Tuple[FPSet, jnp.ndarray]:
     n = lo.shape[0]
     with jax.named_scope("jaxtlc.dedup"):
         s2, is_new_c, c_idx, _ = fpset_insert_sorted(s, lo, hi, mask)
-    # c_idx is a permutation of the lanes: nothing is dropped
+    # c_idx holds each representative's lane once; past nreps it may hold
+    # the out-of-range lane n (_sorted_order), which the drop leaves out
     is_new = jnp.zeros(n, bool).at[c_idx].set(is_new_c, mode="drop")
     return s2, is_new

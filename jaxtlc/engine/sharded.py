@@ -404,8 +404,10 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int):
             fset, new_k, idx_k, _ = fpset_insert_sorted(
                 FPSet(table), lo_k, hi_k, mask_k)
         with jax.named_scope("jaxtlc.compact"):
-            # idx_k is a permutation of the segment's rows: the compare
-            # and the drop below keep nothing out (ROADMAP C10)
+            # idx_k names each representative's row once; past them a
+            # segment wider than 32,768 rows may hold the out-of-range
+            # row `width` (fpset._sorted_order's pad), which the compare
+            # and the drop below leave out (ROADMAP C10)
             lane_k = jnp.where(
                 idx_k < width,
                 compact_lanes(cnt, k * width + idx_k, bucket), DB)

@@ -29,7 +29,7 @@ from ..engine.bfs import (
     CheckResult,
 )
 from ..engine.fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED, fp64_words_mxu
-from ..engine.fpset import fpset_insert_sorted, fpset_new
+from ..engine.fpset import enqueue_order, fpset_insert_sorted, fpset_new
 from .codec import GenCodec
 from .ir import GenSpec
 from .kernel import GenKernel, initial_field_vectors, make_gen_kernel
@@ -156,7 +156,7 @@ def make_gen_engine(
             fp_capacity * 0.85
         )
         insert_mask = fvalid & ~fp_full
-        fps, is_new_c, c_idx, _ = fpset_insert_sorted(
+        fps, is_new_c, c_idx, nreps = fpset_insert_sorted(
             c.fps, lo, hi, insert_mask, probe_width=R, claim_width=R
         )
         n_new = is_new_c.sum().astype(jnp.int32)
@@ -166,11 +166,7 @@ def make_gen_engine(
         # A-wide segment loop covers bursts where one chunk yields more
         # than A distinct new states (same pattern as bfs.py enq_body -
         # a single A-wide write would silently drop the overflow)
-        _, e_idx = lax.sort(
-            ((~is_new_c).astype(jnp.uint32), c_idx.astype(jnp.uint32)),
-            num_keys=2,
-            is_stable=True,
-        )
+        e_idx = enqueue_order(is_new_c, c_idx, nreps, R)
         e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
 
         def enq_cond(st):

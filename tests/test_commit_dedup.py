@@ -153,6 +153,278 @@ def test_sorted_insert_past_one_probe_block_matches_host_replay(
 
 
 # ---------------------------------------------------------------------------
+# the ladder (ISSUE 49): the second and third sorts at the width of what
+# is live, against host references that share no code with them
+# ---------------------------------------------------------------------------
+
+LADDER_N = 70_000  # sort_ladder: 16,384 / 32,768 / 65,536 / whole
+LADDER_R = 8_192  # the enqueue's: 8,192 / 16,384 / 32,768 / whole
+
+
+def test_the_ladder_is_a_function_of_the_static_widths():
+    """`fpset.sort_ladder`: ascending, at most four rungs, each a power
+    of two of at least 16,384 lanes but the enqueue's first (its probe
+    width) and the last, which is the whole array; 32,768 lanes or
+    fewer get no power of two - the compaction ONE rung (the parent's
+    program: recheck, the pool, serve/sweep's vmap), the enqueue its
+    probe width and the whole (the parent's two-way conditional)."""
+    from jaxtlc.engine.fpset import sort_ladder
+
+    assert sort_ladder(LADDER_N) == (16384, 32768, 65536, LADDER_N)
+    assert sort_ladder(LADDER_N, LADDER_R) == (
+        8192, 16384, 32768, LADDER_N)
+    # the cells' own widths (PERF.md, PR 49 Step 0)
+    assert sort_ladder(200704) == (32768, 65536, 131072, 200704)
+    assert sort_ladder(110592) == (16384, 32768, 65536, 110592)
+    assert sort_ladder(356352) == (65536, 131072, 262144, 356352)
+    assert sort_ladder(196608, 32768) == (32768, 65536, 131072, 196608)
+    for n in (1, 1280, 20480, 32768):
+        assert sort_ladder(n) == (n,)
+        assert sort_ladder(n, n) == (n,)
+    assert sort_ladder(1280, 256) == (256, 1280)
+    assert sort_ladder(32768, 8192) == (8192, 32768)
+    for n in (32769, 65536, 69632, 1 << 20):
+        for first in (0, 4096, 8192):
+            rungs = sort_ladder(n, first)
+            assert rungs[-1] == n and len(rungs) <= 4
+            assert list(rungs) == sorted(set(rungs))
+            for w in rungs[bool(first):-1]:
+                assert w >= 16384 and w & (w - 1) == 0, rungs
+
+
+@pytest.fixture(scope="module")
+def ladder_order():
+    """ONE compile of `_sorted_order` at LADDER_N lanes for every fill
+    share below."""
+    import jax
+
+    from jaxtlc.engine import fpset
+
+    return jax.jit(fpset._sorted_order)
+
+
+def _fill(kind: str, nv: int, n: int):
+    """Stored-form words (what `_sorted_order` is handed: mask-zeroed,
+    never (0, 0) on a valid lane) with `nv` valid lanes scattered over
+    the n, duplicates among them."""
+    rng = np.random.default_rng(nv + 1)
+    lo = np.zeros(n, np.uint32)
+    hi = np.zeros(n, np.uint32)
+    at = rng.permutation(n)[:nv]
+    if kind == "dups":  # one class: a single representative
+        lo[at], hi[at] = 7, 9
+    else:  # about two lanes a class, (hi, lo) both in play
+        lo[at] = rng.integers(1, max(2, nv // 4), nv, dtype=np.uint32)
+        hi[at] = rng.integers(0, 2, nv, dtype=np.uint32)
+    return lo, hi
+
+
+@pytest.mark.parametrize("kind, nv", [
+    ("mixed", 0), ("mixed", 5), ("mixed", 16384), ("mixed", 16385),
+    ("mixed", 32768), ("mixed", 32769), ("mixed", 65536),
+    ("mixed", 65537), ("mixed", LADDER_N), ("dups", 16385),
+    ("dups", LADDER_N)],
+    ids=["no-valid-lane", "five", "rung0-full", "rung0-plus-1",
+         "rung1-full", "rung1-plus-1", "rung2-full", "rung2-plus-1",
+         "every-lane-valid", "all-duplicates", "all-duplicates-whole"])
+def test_ordering_on_every_rung_matches_the_host_reference(
+        ladder_order, kind, nv):
+    """`_sorted_order` at a width whose ladder has four rungs, the
+    valid lanes filling exactly a rung and one past it: rows [0, nreps)
+    are the highest lane of every class in ascending (hi, lo) - a host
+    reference in numpy -, nreps the class count, and no row past nreps
+    names a representative: past the rung every c_idx is the
+    out-of-range lane n (what `fpset_insert`'s and the mesh's drop
+    leave out)."""
+    import jax.numpy as jnp
+
+    from jaxtlc.engine.fpset import sort_ladder
+
+    n = LADDER_N
+    lo, hi = _fill(kind, nv, n)
+    c_lo, c_hi, c_idx, nreps = (
+        np.asarray(x) for x in ladder_order(jnp.asarray(lo),
+                                            jnp.asarray(hi)))
+    last = {}  # class -> its highest valid lane
+    for lane in np.flatnonzero(lo | hi):
+        last[(int(hi[lane]), int(lo[lane]))] = int(lane)
+    want = sorted(last.items())
+    nreps = int(nreps)
+    assert nreps == len(want)
+    assert c_idx[:nreps].tolist() == [lane for _, lane in want]
+    assert list(zip(c_hi[:nreps].tolist(), c_lo[:nreps].tolist())) == [
+        key for key, _ in want]
+    rung = next(w for w in sort_ladder(n) if w >= nv)
+    reps = set(last.values())
+    assert not reps & set(c_idx[nreps:].tolist())
+    assert (c_idx[rung:] == n).all()
+    assert not c_lo[rung:].any() and not c_hi[rung:].any()
+    assert ((c_idx[nreps:rung] >= 0) & (c_idx[nreps:rung] < n)).all()
+
+
+@pytest.fixture(scope="module")
+def ladder_enqueue():
+    """ONE compile of the enqueue's order (`fpset.enqueue_order`, what
+    `bfs.make_stage_pair` calls) at LADDER_N lanes, R = LADDER_R."""
+    import jax
+
+    from jaxtlc.engine.fpset import enqueue_order
+
+    def order(is_new_c, c_idx, nreps):
+        return enqueue_order(is_new_c, c_idx, nreps, LADDER_R)
+
+    return jax.jit(order)
+
+
+@pytest.mark.parametrize("nreps", [
+    0, 1, 8192, 8193, 16384, 16385, 32768, 32769, LADDER_N],
+    ids=["none", "one", "R", "R-plus-1", "rung1-full", "rung1-plus-1",
+         "rung2-full", "rung2-plus-1", "all-distinct-burst"])
+def test_enqueue_order_on_both_sides_of_each_rung(ladder_enqueue, nreps):
+    """The enqueue's order with nreps on both sides of every rung: the
+    new rows' lanes come out ascending in the first n_new entries,
+    whatever the rows past nreps hold (the compaction's pad lane n
+    among them)."""
+    import jax.numpy as jnp
+
+    n = LADDER_N
+    rng = np.random.default_rng(nreps + 3)
+    c_idx = np.full(n, n, np.int32)  # past nreps: nobody's lane
+    c_idx[:nreps] = rng.permutation(n)[:nreps]
+    is_new_c = np.zeros(n, bool)
+    is_new_c[:nreps] = rng.random(nreps) < 0.6
+    e_idx = np.asarray(ladder_enqueue(
+        jnp.asarray(is_new_c), jnp.asarray(c_idx), jnp.int32(nreps)))
+    n_new = int(is_new_c.sum())
+    assert e_idx.shape == (n,)
+    assert e_idx[:n_new].tolist() == sorted(c_idx[is_new_c].tolist())
+
+
+def _rungs_taken(monkeypatch, log):
+    """Wrap `fpset.sort_live` (test side) so a run leaves, per call
+    site, the rung every step took."""
+    import jax
+
+    from jaxtlc.engine import fpset
+
+    real = fpset.sort_live
+
+    def spy(operands, num_keys, n_live, widths, fill, tail=False):
+        if len(widths) > 1:
+            jax.debug.callback(
+                lambda v, site=(tail, widths): log.append(
+                    (site, next(w for w in site[1] if w >= int(v)))),
+                n_live)
+        return real(operands, num_keys, n_live, widths, fill, tail)
+
+    monkeypatch.setattr(fpset, "sort_live", spy)
+
+
+def _live_queue(carry):
+    """The rows of the queue a run can still read: what is left of the
+    level being popped, then the level being built, in order."""
+    q = np.asarray(carry.queue)
+    par = int(carry.parity)
+    return np.concatenate([
+        q[par, int(carry.qhead):int(carry.level_n)],
+        q[1 - par, :int(carry.next_n)]])
+
+
+def test_engine_across_rungs_is_the_whole_width_engine(
+        monkeypatch, ff_run):
+    """An engine whose steps cross at least three rungs of each sort -
+    the ladder shrunk by a test-side patch of `sort_ladder` (powers of
+    two from 32 lanes for both sorts at chunk 128: the FF corner's
+    steps hold 0 to a few hundred valid lanes) - against the same engine with the ladder patched
+    to its last rung alone, every sort at the whole width: the same
+    queue rows in the same order after each of the first 120 steps, and
+    at the end the same table words, counters and outdegree histogram - which
+    are also the module engine's, whose ladder is the shipped one."""
+    import jax
+
+    from jaxtlc.engine import fpset
+
+    def fine(n, first=0):
+        return tuple(w for w in (32, 64, 128, 256, 512) if w < n) + (n,)
+
+    def build(ladder):
+        """The live queue after each of the first 120 steps, and the
+        final carry."""
+        monkeypatch.setattr(fpset, "sort_ladder", ladder)
+        init_fn, run_fn, step_fn = make_engine(FF, **KW, donate=False)
+        carry, step, queues = init_fn(), step_fn.segment(1), []
+        for _ in range(120):
+            carry = step(carry)
+            queues.append(_live_queue(carry))
+        return queues, jax.block_until_ready(run_fn(carry))
+
+    log = []
+    _rungs_taken(monkeypatch, log)
+    queues_l, end_l = build(fine)
+    jax.effects_barrier()
+    taken = {}
+    for site, rung in log:
+        taken.setdefault(site, set()).add(rung)
+    assert len(taken) == 2  # the compaction (tail) and the enqueue
+    for site, rungs in taken.items():
+        assert len(rungs) >= 3, (site, rungs)
+    queues_w, end_w = build(lambda n, first=0: (n,))
+
+    # mid-level steps among them: a level being popped and one built
+    assert sum(len(q) > 128 for q in queues_l) > 10
+    for a, b in zip(queues_l, queues_w):
+        assert a.shape == b.shape and (a == b).all()
+    for b in (end_w, ff_run[0]):
+        for leaf in end_l._fields:
+            if leaf != "queue":  # rows nobody reads differ by rung
+                assert _same_leaves(
+                    getattr(end_l, leaf), getattr(b, leaf)), leaf
+    assert signature(result_from_carry(end_l, 0.0)) == signature(ff_run[1])
+
+
+def test_mesh_insert_meets_the_pad_lane(monkeypatch):
+    """`sharded.insert_compacted` with a segment wide enough for a
+    ladder (40,000 rows) and mostly invalid lanes: the compaction runs
+    on its narrowest rung, the rows past it carry the lane `width`, and
+    the `idx_k < width` guard drops them - is_new, the claimants'
+    lanes and verdicts up to `c_rows`, and the table are what the
+    whole-width ordering returns."""
+    import jax
+    import jax.numpy as jnp
+
+    from jaxtlc.engine import fpset, sharded
+
+    D, bucket, width = 2, 30_000, 40_000
+    rng = np.random.default_rng(5)
+    cnt = np.array([2_000, 1_500], np.int32)
+    lo = rng.integers(1, 600, D * bucket, dtype=np.uint32)
+    hi = rng.integers(0, 3, D * bucket, dtype=np.uint32)
+    mask = np.zeros(D * bucket, bool)
+    for d in range(D):  # each bucket's live rows are a prefix
+        mask[d * bucket: d * bucket + cnt[d]] = True
+    mask &= rng.random(D * bucket) < 0.9
+    assert fpset.sort_ladder(width)[0] >= mask.sum()
+
+    def run():
+        out = jax.jit(lambda t, *a: sharded.insert_compacted(
+            t, *a, width=width))(
+            fpset.fpset_new(1 << 14).table, jnp.asarray(lo),
+            jnp.asarray(hi), jnp.asarray(mask), jnp.asarray(cnt))
+        return [np.asarray(x) for x in out]
+
+    table, is_new, c_lane, c_new, c_rows, trips = run()
+    monkeypatch.setattr(fpset, "sort_ladder", lambda n, first=0: (n,))
+    table_w, is_new_w, c_lane_w, c_new_w, c_rows_w, trips_w = run()
+    assert int(trips) == int(trips_w) == 1 and is_new.sum() > 500
+    assert (is_new == is_new_w).all() and (table == table_w).all()
+    assert int(c_rows) == int(c_rows_w) > 0
+    assert (c_lane[:c_rows] == c_lane_w[:c_rows]).all()
+    assert (c_new == c_new_w).all()
+    # the pad lane came through as the out-of-range received lane
+    assert (c_lane[fpset.sort_ladder(width)[0]:width] == D * bucket).all()
+
+
+# ---------------------------------------------------------------------------
 # the segment program (ISSUE 26): loops only, writes in place
 # ---------------------------------------------------------------------------
 

@@ -468,6 +468,29 @@ def test_sweep_parity_bit_for_bit(server, sweep_jobs):
             assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_sweep_program_holds_the_sorts_it_held(server, sweep_jobs):
+    """Under `vmap(run_fn)` a switch on a batched count runs every
+    branch and selects, so a ladder of sort widths (ISSUE 49) would
+    multiply the vmapped program's sorts: at the sweep's geometry (768
+    candidate lanes) `fpset.sort_ladder` gives the compaction ONE rung
+    and the enqueue its probe width and the whole, and the lowered
+    program holds the six sorts it held before there was a ladder -
+    grouping, compaction, the enqueue's two, the probe block's two
+    (the round-0 claimers' and the straggler walk's compactions)."""
+    import re
+
+    from jaxtlc.engine.fpset import sort_ladder
+
+    eng = _sweep_engine(server)
+    ncand = _OPTS["chunk"] * eng.backend.n_lanes
+    assert sort_ladder(ncand) == (ncand,)
+    assert sort_ladder(ncand, 2 * _OPTS["chunk"]) == (
+        2 * _OPTS["chunk"], ncand)
+    text = eng._vrun.lower(
+        eng._stack([{"MAXR": v} for v in (0, 1, 2)])).as_text()
+    assert len(re.findall(r"stablehlo\.sort", text)) == 6
+
+
 def test_sweep_matches_baked_constant_run_check(tmp_path, sweep_jobs):
     """Independent baseline: K `api.run_check` calls on TwoPhaseB
     variants with MAXR BAKED into the cfg (the pre-sweep path - its
