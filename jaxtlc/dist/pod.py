@@ -537,9 +537,8 @@ def reshard_carry(carry, backend, d_new: int,
         extra["obs_bodies"] = row0(carry.obs_bodies)
         extra["obs_expanded"] = row0(carry.obs_expanded)
     if getattr(carry, "route_stat", None) is not None:
-        # owner-routing telemetry (fullest bucket, bodies run, insert
-        # segments and enqueue blocks run): the same maxima on every
-        # new row
+        # owner-routing telemetry (fullest bucket, bodies run): the
+        # same maxima on every new row
         stat = np.asarray(carry.route_stat)
         if stat.shape[1] != ROUTE_STAT_COLS:
             raise ValueError(
@@ -548,6 +547,13 @@ def reshard_carry(carry, backend, d_new: int,
                 "another version"
             )
         extra["route_stat"] = np.tile(stat.max(axis=0), (d_new, 1))
+    if getattr(carry, "commit_stat", None) is None:
+        raise ValueError(
+            "checkpoint lacks leaf 'commit_stat' of this engine's "
+            "carry - cut by another version"
+        )
+    # the commit's counts: partial counters like the others
+    extra["commit_stat"] = row0(carry.commit_stat)
     return ShardCarry(
         table=table2,
         queue=queue2,
@@ -766,6 +772,17 @@ def run_pod(
                     f"checkpoint host_rows mismatch: host {host} owns "
                     f"rows {cur} but the shard file holds {ids} - "
                     "launch hosts in their original order or --reshard"
+                )
+            lacking = [f for f in template._fields
+                       if getattr(template, f) is not None
+                       and f not in payload]
+            if lacking:
+                # never padded: a leaf this engine carries and the
+                # file lacks (`commit_stat` before ISSUE 50) would
+                # resume with counts that leave the file's bodies out
+                raise ValueError(
+                    f"checkpoint lacks leaf {lacking[0]!r} of this "
+                    "engine's carry - cut by another version"
                 )
             for f, arr in payload.items():
                 leaf = getattr(carry, f, None)

@@ -490,7 +490,8 @@ def make_deferred_checker(backend: SpecBackend, n: int,
 
     Returns check(flat [n, F] int32, faction [n] int32 or None,
     is_new_c [n] bool, c_idx [n] int32, nreps) ->
-    (viol, viol_state [F], viol_action, cert-or-None): the claimant
+    (viol, viol_state [F], viol_action, cert-or-None, the segments
+    walked: the carry's `commit_stat` counts them): the claimant
     slice is walked in probe-width segments (one segment in steady
     state: new-per-chunk ~ chunk <= R), each an [R, F] row gather +
     one R-wide vmapped invariant kernel - the whole point: R ~ 2*chunk
@@ -537,7 +538,7 @@ def make_deferred_checker(backend: SpecBackend, n: int,
                 cert_bad = cert_bad | cert_fn(rows, fresh)
             return seg + 1, bad_any, bad_lane, cert_bad
 
-        _, bad_any, bad_lane, cert_bad = lax.while_loop(
+        trips, bad_any, bad_lane, cert_bad = lax.while_loop(
             cond, body,
             (jnp.int32(0), jnp.zeros(n_codes, bool),
              jnp.full(n_codes, -1, jnp.int32), jnp.bool_(False)),
@@ -561,7 +562,7 @@ def make_deferred_checker(backend: SpecBackend, n: int,
             jnp.int32(-1),
         )
         cert = cert_bad if cert_fn is not None else None
-        return viol, viol_state, viol_action, cert
+        return viol, viol_state, viol_action, cert, trips
 
     return check
 

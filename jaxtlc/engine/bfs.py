@@ -56,7 +56,16 @@ from jax import lax
 from ..config import ModelConfig
 from ..spec.labels import LABELS
 from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED, fp64_words_mxu
-from .fpset import enqueue_order, fpset_insert_sorted, fpset_new
+from .fpset import (
+    COMMIT_STAT_COLS,
+    LADDER_RUNGS,
+    commit_stat_fields,
+    commit_widths,
+    count_block,
+    enqueue_order,
+    fpset_insert_sorted,
+    fpset_new,
+)
 
 # violation codes
 OK = 0
@@ -190,6 +199,17 @@ class EngineCarry(NamedTuple):
     st_ap_stat: jnp.ndarray = None  # staged block's two
     ap_src: jnp.ndarray = None
     st_ap_src: jnp.ndarray = None  # staged block's failing source
+    # --- the commit's own counts (ISSUE 50; every engine of
+    # make_backend_engine) -------------------------------------------
+    # Cumulative [COMMIT_LEAF_COLS] uint32: fpset_insert_sorted's block
+    # of every chunk-wide body, then the ENGINE_COUNTS and the
+    # histogram of the rung the enqueue's sort ran at.  Counts only:
+    # the widths they are read against are static (commit_geometry).
+    # Where the loop also steps a small body, that body adds nothing:
+    # its widths are another's.  Telemetry: no control flow reads it.
+    # The commit makes the block itself, so the pipelined pair stages
+    # nothing
+    commit_stat: jnp.ndarray = None
 
 
 class CheckResult(NamedTuple):
@@ -366,6 +386,45 @@ class CheckResult(NamedTuple):
     live_swept_rows: int = None
     live_edge_bytes: int = None
     live_host_bytes: int = None
+    # the commit's own counts over the check (ISSUE 50; telemetry; every
+    # engine of make_backend_engine and the mesh; None elsewhere), read
+    # from the carry's `commit_stat`: loop bodies (the chunk-wide ones;
+    # a small body counts nothing); the candidate lanes the insert mask
+    # let through and their distinct representatives; how often the
+    # compaction's sort and the enqueue's ran at each rung of its
+    # ladder; the probe's segments, the representatives whose round-0
+    # claim wrote a slot, the blocks that write scattered, the
+    # claimants it left to the straggler walk (with `commit_claimed`
+    # what sizes the walk's block, ROADMAP A12) and the walk's rounds
+    # (a compaction of the pending set, or one bucket step of its
+    # slice); the rows found new and enqueued; the trips of the
+    # deferred checker, `commit_probe_width` rows each (0: the checker
+    # is immediate).  Beside them, where the caller names the geometry
+    # (commit_geometry; on the mesh `route`), the static widths a ratio
+    # needs: the candidate lanes, the rows of a probe segment and of
+    # one block of the claim's write, the two ladders (the last rung
+    # the whole array).  On the mesh every count is summed over the
+    # devices (`commit_bodies` is devices x the loop's bodies), a
+    # segment of the owner-side insert is one call of the seam
+    # (`commit_probe_segments` sums `commit_segments`) and the enqueue
+    # has no ladder
+    commit_bodies: int = None
+    commit_valid: int = None
+    commit_reps: int = None
+    commit_compact_rung: tuple = None
+    commit_enqueue_rung: tuple = None
+    commit_probe_segments: int = None
+    commit_claimed: int = None
+    commit_claim_blocks: int = None
+    commit_stragglers: int = None
+    commit_walk_rounds: int = None
+    commit_new: int = None
+    commit_checker_trips: int = None
+    commit_width: int = None
+    commit_compact_ladder: tuple = None
+    commit_enqueue_ladder: tuple = None
+    commit_probe_width: int = None
+    commit_claim_block: int = None
 
 
 MESH_COUNTERS = ("shard_distinct", "shard_generated", "route_max_fill",
@@ -385,15 +444,65 @@ STEP_COUNTERS = ("step_lanes", "step_slots", "state_words", "state_bits",
                  "live_fair_edges", "live_h_states", "live_p_states",
                  "live_survivors", "live_outer", "live_sweeps",
                  "live_swept_rows", "live_edge_bytes", "live_host_bytes")
+COMMIT_COUNTERS = tuple(
+    f for f in CheckResult._fields if f.startswith("commit_")
+    and f not in MESH_COUNTERS)
+
+
+def _counters(result: CheckResult, names: tuple) -> dict:
+    return {k: list(v) if isinstance(v, tuple) else v
+            for k in names
+            for v in (getattr(result, k),) if v is not None}
 
 
 def mesh_counters(result: CheckResult) -> dict:
-    """The mesh engine's and the struct-compiled step's counters of a
-    result, as the extra fields of the journal's `final` event; {} for
-    a one-chip result of the hand kernel."""
-    return {k: list(v) if isinstance(v, tuple) else v
-            for k in MESH_COUNTERS + STEP_COUNTERS
-            for v in (getattr(result, k),) if v is not None}
+    """The mesh engine's, the struct-compiled step's and the commit's
+    counters of a result, as the extra fields of the journal's `final`
+    event."""
+    return _counters(
+        result, MESH_COUNTERS + STEP_COUNTERS + COMMIT_COUNTERS)
+
+
+def commit_counters(result: CheckResult) -> dict:
+    """The commit's counters of a result alone: the attributes of the
+    `check.result` span, the one record every entry point writes."""
+    return _counters(result, COMMIT_COUNTERS)
+
+
+# what an engine's loop adds to fpset's block a body: 1, the rows it
+# enqueued, the deferred checker's trips (0: immediate)
+ENGINE_COUNTS = ("bodies", "new", "checker_trips")
+COMMIT_LEAF_COLS = COMMIT_STAT_COLS + len(ENGINE_COUNTS) + LADDER_RUNGS
+
+
+def commit_geometry(n_lanes: int, chunk: int) -> dict:
+    """The static widths the counts of an engine of make_backend_engine
+    are read against (fpset.commit_widths), as result_from_carry takes
+    them: of `n_lanes` successor lanes a state (a backend's `n_lanes`;
+    the hand kernel's spec.kernel.lane_layout) popped `chunk` at a
+    time."""
+    return commit_widths(chunk * n_lanes, probe_width(chunk, n_lanes))
+
+
+def commit_result_fields(stat, widths: dict = None,
+                         tail: tuple = ENGINE_COUNTS,
+                         hist: str = "enqueue_rung") -> dict:
+    """CheckResult's `commit_*` fields from a host `commit_stat` leaf:
+    fpset's block, then the engine's `tail` counts and what follows
+    them as `hist` (None: nothing); with `widths`, the statics beside
+    them and each histogram cut to its ladder's rungs."""
+    stat = np.asarray(stat)
+    out = commit_stat_fields(stat[:COMMIT_STAT_COLS])
+    out.update(commit_stat_fields(stat[COMMIT_STAT_COLS:], tail, hist))
+    if widths is not None:
+        out.update(widths)
+        for rung, ladder in (("compact_rung", "compact_ladder"),
+                             ("enqueue_rung", "enqueue_ladder")):
+            if rung in out:
+                out[rung] = out[rung][:len(out[ladder])]
+            else:  # the mesh's enqueue has no ladder
+                del out[ladder]
+    return {f"commit_{k}": v for k, v in out.items()}
 
 
 def with_step_counters(result: CheckResult, backend) -> CheckResult:
@@ -532,6 +641,13 @@ def make_engine(
     )
 
 
+def probe_width(ck: int, n_lanes: int) -> int:
+    """Rows of one segment of the commit's probe / claim at pop width
+    `ck`: steady-state new-per-chunk == chunk, so 2x covers bursts and
+    the segment loops keep worst cases exact."""
+    return min(2 * ck, ck * n_lanes)
+
+
 def make_stage_pair(
     backend,
     ck: int,
@@ -594,7 +710,7 @@ def make_stage_pair(
     # compaction widths: probe/claim/enqueue touch only this many rows
     # per segment; steady-state new-per-chunk == chunk, so 2x covers
     # bursts and the segment loops keep worst cases exact
-    R = min(2 * ck, ncand)  # fpset probe width
+    R = probe_width(ck, L)  # fpset probe width
     CW = min(2 * ck, R)  # fpset round-0 claim width
     A = min(2 * ck, ncand)  # enqueue/stat segment width
     expand_fn = make_expand_stage(
@@ -655,9 +771,10 @@ def make_stage_pair(
         # claim inside it is `jaxtlc.fpset` (_probe_block), so a trace
         # attributes an op to the innermost of the two
         with jax.named_scope("jaxtlc.dedup"):
-            fps, is_new_c, c_idx, nreps = fpset_insert_sorted(
+            fps, is_new_c, c_idx, nreps, cstat = fpset_insert_sorted(
                 c.fps, ex.lo, ex.hi, insert_mask,
                 probe_width=R, claim_width=CW,
+                stat_cols=COMMIT_LEAF_COLS,
             )
         n_new = is_new_c.sum().astype(jnp.int32)
         q_full = c.next_n + n_new > qcap
@@ -672,7 +789,7 @@ def make_stage_pair(
             # the sort runs at the rung that holds nreps - the probe
             # width R first (~6x less comparator traffic than ncand),
             # the whole array last: all-distinct bursts stay exact.
-            e_idx = enqueue_order(is_new_c, c_idx, nreps, R)
+            e_idx, e_at = enqueue_order(is_new_c, c_idx, nreps, R)
             e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
 
             def enq_cond(st):
@@ -751,8 +868,9 @@ def make_stage_pair(
             viol_state = c.viol_state
             viol_action = c.viol_action
             d_cert = None
+            chk_trips = jnp.int32(0)
             if checker is not None:
-                d_viol, d_state, d_action, d_cert = checker(
+                d_viol, d_state, d_action, d_cert, chk_trips = checker(
                     ex.flat, ex.action, is_new_c, c_idx, nreps
                 )
                 hit = (d_viol != OK) & (viol == OK)
@@ -818,6 +936,12 @@ def make_stage_pair(
                 # device coverage plane: fold this block's per-site visit
                 # increments into the cumulative counters (telemetry only)
                 extra["cov_counts"] = c.cov_counts + ex.cov
+            if c.commit_stat is not None:
+                # what this commit did, as counts: the insert's block,
+                # then the loop's own and the rung the enqueue sorted at
+                extra["commit_stat"] = c.commit_stat + cstat + count_block(
+                    ENGINE_COUNTS, LADDER_RUNGS, e_at, lead=COMMIT_STAT_COLS,
+                    bodies=1, new=n_new, checker_trips=chk_trips)
             obs = {}
             if obs_slots:
                 # one telemetry row per completed level (post-commit
@@ -1063,7 +1187,7 @@ def make_backend_engine(
             .set(packed0)
         )
         lo, hi = fp64_words_mxu(packed0, nbits, fp_index, seed)
-        fps, is_new_c, _, _ = fpset_insert_sorted(
+        fps, is_new_c, _, _, _ = fpset_insert_sorted(
             fpset_new(fp_capacity), lo, hi, kept0
         )
         distinct0 = is_new_c.sum().astype(jnp.uint32)
@@ -1160,6 +1284,7 @@ def make_backend_engine(
             viol=viol,
             viol_state=viol_state,
             viol_action=jnp.int32(-1),
+            commit_stat=jnp.zeros(COMMIT_LEAF_COLS, jnp.uint32),
             **staged,
             **obs,
         )
@@ -1265,7 +1390,12 @@ def make_backend_engine(
     else:
         body = make_body(chunk)
         if small:
-            small_body = make_body(small)
+            counted = make_body(small)
+
+            def small_body(c: EngineCarry) -> EngineCarry:
+                # the commit's counts are the chunk-wide bodies': they
+                # are read against one set of static widths
+                return counted(c)._replace(commit_stat=c.commit_stat)
 
         def cond(c: EngineCarry):
             return ((c.qhead < c.level_n) | (c.next_n > 0)) & (c.viol == OK)
@@ -1344,7 +1474,8 @@ def check(
     afc = float(fpset_actual_collision(carry.fps))
     sites = backend.coverage.sites if backend.coverage else None
     return result_from_carry(
-        carry, wall, fp_capacity=fp_capacity, sites=sites
+        carry, wall, fp_capacity=fp_capacity, sites=sites,
+        commit=commit_geometry(backend.n_lanes, chunk),
     )._replace(actual_fp_collision=afc)
 
 
@@ -1438,7 +1569,7 @@ def make_enumerator(
         packed0 = cdc.pack(inits)
         states = jnp.zeros((cap + A, W), jnp.uint32).at[:n0].set(packed0)
         lo, hi = fp64_words_mxu(packed0, nbits, fp_index, seed)
-        fps, _, _, _ = fpset_insert_sorted(
+        fps, _, _, _, _ = fpset_insert_sorted(
             fpset_new(fp_capacity), lo, hi, jnp.ones(n0, bool)
         )
         obs = {}
@@ -1474,7 +1605,7 @@ def make_enumerator(
         lo, hi = fp64_words_mxu(packed, nbits, fp_index, seed)
 
         fp_full = (c.tail + ncand) > int(fp_capacity * fp_highwater)
-        fps, is_new_c, c_idx, nreps = fpset_insert_sorted(
+        fps, is_new_c, c_idx, nreps, _ = fpset_insert_sorted(
             c.fps, lo, hi, fvalid & ~fp_full, probe_width=R, claim_width=R
         )
         n_new = is_new_c.sum().astype(jnp.int32)
@@ -1482,7 +1613,7 @@ def make_enumerator(
 
         # append new states at the tail in candidate order (the engines'
         # sort-compact + A-wide contiguous-write pattern)
-        e_idx = enqueue_order(is_new_c, c_idx, nreps, R)
+        e_idx, _ = enqueue_order(is_new_c, c_idx, nreps, R)
         e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
 
         def enq_cond(st):
@@ -1582,9 +1713,11 @@ def cov_totals(carry) -> "np.ndarray | None":
 def result_from_carry(
     carry: EngineCarry, wall_s: float, iterations: int = -1,
     fp_capacity: int = 0, labels: tuple = LABELS, viol_names: dict = None,
-    sites: tuple = None,
+    sites: tuple = None, commit: dict = None,
 ) -> CheckResult:
-    """Pull a finished (or interrupted) carry to host as a CheckResult."""
+    """Pull a finished (or interrupted) carry to host as a CheckResult.
+    `commit` (commit_geometry of the engine that ran the carry) puts the
+    static widths beside the commit's counts."""
     act_gen = np.asarray(carry.act_gen)[: len(labels)]
     act_dist = np.asarray(carry.act_dist)[: len(labels)]
     hist = np.asarray(carry.outdeg_hist)[:-1].astype(np.int64)  # drop dump
@@ -1618,6 +1751,8 @@ def result_from_carry(
             ("action_prop_edges", "action_prop_moved"),
             map(int, np.asarray(aps))))
         sym_counts["action_prop_source"] = np.asarray(carry.ap_src)
+    if getattr(carry, "commit_stat", None) is not None:
+        sym_counts.update(commit_result_fields(carry.commit_stat, commit))
     pruned = getattr(carry, "por_pruned", None)
     if pruned is not None:
         pruned = int(np.asarray(pruned).sum())  # shards carry partials

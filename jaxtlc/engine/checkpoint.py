@@ -38,6 +38,8 @@ from .bfs import (
     CheckResult,
     EngineCarry,
     carry_done,
+    commit_counters,
+    commit_geometry,
     make_engine,
     result_from_carry,
 )
@@ -164,9 +166,22 @@ def load_checkpoint(path: str, template: EngineCarry):
                 )
     t_paths, treedef = jax.tree_util.tree_flatten_with_path(template)
     if len(leaves) != len(t_paths):
+        # by name: the first leaf of this engine's carry the file does
+        # not hold at its place - what a snapshot cut by another
+        # version lacks (`.commit_stat` before ISSUE 50) or, with other
+        # flags, the first leaf the two carries differ in.  Never
+        # padded: a zeroed block would leave the file's bodies out
+        first = next(
+            (jax.tree_util.keystr(path)
+             for got, (path, want) in zip(leaves, t_paths)
+             if got.shape != want.shape
+             or got.dtype != np.asarray(want).dtype),
+            jax.tree_util.keystr(t_paths[min(len(leaves),
+                                             len(t_paths) - 1)][0]))
         raise ValueError(
             f"checkpoint has {len(leaves)} leaves, engine expects "
-            f"{len(t_paths)} - geometry mismatch"
+            f"{len(t_paths)} - geometry mismatch, or cut by another "
+            f"version: no leaf {first} where the engine has one"
         )
     for got, (path, want) in zip(leaves, t_paths):
         # the carry's own name for the leaf (`.route_stat`): a snapshot
@@ -386,8 +401,13 @@ def check_with_checkpoints(
     wall = time.time() - t0
     from .fpset import fpset_actual_collision
 
-    with span("check.result"):
+    with span("check.result") as read:
         afc = float(fpset_actual_collision(carry.fps))
-        return result_from_carry(carry, wall, iterations=segments)._replace(
-            actual_fp_collision=afc
-        )
+        from ..spec.kernel import lane_layout
+
+        result = result_from_carry(
+            carry, wall, iterations=segments,
+            commit=commit_geometry(lane_layout(cfg)[1], chunk))
+        # the commit's counts, on the one record this entry writes
+        read.attrs.update(commit_counters(result))
+        return result._replace(actual_fp_collision=afc)

@@ -141,7 +141,8 @@ def _slot_write(table, slot, lo, hi, active, n_live=None):
 
     `n_live` (traced) promises that only the first n_live lanes can be
     active: a `_blocked` write then scatters block after block only as
-    far as that."""
+    far as that.  Returns (table, the blocks scattered: 1 for a write
+    that is not cut)."""
 
     def add_rows(table, slot, lo, hi, active):
         b = jnp.where(active, slot // BUCKET, 0)
@@ -155,7 +156,7 @@ def _slot_write(table, slot, lo, hi, active, n_live=None):
 
     n = slot.shape[0]
     if n_live is None or not _blocked(n):
-        return add_rows(table, slot, lo, hi, active)
+        return add_rows(table, slot, lo, hi, active), jnp.int32(1)
     block = n // WRITE_BLOCKS
 
     def write(st):
@@ -166,7 +167,7 @@ def _slot_write(table, slot, lo, hi, active, n_live=None):
 
     return lax.while_loop(
         lambda st: st[1] * block < n_live, write, (table, jnp.int32(0))
-    )[0]
+    )
 
 
 def _remap(lo, hi):
@@ -401,7 +402,10 @@ def _probe_block(table, lo, hi, active, claim_width: int):
 def _probe_claim(table, lo, hi, active, claim_width: int):
     """Insert-or-find `active` entries of a fingerprint block that is
     sorted ascending by (hi, lo) and duplicate-free.  Returns
-    (table, is_new).  table: [nb, 2B]; lo/hi/active: [R]."""
+    (table, is_new, counts).  table: [nb, 2B]; lo/hi/active: [R];
+    counts: what the block did, int32 scalars it has anyway and the
+    trip counts of its loops - (claimed, claim_blocks, stragglers,
+    walk_rounds) of COMMIT_COUNTS."""
     nb = table.shape[0]
     cap = nb * BUCKET
     R = lo.shape[0]
@@ -436,8 +440,8 @@ def _probe_claim(table, lo, hi, active, claim_width: int):
         ((~claimed).astype(jnp.uint32), bid * BUCKET + slot, lo, hi),
         num_keys=1, is_stable=True,
     )
-    table = _slot_write(table, t_tgt, t_lo, t_hi,
-                        jnp.arange(R) < nclaim, nclaim)
+    table, claim_blocks = _slot_write(table, t_tgt, t_lo, t_hi,
+                                      jnp.arange(R) < nclaim, nclaim)
 
     is_new = claimed
     pending = active & ~found & ~claimed
@@ -451,11 +455,11 @@ def _probe_claim(table, lo, hi, active, claim_width: int):
     S = min(R, 2048)
 
     def outer_cond(st):
-        table, is_new, pending = st
+        table, is_new, pending, rounds = st
         return pending.any()
 
     def outer_body(st):
-        table, is_new, pending = st
+        table, is_new, pending, rounds = st
         npend = (~pending).astype(jnp.uint32)
         pos = jnp.arange(R, dtype=jnp.uint32)
         _, p_bid, p_lo, p_hi, p_pos = lax.sort(
@@ -503,8 +507,8 @@ def _probe_claim(table, lo, hi, active, claim_width: int):
             rnk = (same & less).sum(axis=1).astype(jnp.int32)
             sl = occ + rnk
             ok = wnt & (sl < BUCKET)
-            table = _slot_write(table, cur_b * BUCKET + sl, s_lo, s_hi,
-                                ok, n_act)
+            table, _ = _slot_write(table, cur_b * BUCKET + sl, s_lo, s_hi,
+                                   ok, n_act)
             new = new | ok
             pend2 = pend & ~(f | ok)
             # unsettled claimants advance to the next bucket
@@ -512,30 +516,36 @@ def _probe_claim(table, lo, hi, active, claim_width: int):
                               cur_b)
             return table, cur_b, pend2, new, k + 1
 
-        table, _, _, s_new, _ = lax.while_loop(
+        table, _, _, s_new, walked = lax.while_loop(
             walk_cond, walk_body,
             (table, s_bid, s_act, jnp.zeros(S, bool), jnp.int32(0)),
         )
         upd_pos = jnp.where(s_act, s_pos, R)
         is_new = is_new.at[upd_pos].set(s_new, mode="drop")
         pending = pending.at[upd_pos].set(False, mode="drop")
-        return table, is_new, pending
+        return table, is_new, pending, rounds + 1 + walked
 
-    table, is_new, _ = lax.while_loop(
-        outer_cond, outer_body, (table, is_new, pending)
+    table, is_new, _, rounds = lax.while_loop(
+        outer_cond, outer_body, (table, is_new, pending, jnp.int32(0))
     )
-    return table, is_new
+    # no reduction at block width for the counts: the claim's running
+    # sum ends on the entries the home bucket did not hold, of which
+    # round 0 wrote nclaim and left the rest pending
+    counts = (nclaim.astype(jnp.int32), claim_blocks,
+              wc[-1] - nclaim.astype(jnp.int32), rounds)
+    return table, is_new, counts
 
 
 def _probe_segments(table, c_lo, c_hi, active, n_rows, R: int, C: int):
     """Probe / claim the ordered candidates c_lo/c_hi [n] (fp-ascending,
-    `active` marking the dup-free representatives, all of them within
-    the first `n_rows` rows) in R-wide blocks.  Returns (table,
-    is_new [n]).  The one place the commit's table is written, and
-    never under a conditional."""
+    `active` marking the dup-free representatives: the first `n_rows`
+    rows) in R-wide blocks.  Returns (table, is_new [n], the blocks
+    run, `_probe_claim`'s counts summed over them).  The one place the
+    commit's table is written, and never under a conditional."""
     n = c_lo.shape[0]
     if R == n:
-        return _probe_block(table, c_lo, c_hi, active, C)
+        table, is_new, counts = _probe_block(table, c_lo, c_hi, active, C)
+        return table, is_new, jnp.int32(1), counts
 
     # block loop: one trip unless a chunk is nearly all-distinct; each
     # block stays fp-sorted.
@@ -549,23 +559,26 @@ def _probe_segments(table, c_lo, c_hi, active, n_rows, R: int, C: int):
     p_act = jnp.pad(active, (0, pad))
 
     def seg_cond(st):
-        table, is_new_p, seg = st
+        table, is_new_p, seg, counts = st
         return (seg * R < n_rows) & (seg < nseg)
 
     def seg_body(st):
-        table, is_new_p, seg = st
+        table, is_new_p, seg, counts = st
         off = seg * R
         b_lo = lax.dynamic_slice(p_lo, (off,), (R,))
         b_hi = lax.dynamic_slice(p_hi, (off,), (R,))
         b_act = lax.dynamic_slice(p_act, (off,), (R,))
-        table, b_new = _probe_block(table, b_lo, b_hi, b_act, C)
+        table, b_new, b_counts = _probe_block(table, b_lo, b_hi, b_act, C)
         is_new_p = lax.dynamic_update_slice(is_new_p, b_new, (off,))
-        return table, is_new_p, seg + 1
+        return (table, is_new_p, seg + 1,
+                tuple(a + b for a, b in zip(counts, b_counts)))
 
-    table, is_new_p, _ = lax.while_loop(
-        seg_cond, seg_body, (table, jnp.zeros(nseg * R, bool), jnp.int32(0))
+    table, is_new_p, seg, counts = lax.while_loop(
+        seg_cond, seg_body,
+        (table, jnp.zeros(nseg * R, bool), jnp.int32(0),
+         (jnp.int32(0),) * 4)
     )
-    return table, is_new_p[:n]
+    return table, is_new_p[:n], seg, counts
 
 
 LADDER_FLOOR = 16384  # no power-of-two rung is narrower
@@ -597,6 +610,69 @@ def sort_ladder(n: int, first: int = 0) -> Tuple[int, ...]:
     return tuple(pows[-(LADDER_RUNGS - 1):]) + (n,)
 
 
+# What one commit did, as counts (ISSUE 50): `fpset_insert_sorted` hands
+# back one uint32 vector a call - the COMMIT_COUNTS, then a
+# LADDER_RUNGS-bin histogram of the rung of `sort_ladder(n)` the
+# compaction sorted at.  Scalars the program has anyway and the final
+# values of its loops' counters - histograms and trip counts, never a
+# sum of widths: a width is static (`commit_widths`), the host
+# multiplies.  An engine appends its own loops' counts and sums the
+# whole a body into a leaf of its carry (engine.bfs, engine.sharded).
+COMMIT_COUNTS = (
+    "valid", "reps", "probe_segments",  # the seam, `fpset_insert_sorted`
+    "claimed", "claim_blocks", "stragglers",
+    "walk_rounds")  # `_probe_claim`, summed over the segments
+COMMIT_STAT_COLS = len(COMMIT_COUNTS) + LADDER_RUNGS
+
+
+def count_block(names: tuple, bins: int = 0, at=None, lead: int = 0,
+                tail: int = 0, **counts):
+    """A [lead + len(names) + bins + tail] uint32 vector: `lead` zeros,
+    the named counts (0 where not given), a one-hot of the bin index
+    `at` where given, `tail` zeros.  Selects against one iota and
+    nothing else, and every block of a body at the leaf's one width
+    (the seam's with a `tail` for its caller's columns, the caller's
+    behind a `lead`), so that the blocks and their sum into the leaf
+    fuse into one small elementwise operation: on the chip every
+    operation of a body costs microseconds whatever its size - a stack
+    of scalars, a one-hot and a concatenate were three, and blocks of
+    two widths joined by a concatenate were three again (PERF.md
+    section 6, PR 50)."""
+    cols = lead + len(names) + bins + tail
+    col = jnp.arange(cols, dtype=jnp.int32)
+    out = jnp.zeros(cols, jnp.uint32)
+    for name, value in counts.items():
+        out = out + jnp.where(col == lead + names.index(name),
+                              jnp.asarray(value).astype(jnp.uint32), 0)
+    if at is not None:  # an index past the bins counts nowhere
+        out = out + ((col == lead + len(names) + at) & (at < bins)
+                     ).astype(jnp.uint32)
+    return out
+
+
+def commit_stat_fields(stat, names: tuple = COMMIT_COUNTS,
+                       hist: str = "compact_rung") -> dict:
+    """A host `count_block` by name: the counts, and what follows them
+    as the tuple `hist` (None: nothing follows)."""
+    stat = [int(v) for v in np.asarray(stat)]
+    out = dict(zip(names, stat))
+    if hist is not None:
+        out[hist] = tuple(stat[len(names):])
+    return out
+
+
+def commit_widths(width: int, probe_width: int = 0) -> dict:
+    """The static widths a commit's counts are read against, for an
+    insert over `width` candidate lanes probed `probe_width` rows a
+    segment (0: all of them): the rows of one block of the round-0
+    claim's write, and the two sorts' ladders."""
+    R = min(probe_width or width, width)
+    return dict(width=width, probe_width=R,
+                claim_block=R // WRITE_BLOCKS if _blocked(R) else R,
+                compact_ladder=sort_ladder(width),
+                enqueue_ladder=sort_ladder(width, R))
+
+
 def sort_live(operands, num_keys: int, n_live, widths, fill, tail=False):
     """Stable `lax.sort` of `operands` ([n] each) whose live part is
     known to be the first - `tail`: the last - `n_live` rows, every
@@ -604,8 +680,9 @@ def sort_live(operands, num_keys: int, n_live, widths, fill, tail=False):
     static slice of the narrowest of `widths` (a `sort_ladder`) that
     holds n_live, by `lax.switch` on the traced count.  Hands back, at
     the old shape [n], the sorted operands whose `fill` is not None,
-    the rows past the rung holding that operand's `fill`.  One rung:
-    the plain sort, no conditional."""
+    the rows past the rung holding that operand's `fill`, and after
+    them the index of the rung taken.  One rung: the plain sort, no
+    conditional."""
     n = operands[0].shape[0]
     kept = [i for i, f in enumerate(fill) if f is not None]
 
@@ -621,16 +698,17 @@ def sort_live(operands, num_keys: int, n_live, widths, fill, tail=False):
         return run
 
     if len(widths) == 1:
-        return rung(n)(operands)
+        return rung(n)(operands) + (jnp.int32(0),)
     at = sum((n_live > w).astype(jnp.int32) for w in widths[:-1])
-    return lax.switch(at, [rung(w) for w in widths], operands)
+    return lax.switch(at, [rung(w) for w in widths], operands) + (at,)
 
 
 def _sorted_order(lo, hi):
     """The in-batch dedup's ordering: two stable sorts of already
     MIXED, remapped, mask-zeroed fingerprint words give (c_lo, c_hi,
-    c_idx int32, nreps), the distinct representatives compacted
-    fp-ascending into the first nreps rows.  The grouping sort sees all
+    c_idx int32, nreps, the valid lanes, the compaction's rung), the
+    distinct representatives compacted fp-ascending into the first
+    nreps rows.  The grouping sort sees all
     n candidate lanes; the compaction runs at the rung of
     `sort_ladder(n)` that holds the valid lanes, so rows past nreps are
     non-representatives and then, past the rung, (0, 0) words with the
@@ -663,12 +741,13 @@ def _sorted_order(lo, hi):
     # and a few tens of percent of it (the cells' lane_live_pct): the sort
     # runs over that tail alone
     nonrep = (~rep).astype(jnp.uint32)
-    c_lo, c_hi, c_idx = sort_live(
-        (nonrep, s_lo, s_hi, s_idx), 1, valid.sum(), sort_ladder(n),
+    nvalid = valid.sum()
+    c_lo, c_hi, c_idx, at = sort_live(
+        (nonrep, s_lo, s_hi, s_idx), 1, nvalid, sort_ladder(n),
         fill=(None, 0, 0, n), tail=True,
     )
     nreps = rep.sum().astype(jnp.int32)
-    return c_lo, c_hi, c_idx.astype(jnp.int32), nreps
+    return c_lo, c_hi, c_idx.astype(jnp.int32), nreps, nvalid, at
 
 
 def enqueue_order(is_new_c, c_idx, nreps, first: int):
@@ -677,21 +756,21 @@ def enqueue_order(is_new_c, c_idx, nreps, first: int):
     entries past the new rows are not to be read.  Every new row lies in
     the first nreps compacted rows, so the sort runs at the rung of
     `sort_ladder(n, first)` that holds nreps, `first` being the caller's
-    probe width."""
-    (e_idx,) = sort_live(
+    probe width.  Returns (e_idx, the index of the rung taken)."""
+    return sort_live(
         ((~is_new_c).astype(jnp.uint32), c_idx.astype(jnp.uint32)),
         2, nreps, sort_ladder(c_idx.shape[0], first), fill=(None, 0),
     )
-    return e_idx
 
 
 def fpset_insert_sorted(
-    s: FPSet, lo, hi, mask, probe_width: int = 0, claim_width: int = 0
-) -> Tuple[FPSet, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    s: FPSet, lo, hi, mask, probe_width: int = 0, claim_width: int = 0,
+    stat_cols: int = COMMIT_STAT_COLS,
+) -> Tuple[FPSet, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Insert-or-find a batch; results in *compacted* order.
 
     lo/hi: [N] uint32; mask: [N] bool.  Returns (set, is_new_c [N] bool,
-    c_idx [N] int32, nreps int32): entry j < nreps of the compacted order
+    c_idx [N] int32, nreps int32, stat): entry j < nreps of the compacted order
     is the representative of a distinct masked fingerprint, originally at
     lane c_idx[j]; is_new_c[j] says whether it was new to the table.
     Representatives are fingerprint-sorted (ascending (hi, lo)).  Past
@@ -704,6 +783,12 @@ def fpset_insert_sorted(
     sort), keeping attribution deterministic across engines/backends.
     probe_width bounds the per-segment probe row count (0 = whole batch);
     claim_width bounds the round-0 claim scatter (0 = probe_width).
+    `stat` is the call's [stat_cols] uint32 block of counts: the lanes
+    the mask let through, their representatives, the probe's segments
+    and what they claimed and walked, the rung the compaction sorted
+    at - COMMIT_STAT_COLS columns, and zeros after them where the
+    caller asks for room for its own.  A caller that keeps no block
+    drops it, and the adds with it.
     """
     n = lo.shape[0]
     R = min(probe_width or n, n)
@@ -712,11 +797,15 @@ def fpset_insert_sorted(
     lo, hi = _remap(lo, hi)
     lo = jnp.where(mask, lo, 0)
     hi = jnp.where(mask, hi, 0)
-    c_lo, c_hi, c_idx, nreps = _sorted_order(lo, hi)
-    table, is_new_c = _probe_segments(
+    c_lo, c_hi, c_idx, nreps, nvalid, at = _sorted_order(lo, hi)
+    table, is_new_c, segments, counts = _probe_segments(
         s.table, c_lo, c_hi, jnp.arange(n) < nreps, nreps, R, C
     )
-    return FPSet(table), is_new_c, c_idx, nreps
+    stat = count_block(
+        COMMIT_COUNTS, LADDER_RUNGS, at, tail=stat_cols - COMMIT_STAT_COLS,
+        valid=nvalid, reps=nreps, probe_segments=segments,
+        **dict(zip(COMMIT_COUNTS[3:], counts)))
+    return FPSet(table), is_new_c, c_idx, nreps, stat
 
 
 def fpset_insert(s: FPSet, lo, hi, mask) -> Tuple[FPSet, jnp.ndarray]:
@@ -731,7 +820,7 @@ def fpset_insert(s: FPSet, lo, hi, mask) -> Tuple[FPSet, jnp.ndarray]:
     engine checks before calling)."""
     n = lo.shape[0]
     with jax.named_scope("jaxtlc.dedup"):
-        s2, is_new_c, c_idx, _ = fpset_insert_sorted(s, lo, hi, mask)
+        s2, is_new_c, c_idx, _, _ = fpset_insert_sorted(s, lo, hi, mask)
     # c_idx holds each representative's lane once; past nreps it may hold
     # the out-of-range lane n (_sorted_order), which the drop leaves out
     is_new = jnp.zeros(n, bool).at[c_idx].set(is_new_c, mode="drop")

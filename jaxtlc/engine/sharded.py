@@ -84,12 +84,18 @@ from .bfs import (
     VIOL_ROUTE_OVERFLOW,
     VIOL_SLOT_OVERFLOW,
     VIOLATION_NAMES,
+    ENGINE_COUNTS,
+    commit_result_fields,
     outdegree_from_hist,
 )
 from . import backend as _backend
 from .fingerprint import DEFAULT_FP_INDEX, DEFAULT_SEED
 from .fpset import (
+    COMMIT_COUNTS,
+    COMMIT_STAT_COLS,
     FPSet,
+    commit_widths,
+    count_block,
     fpset_insert_sorted,
     fpset_member,
     host_insert,
@@ -104,7 +110,12 @@ from .backend import SpecBackend, gen_backend, kubeapi_backend  # noqa: F401,E40
 # columns of ShardCarry.route_stat (below); a snapshot whose leaf has
 # another count was cut by another version and is refused by the
 # leaf's name (checkpoint.load_checkpoint, dist.pod)
-ROUTE_STAT_COLS = 4
+ROUTE_STAT_COLS = 2
+# what a device's loop adds to fpset's block a body (ShardCarry.
+# commit_stat): the one-chip engine's counts, and the blocks its
+# enqueue wrote
+MESH_COUNTS = ENGINE_COUNTS + ("enqueue_trips",)
+MESH_STAT_COLS = COMMIT_STAT_COLS + len(MESH_COUNTS)
 
 
 class ShardCarry(NamedTuple):
@@ -173,12 +184,17 @@ class ShardCarry(NamedTuple):
     # any body (its width is route_bucket_width: at that width a
     # candidate would not fit and the run halts with
     # VIOL_ROUTE_OVERFLOW); column 1: bodies run, each of which hands
-    # the two all_to_alls their static shapes (route_geometry);
-    # column 2: segments this device's owner-side insert has run
-    # (commit_width rows each: over column 1, the trips a body);
-    # column 3: blocks of as many rows this device's enqueue has
-    # written (enqueue_new_rows: they follow a body's new rows)
-    route_stat: jnp.ndarray = None  # [D, 4] int32
+    # the two all_to_alls their static shapes (route_geometry)
+    route_stat: jnp.ndarray = None  # [D, 2] int32
+    # --- the commit's own counts (ISSUE 50; telemetry) -----------------
+    # per device, cumulative: fpset_insert_sorted's block of every
+    # segment of the owner-side insert (a segment is one call of the
+    # seam, commit_width rows: `probe_segments` is the result's
+    # `commit_segments`), then the MESH_COUNTS a body - 1, the rows it
+    # enqueued, the deferred checker's trips, and the blocks of
+    # commit_width rows its enqueue wrote (enqueue_new_rows: they
+    # follow a body's new rows; the result's `enqueue_segments`)
+    commit_stat: jnp.ndarray = None  # [D, MESH_STAT_COLS] uint32
 
 
 class ShardEx(NamedTuple):
@@ -377,12 +393,13 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int):
     A body of one segment is the one insert exactly, table included:
     compaction keeps lane order.
 
-    Returns (table, is_new [D * B], c_lane, c_new, c_rows, trips): the
-    claimants of every segment end to end, each segment's up to its
-    last new row - received lane (D * B on the rows between) and
-    verdict, `c_rows` rows in use of a whole number of segments - for
-    the deferred checker (the enqueue reads is_new alone), and the
-    segments run."""
+    Returns (table, is_new [D * B], c_lane, c_new, c_rows, trips,
+    stat): the claimants of every segment end to end, each segment's
+    up to its last new row - received lane (D * B on the rows between)
+    and verdict, `c_rows` rows in use of a whole number of segments -
+    for the deferred checker (the enqueue reads is_new alone), the
+    segments run, and the segments' blocks of counts
+    (fpset_insert_sorted's fifth value, MESH_STAT_COLS wide) summed."""
     (D,) = cnt.shape
     DB = r_lo.shape[0]
     bucket = DB // D
@@ -393,7 +410,7 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int):
         for a in (r_lo, r_hi, ins_mask))
 
     def insert_segment(st):
-        k, table, is_new, c_lane, c_new, used = st
+        k, table, is_new, c_lane, c_new, used, stat = st
         # device scope of the compaction: the segment's words, and the
         # claimants mapped back to received lanes
         with jax.named_scope("jaxtlc.compact"):
@@ -401,8 +418,8 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int):
                 compact_rows(a, cnt, k * width, width)
                 for a in (p_lo, p_hi, p_mask))
         with jax.named_scope("jaxtlc.dedup"):
-            fset, new_k, idx_k, _ = fpset_insert_sorted(
-                FPSet(table), lo_k, hi_k, mask_k)
+            fset, new_k, idx_k, _, stat_k = fpset_insert_sorted(
+                FPSet(table), lo_k, hi_k, mask_k, stat_cols=MESH_STAT_COLS)
         with jax.named_scope("jaxtlc.compact"):
             # idx_k names each representative's row once; past them a
             # segment wider than 32,768 rows may hold the out-of-range
@@ -417,17 +434,18 @@ def insert_compacted(table, r_lo, r_hi, ins_mask, cnt, width: int):
                 lax.dynamic_update_slice(c_lane, lane_k, (used,)),
                 lax.dynamic_update_slice(c_new, new_k, (used,)),
                 used + jnp.max(jnp.where(new_k, row + 1, 0)),
+                stat + stat_k,
             )
 
     # the claimant buffers hold every received lane and one segment's
     # overrun, in whole segments
     cap = (-(-DB // width) + 1) * width
-    _, table, is_new, c_lane, c_new, c_rows = lax.while_loop(
+    _, table, is_new, c_lane, c_new, c_rows, stat = lax.while_loop(
         lambda st: st[0] >= 0, insert_segment,
         (trips - 1, table, jnp.zeros(DB, bool),
          jnp.full(cap, DB, jnp.int32), jnp.zeros(cap, bool),
-         jnp.int32(0)))
-    return table, is_new, c_lane, c_new, c_rows, trips
+         jnp.int32(0), jnp.zeros(MESH_STAT_COLS, jnp.uint32)))
+    return table, is_new, c_lane, c_new, c_rows, trips, stat
 
 
 def enqueue_new_rows(queue, r_flat, is_new, qtail, n_go, width: int):
@@ -570,14 +588,14 @@ def make_sharded_engine(
     D*B verdicts brings the new lanes to the front in lane order, and
     their rows go onto the ring as blocks of the same width, a row
     gather and two contiguous slice writes each, as many blocks as the
-    new rows need (`enqueue_segments`, route_stat[:, 3]: one a body in
-    the common case).  The highest segment goes first,
+    new rows need (`enqueue_segments`, the carry's commit_stat: one a
+    body in the common case).  The highest segment goes first,
     which keeps the dedup's highest-lane representative across
     segments: counts, queue rows, per-action and outdegree statistics
     are bit-for-bit those of one insert over all D*B lanes, and the
     table holds the same fingerprints (slot order inside a bucket may
     differ where two segments claim in it).  `commit_segments` (per
-    device, carry leaf route_stat[:, 2]) and `commit_rows` on the
+    device, the carry's commit_stat) and `commit_rows` on the
     result and in the journal's `final` event say how often it ran.
 
     deferred (tri-state, resolved against the PER-DEVICE chunk by
@@ -749,6 +767,7 @@ def make_sharded_engine(
             viol_local=jnp.zeros(D, bool),
             cont=jnp.ones(D, bool),
             route_stat=jnp.zeros((D, ROUTE_STAT_COLS), jnp.int32),
+            commit_stat=jnp.zeros((D, MESH_STAT_COLS), jnp.uint32),
             **pv,
             **obs,
         )
@@ -982,8 +1001,8 @@ def make_sharded_engine(
             )
             ins_mask = r_valid & ~fp_full
         cnt = r_valid.reshape(D, B).sum(axis=1).astype(jnp.int32)
-        table, is_new, c_lane, c_new, c_rows, trips = insert_compacted(
-            table, r_lo, r_hi, ins_mask, cnt, W)
+        table, is_new, c_lane, c_new, c_rows, _, cstat = (
+            insert_compacted(table, r_lo, r_hi, ins_mask, cnt, W))
 
         with jax.named_scope("jaxtlc.enqueue"):
             n_new = is_new.sum().astype(jnp.int32)
@@ -1048,12 +1067,13 @@ def make_sharded_engine(
         # ---- violations (local detect, global max) ----
         new_viol = jnp.int32(OK)
         new_vstate = viol_state
+        chk_trips = jnp.int32(0)
         if checker is not None:
             # owner-side deferred invariants over the fresh-insert
             # claimants of the received batch (the r_* payload carries
             # no action ids - violation_action stays -1, as the
             # sharded result always reports)
-            d_viol, d_state, _d_act, _d_cert = checker(
+            d_viol, d_state, _d_act, _d_cert, chk_trips = checker(
                 r_flat, None, c_new[:DB], c_lane[:DB], c_rows
             )
             hit = d_viol != OK
@@ -1194,9 +1214,10 @@ def make_sharded_engine(
         route_stat = jnp.stack([
             jnp.maximum(c.route_stat[0, 0], ex.route_fill),
             c.route_stat[0, 1] + 1,
-            c.route_stat[0, 2] + trips,
-            c.route_stat[0, 3] + enq_trips,
         ])
+        commit_stat = c.commit_stat[0] + cstat + count_block(
+            MESH_COUNTS, lead=COMMIT_STAT_COLS, bodies=1, new=n_new,
+            checker_trips=chk_trips, enqueue_trips=enq_trips)
 
         return ShardCarry(
             table=table[None],
@@ -1216,6 +1237,7 @@ def make_sharded_engine(
             viol_local=viol_local2[None],
             cont=cont[None],
             route_stat=route_stat[None],
+            commit_stat=commit_stat[None],
             **pv2,
             **obs2,
             **cov_acc,
@@ -1274,6 +1296,7 @@ def make_sharded_engine(
         viol_local=P(axis),
         cont=P(axis),
         route_stat=P(axis),
+        commit_stat=P(axis),
         **pv_specs,
     )
     run_fn = jax.jit(
@@ -1671,10 +1694,22 @@ def result_from_shard_carry(
             route_bucket=int(route["bucket"]),
             # every device runs every body: column 1 is the same on all
             route_bytes=int(stat[:, 1].max()) * int(route["step_bytes"]),
-            commit_segments=tuple(int(v) for v in stat[:, 2]),
-            enqueue_segments=tuple(int(v) for v in stat[:, 3]),
             commit_rows=int(route["commit_rows"]),
         )
+    commit = {}
+    if getattr(out, "commit_stat", None) is not None:
+        # summed over the devices, but for the two counts that had a
+        # name a device before they had a block
+        stat = np.asarray(out.commit_stat).astype(np.int64)
+        commit = commit_result_fields(
+            stat.sum(axis=0),
+            route and commit_widths(int(route["commit_rows"])),
+            MESH_COUNTS, None)
+        commit.pop("commit_enqueue_trips")
+        commit.update(
+            commit_segments=tuple(
+                stat[:, COMMIT_COUNTS.index("probe_segments")].tolist()),
+            enqueue_segments=tuple(stat[:, -1].tolist()))
     act_gen = np.asarray(out.act_gen).sum(axis=0)[: len(labels)]
     act_dist = np.asarray(out.act_dist).sum(axis=0)[: len(labels)]
     hist = np.asarray(out.outdeg_hist).sum(axis=0)[:-1].astype(np.int64)
@@ -1722,6 +1757,7 @@ def result_from_shard_carry(
         ),
         site_coverage=site_coverage,
         **routing,
+        **commit,
     )
 
 

@@ -85,7 +85,7 @@ def make_gen_engine(
             .set(packed0)
         )
         lo, hi = fp64_words_mxu(packed0, nbits, fp_index, seed)
-        fps, is_new_c, _, _ = fpset_insert_sorted(
+        fps, is_new_c, _, _, _ = fpset_insert_sorted(
             fpset_new(fp_capacity), lo, hi, jnp.ones(n0, bool)
         )
         # initial-state invariant check
@@ -156,7 +156,7 @@ def make_gen_engine(
             fp_capacity * 0.85
         )
         insert_mask = fvalid & ~fp_full
-        fps, is_new_c, c_idx, nreps = fpset_insert_sorted(
+        fps, is_new_c, c_idx, nreps, _ = fpset_insert_sorted(
             c.fps, lo, hi, insert_mask, probe_width=R, claim_width=R
         )
         n_new = is_new_c.sum().astype(jnp.int32)
@@ -166,7 +166,7 @@ def make_gen_engine(
         # A-wide segment loop covers bursts where one chunk yields more
         # than A distinct new states (same pattern as bfs.py enq_body -
         # a single A-wide write would silently drop the overflow)
-        e_idx = enqueue_order(is_new_c, c_idx, nreps, R)
+        e_idx, _ = enqueue_order(is_new_c, c_idx, nreps, R)
         e_idx_p = jnp.concatenate([e_idx, jnp.zeros(A, jnp.uint32)])
 
         def enq_cond(st):

@@ -176,18 +176,6 @@ def snapshot(since: float = None) -> list:
     return rows if since is None else [r for r in rows if r.t1 >= since]
 
 
-def self_time(rows) -> dict:
-    """{span id: its duration less what its direct children cover}
-    (children of one parent on one thread do not overlap, so their
-    durations add).  For reading a snapshot by hand: which span's own
-    code, not its children's, holds the time (PERF.md section 5)."""
-    out = {r.id: r.t1 - r.t0 for r in rows}
-    for r in rows:
-        if r.parent in out:
-            out[r.parent] -= r.t1 - r.t0
-    return {k: max(v, 0.0) for k, v in out.items()}
-
-
 def journal_event() -> dict:
     """The `spans` journal event's fields for the job of this context:
     `rows` = [[name, t0, dur_s, parent_index], ...] over its spans

@@ -91,7 +91,7 @@ def migrate_table(old_table: np.ndarray, new_capacity: int,
             b_lo = np.pad(b_lo, (0, batch - nb))
             b_hi = np.pad(b_hi, (0, batch - nb))
         mask = np.arange(batch) < nb
-        fps, is_new, _, _ = fpset_insert_sorted(
+        fps, is_new, _, _, _ = fpset_insert_sorted(
             fps, jnp.asarray(b_lo), jnp.asarray(b_hi), jnp.asarray(mask)
         )
         inserted += int(np.asarray(is_new).sum())
@@ -199,7 +199,8 @@ def migrate_engine_carry(
     # their own dtypes - a reduced run regrows like any other
     for f in ("sym_viol", "st_sym", "sym_stat", "st_sym_stat",
               "por_pruned", "st_pruned", "con_stat", "st_con_stat",
-              "ap_stat", "st_ap_stat", "ap_src", "st_ap_src"):
+              "ap_stat", "st_ap_stat", "ap_src", "st_ap_src",
+              "commit_stat"):
         if getattr(carry, f, None) is not None:
             staged[f] = jnp.asarray(np.asarray(getattr(carry, f)))
 
@@ -328,6 +329,12 @@ def migrate_shard_carry(
         # change
         pv["route_stat"] = jnp.asarray(
             np.asarray(carry.route_stat), jnp.int32
+        )
+    if getattr(carry, "commit_stat", None) is not None:
+        # the commit's counts: the insert's segment is a chunk wide
+        # whatever the capacities
+        pv["commit_stat"] = jnp.asarray(
+            np.asarray(carry.commit_stat), jnp.uint32
         )
     return ShardCarry(
         table=jnp.asarray(table2),

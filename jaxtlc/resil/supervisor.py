@@ -78,6 +78,8 @@ from ..engine.bfs import (
     VIOLATION_NAMES,
     CheckResult,
     carry_done,
+    commit_counters,
+    commit_geometry,
     make_engine,
     mesh_counters,
     with_step_counters,
@@ -471,14 +473,19 @@ class SingleDeviceAdapter:
         from ..engine.fpset import fpset_actual_collision
 
         afc = float(fpset_actual_collision(carry.fps))
-        kw = {}
         if self.backend is not None:
+            n_lanes = self.backend.n_lanes
             kw = dict(labels=self.backend.labels,
                       viol_names=self.backend.viol_names,
                       sites=self.cov_sites())
+        else:
+            from ..spec.kernel import lane_layout
+
+            n_lanes, kw = lane_layout(self.cfg)[1], {}
         return with_step_counters(result_from_carry(
             carry, wall, iterations=segments,
-            fp_capacity=params["fp_capacity"], **kw,
+            fp_capacity=params["fp_capacity"],
+            commit=commit_geometry(n_lanes, self.chunk), **kw,
         )._replace(actual_fp_collision=afc), self.backend)
 
 
@@ -1237,8 +1244,11 @@ def supervise(adapter, params: dict,
             flush_save()
 
     wall = time.time() - t0
-    with span("check.result"):
+    with span("check.result") as read:
         result = adapter.result(carry, wall, segments, params)
+        # the commit's counts, on the record every entry point writes
+        # (check_with_checkpoints, which has no journal, too)
+        read.attrs.update(commit_counters(result))
         # every supervised run ends with exactly one structured final
         # event: verdict + counters + wall, whatever the exit path
         verdict = ("exhausted" if exhausted

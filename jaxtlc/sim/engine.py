@@ -181,7 +181,7 @@ def make_sim_engine(
             fp_capacity * fp_highwater
         )
         sat = sat | would_over
-        fps, is_new, _, _ = fpset_insert_sorted(
+        fps, is_new, _, _, _ = fpset_insert_sorted(
             fps, lo, hi, mask & ~sat
         )
         distinct = distinct + is_new.sum().astype(jnp.uint32)

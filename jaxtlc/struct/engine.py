@@ -25,6 +25,7 @@ import jax
 from ..engine.bfs import (
     CheckResult,
     VIOLATION_NAMES,
+    commit_geometry,
     result_from_carry,
     with_step_counters,
 )
@@ -95,6 +96,7 @@ def check_struct(
         out, wall, fp_capacity=fp_capacity, labels=backend.labels,
         viol_names=backend.viol_names,
         sites=backend.coverage.sites if backend.coverage else None,
+        commit=commit_geometry(backend.n_lanes, chunk),
     ), backend)
     if capture_fps and result.violation == 0:
         import numpy as np

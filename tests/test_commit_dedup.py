@@ -23,7 +23,12 @@ import pytest
 
 from jaxtlc.config import ModelConfig
 from jaxtlc.engine import checkpoint as ck
-from jaxtlc.engine.bfs import make_engine, result_from_carry
+from jaxtlc.engine.backend import kubeapi_backend
+from jaxtlc.engine.bfs import (
+    commit_geometry,
+    make_engine,
+    result_from_carry,
+)
 from jaxtlc.resil import FaultPlan, SupervisorOptions, check_supervised
 
 FF = ModelConfig(False, False)
@@ -59,7 +64,8 @@ def ff_run():
 
     init_fn, run_fn, _ = make_engine(FF, **KW, donate=False)
     carry = jax.block_until_ready(run_fn(init_fn()))
-    r = result_from_carry(carry, 0.0)
+    r = result_from_carry(carry, 0.0, commit=commit_geometry(
+        kubeapi_backend(FF).n_lanes, KW["chunk"]))
     assert (r.generated, r.distinct, r.depth) == EXPECT_FF
     return carry, r
 
@@ -116,7 +122,7 @@ def test_sorted_insert_past_one_probe_block_matches_host_replay(
         if prev is not None:  # a third of the lanes were seen before
             lo[::3], hi[::3] = prev[0][::3], prev[1][::3]
         prev = (lo.copy(), hi.copy())
-        s, is_new_c, c_idx, nreps = insert(
+        s, is_new_c, c_idx, nreps, _ = insert(
             s, jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(mask))
         is_new_c, c_idx, nreps = (
             np.asarray(is_new_c), np.asarray(c_idx), int(nreps))
@@ -150,6 +156,179 @@ def test_sorted_insert_past_one_probe_block_matches_host_replay(
     assert not any(fpset.host_insert(table, *key) for key in seen)
     # the width was real: some batch took more than one probe block
     assert blocks >= (0 if kind == "masked" else 2)
+
+
+# ---------------------------------------------------------------------------
+# the commit's own counts (ISSUE 50): the seam's fifth value against a
+# numpy recount, and a whole check's block against its identities
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n, probe_width", [
+    (4096, 256), (4096, 1024), (40_000, 8192)],
+    ids=["4096x256", "4096x1024-blocked-write", "40000-three-rungs"])
+def test_the_inserts_own_counts_match_a_numpy_recount(n, probe_width):
+    """`fpset_insert_sorted`'s fifth value over three batches into one
+    table, the valid share falling from batch to batch (at 40,000 lanes
+    across the three rungs of the compaction's ladder): the lanes the
+    mask let through, their classes, those the table held, those
+    written in round 0 or left to the walk, the probe's segments, the
+    round-0 write's blocks and the rung the compaction sorted at - all
+    recounted in numpy from the batch and the set of what went
+    before."""
+    import jax
+    import jax.numpy as jnp
+
+    from jaxtlc.engine import fpset
+
+    insert = jax.jit(lambda s, lo, hi, mask: fpset.fpset_insert_sorted(
+        s, lo, hi, mask, probe_width=probe_width,
+        claim_width=probe_width)[::4])
+    ladder = fpset.sort_ladder(n)
+    s, seen, rungs, prev = fpset.fpset_new(1 << 18), set(), [], None
+    most = written = 0  # a batch's segments; writes that stopped early
+    for step, fill in enumerate((0.95, 0.6, 0.2)):
+        lo, hi, _ = _batch("mixed", 60 + step, n)
+        if prev is not None:  # a third of the lanes were seen before
+            lo[::3], hi[::3] = prev[0][::3], prev[1][::3]
+        prev = (lo.copy(), hi.copy())
+        mask = np.random.default_rng(step).random(n) < fill
+        s, stat = insert(s, jnp.asarray(lo), jnp.asarray(hi),
+                         jnp.asarray(mask))
+        did = fpset.commit_stat_fields(stat)
+        classes = {(int(a), int(b))
+                   for a, b in zip(lo[mask], hi[mask])}
+        fresh = classes - seen
+        seen |= classes
+        assert did["valid"] == mask.sum()
+        assert did["reps"] == len(classes)
+        # a representative its home bucket does not hold writes a slot
+        # in round 0 or is left to the walk (a class of an earlier
+        # batch that lives past a full home bucket walks to be found)
+        assert did["claimed"] <= len(fresh) <= (
+            did["claimed"] + did["stragglers"]) <= len(classes)
+        assert (did["walk_rounds"] > 0) == (did["stragglers"] > 0)
+        segments = -(-len(classes) // probe_width)
+        assert did["probe_segments"] == segments >= 1
+        block = probe_width // fpset.WRITE_BLOCKS
+        if fpset._blocked(probe_width):  # as far as its claimers go
+            assert -(-did["claimed"] // block) <= did["claim_blocks"] <= (
+                segments * fpset.WRITE_BLOCKS)
+            written += did["claim_blocks"] < segments * fpset.WRITE_BLOCKS
+        else:
+            assert did["claim_blocks"] == segments
+        most = max(most, segments)
+        at = next(i for i, w in enumerate(ladder) if w >= mask.sum())
+        assert did["compact_rung"] == tuple(
+            int(i == at) for i in range(fpset.LADDER_RUNGS))
+        rungs.append(at)
+        # the seam's own counts and no more: a loop's are its engine's
+        assert stat.shape == (fpset.COMMIT_STAT_COLS,)
+    assert len(set(rungs)) == len(ladder)  # every rung was taken
+    assert most > 1 and written == (3 if fpset._blocked(probe_width) else 0)
+
+
+def _block_identities(r, n_init: int, masked: int = 0):
+    """What holds of a whole check's block whatever the model: `r` a
+    CheckResult of a finished run from `n_init` initial states, on a
+    model whose insert mask lets every kept successor through
+    (`masked`: the successors its constraint discarded)."""
+    new = r.distinct - n_init
+    assert r.commit_bodies > 0 and r.commit_new == new
+    assert sum(r.commit_compact_rung) == r.commit_probe_segments or (
+        sum(r.commit_compact_rung) == r.commit_bodies)
+    assert r.commit_valid == r.generated - n_init - masked
+    assert r.commit_claimed <= new <= (
+        r.commit_claimed + r.commit_stragglers) <= r.commit_reps
+    assert (r.commit_walk_rounds > 0) == (r.commit_stragglers > 0)
+    assert len(r.commit_compact_rung) == len(r.commit_compact_ladder)
+    assert r.commit_compact_ladder[-1] == r.commit_width
+    assert r.commit_probe_width <= r.commit_width
+
+
+def test_a_whole_checks_block_keeps_its_identities(ff_run):
+    """The hand path's block over the FF corner (the module's engine):
+    both rung histograms sum to the bodies, the claims and the walk's
+    new rows are the distinct states less the initial ones, the valid
+    lanes are what was generated less the initial states, and the
+    statics beside them are the geometry's - absent where the caller
+    names none."""
+    from jaxtlc.engine.bfs import commit_counters
+
+    carry, r = ff_run
+    L = kubeapi_backend(FF).n_lanes
+    n_init = len(kubeapi_backend(FF).initial_vectors())
+    _block_identities(r, n_init)
+    assert sum(r.commit_compact_rung) == r.commit_bodies
+    assert sum(r.commit_enqueue_rung) == r.commit_bodies
+    # chunk 128 is under the deferred mode's threshold: no checker
+    assert r.commit_checker_trips == 0
+    assert (r.commit_width, r.commit_probe_width, r.commit_claim_block
+            ) == (128 * L, 256, 256)
+    assert r.commit_compact_ladder == (128 * L,)
+    assert r.commit_enqueue_ladder == (256, 128 * L)
+    # a body probes in one segment unless nothing was valid
+    assert r.commit_probe_segments <= r.commit_bodies
+    # the table ends half full: some claims met a full home bucket
+    assert 0 < r.commit_stragglers < r.commit_claimed
+    named = commit_counters(r)
+    assert set(named) == {
+        f for f in r._fields if f.startswith("commit_")} - {
+        "commit_segments", "commit_rows"}
+    assert json.loads(json.dumps(named)) == named  # journal-ready
+    bare = commit_counters(result_from_carry(carry, 0.0))
+    assert bare["commit_valid"] == r.commit_valid
+    assert not [k for k in bare if "ladder" in k or "width" in k]
+
+
+@pytest.mark.parametrize("entry", ["ckpt", "supervised"])
+def test_the_block_rides_the_check_result_span(tmp_path, ff_run, entry):
+    """Both entries close a `check.result` span around the one read of
+    the carry, and the block is its attributes: the supervisor's, which
+    also writes it on `final`, and `check_with_checkpoints`, which
+    writes no journal.  The counts are the module engine's - a run cut
+    into segments commits the same bodies."""
+    import time
+
+    from jaxtlc.engine.bfs import commit_counters
+    from jaxtlc.obs import spans
+
+    t0 = time.time()
+    if entry == "ckpt":
+        r = ck.check_with_checkpoints(FF, **KW, ckpt_every=64)
+    else:
+        events = []
+        r = check_supervised(FF, **KW, opts=SupervisorOptions(
+            ckpt_every=64,
+            on_event=lambda kind, info: events.append((kind, info)),
+        )).result
+        (final,) = [info for kind, info in events if kind == "final"]
+        assert {k: final[k] for k in commit_counters(r)} == (
+            commit_counters(r))
+    assert signature(r) == signature(ff_run[1])
+    (read,) = [row for row in spans.snapshot(since=t0)
+               if row.name == "check.result"]
+    assert read.attrs == commit_counters(r) == commit_counters(ff_run[1])
+
+
+def test_a_snapshot_without_the_block_is_refused_by_name(ff_run, tmp_path):
+    """The block is a leaf of the carry's layout: a snapshot cut before
+    it does not resume - the refusal names the leaf, nothing pads it -
+    and one of another shape is refused by the same name."""
+    from jaxtlc.engine.checkpoint import load_checkpoint, save_checkpoint
+
+    carry = ff_run[0]
+    path = str(tmp_path / "old.npz")
+    save_checkpoint(path, carry._replace(commit_stat=None), {})
+    with pytest.raises(ValueError, match=r"no leaf \.commit_stat"):
+        load_checkpoint(path, carry)
+    save_checkpoint(path, carry._replace(
+        commit_stat=carry.commit_stat[:-1]), {})
+    with pytest.raises(ValueError, match=r"\.commit_stat shape"):
+        load_checkpoint(path, carry)
+    save_checkpoint(path, carry, {})
+    _, back = load_checkpoint(path, carry)
+    assert _same_leaves(back, carry)
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +421,7 @@ def test_ordering_on_every_rung_matches_the_host_reference(
 
     n = LADDER_N
     lo, hi = _fill(kind, nv, n)
-    c_lo, c_hi, c_idx, nreps = (
+    c_lo, c_hi, c_idx, nreps, nvalid, at = (
         np.asarray(x) for x in ladder_order(jnp.asarray(lo),
                                             jnp.asarray(hi)))
     last = {}  # class -> its highest valid lane
@@ -255,6 +434,8 @@ def test_ordering_on_every_rung_matches_the_host_reference(
     assert list(zip(c_hi[:nreps].tolist(), c_lo[:nreps].tolist())) == [
         key for key, _ in want]
     rung = next(w for w in sort_ladder(n) if w >= nv)
+    # what the commit's block counts: the valid lanes, and the rung
+    assert int(nvalid) == nv and sort_ladder(n)[int(at)] == rung
     reps = set(last.values())
     assert not reps & set(c_idx[nreps:].tolist())
     assert (c_idx[rung:] == n).all()
@@ -287,15 +468,19 @@ def test_enqueue_order_on_both_sides_of_each_rung(ladder_enqueue, nreps):
     among them)."""
     import jax.numpy as jnp
 
+    from jaxtlc.engine.fpset import sort_ladder
+
     n = LADDER_N
     rng = np.random.default_rng(nreps + 3)
     c_idx = np.full(n, n, np.int32)  # past nreps: nobody's lane
     c_idx[:nreps] = rng.permutation(n)[:nreps]
     is_new_c = np.zeros(n, bool)
     is_new_c[:nreps] = rng.random(nreps) < 0.6
-    e_idx = np.asarray(ladder_enqueue(
+    e_idx, at = (np.asarray(x) for x in ladder_enqueue(
         jnp.asarray(is_new_c), jnp.asarray(c_idx), jnp.int32(nreps)))
     n_new = int(is_new_c.sum())
+    ladder = sort_ladder(n, LADDER_R)
+    assert ladder[int(at)] == next(w for w in ladder if w >= nreps)
     assert e_idx.shape == (n,)
     assert e_idx[:n_new].tolist() == sorted(c_idx[is_new_c].tolist())
 
@@ -318,6 +503,15 @@ def _rungs_taken(monkeypatch, log):
         return real(operands, num_keys, n_live, widths, fill, tail)
 
     monkeypatch.setattr(fpset, "sort_live", spy)
+
+
+def _commit_counts(carry) -> dict:
+    """The carry's `commit_stat` by name, less what a patched ladder
+    changes: the two rung histograms."""
+    from jaxtlc.engine.bfs import commit_result_fields
+
+    return {k: v for k, v in commit_result_fields(
+        carry.commit_stat).items() if "rung" not in k}
 
 
 def _live_queue(carry):
@@ -376,9 +570,12 @@ def test_engine_across_rungs_is_the_whole_width_engine(
         assert a.shape == b.shape and (a == b).all()
     for b in (end_w, ff_run[0]):
         for leaf in end_l._fields:
-            if leaf != "queue":  # rows nobody reads differ by rung
+            # rows nobody reads differ by rung; so do the rungs counted
+            if leaf not in ("queue", "commit_stat"):
                 assert _same_leaves(
                     getattr(end_l, leaf), getattr(b, leaf)), leaf
+        # ... and nothing else the commit counts about itself
+        assert _commit_counts(end_l) == _commit_counts(b)
     assert signature(result_from_carry(end_l, 0.0)) == signature(ff_run[1])
 
 
@@ -412,9 +609,14 @@ def test_mesh_insert_meets_the_pad_lane(monkeypatch):
             jnp.asarray(hi), jnp.asarray(mask), jnp.asarray(cnt))
         return [np.asarray(x) for x in out]
 
-    table, is_new, c_lane, c_new, c_rows, trips = run()
+    table, is_new, c_lane, c_new, c_rows, trips, stat = run()
+    # the segment's block: the mask's lanes, on the narrowest rung
+    did = fpset.commit_stat_fields(stat)
+    assert did["valid"] == mask.sum() and did["probe_segments"] == 1
+    assert did["claimed"] + did["stragglers"] >= is_new.sum() > 0
+    assert did["compact_rung"][0] == 1
     monkeypatch.setattr(fpset, "sort_ladder", lambda n, first=0: (n,))
-    table_w, is_new_w, c_lane_w, c_new_w, c_rows_w, trips_w = run()
+    table_w, is_new_w, c_lane_w, c_new_w, c_rows_w, trips_w, _ = run()
     assert int(trips) == int(trips_w) == 1 and is_new.sum() > 500
     assert (is_new == is_new_w).all() and (table == table_w).all()
     assert int(c_rows) == int(c_rows_w) > 0
@@ -657,9 +859,29 @@ def test_real_engine_takes_both_tiers():
         while int(carry.level) <= upto:
             carry = seg(carry)
         assert int(carry.viol) == 0
+        blocks.append((result_from_carry(carry, 0.0, commit=commit_geometry(
+            kubeapi_backend(cfg).n_lanes, ck_)), int(carry.obs_bodies)))
         return [r for r in obs_rows(carry)[0] if r["level"] <= upto]
 
+    blocks = []
     two_tier, one_tier = levels(chunk), levels(1024)
+    # the commit's own counts (ISSUE 50) are the chunk-wide bodies',
+    # read against one set of static widths: the small body adds nothing
+    (both, stepped), (one, one_stepped) = blocks
+    L = one.commit_width // 1024
+    assert both.commit_width == chunk * L
+    assert both.commit_probe_width == 2 * chunk
+    assert both.commit_claim_block == 2 * chunk // 8
+    assert 0 < both.commit_bodies < stepped
+    assert one.commit_bodies == one_stepped
+    assert sum(both.commit_compact_rung) == both.commit_bodies == sum(
+        both.commit_enqueue_rung)
+    n_init = one.generated - one.commit_valid
+    assert 0 < both.commit_valid < both.generated - n_init
+    assert 0 < both.commit_new < both.distinct - n_init
+    # chunk 2^14 defers its invariants: a trip a probe segment
+    assert both.commit_checker_trips == both.commit_probe_segments > 0
+    assert one.commit_checker_trips == 0
     assert len(two_tier) == len(one_tier) == upto
     counted = ("level", "generated", "distinct", "queue", "expanded")
     for a, b in zip(two_tier, one_tier):

@@ -414,7 +414,7 @@ def test_dense_walk_matches_host_replay():
     for step in range(3):
         lo, hi, mask = _hot_bucket_batch(100 + step, n)
         lo[::7], hi[::7] = lo[1::7][:len(lo[::7])], hi[1::7][:len(hi[::7])]
-        s, is_new_c, c_idx, _ = fpset.fpset_insert_sorted(
+        s, is_new_c, c_idx, _, stat = fpset.fpset_insert_sorted(
             s, jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(mask),
             probe_width=n, claim_width=64,
         )
@@ -425,6 +425,12 @@ def test_dense_walk_matches_host_replay():
                 fresh[key] = int(lane)  # the highest lane wins
         new_lanes = np.asarray(c_idx)[np.asarray(is_new_c)]
         assert sorted(new_lanes.tolist()) == sorted(fresh.values())
+        # the call's counts say the walk was driven: at most 64 claims
+        # written in round 0, every other fresh class a straggler
+        did = fpset.commit_stat_fields(stat)
+        assert did["claimed"] <= 64
+        assert did["stragglers"] >= len(fresh) - did["claimed"] > 0
+        assert did["walk_rounds"] >= 2  # a compaction and a bucket step
         for key in fresh:
             assert fpset.host_insert(ref, *key)
         seen |= set(fresh)

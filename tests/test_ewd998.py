@@ -64,7 +64,14 @@ def test_the_shipped_files_are_the_sources_model():
         assert "CONSTRAINT" in f.read()
 
 
-def test_engine_interpreter_and_reference_agree_at_n2():
+@pytest.fixture(scope="module")
+def n2_runs():
+    """The module's two engine runs at N = 2, each made once: the
+    immediate and the deferred invariant mode ({deferred: result})."""
+    return {False: engine(2), True: engine(2, deferredinv=True)}
+
+
+def test_engine_interpreter_and_reference_agree_at_n2(n2_runs):
     """All five numbers, three ways: generated, distinct, depth, the
     per-action totals and the discards."""
     want = reference(2)
@@ -76,7 +83,7 @@ def test_engine_interpreter_and_reference_agree_at_n2():
     assert dict(generated=host.generated, distinct=host.distinct,
                 depth=host.depth, discarded=host.discarded,
                 action_generated=host.action_generated) == N2
-    r = engine(2)
+    r = n2_runs[False]
     assert five(r) == N2
     assert r.constraint_rows == N2["generated"] - 16
     assert (r.step_lanes, r.step_slots, r.struct_traps) == (10, 10, 0)
@@ -84,8 +91,43 @@ def test_engine_interpreter_and_reference_agree_at_n2():
     assert r.constraint_names == ("StateConstraint",)
 
 
-def test_the_deferred_invariant_mode_agrees_at_n2():
-    assert five(engine(2, deferredinv=True)) == N2
+def test_the_deferred_invariant_mode_agrees_at_n2(n2_runs):
+    assert five(n2_runs[True]) == N2
+
+
+@pytest.mark.parametrize("deferred", [False, True],
+                         ids=["immediate", "deferred"])
+def test_the_commits_own_counts_on_a_constrained_struct_model(
+        n2_runs, deferred):
+    """ISSUE 50's block on the struct path, where a CONSTRAINT masks
+    the insert: the valid lanes are what was generated less the
+    initial states less the discards; the claims and the walk's new
+    rows are the kept states less the kept initial ones; both ladder
+    sorts ran once a body; and the deferred checker's trips are counted
+    only where the mode runs one - the same counts otherwise."""
+    r = n2_runs[deferred]
+    n_init = 16
+    assert r.commit_valid == (
+        r.generated - n_init - r.constraint_discarded)
+    new = r.distinct - n_init  # every initial state is kept at N = 2
+    assert r.commit_new == new
+    assert r.commit_claimed <= new <= (
+        r.commit_claimed + r.commit_stragglers) <= r.commit_reps
+    assert sum(r.commit_compact_rung) == r.commit_bodies > 0
+    assert sum(r.commit_enqueue_rung) == r.commit_bodies
+    assert (r.commit_width, r.commit_probe_width) == (256 * r.step_slots,
+                                                      512)
+    if deferred:
+        # a trip a probe segment: the checker walks the
+        # representatives, not the new rows (PERF.md 7-20a)
+        assert r.commit_checker_trips == r.commit_probe_segments > 0
+    else:
+        assert r.commit_checker_trips == 0
+    other = n2_runs[not deferred]
+    assert {f: getattr(r, f) for f in r._fields
+            if f.startswith("commit_") and "checker" not in f} == {
+        f: getattr(other, f) for f in r._fields
+        if f.startswith("commit_") and "checker" not in f}
 
 
 def test_preflight_names_the_constraint_of_the_unmodified_cfg():

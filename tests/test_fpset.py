@@ -66,23 +66,29 @@ def test_segmented_probe_partial_final_segment():
     # regression: probe_width not dividing the batch must not clamp the
     # final partial segment (dynamic_slice clamps OOB starts; the unpadded
     # version re-probed earlier entries and never probed the tail)
-    from jaxtlc.engine.fpset import fpset_insert_sorted
+    from jaxtlc.engine.fpset import commit_stat_fields, fpset_insert_sorted
 
     s = fpset_new(1 << 8)
     vals = np.arange(10, dtype=np.uint32)
-    s, is_new_c, c_idx, nreps = fpset_insert_sorted(
+    s, is_new_c, c_idx, nreps, stat = fpset_insert_sorted(
         s, jnp.asarray(vals), jnp.asarray(vals ^ 0xABCD), jnp.ones(10, bool),
         probe_width=4,
     )
     assert int(nreps) == 10
+    # the call's own counts: three segments of four rows for ten
+    did = commit_stat_fields(stat)
+    assert (did["valid"], did["reps"], did["probe_segments"]) == (10, 10, 3)
+    assert did["claimed"] + did["stragglers"] == 10
     assert int(np.asarray(is_new_c).sum()) == 10
     assert int(fpset_count(s)) == 10
     # idempotence: nothing is new the second time
-    s, is_new_c, _, _ = fpset_insert_sorted(
+    s, is_new_c, _, _, stat = fpset_insert_sorted(
         s, jnp.asarray(vals), jnp.asarray(vals ^ 0xABCD), jnp.ones(10, bool),
         probe_width=4,
     )
     assert not np.asarray(is_new_c).any()
+    did = commit_stat_fields(stat)
+    assert (did["reps"], did["claimed"], did["stragglers"]) == (10, 0, 0)
 
 
 def test_mix_unmix_roundtrip_and_actual_collision():
@@ -143,10 +149,17 @@ def test_blocked_write_bit_for_bit(monkeypatch):
             mask = rng.random(n) < 0.9
             # step 0 claims in round 0 (several blocks); a narrow claim
             # width then sends most claimers to the straggler walk
-            s, is_new_c, c_idx, nreps = insert(
+            s, is_new_c, c_idx, nreps, stat = insert(
                 s, jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(mask),
                 claim_width=128 if step else 0,
             )
+            # the blocks the round-0 write scattered follow its
+            # claimers: 128 rows a block here, one where it is not cut
+            did = fpset.commit_stat_fields(stat)
+            assert did["claim_blocks"] == (
+                -(-did["claimed"] // (n // fpset.WRITE_BLOCKS))
+                if blocked else 1)
+            assert did["claimed"] <= (128 if step else n)
             fresh = {(int(a), int(b))
                      for a, b, m in zip(lo, hi, mask) if m} - seen
             assert int(np.asarray(is_new_c).sum()) == len(fresh)

@@ -17,7 +17,7 @@ import pytest
 from jaxtlc.api import CheckRequest, run_check
 from jaxtlc.config import ModelConfig, make_scaled
 from jaxtlc.engine.backend import kubeapi_backend
-from jaxtlc.engine import sharded
+from jaxtlc.engine import fpset, sharded
 from jaxtlc.engine.sharded import (
     commit_width,
     compact_lanes,
@@ -226,6 +226,13 @@ def test_shards_partition_the_one_chip_table(mesh_run, one_chip_fps):
 # -- (d) the routing counters ---------------------------------------------
 
 
+def segments(carry):
+    """[D, 2]: the segments a device's owner-side insert has run and the
+    blocks its enqueue has written, off the carry's `commit_stat`."""
+    stat = np.asarray(carry.commit_stat)
+    return stat[:, [fpset.COMMIT_COUNTS.index("probe_segments"), -1]]
+
+
 def carry_digest(c, qcap: int, table: bool = True) -> str:
     """What a check leaves behind, less the dump rows and bins that a
     body writes whether or not it pops (tests/test_mesh_cell.py pins the
@@ -281,14 +288,14 @@ def test_results_with_counters_are_the_parents_bit_for_bit(mesh_run):
     statistics: the parent's, bit for bit, though some bodies of this
     run take two insert segments (the table's fingerprints, slot order
     aside: test_shards_partition_the_one_chip_table)."""
-    carry, segments = mesh_run
+    carry, n_segments = mesh_run
     assert carry_digest(carry, GEOM["queue_capacity"], table=False
                         ) == PARENT_DIGEST_LESS_TABLE
     # a finished check leaves its last segment: 109 levels of at most
     # one body per level here, so fewer than 16 x segments bodies
     stat = np.asarray(carry.route_stat)
-    assert 16 * (segments - 1) < stat[0, 1] < 16 * segments
-    assert (stat[:, 2] > stat[:, 1]).any()
+    assert 16 * (n_segments - 1) < stat[0, 1] < 16 * n_segments
+    assert (segments(carry)[:, 0] > stat[:, 1]).any()
 
 
 def test_wide_chunk_paths_are_the_parents_bit_for_bit():
@@ -307,7 +314,7 @@ def test_one_segment_a_body_leaves_the_parents_table_too(kw):
     carry = jax.block_until_ready(run_fn(init_fn()))
     assert carry_digest(carry, GEOM["queue_capacity"]) == PARENT_DIGEST
     stat = np.asarray(carry.route_stat)
-    assert (stat[:, 2] <= stat[:, 1]).all()
+    assert (segments(carry)[:, 0] <= stat[:, 1]).all()
 
 
 def test_route_counters_against_the_hand_count(mesh_run):
@@ -331,6 +338,55 @@ def test_route_counters_against_the_hand_count(mesh_run):
     # without the geometry the result carries no routing counters
     bare = result_from_shard_carry(carry, 1.0)
     assert bare.route_bytes is None and bare.route_max_fill is None
+
+
+def test_the_commits_own_counts_on_the_mesh(mesh_run, narrow_run):
+    """ISSUE 50's block on four devices: one accumulator a device, its
+    `probe_segments` named `commit_segments` and its `enqueue_trips`
+    `enqueue_segments` as before there was a block, every other count
+    summed over the devices; every device counts every body; the valid
+    lanes are what the owners received (generated less the initial
+    states); the compaction sorted once a segment and the enqueue has
+    no ladder.  Several segments a body (NARROW rows each) change the
+    segments and nothing the insert counts about states."""
+    carry, _ = mesh_run
+    with segments_of(96):  # the geometry the fixture's engine was built at
+        geo = route_geometry(kubeapi_backend(FF), 128, 4, 2.0)
+    r = result_from_shard_carry(carry, 1.0, route=geo)
+    n_init = len(kubeapi_backend(FF).initial_vectors())
+    stat = np.asarray(carry.commit_stat)
+    assert stat.shape == (4, fpset.COMMIT_STAT_COLS + len(
+        sharded.MESH_COUNTS))
+    assert r.commit_segments == tuple(segments(carry)[:, 0].tolist())
+    assert r.enqueue_segments == tuple(segments(carry)[:, 1].tolist())
+    assert r.commit_probe_segments == sum(r.commit_segments)
+    bodies = int(np.asarray(carry.route_stat)[0, 1])
+    per_device = [fpset.commit_stat_fields(
+        row[fpset.COMMIT_STAT_COLS:], sharded.MESH_COUNTS, None)
+        for row in stat]
+    assert [d["bodies"] for d in per_device] == [bodies] * 4
+    assert r.commit_bodies == 4 * bodies
+    assert r.commit_valid == r.generated - n_init
+    new = r.distinct - n_init
+    assert r.commit_new == new
+    assert r.commit_claimed <= new <= (
+        r.commit_claimed + r.commit_stragglers) <= r.commit_reps
+    assert sum(r.commit_compact_rung) == r.commit_probe_segments
+    assert r.commit_enqueue_rung is None and r.commit_enqueue_ladder is None
+    # the statics: the test's 96-row segments, probed whole; chunk 128
+    # is under the deferred mode's threshold
+    assert (r.commit_width, r.commit_probe_width, r.commit_claim_block,
+            r.commit_checker_trips) == (96, 96, 96, 0)
+    assert r.commit_compact_ladder == (96,)
+    # without the geometry the counts are still there
+    bare = result_from_shard_carry(carry, 1.0)
+    assert bare.commit_rows is None and bare.commit_width is None
+    assert bare.commit_segments == r.commit_segments
+    assert bare.commit_probe_segments == r.commit_probe_segments
+    many = result_from_shard_carry(narrow_run, 1.0)
+    assert many.commit_probe_segments > r.commit_probe_segments
+    assert (many.commit_valid, many.commit_bodies) == (
+        r.commit_valid, r.commit_bodies)
 
 
 # -- (e) the owner-side insert, a segment of compacted candidates at a time
@@ -429,10 +485,10 @@ def test_many_segments_a_body_give_the_same_check(
     stat = np.asarray(carry.route_stat)
     bodies = int(stat[0, 1])
     assert bodies == int(np.asarray(mesh_run[0].route_stat)[0, 1])
-    assert (stat[:, 2] > bodies).all()  # several segments a body
+    ran = segments(carry)[:, 0]
+    assert (ran > bodies).all()  # several segments a body
     # and never more than the received candidates need
-    assert (stat[:, 2] <= (ref.generated + 3 * bodies) // NARROW + bodies
-            ).all()
+    assert (ran <= (ref.generated + 3 * bodies) // NARROW + bodies).all()
 
 
 def test_spill_veto_goes_through_the_segments():
@@ -455,7 +511,7 @@ def test_spill_veto_goes_through_the_segments():
     ex = rt._expand_fn(carry)
     received = np.asarray(ex.r_valid).sum(axis=1)
     assert (received > NARROW).any()
-    before = np.asarray(carry.route_stat)[:, 2]
+    before = segments(carry)[:, 0]
     all_veto = rt._commit_fn(carry, ex, np.ones((4, rt._DB), bool))
     assert (np.asarray(all_veto.distinct)
             == np.asarray(carry.distinct)).all()
@@ -463,7 +519,7 @@ def test_spill_veto_goes_through_the_segments():
     assert (np.asarray(all_veto.table) == np.asarray(carry.table)).all()
     assert int(np.asarray(all_veto.spill_hits).sum()) == received.sum()
     # the segments still ran: they cover what arrived, vetoed or not
-    assert (np.asarray(all_veto.route_stat)[:, 2] - before
+    assert (segments(all_veto)[:, 0] - before
             == -(-received // NARROW)).all()
     # half the lanes vetoed: exactly the rest can be new
     ex_lo = np.asarray(ex.r_lo)
@@ -489,7 +545,7 @@ def test_pipeline_goes_through_the_segments():
     assert carry_digest(carry, GEOM["queue_capacity"], table=False
                         ) == PARENT_DIGEST_LESS_TABLE
     stat = np.asarray(carry.route_stat)
-    assert (stat[:, 2] > stat[:, 1]).all()
+    assert (segments(carry)[:, 0] > stat[:, 1]).all()
 
 
 def test_commit_counters_cross_a_regrow_and_a_reshard(mesh_run):
@@ -497,23 +553,29 @@ def test_commit_counters_cross_a_regrow_and_a_reshard(mesh_run):
     from jaxtlc.resil.regrow import migrate_shard_carry
 
     carry, _ = mesh_run
-    stat = np.asarray(carry.route_stat)
-    assert stat.shape == (4, 4) and (stat[:, 2:] > 0).all()
+    stat, ran = np.asarray(carry.route_stat), segments(carry)
+    assert stat.shape == (4, 2) and (ran > 0).all()
     old = dict(queue_capacity=GEOM["queue_capacity"],
                fp_capacity=GEOM["fp_capacity"], route_factor=2.0)
     grown = migrate_shard_carry(carry, old, dict(
         old, fp_capacity=2 * GEOM["fp_capacity"], route_factor=4.0))
     assert (np.asarray(grown.route_stat) == stat).all()
+    assert (np.asarray(grown.commit_stat)
+            == np.asarray(carry.commit_stat)).all()
     r = result_from_shard_carry(
         grown, 1.0, route=route_geometry(kubeapi_backend(FF), 128, 4, 4.0))
-    assert r.commit_segments == tuple(int(v) for v in stat[:, 2])
-    assert r.enqueue_segments == tuple(int(v) for v in stat[:, 3])
+    assert r.commit_segments == tuple(ran[:, 0].tolist())
+    assert r.enqueue_segments == tuple(ran[:, 1].tolist())
     assert r.commit_rows == 128
     halved = reshard_carry(
         jax.tree.map(np.asarray, carry), kubeapi_backend(FF), 2)
-    # a pod's new rows all start from the old pod's maxima
+    # a pod's new rows all start from the old pod's maxima; the
+    # commit's counts are partial sums, and go on from row 0
     assert (np.asarray(halved.route_stat) == stat.max(axis=0)).all()
-    assert np.asarray(halved.route_stat).shape == (2, 4)
+    assert np.asarray(halved.route_stat).shape == (2, 2)
+    assert (np.asarray(halved.commit_stat)[0]
+            == np.asarray(carry.commit_stat).sum(axis=0)).all()
+    assert not np.asarray(halved.commit_stat)[1].any()
 
 
 # -- (g) the enqueue: a compaction and contiguous writes onto the ring ----
@@ -619,7 +681,7 @@ def ring_run(ring_engine):
     while bool(np.asarray(carry.cont).any()):
         carry = jax.block_until_ready(step_fn(carry))
         tails.append(np.asarray(carry.qtail))
-        stats.append(np.asarray(carry.route_stat))
+        stats.append(segments(carry))
         if len(stats) == 60:
             mid = carry
     return carry, np.stack(tails), np.stack(stats), mid
@@ -657,9 +719,9 @@ def test_enqueue_segments_against_the_hand_count(ring_run):
     new = np.diff(tails, axis=0)  # [bodies, 4]
     assert (new > 2 * NARROW).any()  # bodies of three blocks and more
     want = np.cumsum(-(-new // NARROW), axis=0)
-    assert (stats[:, :, 3] == want).all()
+    assert (stats[:, :, 1] == want).all()
     # the insert's segments follow what arrived, so they are more
-    assert (stats[-1, :, 2] > stats[-1, :, 3]).all()
+    assert (stats[-1, :, 0] > stats[-1, :, 1]).all()
 
 
 def test_a_full_queue_halts_and_leaves_the_ring(ring_engine, ring_run):
@@ -678,8 +740,7 @@ def test_a_full_queue_halts_and_leaves_the_ring(ring_engine, ring_run):
     assert (np.asarray(halted.queue)[:, :RING]
             == np.asarray(full.queue)[:, :RING]).all()
     assert (np.asarray(halted.qtail) == np.asarray(full.qtail)).all()
-    assert (np.asarray(halted.route_stat)[:, 3]
-            == np.asarray(full.route_stat)[:, 3]).all()
+    assert (segments(halted)[:, 1] == segments(full)[:, 1]).all()
     # the same body with room enqueues
     went = jax.block_until_ready(step_fn(mid))
     assert (np.asarray(went.qtail) > np.asarray(mid.qtail)).any()
@@ -739,15 +800,16 @@ def test_no_scatter_holds_the_queue():
 
 def test_a_snapshot_from_before_the_column_is_refused_by_name(
         mesh_run, tmp_path):
-    """route_stat grew a column with enqueue_segments: a checkpoint cut
-    by the engine before (three columns) does not resume, and the
-    refusal names the leaf - whole-carry snapshots and a pod's reshard
-    alike."""
+    """route_stat lost its two segment counts to the commit's block
+    (ISSUE 50): a checkpoint cut by an engine before (three columns, or
+    four) does not resume, and the refusal names the leaf - whole-carry
+    snapshots and a pod's reshard alike."""
     from jaxtlc.dist.pod import reshard_carry
     from jaxtlc.engine.checkpoint import load_checkpoint, save_checkpoint
 
     carry = jax.tree.map(np.asarray, mesh_run[0])
-    old = carry._replace(route_stat=carry.route_stat[:, :3])
+    old = carry._replace(route_stat=np.pad(carry.route_stat,
+                                           ((0, 0), (0, 1))))
     path = str(tmp_path / "old.npz")
     save_checkpoint(path, old, {})
     with pytest.raises(ValueError, match=r"\.route_stat shape \(4, 3\)"):
@@ -757,6 +819,15 @@ def test_a_snapshot_from_before_the_column_is_refused_by_name(
     save_checkpoint(path, carry, {})
     _, back = load_checkpoint(path, carry)
     assert (np.asarray(back.route_stat) == carry.route_stat).all()
+    # the commit's own counts (ISSUE 50) are a leaf of the layout too:
+    # a snapshot cut before them is refused by the leaf's name, not
+    # resumed with a zeroed block
+    older = carry._replace(commit_stat=None)
+    save_checkpoint(path, older, {})
+    with pytest.raises(ValueError, match=r"no leaf \.commit_stat"):
+        load_checkpoint(path, carry)
+    with pytest.raises(ValueError, match="lacks leaf 'commit_stat'"):
+        reshard_carry(older, kubeapi_backend(FF), 2)
 
 
 # -- (f) the source side without per-element indexing at candidate width --
